@@ -1,0 +1,527 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the served path starts on the chip.
+
+One process, no children. With no arguments (one TPU chip) it serves
+Qwen3-1.7B — preset widths, full depth, random weights from a fixed key —
+through the entry points a user calls: ``make_mesh`` → ``Engine`` →
+``Fleet.build`` → ``BatchEngine`` → the fused paged-attention kernel
+compiled by Mosaic (``interpret=False``: a kernel that cannot compile
+fails the run; nothing continues on the CPU or the interpreter). It then
+FAILS unless every request finished with the tokens it asked for, the
+fleet recorded no replica failure, nothing retraced, the pool's
+invariants hold, the prefix cache hit, and the paged step's logits agree
+with ``Engine``'s contiguous-cache forward on the same chip.
+
+``--chips 4`` (a four-chip host) runs only the tensor-parallel phase:
+Qwen3-8B over ``make_mesh({"tp": 4})`` through ``BatchEngine``, once in
+``mode="dist"`` (AG-GEMM / GEMM-RS over ICI) and once in ``mode="xla"`` on
+the same weights, compared numerically.
+
+Every line of standard output is one JSON object. The last one is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``;
+on any failure the exit code is non-zero and that line is not printed.
+Seconds printed here are set-up and wall records of this run, not
+performance results.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+
+# Smoke geometry. Widths, depth, vocabulary and max_length are the presets'
+# own; nothing here shrinks the model (block_n is Engine's default).
+# tests/test_chip_smoke.py drives the same functions with a tiny dict of its
+# own on the CPU.
+ONE_CHIP = dict(
+    model="qwen3-1.7b", interpret=False, block_n=256, seed=0,
+    n_slots=8, block_size=16, prefill_chunk=64,
+    n_requests=12, prompt_range=(200, 1500), new_tokens=64,
+    ref_len=320,        # two requests share this length (Engine batch)
+    prefix_len=512,     # second wave shares this much of a finished prompt
+)
+FOUR_CHIPS = dict(
+    model="qwen3-8b", interpret=False, block_n=256, seed=0,
+    n_slots=8, block_size=16, prefill_chunk=64,
+    n_requests=10, prompt_range=(200, 1500), new_tokens=64, ref_len=320,
+)
+
+# Largest |difference| of two logit rows over the largest |reference logit|.
+# bf16 keeps 8 mantissa bits (2^-8 per rounded op); over 28-36 layers of
+# ~8 rounded ops that accumulates to a few percent of the logit scale
+# between two correct programs that tile or order the same sums
+# differently, while a wrong block, mask or rope position moves logits by
+# their own scale (ratio near 1). float32 (the CPU test) gets 1e-4.
+TOL_BF16 = 0.1
+TOL_F32 = 1e-4
+
+
+class SmokeFailure(AssertionError):
+    """A check of the smoke did not hold."""
+
+
+def emit(**obj) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+class _CacheEvents:
+    """Counts JAX's persistent-compilation-cache hits and misses."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snapshot(self) -> dict:
+        return {"cache_hits": self.hits, "cache_misses": self.misses}
+
+
+def device_info(devices) -> dict:
+    import jax
+
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(jax.devices())}
+
+
+def make_prompts(rng, vocab: int, geo: dict) -> list[list[int]]:
+    """``n_requests`` prompts from ``rng``: the first two of ``ref_len``
+    tokens (the pair compared with ``Engine``), the third at the top of the
+    range (so a finished prompt always covers the shared prefix), the rest
+    uniform over ``prompt_range``."""
+    lo, hi = geo["prompt_range"]
+    lens = [geo["ref_len"], geo["ref_len"], hi]
+    lens += [int(x) for x in rng.integers(lo, hi + 1,
+                                          geo["n_requests"] - len(lens))]
+    return [[int(t) for t in rng.integers(0, vocab, n)] for n in lens]
+
+
+def peak_bytes(devices) -> list:
+    out = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        out.append(stats.get("peak_bytes_in_use"))
+    return out
+
+
+# -- numeric references ------------------------------------------------------
+
+
+def paged_logits(be, prompts, next_tok):
+    """Logits of the PAGED programs on ``be``'s own pool for equal-length
+    ``prompts``: chunked prefill through ``Engine._make_sm(paged="prefill")``
+    (the mixed step's forward, which returns logits where the serving step
+    returns sampled tokens), then one decode-shaped step
+    (``paged="decode"``) feeding ``next_tok``. Returns float32
+    ``(prefill_last_position_logits, decode_logits)``, one row a prompt."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from triton_distributed_tpu.serving.kv_pool import PagedKVState
+
+    eng, pool = be.engine, be.pool
+    n, chunk, n_p, plen = be.n_slots, be.prefill_chunk, len(prompts), \
+        len(prompts[0])
+    pre = jax.jit(eng._make_sm(eng.prefill_mode, paged="prefill",
+                               paged_attn=be.paged_attn),
+                  donate_argnums=(2, 3))
+    dec = jax.jit(eng._make_sm(eng.decode_mode, paged="decode",
+                               paged_attn=be.paged_attn),
+                  donate_argnums=(2, 3))
+    sids = [f"smoke-ref-{i}" for i in range(n_p)]
+    for sid in sids:
+        check(pool.ensure(sid, plen + 1), "pool could not fund the "
+              "reference sequences on an idle engine")
+    try:
+        tables = jnp.asarray(pool.padded_tables(sids + [None] * (n - n_p)))
+        live = np.arange(n) < n_p
+        mask = jnp.asarray(live)
+        k, v = pool.state.k, pool.state.v
+        toks = np.asarray(prompts, np.int32)
+        for off in range(0, plen, chunk):
+            take = min(chunk, plen - off)
+            ids = np.zeros((n, chunk), np.int32)
+            ids[:n_p, :take] = toks[:, off:off + take]
+            pre_logits, k, v = pre(
+                eng.params, jnp.asarray(ids), k, v,
+                jnp.asarray(np.where(live, off, 0).astype(np.int32)),
+                tables, mask,
+                jnp.asarray(np.where(live, take, 0).astype(np.int32)))
+        ids = np.zeros((n, 1), np.int32)
+        ids[:n_p, 0] = next_tok
+        dec_logits, k, v = dec(
+            eng.params, jnp.asarray(ids), k, v,
+            jnp.asarray(np.where(live, plen, 0).astype(np.int32)),
+            tables, mask)
+        pool.state = PagedKVState(k=k, v=v)
+    finally:
+        for sid in sids:
+            pool.release(sid)
+    return (np.asarray(pre_logits, np.float32)[:n_p],
+            np.asarray(dec_logits, np.float32)[:n_p])
+
+
+def contiguous_logits(engine, prompts, next_tok):
+    """The same two logit rows from ``Engine``'s contiguous-cache forward
+    (``prefill`` of the whole prompt, then ``decode_step``)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    ids = jnp.asarray(prompts, jnp.int32)
+    pre_logits, kv = engine.prefill(ids, engine.new_cache(len(prompts)))
+    dec_logits, _ = engine.decode_step(jnp.asarray(next_tok, jnp.int32), kv)
+    return (np.asarray(pre_logits, np.float32),
+            np.asarray(dec_logits, np.float32))
+
+
+def compare_logits(what: str, got, ref, tol: float) -> dict:
+    import numpy as np
+
+    out = {"phase": "numeric", "compared": what, "tolerance": tol}
+    for name, g, r in (("prefill", got[0], ref[0]),
+                       ("decode", got[1], ref[1])):
+        check(g.shape == r.shape, f"{what}: {name} logits shape {g.shape} "
+              f"!= reference {r.shape}")
+        check(bool(np.isfinite(g).all() and np.isfinite(r).all()),
+              f"{what}: non-finite {name} logits")
+        out[f"{name}_max_abs_diff"] = float(np.abs(g - r).max())
+        out[f"{name}_max_abs_ref"] = float(np.abs(r).max())
+        out[f"{name}_rel"] = (out[f"{name}_max_abs_diff"]
+                              / max(out[f"{name}_max_abs_ref"], 1e-30))
+        out[f"{name}_argmax_equal"] = int(
+            (g.argmax(-1) == r.argmax(-1)).sum())
+    emit(**out)
+    for name in ("prefill", "decode"):
+        check(out[f"{name}_rel"] <= tol,
+              f"{what}: {name} logits differ by {out[f'{name}_rel']:.4g} "
+              f"of the reference scale (tolerance {tol})")
+    return out
+
+
+def logit_tolerance(config) -> float:
+    import jax.numpy as jnp
+
+    return TOL_BF16 if jnp.dtype(config.dtype).itemsize < 4 else TOL_F32
+
+
+def token_agreement(a: list[int], b: list[int]) -> int:
+    """Length of the common greedy prefix (printed, never gated: with bf16
+    and random weights greedy tokens flip on near-ties)."""
+    n = 0
+    for x, y in zip(a, b):
+        if x != y:
+            break
+        n += 1
+    return n
+
+
+# -- one chip: Fleet -> BatchEngine -> fused paged attention -----------------
+
+
+def drain_fleet(fleet, caches: _CacheEvents, max_steps: int = 50_000) -> dict:
+    """Step ``fleet`` until idle. Returns step/wall counts and, for the
+    first call of each compiled step, its wall seconds (compile + one run)
+    with the compile-cache counters at that point."""
+    eng = fleet.replicas[0].engine
+    first: dict = {}
+    steps = idle = 0
+    t_all = time.perf_counter()
+    while steps < max_steps:
+        before = {k: eng.metrics.counters.get(k, 0.0)
+                  for k in ("prefill_steps", "decode_steps")}
+        c0 = caches.snapshot()
+        t0 = time.perf_counter()
+        busy = fleet.step()
+        dt = time.perf_counter() - t0
+        for kind, key in (("mixed", "prefill_steps"),
+                          ("decode", "decode_steps")):
+            if kind not in first and \
+                    eng.metrics.counters.get(key, 0.0) > before[key]:
+                c1 = caches.snapshot()
+                first[kind] = {
+                    "seconds": round(dt, 3),
+                    "cache_hits": c1["cache_hits"] - c0["cache_hits"],
+                    "cache_misses": c1["cache_misses"] - c0["cache_misses"]}
+        steps += 1
+        if busy:
+            idle = 0
+        elif not fleet.pending and all(rep.empty or rep.state == "DEAD"
+                                       for rep in fleet.replicas):
+            break
+        else:
+            idle += 1
+            check(idle <= 1000, "fleet made no progress for 1000 idle steps")
+    check(steps < max_steps, f"fleet still busy after {max_steps} steps")
+    return {"steps": steps, "wall_s": time.perf_counter() - t_all,
+            "first_call": first}
+
+
+def check_fleet(fleet, want: dict) -> None:
+    """The checks the replica error boundary cannot swallow."""
+    rep = fleet.replicas[0]
+    eng = rep.engine
+    fm = fleet.metrics.as_dict()
+    bad = [r for r in fleet.replicas if r.state != "HEALTHY"]
+    if bad or fm.get("replica_step_failures", 0.0):
+        errors = [r.last_error for r in fleet.replicas if r.last_error]
+        raise SmokeFailure(
+            f"replica left HEALTHY or a step failed: states "
+            f"{[r.state for r in fleet.replicas]}, step failures "
+            f"{fm.get('replica_step_failures', 0.0)}, first recorded "
+            f"exception: {errors[0] if errors else None}; state log "
+            f"{fleet.state_log[:3]}")
+    failed = fleet.failed
+    check(not failed and not fm.get("requests_failed", 0.0)
+          and not eng.metrics.counters.get("requests_failed", 0.0),
+          f"requests failed: "
+          f"{ {k: getattr(r, 'error', None) for k, r in failed.items()} }")
+    fin = fleet.finished
+    for rid, n_new in want.items():
+        check(rid in fin, f"request {rid} did not finish")
+        check(len(fin[rid].output) == n_new,
+              f"request {rid} produced {len(fin[rid].output)} tokens, "
+              f"asked for {n_new}")
+    check(eng.trace_counts == {"decode": 1, "prefill": 1},
+          f"trace_counts {eng.trace_counts} != {{1, 1}} (a step retraced)")
+    eng.pool.check_invariants()
+    fleet.check_invariants()
+    check(eng.metrics.counters.get("prefix_hits", 0.0) > 0,
+          "the shared-prefix wave produced no prefix-cache hit")
+
+
+def run_one_chip(devices, geo: dict) -> None:
+    import jax
+    import numpy as np
+
+    from triton_distributed_tpu.kernels.paged_attention import (
+        tuned_paged_tile,
+    )
+    from triton_distributed_tpu.models.config import ModelConfig
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.runtime.mesh import make_mesh
+    from triton_distributed_tpu.serving.fleet import Fleet
+    from triton_distributed_tpu.tools.aot import enable_xla_compilation_cache
+
+    cache_path = enable_xla_compilation_cache()
+    caches = _CacheEvents()
+    emit(phase="device", compile_cache_dir=cache_path, **device_info(devices))
+
+    cfg = ModelConfig.from_name(geo["model"])
+    mesh = make_mesh({"tp": 1}, devices=devices[:1], set_default=False)
+    t0 = time.perf_counter()
+    engine = Engine(cfg, mesh=mesh, mode="dist",
+                    key=jax.random.PRNGKey(geo["seed"]),
+                    block_n=geo["block_n"], interpret=geo["interpret"])
+    jax.block_until_ready(engine.params)
+    t_params = time.perf_counter() - t0
+
+    # The served steps reach ``paged_attention`` only under the jit trace,
+    # where the tuner never times: it bakes a cached winner or the
+    # heuristic default. So the decode shape (L=1, six kv-tile candidates)
+    # is tuned here, eagerly, before the first trace — what the tuner's own
+    # trace-fallback warning asks of a caller. The chunk shape (L>1) stays
+    # on the heuristic: its 42 (kv-tile, q-tile) candidates include
+    # q_tile=1 x tile=1, 131k grid steps a call over ~1.8k timed calls
+    # (ROADMAP S5).
+    g = cfg.n_heads // cfg.n_kv_heads
+    max_blocks = -(-cfg.max_length // geo["block_size"])
+    tile_args = (geo["block_size"], cfg.n_kv_heads, cfg.head_dim, max_blocks,
+                 str(np.dtype(cfg.dtype)))
+    t0 = time.perf_counter()
+    decode_cfg = tuned_paged_tile(*tile_args, L=1, g=g)
+    t_tune = time.perf_counter() - t0
+    mixed_cfg = tuned_paged_tile(*tile_args, L=geo["prefill_chunk"], g=g)
+    emit(phase="autotune", seconds=round(t_tune, 3),
+         decode_tile_qtile=list(decode_cfg), decode_tuned_eagerly=True,
+         mixed_tile_qtile=list(mixed_cfg), mixed_tuned_eagerly=False)
+
+    t0 = time.perf_counter()
+    fleet = Fleet.build(engine, n_replicas=1, n_slots=geo["n_slots"],
+                        block_size=geo["block_size"],
+                        prefill_chunk=geo["prefill_chunk"],
+                        paged_attn="fused")
+    be = fleet.replicas[0].engine
+    jax.block_until_ready(be.pool.state)
+    t_pool = time.perf_counter() - t0
+    emit(phase="build", model=geo["model"], n_layers=cfg.n_layers,
+         d_model=cfg.d_model, vocab=cfg.vocab_size,
+         max_length=cfg.max_length, params_s=round(t_params, 3),
+         pool_s=round(t_pool, 3), pool_blocks=be.pool.n_blocks,
+         kv_dtype=be.pool.kv_dtype.name, paged_attn=be.paged_attn)
+
+    rng = np.random.default_rng(geo["seed"])
+    prompts = make_prompts(rng, cfg.vocab_size, geo)
+    want, rids = {}, []
+    for p in prompts:
+        rid = fleet.submit(p, geo["new_tokens"])
+        rids.append(rid)
+        want[rid] = geo["new_tokens"]
+    wave1 = drain_fleet(fleet, caches)
+    # Second wave: two requests sharing a prefix with a request that has
+    # FINISHED (the radix cache inserts at completion).
+    donor = prompts[2]
+    check(len(donor) >= geo["prefix_len"], "donor prompt shorter than the "
+          "shared prefix")
+    lo, hi = geo["prompt_range"]
+    for _ in range(2):
+        tail = rng.integers(0, cfg.vocab_size, int(rng.integers(lo, hi) // 4))
+        rid = fleet.submit(donor[:geo["prefix_len"]] + [int(t) for t in tail],
+                           geo["new_tokens"])
+        want[rid] = geo["new_tokens"]
+    wave2 = drain_fleet(fleet, caches)
+    emit(phase="first_call", **wave1["first_call"], **caches.snapshot())
+    tokens = sum(len(r.output) for r in fleet.finished.values())
+    emit(phase="serve", requests=len(want), steps=wave1["steps"]
+         + wave2["steps"], tokens_generated=tokens,
+         wall_s=round(wave1["wall_s"] + wave2["wall_s"], 3),
+         prefix_hits=be.metrics.counters.get("prefix_hits", 0.0),
+         prefix_cached_tokens=be.metrics.counters.get(
+             "prefix_cached_tokens", 0.0),
+         preemptions=be.metrics.counters.get("preemptions", 0.0),
+         trace_counts=be.trace_counts,
+         replica_states=[r.state for r in fleet.replicas])
+    check_fleet(fleet, want)
+
+    # Numbers, not argmax: paged step against the contiguous-cache forward.
+    ref_prompts = prompts[:2]
+    next_tok = [p[0] for p in ref_prompts]
+    compare_logits("paged mixed+decode step vs Engine contiguous cache",
+                   paged_logits(be, ref_prompts, next_tok),
+                   contiguous_logits(engine, ref_prompts, next_tok),
+                   logit_tolerance(cfg))
+    be.pool.check_invariants()
+    served = [list(fleet.finished[r].output) for r in rids[:2]]
+    alone = np.asarray(engine.serve(np.asarray(ref_prompts, np.int32),
+                                    geo["new_tokens"])).tolist()
+    emit(phase="greedy_agreement", gated=False, of=geo["new_tokens"],
+         common_prefix=[token_agreement(s, a)
+                        for s, a in zip(served, alone)])
+    emit(phase="memory", peak_bytes_in_use=peak_bytes(devices[:1]),
+         **caches.snapshot())
+
+
+# -- four chips: TP=4 dist against xla ---------------------------------------
+
+
+def run_four_chips(devices, geo: dict) -> None:
+    import jax
+    import numpy as np
+
+    from triton_distributed_tpu.models.config import ModelConfig
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.runtime.mesh import make_mesh
+    from triton_distributed_tpu.serving.batch_engine import BatchEngine
+    from triton_distributed_tpu.tools.aot import enable_xla_compilation_cache
+
+    cache_path = enable_xla_compilation_cache()
+    caches = _CacheEvents()
+    emit(phase="device", compile_cache_dir=cache_path, **device_info(devices))
+    cfg = ModelConfig.from_name(geo["model"])
+    mesh = make_mesh({"tp": len(devices)}, devices=devices, set_default=False)
+    t0 = time.perf_counter()
+    engines = {"dist": Engine(cfg, mesh=mesh, mode="dist",
+                              key=jax.random.PRNGKey(geo["seed"]),
+                              block_n=geo["block_n"],
+                              interpret=geo["interpret"])}
+    jax.block_until_ready(engines["dist"].params)
+    engines["xla"] = Engine(cfg, mesh=mesh, mode="xla",
+                            params=engines["dist"].params,
+                            block_n=geo["block_n"],
+                            interpret=geo["interpret"])
+    emit(phase="build", model=geo["model"], n_layers=cfg.n_layers,
+         d_model=cfg.d_model, vocab=cfg.vocab_size, tp=len(devices),
+         params_s=round(time.perf_counter() - t0, 3),
+         peak_bytes_after_params=peak_bytes(devices))
+
+    rng = np.random.default_rng(geo["seed"])
+    prompts = make_prompts(rng, cfg.vocab_size, geo)
+    ref_prompts = prompts[:2]
+    next_tok = [p[0] for p in ref_prompts]
+    outputs, logits = {}, {}
+    for mode, engine in engines.items():
+        t0 = time.perf_counter()
+        # nan_guard: a non-finite logit row quarantines its request, which
+        # the failed-requests check below then reports.
+        be = BatchEngine(engine, n_slots=geo["n_slots"],
+                         block_size=geo["block_size"],
+                         prefill_chunk=geo["prefill_chunk"],
+                         paged_attn="fused", nan_guard=True)
+        rids = [be.submit(p, geo["new_tokens"]) for p in prompts]
+        done = be.run(max_steps=50_000)   # a step exception propagates
+        check(not be.failed, f"{mode}: requests failed: "
+              f"{ {k: r.error for k, r in be.failed.items()} }")
+        for rid in rids:
+            check(len(done.get(rid, ())) == geo["new_tokens"],
+                  f"{mode}: request {rid} produced "
+                  f"{len(done.get(rid, ()))} tokens")
+        check(be.trace_counts == {"decode": 1, "prefill": 1},
+              f"{mode}: trace_counts {be.trace_counts} != {{1, 1}}")
+        be.pool.check_invariants()
+        outputs[mode] = [done[r] for r in rids]
+        logits[mode] = paged_logits(be, ref_prompts, next_tok)
+        emit(phase="serve", mode=mode, requests=len(rids),
+             steps=int(be.metrics.counters.get("prefill_steps", 0.0)
+                       + be.metrics.counters.get("decode_steps", 0.0)),
+             tokens_generated=sum(len(o) for o in outputs[mode]),
+             wall_s=round(time.perf_counter() - t0, 3),
+             trace_counts=be.trace_counts, **caches.snapshot())
+        del be   # free this mode's pool before the next is built
+    compare_logits("TP=4 paged mixed+decode step, mode=dist vs mode=xla",
+                   logits["dist"], logits["xla"], logit_tolerance(cfg))
+    emit(phase="greedy_agreement", gated=False, of=geo["new_tokens"],
+         common_prefix=[token_agreement(a, b) for a, b in
+                        zip(outputs["dist"], outputs["xla"])])
+    emit(phase="memory", peak_bytes_in_use=peak_bytes(devices),
+         **caches.snapshot())
+
+
+def smoke(run, devices, geo: dict) -> int:
+    """Run one phase. Prints the ``ok`` line and returns 0 only if every
+    check of it held; any other exception propagates (non-zero exit, no
+    ``ok`` line)."""
+    try:
+        run(devices, geo)
+    except SmokeFailure as e:
+        print(f"chip_smoke FAILED: {e}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device_info(devices)}),
+          flush=True)
+    return 0
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                        help="4: run only the TP=4 Qwen3-8B dist-vs-xla "
+                             "phase (needs a four-chip host)")
+    args = parser.parse_args(argv)
+
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < args.chips:
+        print(f"chip_smoke: needs {args.chips} TPU device(s); JAX found "
+              f"{len(devices)} x {devices[0].platform!r}. No CPU or "
+              f"interpreter continuation.", file=sys.stderr)
+        return 2
+    if args.chips == 4:
+        return smoke(run_four_chips, devices[:4], FOUR_CHIPS)
+    return smoke(run_one_chip, devices[:1], ONE_CHIP)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

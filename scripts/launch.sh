@@ -39,15 +39,19 @@ fi
 REPO_DIR="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 export PYTHONPATH="${REPO_DIR}${PYTHONPATH:+:$PYTHONPATH}"
 
-# Persistent XLA compile cache: with N hosts compiling the same SPMD
-# program, a shared cache dir (NFS/GCS-fuse) makes host 1..N-1 deserialize
-# what host 0 compiled. Safe to leave default (per-host) too.
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/triton_distributed_tpu/xla}"
+# Persistent XLA compile cache — the same rule as
+# tools/aot.enable_xla_compilation_cache: a directory the environment
+# names wins; otherwise the fixed in-checkout .cache/jax (never $HOME, a
+# temp name, a pid or a time: the path is part of the cache key). With N
+# hosts compiling the same SPMD program, pointing it at a shared dir
+# (NFS/GCS-fuse) makes host 1..N-1 deserialize what host 0 compiled.
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$REPO_DIR/.cache/jax}"
 
-# Contextual-autotuner disk cache must be per-chip-type but SHARED across
-# the job's hosts if possible (the vote is collective either way; a shared
-# cache just skips re-tunes). TDT_AUTOTUNE=0 disables tuning entirely.
-export TDT_AUTOTUNE_CACHE="${TDT_AUTOTUNE_CACHE:-$HOME/.cache/triton_distributed_tpu/autotune.json}"
+# Contextual-autotuner winners live beside it (they change what gets
+# compiled); share across the job's hosts if possible (the vote is
+# collective either way; a shared cache just skips re-tunes).
+# TDT_AUTOTUNE=0 disables tuning entirely.
+export TDT_AUTOTUNE_CACHE="${TDT_AUTOTUNE_CACHE:-$REPO_DIR/.cache/autotune.json}"
 
 # Surface hangs rather than waiting forever on a lost host: a collective
 # stuck longer than this dumps per-host stacks and aborts the job.

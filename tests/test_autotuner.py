@@ -101,21 +101,30 @@ def test_infeasible_configs_lose(monkeypatch):
 
     assert tuner.tune(make_thunk, "k") == 4.0
 
-    # Every candidate failing is a TRANSIENT (jitter/compile hiccup): the
-    # tuner falls back to config 0 with a warning and does NOT cache the
-    # verdict, so a later call re-tunes — it must not crash the caller
-    # (and in multi-process runs every process must still join the vote,
-    # so there is no early raise).
+    # Every candidate failing to BUILD is not a transient: there is nothing
+    # to choose from, so the tuner raises the first candidate's exception
+    # (config 0 would fail the same way later, with the cause gone).
     tuner_all_bad = autotuner.ContextualAutotuner("i2", ["bad", "bad2"])
 
     def all_bad(cfg):
-        raise ValueError("does not compile")
+        raise ValueError(f"does not compile: {cfg}")
 
+    with pytest.raises(RuntimeError, match="all 2 candidate.*bad") as ei:
+        tuner_all_bad.tune(all_bad, "k")
+    assert isinstance(ei.value.__cause__, ValueError)
+    assert tuner_all_bad.peek("k") is None  # nothing cached
+
+    # Candidates that BUILD but yield no valid timing (slope jitter) are
+    # the transient: config 0 with a warning, verdict NOT cached, so a
+    # later call re-tunes (and in multi-process runs every process still
+    # joins the vote).
+    tuner_jitter = autotuner.ContextualAutotuner(
+        "i3", ["a", "b"], timer=lambda thunk: float("inf"))
     with pytest.warns(UserWarning, match="no candidate"):
-        assert tuner_all_bad.tune(all_bad, "k") == "bad"
-    assert tuner_all_bad.peek("k") is None  # verdict not cached
+        assert tuner_jitter.tune(lambda cfg: (lambda: cfg), "k") == "a"
+    assert tuner_jitter.peek("k") is None  # verdict not cached
     with pytest.warns(UserWarning, match="no candidate"):
-        tuner_all_bad.tune(all_bad, "k")  # re-asked, not memoized
+        tuner_jitter.tune(lambda cfg: (lambda: cfg), "k")  # re-asked
 
 
 def test_decorator_form(monkeypatch):

@@ -1,0 +1,158 @@
+"""The served path's kernels, compiled by the chip's own compiler.
+
+Every case lowers and compiles for a DESCRIBED ``v5e:2x2`` (the four-chip
+host; no chip attached, nothing runs) in this test's own process — what
+interpret mode cannot show: tile alignment, VMEM budgets, kernels that
+cannot be partitioned. Shapes are the smoke's (``chip_smoke.py``):
+Qwen3-1.7B on one chip and Qwen3-8B's TP=4 shard, 8 slots, block 16,
+chunk 64, a 2,048-block pool.
+
+The topology is described inside a module-scoped fixture, never at import,
+in a ``skipif`` or in ``parametrize`` arguments: only one process may load
+the TPU library, xdist workers all import this file, and only the worker
+that RUNS it may load it. Keep every such test in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from triton_distributed_tpu.runtime.compat import shard_map
+
+BLOCK, SLOTS, CHUNK, MAX_LEN = 16, 8, 64, 4096
+MAX_BLOCKS = MAX_LEN // BLOCK
+N_BLOCKS = SLOTS * MAX_BLOCKS
+DH = 128
+# (Hq, Hkv) per device: Qwen3-1.7B whole, Qwen3-8B's TP=4 shard.
+HEADS = {"qwen3-1.7b": (16, 8), "qwen3-8b-tp4": (8, 2)}
+# Qwen3-8B: d_model, fused qkv width, d_ff.
+D8, QKV8, FF8 = 4096, (32 + 2 * 8) * DH, 12_288
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("tp",))
+
+
+@pytest.fixture(autouse=True)
+def _compile_cache_off():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prior)
+    cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+@pytest.mark.parametrize("L", [1, CHUNK], ids=["decode", "chunk"])
+@pytest.mark.parametrize("model", sorted(HEADS))
+def test_paged_attention_compiles(one_chip, model, L):
+    from triton_distributed_tpu.kernels.paged_attention import (
+        paged_attention,
+    )
+
+    hq, hkv = HEADS[model]
+
+    def fn(q, kp, vp, tables, kv_lens, q_lens):
+        return paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
+                               interpret=False)
+
+    pool = _sds((N_BLOCKS, BLOCK, hkv, DH), jnp.bfloat16, one_chip)
+    compiled = jax.jit(fn).lower(
+        _sds((SLOTS, L, hq, DH), jnp.bfloat16, one_chip), pool, pool,
+        _sds((SLOTS, MAX_BLOCKS), jnp.int32, one_chip),
+        _sds((SLOTS,), jnp.int32, one_chip),
+        _sds((SLOTS,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _tp4_compile(tp4, fn, in_specs, out_specs, *shapes):
+    sm = shard_map(fn, mesh=tp4, in_specs=in_specs, out_specs=out_specs,
+                   check_vma=False)
+    args = [_sds(s, jnp.bfloat16, NamedSharding(tp4, spec))
+            for s, spec in zip(shapes, in_specs)]
+    return jax.jit(sm).lower(*args).compile().as_text()
+
+
+# Rows per device: a decode step at 8 slots over TP=4 (2 — below the
+# sublane tile, padded inside the kernel wrappers) and a mixed step
+# (8 slots x chunk 64 / 4).
+@pytest.mark.parametrize("rows", [SLOTS // 4, SLOTS * CHUNK // 4],
+                         ids=["decode-rows", "chunk-rows"])
+@pytest.mark.parametrize("proj", ["qkv", "gate_up"])
+def test_ag_gemm_compiles_tp4(tp4, proj, rows):
+    from triton_distributed_tpu.kernels.allgather_gemm import (
+        AGGEMMConfig,
+        ag_gemm_device,
+    )
+
+    n = {"qkv": QKV8, "gate_up": 2 * FF8}[proj]
+    text = _tp4_compile(
+        tp4,
+        lambda a, b: ag_gemm_device(a, b, axis="tp",
+                                    config=AGGEMMConfig(block_n=256),
+                                    interpret=False),
+        (P("tp", None), P(None, "tp")), P(None, "tp"),
+        (4 * rows, D8), (D8, n))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [SLOTS // 4, SLOTS * CHUNK // 4],
+                         ids=["decode-rows", "chunk-rows"])
+@pytest.mark.parametrize("proj", ["o", "down"])
+def test_gemm_rs_compiles_tp4(tp4, proj, rows):
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        GEMMRSConfig,
+        gemm_rs_device,
+    )
+
+    k = {"o": 32 * DH, "down": FF8}[proj]
+    text = _tp4_compile(
+        tp4,
+        lambda a, b: gemm_rs_device(a, b, axis="tp",
+                                    config=GEMMRSConfig(block_n=256),
+                                    interpret=False),
+        (P(None, "tp"), P("tp", None)), P("tp", None),
+        (4 * rows, k), (k, D8))
+    assert "tpu_custom_call" in text
+
+
+def test_oneshot_allreduce_compiles_tp4(tp4):
+    from triton_distributed_tpu.kernels.allreduce import oneshot_all_reduce
+
+    # mode="ar" decode: every device holds the full (8, d_model) partial.
+    text = _tp4_compile(
+        tp4,
+        lambda x: oneshot_all_reduce(x[0], axis="tp", interpret=False)[None],
+        (P("tp", None, None),), P("tp", None, None), (4, SLOTS, D8))
+    assert "tpu_custom_call" in text

@@ -1,0 +1,326 @@
+"""chip_smoke.py's own checks, and the start-up rules it leans on.
+
+The smoke's serving function runs here at ``tiny`` on the CPU (the geometry
+comes from this test, not from an option of the script); the rest are unit
+tests of the repairs the chip run needs: one compile-cache rule, errors
+that must raise instead of being absorbed, and the pool's allocation.
+"""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
+import chip_smoke  # noqa: E402
+
+from triton_distributed_tpu.models import Engine, ModelConfig  # noqa: E402
+from triton_distributed_tpu.runtime import autotuner, perf_model  # noqa: E402
+from triton_distributed_tpu.runtime import platform as _platform  # noqa: E402
+from triton_distributed_tpu.runtime.mesh import make_mesh  # noqa: E402
+from triton_distributed_tpu.serving import HEALTHY, Fleet  # noqa: E402
+from triton_distributed_tpu.serving import batch_engine as _be  # noqa: E402
+from triton_distributed_tpu.serving import kv_pool as _kv  # noqa: E402
+from triton_distributed_tpu.tools import aot  # noqa: E402
+
+TINY = dict(model="tiny", interpret=None, block_n=8, seed=0, n_slots=2,
+            block_size=4, prefill_chunk=8, n_requests=4,
+            prompt_range=(6, 16), new_tokens=3, ref_len=10, prefix_len=8)
+
+
+# -- the smoke itself ---------------------------------------------------------
+
+
+@pytest.fixture
+def restore_compile_cache_config():
+    """The smoke turns the persistent compile cache on for its process;
+    this worker runs other test files afterwards, so put it back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    prior = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in prior.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def test_smoke_passes_at_tiny_and_prints_the_ok_line(
+        capsys, restore_compile_cache_config):
+    rc = chip_smoke.smoke(chip_smoke.run_one_chip, jax.devices()[:1], TINY)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0
+    records = [json.loads(line) for line in lines]   # every line is JSON
+    last = records[-1]
+    assert last == {"ok": True, "device": {
+        "platform": "cpu", "kind": jax.devices()[0].device_kind,
+        "count": len(jax.devices())}}
+    phases = [r.get("phase") for r in records[:-1]]
+    assert phases == ["device", "autotune", "build", "first_call", "serve",
+                      "numeric", "greedy_agreement", "memory"]
+    serve = records[phases.index("serve")]
+    assert serve["trace_counts"] == {"decode": 1, "prefill": 1}
+    assert serve["prefix_hits"] > 0
+    assert serve["tokens_generated"] == serve["requests"] * TINY["new_tokens"]
+    assert records[phases.index("numeric")]["prefill_rel"] < 1e-4
+
+
+def test_forced_step_exception_fails_the_smoke(
+        monkeypatch, capsys, restore_compile_cache_config):
+    """A step that raises at run time is absorbed by the replica error
+    boundary (a quarantined replica, failed requests, no exception) — the
+    smoke must turn that into a non-zero exit and no ``ok`` line."""
+    def boom(self):
+        raise RuntimeError("forced decode failure")
+
+    monkeypatch.setattr(_be.BatchEngine, "_run_decode", boom)
+    rc = chip_smoke.smoke(chip_smoke.run_one_chip, jax.devices()[:1], TINY)
+    out, err = capsys.readouterr()
+    assert rc == 1
+    assert '"ok"' not in out
+    assert "forced decode failure" in err   # first recorded exception shown
+
+
+def test_main_refuses_a_platform_that_is_not_a_tpu(capsys):
+    rc = chip_smoke.main([])
+    out, err = capsys.readouterr()
+    assert rc != 0 and out == "" and "TPU" in err
+    assert chip_smoke.main(["--chips", "4"]) != 0
+
+
+# -- one compile-cache rule ---------------------------------------------------
+
+
+@pytest.fixture
+def config_updates(monkeypatch):
+    """Record ``jax.config.update`` calls without applying them."""
+    calls = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.__setitem__(k, v))
+    return calls
+
+
+def test_cache_dir_from_environment_sets_none_in_code(monkeypatch,
+                                                      config_updates):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/where")
+    assert aot.enable_xla_compilation_cache() == "/some/where"
+    assert "jax_compilation_cache_dir" not in config_updates
+
+
+def test_cache_dir_default_is_fixed_and_inside_the_checkout(monkeypatch,
+                                                            config_updates):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = aot.enable_xla_compilation_cache()
+    assert config_updates["jax_compilation_cache_dir"] == first
+    assert aot.enable_xla_compilation_cache() == first   # equal across calls
+    assert first == os.path.join(_REPO, ".cache", "jax")
+    assert not first.startswith(os.path.expanduser("~") + os.sep) \
+        or _REPO.startswith(os.path.expanduser("~"))
+
+
+def test_tuning_and_executable_caches_default_beside_it(monkeypatch):
+    monkeypatch.delenv("TDT_AUTOTUNE_CACHE", raising=False)
+    monkeypatch.delenv("TDT_AOT_CACHE", raising=False)
+    root = os.path.join(_REPO, ".cache")
+    assert autotuner._cache_path() == os.path.join(root, "autotune.json")
+    assert aot.AOTExecutableCache().cache_dir == os.path.join(root, "aot")
+    monkeypatch.setenv("TDT_AUTOTUNE_CACHE", "/x/a.json")
+    monkeypatch.setenv("TDT_AOT_CACHE", "/x/aot")
+    assert autotuner._cache_path() == "/x/a.json"
+    assert aot.AOTExecutableCache().cache_dir == "/x/aot"
+
+
+# -- errors that raise instead of being absorbed ------------------------------
+
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.fixture
+def fresh_hw():
+    perf_model.detect_hardware.cache_clear()
+    yield
+    perf_model.detect_hardware.cache_clear()
+
+
+def test_detect_hardware_raises_on_an_unknown_tpu_kind(monkeypatch, fresh_hw):
+    monkeypatch.setattr(perf_model.jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", "TPU v9x")])
+    with pytest.raises(ValueError, match="TPU v9x"):
+        perf_model.detect_hardware()
+    with pytest.raises(ValueError, match="TPU v9x"):
+        perf_model.peak_bf16_tflops()
+    assert perf_model.peak_bf16_tflops(default=1000.0) == 1000.0
+
+
+def test_detect_hardware_known_tpu_cpu_and_dead_backend(monkeypatch,
+                                                        fresh_hw):
+    monkeypatch.setattr(perf_model.jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", "TPU v5 lite")])
+    assert perf_model.detect_hardware().name == "v5e"
+    perf_model.detect_hardware.cache_clear()
+    monkeypatch.setattr(perf_model.jax, "devices",
+                        lambda *a: [_FakeDevice("cpu", "cpu")])
+    assert perf_model.detect_hardware().name == "v5e"   # modelling choice
+
+    def dead(*a):
+        raise RuntimeError("backend failed to start")
+
+    perf_model.detect_hardware.cache_clear()
+    monkeypatch.setattr(perf_model.jax, "devices", dead)
+    with pytest.raises(RuntimeError, match="failed to start"):
+        perf_model.detect_hardware()
+
+
+def test_autotuner_raises_when_every_candidate_fails_to_build(
+        caplog, monkeypatch, tmp_path):
+    monkeypatch.setenv("TDT_AUTOTUNE_CACHE", str(tmp_path / "autotune.json"))
+    autotuner.clear_cache()
+    autotuner._logged_build_failures.clear()
+
+    def never(cfg):
+        raise ValueError(f"Mosaic refuses {cfg}")
+
+    tuner = autotuner.ContextualAutotuner(
+        "smoke_all_bad", [(8, 1), (4, 1)],
+        multi_timer=lambda thunks: [1.0 for _ in thunks])
+    with pytest.raises(RuntimeError, match=r"all 2 candidate.*\(8, 1\)"):
+        tuner.tune(never, "ctx")
+
+    # One failing candidate just loses; its exception text is logged once.
+    def one_bad(cfg):
+        if cfg == (8, 1):
+            raise ValueError("Mosaic refuses (8, 1)")
+        return lambda n: jnp.zeros(())
+
+    tuner = autotuner.ContextualAutotuner(
+        "smoke_one_bad", [(8, 1), (4, 1)],
+        multi_timer=lambda thunks: [float("inf") if t is None else 1.0
+                                    for t in thunks])
+    caplog.clear()
+    with caplog.at_level("WARNING"):
+        assert tuner.tune(one_bad, "ctx") == (4, 1)
+        autotuner.clear_cache()
+        assert tuner.tune(one_bad, "ctx") == (4, 1)
+    assert sum("Mosaic refuses (8, 1)" in r.getMessage()
+               for r in caplog.records) == 1
+    autotuner.clear_cache()
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    mesh = make_mesh({"tp": 1}, devices=jax.devices()[:1], set_default=False)
+    return Engine(ModelConfig.from_name("tiny"), mesh=mesh, mode="xla",
+                  block_n=8)
+
+
+def _fleet(engine):
+    return Fleet.build(engine, n_replicas=1, n_slots=2, n_blocks=16,
+                       block_size=4, prefill_chunk=8)
+
+
+@pytest.mark.parametrize("message, raised", [
+    ("Mosaic failed to compile TPU kernel", _be.StepBuildError),
+    ("RESOURCE_EXHAUSTED: out of memory allocating 4.0G", RuntimeError),
+])
+def test_build_and_resource_errors_pass_the_replica_boundary(
+        tiny_engine, monkeypatch, message, raised):
+    fleet = _fleet(tiny_engine)
+    eng = fleet.replicas[0].engine
+
+    def refuse(*a, **k):
+        raise RuntimeError(message)
+
+    if raised is RuntimeError:
+        # Not a first call: only the resource text carries it through.
+        eng._steps_built.update({"engine.prefill", "engine.decode"})
+    monkeypatch.setattr(eng, "_mixed_step", refuse)
+    fleet.submit([1, 2, 3, 4, 5], 2)
+    with pytest.raises(raised, match=message.split(":")[0]):
+        fleet.run(max_steps=20)
+    assert fleet.replicas[0].state == HEALTHY
+    assert not fleet.metrics.as_dict().get("replica_step_failures")
+
+
+def test_a_runtime_step_error_is_still_a_replica_fault(tiny_engine,
+                                                       monkeypatch):
+    fleet = _fleet(tiny_engine)
+    eng = fleet.replicas[0].engine
+    fleet.submit([1, 2, 3, 4, 5], 4)
+    assert fleet.step() and fleet.step()      # both steps built and run
+    assert eng._steps_built == {"engine.prefill", "engine.decode"}
+
+    def flaky(*a, **k):
+        raise RuntimeError("transient device error")
+
+    monkeypatch.setattr(eng, "_decode_step", flaky)
+    fleet.step()                              # absorbed, not raised
+    assert fleet.metrics.as_dict()["replica_step_failures"] == 1
+    assert fleet.replicas[0].state != HEALTHY
+
+
+def test_quantized_pool_is_refused_on_a_tpu_backend(monkeypatch):
+    cfg = ModelConfig.from_name("tiny")
+    monkeypatch.setattr(_kv, "on_tpu", lambda: True)
+    for kv_dtype in ("int8", "fp8"):
+        with pytest.raises(NotImplementedError,
+                           match="does not compile for the chip"):
+            _kv.KVPool(cfg, n_blocks=8, block_size=4, kv_dtype=kv_dtype)
+    _kv.KVPool(cfg, n_blocks=8, block_size=4)      # model dtype: fine
+    monkeypatch.setattr(_kv, "on_tpu", lambda: False)
+    assert _kv.KVPool(cfg, n_blocks=8, block_size=4,
+                      kv_dtype="int8").kv_quant     # interpreter: allowed
+
+
+# -- the pool is born sharded -------------------------------------------------
+
+
+def test_kv_pool_arrays_are_allocated_in_their_sharded_layout(mesh8,
+                                                              monkeypatch):
+    from jax._src.core import trace_state_clean
+    from jax.sharding import NamedSharding
+
+    from triton_distributed_tpu.models.kv_cache import KVCache
+
+    real_zeros = jnp.zeros
+
+    def zeros_only_under_a_trace(*a, **k):
+        assert not trace_state_clean(), "whole arena built eagerly"
+        return real_zeros(*a, **k)
+
+    def no_device_put(*a, **k):
+        raise AssertionError("arena re-laid out with device_put")
+
+    monkeypatch.setattr(_kv.jnp, "zeros", zeros_only_under_a_trace)
+    monkeypatch.setattr(_kv.jax, "device_put", no_device_put)
+    _kv._zeros_fn.cache_clear()
+    cfg = ModelConfig.from_name("tiny")          # 8 kv heads over tp=8
+    pool = _kv.KVPool(cfg, n_blocks=6, block_size=4, mesh=mesh8,
+                      kv_dtype="int8")
+    st = pool.state
+    want = NamedSharding(mesh8, KVCache.spec("tp")[0])
+    for arr in (st.k, st.v):
+        assert arr.sharding.is_equivalent_to(want, arr.ndim)
+        assert {s.data.shape for s in arr.addressable_shards} == {
+            (cfg.n_layers, 6, 4, 1, cfg.head_dim)}
+        assert not np.asarray(arr).any()
+    want_s = NamedSharding(mesh8, KVCache.scale_spec("tp"))
+    for arr in (st.k_scale, st.v_scale):
+        assert arr.dtype == jnp.float32
+        assert arr.sharding.is_equivalent_to(want_s, arr.ndim)
+    _kv._zeros_fn.cache_clear()
+
+
+def test_cache_dir_helper_is_fixed_and_in_checkout():
+    assert _platform.cache_dir("jax") == os.path.join(_REPO, ".cache", "jax")
+    assert _platform.cache_dir() == os.path.join(_REPO, ".cache")

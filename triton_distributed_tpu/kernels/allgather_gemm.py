@@ -398,6 +398,17 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
         # XLA delegation on ragged/VMEM-infeasible shapes.
         out = ag_gemm_single_chip(a_local, b_local, interpret=interpret)
         return (out, _probes.host_stub_buffer()) if probes else out
+    m_pad = common.mosaic_row_pad(m, a_local.dtype, interpret)
+    if m_pad != m:
+        # Mosaic only: a decode step's per-device row count (n_slots /
+        # world) is below the sublane tile the (m, bn) out block needs.
+        # Zero rows ride the gather and are dropped from every segment.
+        res = ag_gemm_device(
+            jnp.pad(a_local, ((0, m_pad - m), (0, 0))), b_local, axis=axis,
+            config=config, interpret=interpret, probes=probes)
+        out = (res[0] if probes else res).reshape(world, m_pad, n_local)
+        out = out[:, :m].reshape(world * m, n_local)
+        return (out, res[1]) if probes else out
     out_dtype = jnp.promote_types(a_local.dtype, b_local.dtype)
     config, bn_tail = _split_blocks(config, m, k, n_local,
                                     a_local.dtype.itemsize,

@@ -180,6 +180,27 @@ MOSAIC_VMEM_BUDGET = MOSAIC_VMEM_LIMIT - MOSAIC_VMEM_MARGIN
 VMEM_STAGE_BUDGET = 4 * 2 ** 20
 
 
+def sublane_rows(dtype) -> int:
+    """Rows of one native (sublane, 128-lane) tile for ``dtype``: 8 for
+    4-byte, 16 for 2-byte, 32 for 1-byte elements. Mosaic refuses a block
+    or DMA slice whose second-minor extent is neither a multiple of this
+    nor the whole array, so the distributed GEMMs pad their per-device row
+    count up to it (decode steps carry ``n_slots / world`` rows — 2 at
+    8 slots over TP=4)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def mosaic_row_pad(m: int, dtype, interpret) -> int:
+    """``m`` rounded up to the sublane tile when the kernel will be compiled
+    by Mosaic (``interpret`` resolves to False); ``m`` itself under the
+    interpreter, which has no tiling constraint — the CPU tests keep their
+    exact shapes."""
+    if resolve_interpret(interpret) is not False:
+        return m
+    sub = sublane_rows(dtype)
+    return -(-m // sub) * sub
+
+
 def row_tile(m: int, row_bytes: int, budget: int = VMEM_STAGE_BUDGET) -> int:
     """Row-tile size so a kernel's VMEM row buffers (``row_bytes`` combined
     bytes per row across all tile buffers) stay under ``budget``; 8-aligned

@@ -192,6 +192,16 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
     if M % world:
         raise ValueError(f"M {M} not divisible by world {world}")
     m = M // world
+    m_pad = common.mosaic_row_pad(m, a_local.dtype, interpret)
+    if m_pad != m:
+        # Mosaic only (see ag_gemm_device): pad every destination segment
+        # of A with zero rows; the scattered result drops them again.
+        a_pad = jnp.pad(a_local.reshape(world, m, k_local),
+                        ((0, 0), (0, m_pad - m), (0, 0)))
+        res = gemm_rs_device(
+            a_pad.reshape(world * m_pad, k_local), b_local, axis=axis,
+            config=config, interpret=interpret, probes=probes)
+        return (res[0][:m], res[1]) if probes else res[:m]
     out_dtype = jnp.promote_types(a_local.dtype, b_local.dtype)
     config = config.resolve(m, k_local, n, a_local.dtype.itemsize,
                             out_dtype.itemsize)

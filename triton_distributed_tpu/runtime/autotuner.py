@@ -26,6 +26,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import json
+import logging
 import os
 import statistics
 import time
@@ -35,11 +36,12 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 
-_DEFAULT_CACHE = os.path.join(
-    os.path.expanduser("~"), ".cache", "triton_distributed_tpu",
-    "autotune.json")
 
 _memory_cache: dict[str, Any] = {}
+
+_log = logging.getLogger(__name__)
+# (tuner name, config repr) pairs whose build failure was already logged.
+_logged_build_failures: set = set()
 
 # Per-tuner-name counts of configs statically rejected by the resource
 # analyzer (the ``pruner=`` hook) before any compile/timing. bench.py's
@@ -94,7 +96,12 @@ def _device_kind() -> str:
 
 
 def _cache_path() -> str:
-    return os.environ.get("TDT_AUTOTUNE_CACHE", _DEFAULT_CACHE)
+    """``TDT_AUTOTUNE_CACHE`` if set, else the in-checkout
+    ``.cache/autotune.json`` (winners change what gets compiled, so they
+    live beside the compile cache — ``runtime.platform.cache_dir``)."""
+    from triton_distributed_tpu.runtime.platform import cache_dir
+
+    return os.environ.get("TDT_AUTOTUNE_CACHE") or cache_dir("autotune.json")
 
 
 def _load_disk_cache() -> dict:
@@ -240,6 +247,26 @@ class ContextualAutotuner:
         return (f"{self.name}|{context_key}|{digest}|{self._METHODOLOGY}"
                 f"|{_device_kind()}|jax{jax.__version__}")
 
+    def _log_build_failure(self, i: int, exc: Exception) -> None:
+        key = (self.name, repr(self.configs[i]))
+        if key not in _logged_build_failures:
+            _logged_build_failures.add(key)
+            _log.warning("autotune %s: candidate %r failed to build and "
+                         "loses: %s: %s", self.name, self.configs[i],
+                         type(exc).__name__, exc)
+
+    def _raise_if_none_built(self, build_errors: dict, tried: list,
+                             context_key: str) -> None:
+        """Local decision, safe under SPMD: every process builds the same
+        candidates from the same shapes, so all of them raise together."""
+        if tried and len(build_errors) == len(tried):
+            first = build_errors[tried[0]]
+            raise RuntimeError(
+                f"autotune {self.name} [{context_key}]: all {len(tried)} "
+                f"candidate configs failed to build; first "
+                f"({self.configs[tried[0]]!r}): "
+                f"{type(first).__name__}: {first}") from first
+
     def peek(self, context_key: str):
         """The cached winner for this context, or None — NEVER times or
         writes; safe under an active jax trace. In MULTI-process runs only
@@ -334,32 +361,48 @@ class ContextualAutotuner:
             if pruned:
                 _note_pruned(self.name, len(pruned))
 
+        # A candidate that fails to BUILD (trace, lower, Mosaic/XLA compile)
+        # loses, and its exception text goes to the log once. When every
+        # candidate that was tried fails to build there is nothing to
+        # choose from: raise the first failure instead of handing the
+        # caller config 0, which would fail the same way later with the
+        # cause gone.
+        build_errors: dict[int, Exception] = {}
+        tried = [i for i in range(len(self.configs)) if i not in pruned]
+
+        def build(i):
+            try:
+                return make_thunk(self.configs[i])
+            except Exception as e:  # noqa: BLE001 — candidate loses
+                build_errors[i] = e
+                self._log_build_failure(i, e)
+                return None
+
         if self.multi_timer is not None:
-            thunks = []
-            for i, cfg in enumerate(self.configs):
-                if i in pruned:
-                    thunks.append(None)  # statically rejected: never built
-                    continue
-                try:
-                    thunks.append(make_thunk(cfg))
-                except Exception:
-                    thunks.append(None)  # infeasible config loses
+            thunks = [None if i in pruned else build(i)
+                      for i in range(len(self.configs))]
+            self._raise_if_none_built(build_errors, tried, context_key)
             timings = list(self.multi_timer(thunks))
         else:
             timings = []
-            for i, cfg in enumerate(self.configs):
-                if i in pruned:
-                    timings.append(float("inf"))  # statically rejected
+            for i in range(len(self.configs)):
+                thunk = None if i in pruned else build(i)
+                if thunk is None:
+                    timings.append(float("inf"))  # pruned or unbuildable
                     continue
                 try:
-                    thunk = make_thunk(cfg)
                     if self.timer is not None:
                         timings.append(self.timer(thunk))
                     else:
                         timings.append(perf_thunk(thunk, iters=self.iters,
                                                   calls=self.calls))
-                except Exception:
-                    timings.append(float("inf"))  # infeasible config loses
+                except Exception as e:  # noqa: BLE001 — candidate loses
+                    # perf_thunk's first call is where a lazily-jitted
+                    # thunk compiles, so this is a build failure too.
+                    build_errors[i] = e
+                    self._log_build_failure(i, e)
+                    timings.append(float("inf"))
+            self._raise_if_none_built(build_errors, tried, context_key)
         best, valid = _vote_across_processes(timings)
         if not valid:
             # Every candidate failed/jittered out on every process — a

@@ -1,76 +1,26 @@
-"""JAX version-compatibility shims.
+"""The JAX spellings the rest of the tree imports from one place.
 
-The framework targets the modern ``jax.shard_map`` API (promoted out of
-``jax.experimental`` with ``check_rep`` renamed to ``check_vma``); older
-jaxlibs still in some images only ship the experimental spelling. One
-wrapper here keeps every call site on the new API so nothing else in the
-tree needs a version branch.
+One installation (JAX 0.9.0): ``jax.shard_map``, ``jax.lax.axis_size`` and
+the dict form of a remote ``device_id`` all exist, so these are plain
+aliases. They keep their names because 39 files import them and the
+comm-safety analyzer (``analysis/events.py``) patches ``axis_size`` and
+``mesh_device_id`` here to trace kernels on the CPU.
 """
 
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 
-# ``pltpu.TPUCompilerParams`` became ``pltpu.CompilerParams`` (gaining
-# fields like ``has_side_effects`` along the way). Alias the new spelling
-# onto old installs, dropping kwargs the old dataclass doesn't know —
-# those only matter when Mosaic actually compiles for a TPU, and a TPU
-# image ships a jax new enough to take the real class.
-from jax.experimental.pallas import tpu as _pltpu
-
-if not hasattr(_pltpu, "CompilerParams"):
-    _fields = {f.name for f in dataclasses.fields(_pltpu.TPUCompilerParams)}
-
-    def _compiler_params(**kw):
-        return _pltpu.TPUCompilerParams(
-            **{k: v for k, v in kw.items() if k in _fields})
-
-    _pltpu.CompilerParams = _compiler_params
-
-# ``pltpu.TPUMemorySpace`` became ``pltpu.MemorySpace`` and grew a
-# distinct HBM member; old jax's ANY is the compiler-placed (HBM in
-# practice) space those call sites mean.
-if not hasattr(_pltpu, "MemorySpace"):
-
-    class _MemorySpace:
-        ANY = _pltpu.TPUMemorySpace.ANY
-        VMEM = _pltpu.TPUMemorySpace.VMEM
-        SMEM = _pltpu.TPUMemorySpace.SMEM
-        SEMAPHORE = _pltpu.TPUMemorySpace.SEMAPHORE
-        HBM = _pltpu.TPUMemorySpace.ANY
-
-    _pltpu.MemorySpace = _MemorySpace
+shard_map = jax.shard_map
 
 
 def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` on new jax; on old jax the classic
-    ``psum(1, axis)`` spelling — a Python scalar under a named axis folds
-    statically to the axis size, no collective is emitted."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a named mesh axis (patched by the analyzer)."""
+    return jax.lax.axis_size(axis_name)
 
 
 def mesh_device_id(axis: str, peer):
     """Remote-DMA / semaphore ``device_id`` for "rank ``peer`` along mesh
-    ``axis``". New jax takes the dict form (unnamed axes keep this device's
-    coordinates — required on multi-axis meshes); old jax's interpreter
-    chokes on dicts but handles a bare index on single-axis meshes, the
-    only meshes its discharge rules support anyway."""
-    if hasattr(jax, "shard_map"):  # same sentinel as the shims below
-        return {axis: peer}
-    return peer
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on new jax; the experimental fallback (with
-    ``check_vma`` mapped onto its ``check_rep`` predecessor) on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    ``axis``": the dict form, so unnamed axes keep this device's
+    coordinates (required on multi-axis meshes)."""
+    return {axis: peer}

@@ -72,9 +72,9 @@ _DEFAULT_HW = _HW_TABLE["tpu v5 lite"]
 
 def match_hardware(kind: str) -> Hardware | None:
     """Resolve a jax ``device_kind`` string to its speeds-and-feeds row, or
-    None when the kind is unknown (callers choose their own fallback:
-    ``detect_hardware`` falls back to v5e for crossovers, bench's
-    plausibility gate falls back LOOSE so it never rejects real samples)."""
+    None when the kind is unknown (callers decide: ``detect_hardware``
+    raises on a TPU backend, bench's plausibility gate falls back LOOSE so
+    it never rejects real samples)."""
     kind = kind.lower()
     for prefix, hw in sorted(_HW_TABLE.items(), key=lambda kv: -len(kv[0])):
         if kind.startswith(prefix):
@@ -87,14 +87,24 @@ def match_hardware(kind: str) -> Hardware | None:
 
 @functools.cache
 def detect_hardware() -> Hardware:
-    """The attached chip's figures (v5e fallback for unknown kinds — on the
-    CPU-simulation mesh the model still yields the same *relative*
-    crossovers, which is all dispatch needs)."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except RuntimeError:
+    """The attached chip's figures, from ``jax.devices()[0].device_kind``.
+
+    On a TPU backend this is detection: a ``device_kind`` the table does
+    not hold raises (naming it) instead of answering with another chip's
+    peaks, and a backend that failed to start re-raises. On any other
+    backend (the CPU interpreter tests, the virtual mesh) it returns the
+    v5e row — a MODELLING choice, not detection: dispatch only needs the
+    same relative crossovers the chip would give, and the tests pin them.
+    """
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
         return _DEFAULT_HW
-    return match_hardware(kind) or _DEFAULT_HW
+    hw = match_hardware(dev.device_kind)
+    if hw is None:
+        raise ValueError(
+            f"unknown TPU device_kind {dev.device_kind!r}: add its peaks to "
+            f"perf_model._HW_TABLE (known: {sorted(_HW_TABLE)})")
+    return hw
 
 
 def peak_bf16_tflops(kind: str | None = None, *, tolerance: float = 1.0,
@@ -106,17 +116,20 @@ def peak_bf16_tflops(kind: str | None = None, *, tolerance: float = 1.0,
     ``tolerance`` scales the peak (bench passes 1.02: measurement slack so
     a 199 TF/s sample on a 197-peak v5e is not rejected). ``default`` is
     returned UNSCALED for unknown kinds when given (bench passes 1000.0 —
-    loose beats wrongly rejecting every sample); otherwise unknown kinds
-    fall back to the v5e figure."""
+    loose beats wrongly rejecting every sample); without it an unknown
+    kind of an attached TPU raises, and anything that is not a TPU gets the
+    v5e figure (the same modelling choice as ``detect_hardware``)."""
+    attached_tpu = False
     if kind is None:
-        try:
-            kind = jax.devices()[0].device_kind
-        except RuntimeError:
-            kind = ""
+        dev = jax.devices()[0]
+        kind, attached_tpu = dev.device_kind, dev.platform == "tpu"
     hw = match_hardware(kind)
     if hw is None:
         if default is not None:
             return default
+        if attached_tpu:
+            raise ValueError(f"unknown TPU device_kind {kind!r}: add its "
+                             f"peaks to perf_model._HW_TABLE")
         hw = _DEFAULT_HW
     return hw.peak_bf16_flops / 1e12 * tolerance
 

@@ -82,6 +82,21 @@ _SNAPSHOT_WINDOWS = ((10.0, "10s"), (300.0, "5m"))
 _SNAPSHOT_SERIES = ("ttft_s", "tbt_s", "queue_wait_s")
 
 
+class StepBuildError(RuntimeError):
+    """A compiled step failed on its FIRST call — where it is traced,
+    lowered, compiled (Mosaic/XLA) and first given device memory. That is
+    a fault of the program or of the device's capacity, not of a replica:
+    ``Fleet``'s replica error boundary re-raises it instead of
+    quarantining the replica and failing its requests (docs/resilience.md).
+    The original exception is ``__cause__``."""
+
+
+def is_resource_error(exc: BaseException) -> bool:
+    """True for the runtime's out-of-memory error (``RESOURCE_EXHAUSTED``),
+    which is a capacity fault at any step, first or not."""
+    return "RESOURCE_EXHAUSTED" in str(exc)
+
+
 @dataclasses.dataclass
 class _Slot:
     """Host bookkeeping for one occupied batch slot."""
@@ -307,6 +322,10 @@ class BatchEngine:
                                               metrics=self.metrics)
                              if prefix_cache else None)
         self.trace_counts = {"decode": 0, "prefill": 0}
+        # Fault sites ("engine.decode"/"engine.prefill") whose jitted step
+        # has returned at least once; until then a failure of the call is a
+        # StepBuildError (see ``_call_step``).
+        self._steps_built: set[str] = set()
         self._slots: list[_Slot | None] = [None] * n_slots
         self._admit_seq = 0
         self._req_counter = 0
@@ -475,6 +494,7 @@ class BatchEngine:
         self._decode_step = other._decode_step
         self._mixed_step = other._mixed_step
         self.trace_counts = other.trace_counts
+        self._steps_built = other._steps_built
 
     def _next_key(self):
         if self.engine.temperature == 0.0:
@@ -885,6 +905,24 @@ class BatchEngine:
         buffers and the retry re-runs against intact state. (Real
         device-side failures are out of retry's scope for exactly that
         donation reason.)"""
+        if site not in self._steps_built:
+            # First call of this jitted step: tracing, lowering, the
+            # Mosaic/XLA compile and the first device allocation all
+            # happen inside it, and none of them is a replica fault.
+            # Injected faults fire in ``attempt`` BEFORE ``fn`` and are
+            # not wrapped.
+            inner = fn
+
+            def fn(corrupt):
+                try:
+                    out = inner(corrupt)
+                except Exception as e:
+                    raise StepBuildError(
+                        f"{site}: first call of the compiled step failed "
+                        f"({type(e).__name__}: {e})") from e
+                self._steps_built.add(site)
+                return out
+
         if _faults._PLAN is None:
             return fn(self._corrupt0)
 

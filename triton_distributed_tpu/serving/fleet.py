@@ -65,7 +65,11 @@ from triton_distributed_tpu.obs.slo import STATE_LEVEL
 from triton_distributed_tpu.resilience import checkpoint as _ckpt
 from triton_distributed_tpu.resilience import faults as _faults
 from triton_distributed_tpu.resilience import guards as _guards
-from triton_distributed_tpu.serving.batch_engine import BatchEngine
+from triton_distributed_tpu.serving.batch_engine import (
+    BatchEngine,
+    StepBuildError,
+    is_resource_error,
+)
 from triton_distributed_tpu.serving.metrics import Metrics
 from triton_distributed_tpu.serving.router import Router
 from triton_distributed_tpu.serving.scheduler import Request
@@ -910,6 +914,12 @@ class Fleet:
                     _faults.fire(f"replica.{rep.idx}.step")
                 stepped = rep.engine.step()
             except Exception as e:  # noqa: BLE001 — replica error boundary
+                # A step that cannot be built (trace/lower/compile/first
+                # allocation) or a device out of memory is not a replica
+                # fault: quarantining would hide it behind failed requests
+                # and a clean exit.
+                if isinstance(e, StepBuildError) or is_resource_error(e):
+                    raise
                 self._record_failure(rep, e)
                 continue
             if rep.consecutive_failures:

@@ -51,6 +51,7 @@ import numpy as np
 
 from triton_distributed_tpu.models.kv_cache import KVCache
 from triton_distributed_tpu.resilience import faults as _faults
+from triton_distributed_tpu.runtime.platform import on_tpu
 
 
 #: Wire dtypes the pool can quantize KV storage into. ``"fp8"`` is the
@@ -89,6 +90,18 @@ def resolve_kv_dtype(config, kv_dtype):
         f"unsupported kv_dtype {kv_dtype!r}: expected None, "
         f"{sorted(KV_WIRE_DTYPES)}, or the model dtype "
         f"{jnp.dtype(config.dtype).name!r}")
+
+
+@functools.lru_cache(maxsize=32)
+def _zeros_fn(shape, dtype, sharding):
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
+
+
+def _zeros(shape, dtype, sharding):
+    """A zero array allocated directly under ``sharding`` (None = default
+    device): every device fills only its own shard. The tiny program is
+    memoized per (shape, dtype, sharding) — K and V share one."""
+    return _zeros_fn(tuple(shape), jnp.dtype(dtype), sharding)()
 
 
 def blocks_needed(n_tokens: int, block_size: int) -> int:
@@ -142,23 +155,34 @@ class KVPool:
         self.max_seq_len = max_seq_len or config.max_length
         self.max_blocks_per_seq = math.ceil(self.max_seq_len / block_size)
         self.kv_dtype, self.kv_quant = resolve_kv_dtype(config, kv_dtype)
+        if self.kv_quant and on_tpu():
+            raise NotImplementedError(
+                f"kv_dtype={self.kv_dtype.name!r} does not compile for the "
+                f"chip: Mosaic refuses the fused kernel's per-block scale "
+                f"DMA (kernels/paged_attention.py, scale arena "
+                f"(n_blocks, block_size, n_kv_heads) f32) with 'Slice "
+                f"shape along dimension 2 must be aligned to tiling (128), "
+                f"but is {config.n_kv_heads}'. Quantized KV has only ever "
+                f"run under the Pallas interpreter; serve with the model "
+                f"dtype on a TPU (ROADMAP S5).")
         shape = (config.n_layers, n_blocks, block_size,
                  config.n_kv_heads, config.head_dim)
-        k = jnp.zeros(shape, self.kv_dtype)
-        v = jnp.zeros(shape, self.kv_dtype)
-        ks = vs = None
-        if self.kv_quant:
-            ks = jnp.zeros(shape[:-1], jnp.float32)
-            vs = jnp.zeros(shape[:-1], jnp.float32)
+        sh = ssh = None
         if mesh is not None:
             from triton_distributed_tpu.runtime.mesh import sharding_for
 
             sh = sharding_for(KVCache.spec(axis)[0], mesh)
-            k, v = jax.device_put(k, sh), jax.device_put(v, sh)
-            if self.kv_quant:
-                ssh = sharding_for(KVCache.scale_spec(axis), mesh)
-                ks = jax.device_put(ks, ssh)
-                vs = jax.device_put(vs, ssh)
+            ssh = sharding_for(KVCache.scale_spec(axis), mesh)
+        # Each arena is born in its sharded layout: ``jnp.zeros`` +
+        # ``device_put`` would build the WHOLE pool on the default device
+        # first — on four chips, all of it on chip 0 beside its weight
+        # shard (``Qwen3.init`` allocates the same way).
+        k = _zeros(shape, self.kv_dtype, sh)
+        v = _zeros(shape, self.kv_dtype, sh)
+        ks = vs = None
+        if self.kv_quant:
+            ks = _zeros(shape[:-1], jnp.float32, ssh)
+            vs = _zeros(shape[:-1], jnp.float32, ssh)
         self.state = PagedKVState(k=k, v=v, k_scale=ks, v_scale=vs)
         # LIFO free list, low block ids first out — recently freed blocks
         # are reused immediately (warm in whatever cache level they touched).

@@ -370,8 +370,6 @@ def aot_compile_flagship(name: str, *, topology: str = "v5e:2x4"):
 # Serialized-executable cache (engine cold-start; attached devices).
 # ---------------------------------------------------------------------------
 
-_DEFAULT_AOT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "triton_distributed_tpu", "aot")
 
 
 class AOTExecutableCache:
@@ -385,8 +383,12 @@ class AOTExecutableCache:
     ``aot_compile_flagship`` for detached-topology validation."""
 
     def __init__(self, cache_dir: str | None = None):
-        self.cache_dir = cache_dir or os.environ.get(
-            "TDT_AOT_CACHE", _DEFAULT_AOT_DIR)
+        from triton_distributed_tpu.runtime.platform import (
+            cache_dir as _default_dir,
+        )
+
+        self.cache_dir = (cache_dir or os.environ.get("TDT_AOT_CACHE")
+                          or _default_dir("aot"))
 
     def _key(self, name: str, args, mesh: Mesh | None,
              lowered_text: str) -> str:
@@ -449,14 +451,25 @@ class AOTExecutableCache:
 # ---------------------------------------------------------------------------
 
 
-def enable_xla_compilation_cache(path: str | None = None) -> None:
-    """Persist XLA compiles across processes (repeat AOT runs near-instant)."""
-    path = path or os.path.join(
-        os.path.expanduser("~"), ".cache", "triton_distributed_tpu",
-        "xla_cache")
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+def enable_xla_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    THE rule for every entry point (``chip_smoke.py``, ``bench.py``, this
+    CLI; ``scripts/launch.sh`` states it in shell): where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and this
+    sets NO directory in code; where it is not, the cache goes to the
+    fixed in-checkout ``.cache/jax`` (``runtime.platform.cache_dir``) —
+    never the home directory, a temporary name, a pid or a time, because
+    the path is part of the cache key."""
+    from triton_distributed_tpu.runtime.platform import cache_dir
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = cache_dir("jax")
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    return path
 
 
 def main(argv=None) -> int:
