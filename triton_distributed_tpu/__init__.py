@@ -29,7 +29,7 @@ The compute path is pure JAX/Pallas; native (C++) runtime IO lives in
 ctypes with a pure-Python fallback — runtime/io_native.py). The AOT path is
 ``tools.aot``:
 Mosaic-compilation of every flagship kernel against a detached TPU topology
-descriptor at production shapes (tests/test_mosaic_aot.py) plus a
+descriptor at production shapes (tests/test_chip_compile.py) plus a
 serialized-executable cache that cuts engine cold-start
 (``Engine(aot_cache=True)``).
 """

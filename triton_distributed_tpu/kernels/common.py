@@ -165,7 +165,7 @@ def dma_sems(shape: int | tuple):
 # Mosaic's scoped-VMEM stack limit per kernel (v5e/v5p default 16MB): the
 # budget every kernel's resident buffers + double-buffered pipeline blocks
 # must fit (verified against the real enforcer via AOT topology compiles,
-# tests/test_mosaic_aot.py). Block auto-selection targets the limit minus a
+# tests/test_chip_compile.py). Block auto-selection targets the limit minus a
 # margin: the enforcer counts alignment padding and bookkeeping beyond the
 # plain buffer arithmetic (a 15.4M working set was rejected at the 16M
 # limit), so plan for ~14M.
