@@ -27,6 +27,7 @@ performance results.
 from __future__ import annotations
 
 import argparse
+import faulthandler
 import json
 import sys
 import time
@@ -56,6 +57,7 @@ FOUR_CHIPS = dict(
 # their own scale (ratio near 1). float32 (the CPU test) gets 1e-4.
 TOL_BF16 = 0.1
 TOL_F32 = 1e-4
+RUN_LIMIT_S = 1150
 
 
 class SmokeFailure(AssertionError):
@@ -509,18 +511,24 @@ def main(argv=None) -> int:
                         help="4: run only the TP=4 Qwen3-8B dist-vs-xla "
                              "phase (needs a four-chip host)")
     args = parser.parse_args(argv)
+    # The contract is 1,200 s: past it, dump every thread's stack and exit
+    # non-zero rather than hang on a collective or a compile.
+    faulthandler.dump_traceback_later(RUN_LIMIT_S, exit=True,
+                                      file=sys.__stderr__)
+    try:
+        import jax
 
-    import jax
-
-    devices = jax.devices()
-    if devices[0].platform != "tpu" or len(devices) < args.chips:
-        print(f"chip_smoke: needs {args.chips} TPU device(s); JAX found "
-              f"{len(devices)} x {devices[0].platform!r}. No CPU or "
-              f"interpreter continuation.", file=sys.stderr)
-        return 2
-    if args.chips == 4:
-        return smoke(run_four_chips, devices[:4], FOUR_CHIPS)
-    return smoke(run_one_chip, devices[:1], ONE_CHIP)
+        devices = jax.devices()
+        if devices[0].platform != "tpu" or len(devices) < args.chips:
+            print(f"chip_smoke: needs {args.chips} TPU device(s); JAX found "
+                  f"{len(devices)} x {devices[0].platform!r}. No CPU or "
+                  f"interpreter continuation.", file=sys.stderr)
+            return 2
+        if args.chips == 4:
+            return smoke(run_four_chips, devices[:4], FOUR_CHIPS)
+        return smoke(run_one_chip, devices[:1], ONE_CHIP)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 if __name__ == "__main__":
