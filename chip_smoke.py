@@ -111,6 +111,16 @@ def make_prompts(rng, vocab: int, geo: dict) -> list[list[int]]:
     return [[int(t) for t in rng.integers(0, vocab, n)] for n in lens]
 
 
+def under_trace(fn):
+    """``fn()`` evaluated while a jax trace is active — the state in which
+    the served steps consult the tuner (it then never times)."""
+    import jax
+
+    box = []
+    jax.eval_shape(lambda: box.append(fn()) or 0)
+    return box[0]
+
+
 def peak_bytes(devices) -> list:
     out = []
     for d in devices:
@@ -335,10 +345,10 @@ def run_one_chip(devices, geo: dict) -> None:
     # where the tuner never times: it bakes a cached winner or the
     # heuristic default. So the decode shape (L=1, six kv-tile candidates)
     # is tuned here, eagerly, before the first trace — what the tuner's own
-    # trace-fallback warning asks of a caller. The chunk shape (L>1) stays
-    # on the heuristic: its 42 (kv-tile, q-tile) candidates include
-    # q_tile=1 x tile=1, 131k grid steps a call over ~1.8k timed calls
-    # (ROADMAP S5).
+    # trace-fallback warning asks of a caller. The chunk shape (L>1) is NOT:
+    # a cold eager tune of its 42 (kv-tile, q-tile) candidates took about
+    # twenty minutes on the chip and chose the heuristic default (PR 22,
+    # ROADMAP S5), so it is only asked, under a trace as the step asks it.
     g = cfg.n_heads // cfg.n_kv_heads
     max_blocks = -(-cfg.max_length // geo["block_size"])
     tile_args = (geo["block_size"], cfg.n_kv_heads, cfg.head_dim, max_blocks,
@@ -346,7 +356,8 @@ def run_one_chip(devices, geo: dict) -> None:
     t0 = time.perf_counter()
     decode_cfg = tuned_paged_tile(*tile_args, L=1, g=g)
     t_tune = time.perf_counter() - t0
-    mixed_cfg = tuned_paged_tile(*tile_args, L=geo["prefill_chunk"], g=g)
+    mixed_cfg = under_trace(lambda: tuned_paged_tile(
+        *tile_args, L=geo["prefill_chunk"], g=g))
     emit(phase="autotune", seconds=round(t_tune, 3),
          decode_tile_qtile=list(decode_cfg), decode_tuned_eagerly=True,
          mixed_tile_qtile=list(mixed_cfg), mixed_tuned_eagerly=False)
