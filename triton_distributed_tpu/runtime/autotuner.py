@@ -36,6 +36,7 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 
+from triton_distributed_tpu.runtime.platform import cache_dir
 
 _memory_cache: dict[str, Any] = {}
 
@@ -99,8 +100,6 @@ def _cache_path() -> str:
     """``TDT_AUTOTUNE_CACHE`` if set, else the in-checkout
     ``.cache/autotune.json`` (winners change what gets compiled, so they
     live beside the compile cache — ``runtime.platform.cache_dir``)."""
-    from triton_distributed_tpu.runtime.platform import cache_dir
-
     return os.environ.get("TDT_AUTOTUNE_CACHE") or cache_dir("autotune.json")
 
 

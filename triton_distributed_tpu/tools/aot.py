@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from triton_distributed_tpu.runtime import platform as _platform
 from triton_distributed_tpu.runtime.utils import dist_print
 
 
@@ -371,7 +372,6 @@ def aot_compile_flagship(name: str, *, topology: str = "v5e:2x4"):
 # ---------------------------------------------------------------------------
 
 
-
 class AOTExecutableCache:
     """Disk cache of serialized compiled executables keyed by
     (name, abstract args, mesh, device kind, jax version) — the reference's
@@ -383,12 +383,8 @@ class AOTExecutableCache:
     ``aot_compile_flagship`` for detached-topology validation."""
 
     def __init__(self, cache_dir: str | None = None):
-        from triton_distributed_tpu.runtime.platform import (
-            cache_dir as _default_dir,
-        )
-
         self.cache_dir = (cache_dir or os.environ.get("TDT_AOT_CACHE")
-                          or _default_dir("aot"))
+                          or _platform.cache_dir("aot"))
 
     def _key(self, name: str, args, mesh: Mesh | None,
              lowered_text: str) -> str:
@@ -461,11 +457,9 @@ def enable_xla_compilation_cache() -> str:
     fixed in-checkout ``.cache/jax`` (``runtime.platform.cache_dir``) —
     never the home directory, a temporary name, a pid or a time, because
     the path is part of the cache key."""
-    from triton_distributed_tpu.runtime.platform import cache_dir
-
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
-        path = cache_dir("jax")
+        path = _platform.cache_dir("jax")
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
