@@ -91,6 +91,11 @@ class _CacheEvents:
     def snapshot(self) -> dict:
         return {"cache_hits": self.hits, "cache_misses": self.misses}
 
+    def close(self) -> None:
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_listener(self._on_event)
+
 
 def device_info(devices) -> dict:
     import jax
@@ -200,7 +205,7 @@ def contiguous_logits(engine, prompts, next_tok):
             np.asarray(dec_logits, np.float32))
 
 
-def compare_logits(what: str, got, ref, tol: float) -> dict:
+def compare_logits(what: str, got, ref, tol: float) -> None:
     import numpy as np
 
     out = {"phase": "numeric", "compared": what, "tolerance": tol}
@@ -221,7 +226,6 @@ def compare_logits(what: str, got, ref, tol: float) -> dict:
         check(out[f"{name}_rel"] <= tol,
               f"{what}: {name} logits differ by {out[f'{name}_rel']:.4g} "
               f"of the reference scale (tolerance {tol})")
-    return out
 
 
 def logit_tolerance(config) -> float:
@@ -282,8 +286,9 @@ def drain_fleet(fleet, caches: _CacheEvents, max_steps: int = 50_000) -> dict:
             "first_call": first}
 
 
-def check_fleet(fleet, want: dict) -> None:
-    """The checks the replica error boundary cannot swallow."""
+def check_fleet(fleet, rids: list, n_new: int) -> None:
+    """The checks the replica error boundary cannot swallow: every request
+    in ``rids`` finished with exactly ``n_new`` tokens on a healthy fleet."""
     rep = fleet.replicas[0]
     eng = rep.engine
     fm = fleet.metrics.as_dict()
@@ -302,20 +307,19 @@ def check_fleet(fleet, want: dict) -> None:
           f"requests failed: "
           f"{ {k: getattr(r, 'error', None) for k, r in failed.items()} }")
     fin = fleet.finished
-    for rid, n_new in want.items():
+    for rid in rids:
         check(rid in fin, f"request {rid} did not finish")
         check(len(fin[rid].output) == n_new,
               f"request {rid} produced {len(fin[rid].output)} tokens, "
               f"asked for {n_new}")
     check(eng.trace_counts == {"decode": 1, "prefill": 1},
           f"trace_counts {eng.trace_counts} != {{1, 1}} (a step retraced)")
-    eng.pool.check_invariants()
-    fleet.check_invariants()
+    fleet.check_invariants()      # every replica pool's invariants too
     check(eng.metrics.counters.get("prefix_hits", 0.0) > 0,
           "the shared-prefix wave produced no prefix-cache hit")
 
 
-def run_one_chip(devices, geo: dict) -> None:
+def run_one_chip(devices, geo: dict, caches: _CacheEvents) -> None:
     import jax
     import numpy as np
 
@@ -329,7 +333,6 @@ def run_one_chip(devices, geo: dict) -> None:
     from triton_distributed_tpu.tools.aot import enable_xla_compilation_cache
 
     cache_path = enable_xla_compilation_cache()
-    caches = _CacheEvents()
     emit(phase="device", compile_cache_dir=cache_path, **device_info(devices))
 
     cfg = ModelConfig.from_name(geo["model"])
@@ -378,11 +381,7 @@ def run_one_chip(devices, geo: dict) -> None:
 
     rng = np.random.default_rng(geo["seed"])
     prompts = make_prompts(rng, cfg.vocab_size, geo)
-    want, rids = {}, []
-    for p in prompts:
-        rid = fleet.submit(p, geo["new_tokens"])
-        rids.append(rid)
-        want[rid] = geo["new_tokens"]
+    rids = [fleet.submit(p, geo["new_tokens"]) for p in prompts]
     wave1 = drain_fleet(fleet, caches)
     # Second wave: two requests sharing a prefix with a request that has
     # FINISHED (the radix cache inserts at completion).
@@ -392,13 +391,13 @@ def run_one_chip(devices, geo: dict) -> None:
     lo, hi = geo["prompt_range"]
     for _ in range(2):
         tail = rng.integers(0, cfg.vocab_size, int(rng.integers(lo, hi) // 4))
-        rid = fleet.submit(donor[:geo["prefix_len"]] + [int(t) for t in tail],
-                           geo["new_tokens"])
-        want[rid] = geo["new_tokens"]
+        rids.append(fleet.submit(
+            donor[:geo["prefix_len"]] + [int(t) for t in tail],
+            geo["new_tokens"]))
     wave2 = drain_fleet(fleet, caches)
     emit(phase="first_call", **wave1["first_call"], **caches.snapshot())
     tokens = sum(len(r.output) for r in fleet.finished.values())
-    emit(phase="serve", requests=len(want), steps=wave1["steps"]
+    emit(phase="serve", requests=len(rids), steps=wave1["steps"]
          + wave2["steps"], tokens_generated=tokens,
          wall_s=round(wave1["wall_s"] + wave2["wall_s"], 3),
          prefix_hits=be.metrics.counters.get("prefix_hits", 0.0),
@@ -407,7 +406,7 @@ def run_one_chip(devices, geo: dict) -> None:
          preemptions=be.metrics.counters.get("preemptions", 0.0),
          trace_counts=be.trace_counts,
          replica_states=[r.state for r in fleet.replicas])
-    check_fleet(fleet, want)
+    check_fleet(fleet, rids, geo["new_tokens"])
 
     # Numbers, not argmax: paged step against the contiguous-cache forward.
     ref_prompts = prompts[:2]
@@ -430,7 +429,7 @@ def run_one_chip(devices, geo: dict) -> None:
 # -- four chips: TP=4 dist against xla ---------------------------------------
 
 
-def run_four_chips(devices, geo: dict) -> None:
+def run_four_chips(devices, geo: dict, caches: _CacheEvents) -> None:
     import jax
     import numpy as np
 
@@ -441,7 +440,6 @@ def run_four_chips(devices, geo: dict) -> None:
     from triton_distributed_tpu.tools.aot import enable_xla_compilation_cache
 
     cache_path = enable_xla_compilation_cache()
-    caches = _CacheEvents()
     emit(phase="device", compile_cache_dir=cache_path, **device_info(devices))
     cfg = ModelConfig.from_name(geo["model"])
     mesh = make_mesh({"tp": len(devices)}, devices=devices, set_default=False)
@@ -506,11 +504,14 @@ def smoke(run, devices, geo: dict) -> int:
     """Run one phase. Prints the ``ok`` line and returns 0 only if every
     check of it held; any other exception propagates (non-zero exit, no
     ``ok`` line)."""
+    caches = _CacheEvents()
     try:
-        run(devices, geo)
+        run(devices, geo, caches)
     except SmokeFailure as e:
         print(f"chip_smoke FAILED: {e}", file=sys.stderr)
         return 1
+    finally:
+        caches.close()
     print(json.dumps({"ok": True, "device": device_info(devices)}),
           flush=True)
     return 0
