@@ -1,0 +1,76 @@
+"""The comparison that decides ``correct``.
+
+After the window has closed, a seeded sample of the requests it finished
+(the longest among them) is read once by the plain float32 reference: the
+prompt followed by the tokens the program served. At every served position
+the reference gives a row of logits; a sound greedy server's token is the
+reference's best or lies just below it. The number compared at a position
+is that gap in units of the row's own spread:
+
+    gap = (best reference logit - reference logit of the served token)
+          / standard deviation of the reference's row
+
+Two numbers are held to a limit each: the widest gap (``gap_max``) and the
+mean gap (``gap_mean``) over all served positions of the sample. Valid for
+greedy tokens only, which is what every mix sends.
+
+The control is the reference itself in the precision below the
+configuration's (float8 for bfloat16), put in the program's place: at each
+position, the gap of the token that the lower precision puts first.
+"""
+
+from __future__ import annotations
+
+# Limits by served dtype, for a cell whose file states none. They were read
+# on the chip at the one-chip configuration's sizes (PERF.md section 2 gives
+# the readings); a cell of another model states its own in
+# ``cells/<cell>.json``, set from readings at its own sizes.
+DEFAULT_LIMITS = {"bfloat16": {"gap_max": 0.2, "gap_mean": 0.004}}
+
+
+def gaps(read) -> "list[float]":
+    return ((read["best"] - read["picked"]) / read["std"]).tolist()
+
+
+def summarise(all_gaps) -> dict:
+    return {"gap_max": max(all_gaps), "gap_mean": sum(all_gaps) / len(all_gaps),
+            "top1_share": sum(1 for g in all_gaps if g <= 0) / len(all_gaps)}
+
+
+CONTROL_PRECISION = "fp8"
+
+
+def compare(sizes, seed: int, sample, device, *, limits=None,
+            control: bool = False) -> dict:
+    """``sample`` is a list of (prompt, served tokens). Returns the numbers
+    compared beside their limits, and ``correct``."""
+    from perfbench import reference, weights
+
+    limits = {**DEFAULT_LIMITS.get(sizes.dtype, {}), **(limits or {})}
+    if not limits:
+        raise ValueError(f"no limits for {sizes.dtype}: the cell's file "
+                         f"has to state them")
+    out = {"limits": limits, "requests": len(sample),
+           "tokens": sum(len(o) for _, o in sample), "correct": False}
+    if not sample:
+        out["why"] = "no finished request to compare"
+        return out
+    w = weights.Weights(sizes, seed, device)
+    seqs = [(list(prompt) + list(served), len(prompt))
+            for prompt, served in sample]
+    sound = [g for r in reference.forward_positions(sizes, w, seqs)
+             for g in gaps(r)]
+    ctrl = []
+    if control:
+        low = reference.forward_positions(sizes, w, seqs,
+                                          precision=CONTROL_PRECISION)
+        ref_low = reference.forward_positions(
+            sizes, w, seqs, gather=[r["best_token"] for r in low])
+        ctrl = [g for r in ref_low for g in gaps(r)]
+    out["compared"] = summarise(sound)
+    out["correct"] = all(out["compared"][k] <= limits[k] for k in limits)
+    if control:
+        out["control"] = summarise(ctrl)
+        out["control_correct"] = all(out["control"][k] <= limits[k]
+                                     for k in limits)
+    return out
