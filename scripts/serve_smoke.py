@@ -71,6 +71,19 @@ import time
 import numpy as np
 
 
+def _wall_clock_arm_attn() -> str:
+    """``paged_attn`` for the two arms whose SLO windows and detectors are
+    wall-clock seconds sized for steps of milliseconds (``--adaptive``,
+    ``--incidents``): the fused kernel where it is compiled, its
+    bit-identical gather oracle where it would be interpreted. Interpreted,
+    a step costs ~0.3 s, ``main_adaptive``'s 2 s fast window never holds
+    ``min_count`` TTFT samples and WARN cannot fire. So on a CPU these two
+    arms do not cover the fused kernel; every other arm does."""
+    from triton_distributed_tpu.runtime.platform import on_tpu
+
+    return "fused" if on_tpu() else "gather"
+
+
 def main_fleet(duration_s: float = 30.0, *, rate_hz: float = 4.0,
                n_replicas: int = 3, n_slots: int = 4,
                n_blocks: int | None = 12, seed: int = 0,
@@ -347,7 +360,7 @@ def main_adaptive(*, seed: int = 0, warmup: int = 24, burst: int = 48,
     config = ModelConfig.from_name("tiny", max_length=128)
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     be = BatchEngine(engine, n_slots=4, n_blocks=96, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, paged_attn=_wall_clock_arm_attn())
     rng = np.random.default_rng(seed)
     start = time.monotonic()
 
@@ -605,7 +618,7 @@ def main_incidents(*, seed: int = 0, warmup: int = 32,
     config = ModelConfig.from_name("tiny", max_length=128)
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     be = BatchEngine(engine, n_slots=4, n_blocks=96, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, paged_attn=_wall_clock_arm_attn())
     if be.incidents is None:
         raise RuntimeError("incident engine not attached — it must be "
                            "always-on by default")
@@ -826,7 +839,7 @@ def main_kvq(*, seed: int = 0, kv_dtype: str = "int8", gen: int = 64,
     """The ``--kvq`` arm: the quantized KV cache's serving contract.
 
     One quantized BatchEngine (``kv_dtype`` int8 by default) on a pool
-    tight enough that four long generations preempt each other, serving
+    tight enough that two long generations preempt each other, serving
     a shared-prefix workload twice:
 
       * COLD pass: fresh cache — prefills write quantized blocks, the
@@ -856,15 +869,19 @@ def main_kvq(*, seed: int = 0, kv_dtype: str = "int8", gen: int = 64,
     start = time.monotonic()
 
     rng = np.random.default_rng(seed)
-    n_req = 6
+    n_req = 4
     prefix = rng.integers(0, config.vocab_size, size=24).tolist()
     prompts = [prefix + rng.integers(0, config.vocab_size,
                                      size=4).tolist()
                for _ in range(n_req)]
-    # Peak residency per request is ceil((28 + gen + 1) / 4) ~ 24 blocks;
-    # 60 blocks cannot hold four of those, so the long decode phase
-    # preempts and re-admits — the churn the bit-exactness claim is about.
-    be = BatchEngine(engine, n_slots=4, n_blocks=60, block_size=4,
+    # Peak residency per request is ceil((28 + gen + 1) / 8) = 12 blocks;
+    # 16 blocks cannot hold two of those, so the long decode phase
+    # preempts and re-admits — the churn the bit-exactness claim is about —
+    # while the other two requests wait in the queue for a slot.
+    # Two slots and blocks of 8: where the fused kernel is interpreted
+    # (any CPU run) a step costs ~0.09 s a slot, and two waves of gen
+    # steps, twice, are some 330 steps with the recompute.
+    be = BatchEngine(engine, n_slots=2, n_blocks=16, block_size=8,
                      prefill_chunk=8, kv_dtype=kv_dtype)
 
     def one_pass(tag):

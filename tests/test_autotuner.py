@@ -2,6 +2,7 @@
 (docs/autotuner.md): thunk-level tuning, cross-process vote, persistent
 cache, decorator form."""
 
+import functools
 import json
 
 import jax.numpy as jnp
@@ -243,13 +244,23 @@ def test_cache_key_separates_hardware_kinds_and_jax_version(monkeypatch):
     assert tuner.peek("ctx") is None
 
 
-def test_tuned_matmul_blocks_small_cpu():
+def test_tuned_matmul_blocks_small_cpu(monkeypatch):
     """End-to-end on tiny shapes (CPU): returns a feasible blocking and the
-    ag_gemm path computes correctly with it."""
+    ag_gemm path computes correctly with it.
+
+    The stock tune is ten candidates (all one blocking once capped at 256)
+    by fourteen rounds of 128 interpreted matmuls: 259 s for nothing this
+    test asserts. Two distinct candidates and three rounds run the same
+    path; the timer's ranking has its own tests above."""
     from triton_distributed_tpu.kernels.allgather_gemm import (
         ag_gemm_single_chip_autotuned,
     )
 
+    monkeypatch.setattr(autotuner, "MATMUL_BLOCK_CANDIDATES",
+                        ((256, 256, 256), (128, 256, 256)))
+    monkeypatch.setattr(
+        autotuner, "interleaved_slope_timer",
+        functools.partial(autotuner.interleaved_slope_timer, rounds=3))
     m = k = n = 256
     bm, bn, bk = autotuner.tuned_matmul_blocks(m, k, n, "float32")
     assert m % bm == 0 and n % bn == 0 and k % bk == 0

@@ -333,7 +333,12 @@ def _kill_sweep(setup, tmp_path, stride):
     specs = _specs(config, 28, seed=3, lo=4, hi=9, glo=8, ghi=13)
     # The preemption-golden shape: slots can outgrow the pool, so decode
     # growth forces evictions — churn the sweep must survive.
-    kw = _build_kwargs(n_slots=3, n_blocks=8, speculative=True)
+    # The gather attention path (the fused kernel's bit-identical oracle,
+    # tests/test_paged_attention.py): what is restored is the pool and the
+    # schedule, and the interpreted fused kernel makes each of the sweep's
+    # 64+-step runs ~25 s where this one takes a second.
+    kw = _build_kwargs(n_slots=3, n_blocks=8, speculative=True,
+                       paged_attn="gather")
 
     golden = Fleet.build(engine, **kw)
     _submit_all(golden, specs)

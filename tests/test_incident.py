@@ -416,11 +416,12 @@ def test_stats_dump_and_perfdb_shapes():
     eng = IncidentEngine(replica=3)
     st = eng.stats()
     assert set(st) == {"open", "total", "closed", "evicted", "steps",
-                       "severity_level", "detect_latency_steps", "ring"}
+                       "severity_level", "detect_latency_steps", "ring",
+                       "annotations"}
     d = eng.dump()
     assert d["replica"] == 3
     assert set(d) == {"replica", "steps", "opened", "closed", "evicted",
-                      "incidents"}
+                      "incidents", "annotations"}
     from triton_distributed_tpu.obs.perfdb import metric_direction
     s = eng.perfdb_sample()
     assert set(s) == {"incidents_open", "incidents_total",

@@ -93,7 +93,8 @@ def test_recorder_exact_attribution_with_virtual_clock():
     s = j.summary
     assert s["attribution_s"] == {"route": 1.0, "queue": 2.0,
                                   "prefill": 3.0, "decode": 2.0,
-                                  "preempted": 1.0, "requeue": 0.0}
+                                  "preempted": 1.0, "requeue": 0.0,
+                                  "restore": 0.0}
     assert s["total_s"] == 9.0
     assert _frac_sum(s) == pytest.approx(1.0, abs=1e-9)
     assert s["dominant"] == "prefill"

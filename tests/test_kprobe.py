@@ -405,7 +405,7 @@ def test_collective_bit_identity(mesh8, rng, kind):
             res = out[0] if probes else out
             return res[None]
         return shard_map(dev, mesh=mesh8, in_specs=P("tp"),
-                         out_specs=P("tp"))(x)
+                         out_specs=P("tp"), check_vma=False)(x)
 
     assert np.array_equal(np.asarray(run(False)), np.asarray(run(True)))
 
@@ -427,7 +427,7 @@ def test_gemm_rs_bit_identity(mesh8, rng):
                                  probes=probes)
             return out[0] if probes else out
         return shard_map(dev, mesh=mesh8, in_specs=(P(None, "tp"), P("tp")),
-                         out_specs=P("tp"))(a, b)
+                         out_specs=P("tp"), check_vma=False)(a, b)
 
     assert np.array_equal(np.asarray(run(False)), np.asarray(run(True)))
 
@@ -449,7 +449,7 @@ def test_ag_gemm_bit_identity(mesh8, rng):
                                  probes=probes)
             return out[0] if probes else out
         return shard_map(dev, mesh=mesh8, in_specs=(P("tp"), P(None, "tp")),
-                         out_specs=P(None, "tp"))(a, b)
+                         out_specs=P(None, "tp"), check_vma=False)(a, b)
 
     assert np.array_equal(np.asarray(run(False)), np.asarray(run(True)))
 
@@ -468,11 +468,12 @@ def test_ep_a2a_bit_identity(mesh8, rng):
 
     def run(probes):
         def dev(t, c):
-            res = fast_all_to_all(t, c[0], ctx=ctx, probes=probes)
+            res = fast_all_to_all(t[0], c[0], ctx=ctx, probes=probes)
             out, rcounts = res[0], res[1]
             return out[None], rcounts[None]
         return shard_map(dev, mesh=mesh8, in_specs=(P("tp"), P("tp")),
-                         out_specs=(P("tp"), P("tp")))(toks, counts)
+                         out_specs=(P("tp"), P("tp")),
+                         check_vma=False)(toks, counts)
 
     out_off, cnt_off = run(False)
     out_on, cnt_on = run(True)
@@ -498,6 +499,6 @@ def test_moe_ag_group_gemm_bit_identity(mesh8, rng):
             return res[0][None]
         return shard_map(dev, mesh=mesh8,
                          in_specs=(P("tp"), P("tp"), P(None, None, "tp")),
-                         out_specs=P("tp"))(x, ids, w)
+                         out_specs=P("tp"), check_vma=False)(x, ids, w)
 
     assert np.array_equal(np.asarray(run(False)), np.asarray(run(True)))

@@ -430,6 +430,17 @@ def test_ledger_selfcheck_covers_all_reduce_and_all_to_all(mesh8):
     assert sc["consistent"]
 
 
+def test_ledger_selfcheck_executes_every_family(mesh8):
+    """On the 8-device CPU mesh all four collectives RUN under the
+    interpreter: selfcheck's arrays stay under the interpreter's buffer
+    ceiling (tests/conftest.py), so none falls back to the analytical
+    replay and none deadlocks."""
+    sc = comm_ledger.selfcheck(mesh=mesh8, axis="tp")
+    for fam in ("ag", "rs", "ar", "a2a"):
+        assert sc[f"{fam}_mode"] == "executed", fam
+    assert sc["consistent"]
+
+
 def test_instrumented_all_gather_records_when_enabled(mesh8):
     """End-to-end through the real kernel wrapper: enabling the ledger and
     calling ``all_gather`` must produce a ledger entry whose bytes match

@@ -1,7 +1,15 @@
 """End-to-end Qwen3 + Engine tests — analog of the reference's
 test_e2e_inference.py: token generation through the distributed kernel path
 must match the XLA-collective golden, across prefill/decode mode mixes.
-Tiny config per the conftest interpreter ceiling."""
+Tiny config per the conftest interpreter ceiling.
+
+One dist-mode forward of `tiny` is about 46,000 interpreter callbacks on
+eight virtual devices (66 s on an idle 8-core host) and about 20 s on four,
+whatever the batch; an `ar` or `xla` forward on eight is 5 s or less. So
+the `dist` comparisons run at TP=4 and everything else at TP=8, and every
+engine and every served result is built once and shared."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -10,45 +18,66 @@ import pytest
 
 from triton_distributed_tpu.models import Engine, KVCache, ModelConfig, Qwen3
 from triton_distributed_tpu.runtime import assert_allclose
+from triton_distributed_tpu.runtime.mesh import make_mesh
 
+# GEN=3 is one prefill and two decode steps: enough to show that decode
+# continues prefill, that the second decode reads what the first wrote, and
+# that the scanned loop's body runs a second time on its own carry.
 B, L0, GEN = 8, 4, 3
 
 
+class _Shared:
+    """Params, prompt, one Engine per (mode, prefill_mode) and what each
+    has served on one mesh, made on first use and kept for the module: no
+    test pays for a forward that another has already run."""
+
+    def __init__(self, mesh):
+        self.mesh = mesh
+        self.config = ModelConfig.from_name("tiny")
+        self.params = Qwen3(self.config, block_n=8).init(
+            jax.random.PRNGKey(0), mesh)
+        self.ids = jax.random.randint(jax.random.PRNGKey(1), (B, L0), 0,
+                                      self.config.vocab_size, jnp.int32)
+
+    # No default for prefill_mode: functools.cache keys on the arguments as
+    # they are passed, and ("dist",) is not ("dist", None).
+    @functools.cache
+    def engine(self, mode, prefill_mode):
+        return Engine(self.config, mesh=self.mesh, mode=mode,
+                      prefill_mode=prefill_mode, params=self.params,
+                      block_n=8)
+
+    @functools.cache
+    def prefill_logits(self, mode):
+        e = self.engine(mode, None)
+        return e.prefill(self.ids, e.new_cache(B))[0]
+
+    @functools.cache
+    def served(self, mode, prefill_mode):
+        return np.asarray(
+            self.engine(mode, prefill_mode).serve(self.ids, GEN))
+
+
 @pytest.fixture(scope="module")
-def setup(request):
-    # module-scoped: build params once for all mode combinations
-    mesh8 = request.getfixturevalue("mesh8")
-    config = ModelConfig.from_name("tiny")
-    model = Qwen3(config, block_n=8)
-    params = model.init(jax.random.PRNGKey(0), mesh8)
-    ids = jax.random.randint(jax.random.PRNGKey(1), (B, L0), 0,
-                             config.vocab_size, jnp.int32)
-    return mesh8, config, params, ids
+def tp4():
+    return _Shared(make_mesh({"tp": 4}, devices=jax.devices()[:4],
+                             set_default=False))
 
 
-def _engine(setup, mode, prefill_mode=None):
-    mesh, config, params, _ = setup
-    return Engine(config, mesh=mesh, mode=mode, prefill_mode=prefill_mode,
-                  params=params, block_n=8)
+@pytest.fixture(scope="module")
+def tp8(mesh8):
+    return _Shared(mesh8)
 
 
-def test_prefill_logits_dist_matches_xla(setup):
-    _, config, _, ids = setup
-    ex = _engine(setup, "xla")
-    ed = _engine(setup, "dist")
-    lx, _ = ex.prefill(ids, ex.new_cache(B))
-    ld, _ = ed.prefill(ids, ed.new_cache(B))
-    assert lx.shape == (B, config.vocab_size)
-    assert_allclose(ld, lx, atol=2e-3, rtol=2e-3)
+def test_prefill_logits_dist_matches_xla(tp4):
+    lx = tp4.prefill_logits("xla")
+    assert lx.shape == (B, tp4.config.vocab_size)
+    assert_allclose(tp4.prefill_logits("dist"), lx, atol=2e-3, rtol=2e-3)
 
 
-def test_prefill_logits_ar_matches_xla(setup):
-    ex = _engine(setup, "xla")
-    ea = _engine(setup, "ar")
-    _, _, _, ids = setup
-    lx, _ = ex.prefill(ids, ex.new_cache(B))
-    la, _ = ea.prefill(ids, ea.new_cache(B))
-    assert_allclose(la, lx, atol=2e-3, rtol=2e-3)
+def test_prefill_logits_ar_matches_xla(tp8):
+    assert_allclose(tp8.prefill_logits("ar"), tp8.prefill_logits("xla"),
+                    atol=2e-3, rtol=2e-3)
 
 
 @pytest.mark.parametrize("mode,prefill_mode", [
@@ -56,40 +85,39 @@ def test_prefill_logits_ar_matches_xla(setup):
     ("ar", None),            # AR everywhere
     ("dist", "xla"),         # reference engine style: golden prefill,
 ])                           # distributed decode (engine.py:121)
-def test_generation_matches_xla_golden(setup, mode, prefill_mode):
-    _, _, _, ids = setup
-    golden = np.asarray(_engine(setup, "xla").serve(ids, GEN))
-    got = np.asarray(_engine(setup, mode, prefill_mode).serve(ids, GEN))
+def test_generation_matches_xla_golden(request, mode, prefill_mode):
+    shared = request.getfixturevalue("tp4" if mode == "dist" else "tp8")
+    golden = shared.served("xla", None)
     assert golden.shape == (B, GEN)
-    np.testing.assert_array_equal(got, golden)
+    np.testing.assert_array_equal(shared.served(mode, prefill_mode), golden)
 
 
-def test_serve_scanned_matches_serve(setup):
+def test_serve_scanned_matches_serve(tp4, tp8):
     """The one-executable scanned decode loop (prefill + lax.scan) must
     generate token-for-token what the per-step loop generates, on both the
-    xla golden and the distributed kernel path."""
-    _, _, _, ids = setup
-    for mode in ("xla", "dist"):
-        e = _engine(setup, mode)
+    xla golden and the distributed kernel path. GEN=3 runs the scan body
+    twice: the second iteration reads the KV carry and the collectives'
+    semaphore state that the first one left."""
+    for mode, shared in (("xla", tp8), ("dist", tp4)):
         np.testing.assert_array_equal(
-            np.asarray(e.serve_scanned(ids, GEN)),
-            np.asarray(e.serve(ids, GEN)), err_msg=mode)
+            np.asarray(
+                shared.engine(mode, None).serve_scanned(shared.ids, GEN)),
+            shared.served(mode, None), err_msg=mode)
 
 
-def test_kv_cache_offset_advances(setup):
-    _, _, _, ids = setup
-    e = _engine(setup, "xla")
+def test_kv_cache_offset_advances(tp8):
+    e = tp8.engine("xla", None)
     kv = e.new_cache(B)
     assert int(kv.offset) == 0
-    _, kv = e.prefill(ids, kv)
+    _, kv = e.prefill(tp8.ids, kv)
     assert int(kv.offset) == L0
     _, kv = e.decode_step(jnp.zeros((B,), jnp.int32), kv)
     assert int(kv.offset) == L0 + 1
 
 
-def test_cache_sharded_over_kv_heads(setup):
-    mesh, config, _, _ = setup
-    kv = KVCache.create(config, B, mesh=mesh)
+def test_cache_sharded_over_kv_heads(tp8):
+    config = tp8.config
+    kv = KVCache.create(config, B, mesh=tp8.mesh)
     # kv-head dim sharded tp-ways
     assert kv.k.sharding.shard_shape(kv.k.shape)[3] == config.n_kv_heads // 8
 

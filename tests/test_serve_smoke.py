@@ -44,7 +44,11 @@ def test_serve_smoke_short():
     assert sc["ag_bytes"] == sc["ag_expected"] > 0
     assert sc["rs_bytes"] == sc["rs_expected"] > 0
     assert sc["entries"]          # the checked series are present
-    for entry in sc["entries"].values():
+    for key, entry in sc["entries"].items():
+        # Executed (wall-timed) series come joined with one aggregate,
+        # which is not a series.
+        if key == "roofline_summary":
+            continue
         assert entry["bytes_total"] > 0
         assert entry["calls"] + entry["traced_calls"] >= 1
 
@@ -171,7 +175,7 @@ def test_serve_smoke_spec(tmp_path):
     retraces on either engine (main_spec raises on any violation); the
     stats feed carries the spec block serve_top renders as its pane."""
     feed = tmp_path / "spec_stats.jsonl"
-    m = _load().main_spec(seed=0, n_requests=8, gen=24,
+    m = _load().main_spec(seed=0, n_requests=8, gen=16,
                           stats_jsonl=str(feed))
     assert m["requests_completed"] == m["requests_submitted"] > 0
     assert m["divergent_requests"] == 0

@@ -25,10 +25,12 @@ def test_tutorials_exist():
 
 @pytest.mark.parametrize(
     "script", _TUTORIALS, ids=[os.path.basename(t) for t in _TUTORIALS])
-def test_tutorial_runs(script):
+def test_tutorial_runs(script, test_limit_s):
+    # Under the limit of tests/conftest.py, so a tutorial that hangs is
+    # reaped by its test and not orphaned when the limit ends the worker.
     r = subprocess.run(
         [sys.executable, script], capture_output=True, text=True,
-        timeout=600, cwd=_REPO)
+        timeout=test_limit_s - 30, cwd=_REPO)
     assert r.returncode == 0, (
         f"{os.path.basename(script)} failed:\n{r.stdout[-2000:]}\n"
         f"{r.stderr[-2000:]}")

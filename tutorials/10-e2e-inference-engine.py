@@ -35,11 +35,15 @@ import numpy as np  # noqa: E402
 from triton_distributed_tpu.models import Engine, ModelConfig  # noqa: E402
 from triton_distributed_tpu.runtime.mesh import make_mesh  # noqa: E402
 
-B, L0, GEN = 8, 4, 3
+# Interpreter-sized: one dist-mode forward is tens of thousands of
+# interpreter callbacks whatever the batch (about 20 s at TP=4, 65 s at
+# TP=8), so the tutorial runs TP=4 and generates two tokens per mode
+# (tests/test_qwen_e2e.py scans for three, where the loop's body runs twice).
+WORLD, B, L0, GEN = 4, 8, 4, 2
 
 
 def main():
-    mesh = make_mesh({"tp": 8})
+    mesh = make_mesh({"tp": WORLD}, devices=jax.devices()[:WORLD])
     config = ModelConfig.from_name("tiny")   # interpreter-sized; real runs
     # use e.g. ModelConfig.from_name("Qwen/Qwen3-32B") on a v5p slice.
     ids = jax.random.randint(jax.random.PRNGKey(1), (B, L0), 0,
@@ -50,18 +54,20 @@ def main():
 
     params = Qwen3(config, block_n=8).init(jax.random.PRNGKey(0), mesh)
 
-    def engine(mode):
-        return Engine(config, mesh=mesh, mode=mode, params=params, block_n=8)
+    # One Engine per mode: an Engine keeps its compiled steps, so reuse it.
+    engines = {mode: Engine(config, mesh=mesh, mode=mode, params=params,
+                            block_n=8)
+               for mode in ("xla", "dist", "ar")}
 
-    golden = np.asarray(engine("xla").serve(ids, GEN))
+    golden = np.asarray(engines["xla"].serve(ids, GEN))
     print(f"  xla golden tokens: {golden[0].tolist()} ...")
 
     for mode in ("dist", "ar"):
-        got = np.asarray(engine(mode).serve(ids, GEN))
+        got = np.asarray(engines[mode].serve(ids, GEN))
         np.testing.assert_array_equal(got, golden)
         print(f"  mode={mode:4s} tokens match the xla golden exactly")
 
-    scanned = np.asarray(engine("dist").serve_scanned(ids, GEN))
+    scanned = np.asarray(engines["dist"].serve_scanned(ids, GEN))
     np.testing.assert_array_equal(scanned, golden)
     print("  serve_scanned (whole decode loop, ONE executable) matches too")
     print("tutorial 10 ok: e2e engine, three modes, scanned decode loop")
