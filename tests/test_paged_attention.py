@@ -221,6 +221,68 @@ def test_fused_prefill_causal_boundary_straddle(rng):
                                    err_msg=f"tile_blocks={tile_blocks}")
 
 
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("shape", ["decode", "chunk"])
+def test_stacked_arena_layer_equals_per_layer_call(rng, shape, kv_dtype):
+    """The model's layer scan carries the STACKED arena and hands the
+    kernel a layer index: ``paged_attention(..., layer=li)`` over
+    (n_layers, n_blocks, bs, Hkv, dh) must equal the per-layer call on
+    ``pool[li]`` bit for bit, for a traced index as for a static one —
+    decode shape (L=1) and chunk shape (L>1, ragged q_lens), bf16 and
+    quantized int8 pools (scale arenas stacked the same way)."""
+    n_layers, B, bs, Hkv, g, dh, max_blocks = 3, 3, 8, 2, 2, 16, 4
+    n_blocks = B * max_blocks + 2
+    L = 1 if shape == "decode" else 6
+    raw = [rng.normal(size=(n_layers, n_blocks, bs, Hkv, dh)) for _ in "kv"]
+    quant = kv_dtype == "int8"
+    if quant:
+        (kp, ks), (vp, vs) = (nn.quantize_kv_rows(jnp.asarray(x), jnp.int8)
+                              for x in raw)
+    else:
+        kp, vp = (jnp.asarray(x, jnp.bfloat16) for x in raw)
+    q_dtype = jnp.float32 if quant else jnp.bfloat16
+
+    def scales(li=None):
+        if not quant:
+            return {}
+        return dict(k_scale=ks if li is None else ks[li],
+                    v_scale=vs if li is None else vs[li])
+
+    q = jnp.asarray(rng.normal(size=(B, L, Hkv * g, dh)), q_dtype)
+    tables = jnp.asarray(
+        rng.permutation(n_blocks)[:B * max_blocks].reshape(B, max_blocks),
+        jnp.int32)
+    q_lens = jnp.asarray([L, max(1, L - 2), max(1, L // 2)], jnp.int32)
+    kv_lens = jnp.asarray([max_blocks * bs, 13, 9], jnp.int32) + q_lens - L
+    kw = dict(q_lens=q_lens, q_tile=min(L, 4), tile_blocks=2,
+              interpret=True)
+
+    @jax.jit
+    def traced(li):
+        return paged_attention(q, kp, vp, tables, kv_lens, layer=li,
+                               **scales(), **kw)
+
+    outs = []
+    for li in range(n_layers):
+        per_layer = paged_attention(q, kp[li], vp[li], tables, kv_lens,
+                                    **scales(li), **kw)
+        stacked = paged_attention(q, kp, vp, tables, kv_lens, layer=li,
+                                  **scales(), **kw)
+        np.testing.assert_array_equal(np.asarray(stacked, np.float32),
+                                      np.asarray(per_layer, np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(traced(jnp.int32(li)), np.float32),
+            np.asarray(per_layer, np.float32))
+        outs.append(np.asarray(per_layer, np.float32))
+    # distinct data per layer: the index is live, not ignored
+    assert not np.array_equal(outs[0], outs[1])
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, kp, vp, tables, kv_lens, **scales(), **kw)
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, kp[0], vp[0], tables, kv_lens, layer=0,
+                        **scales(0), **kw)
+
+
 def test_fused_rejects_non_int32_tables(rng):
     q, kp, vp, tables, kv_lens = _pool_case(rng, 2, 8, 2, 1, 16, 2)
     with pytest.raises(TypeError, match="int32"):

@@ -13,6 +13,7 @@ The load-bearing guarantees (docs/serving.md):
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -314,6 +315,117 @@ def test_priority_preempts_low_priority(setup):
     # the high-priority request finished before at least one evictee
     assert finished[hi].finish_t < max(finished[r].finish_t for r in lo)
     assert finished[hi].n_preemptions == 0
+
+
+# -- 5. the pool is updated where it lies -----------------------------------
+
+_BS, _NB = 4, 40      # block size and pool blocks of the hand-made steps
+
+
+def _paged_step(engine, kind, paged_attn, quant=False):
+    """One hand-made paged step of ``forward_device`` (through the step's
+    own shard_map): 4 slots with slot 2 DEAD but holding stale table rows,
+    shuffled tables, every arena filled with random data. Returns
+    ``(sm, args, written)``: ``written`` is the set of (block, line) the
+    step's tables address for live tokens — the same in every layer."""
+    c = engine.config
+    rng = np.random.default_rng(7)
+    B, L = 4, (1 if kind == "decode" else 4)
+    max_blocks = c.max_length // _BS
+    shape = (c.n_layers, _NB, _BS, c.n_kv_heads, c.head_dim)
+    tables = rng.permutation(_NB)[:B * max_blocks].reshape(B, max_blocks)
+    offsets = np.asarray([5, 0, 9, 3], np.int32)
+    mask = np.asarray([True, True, False, True])
+    seq_lens = np.asarray([4, 2, 3, 0] if L > 1 else [1] * B, np.int32)
+    written = {(int(tables[b, (offsets[b] + l) // _BS]),
+                int((offsets[b] + l) % _BS))
+               for b in range(B) if mask[b] for l in range(seq_lens[b])}
+    if quant:
+        arenas = [jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+                  for _ in "kv"]
+        arenas += [jnp.asarray(rng.uniform(0.01, 0.02, size=shape[:-1]),
+                               jnp.float32) for _ in "kv"]
+    else:
+        arenas = [jnp.asarray(rng.normal(size=shape), c.dtype) for _ in "kv"]
+    ids = jnp.asarray(rng.integers(0, c.vocab_size, size=(B, L)), jnp.int32)
+    args = [engine.params, ids, *arenas, jnp.asarray(offsets),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(mask)]
+    if kind == "prefill":
+        args.append(jnp.asarray(seq_lens))
+    sm = engine._make_sm("xla", paged=kind, paged_attn=paged_attn,
+                         kv_quant=quant)
+    return sm, args, written
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_paged_step_appends_in_place_fused_equals_gather(setup, kind):
+    """One decode step and one mixed step on a pool full of data: the
+    append touches exactly the (layer, block, line) rows the tables
+    address for live tokens — a dead slot's stale table rows and positions
+    past ``seq_lens`` write NOTHING, every other byte of every layer is
+    the input's — on the fused path (the kernel DMAs ``[layer, block]`` out
+    of the carried arena) and on the gather oracle (``pool[layer]``)
+    alike. The two agree bit for bit on the first layer's appended rows
+    and, past it, to the float32 rounding of their two softmax orders."""
+    _, _, engine = setup
+    outs = {}
+    for paged_attn in ("fused", "gather"):
+        sm, args, written = _paged_step(engine, kind, paged_attn)
+        outs[paged_attn] = [np.asarray(o) for o in jax.jit(sm)(*args)]
+        assert written
+        for before, after in zip(args[2:4], outs[paged_attn][1:]):
+            before = np.asarray(before)
+            touched = np.zeros(before.shape[:3], bool)
+            for blk, line in written:
+                touched[:, blk, line] = True
+            np.testing.assert_array_equal(after[~touched], before[~touched])
+            # an appended row is new data, in every layer
+            assert (after[touched] != before[touched]).any(axis=(-1, -2)).all()
+    for f, g in zip(outs["fused"][1:], outs["gather"][1:]):
+        np.testing.assert_array_equal(f[0], g[0])
+        np.testing.assert_allclose(f, g, rtol=0, atol=1e-5)
+    live = np.asarray(args[6]) & (np.asarray(args[-1]) > 0)
+    np.testing.assert_allclose(outs["fused"][0][live],
+                               outs["gather"][0][live], rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("paged_attn", ["fused", "gather"])
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_paged_pool_rides_the_layer_scan_as_carry(setup, kind, paged_attn,
+                                                  quant):
+    """Structural guard (PERF.md, PR 26): in the paged ``forward_device``
+    the arenas — and a quantized pool's scale arenas — appear in the layer
+    scan ONLY among the carry. As ``xs``/``ys`` each layer of the pool is
+    sliced out to feed the Pallas call and stacked back, five passes over
+    both arenas a step on the chip; nothing else in tier-1 would notice."""
+    _, config, engine = setup
+    sm, args, _ = _paged_step(engine, kind, paged_attn, quant=quant)
+    arena = tuple(args[2].shape)
+    pool_shapes = {arena, arena[1:], arena[:-1], arena[1:-1]}
+
+    def scans(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scans(sub)
+
+    def n_pool(avals):
+        return sum(tuple(v.aval.shape) in pool_shapes for v in avals)
+
+    layer_scans = [e for e in scans(jax.make_jaxpr(sm)(*args).jaxpr)
+                   if n_pool(e.invars)]
+    assert len(layer_scans) == 1
+    eqn, = layer_scans
+    assert eqn.params["length"] == config.n_layers
+    n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+    carry = eqn.invars[n_const:n_const + n_carry]
+    n_arenas = 4 if quant else 2
+    assert n_pool(carry) == n_arenas
+    assert n_pool(eqn.invars) == n_arenas            # none in consts or xs
+    assert n_pool(eqn.outvars[:n_carry]) == n_arenas
+    assert n_pool(eqn.outvars[n_carry:]) == 0        # none in ys
 
 
 def test_pool_sharded_over_kv_heads(mesh8):

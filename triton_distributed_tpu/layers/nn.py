@@ -262,7 +262,7 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
                           scale: float, slot_mask=None,
                           use_flash_decode: bool = True, seq_lens=None,
                           interpret=None, paged_attn: str = "fused",
-                          kv_scales=None):
+                          kv_scales=None, layer=None):
     """GQA attention of new queries against a BLOCK-PAGED KV pool — the
     paged twin of ``attn_with_cache``.
 
@@ -280,13 +280,19 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
     q:            (B, L, Hq, dh) new queries (rope'd); the new tokens' K/V
                   are already in the pool (``paged_cache_update`` runs
                   first).
-    k/v_pool:     (n_blocks, block_size, Hkv, dh) one layer of the pool.
+    k/v_pool:     (n_blocks, block_size, Hkv, dh) one layer of the pool,
+                  or — with ``layer`` () int32 — the stacked
+                  (n_layers, n_blocks, block_size, Hkv, dh) arena the
+                  model's layer scan carries whole: the fused kernel DMAs
+                  ``[layer, block]`` straight out of it, the gather oracle
+                  reads ``pool[layer]`` (a slice XLA fuses into the gather).
     block_tables: (B, max_blocks) int32; offset: () or (B,) cache length
     BEFORE this step; slot_mask: (B,) bool dead-slot mask (dead rows'
     outputs are garbage the serving engine discards). -> (B, L, Hq, dh).
 
     ``kv_scales`` — ``(k_scale, v_scale)``, each (n_blocks, block_size,
-    Hkv) f32 — marks the pool QUANTIZED (int8/fp8 wire dtype, per-row
+    Hkv) f32, stacked like the pools — marks the pool QUANTIZED (int8/fp8
+    wire dtype, per-row
     scales from ``quantize_kv_rows``): the fused kernel dequantizes in
     VMEM staging right after the pool->VMEM DMA, the gather oracle
     dequantizes its materialized view with ``dequantize_kv_rows``, and
@@ -304,12 +310,12 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
             f"paged_attn must be 'fused' or 'gather', got {paged_attn!r}")
     B, L, Hq, dh = q.shape
     fused = paged_attn == "fused"
-    Hkv = k_pool.shape[2]
+    bs, Hkv = k_pool.shape[-3:-1]
     quant = kv_scales is not None
-    if quant and kv_scales[0].shape != k_pool.shape[:3]:
+    if quant and kv_scales[0].shape != k_pool.shape[:-1]:
         raise ValueError(
             f"kv_scales shape {kv_scales[0].shape} does not match pool "
-            f"rows {k_pool.shape[:3]}")
+            f"rows {k_pool.shape[:-1]}")
 
     from triton_distributed_tpu.obs import comm_ledger as _ledger
 
@@ -330,10 +336,10 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
             # The exact q_tile the kernel will run (memoized/deterministic
             # off-TPU), so the ledger equals the analytic model.
             _, q_tile = tuned_paged_tile(
-                k_pool.shape[1], Hkv, dh, block_tables.shape[1],
+                bs, Hkv, dh, block_tables.shape[1],
                 str(k_pool.dtype), L=L, g=Hq // Hkv)
         nbytes = pm.paged_attn_bytes(
-            B, block_tables.shape[1], k_pool.shape[1], Hkv, dh,
+            B, block_tables.shape[1], bs, Hkv, dh,
             n_q_heads=Hq,
             itemsize=(q.dtype.itemsize if quant
                       else k_pool.dtype.itemsize),
@@ -359,20 +365,22 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
             q, k_pool, v_pool, block_tables, off + q_lens, q_lens=q_lens,
             slot_mask=slot_mask, scale=scale, interpret=interpret,
             k_scale=kv_scales[0] if quant else None,
-            v_scale=kv_scales[1] if quant else None)
+            v_scale=kv_scales[1] if quant else None, layer=layer)
 
     from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
 
-    k_view = paged_gather_kv(k_pool, block_tables, slot_mask=slot_mask)
-    v_view = paged_gather_kv(v_pool, block_tables, slot_mask=slot_mask)
+    def view(pool):
+        if layer is not None:
+            pool = jax.lax.dynamic_index_in_dim(pool, layer, 0,
+                                                keepdims=False)
+        return paged_gather_kv(pool, block_tables, slot_mask=slot_mask)
+
+    k_view, v_view = view(k_pool), view(v_pool)
     if quant:
         # Oracle-side dequant: gather the per-row scales through the SAME
         # table walk, reconstruct f32 views (identical expression to the
         # kernel's in-VMEM dequant), and run the dense reference on those.
-        ks_view = paged_gather_kv(kv_scales[0], block_tables,
-                                  slot_mask=slot_mask)
-        vs_view = paged_gather_kv(kv_scales[1], block_tables,
-                                  slot_mask=slot_mask)
+        ks_view, vs_view = view(kv_scales[0]), view(kv_scales[1])
         k_view = dequantize_kv_rows(k_view, ks_view)
         v_view = dequantize_kv_rows(v_view, vs_view)
     return attn_with_cache(q, k_view, v_view, offset, scale=scale,
@@ -399,12 +407,18 @@ def cache_update(cache, new, offset):
 
 
 def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
-                       scale_pool=None):
+                       scale_pool=None, layer=None):
     """Write ``new`` (B, L, H, dh) into a block-paged KV pool layer
     (n_blocks, block_size, H, dh) at per-slot positions — the
     PagedAttention write: token (b, l) lands in block
     ``block_tables[b, (offsets[b] + l) // block_size]`` at line
     ``(offsets[b] + l) % block_size``. Functional: returns the new pool.
+
+    ``layer`` () int32 — the pool is the STACKED arena (n_layers,
+    n_blocks, block_size, H, dh) and the rows land at ``[layer, block,
+    line]``: one scatter of B*L rows into the arena where it lies (the
+    layer scan carries the arena, and XLA updates a carried operand in
+    place), never a slice-update-restack of a whole layer.
 
     ``write_mask`` — (B,) slot mask or (B, L) per-token mask (varlen
     chunked prefill: only row b's first seq_lens[b] tokens are real) —
@@ -419,8 +433,12 @@ def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
     mask), so a KV row and its scale can never land in different blocks.
     Returns ``(pool, scale_pool)`` instead of ``pool``.
     """
+    if (layer is None) != (pool.ndim == 4):
+        raise ValueError(
+            f"layer goes with the stacked 5-D arena and only with it "
+            f"(pool rank {pool.ndim}, layer {layer!r})")
     B, L = new.shape[:2]
-    n_blocks, bs = pool.shape[:2]
+    n_blocks, bs = pool.shape[-4:-2]
     pos = (jnp.asarray(offsets, jnp.int32)[:, None]
            + jnp.arange(L, dtype=jnp.int32)[None])                 # (B, L)
     slot = jnp.minimum(pos // bs, block_tables.shape[1] - 1)
@@ -430,9 +448,9 @@ def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
     if write_mask is not None:
         wm = (write_mask if write_mask.ndim == 2 else write_mask[:, None])
         blk = jnp.where(wm, blk, n_blocks)          # out of range -> dropped
+    idx = (blk, pos % bs) if layer is None else (layer, blk, pos % bs)
     if scale_pool is None:
-        return pool.at[blk, pos % bs].set(new.astype(pool.dtype),
-                                          mode="drop")
+        return pool.at[idx].set(new.astype(pool.dtype), mode="drop")
     q, scales = quantize_kv_rows(new, pool.dtype)
-    return (pool.at[blk, pos % bs].set(q, mode="drop"),
-            scale_pool.at[blk, pos % bs].set(scales, mode="drop"))
+    return (pool.at[idx].set(q, mode="drop"),
+            scale_pool.at[idx].set(scales, mode="drop"))

@@ -131,7 +131,7 @@ class TPAttn:
     def _qkv_to_attn(self, params, qkv, k_cache, v_cache, offset, world,
                      use_flash_decode: bool = True, seq_lens=None,
                      interpret=None, block_tables=None, slot_mask=None,
-                     paged_attn: str = "fused", kv_scales=None):
+                     paged_attn: str = "fused", kv_scales=None, layer=None):
         """qkv (B, L, q_size+2*kv_size) local-head projection -> attention
         output (B, L, q_size) plus updated caches. The qk-norm -> RoPE ->
         cache-append -> GQA-attend pipeline shared by every mode
@@ -144,7 +144,10 @@ class TPAttn:
         - contiguous (``block_tables=None``): k/v_cache (B, S, Hkv, dh),
           ``offset`` () scalar (the Engine path) or (B,) per-row.
         - PAGED (serving): k/v_cache are one layer of the block pool
-          (n_blocks, block_size, Hkv, dh); ``block_tables`` (B, max_blocks)
+          (n_blocks, block_size, Hkv, dh) or, with ``layer`` () int32, the
+          whole stacked arena (n_layers, n_blocks, block_size, Hkv, dh) —
+          what the model's layer scan carries, appended to and read at
+          ``[layer, block]`` where it lies; ``block_tables`` (B, max_blocks)
           maps each slot's sequence onto pool blocks, ``offset`` is the
           (B,) per-slot depth vector, and ``slot_mask`` (B,) drops dead
           slots' cache writes. New K/V scatter into the pool; attention
@@ -201,40 +204,45 @@ class TPAttn:
         if kv_scales is not None:
             k_cache, ks = nn.paged_cache_update(k_cache, k, block_tables,
                                                 offset, wm,
-                                                scale_pool=kv_scales[0])
+                                                scale_pool=kv_scales[0],
+                                                layer=layer)
             v_cache, vs = nn.paged_cache_update(v_cache, v, block_tables,
                                                 offset, wm,
-                                                scale_pool=kv_scales[1])
+                                                scale_pool=kv_scales[1],
+                                                layer=layer)
             out = nn.paged_attn_with_cache(
                 q, k_cache, v_cache, block_tables, offset, scale=dh ** -0.5,
                 slot_mask=slot_mask, use_flash_decode=use_flash_decode,
                 seq_lens=seq_lens, interpret=interpret,
-                paged_attn=paged_attn, kv_scales=(ks, vs))
+                paged_attn=paged_attn, kv_scales=(ks, vs), layer=layer)
             return out.reshape(B, L, qs), k_cache, v_cache, (ks, vs)
         k_cache = nn.paged_cache_update(k_cache, k, block_tables,
-                                        offset, wm)
+                                        offset, wm, layer=layer)
         v_cache = nn.paged_cache_update(v_cache, v, block_tables,
-                                        offset, wm)
+                                        offset, wm, layer=layer)
         out = nn.paged_attn_with_cache(q, k_cache, v_cache, block_tables,
                                        offset, scale=dh ** -0.5,
                                        slot_mask=slot_mask,
                                        use_flash_decode=use_flash_decode,
                                        seq_lens=seq_lens, interpret=interpret,
-                                       paged_attn=paged_attn)
+                                       paged_attn=paged_attn, layer=layer)
         return out.reshape(B, L, qs), k_cache, v_cache
 
     # -- per-device forwards (inside shard_map) -----------------------------
 
     def dist_fwd(self, params, x_local, k_cache, v_cache, offset, *,
                  seq_lens=None, interpret=None, block_tables=None,
-                 slot_mask=None, paged_attn: str = "fused", kv_scales=None):
+                 slot_mask=None, paged_attn: str = "fused", kv_scales=None,
+                 layer=None):
         """x_local: (B_local, L, d) batch-shard -> same layout out.
         AG-GEMM -> attention -> GEMM-RS (reference dist_triton_fwd :203).
         ``seq_lens``: (B,) varlen prefill lengths (nn.attn_with_cache).
         ``block_tables``/``slot_mask``/``paged_attn``: paged-KV serving
         path (``_qkv_to_attn``) — tables/mask cover the FULL batch,
         replicated. ``kv_scales`` (quantized paged pool) appends the
-        updated (k_scale, v_scale) tuple as a 4th output."""
+        updated (k_scale, v_scale) tuple as a 4th output. ``layer``: the
+        caches are the stacked paged arenas, read and appended at this
+        layer (``_qkv_to_attn``)."""
         world = _axis_size(self.axis)
         Bl, L, d = x_local.shape
         qkv = ag_gemm_device(
@@ -244,7 +252,8 @@ class TPAttn:
         res = self._qkv_to_attn(
             params, qkv, k_cache, v_cache, offset, world, seq_lens=seq_lens,
             interpret=interpret, block_tables=block_tables,
-            slot_mask=slot_mask, paged_attn=paged_attn, kv_scales=kv_scales)
+            slot_mask=slot_mask, paged_attn=paged_attn, kv_scales=kv_scales,
+            layer=layer)
         out, k_cache, v_cache = res[:3]
         out = gemm_rs_device(
             out.reshape(world * Bl * L, -1), params["w_o"], axis=self.axis,
@@ -257,7 +266,8 @@ class TPAttn:
 
     def ar_fwd(self, params, x_full, k_cache, v_cache, offset, *,
                interpret=None, seq_lens=None, block_tables=None,
-               slot_mask=None, paged_attn: str = "fused", kv_scales=None):
+               slot_mask=None, paged_attn: str = "fused", kv_scales=None,
+               layer=None):
         """x_full: (B, L, d) replicated -> replicated out.
         Local GEMMs -> one-shot allreduce (reference dist_triton_AR_fwd)."""
         world = _axis_size(self.axis)
@@ -266,7 +276,8 @@ class TPAttn:
         res = self._qkv_to_attn(
             params, qkv, k_cache, v_cache, offset, world, interpret=interpret,
             seq_lens=seq_lens, block_tables=block_tables,
-            slot_mask=slot_mask, paged_attn=paged_attn, kv_scales=kv_scales)
+            slot_mask=slot_mask, paged_attn=paged_attn, kv_scales=kv_scales,
+            layer=layer)
         out, k_cache, v_cache = res[:3]
         partial = out.reshape(B * L, -1) @ params["w_o"]
         out = oneshot_all_reduce(partial, axis=self.axis, interpret=interpret)
@@ -277,7 +288,7 @@ class TPAttn:
 
     def xla_fwd(self, params, x_local, k_cache, v_cache, offset, *,
                 seq_lens=None, block_tables=None, slot_mask=None,
-                paged_attn: str = "fused", kv_scales=None):
+                paged_attn: str = "fused", kv_scales=None, layer=None):
         """Golden/baseline path: same math via jnp + XLA collectives.
         Batch-sharded in/out like ``dist_fwd``. ``paged_attn`` still
         routes paged decode through the fused kernel (interpret mode on
@@ -292,7 +303,7 @@ class TPAttn:
             params, qkv, k_cache, v_cache, offset, world,
             use_flash_decode=False, seq_lens=seq_lens,
             block_tables=block_tables, slot_mask=slot_mask,
-            paged_attn=paged_attn, kv_scales=kv_scales)
+            paged_attn=paged_attn, kv_scales=kv_scales, layer=layer)
         out, k_cache, v_cache = res[:3]
         partial = out.reshape(world * Bl * L, -1) @ params["w_o"]
         out = jax.lax.psum_scatter(partial, self.axis, scatter_dimension=0,

@@ -290,7 +290,10 @@ class Qwen3:
           block_tables (B, max_blocks) int32 + ``slot_mask`` (B,) bool
                        switch the caches to the block-paged pool layout
                        (n_layers, n_blocks, block_size, local_kv_heads, dh)
-                       — see ``TPAttn._qkv_to_attn``.
+                       — see ``TPAttn._qkv_to_attn``. The arenas are
+                       carried through the layer scan whole and updated
+                       where they lie (no layer of them is ever sliced
+                       out or stacked back).
           paged_attn   "fused" (default) routes every paged step shape
                        through the fused block-walk kernel; "gather" pins
                        the materialized-view escape hatch / test oracle
@@ -374,38 +377,42 @@ class Qwen3:
                          "w_down": lp_mlp.pop("w_down")}
             scan_layers["mlp"] = lp_mlp
 
-        def body(h, xs):
-            if quant:
-                lp, kc, vc, ksc, vsc, li = xs
-                sc = (ksc, vsc)
+        # The PAGED pool follows the same rule: the stacked arenas (and a
+        # quantized pool's scale arenas) ride the scan as CARRY beside h
+        # and the body passes the layer index down — the append scatters
+        # its rows into ``[li, block, line]`` of the arena where it lies and
+        # the fused kernel DMAs ``[li, block]`` out of it. As ``xs``/``ys``
+        # each layer of the pool was sliced out to feed the Pallas call and
+        # stacked back: five passes over both arenas a step and a second
+        # pool of temporaries (PERF.md, PR 26). The contiguous cache
+        # (``Engine``'s own, no block tables, no served path) keeps
+        # ``xs``/``ys``.
+        paged = block_tables is not None
+
+        def body(carry, xs):
+            if paged:
+                h, kv = carry[0], carry[1:]   # (k, v[, k_scale, v_scale])
+                lp, li = xs
             else:
-                lp, kc, vc, li = xs
-                sc = None
+                h = carry
+                lp, kv, li = xs[0], xs[1:3], xs[3]
+            kc, vc = kv[:2]
+            sc = kv[2:] if quant else None
             resid = h
             hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
+            akw = dict(seq_lens=seq_lens, block_tables=block_tables,
+                       slot_mask=slot_mask, paged_attn=paged_attn,
+                       kv_scales=sc, layer=li if paged else None)
             if mode == "dist":
                 res = attn.dist_fwd(lp["attn"], hn, kc, vc, offset,
-                                    interpret=interpret,
-                                    seq_lens=seq_lens,
-                                    block_tables=block_tables,
-                                    slot_mask=slot_mask,
-                                    paged_attn=paged_attn, kv_scales=sc)
+                                    interpret=interpret, **akw)
             elif mode == "xla":
-                res = attn.xla_fwd(lp["attn"], hn, kc, vc, offset,
-                                   seq_lens=seq_lens,
-                                   block_tables=block_tables,
-                                   slot_mask=slot_mask,
-                                   paged_attn=paged_attn, kv_scales=sc)
+                res = attn.xla_fwd(lp["attn"], hn, kc, vc, offset, **akw)
             else:
                 res = attn.ar_fwd(lp["attn"], hn, kc, vc, offset,
-                                  interpret=interpret,
-                                  seq_lens=seq_lens,
-                                  block_tables=block_tables,
-                                  slot_mask=slot_mask,
-                                  paged_attn=paged_attn, kv_scales=sc)
-            a, kc, vc = res[:3]
-            if quant:
-                ksc, vsc = res[3]
+                                  interpret=interpret, **akw)
+            a = res[0]
+            kv = tuple(res[1:3]) + (tuple(res[3]) if quant else ())
             h = resid + a
             resid = h
             hn = nn.rms_norm(h, lp["post_norm"], c.rms_eps)
@@ -427,30 +434,24 @@ class Qwen3:
             else:
                 m = mlp.ar_fwd(lp["mlp"], flat, interpret=interpret)
             h = resid + m.reshape(hn.shape)
-            tail = (kc, vc, ksc, vsc) if quant else (kc, vc)
-            if return_moe_stats:
-                return h, tail + (stats,)
-            return h, tail
+            ys = (stats,) if return_moe_stats else ()
+            if paged:
+                return (h,) + kv, ys
+            return h, kv + ys
 
         layer_ids = jnp.arange(c.n_layers, dtype=jnp.int32)
-        xs = ((scan_layers, k_cache, v_cache, kv_scales[0], kv_scales[1],
-               layer_ids) if quant
-              else (scan_layers, k_cache, v_cache, layer_ids))
-        new_ks = new_vs = None
-        if return_moe_stats:
-            h, ys = jax.lax.scan(body, h, xs)
-            if quant:
-                new_k, new_v, new_ks, new_vs, layer_stats = ys
-            else:
-                new_k, new_v, layer_stats = ys
-            moe_stats = jax.tree.map(
-                lambda x: jax.lax.psum(jnp.sum(x), self.axis), layer_stats)
+        if paged:
+            carry, ys = jax.lax.scan(
+                body, (h, k_cache, v_cache) + tuple(kv_scales or ()),
+                (scan_layers, layer_ids))
+            h, kv_out = carry[0], carry[1:]
         else:
-            h, ys = jax.lax.scan(body, h, xs)
-            if quant:
-                new_k, new_v, new_ks, new_vs = ys
-            else:
-                new_k, new_v = ys
+            h, ys = jax.lax.scan(
+                body, h, (scan_layers, k_cache, v_cache, layer_ids))
+            kv_out, ys = ys[:2], ys[2:]
+        if return_moe_stats:
+            moe_stats = jax.tree.map(
+                lambda x: jax.lax.psum(jnp.sum(x), self.axis), ys[0])
 
         h = nn.rms_norm(h, params["final_norm"], c.rms_eps)
         lm_head = (params["embed"].T if c.tie_embeddings
@@ -484,8 +485,6 @@ class Qwen3:
             last = jax.lax.all_gather(last, self.axis, axis=0, tiled=True)
         # bf16 operands, fp32 accumulation — no materialized fp32 weight copy
         logits = jnp.dot(last, lm_head, preferred_element_type=jnp.float32)
-        kv_out = ((new_k, new_v, new_ks, new_vs) if quant
-                  else (new_k, new_v))
         if spec_verify:
             return (logits, greedy) + kv_out
         if return_moe_stats:
