@@ -40,10 +40,11 @@ def summarise(all_gaps) -> dict:
 CONTROL_PRECISION = "fp8"
 
 
-def compare(sizes, seed: int, sample, device, *, limits=None,
+def compare(family, sizes, seed: int, sample, device, *, limits=None,
             control: bool = False) -> dict:
-    """``sample`` is a list of (prompt, served tokens). Returns the numbers
-    compared beside their limits, and ``correct``."""
+    """``sample`` is a list of (prompt, served tokens); ``family`` is the
+    configuration's (``perfbench/families/``). Returns the numbers compared
+    beside their limits, and ``correct``."""
     from perfbench import reference, weights
 
     limits = {**DEFAULT_LIMITS.get(sizes.dtype, {}), **(limits or {})}
@@ -55,17 +56,17 @@ def compare(sizes, seed: int, sample, device, *, limits=None,
     if not sample:
         out["why"] = "no finished request to compare"
         return out
-    w = weights.Weights(sizes, seed, device)
+    w = weights.Weights(family, sizes, seed, device)
     seqs = [(list(prompt) + list(served), len(prompt))
             for prompt, served in sample]
-    sound = [g for r in reference.forward_positions(sizes, w, seqs)
+    sound = [g for r in reference.forward_positions(w, seqs)
              for g in gaps(r)]
     ctrl = []
     if control:
-        low = reference.forward_positions(sizes, w, seqs,
+        low = reference.forward_positions(w, seqs,
                                           precision=CONTROL_PRECISION)
         ref_low = reference.forward_positions(
-            sizes, w, seqs, gather=[r["best_token"] for r in low])
+            w, seqs, gather=[r["best_token"] for r in low])
         ctrl = [g for r in ref_low for g in gaps(r)]
     out["compared"] = summarise(sound)
     out["correct"] = all(out["compared"][k] <= limits[k] for k in limits)

@@ -3,8 +3,8 @@ plain reference, the result line.
 
 Driven by data: the cell, its configuration, its traffic mix and the
 metrics it reports are found by name from ``BENCHMARK.json`` and the files
-under ``perfbench/`` (see README.md). No cell's, model's or metric's name
-appears in this file.
+under ``perfbench/`` (see README.md); what the model is, is its family's
+(``families/``). No cell's, model's or metric's name appears in this file.
 """
 
 from __future__ import annotations
@@ -20,8 +20,9 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WARM_OUTPUT = 4            # tokens each warm-up request generates
-SAMPLE_REQUESTS = 3        # finished requests compared with the reference
-SAMPLE_TOKEN_BUDGET = 9000  # prompt + served tokens the reference may read
+# What the reference reads, where the cell's file states no "sample":
+# finished requests compared, and prompt + served tokens over all of them.
+SAMPLE = {"requests": 3, "token_budget": 9000}
 KV_SAMPLE_EVERY = 8        # steps between readings of the pool's live share
 TRACE_SPAN_S = 3.0         # the traced span is the end of the window: the
                            # seconds that writing the trace takes then fall
@@ -43,8 +44,9 @@ def load_json(root, *parts):
 
 def load_cell(workload: str, root: str = ROOT) -> dict:
     """The cell's entry, its configuration, its traffic parameters (the
-    mix's file, then ``cells/<workload>.json`` laid over it) and the
-    metrics it reports. ``root`` holds ``BENCHMARK.json``."""
+    mix's file, then ``cells/<workload>.json`` laid over it), the limits of
+    ``correct`` and the sample it reads (laid over the defaults the same
+    way) and the metrics it reports. ``root`` holds ``BENCHMARK.json``."""
     bench = load_json(root, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -57,19 +59,21 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
     bench_dir = os.path.dirname(os.path.dirname(cfg_file))
     traffic = load_json(root, bench_dir, "traffic", cell["traffic"] + ".json")
     cell_file = os.path.join(root, bench_dir, "cells", workload + ".json")
-    limits = {}
+    limits, sample = {}, dict(SAMPLE)
     if os.path.exists(cell_file):
         with open(cell_file) as f:
             extra = json.load(f)
         traffic.update(extra.get("traffic", {}))
         limits = extra.get("limits", {})
+        sample.update(extra.get("sample", {}))
 
     def reported(group):
         return [m for m in bench[group]
                 if "workloads" not in m or workload in m["workloads"]]
 
     return {"cell": cell, "config": cfg, "traffic": traffic,
-            "limits": limits, "end_to_end": reported("end_to_end"),
+            "limits": limits, "sample": sample,
+            "end_to_end": reported("end_to_end"),
             "per_layer": reported("per_layer")}
 
 
@@ -100,7 +104,9 @@ class Records:
     kv_live: list            # sampled live share of the pool
     counters: dict           # the program's counters, change over the window
     queue_wait_s: list       # its queue_wait_s samples of the window
-    sizes: object
+    sizes: object            # the configuration's, as its family made them
+    family: object           # the family's module: its counts of operations
+                             # and bytes are family.<count>(sizes, ...)
     n_slots: int
     n_chips: int
     device_kind: str
@@ -283,7 +289,7 @@ class CompileCounter:
                 self._on)
 
 
-def pick_sample(finished, seed: int):
+def pick_sample(finished, seed: int, requests: int, token_budget: int):
     """The finished requests the reference reads: the longest, then others
     drawn from the seed, inside the token budget."""
     from perfbench import lengths
@@ -297,9 +303,9 @@ def pick_sample(finished, seed: int):
     order = sorted(range(len(finished)), key=lambda i: -size(finished[i]))
     rest = order[1:]
     lengths.rng_for(seed, 7).shuffle(rest)
-    picked, budget = [], SAMPLE_TOKEN_BUDGET
+    picked, budget = [], token_budget
     for i in [order[0]] + rest:
-        if len(picked) == SAMPLE_REQUESTS:
+        if len(picked) == requests:
             break
         if picked and size(finished[i]) > budget:
             continue
@@ -312,12 +318,13 @@ def pick_sample(finished, seed: int):
 def set_up(spec: dict, seed: int, *, t_start: float, allow_cpu: bool = False,
            engine_overrides: dict | None = None, tamper=None):
     """Everything before the window except the traffic plan: the device
-    check, the compile cache, weights from the seed, ``Fleet.build``, and
-    the warm-up of every program the window can drive."""
+    check, the compile cache, the configuration's family, weights from the
+    seed, ``Fleet.build``, and the warm-up of every program the window can
+    drive."""
     phases: dict = {"start_s": time.monotonic() - t_start}
     import jax
 
-    from perfbench import peaks, system, weights
+    from perfbench import families, peaks, system
 
     phases["jax_import_s"] = time.monotonic() - t_start
     cell, cfg = spec["cell"], spec["config"]
@@ -338,10 +345,11 @@ def set_up(spec: dict, seed: int, *, t_start: float, allow_cpu: bool = False,
     cache_dir = system.enable_compile_cache()
     say("device", compile_cache_dir=cache_dir, **dev)
 
-    sizes = weights.ModelSizes.from_hf(cfg, qk_norm=cfg.get("qk_norm", True))
+    family = families.load_family(cfg)
+    sizes = family.sizes(cfg)
     compiles = CompileCounter()
     phases["import_s"] = time.monotonic() - t_start
-    served = system.Served(cfg, sizes, seed, devices,
+    served = system.Served(cfg, family, sizes, seed, devices,
                            engine_overrides=engine_overrides, phases=phases)
     if tamper is not None:
         tamper(served)
@@ -354,7 +362,7 @@ def set_up(spec: dict, seed: int, *, t_start: float, allow_cpu: bool = False,
     phases["warm_s"] = time.monotonic() - t0
     say("first_call", mixed_s=first["mixed"], decode_s=first["decode"],
         warm_s=phases["warm_s"], compilations=compiles.n)
-    return served, sizes, devices, dev, phases, compiles
+    return served, family, sizes, devices, dev, phases, compiles
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
@@ -373,7 +381,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
     from perfbench import check
     from perfbench.traffic_kinds import load_kind
 
-    served, sizes, devices, dev, phases, compiles = set_up(
+    served, family, sizes, devices, dev, phases, compiles = set_up(
         spec, seed, t_start=t_start, allow_cpu=allow_cpu,
         engine_overrides=engine_overrides, tamper=tamper)
     t0 = time.monotonic()
@@ -416,7 +424,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
         raise BenchFailure(f"the window compiled {compiled_inside} "
                            f"program(s) or the fleet is not sound: {health}")
 
-    rec = records_of(out, served, sizes, len(devices), dev["kind"], setup_s)
+    rec = records_of(out, served, family, sizes, len(devices), dev["kind"],
+                     setup_s)
     attempted, failed = count_requests(rec)
     say("window", attempted=attempted, failed=failed,
         standing=len(standing), finished=len(rec.finished),
@@ -431,14 +440,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
         tokens_in_window=sum(s[4] for s in rec.steps
                              if s[1] < rec.t_close))
 
-    sample = pick_sample(rec.finished, seed)
+    sample = pick_sample(rec.finished, seed, **spec["sample"])
     served.close()
     t0 = time.monotonic()
-    verdict = check.compare(sizes, seed, sample, devices[0],
+    verdict = check.compare(family, sizes, seed, sample, devices[0],
                             limits=spec["limits"], control=control)
     verdict["seconds"] = time.monotonic() - t0
     say("correct", **verdict)
     correct = bool(verdict["correct"] and attempted > 0)
+    # Each number compared beside its limit, as the last lines on standard
+    # error: where a run is not correct, the end of it is what is kept.
+    for name, limit in verdict["limits"].items():
+        print(f"perfbench: compared {name} "
+              f"{verdict.get('compared', {}).get(name)} limit {limit}",
+              file=sys.stderr, flush=True)
 
     if tracer:
         from perfbench import xplane
@@ -454,22 +469,23 @@ def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     device = {**dev, "memory_peak_bytes": int(peak)}
     result = {"correct": correct, "attempted": attempted, "failed": failed,
-              "metrics": metrics, "device": device, "check": verdict}
+              "metrics": metrics, "device": device}
     if rec.trace is not None:
         device["busy_s"] = rec.trace["busy_s"]
         device["window_s"] = rec.trace["window_s"]
         result["breakdown"] = rec.trace["breakdown"]
+    result["check"] = verdict      # the numbers compared come last
     return result
 
 
-def records_of(out: dict, served, sizes, n_chips: int, device_kind: str,
-               setup_s: float = 0.0) -> Records:
+def records_of(out: dict, served, family, sizes, n_chips: int,
+               device_kind: str, setup_s: float = 0.0) -> Records:
     """What ``drive`` returned, with what the readers need beside it."""
     return Records(
         t_open=out["t_open"], t_close=out["t_close"], t_end=out["t_end"],
         setup_s=setup_s, tracked=out["tracked"], steps=out["steps"],
         kv_live=out["kv_live"], counters=out["counters"],
-        queue_wait_s=served.queue_wait_new(), sizes=sizes,
+        queue_wait_s=served.queue_wait_new(), sizes=sizes, family=family,
         n_slots=served.n_slots, n_chips=n_chips, device_kind=device_kind)
 
 

@@ -1,5 +1,5 @@
-"""The plain reference: a dense Qwen3-style decoder in straightforward
-``jax.numpy``, float32, ``jax.default_matmul_precision("highest")``.
+"""The plain reference: a decoder in straightforward ``jax.numpy``, float32,
+``jax.default_matmul_precision("highest")``.
 
 No cache, no kernels, no batching; it imports nothing of the program under
 test and takes nothing the program has made. Weights come from
@@ -7,12 +7,12 @@ test and takes nothing the program has made. Weights come from
 time, so one float32 layer is all that sits on the device beside the
 activations.
 
-The block, as the model's public description has it (Qwen3 technical
-report; HF ``modeling_qwen3``): pre-norm residual blocks; RMSNorm in
-float32; grouped-query attention with a per-head RMSNorm on q and k before
-rotate-half RoPE; causal softmax attention scaled by ``head_dim ** -0.5``;
-SwiGLU feed-forward ``down(silu(gate(x)) * up(x))``; a final RMSNorm and a
-head that is the transposed embedding when ``tie_word_embeddings``.
+This file holds what every model family shares: the primitives (RMSNorm in
+float32, rotate-half RoPE, a linear layer in the precision asked for, causal
+softmax attention with grouped heads) and the driver (embedding -> layers
+-> blocked head, ``forward_positions``). The equations of a layer, and
+which weights it has, are its family's (``perfbench/families/<family>.py``:
+``layer_forward``, ``layer_weights``, ``head_weights``).
 
 ``precision`` selects the arithmetic of the linear layers:
 
@@ -83,7 +83,8 @@ def linear(x, w, precision):
 
 
 def attention(q, k, v, scale):
-    """Causal GQA. q: (S, Hq, dh); k, v: (S, Hkv, dh) -> (S, Hq*dh)."""
+    """Causal softmax attention, Hq // Hkv query heads to a key head.
+    q: (S, Hq, dh); k: (S, Hkv, dh); v: (S, Hkv, dv) -> (S, Hq*dv)."""
     S, Hq, dh = q.shape
     Hkv = k.shape[1]
     g = Hq // Hkv
@@ -98,29 +99,7 @@ def attention(q, k, v, scale):
         s = jnp.where(cols[None, None, :] <= rows[None, :, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         outs.append(jnp.einsum("hqk,khd->qhd", p, vq))
-    return jnp.concatenate(outs, axis=0).reshape(S, Hq * dh)
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "precision"))
-def layer_forward(h, lw, *, dims, precision):
-    """One decoder layer over one whole sequence. h: (S, d) float32."""
-    n_heads, n_kv, dh, eps, theta = dims
-    S = h.shape[0]
-    pos = jnp.arange(S)
-    x = rms_norm(h, lw["input_norm"], eps)
-    q = linear(x, lw["wq"], precision).reshape(S, n_heads, dh)
-    k = linear(x, lw["wk"], precision).reshape(S, n_kv, dh)
-    v = linear(x, lw["wv"], precision).reshape(S, n_kv, dh)
-    if "q_norm" in lw:
-        q = rms_norm(q, lw["q_norm"], eps)
-        k = rms_norm(k, lw["k_norm"], eps)
-    q, k = rope(q, pos, theta), rope(k, pos, theta)
-    a = attention(q, k, v, dh ** -0.5)
-    h = h + linear(a, lw["wo"], precision)
-    x = rms_norm(h, lw["post_norm"], eps)
-    gate = linear(x, lw["wg"], precision)
-    up = linear(x, lw["wu"], precision)
-    return h + linear(jax.nn.silu(gate) * up, lw["wd"], precision)
+    return jnp.concatenate(outs, axis=0).reshape(S, Hq * v.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "precision"))
@@ -144,19 +123,19 @@ def f32(tree):
                         if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
 
 
-def forward_positions(model, weights, sequences, *, precision="float32",
+def forward_positions(weights, sequences, *, precision="float32",
                       gather=None):
     """Run each of ``sequences`` (``(tokens, first)``: the prompt followed
     by the served tokens, and where the served tokens begin) through the
     whole stack once and read the head at positions ``first - 1`` to
     ``len(tokens) - 2``: the positions whose next token was served.
 
-    ``model`` is the configuration's sizes (``perfbench.weights.ModelSizes``);
-    ``weights`` makes the seeded weights (``perfbench.weights.Weights``), one
-    layer at a time, each layer once for all the sequences. ``gather`` names,
-    for each sequence, the token whose logit is read at each of those
-    positions (default: the served token). Returns one dict of numpy arrays
-    a sequence, one entry a served token.
+    ``weights`` (``perfbench.weights.Weights``) carries the family, the
+    configuration's sizes and the seed, and makes the weights one layer at a
+    time, each layer once for all the sequences. ``gather`` names, for each
+    sequence, the token whose logit is read at each of those positions
+    (default: the served token). Returns one dict of numpy arrays a
+    sequence, one entry a served token.
 
     A sequence is padded at its end to a multiple of ``SEQ_PAD`` (causal
     attention: padding never reaches an earlier position) and the head
@@ -164,8 +143,7 @@ def forward_positions(model, weights, sequences, *, precision="float32",
     """
     import numpy as np
 
-    dims = (model.n_heads, model.n_kv_heads, model.head_dim, model.rms_eps,
-            model.rope_theta)
+    family, sizes = weights.family, weights.sizes
     with jax.default_matmul_precision("highest"):
         g = weights.globals_()
         hs = []
@@ -174,12 +152,13 @@ def forward_positions(model, weights, sequences, *, precision="float32",
             ids[:len(tokens)] = tokens
             hs.append(jnp.take(g["embed"], jnp.asarray(ids),
                                axis=0).astype(jnp.float32))
-        for i in range(model.n_layers):
+        for i in range(sizes.n_layers):
             lw = f32(weights.layer(i))
-            hs = [layer_forward(h, lw, dims=dims, precision=precision)
+            hs = [family.layer_forward(h, lw, sizes, i, precision)
                   for h in hs]
-        head = g["embed"].T if model.tie_embeddings else g["lm_head"]
-        fn = g["final_norm"].astype(jnp.float32)
+        hw = family.head_weights(sizes, g)
+        head, eps = hw["head"], hw["eps"]
+        fn = hw["final_norm"].astype(jnp.float32)
         out = []
         for j, ((tokens, first), h) in enumerate(zip(sequences, hs)):
             n, n_out = len(tokens), len(tokens) - first
@@ -192,7 +171,7 @@ def forward_positions(model, weights, sequences, *, precision="float32",
             picked = jnp.take(h, jnp.asarray(rows), axis=0)
             parts = [head_block(picked[r0:r0 + HEAD_BLOCK], fn, head,
                                 jnp.asarray(toks[r0:r0 + HEAD_BLOCK]),
-                                eps=model.rms_eps, precision=precision)
+                                eps=eps, precision=precision)
                      for r0 in range(0, rows.shape[0], HEAD_BLOCK)]
             out.append({k: np.concatenate([np.asarray(p[k]) for p in parts])
                         [:n_out] for k in parts[0]})

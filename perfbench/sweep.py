@@ -36,7 +36,7 @@ def main(argv=None) -> int:
                     help="comma-separated requests per second, ascending")
     args = ap.parse_args(argv)
     spec = core.load_cell(args.workload)
-    served, sizes, devices, dev, phases, compiles = core.set_up(
+    served, family, sizes, devices, dev, phases, compiles = core.set_up(
         spec, args.seed, t_start=T_START)
     carried = None
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
@@ -49,7 +49,8 @@ def main(argv=None) -> int:
             carried = core.open_standing(served, plan)
         served.queue_wait_new()
         out = core.drive(served, plan, args.seconds, 0.0, standing=carried)
-        rec = core.records_of(out, served, sizes, len(devices), dev["kind"])
+        rec = core.records_of(out, served, family, sizes, len(devices),
+                              dev["kind"])
         def in_flight(share):
             t = rec.t_open + share * args.seconds
             return sum(1 for tr in rec.tracked if tr.submit_t <= t

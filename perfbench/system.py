@@ -5,8 +5,9 @@ Everything the benchmark takes from ``triton_distributed_tpu`` goes through
 ``Fleet.build`` -> ``BatchEngine``), its counters and histograms as they
 are, and today's private per-request handle (``fleet._submitted``; a public
 one is asked of the ``tracing`` issue in PERF.md). Nothing here names a
-model or a cell: sizes come from the configuration's file, keyword
-arguments of ``Engine`` and ``Fleet.build`` pass through unread.
+model or a cell: the program's configuration object and its parameters come
+from the configuration's family (``perfbench/families/``), keyword arguments
+of ``Engine`` and ``Fleet.build`` pass through unread.
 """
 
 from __future__ import annotations
@@ -30,28 +31,15 @@ def enable_compile_cache() -> str:
 class Served:
     """One configuration, built and ready to take requests."""
 
-    def __init__(self, cfg: dict, sizes, seed: int, devices, *,
+    def __init__(self, cfg: dict, family, sizes, seed: int, devices, *,
                  engine_overrides: dict | None = None, phases=None):
         import jax
-        import jax.numpy as jnp
 
-        from triton_distributed_tpu.models.config import ModelConfig
         from triton_distributed_tpu.models.engine import Engine
-        from triton_distributed_tpu.models.qwen import Qwen3
         from triton_distributed_tpu.runtime.mesh import make_mesh
         from triton_distributed_tpu.serving.fleet import Fleet
 
-        from perfbench import weights
-
         serve = cfg["serve"]
-        mcfg = ModelConfig(
-            model_name=cfg["source"], vocab_size=sizes.vocab_size,
-            d_model=sizes.d_model, n_layers=sizes.n_layers,
-            n_heads=sizes.n_heads, n_kv_heads=sizes.n_kv_heads,
-            head_dim=sizes.head_dim, d_ff=sizes.d_ff,
-            rope_theta=sizes.rope_theta, rms_eps=sizes.rms_eps,
-            tie_embeddings=sizes.tie_embeddings, qk_norm=sizes.qk_norm,
-            max_length=sizes.max_length, dtype=jnp.dtype(sizes.dtype))
         n_dev = 1
         for v in serve["mesh"].values():
             n_dev *= v
@@ -59,8 +47,7 @@ class Served:
                          set_default=False)
         ekw = {**serve["engine"], **(engine_overrides or {})}
         t0 = time.monotonic()
-        params = weights.program_params(
-            sizes, seed, Qwen3(mcfg, block_n=ekw.get("block_n", 256)), mesh)
+        mcfg, params = family.program(cfg, sizes, seed, mesh, ekw)
         jax.block_until_ready(params)
         t1 = time.monotonic()
         self.engine = Engine(mcfg, mesh=mesh, params=params, **ekw)

@@ -43,7 +43,7 @@ def test_gaps_and_rates_from_a_hand_made_step_log():
         steps=[(10.0, 10.1, "mixed", 0, 1, 0), (10.1, 10.15, "decode", 1, 1, 5),
                (10.15, 10.25, "decode", 2, 1, 6), (11.1, 11.2, "decode", 1, 1, 7)],
         kv_live=[0.25, 0.5], counters={"preemptions": 0.0}, queue_wait_s=[0.01],
-        sizes=None, n_slots=2, n_chips=1, device_kind="cpu")
+        sizes=None, family=None, n_slots=2, n_chips=1, device_kind="cpu")
     assert readers.ttft_ms(rec) == pytest.approx([100.0, 400.0])
     assert sorted(readers.gaps_ms(rec)) == pytest.approx([50.0, 70.0, 100.0])
     from perfbench.end_to_end import ttft_mean_ms
@@ -117,6 +117,13 @@ def test_open_loop_deals_the_same_sizes_and_gaps_in_another_order_by_seed():
                and len(p.prompt) + p.max_new_tokens <= KW["max_total"]
                for p in sa)
     assert max(len(p.prompt) for p in sa) > params["prompt"]["hi"]
+    # the same population for every seed, with the seed's own token ids
+
+    def sizes(ps):
+        return [(len(p.prompt), p.max_new_tokens) for p in ps]
+
+    assert sizes(sa) == sizes(sc)
+    assert [p.prompt for p in sa] != [p.prompt for p in sc]
     del params["standing"]
     assert whole(params, 5)[0] == []          # no pace stated: an idle fleet
 
@@ -168,11 +175,13 @@ def test_peaks_table_knows_the_v5e_and_refuses_the_rest():
 
 
 def test_min_bytes_of_a_decode_step():
-    from perfbench import weights
+    from perfbench import families
 
     with open(os.path.join(BENCH, "configs", "qwen3-1.7b.json")) as f:
-        m = weights.ModelSizes.from_hf(json.load(f))
-    assert peaks.layer_matmul_params(m) == 1_409_286_144
-    assert peaks.kv_bytes_per_token(m) == 112 * 1024
-    b = peaks.decode_step_min_bytes(m, [1000] * 32)
+        cfg = json.load(f)
+    family = families.load_family(cfg)
+    m = family.sizes(cfg)
+    assert family.layer_matmul_params(m) == 1_409_286_144
+    assert family.kv_bytes_per_token(m) == 112 * 1024
+    b = family.decode_step_min_bytes(m, [1000] * 32)
     assert b == (1_409_286_144 + 2048 * 151_936) * 2 + 32_000 * 112 * 1024

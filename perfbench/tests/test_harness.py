@@ -1,7 +1,8 @@
 """The whole run at a tiny size on the CPU, through the tests' entry
 (``run_cell(allow_cpu=True)``; the command itself refuses a CPU), from files
-that only this test adds: a configuration, two mixes, a cell file and a
-per-layer metric reader, none of which any file of the harness names."""
+that only this test adds: configurations, two mixes, cell files, per-layer
+metric readers and a model family, none of which any file of the harness
+names."""
 
 import json
 import sys
@@ -12,7 +13,7 @@ import pytest
 from perfbench import core
 
 TINY = {
-    "source": "test", "reduced": [], "chips": 1,
+    "family": "qwen3", "source": "test", "reduced": [], "chips": 1,
     "vocab_size": 4096, "hidden_size": 64, "num_hidden_layers": 2,
     "num_attention_heads": 8, "num_key_value_heads": 4, "head_dim": 8,
     "intermediate_size": 128, "rope_theta": 10000, "rms_norm_eps": 1e-6,
@@ -24,17 +25,193 @@ TINY = {
                         "prefill_chunk": 8, "n_blocks": 128,
                         "paged_attn": "fused"}},
 }
+# A second architecture, of the family below: no q/k norm, untied head, and a
+# first layer whose feed-forward is narrower than the others'.
+STEP = {
+    "family": "narrowfirst", "source": "test", "reduced": [], "chips": 1,
+    "vocab": 4096, "width": 64, "layers": 3, "heads": 8, "kv_heads": 4,
+    "head_width": 8, "ff": 128, "ff_first": 64, "theta": 10000, "eps": 1e-6,
+    "positions": 96, "dtype": "float32",
+    # the gather path (the fused kernel's oracle): a millisecond a step here,
+    # where the interpreted kernel takes a third of a second a layer
+    "serve": {**TINY["serve"],
+              "fleet": {**TINY["serve"]["fleet"], "paged_attn": "gather"}},
+}
+NARROWFIRST = '''
+"""A family that only a test adds: a decoder that is not the harness's own.
+No per-head norm on q and k, an untied head, and layer 0's feed-forward is
+narrower than the other layers', so a layer's weights depend on its index.
+The program serves it through its dense model class with the q/k norm off
+and layer 0's gate, up and down padded with zeros to the common width
+(silu(0) * 0 adds exactly nothing); the reference computes the narrow layer
+as it is."""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from perfbench.peaks import itemsize
+from perfbench.reference import attention, linear, rms_norm, rope
+from perfbench.weights import keys, norm_weight, randw
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    vocab_size: int
+    d_model: int
+    n_layers: int
+    max_length: int
+    dtype: str
+    heads: int
+    kv_heads: int
+    dh: int
+    ff: int
+    ff_first: int
+    theta: float
+    eps: float
+
+
+def sizes(cfg):
+    return Sizes(vocab_size=cfg["vocab"], d_model=cfg["width"],
+                 n_layers=cfg["layers"], max_length=cfg["positions"],
+                 dtype=cfg["dtype"], heads=cfg["heads"],
+                 kv_heads=cfg["kv_heads"], dh=cfg["head_width"],
+                 ff=cfg["ff"], ff_first=cfg["ff_first"],
+                 theta=float(cfg["theta"]), eps=float(cfg["eps"]))
+
+
+def ff_of(m, layer_index):
+    return m.ff_first if layer_index == 0 else m.ff
+
+
+def plain_layer(m, ff, key):
+    dt, d = jnp.dtype(m.dtype), m.d_model
+    ks = jax.random.split(key, 9)
+    return {"wq": randw(ks[0], (d, m.heads * m.dh), d, dt),
+            "wk": randw(ks[1], (d, m.kv_heads * m.dh), d, dt),
+            "wv": randw(ks[2], (d, m.kv_heads * m.dh), d, dt),
+            "wo": randw(ks[3], (m.heads * m.dh, d), m.heads * m.dh, dt),
+            "wg": randw(ks[4], (d, ff), d, dt),
+            "wu": randw(ks[5], (d, ff), d, dt),
+            "wd": randw(ks[6], (ff, d), ff, dt),
+            "norm1": norm_weight(ks[7], (d,)),
+            "norm2": norm_weight(ks[8], (d,))}
+
+
+_layer_weights = jax.jit(plain_layer, static_argnums=(0, 1))
+
+
+def layer_weights(m, key, layer_index):
+    return _layer_weights(m, ff_of(m, layer_index), key)
+
+
+def plain_globals(m, key):
+    dt = jnp.dtype(m.dtype)
+    ks = jax.random.split(key, 3)
+    return {"embed": randw(ks[0], (m.vocab_size, m.d_model), m.d_model, dt),
+            "final_norm": norm_weight(ks[1], (m.d_model,)),
+            "lm_head": randw(ks[2], (m.d_model, m.vocab_size), m.d_model, dt)}
+
+
+global_weights = jax.jit(plain_globals, static_argnums=0)
+
+
+def head_weights(m, g):
+    return {"final_norm": g["final_norm"], "head": g["lm_head"], "eps": m.eps}
+
+
+def program(cfg, m, seed, mesh, engine_kwargs):
+    from jax.sharding import NamedSharding
+
+    from triton_distributed_tpu.models.config import ModelConfig
+    from triton_distributed_tpu.models.qwen import Qwen3
+
+    mcfg = ModelConfig(
+        model_name=cfg["source"], vocab_size=m.vocab_size, d_model=m.d_model,
+        n_layers=m.n_layers, n_heads=m.heads, n_kv_heads=m.kv_heads,
+        head_dim=m.dh, d_ff=m.ff, rope_theta=m.theta, rms_eps=m.eps,
+        tie_embeddings=False, qk_norm=False, max_length=m.max_length,
+        dtype=jnp.dtype(m.dtype))
+    model = Qwen3(mcfg, block_n=engine_kwargs.get("block_n", 256))
+    world = mesh.shape[model.axis]
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             model.param_specs())
+    pad = m.ff - m.ff_first
+
+    @functools.partial(jax.jit, out_shardings=shardings)
+    def make(gkey, lkeys):
+        first = plain_layer(m, m.ff_first, lkeys[0])
+        first["wg"] = jnp.pad(first["wg"], ((0, 0), (0, pad)))
+        first["wu"] = jnp.pad(first["wu"], ((0, 0), (0, pad)))
+        first["wd"] = jnp.pad(first["wd"], ((0, pad), (0, 0)))
+        rest = jax.vmap(functools.partial(plain_layer, m, m.ff))(lkeys[1:])
+        lw = jax.tree.map(lambda a, b: jnp.concatenate([a[None], b]),
+                          first, rest)
+        g = plain_globals(m, gkey)
+        return {
+            "embed": g["embed"], "final_norm": g["final_norm"],
+            "lm_head": g["lm_head"],
+            "layers": {
+                "input_norm": lw["norm1"], "post_norm": lw["norm2"],
+                "attn": {"w_qkv": jax.vmap(lambda q, k, v: model.attn.pack_qkv(
+                    q, k, v, world))(lw["wq"], lw["wk"], lw["wv"]),
+                         "w_o": lw["wo"]},
+                "mlp": {"w_gate_up": jax.vmap(
+                    lambda a, b: model.mlp.interleave_gate_up(a, b, world))(
+                        lw["wg"], lw["wu"]),
+                        "w_down": lw["wd"]}}}
+
+    return mcfg, make(*keys(seed, m.n_layers))
+
+
+@functools.partial(jax.jit, static_argnames=("m", "ff", "precision"))
+def _layer_forward(h, lw, *, m, ff, precision):
+    assert lw["wg"].shape == (m.d_model, ff), "the layer's index decides"
+    S = h.shape[0]
+    pos = jnp.arange(S)
+    x = rms_norm(h, lw["norm1"], m.eps)
+    q = linear(x, lw["wq"], precision).reshape(S, m.heads, m.dh)
+    k = linear(x, lw["wk"], precision).reshape(S, m.kv_heads, m.dh)
+    v = linear(x, lw["wv"], precision).reshape(S, m.kv_heads, m.dh)
+    a = attention(rope(q, pos, m.theta), rope(k, pos, m.theta), v,
+                  m.dh ** -0.5)
+    h = h + linear(a, lw["wo"], precision)
+    x = rms_norm(h, lw["norm2"], m.eps)
+    act = jax.nn.silu(linear(x, lw["wg"], precision)) \\
+        * linear(x, lw["wu"], precision)
+    return h + linear(act, lw["wd"], precision)
+
+
+def layer_forward(h, lw, m, layer_index, precision):
+    return _layer_forward(h, lw, m=m, ff=ff_of(m, layer_index),
+                          precision=precision)
+
+
+def decode_step_min_bytes(m, context_lens):
+    attn = m.d_model * (m.heads + 2 * m.kv_heads) * m.dh \\
+        + m.heads * m.dh * m.d_model
+    mlp = 3 * m.d_model * sum(ff_of(m, i) for i in range(m.n_layers))
+    weights = m.n_layers * attn + mlp + m.d_model * m.vocab_size
+    kv = 2 * m.n_layers * m.kv_heads * m.dh
+    return (weights + kv * float(sum(context_lens))) * itemsize(m.dtype)
+'''
 LENGTHS = {"prompt": {"median": 12, "sigma": 0.5, "lo": 4, "hi": 30},
            "output": {"median": 24, "sigma": 0.3, "lo": 16, "hi": 40}}
 BENCH = {
     "command": ["python3", "perfbench/run.py"], "paths": ["perfbench"],
     "run_seconds": 2,
     "configs": [{"name": "tiny", "source": "test", "reduced": [], "why": "t",
-                 "file": "perfbench/configs/tiny.json"}],
+                 "file": "perfbench/configs/tiny.json"},
+                {"name": "step", "source": "test", "reduced": [], "why": "t",
+                 "file": "perfbench/configs/step.json"}],
     "workloads": [
         {"name": "tiny.open", "config": "tiny", "traffic": "open",
          "chips": 1, "why": "t"},
         {"name": "tiny.closed", "config": "tiny", "traffic": "closed",
+         "chips": 1, "why": "t"},
+        {"name": "step.open", "config": "step", "traffic": "open",
          "chips": 1, "why": "t"}],
     "end_to_end": [
         {"name": "ttft_mean_ms", "unit": "ms", "better": "lower",
@@ -54,7 +231,10 @@ BENCH = {
          "moves": "out_tokens_per_s"},
         {"name": "paged_attn_device_share", "unit": "%", "better": "lower",
          "source": "device_trace", "layer": "kernels",
-         "moves": "itl_p95_ms"}],
+         "moves": "itl_p95_ms"},
+        {"name": "floor_bytes", "unit": "bytes", "better": "lower",
+         "source": "program_counter", "layer": "kernels",
+         "moves": "out_tokens_per_s", "workloads": ["step.open"]}],
 }
 
 
@@ -62,10 +242,12 @@ BENCH = {
 def root(tmp_path_factory):
     """A checkout-shaped directory that holds only new files."""
     root = tmp_path_factory.mktemp("bench_root")
-    for sub in ("configs", "traffic", "cells", "layer_metrics"):
+    for sub in ("configs", "traffic", "cells", "layer_metrics", "families"):
         (root / "perfbench" / sub).mkdir(parents=True)
     (root / "BENCHMARK.json").write_text(json.dumps(BENCH))
     (root / "perfbench/configs/tiny.json").write_text(json.dumps(TINY))
+    (root / "perfbench/configs/step.json").write_text(json.dumps(STEP))
+    (root / "perfbench/families/narrowfirst.py").write_text(NARROWFIRST)
     (root / "perfbench/traffic/open.json").write_text(json.dumps(
         {"kind": "open_poisson", "drain_limit_s": 30, **LENGTHS}))
     (root / "perfbench/traffic/closed.json").write_text(json.dumps(
@@ -74,23 +256,35 @@ def root(tmp_path_factory):
          "output": {"median": 8, "sigma": 0.3, "lo": 4, "hi": 12}}))
     # float32 has no limits of its own in the harness: the cells state them
     limits = {"gap_max": 1e-3, "gap_mean": 1e-5}
+    rate = {"rate_rps": 4.0, "standing": {
+        "token_s": 0.02, "prefill_tokens_per_s": 200}}
     (root / "perfbench/cells/tiny.open.json").write_text(json.dumps(
-        {"traffic": {"rate_rps": 4.0, "standing": {
-            "token_s": 0.02, "prefill_tokens_per_s": 200}},
-         "limits": limits}))
+        {"traffic": rate, "limits": limits}))
     (root / "perfbench/cells/tiny.closed.json").write_text(json.dumps(
         {"limits": limits}))
+    (root / "perfbench/cells/step.open.json").write_text(json.dumps(
+        {"traffic": rate, "limits": limits, "sample": {"requests": 2}}))
     (root / "perfbench/layer_metrics/steps_counted.py").write_text(
         textwrap.dedent("""
         def read(rec):
             return rec.counters["decode_steps"] + rec.counters["prefill_steps"]
         """))
-    from perfbench import layer_metrics
+    (root / "perfbench/layer_metrics/floor_bytes.py").write_text(
+        textwrap.dedent("""
+        def read(rec):
+            return rec.family.decode_step_min_bytes(
+                rec.sizes, [s[5] for s in rec.steps if s[2] == "decode"])
+        """))
+    from perfbench import families, layer_metrics
 
     layer_metrics.__path__.append(str(root / "perfbench/layer_metrics"))
+    families.__path__.append(str(root / "perfbench/families"))
     yield str(root)
     layer_metrics.__path__.pop()
-    sys.modules.pop("perfbench.layer_metrics.steps_counted", None)
+    families.__path__.pop()
+    for name in ("layer_metrics.steps_counted", "layer_metrics.floor_bytes",
+                 "families.narrowfirst"):
+        sys.modules.pop("perfbench." + name, None)
 
 
 @pytest.fixture
@@ -124,6 +318,10 @@ def test_a_cell_its_rate_and_its_metrics_come_from_files(root):
         "ttft_mean_ms", "itl_p95_ms", "out_tokens_per_s", "setup_s"]
     closed = core.load_cell("tiny.closed", root)
     assert "ttft_mean_ms" not in [m["name"] for m in closed["end_to_end"]]
+    # what the reference reads: the defaults, a cell file's "sample" over them
+    assert spec["sample"] == closed["sample"] == core.SAMPLE
+    assert core.load_cell("step.open", root)["sample"] == {
+        **core.SAMPLE, "requests": 2}
     with pytest.raises(core.BenchFailure):
         core.load_cell("tiny.none", root)
 
@@ -136,9 +334,12 @@ def test_open_loop_run_reports_the_contract_keys_and_fails_the_control(
     the program's own tokens pass."""
     res = core.run_cell("tiny.open", 2 ** 31 + 11, 2.0, 0, root=root,
                         allow_cpu=True, control=True)
-    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
-    assert set(res) == {"correct", "attempted", "failed", "metrics", "device",
-                        "check"}
+    printed = capsys.readouterr()
+    lines = [json.loads(x) for x in printed.out.splitlines()]
+    assert list(res) == ["correct", "attempted", "failed", "metrics", "device",
+                         "check"]             # the numbers compared come last
+    assert [x.split()[2::2] for x in printed.err.splitlines()[-2:]] == [
+        ["gap_max", "limit"], ["gap_mean", "limit"]]
     assert res["correct"] is True and res["failed"] == 0
     standing = next(x for x in lines if x["phase"] == "standing")
     window = next(x for x in lines if x["phase"] == "window")
@@ -184,6 +385,67 @@ def test_closed_loop_run_with_a_token_altered_where_it_is_produced(
     assert res["metrics"]["out_tokens_per_s"]["value"] > 0
 
 
+def test_a_family_no_harness_file_names_runs_from_new_files_alone(
+        root, capsys, restore_compile_cache_config):
+    """A configuration of another architecture is files only: its family
+    (found by the ``family`` of its file), its configuration and its cell
+    file. The whole run comes out correct against the family's own plain
+    layers (layer 0 narrower than the rest), its float8 control does not,
+    and the reference reads as many requests as the cell's file says."""
+    import os
+
+    from perfbench import families
+
+    here = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "families")
+    assert "narrowfirst" in families.known()
+    assert not os.path.exists(os.path.join(here, "narrowfirst.py"))
+    res = core.run_cell("step.open", 2 ** 31 + 5, 2.0, 0, root=root,
+                        allow_cpu=True, control=True)
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    build = next(x for x in lines if x["phase"] == "build")
+    assert (build["n_layers"], build["d_model"], build["vocab"]) == (3, 64, 4096)
+    assert res["correct"] is True and res["failed"] == 0
+    verdict = res["check"]
+    assert verdict["requests"] == 2 and verdict["tokens"] >= 32
+    assert verdict["compared"]["gap_max"] <= verdict["limits"]["gap_max"]
+    assert verdict["control_correct"] is False
+    assert verdict["control"]["gap_max"] > 3 * verdict["limits"]["gap_max"]
+    assert res["metrics"]["out_tokens_per_s"]["value"] > 0
+
+
+def test_a_reader_reaches_the_familys_counts_through_the_records(root):
+    import importlib
+
+    from perfbench import families
+
+    spec = core.load_cell("step.open", root)
+    family = families.load_family(spec["config"])
+    sizes = family.sizes(spec["config"])
+    rec = core.Records(
+        t_open=0.0, t_close=1.0, t_end=1.0, setup_s=1.0, tracked=[],
+        kv_live=[], steps=[(0.1, 0.2, "decode", 2, 2, 30)], counters={},
+        queue_wait_s=[], sizes=sizes, family=family, n_slots=4, n_chips=1,
+        device_kind="cpu")
+    assert "floor_bytes" in [m["name"] for m in spec["per_layer"]]
+    mod = importlib.import_module(core.reader_module("layer_metrics",
+                                                     "floor_bytes"))
+    # 3 layers of attention (64 x (8 + 2 x 4) x 8 + 8 x 8 x 64), feed-forward
+    # widths 64, 128, 128, the head, and 2 x 3 x 4 x 8 a token of context
+    params = 3 * (64 * 16 * 8 + 64 * 64) + 3 * 64 * (64 + 128 + 128) \
+        + 64 * 4096
+    assert mod.read(rec) == (params + 192 * 30) * 4
+
+
+def test_a_run_of_a_configuration_without_a_family_fails_with_the_list(root):
+    import time
+
+    spec = core.load_cell("tiny.closed", root)
+    spec["config"] = {**spec["config"], "family": None}
+    with pytest.raises(core.BenchFailure, match="narrowfirst.*qwen3"):
+        core.set_up(spec, 1, t_start=time.monotonic(), allow_cpu=True)
+
+
 def test_per_layer_readers_are_found_by_name_and_may_find_nothing(root):
     """A traced run's metrics are the cell's per-layer metrics: a reader
     added as a file is used, and one that finds no trace is left out."""
@@ -195,7 +457,7 @@ def test_per_layer_readers_are_found_by_name_and_may_find_nothing(root):
         t_end=1.0, setup_s=1.0, tracked=[], kv_live=[],
         steps=[(0.1, 0.2, "decode", 2, 2, 30)],
         counters={"decode_steps": 1.0, "prefill_steps": 2.0},
-        queue_wait_s=[], sizes=None, n_slots=4, n_chips=1,
+        queue_wait_s=[], sizes=None, family=None, n_slots=4, n_chips=1,
         device_kind="cpu")
     got = {}
     for m in spec["per_layer"]:

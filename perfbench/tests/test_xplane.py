@@ -80,18 +80,20 @@ def records(trace, n_chips):
     import json
     import os
 
-    from perfbench import weights
+    from perfbench import families
 
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(here, "configs", "qwen3-1.7b.json")) as f:
-        sizes = weights.ModelSizes.from_hf(json.load(f))
+        cfg = json.load(f)
+    family = families.load_family(cfg)
     return core.Records(
         t_open=0.0, t_close=1.0, t_end=1.0,
         setup_s=1.0, tracked=[], kv_live=[], counters={},
         steps=[(0.10, 0.11, "decode", 32, 32, 32 * 1000),
                (0.12, 0.13, "decode", 32, 32, 32 * 1000),
                (0.90, 0.91, "decode", 32, 32, 32 * 4000)],   # outside the span
-        queue_wait_s=[], sizes=sizes, n_slots=32, n_chips=n_chips,
+        queue_wait_s=[], sizes=family.sizes(cfg), family=family, n_slots=32,
+        n_chips=n_chips,
         device_kind="TPU v5 lite", trace=trace)
 
 

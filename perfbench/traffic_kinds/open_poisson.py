@@ -12,7 +12,10 @@ the longest request lives; a request that arrived ``a`` seconds before is
 caught part-way, as it would stand after ``a`` seconds of service at the
 stated pace (``prefill_tokens_per_s``, then a token every ``token_s``):
 what it would have generated is already in its prompt, the rest is what it
-asks for, and one that would have finished is left out."""
+asks for, and one that would have finished is left out. That population is
+the same for every seed (its sizes are dealt once, by ``STANDING_DEAL``; the
+seed gives its token ids): dealt by the seed, how much context the window
+opened on was the seed's doing, and the waits of the window followed it."""
 
 from __future__ import annotations
 
@@ -20,26 +23,29 @@ from perfbench import lengths
 from perfbench.traffic_kinds import Planned
 
 
+STANDING_DEAL = 0      # the one deal of every seed's standing population
+
+
 class Plan:
     def __init__(self, params, *, seed, seconds, vocab, max_total, n_slots):
         self.rate = float(params["rate_rps"])
-        self._params, self._seed = params, seed
+        self._params = params
         self._vocab, self._max_total = vocab, max_total
         self._ids = lengths.rng_for(seed, 4)
         self.requests = [
             Planned(t, lengths.token_ids(self._ids, p, vocab), o)
-            for t, p, o in self._arrivals(seconds, stream=0)]
+            for t, p, o in self._arrivals(seconds, seed, stream=0)]
         self._next = 0
 
-    def _arrivals(self, span_s, stream):
+    def _arrivals(self, span_s, seed, stream):
         """(time, prompt, output) of ``rate x span_s`` arrivals inside
-        (0, span_s), in order of time."""
+        (0, span_s), in order of time, dealt by ``seed``."""
         params = self._params
         n = max(1, round(self.rate * span_s))
 
         def deal(values, k):
-            return lengths.dealt(values, lengths.rng_for(
-                self._seed, 10 * stream + k))
+            return lengths.dealt(values, lengths.rng_for(seed,
+                                                         10 * stream + k))
 
         prompts = deal(lengths.lognormal_quantiles(n, **params["prompt"]), 1)
         outputs = deal(lengths.lognormal_quantiles(n, **params["output"]), 2)
@@ -60,7 +66,7 @@ class Plan:
         lead_s = (self._params["prompt"]["hi"] / prefill
                   + self._params["output"]["hi"] * token_s)
         out = []
-        for t, p, o in self._arrivals(lead_s, stream=1):
+        for t, p, o in self._arrivals(lead_s, STANDING_DEAL, stream=1):
             served_s = lead_s - t - p / prefill
             given = max(0, int(served_s / token_s))
             if given < o:
