@@ -156,3 +156,62 @@ def test_oneshot_allreduce_compiles_tp4(tp4):
         lambda x: oneshot_all_reduce(x[0], axis="tp", interpret=False)[None],
         (P("tp", None, None),), P("tp", None, None), (4, SLOTS, D8))
     assert "tpu_custom_call" in text
+
+
+# JoyAI-LLM-Flash's cell (perfbench/configs/joyai-llm-flash-ep16.json): 32
+# slots, a 3,328-block pool of 40 stacked layers, latent rows of 576 padded
+# to 640, 32 query heads on the one shared key head.
+LAT_SLOTS, LAT_BLOCKS, LAT_LAYERS, LAT_ROW, LAT_V, LAT_HEADS = \
+    32, 3328, 40, 640, 512, 32
+
+
+@pytest.mark.parametrize("L", [1, CHUNK], ids=["decode", "chunk"])
+def test_latent_paged_attention_compiles(one_chip, L):
+    from triton_distributed_tpu.kernels.paged_attention import (
+        paged_attention,
+    )
+
+    def fn(q, arena, tables, kv_lens, q_lens, layer):
+        return paged_attention(q, arena, None, tables, kv_lens, q_lens=q_lens,
+                               interpret=False, layer=layer, v_dim=LAT_V,
+                               scale=192 ** -0.5)
+
+    compiled = jax.jit(fn).lower(
+        _sds((LAT_SLOTS, L, LAT_HEADS, LAT_ROW), jnp.bfloat16, one_chip),
+        _sds((LAT_LAYERS, LAT_BLOCKS, BLOCK, LAT_ROW), jnp.bfloat16, one_chip),
+        _sds((LAT_SLOTS, MAX_BLOCKS), jnp.int32, one_chip),
+        _sds((LAT_SLOTS,), jnp.int32, one_chip),
+        _sds((LAT_SLOTS,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_paged_attention" in text
+
+
+@pytest.mark.parametrize("tiles,rows", [(32, 16), (144, 128)],
+                         ids=["decode-tiles", "chunk-tiles"])
+@pytest.mark.parametrize("d,f", [(2048, 1536), (768, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_product_over_expert_tiles_compiles(one_chip, monkeypatch,
+                                                    tiles, rows, d, f):
+    """The routed experts' product at the cell's sizes: tiles of rows sorted
+    by expert against 16 held experts of 39 stacked layers. The wrapper asks
+    ``on_tpu()`` before it hands Mosaic a kernel; the test answers for the
+    described chip."""
+    from triton_distributed_tpu.kernels import moe_utils
+    from triton_distributed_tpu.runtime import platform
+
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+
+    def fn(x, w, live, group_of, layer):
+        return moe_utils.grouped_gemm_skip(
+            x, w, live, layer_idx=layer, interpret=False, group_of=group_of,
+            name="moe_grouped_gemm")
+
+    compiled = jax.jit(fn).lower(
+        _sds((tiles, rows, d), jnp.bfloat16, one_chip),
+        _sds((39, 16, d, f), jnp.bfloat16, one_chip),
+        _sds((tiles,), jnp.int32, one_chip),
+        _sds((tiles,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_gemm" in text

@@ -251,7 +251,8 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
                        n_tiles: int, tile_blocks: int, bs: int,
                        n_blocks: int, scale: float, n_kv: int, g: int,
                        q_tile: int, n_q_tiles: int, probe=_probes.NULL,
-                       ks_ref=None, vs_ref=None, ks_buf=None, vs_buf=None):
+                       ks_ref=None, vs_ref=None, ks_buf=None, vs_buf=None,
+                       v_dim: int | None = None):
     """One (slot, query-tile, block-tile) grid step of fused paged
     attention.
 
@@ -276,7 +277,16 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
     f32 multiplied by the staged scale column — so HBM only ever moves
     wire bytes while the streaming-softmax math below stays the exact f32
     accumulation of the unquantized build.
+
+    LATENT pools (``v_dim`` given; ``vp_ref``/``v_buf`` are None): ONE arena
+    ``(n_layers, n_blocks, bs, W)`` of rows shared by every query head
+    (absorbed latent attention is multi-query attention with one key head).
+    Each block is DMA'd ONCE and used twice: the whole staged row is the
+    key, its first ``v_dim`` columns are the value. A tile's block copies
+    are all started before the first is waited for, and the two dots take
+    the rows in the pool's dtype with float32 accumulation.
     """
+    latent = v_dim is not None
     b = pl.program_id(0)
     qt = pl.program_id(1)
     t = pl.program_id(2)
@@ -302,10 +312,24 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
 
     @pl.when((base < limit) & (qt * q_tile < q_len))
     def _work():
+        def block_copy(i):
+            blk = jnp.clip(tbl_ref[b, t * tile_blocks + i], 0, n_blocks - 1)
+            return pltpu.make_async_copy(kp_ref.at[layer, blk],
+                                         k_buf.at[pl.ds(i * bs, bs)],
+                                         sems.at[0])
+
         # In-kernel block walk: the gather, without the materialized view.
+        # The latent build starts all of a tile's copies, then waits.
+        for i in range(tile_blocks if latent else 0):
+            @pl.when(base + i * bs < limit)
+            def _start(i=i):
+                block_copy(i).start()
         for i in range(tile_blocks):
             @pl.when(base + i * bs < limit)
             def _fetch(i=i):
+                if latent:
+                    block_copy(i).wait()
+                    return
                 # Same defensive clamp as the gather path's mode="clip".
                 blk = jnp.clip(tbl_ref[b, t * tile_blocks + i], 0,
                                n_blocks - 1)
@@ -333,11 +357,17 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
         row_live = row_pos < limit                           # (T*bs, 1) bool
 
         for h in range(n_kv):
-            # f32 casts deliberate — see _flash_decode_kernel: bf16 g-row
-            # sub-tiles hit Mosaic's relayout path and measured slower.
-            q = q_ref[0, h].astype(jnp.float32)              # (q_tile*g, dh)
-            k = k_buf[:, h, :].astype(jnp.float32)           # (T*bs, dh)
-            v = v_buf[:, h, :].astype(jnp.float32)
+            if latent:
+                q = q_ref[0, 0]                              # (q_tile*g, W)
+                k = k_buf[...]                               # (T*bs, W)
+                v = k_buf[:, :v_dim]
+            else:
+                # f32 casts deliberate — see _flash_decode_kernel: bf16
+                # g-row sub-tiles hit Mosaic's relayout path and measured
+                # slower.
+                q = q_ref[0, h].astype(jnp.float32)          # (q_tile*g, dh)
+                k = k_buf[:, h, :].astype(jnp.float32)       # (T*bs, dh)
+                v = v_buf[:, h, :].astype(jnp.float32)
             if ks_buf is not None:
                 # In-staging dequant: one f32 scale per staged (row, kv
                 # head), broadcast over head_dim. Stale (unfetched) rows'
@@ -347,9 +377,10 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
                 k = k * ks_buf[:, h:h + 1]
                 v = v * vs_buf[:, h:h + 1]
             # where, not multiply: 0 * NaN is still NaN.
-            v = jnp.where(row_live, v, 0.0)
+            v = jnp.where(row_live, v, jnp.zeros_like(v))
             scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ()))) * scale      # (q_tile*g, T*bs)
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (q_tile*g, T*bs)
             pos = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
             # Row r of the q block is query token j = qt*q_tile + r//g (the
             # g query heads of one token share a kv head group); it may
@@ -368,7 +399,8 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
             p = jnp.exp(scores - new_max) * valid.astype(jnp.float32)
             l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())))              # (q_tile*g, dh)
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # (q_tile*g, dh)
             m_ref[h] = new_max
         # QK^T + PV dots over the staged rows, all kv heads this tile.
         probe.compute(4 * n_kv * (q_ref.shape[2]) * tile_blocks * bs
@@ -409,10 +441,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                     tile_blocks: int | None = None,
                     q_tile: int | None = None, interpret=None,
                     probes: bool = False, k_scale=None, v_scale=None,
-                    layer=None):
+                    layer=None, v_dim: int | None = None):
     """GQA attention of an L-token query block per slot directly over a
     block-paged KV pool — decode (L=1), chunked prefill, and ragged mixed
     steps all through ONE kernel.
+
+    LATENT form (``v_pool=None`` with ``v_dim``): ``k_pool`` is the one
+    latent arena ``(n_blocks, block_size, W)`` — stacked ``(n_layers,
+    n_blocks, block_size, W)`` with ``layer`` — whose rows every query head
+    shares: the keys are the whole rows, the values their first ``v_dim``
+    columns, so each block is read once and used as both. q is
+    ``(B, L, Hq, W)`` and the result ``(B, L, Hq, v_dim)``. The call is
+    named ``latent_paged_attention`` in a device trace.
 
     q:            (B, L, Hq, dh) new (rope'd) query rows per slot; the new
                   tokens' K/V are already in the pool
@@ -471,7 +511,27 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     quant = k_scale is not None
     if quant != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be given together")
-    if k_pool.ndim == 4:
+    latent = v_pool is None
+    if latent != (v_dim is not None):
+        raise ValueError("v_dim goes with a latent pool (v_pool=None) and "
+                         "only with it")
+    if latent:
+        if quant or probes:
+            raise NotImplementedError("the latent pool has no quantized "
+                                      "and no probed build")
+        # One key head, shared by every query head; the arena keeps its
+        # rank (a unit head axis would change its tiled layout).
+        if k_pool.ndim == 3:
+            if layer is not None:
+                raise ValueError("layer indexes a stacked arena; this "
+                                 "latent pool is one layer")
+            layer, k_pool = 0, k_pool[None]
+        elif layer is None:
+            raise ValueError("a stacked latent arena needs the layer to "
+                             "read")
+        _, n_blocks, bs, _ = k_pool.shape
+        Hkv = 1
+    elif k_pool.ndim == 4:
         if layer is not None:
             raise ValueError("layer indexes a stacked (n_layers, n_blocks, "
                              "...) arena; this pool is one layer")
@@ -482,7 +542,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     elif layer is None:
         raise ValueError("a stacked (n_layers, n_blocks, ...) arena needs "
                          "the layer to read")
-    _, n_blocks, bs, Hkv, _ = k_pool.shape
+    if not latent:
+        _, n_blocks, bs, Hkv, _ = k_pool.shape
+    if k_pool.shape[-1] != dh:
+        raise ValueError(f"pool rows are {k_pool.shape[-1]} wide, queries "
+                         f"{dh}")
     if Hq % Hkv:
         raise ValueError(f"q heads {Hq} not divisible by kv heads {Hkv}")
     if block_tables.dtype != jnp.int32:
@@ -541,6 +605,16 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                                tile_blocks=tile_blocks, bs=bs,
                                n_blocks=n_blocks, scale=scale, n_kv=Hkv,
                                g=g, q_tile=q_tile, n_q_tiles=n_q_tiles)
+    dv = v_dim if latent else dh          # width of a value row
+    if latent:
+        # Positional wrapper: no V pool and no V staging in this build.
+        base_kernel = functools.partial(kernel, v_dim=v_dim)
+
+        def kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
+                   o_ref, k_buf, acc_ref, m_ref, l_ref, sems):
+            base_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref,
+                        kp_ref, None, o_ref, k_buf, None, acc_ref, m_ref,
+                        l_ref, sems)
     if quant:
         # Positional wrapper: the quantized pallas_call passes the scale
         # pools after V and the scale staging after v_buf; the base kernel
@@ -555,16 +629,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                         l_ref, sems, ks_ref=ks_ref, vs_ref=vs_ref,
                         ks_buf=ks_buf, vs_buf=vs_buf, **kw)
 
-    out_specs = pl.BlockSpec((1, Hkv, rows, dh),
+    out_specs = pl.BlockSpec((1, Hkv, rows, dv),
                              lambda b, qt, t, tbl, kl, ql, ly: (b, 0, qt, 0))
-    out_shape = jax.ShapeDtypeStruct((B, Hkv, L_pad * g, dh), jnp.float32)
+    out_shape = jax.ShapeDtypeStruct((B, Hkv, L_pad * g, dv), jnp.float32)
     scratch_shapes = [
-        pltpu.VMEM((tile_blocks * bs, Hkv, dh), k_pool.dtype),  # k stage
-        pltpu.VMEM((tile_blocks * bs, Hkv, dh), v_pool.dtype),  # v stage
+        *([pltpu.VMEM((tile_blocks * bs, dh), k_pool.dtype)]    # row stage
+          if latent else
+          [pltpu.VMEM((tile_blocks * bs, Hkv, dh), k_pool.dtype),   # k stage
+           pltpu.VMEM((tile_blocks * bs, Hkv, dh), v_pool.dtype)]), # v stage
         *([pltpu.VMEM((tile_blocks * bs, Hkv), jnp.float32),    # k scales
            pltpu.VMEM((tile_blocks * bs, Hkv), jnp.float32)]    # v scales
           if quant else []),
-        pltpu.VMEM((Hkv, rows, dh), jnp.float32),   # acc
+        pltpu.VMEM((Hkv, rows, dv), jnp.float32),   # acc
         pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # running max
         pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # denominator
         common.dma_sems(4 if quant else 2),
@@ -607,7 +683,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
             pl.BlockSpec((1, Hkv, rows, dh),
                          lambda b, qt, t, tbl, kl, ql, ly: (b, 0, qt, 0)),
             common.any_spec(),     # k arena: manual per-(layer, block) DMA
-            common.any_spec(),     # v pool
+            *([] if latent else [common.any_spec()]),           # v pool
             *([common.any_spec(),  # k scale pool (quantized build)
                common.any_spec()]  # v scale pool
               if quant else []),
@@ -615,7 +691,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    operands = (block_tables, kv_lens, q_lens, layer, qh, k_pool, v_pool)
+    operands = (block_tables, kv_lens, q_lens, layer, qh, k_pool)
+    if not latent:
+        operands += (v_pool,)
     if quant:
         operands += (k_scale, v_scale)
     outs = pl.pallas_call(
@@ -631,10 +709,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
             kv_itemsize=k_pool.dtype.itemsize, kv_scales=quant,
             L=L, q_tile=q_tile),
         interpret=resolve_interpret(interpret),
+        name="latent_paged_attention" if latent else None,
     )(*operands)
     o = outs[0] if probes else outs
-    o = o.reshape(B, Hkv, L_pad, g, dh).transpose(0, 2, 1, 3, 4)
-    o = o.reshape(B, L_pad, Hq, dh)[:, :L].astype(q.dtype)
+    o = o.reshape(B, Hkv, L_pad, g, dv).transpose(0, 2, 1, 3, 4)
+    o = o.reshape(B, L_pad, Hq, dv)[:, :L].astype(q.dtype)
     if probes:
         return o, outs[1]
     return o

@@ -246,3 +246,116 @@ def _build_fwd(layer: MoEMLP, mesh: Mesh, mode: str, interpret):
             check_vma=False,
         )
     )
+
+
+def swiglu(x, w_gate_up, w_down):
+    """Dense gated SwiGLU, halves concatenated: ``w_gate_up`` (d, 2*ff)."""
+    h = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
+    ff = h.shape[-1] // 2
+    act = (jax.nn.silu(h[..., :ff]) * h[..., ff:]).astype(x.dtype)
+    return jnp.dot(act, w_down,
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+#: What ``HeldExpertsMoE.fwd`` counts on the device, in this order.
+MOE_STATS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
+             "moe_dropped_pairs")
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldExpertsMoE:
+    """The DeepSeek-V3 expert layer as ONE chip of a wide expert-parallel
+    deployment sees it: the router keeps its published width, this device
+    holds the routed experts ``[lo, lo + n_held)`` and computes the part of
+    the result they give; what the absent experts would add is left out
+    (their chips would add it), and no code stands in for them or for the
+    exchange. A shared expert (every chip computes it alike) is added whole.
+
+    Routing (HF ``DeepseekV3TopkRouter``, ``noaux_tc`` with one group):
+    ``s = sigmoid(x @ router)`` in float32 over ALL experts; the ``topk``
+    largest of ``s + bias`` are chosen; their weights are the UNBIASED
+    scores, normalised over all chosen (``norm_topk_prob``), times
+    ``routed_scaling``.
+
+    No pair is dropped at any routing: the held pairs are sorted by expert
+    into a buffer that has room for every pair the tokens could route here
+    (``moe_utils.rows_by_expert``), and the two grouped products walk its
+    tiles, so the work follows the pairs routed.
+
+    Parameters: ``router`` (d, n_experts) f32, ``bias`` (n_experts,) f32,
+    ``w_gate_up`` (n_held, d, 2*d_ff) / ``w_down`` (n_held, d_ff, d) — or
+    layer-stacked with ``layer_idx``, as ``grouped_gemm_skip`` wants them
+    under a scan — and ``shared`` {``w_gate_up`` (d, 2*ff_s), ``w_down``}.
+    """
+
+    d_model: int
+    d_ff: int                  # per routed expert
+    n_experts: int             # the router's width
+    topk: int
+    n_held: int
+    lo: int = 0
+    routed_scaling: float = 1.0
+    norm_topk_prob: bool = True
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def route(self, router, bias, x):
+        """x (n, d) -> (weights (n, k) f32, ids (n, k) int32). The scores
+        are float32 in earnest: ``HIGHEST`` keeps the chip from taking the
+        float32 product in one bfloat16 pass, which moves near-tied scores
+        across the top-k boundary."""
+        s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                   router.astype(jnp.float32),
+                                   precision=jax.lax.Precision.HIGHEST))
+        _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), self.topk)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        if self.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return w * self.routed_scaling, ids.astype(jnp.int32)
+
+    def routed(self, params, x, valid=None, *, layer_idx=None,
+               interpret=None):
+        """The held experts' part of the result for x (n, d), and the
+        counts ``MOE_STATS`` over the valid tokens (``valid`` (n,) bool:
+        padding rows route nowhere and cost nothing). x may come in
+        float32 (a float32 residual stream): the router reads it as it is,
+        the experts read it in the layer's dtype."""
+        n = x.shape[0]
+        w, ids = self.route(params["router"], params["bias"], x)
+        x = x.astype(self.dtype)
+        live = jnp.ones((n, 1), bool) if valid is None else valid[:, None]
+        local = ids - self.lo
+        held = (local >= 0) & (local < self.n_held) & live
+        tile = 16 if n * self.topk <= 1024 else 128
+        row_of_pair, pair_of_row, tile_expert, n_tiles, counts = \
+            moe_utils.rows_by_expert(local, held, n_experts=self.n_held,
+                                     tile=tile)
+        R = pair_of_row.shape[0]
+        # An empty row's pair index is n * topk: out of bounds, filled 0.
+        rows = x.at[pair_of_row // self.topk].get(
+            mode="fill", fill_value=0).reshape(R // tile, tile, -1)
+        tile_live = (jnp.arange(R // tile) < n_tiles).astype(jnp.int32)
+        kw = dict(layer_idx=layer_idx, interpret=interpret,
+                  group_of=tile_expert, name="moe_grouped_gemm")
+        h = moe_utils.grouped_gemm_skip(rows, params["w_gate_up"], tile_live,
+                                        **kw)
+        ff = h.shape[-1] // 2
+        act = (jax.nn.silu(h[..., :ff].astype(jnp.float32))
+               * h[..., ff:].astype(jnp.float32)).astype(h.dtype)
+        out = moe_utils.grouped_gemm_skip(act, params["w_down"], tile_live,
+                                          **kw).reshape(R, -1)
+        pair_out = out.at[row_of_pair].get(                     # (n, k, d)
+            mode="fill", fill_value=0).astype(jnp.float32)
+        y = jnp.sum(pair_out * jnp.where(held, w, 0.0)[..., None], axis=1)
+        n_held = jnp.sum(held)
+        stats = jnp.stack([
+            jnp.sum(live) * self.topk, n_held, jnp.sum(counts > 0),
+            n_held - jnp.sum(pair_of_row < n * self.topk)]).astype(jnp.int32)
+        return y.astype(x.dtype), stats
+
+    def fwd(self, params, x, valid=None, *, layer_idx=None, interpret=None):
+        """x (n, d) -> (shared(x) + the held experts' part, stats)."""
+        y, stats = self.routed(params, x, valid, layer_idx=layer_idx,
+                               interpret=interpret)
+        sh = params["shared"]
+        return y + swiglu(x.astype(self.dtype), sh["w_gate_up"],
+                          sh["w_down"]), stats

@@ -388,6 +388,67 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
                            seq_lens=seq_lens, interpret=interpret)
 
 
+def latent_attn_with_cache(q, pool, block_tables, offset, *, v_dim: int,
+                           scale: float, slot_mask=None, seq_lens=None,
+                           interpret=None, paged_attn: str = "fused",
+                           layer=None):
+    """Absorbed latent attention of new queries against a block-paged
+    LATENT pool — multi-query attention whose one key head is the pool's
+    row and whose values are that row's first ``v_dim`` columns.
+
+    q: (B, L, Hq, W) (latent queries, then the rotated part, zero-padded
+    like the rows); pool: (n_blocks, block_size, W), or the stacked arena
+    with ``layer``; the new tokens' rows are already in it. -> (B, L, Hq,
+    v_dim). ``paged_attn="fused"`` walks the block table inside
+    ``kernels.paged_attention`` (each block read once, used as keys and as
+    values); ``"gather"`` is the materialised-view oracle in plain jnp,
+    float32 scores. Offsets, ``seq_lens`` and ``slot_mask`` as in
+    ``paged_attn_with_cache``; padding query rows give zeros."""
+    if paged_attn not in ("fused", "gather"):
+        raise ValueError(
+            f"paged_attn must be 'fused' or 'gather', got {paged_attn!r}")
+    B, L = q.shape[:2]
+    off = jnp.broadcast_to(jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
+    q_lens = (jnp.full((B,), L, jnp.int32) if seq_lens is None
+              else jnp.asarray(seq_lens, jnp.int32))
+    if paged_attn == "fused":
+        from triton_distributed_tpu.kernels.paged_attention import (
+            paged_attention,
+        )
+
+        return paged_attention(q, pool, None, block_tables, off + q_lens,
+                               q_lens=q_lens, slot_mask=slot_mask,
+                               scale=scale, interpret=interpret, layer=layer,
+                               v_dim=v_dim)
+    from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
+
+    if layer is not None:
+        pool = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    rows = paged_gather_kv(pool, block_tables, slot_mask=slot_mask)
+    scores = jnp.einsum("blhw,bsw->blhs", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    q_pos = off[:, None] + jnp.arange(L)                           # (B, L)
+    key_pos = jnp.arange(rows.shape[1])
+    live = (jnp.arange(L)[None] < q_lens[:, None])[..., None]      # (B, L, 1)
+    mask = (key_pos[None, None] <= q_pos[..., None]) & live
+    scores = jnp.where(mask[:, :, None], scores, _NEG_INF)
+    p = jnp.where(live[:, :, None], jax.nn.softmax(scores, axis=-1), 0.0)
+    out = jnp.einsum("blhs,bsv->blhv", p, rows[..., :v_dim],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def apply_rope_interleaved(x, cos, sin):
+    """RoPE on interleaved pairs (``rope_interleave``): dims (2i, 2i+1)
+    rotate by the i-th angle. x: (..., L, H, dh); cos/sin: (..., L, dh//2).
+    """
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    c, s = cos[..., None, :], sin[..., None, :]
+    return jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
 def cache_update(cache, new, offset):
     """Write ``new`` (B, L, H, dh) into ``cache`` (B, S, H, dh) at ``offset``
     along the sequence dim. Functional: returns the new cache array.
@@ -409,7 +470,8 @@ def cache_update(cache, new, offset):
 def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
                        scale_pool=None, layer=None):
     """Write ``new`` (B, L, H, dh) into a block-paged KV pool layer
-    (n_blocks, block_size, H, dh) at per-slot positions — the
+    (n_blocks, block_size, H, dh) — or ``new`` (B, L, W) into a latent
+    pool layer (n_blocks, block_size, W) — at per-slot positions — the
     PagedAttention write: token (b, l) lands in block
     ``block_tables[b, (offsets[b] + l) // block_size]`` at line
     ``(offsets[b] + l) % block_size``. Functional: returns the new pool.
@@ -433,12 +495,13 @@ def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
     mask), so a KV row and its scale can never land in different blocks.
     Returns ``(pool, scale_pool)`` instead of ``pool``.
     """
-    if (layer is None) != (pool.ndim == 4):
+    if (layer is None) != (pool.ndim == new.ndim):
         raise ValueError(
-            f"layer goes with the stacked 5-D arena and only with it "
-            f"(pool rank {pool.ndim}, layer {layer!r})")
+            f"layer goes with the stacked arena (one rank above the rows') "
+            f"and only with it (pool rank {pool.ndim}, rows' {new.ndim}, "
+            f"layer {layer!r})")
     B, L = new.shape[:2]
-    n_blocks, bs = pool.shape[-4:-2]
+    n_blocks, bs = pool.shape[-new.ndim:][:2]
     pos = (jnp.asarray(offsets, jnp.int32)[:, None]
            + jnp.arange(L, dtype=jnp.int32)[None])                 # (B, L)
     slot = jnp.minimum(pos // bs, block_tables.shape[1] - 1)

@@ -3,9 +3,21 @@
 TPU-native analog of the reference's ``models/config.py`` (``ModelConfig``
 :31). The reference resolves architecture hyper-parameters from HuggingFace
 at load time; this framework runs with zero network egress, so the known
-Qwen3 architectures are recorded here as presets (the numbers are the public
-HF ``config.json`` values) and ``from_name`` resolves them. Loading real
-weights goes through ``Qwen3.load_hf`` with a local checkpoint path.
+architectures are recorded here as presets (the numbers are the public HF
+``config.json`` values) and ``from_name`` resolves them.
+
+One configuration class per decoder block, and ``Engine`` picks the model
+class from the class of the object it is given:
+
+- ``ModelConfig``: dense grouped-query decoders (Qwen3, Llama-3) and the
+  Qwen3-MoE block, run by ``models.qwen.Qwen3``. Loading real weights goes
+  through ``Qwen3.load_hf`` with a local checkpoint path.
+- ``DeepseekV3Config``: the DeepSeek-V3 block (latent attention over a
+  latent cache, sigmoid-routed experts with a shared expert, leading dense
+  layers), run by ``models.deepseek_v3.DeepseekV3``.
+
+Both state ``kv_row_shapes``: what one token's row of each arena of the
+paged pool looks like (``serving.kv_pool.KVPool`` builds the pool from it).
 """
 
 from __future__ import annotations
@@ -43,6 +55,13 @@ class ModelConfig:
     # drop-free serving of skewed routings (layers/moe_mlp.py capacities).
     moe_capacity_factor: float = 2.0
 
+    @property
+    def kv_row_shapes(self):
+        """One token's row in the paged pool's K arena and in its V arena:
+        per-head keys and values."""
+        row = (self.n_kv_heads, self.head_dim)
+        return row, row
+
     @classmethod
     def from_name(cls, name: str, **overrides) -> "ModelConfig":
         key = name.lower().removeprefix("qwen/").removeprefix("meta-llama/")
@@ -50,6 +69,92 @@ class ModelConfig:
             raise ValueError(
                 f"unknown model {name!r}; known: {sorted(_PRESETS)}")
         return cls(model_name=name, **{**_PRESETS[key], **overrides})
+
+
+LANE = 128      # a cache row is padded to a multiple of the chip's lane count
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV3Config:
+    """The DeepSeek-V3 decoder block (HF ``modeling_deepseek_v3``; HF key in
+    brackets). Defaults are JoyAI-LLM-Flash's public ``config.json``.
+
+    ``experts_held`` / ``experts_lo``: this device is one chip's share of a
+    wide expert-parallel deployment and holds the routed experts
+    ``[experts_lo, experts_lo + experts_held)`` of every expert layer. The
+    router keeps its published width ``n_experts`` and ``n_experts_per_tok``;
+    the layer computes the part of the result its own experts give (weights
+    normalised over all chosen experts) and leaves out what the absent ones
+    would add. ``None`` holds all of them.
+    """
+
+    model_name: str = "jdopensource/JoyAI-LLM-Flash"
+    vocab_size: int = 129_280
+    d_model: int = 2048                # hidden_size
+    n_layers: int = 40                 # num_hidden_layers
+    n_dense_layers: int = 1            # first_k_dense_replace
+    n_heads: int = 32                  # num_attention_heads
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    d_ff: int = 7168                   # intermediate_size (dense layers)
+    moe_d_ff: int = 768                # moe_intermediate_size
+    n_experts: int = 256               # n_routed_experts: the router's width
+    n_experts_per_tok: int = 8         # num_experts_per_tok
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    experts_held: int | None = None
+    experts_lo: int = 0
+    rope_theta: float = 32e6           # on interleaved pairs, no scaling
+    rms_eps: float = 1e-6
+    max_length: int = 4096
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def __post_init__(self):
+        held = self.n_held
+        if not (0 <= self.experts_lo and held >= 1
+                and self.experts_lo + held <= self.n_experts):
+            raise ValueError(
+                f"experts held [{self.experts_lo}, {self.experts_lo + held}) "
+                f"do not lie inside the router's {self.n_experts}")
+        if not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError("n_dense_layers must lie in [0, n_layers]")
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def cache_width(self) -> int:
+        """The latent cache row: normalised latent, then the rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_row(self) -> int:
+        """The row as the pool stores it: zero-padded to a lane multiple
+        (576 -> 640), so that every DMA and matmul of the latent kernel is
+        lane-aligned."""
+        return -(-self.cache_width // LANE) * LANE
+
+    @property
+    def kv_row_shapes(self):
+        """ONE latent arena (no V arena): keys are the whole row, values
+        its first ``kv_lora_rank`` columns."""
+        return (self.cache_row,), None
+
+    @classmethod
+    def tiny(cls, **overrides) -> "DeepseekV3Config":
+        """Tiny float32 sizes for tests (not a real checkpoint)."""
+        return cls(**{**dict(
+            model_name="tiny-deepseek-v3", vocab_size=128, d_model=64,
+            n_layers=3, n_heads=4, q_lora_rank=48, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            d_ff=96, moe_d_ff=32, n_experts=16, n_experts_per_tok=4,
+            rope_theta=1e4, max_length=64, dtype=jnp.float32), **overrides})
 
 
 # Public Qwen3 architecture hyper-parameters (HF config.json values).

@@ -23,7 +23,7 @@ from triton_distributed_tpu.runtime.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from triton_distributed_tpu.models.config import ModelConfig
+from triton_distributed_tpu.models.config import DeepseekV3Config, ModelConfig
 from triton_distributed_tpu.models.kv_cache import KVCache
 from triton_distributed_tpu.models.qwen import Qwen3
 from triton_distributed_tpu.models.sampling import sample_token
@@ -31,8 +31,19 @@ from triton_distributed_tpu.obs import trace as _trace
 from triton_distributed_tpu.runtime.mesh import get_default_mesh
 
 
+def model_for(config, *, block_n: int = 256):
+    """The model class that runs ``config``'s decoder block, picked by the
+    class of the configuration object."""
+    if isinstance(config, DeepseekV3Config):
+        from triton_distributed_tpu.models.deepseek_v3 import DeepseekV3
+
+        return DeepseekV3(config)
+    return Qwen3(config, block_n=block_n)
+
+
 class Engine:
-    def __init__(self, config: ModelConfig, *, mesh: Mesh | None = None,
+    def __init__(self, config: ModelConfig | DeepseekV3Config, *,
+                 mesh: Mesh | None = None,
                  mode: str = "dist", prefill_mode: str | None = None,
                  temperature: float = 0.0, top_p: float = 1.0,
                  params=None, key=None, hf_path: str | None = None,
@@ -45,7 +56,7 @@ class Engine:
         cutting engine cold-start (tools/compile_aot.py:470)."""
         self.config = config
         self.mesh = mesh or get_default_mesh()
-        self.model = Qwen3(config, block_n=block_n)
+        self.model = model_for(config, block_n=block_n)
         self.temperature = temperature
         self.top_p = top_p
         self.max_length = max_length or config.max_length
@@ -101,7 +112,7 @@ class Engine:
         (``KVCache.scale_spec``) — both as operands and as outputs, so
         the serving engine can donate them alongside the pools."""
         model = self.model
-        kspec, vspec, _ = KVCache.spec(model.axis)
+        kspec, vspec = model.cache_specs()
         sspec = KVCache.scale_spec(model.axis)
         if spec_verify and paged != "prefill":
             raise ValueError("spec_verify requires the paged='prefill' "
@@ -114,8 +125,10 @@ class Engine:
         if spec_verify:
             out_specs = (P(), P()) + kv_out
         else:
-            out_specs = ((P(),) + kv_out + (P(),) if moe_stats
-                         else (P(),) + kv_out)
+            # A model with ``step_stats`` returns its replicated counts
+            # after the pool of a paged step, as the drop audit does.
+            stats = moe_stats or (paged is not None and model.step_stats)
+            out_specs = (P(),) + kv_out + ((P(),) if stats else ())
         if paged is None:
             fwd = functools.partial(model.forward_device, mode=mode,
                                     interpret=self.interpret,

@@ -73,6 +73,27 @@ class Qwen3:
         return TPMLP(d_model=c.d_model, d_ff=c.d_ff, axis=self.axis,
                      dtype=c.dtype, block_n=self.block_n)
 
+    #: Device-side counts a paged step returns after the pool (none here).
+    step_stats = ()
+
+    def cache_specs(self):
+        """PartitionSpecs of the paged pool's (K, V) arenas."""
+        from triton_distributed_tpu.models.kv_cache import KVCache
+
+        return KVCache.spec(self.axis)[:2]
+
+    def step_flops(self, rows) -> float:
+        """The analytic cost of a step over ``rows`` of (new tokens, cache
+        length) (``obs/efficiency``'s ledger)."""
+        from triton_distributed_tpu.runtime import perf_model
+
+        return perf_model.step_flops(self.config, rows)
+
+    def step_hbm_bytes(self, rows, **kw) -> float:
+        from triton_distributed_tpu.runtime import perf_model
+
+        return perf_model.step_hbm_bytes(self.config, rows, **kw)
+
     # -- parameters ---------------------------------------------------------
 
     def param_specs(self):

@@ -69,7 +69,6 @@ from triton_distributed_tpu.obs.slo import (
 from triton_distributed_tpu.obs.trace import TailSampler
 from triton_distributed_tpu.resilience import faults as _faults
 from triton_distributed_tpu.resilience import guards as _guards
-from triton_distributed_tpu.runtime import perf_model as _pm
 from triton_distributed_tpu.serving.kv_pool import KVPool, PagedKVState
 from triton_distributed_tpu.serving.metrics import Metrics
 from triton_distributed_tpu.serving.prefix_cache import RadixPrefixCache
@@ -383,6 +382,15 @@ class BatchEngine:
         # Quantized pools grow each step by two donated scale-arena
         # operands/outputs right after the K/V pools — same fixed shapes,
         # so it is still exactly ONE trace per step kind.
+        #
+        # A model with ``step_stats`` (device-side counts of the step, a
+        # few int32) has them appended to ``nxt``, so they reach the host
+        # in the transfer that brings the tokens and cost no further sync
+        # (``_take_stats`` splits them off). A latent pool has no V arena:
+        # ``v`` is then None, an empty pytree, in and out.
+
+        def with_stats(nxt, stats):
+            return jnp.concatenate([nxt, *stats]) if stats else nxt
 
         if quant:
             @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5))
@@ -431,13 +439,13 @@ class BatchEngine:
             # one-compile-across-churn guarantee the tests assert on.
             trace_counts["decode"] += 1
             ids = jnp.clip(tok, 0, V - 1)[:, None]
-            logits, k, v = sm_dec(params, ids, k, v, offsets, block_tables,
-                                  slot_mask)
+            logits, k, v, *stats = sm_dec(params, ids, k, v, offsets,
+                                          block_tables, slot_mask)
             logits = logits + corrupt[:, None]
             finite = finite_logits_mask(logits)
             nxt = sample_token(logits, key, temperature=temperature,
                                top_p=top_p)
-            return nxt, finite, k, v
+            return with_stats(nxt, stats), finite, k, v
 
         @functools.partial(jax.jit, donate_argnums=(2, 3))
         def mixed_step(params, ids, k, v, offsets, block_tables, slot_mask,
@@ -449,12 +457,15 @@ class BatchEngine:
                                               block_tables, slot_mask,
                                               seq_lens)
             else:
-                logits, k, v = sm_pre(params, ids, k, v, offsets,
-                                      block_tables, slot_mask, seq_lens)
+                logits, k, v, *stats = sm_pre(params, ids, k, v, offsets,
+                                              block_tables, slot_mask,
+                                              seq_lens)
             logits = logits + corrupt[:, None]
             finite = finite_logits_mask(logits)
             nxt = sample_token(logits, key, temperature=temperature,
                                top_p=top_p)
+            if not spec:
+                nxt = with_stats(nxt, stats)
             # NaN injected at the last position (``corrupt``) only poisons
             # ``nxt``; a REAL non-finite at an interior verify position
             # propagates through causal attention to the last position, so
@@ -1565,17 +1576,27 @@ class BatchEngine:
             return
         comm_s = ((_comm.wall_s_total() - comm0)
                   if _comm.enabled() else 0.0)
-        cfg = self.engine.config
+        model = self.engine.model
         stall = self.eff_stall_source() if self.eff_stall_source else None
         self.efficiency.step_end(
-            flops=_pm.step_flops(cfg, rows),
-            hbm_bytes=_pm.step_hbm_bytes(
-                cfg, rows, block_size=self.pool.block_size,
+            flops=model.step_flops(rows),
+            hbm_bytes=model.step_hbm_bytes(
+                rows, block_size=self.pool.block_size,
                 itemsize=self._eff_itemsize, method=self.paged_attn,
                 kv_itemsize=self._eff_kv_itemsize,
                 kv_scales=self.pool.kv_quant),
             comm_s=comm_s, tokens=tokens, tenants=tenants,
             stall_summary=stall)
+
+    def _take_stats(self, nxt):
+        """Split the model's ``step_stats`` off the end of the step's
+        token vector and add them to the counters of the same names."""
+        if nxt.shape[0] == self.n_slots:
+            return nxt
+        for name, n in zip(self.engine.model.step_stats,
+                           nxt[self.n_slots:]):
+            self.metrics.inc(name, int(n))
+        return nxt[:self.n_slots]
 
     def _run_decode(self):
         comm0 = self._eff_begin()
@@ -1600,7 +1621,7 @@ class BatchEngine:
                     lambda corrupt: self._decode_step(
                         self.engine.params, jnp.asarray(tok), st.k, st.v,
                         offsets, tables, mask, corrupt, key))
-            nxt = np.asarray(nxt)
+            nxt = self._take_stats(np.asarray(nxt))
         self.pool.state = PagedKVState(k=k, v=v, k_scale=ks, v_scale=vs)
         if self.efficiency is not None:
             rows, tenants = [], {}
@@ -1695,7 +1716,7 @@ class BatchEngine:
                     nxt, finite, k, v, ks, vs = out
                 else:
                     nxt, finite, k, v = out
-            nxt = np.asarray(nxt)
+            nxt = self._take_stats(np.asarray(nxt))
         self.pool.state = PagedKVState(k=k, v=v, k_scale=ks, v_scale=vs)
         if self.efficiency is not None:
             rows, tenants = [], {}

@@ -5,6 +5,11 @@ one fixed device allocation of ``n_blocks`` KV blocks per layer
 
     k, v: (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
 
+or, for a model whose cache is one latent row a token
+(``config.kv_row_shapes`` names no V row), ONE arena
+
+    k: (n_layers, n_blocks, block_size, row)
+
 plus a HOST-side free-list allocator mapping sequences onto blocks. A
 sequence of ``n`` tokens owns ``ceil(n / block_size)`` blocks, listed in
 order in its block table; internal fragmentation is bounded by one block
@@ -124,7 +129,8 @@ class PagedKVState:
     """
 
     k: jax.Array   # (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
-    v: jax.Array
+    v: jax.Array | None    # None: a latent pool, whose one arena ``k``
+                           # (n_layers, n_blocks, block_size, row) is both
     k_scale: jax.Array | None = None   # (n_layers, n_blocks, bs, n_kv_heads)
     v_scale: jax.Array | None = None
 
@@ -165,20 +171,32 @@ class KVPool:
                 f"but is {config.n_kv_heads}'. Quantized KV has only ever "
                 f"run under the Pallas interpreter; serve with the model "
                 f"dtype on a TPU (ROADMAP S5).")
-        shape = (config.n_layers, n_blocks, block_size,
-                 config.n_kv_heads, config.head_dim)
+        # What one token's row looks like is the model's to say: per-head
+        # K and V rows, or one latent row and no V arena.
+        k_row, v_row = config.kv_row_shapes
+        self.latent = v_row is None
+        self._row_width = k_row[-1]
+        if self.latent and self.kv_quant:
+            raise NotImplementedError(
+                "a latent pool has no quantized build (its one row is both "
+                "key and value; the per-head row scales do not apply)")
+        shape = (config.n_layers, n_blocks, block_size, *k_row)
         sh = ssh = None
         if mesh is not None:
+            from jax.sharding import PartitionSpec
+
             from triton_distributed_tpu.runtime.mesh import sharding_for
 
-            sh = sharding_for(KVCache.spec(axis)[0], mesh)
+            # A latent row is shared by every head: replicated.
+            sh = sharding_for(PartitionSpec() if self.latent
+                              else KVCache.spec(axis)[0], mesh)
             ssh = sharding_for(KVCache.scale_spec(axis), mesh)
         # Each arena is born in its sharded layout: ``jnp.zeros`` +
         # ``device_put`` would build the WHOLE pool on the default device
         # first — on four chips, all of it on chip 0 beside its weight
         # shard (``Qwen3.init`` allocates the same way).
         k = _zeros(shape, self.kv_dtype, sh)
-        v = _zeros(shape, self.kv_dtype, sh)
+        v = None if self.latent else _zeros(shape, self.kv_dtype, sh)
         ks = vs = None
         if self.kv_quant:
             ks = _zeros(shape[:-1], jnp.float32, ssh)
@@ -267,6 +285,8 @@ class KVPool:
         block's stored bytes are meaningless under any other
         (dtype, quantization scheme) pair."""
         scheme = KV_QUANT_SCHEME if self.kv_quant else "none"
+        if self.latent:
+            scheme += f":latent{self._row_width}"
         return f"{self.kv_dtype.name}:{scheme}"
 
     def owned(self, seq_id) -> int:
@@ -482,7 +502,8 @@ class KVPool:
             @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
             def cow(k, v, ks, vs, s, d):
                 k = k.at[:, d].set(k[:, s])
-                v = v.at[:, d].set(v[:, s])
+                if v is not None:
+                    v = v.at[:, d].set(v[:, s])
                 if ks is not None:
                     ks = ks.at[:, d].set(ks[:, s])
                     vs = vs.at[:, d].set(vs[:, s])
@@ -588,3 +609,10 @@ class KVPool:
                 "unquantized pool carrying scale arenas")
         assert st.k.dtype == self.kv_dtype, (
             f"pool arena dtype {st.k.dtype} != declared {self.kv_dtype}")
+        # One latent arena and no V arena, or K and V arenas alike.
+        if self.latent:
+            assert st.v is None and st.k.ndim == 4, (
+                "a latent pool holds one 4-D arena and no V arena")
+        else:
+            assert st.v is not None and st.v.shape == st.k.shape, (
+                "K and V arenas differ")
