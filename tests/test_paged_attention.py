@@ -75,7 +75,7 @@ def _ref_attn_chunk(q, kp, vp, tables, kv_lens, q_lens):
     qn = np.asarray(q, np.float32)
     kv_lens = np.asarray(kv_lens)
     q_lens = np.asarray(q_lens)
-    out = np.zeros((B, L, Hq, dh), np.float32)
+    out = np.zeros((B, L, Hq, vg.shape[-1]), np.float32)
     for b in range(B):
         for j in range(L):
             if j >= q_lens[b]:
@@ -283,6 +283,149 @@ def test_stacked_arena_layer_equals_per_layer_call(rng, shape, kv_dtype):
                         **scales(0), **kw)
 
 
+# -- the fetch pipeline: two staging slots, a walk as long as the slot -------
+
+def _walk_case(rng, shape, tile_blocks, poison=False):
+    """One batch that holds every length the walk has an edge at — 1 row,
+    one block, exactly one tile, one tile + 1 row, an odd number of tiles,
+    the full table (its last tile ragged, the table padded) — and a dead
+    slot between live ones, over a shuffled table. ``shape``: ``decode``
+    (L = 1), ``chunk`` (ragged ``q_lens``, two query tiles), ``latent``
+    (one arena, chunk shape). Returns the call's arguments, the oracle and
+    the live mask. ``poison`` writes NaN over every pool row that no live
+    slot owns, so a prefetch that stages what the mask does not scrub
+    shows."""
+    bs, max_blocks, n_layers, li = 8, 7, 2, 1
+    span = tile_blocks * bs
+    latent = shape == "latent"
+    Hkv, g, dh, v_dim = (1, 4, 32, 16) if latent else (2, 2, 16, None)
+    L = 1 if shape == "decode" else 6
+    kv = [1, bs, span, span + 1, min(3 * span, max_blocks * bs),
+          max_blocks * bs, 5 * bs, 2 * bs + 3]
+    slot_mask = np.array([True] * 6 + [False, True])
+    B = len(kv)
+    q_lens = np.minimum(np.array([1, L, 1, L // 2, L, 1, L, 2])[:B], L)
+    kv_lens = np.maximum(np.array(kv), q_lens)
+    n_blocks = B * max_blocks + 5
+    rows = rng.normal(size=(n_layers, n_blocks, bs, Hkv, dh))
+    vrows = rng.normal(size=(n_layers, n_blocks, bs, Hkv, dh))
+    tables = rng.permutation(n_blocks)[:B * max_blocks].reshape(
+        B, max_blocks).astype(np.int32)
+    if poison:
+        live = np.zeros((n_blocks, bs), bool)
+        for b in np.flatnonzero(slot_mask):
+            for t in range(int(kv_lens[b])):
+                live[tables[b, t // bs], t % bs] = True
+        # a dead slot walks block 0 (``slot_mask``): its output is thrown
+        # away, NaN and all
+        rows[:, ~live] = np.nan
+        vrows[:, ~live] = np.nan
+    q = jnp.asarray(rng.normal(size=(B, L, Hkv * g, dh)), jnp.float32)
+    kp, vp = jnp.asarray(rows, jnp.float32), jnp.asarray(vrows, jnp.float32)
+    if latent:
+        kp, vp = kp[:, :, :, 0], None
+        ref_k, ref_v = rows[li], rows[li][..., :v_dim]
+    else:
+        ref_k, ref_v = rows[li], vrows[li]
+    ref = _ref_attn_chunk(q, jnp.asarray(ref_k, jnp.float32),
+                          jnp.asarray(ref_v, jnp.float32),
+                          jnp.asarray(tables), kv_lens, q_lens)
+    kw = dict(q_lens=jnp.asarray(q_lens, jnp.int32),
+              slot_mask=jnp.asarray(slot_mask), tile_blocks=tile_blocks,
+              q_tile=min(L, 4), interpret=True, v_dim=v_dim)
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(kv_lens, jnp.int32))
+    return args, kw, li, ref, slot_mask
+
+
+@pytest.mark.parametrize("tile_blocks", [1, 2, 3])
+@pytest.mark.parametrize("shape", ["decode", "chunk", "latent"])
+def test_pipelined_walk_matches_gather_reference(rng, shape, tile_blocks):
+    """The walk's trip count is each slot's own: every edge length in one
+    batch, dead slot included, through the stacked arena with a TRACED
+    layer index, equals the gather oracle — for the K+V build in the decode
+    and the chunk shape and for the latent build."""
+    (q, kp, vp, tables, kv_lens), kw, li, ref, live = _walk_case(
+        rng, shape, tile_blocks)
+
+    @jax.jit
+    def traced(layer):
+        return paged_attention(q, kp, vp, tables, kv_lens, layer=layer, **kw)
+
+    out = np.asarray(traced(jnp.int32(li)))
+    np.testing.assert_allclose(out[live], ref[live], atol=1e-5, rtol=1e-5)
+    assert np.isfinite(out).all(), \
+        "dead slots must emit finite garbage, not NaN"
+    static = paged_attention(q, kp, vp, tables, kv_lens, layer=li, **kw)
+    np.testing.assert_array_equal(np.asarray(static), out)
+
+
+@pytest.mark.parametrize("shape", ["decode", "chunk", "latent"])
+def test_prefetch_stages_nothing_the_mask_does_not_scrub(rng, shape):
+    """NaN in every pool block that no live slot owns and in every row past
+    a slot's frontier: whatever either staging slot held — this tile's dead
+    rows, the last tile's leftovers, a neighbour's blocks — the output of
+    the live slots is finite and the oracle's."""
+    (q, kp, vp, tables, kv_lens), kw, li, ref, live = _walk_case(
+        rng, shape, 2, poison=True)
+    out = np.asarray(paged_attention(q, kp, vp, tables, kv_lens, layer=li,
+                                     **kw))
+    assert np.isfinite(out[live]).all()
+    np.testing.assert_allclose(out[live], ref[live], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", ["paged.decode", "paged.prefill",
+                                    "paged.latent"])
+def test_fetch_pipeline_structure(kernel):
+    """The analyzer's event log of the walk (three kv tiles a grid step):
+    in every tile all copies start before the first is waited for; the
+    next tile's copies — the next grid step's first tile after a step's
+    last — start before this tile's staging is read; every started copy is
+    waited for before the kernel ends; and a semaphore serves one tile at
+    a time."""
+    from triton_distributed_tpu.analysis import events
+    from triton_distributed_tpu.analysis import registry as reg
+
+    n_tiles = 3
+    spec = reg.get(kernel).build(1, tile_blocks=2, max_blocks=2 * n_tiles)
+    log = events.trace_kernel(spec, 1).logs[0]
+    stage = {a.name for a in spec.args if a.name.endswith("_stage")}
+    slot_bytes = {a.name: np.zeros(a.shape[1:], a.dtype).nbytes
+                  for a in spec.args if a.name in stage}
+
+    # Tiles in program order: a tile is opened by its first start; starts
+    # and waits name its slot through the semaphore ("sems", slot, arena).
+    tiles, by_slot, count = [], {}, {}
+    for e in log:
+        if e.kind == "inc":
+            slot = e.sem[1]
+            t = by_slot.get(slot)
+            if t is None or t["waits"]:
+                assert not count.get(e.sem), \
+                    f"{e.sem} restarted with {count[e.sem]} bytes in flight"
+                t = dict(slot=slot, starts=[], waits=[], reads=[])
+                by_slot[slot] = t
+                tiles.append(t)
+            t["starts"].append(e.seq)
+            count[e.sem] = count.get(e.sem, 0) + e.amount
+        elif e.kind == "wait":
+            t = by_slot[e.sem[1]]
+            t["waits"].append(e.seq)
+            count[e.sem] -= e.amount
+            assert count[e.sem] >= 0, f"{e.sem} waited for more than started"
+        elif e.kind == "read" and e.buf in stage and e.dma is None:
+            by_slot[e.lo // slot_bytes[e.buf]]["reads"].append(e.seq)
+    assert len(tiles) == int(np.prod(spec.grid)) * n_tiles
+    assert not any(count.values()), f"copies never waited for: {count}"
+    for n, t in enumerate(tiles):
+        assert len(t["starts"]) == len(t["waits"])
+        assert max(t["starts"]) < min(t["waits"]) < min(t["reads"])
+        assert max(t["waits"]) < min(t["reads"])
+        for nxt in tiles[n + 1:n + 2]:
+            assert nxt["slot"] != t["slot"]
+            assert max(nxt["starts"]) < min(t["reads"]), \
+                f"tile {n + 1}'s copies must fly while tile {n} is computed"
+
+
 def test_fused_rejects_non_int32_tables(rng):
     q, kp, vp, tables, kv_lens = _pool_case(rng, 2, 8, 2, 1, 16, 2)
     with pytest.raises(TypeError, match="int32"):
@@ -304,9 +447,10 @@ def test_gather_clips_out_of_range_blocks(rng):
 
 def test_feasible_tiles_vmem_bounded():
     tiles = _feasible_tiles(16, 8, 128, 64, 2)
-    per_block = 2 * 16 * 8 * 128 * 2
+    per_block = 2 * 2 * 16 * 8 * 128 * 2     # K and V, two staging slots
     from triton_distributed_tpu.kernels import common
     assert all(t * per_block <= common.VMEM_STAGE_BUDGET for t in tiles)
+    assert max(tiles) * per_block == common.VMEM_STAGE_BUDGET   # 32 blocks
     assert all(t <= 64 for t in tiles)
     # heuristic default first, staging <= 512 cache rows
     assert tiles[0] * 16 <= 512

@@ -220,6 +220,27 @@ def test_paged_decode_config_sensitivity():
     assert {f.check for f in blown} == {"vmem-budget"}
 
 
+@pytest.mark.parametrize("kernel,arenas", [("paged.decode", 2),
+                                           ("paged.decode.kvq", 4),
+                                           ("paged.latent", 1)])
+def test_paged_footprint_bills_two_staging_slots(kernel, arenas):
+    """The walk's fetch pipeline stages every arena twice (tile n + 1 lands
+    while tile n is computed) and owns one semaphore a (slot, arena): the
+    spec declares what the kernel allocates, so doubling the tile doubles
+    twice the staging."""
+    geometry = dict(bs=16, max_blocks=64)
+    spec = {t: registry.get(kernel).build(1, tile_blocks=t, **geometry)
+            for t in (8, 16)}
+    stage = [a for a in spec[8].args if a.name.endswith("_stage")]
+    assert len(stage) == arenas and all(a.shape[0] == 2 for a in stage)
+    sems, = [a for a in spec[8].args if isinstance(a, Sem)]
+    assert sems.shape == (2, arenas)
+    one_slot = sum(layout.padded_nbytes(a.shape[1:], a.dtype) for a in stage)
+    grown = (resources.footprint(spec[16]).vmem_bytes
+             - resources.footprint(spec[8]).vmem_bytes)
+    assert grown == 2 * one_slot
+
+
 def test_paged_prefill_config_sensitivity():
     """The (tile_blocks, q_tile) config space: a sane prefill config is
     clean, and blowing up either axis trips the VMEM budget — the same

@@ -7,8 +7,18 @@ build (kernels/probes.py), decode the device telemetry record with
 obs.kprobe, print the stall attribution, and write the per-step Chrome
 trace rows to ``--trace-dir`` (default /tmp/tdtpu_probe_trace).
 ``--prefill N`` probes an N-token chunked-prefill step (causal
-(B, n_q_tiles, n_kv_tiles) grid) instead of the L=1 decode step. Runs on
-any backend (interpret mode off-TPU)."""
+(B, n_q_tiles) grid, kv tiles walked in the kernel) instead of the L=1
+decode step. Runs on any backend (interpret mode off-TPU).
+
+``--kernel``: the fused paged-attention kernel ALONE at a cell's geometry
+(defaults: ``qwen3-1.7b.reasoning`` — 28 layers, a 3,328-block pool of
+16-row blocks, 8 KV heads x 128, 32 slots, contexts drawn 300-3,300 with
+77% of the pool live, shuffled block tables), decode shape (L=1) and chunk
+shape (one slot prefilling ``--chunk`` tokens beside 31 decoding rows, the
+mixed step), one line a (shape, ``--tiles`` entry): ms a step of all
+layers, us a live kv tile, GB/s of live pool bytes. ``--latent W,V`` times
+the latent build (one arena of W-wide rows, values the first V columns;
+``--hkv 1``). No cell runs it; it is ROADMAP S5's yardstick."""
 import functools, time
 import os, sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -59,8 +69,125 @@ def _probes_mode():
     dist_print(f"device trace rows -> {paths[0]}")
 
 
+def _kernel_mode():
+    import argparse
+    import numpy as np
+    from triton_distributed_tpu.kernels.paged_attention import (
+        _feasible_tiles, paged_attention)
+    from triton_distributed_tpu.runtime.utils import dist_print
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--kernel", action="store_true")
+    ap.add_argument("--layers", type=int, default=28)
+    ap.add_argument("--n-blocks", type=int, default=3328)
+    ap.add_argument("--block", type=int, default=16)
+    ap.add_argument("--hkv", type=int, default=8)
+    ap.add_argument("--g", type=int, default=2)
+    ap.add_argument("--dh", type=int, default=128)
+    ap.add_argument("--slots", type=int, default=32)
+    ap.add_argument("--chunk", type=int, default=64)
+    ap.add_argument("--max-len", type=int, default=4096)
+    ap.add_argument("--live", type=float, default=0.77)
+    ap.add_argument("--tiles", default="",
+                    help="comma list of tile_blocks; empty = the default")
+    ap.add_argument("--latent", default="",
+                    help="W,V: one latent arena of W-wide rows")
+    ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--seed", type=int, default=0)
+    a = ap.parse_args()
+
+    rng = np.random.default_rng(a.seed)
+    B, bs, nb = a.slots, a.block, a.n_blocks
+    max_blocks = a.max_len // bs
+    # Contexts 300-3,300, stretched until the live share of the pool is met
+    # (a slot's blocks cover its context and the chunk it may append).
+    u = rng.uniform(size=B)
+
+    def drawn(stretch):
+        lens = np.minimum((300 + stretch * 3000 * u).astype(np.int64),
+                          a.max_len - a.chunk)
+        return lens, -(-(lens + a.chunk) // bs)
+
+    lo, hi = 0.0, 1.0
+    for _ in range(40):
+        mid = (lo + hi) / 2
+        lo, hi = (mid, hi) if drawn(mid)[1].sum() < a.live * nb else (lo, mid)
+    lens, n_live_blocks = drawn(lo)
+    perm = rng.permutation(nb)                       # shuffled tables
+    tables = np.zeros((B, max_blocks), np.int32)
+    at = 0
+    for b in range(B):
+        tables[b, :n_live_blocks[b]] = perm[at:at + n_live_blocks[b]]
+        at += n_live_blocks[b]
+    assert at <= nb, "the contexts drawn do not fit the pool"
+    key = jax.random.PRNGKey(a.seed)
+    if a.latent:
+        W, V = (int(x) for x in a.latent.split(","))
+        row, Hq, dh, kw = (W,), a.g, W, dict(v_dim=V)
+    else:
+        row, Hq, dh, kw = (a.hkv, a.dh), a.hkv * a.g, a.dh, {}
+    arenas = [jax.random.normal(jax.random.fold_in(key, i),
+                                (a.layers, nb, bs, *row), jnp.bfloat16)
+              for i in range(1 if a.latent else 2)]
+    if a.latent:
+        arenas.append(None)
+    block_bytes = sum(x.nbytes for x in arenas if x is not None) \
+        // (a.layers * nb)
+    dist_print(
+        f"geometry: {a.layers} layers, pool {nb} x {bs} rows, row {row}, "
+        f"{B} slots, contexts {lens.min()}-{lens.max()} "
+        f"({n_live_blocks.sum()} blocks live, "
+        f"{n_live_blocks.sum() / nb:.1%} of the pool), {block_bytes} B a "
+        f"block, device {jax.devices()[0].device_kind}")
+
+    for shape, L in (("decode", 1), ("chunk", a.chunk)):
+        q_lens = np.ones((B,), np.int32)
+        if L > 1:
+            q_lens[0] = L        # one slot prefills a chunk, the rest decode
+        kv_lens = (lens + q_lens).astype(np.int32)
+        q = jax.random.normal(jax.random.fold_in(key, 7),
+                              (B, L, Hq, dh), jnp.bfloat16)
+        for tile in [int(t) for t in a.tiles.split(",") if t] or [None]:
+            @jax.jit
+            def step(q, arenas):
+                def layer(q, li):
+                    out = paged_attention(
+                        q, arenas[0], arenas[1], jnp.asarray(tables),
+                        jnp.asarray(kv_lens), q_lens=jnp.asarray(q_lens),
+                        layer=li, tile_blocks=tile, **kw)
+                    # the next layer's queries hang on this layer's output
+                    return q + (out[..., :1] * 1e-9).astype(q.dtype), None
+                return jax.lax.scan(layer, q,
+                                    jnp.arange(a.layers, dtype=jnp.int32))[0]
+
+            step(q, arenas).block_until_ready()
+            step(q, arenas).block_until_ready()
+            t0 = time.perf_counter()
+            out = q
+            for _ in range(a.iters):
+                out = step(out, arenas)
+            out.block_until_ready()
+            ms = (time.perf_counter() - t0) * 1e3 / a.iters
+            # Under jit the kernel takes the heuristic default (an eager
+            # call on a TPU would tune: twenty minutes, PERF.md section 6).
+            t_used = tile or _feasible_tiles(
+                bs, 1 if a.latent else a.hkv, dh, max_blocks, 2)[0]
+            live_blocks = -(-kv_lens.astype(np.int64) // bs)
+            n_tiles = int((-(-live_blocks // t_used)).sum())
+            live_bytes = int(live_blocks.sum()) * block_bytes * a.layers
+            dist_print(
+                f"{shape:6s} tile_blocks={t_used:3d}: {ms:8.3f} ms a step "
+                f"({ms / a.layers * 1e3:7.1f} us a layer), "
+                f"{n_tiles} live tiles a layer, "
+                f"{ms * 1e3 / a.layers / n_tiles:6.2f} us a tile, "
+                f"{live_bytes / ms / 1e6:6.1f} GB/s of live bytes")
+
+
 if "--probes" in sys.argv:
     _probes_mode()
+    sys.exit(0)
+if "--kernel" in sys.argv:
+    _kernel_mode()
     sys.exit(0)
 
 SHORT, LONG = 96, 288

@@ -96,7 +96,12 @@ def cost_estimate(*, flops: int, bytes_accessed: int,
 
 
 def local_copy(src_ref, dst_ref, sem, *, probe=_probes.NULL):
-    """Synchronous local HBM<->VMEM/HBM copy via the DMA engine."""
+    """Synchronous local HBM<->VMEM/HBM copy via the DMA engine: started
+    and waited for on the spot, so the caller pays the copy's whole latency
+    and nothing else is in flight meanwhile. Right for one copy between two
+    phases; NOT for a loop over blocks — there it serialises (a 32 KiB copy
+    a turn reads HBM at 70 GB/s of 819, PERF.md section 6): start every
+    copy of the batch, then wait, as ``paged_attention``'s walk does."""
     probe.dma_issue(src_ref)
     dma = pltpu.make_async_copy(src_ref, dst_ref, sem)
     dma.start()

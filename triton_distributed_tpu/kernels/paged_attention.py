@@ -25,16 +25,21 @@ earlier query tiles skip the DMAs for blocks past their own causal
 frontier — the fused prefill reads at most one causal pass of the prefix
 where the gather always bills three full ones.
 
-Grid: ``(B, n_q_tiles, n_tiles)`` with ``n_tiles = ceil(max_blocks /
-tile_blocks)`` and ``n_q_tiles = ceil(L / q_tile)``; the kv-tile dimension
-is ``arbitrary`` (sequential) so the running (acc, max, denom) triple
-carries across kv tiles and re-initializes per (slot, q-tile). Tiles
-entirely past a slot's causal frontier skip their DMAs AND their math
-(``pl.when`` on the scalar-prefetched lengths) — a short sequence in a
-long-table batch costs only its own bytes. Dead slots are routed to block
-0 on the HOST (same semantics as the gather path) and their outputs
-discarded by the caller; padding query rows (j >= q_lens[b]) emit exact
-zeros, matching ``attn_with_cache``'s varlen contract.
+Grid: ``(B, n_q_tiles)`` with ``n_q_tiles = ceil(L / q_tile)``. The kv
+tiles (``tile_blocks`` pool blocks each) are a loop INSIDE the grid step
+whose trip count is the slot's own number of live tiles, read from the
+scalar-prefetched lengths: the running (acc, max, denom) triple lives for
+the step, and tiles past a slot's causal frontier are never visited — a
+short sequence in a long-table batch costs only its own tiles. The loop is
+a two-slot FETCH PIPELINE (one path for the K+V, the quantized and the
+latent build): every live block of a tile is one DMA an arena, all of a
+tile's copies are started before any is waited for (a 32 KiB copy waited
+for alone costs its latency, 0.46 us: 70 GB/s of 819), and tile ``n + 1``'s
+copies — after a step's last tile, the next grid step's first — fly into
+the other staging slot while tile ``n`` is multiplied. Dead slots are
+routed to block 0 on the HOST (same semantics as the gather path) and their
+outputs discarded by the caller; padding query rows (j >= q_lens[b]) emit
+exact zeros, matching ``attn_with_cache``'s varlen contract.
 
 The (kv-tile, q-tile) pair is a ``ContextualAutotuner`` config keyed on
 (block_size, Hkv, dh, max_blocks, L, g, dtype) — ``tuned_paged_tile`` —
@@ -63,7 +68,7 @@ _NEG_INF = -1e30
 # (kv-tile, q-tile) config autotuning
 # ---------------------------------------------------------------------------
 
-# Candidate kv tile sizes (pool blocks staged per grid step). Preference
+# Candidate kv tile sizes (pool blocks staged per loop turn). Preference
 # order: the VMEM-bounded heuristic winner is inserted first by
 # _feasible_tiles, so off-TPU and trace-time callers get it
 # deterministically.
@@ -77,17 +82,19 @@ _QTILE_CANDIDATES = (64, 32, 16, 8, 4, 2, 1)
 def _feasible_tiles(block_size: int, n_kv_heads: int, head_dim: int,
                     max_blocks: int, itemsize: int,
                     kv_scales: bool = False) -> list[int]:
-    """Candidate kv tiles whose double (K+V) VMEM staging fits the
-    collective staging budget, capped at the table width; heuristic default
-    first (largest feasible tile staging <= 512 cache rows — enough DMA
-    depth to pipeline against the MXU without hogging VMEM, the
-    flash-decode chunk preference applied to blocks). ``kv_scales`` bills
-    the quantized pool's extra f32 per-row scale staging (two more
-    buffers, one scale per staged (row, kv head)) — the wire tiles shrink
-    with ``itemsize`` but the scale staging rides the same budget."""
+    """Candidate kv tiles whose VMEM staging — K and V, TWO slots each,
+    what the kernel allocates for its fetch pipeline — fits the collective
+    staging budget, capped at the table width; heuristic default first
+    (largest feasible tile staging <= 512 cache rows — enough copies in
+    flight to hide a DMA's latency without hogging VMEM, the flash-decode
+    chunk preference applied to blocks). ``kv_scales`` bills the quantized
+    pool's extra f32 per-row scale staging (two more buffers a slot, one
+    scale per staged (row, kv head)) — the wire tiles shrink with
+    ``itemsize`` but the scale staging rides the same budget."""
     per_block = 2 * block_size * n_kv_heads * head_dim * itemsize
     if kv_scales:
         per_block += 2 * block_size * n_kv_heads * 4
+    per_block *= 2                              # two staging slots
     ok = [t for t in _TILE_CANDIDATES
           if t <= max(1, max_blocks)
           and t * per_block <= common.VMEM_STAGE_BUDGET]
@@ -245,137 +252,197 @@ def tuned_paged_tile(block_size: int, n_kv_heads: int, head_dim: int,
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
-                       vp_ref, o_ref, k_buf, v_buf, acc_ref, m_ref, l_ref,
-                       sems, *,
-                       n_tiles: int, tile_blocks: int, bs: int,
-                       n_blocks: int, scale: float, n_kv: int, g: int,
-                       q_tile: int, n_q_tiles: int, probe=_probes.NULL,
-                       ks_ref=None, vs_ref=None, ks_buf=None, vs_buf=None,
-                       v_dim: int | None = None):
-    """One (slot, query-tile, block-tile) grid step of fused paged
-    attention.
+def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
+                       bs: int, n_blocks: int, scale: float, n_kv: int,
+                       g: int, q_tile: int, n_q_tiles: int,
+                       probe_steps: int = 0, v_dim: int | None = None):
+    """One (slot, query-tile) grid step of fused paged attention: the kv
+    tiles of the slot are walked by a loop INSIDE the step, two staging
+    slots deep.
 
-    ``tbl_ref`` (B, max_blocks) int32, ``kvlen_ref`` (B,) int32,
-    ``qlen_ref`` (B,) int32 and ``layer_ref`` (1,) int32 arrive via scalar
-    prefetch (SMEM — readable before any DMA is issued, which is the whole
-    trick: the block ids ARE the gather, resolved in-kernel). K/V pools
-    stay in ANY/HBM as the STACKED ``(n_layers, n_blocks, bs, Hkv, dh)``
-    arenas — the layer is one more DMA index, so the model's layer scan
-    never slices (and so never materializes) a layer of the pool; each tile
-    DMA-copies its ``tile_blocks`` pool blocks into VMEM staging and runs
-    the ``_flash_decode_kernel`` streaming-softmax update per kv head over
-    the staged rows. Blocks past this query tile's causal frontier skip
-    their DMA entirely; the row-liveness mask zeroes whatever stale staging
-    rows the skipped fetch left behind (``jnp.where`` before the PV dot and
-    the ``* valid`` guard on p scrub any NaN/Inf garbage).
+    Refs, in ``pallas_call`` order: ``tbl_ref`` (B, max_blocks) int32,
+    ``kvlen_ref`` (B,) int32, ``qlen_ref`` (B,) int32 and ``layer_ref``
+    (1,) int32 arrive via scalar prefetch (SMEM — readable before any DMA
+    is issued, which is the whole trick: the block ids ARE the gather,
+    resolved in-kernel); the q block; ``n_arenas`` pool arenas in ANY/HBM;
+    the out block (then the probe buffer of a probed build); one staging
+    buffer an arena, ``(2, tile_blocks * bs, ...)``; the running (acc, max,
+    denominator); the DMA semaphores ``(2, n_arenas)``; the walk's state
+    across grid steps (then the probe's ordinal cell). The arenas stay
+    STACKED ``(n_layers, n_blocks, bs, ...)`` — the layer is one more DMA
+    index, so the model's layer scan never slices (and so never
+    materializes) a layer of the pool.
 
-    QUANTIZED pools (``ks_ref``/``vs_ref`` given — int8/fp8 wire dtype
-    with per-row f32 scales): the block's scale rows DMA alongside its
-    K/V rows (semaphores 2/3) into ``ks_buf``/``vs_buf``, and dequant
-    happens HERE, right after the pool->VMEM staging — the wire cast to
-    f32 multiplied by the staged scale column — so HBM only ever moves
-    wire bytes while the streaming-softmax math below stays the exact f32
-    accumulation of the unquantized build.
+    THE FETCH PIPELINE — one path for every build. Block ``i`` of a kv tile
+    is one copy an arena, ``arena.at[layer, blk] -> staging.at[slot, rows
+    of i]`` on semaphore ``[slot, arena]``. Turn ``j`` of the walk STARTS
+    every live copy of tile ``j`` — none is waited for until all are in
+    flight — and then waits for tile ``j - 1``'s copies in the other slot
+    and computes from it: tile ``j``'s bytes fly while tile ``j - 1`` is
+    multiplied. The walk's trip count is the slot's own number of live
+    tiles (``cdiv(limit, tile_blocks * bs)`` from the prefetched lengths),
+    so a short sequence in a long table costs its own tiles and nothing
+    else, and a query tile past the slot's ``q_len`` costs none. After its
+    last tile a step starts the NEXT grid step's first tile (the lengths
+    and the table of every slot are in SMEM), so only the kernel's first
+    tile is fetched cold; ``walk_ref`` (SMEM, two cells) hands the next
+    step "your tile 0 is in flight" and the slot it went to, which is why
+    both grid dimensions are ``arbitrary``: the steps run in order on one
+    core (v5e has one TensorCore; a two-core chip would need a walk state a
+    core). A semaphore belongs to one (slot, arena): its count is back at
+    zero when the tile's waits are done, before the slot is started again
+    two turns later, so a wait can only be met by its own tile's arrivals.
+    Every started copy is waited for (same ``pl.when``) by the step that
+    computes from it, the last one before the kernel ends.
 
-    LATENT pools (``v_dim`` given; ``vp_ref``/``v_buf`` are None): ONE arena
-    ``(n_layers, n_blocks, bs, W)`` of rows shared by every query head
-    (absorbed latent attention is multi-query attention with one key head).
-    Each block is DMA'd ONCE and used twice: the whole staged row is the
-    key, its first ``v_dim`` columns are the value. A tile's block copies
-    are all started before the first is waited for, and the two dots take
-    the rows in the pool's dtype with float32 accumulation.
+    Blocks past this query tile's causal frontier are never copied; the
+    row-liveness mask zeroes whatever stale staging rows the skipped fetch
+    left behind (``jnp.where`` before the PV dot and the ``* valid`` guard
+    on p scrub any NaN/Inf garbage) — in EITHER slot, so the prefetch can
+    stage nothing the mask does not scrub.
+
+    Builds, by ``n_arenas``: 2 — K and V ``(..., Hkv, dh)``. 4 — a
+    QUANTIZED pool (int8/fp8 wire dtype): the per-row f32 scale arenas
+    ``(..., Hkv)`` ride the same pipeline and dequant happens HERE, right
+    after staging — the wire cast to f32 times the staged scale column —
+    so HBM only ever moves wire bytes while the streaming-softmax math
+    stays the exact f32 accumulation of the unquantized build. 1 — a
+    LATENT pool (``v_dim`` given): ONE arena ``(..., W)`` of rows shared by
+    every query head (absorbed latent attention is multi-query attention
+    with one key head); each block is copied ONCE and used twice: the whole
+    staged row is the key, its first ``v_dim`` columns the value, and the
+    two dots take the rows in the pool's dtype with float32 accumulation.
     """
     latent = v_dim is not None
+    tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref = refs[:5]
+    arenas = refs[5:5 + n_arenas]
+    o_ref = refs[5 + n_arenas]
+    rest = refs[6 + n_arenas:]
+    probe = _probes.NULL
+    if probe_steps:
+        probe = _probes.Probe(rest[0], rest[-1], n_steps=probe_steps)
+        rest = rest[1:-1]
+    stages = rest[:n_arenas]
+    acc_ref, m_ref, l_ref, sems, walk_ref = rest[n_arenas:]
+    quant = n_arenas == 4
+
     b = pl.program_id(0)
     qt = pl.program_id(1)
-    t = pl.program_id(2)
-    # Single-device kernel: probe rank 0 / world 1; absolute (slot, q-tile,
-    # kv-tile) step so the decoder labels rows per batch slot.
-    probe.enter((b * n_q_tiles + qt) * n_tiles + t, 0, 1)
-    kv_len = kvlen_ref[b]
-    q_len = qlen_ref[b]
     layer = layer_ref[0]
-    base = t * tile_blocks * bs
-    # Causal fetch ceiling for THIS query tile: its last live query row
-    # (local index jmax_p1 - 1) sits at absolute position
-    # kv_len - q_len + jmax_p1 - 1 and attends no key past itself, so later
-    # blocks skip their DMA — the causal half-read the byte model bills.
-    jmax_p1 = jnp.minimum((qt + 1) * q_tile, q_len)
-    limit = jnp.minimum(kv_len, kv_len - q_len + jmax_p1)
+    span = tile_blocks * bs
 
-    @pl.when(t == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def frontier(b, qt):
+        """(kv_len, q_len, fetch ceiling, live kv tiles) of a grid step.
+        The ceiling is causal: the query tile's last live row (local index
+        jmax_p1 - 1) sits at absolute position kv_len - q_len + jmax_p1 - 1
+        and attends no key past itself, so later blocks are never copied —
+        the causal half-read the byte model bills. No tile is live for a
+        query tile past q_len, and never more than the table holds."""
+        kv_len, q_len = kvlen_ref[b], qlen_ref[b]
+        jmax_p1 = jnp.minimum((qt + 1) * q_tile, q_len)
+        limit = jnp.minimum(kv_len, kv_len - q_len + jmax_p1)
+        n_live = jnp.where(
+            qt * q_tile < q_len,
+            jnp.clip(pl.cdiv(limit, span), 0, n_tiles), 0)
+        return kv_len, q_len, limit, n_live
 
-    @pl.when((base < limit) & (qt * q_tile < q_len))
-    def _work():
-        def block_copy(i):
-            blk = jnp.clip(tbl_ref[b, t * tile_blocks + i], 0, n_blocks - 1)
-            return pltpu.make_async_copy(kp_ref.at[layer, blk],
-                                         k_buf.at[pl.ds(i * bs, bs)],
-                                         sems.at[0])
+    kv_len, q_len, limit, n_live = frontier(b, qt)
+    # The grid step after this one: its first tile is started under this
+    # step's last, so no step but the kernel's first waits for a cold fetch.
+    step = b * n_q_tiles + qt
+    wraps = qt + 1 == n_q_tiles
+    b_nx = jnp.minimum(jnp.where(wraps, b + 1, b), pl.num_programs(0) - 1)
+    _, _, limit_nx, n_live_nx = frontier(b_nx, jnp.where(wraps, 0, qt + 1))
+    has_nx = (step + 1 < pl.num_programs(0) * n_q_tiles) & (n_live_nx > 0)
 
-        # In-kernel block walk: the gather, without the materialized view.
-        # The latent build starts all of a tile's copies, then waits.
-        for i in range(tile_blocks if latent else 0):
-            @pl.when(base + i * bs < limit)
-            def _start(i=i):
-                block_copy(i).start()
-        for i in range(tile_blocks):
-            @pl.when(base + i * bs < limit)
-            def _fetch(i=i):
-                if latent:
-                    block_copy(i).wait()
-                    return
-                # Same defensive clamp as the gather path's mode="clip".
-                blk = jnp.clip(tbl_ref[b, t * tile_blocks + i], 0,
-                               n_blocks - 1)
-                common.local_copy(kp_ref.at[layer, blk],
-                                  k_buf.at[pl.ds(i * bs, bs)], sems.at[0],
-                                  probe=probe)
-                common.local_copy(vp_ref.at[layer, blk],
-                                  v_buf.at[pl.ds(i * bs, bs)], sems.at[1],
-                                  probe=probe)
-                if ks_buf is not None:
-                    common.local_copy(ks_ref.at[layer, blk],
-                                      ks_buf.at[pl.ds(i * bs, bs)],
-                                      sems.at[2], probe=probe)
-                    common.local_copy(vs_ref.at[layer, blk],
-                                      vs_buf.at[pl.ds(i * bs, bs)],
-                                      sems.at[3], probe=probe)
+    @pl.when(step == 0)
+    def _cold():
+        walk_ref[0] = 0
+        walk_ref[1] = 0
+    started = walk_ref[0]       # 1: the step before this started our tile 0
+    slot0 = walk_ref[1]         # the staging slot our tile 0 goes to
+    # Probe rows are absolute (slot, q-tile, kv-tile), so the decoder
+    # labels rows per batch slot; single-device kernel: rank 0 / world 1.
+    row0 = step * n_tiles
 
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def block_copies(b, tile, slot, i):
+        """Block ``i`` of slot ``b``'s kv tile ``tile``: one (source,
+        staging rows, semaphore) copy an arena into staging slot ``slot``.
+        ``b`` None builds a copy to WAIT for: that needs its size and
+        semaphore, not its source, so it reads no table entry."""
+        blk = 0
+        if b is not None:
+            # Same defensive clamp as the gather path's mode="clip".
+            blk = jnp.clip(tbl_ref[b, tile * tile_blocks + i], 0,
+                           n_blocks - 1)
+        return [(arena.at[layer, blk], stage.at[slot, pl.ds(i * bs, bs)],
+                 sems.at[slot, a])
+                for a, (arena, stage) in enumerate(zip(arenas, stages))]
+
+    def for_live_blocks(tile, limit, fn):
+        # Every tile but a slot's last is whole: no test a block there.
+        whole = (tile + 1) * span <= limit
+
+        @pl.when(whole)
+        def _all():
+            for i in range(tile_blocks):
+                fn(i)
+
+        @pl.when(jnp.logical_not(whole))
+        def _ragged():
+            for i in range(tile_blocks):
+                @pl.when(tile * span + i * bs < limit)
+                def _(i=i):
+                    fn(i)
+
+    def start_tile(b, tile, limit, slot):
+        def start(i):
+            for src, dst, sem in block_copies(b, tile, slot, i):
+                probe.dma_issue(src)
+                pltpu.make_async_copy(src, dst, sem).start()
+        for_live_blocks(tile, limit, start)
+
+    def wait_tile(tile, slot):
+        def wait(i):
+            for src, dst, sem in block_copies(None, tile, slot, i):
+                pltpu.make_async_copy(src, dst, sem).wait()
+                probe.dma_wait(src)
+        for_live_blocks(tile, limit, wait)
+
+    def compute_tile(tile, slot):
+        base = tile * span
         # Staging rows whose block was never fetched hold garbage (NaN in
         # interpret mode, stale VMEM on hardware). The score-side causal
         # mask scrubs stale K (a masked score is overwritten), but stale V
         # flows through the PV dot where ``0 * NaN = NaN`` — zero the dead
         # rows explicitly before contracting.
-        row_pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_blocks * bs, 1), 0)
+        row_pos = base + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
         row_live = row_pos < limit                           # (T*bs, 1) bool
 
         for h in range(n_kv):
             if latent:
                 q = q_ref[0, 0]                              # (q_tile*g, W)
-                k = k_buf[...]                               # (T*bs, W)
-                v = k_buf[:, :v_dim]
+                k = stages[0][slot]                          # (T*bs, W)
+                v = stages[0][slot, :, :v_dim]
             else:
                 # f32 casts deliberate — see _flash_decode_kernel: bf16
                 # g-row sub-tiles hit Mosaic's relayout path and measured
                 # slower.
                 q = q_ref[0, h].astype(jnp.float32)          # (q_tile*g, dh)
-                k = k_buf[:, h, :].astype(jnp.float32)       # (T*bs, dh)
-                v = v_buf[:, h, :].astype(jnp.float32)
-            if ks_buf is not None:
+                k = stages[0][slot, :, h, :].astype(jnp.float32)  # (T*bs, dh)
+                v = stages[1][slot, :, h, :].astype(jnp.float32)
+            if quant:
                 # In-staging dequant: one f32 scale per staged (row, kv
                 # head), broadcast over head_dim. Stale (unfetched) rows'
                 # garbage products are scrubbed exactly like the
                 # unquantized build: K by the score-side causal mask, V by
                 # the row_live select below.
-                k = k * ks_buf[:, h:h + 1]
-                v = v * vs_buf[:, h:h + 1]
+                k = k * stages[2][slot, :, h:h + 1]
+                v = v * stages[3][slot, :, h:h + 1]
             # where, not multiply: 0 * NaN is still NaN.
             v = jnp.where(row_live, v, jnp.zeros_like(v))
             scores = jax.lax.dot_general(
@@ -386,7 +453,7 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
             # g query heads of one token share a kv head group); it may
             # attend keys up to its own absolute position
             # kv_len - q_len + j. Padding rows (j >= q_len) mask every key
-            # and emit exact zeros at _finish — the varlen contract.
+            # and emit exact zeros at the end — the varlen contract.
             j = (qt * q_tile
                  + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // g)
             valid = (j < q_len) & (pos <= kv_len - q_len + j)
@@ -403,13 +470,44 @@ def _paged_attn_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
                 preferred_element_type=jnp.float32)          # (q_tile*g, dh)
             m_ref[h] = new_max
         # QK^T + PV dots over the staged rows, all kv heads this tile.
-        probe.compute(4 * n_kv * (q_ref.shape[2]) * tile_blocks * bs
-                      * q_ref.shape[3])
+        probe.compute(4 * n_kv * (q_ref.shape[2]) * span * q_ref.shape[3])
 
-    @pl.when(t == n_tiles - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)       # (n_kv, q_tile*g, 1)
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    def walk(j, carry):
+        # Turn j starts a tile into slot (slot0 + j) % 2 — this step's tile
+        # j, or after its last the next step's tile 0 — then waits for and
+        # computes tile j - 1 from the other slot. Probe row t holds tile
+        # t's waits and compute and the starts issued beside them.
+        tile = j - 1
+        probe.enter(row0 + jnp.maximum(tile, 0), 0, 1,
+                    fresh=(j != 1) | (started == 1))
+        own = j < n_live
+
+        @pl.when(own | has_nx)
+        def _start():
+            start_tile(jnp.where(own, b, b_nx), jnp.where(own, j, 0),
+                       jnp.where(own, limit, limit_nx),
+                       jax.lax.rem(slot0 + j, 2))
+
+        @pl.when(j > 0)
+        def _work():
+            slot = jax.lax.rem(slot0 + tile, 2)
+            wait_tile(tile, slot)
+            compute_tile(tile, slot)
+        return carry
+
+    jax.lax.fori_loop(started, n_live + 1, walk, 0)
+    walk_ref[0] = has_nx.astype(jnp.int32)
+    walk_ref[1] = jax.lax.rem(slot0 + n_live, 2)
+    if probe_steps:
+        # Rows of the tiles the walk never reached (outputs start
+        # uninitialized): open them empty.
+        def open_row(t, carry):
+            probe.enter(row0 + t, 0, 1)
+            return carry
+        jax.lax.fori_loop(jnp.maximum(n_live, 1), n_tiles, open_row, 0)
+
+    denom = jnp.maximum(l_ref[...], 1e-30)           # (n_kv, q_tile*g, 1)
+    o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attn_cost(B: int, max_blocks: int, block_size: int,
@@ -486,8 +584,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                   may point at blocks since reallocated to live sequences;
                   the mask keeps a dead slot from touching them at all).
                   The dead rows' outputs are garbage the caller discards.
-    tile_blocks / q_tile: pool blocks and query tokens staged per grid step
-                  (None = autotuned / heuristic, ``tuned_paged_tile``).
+    tile_blocks / q_tile: pool blocks staged per turn of the in-kernel
+                  walk, and query tokens per grid step (None = autotuned /
+                  heuristic, ``tuned_paged_tile``).
     k/v_scale:    (n_blocks, block_size, Hkv) f32 — stacked like the pools
                   when they are — or None: per-row dequant
                   scales of a QUANTIZED pool (int8/fp8 wire dtype, written
@@ -497,11 +596,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                   storage precision is the ONLY thing that changes.
     probes:       device-telemetry build (a separate compile): returns
                   ``(out, probe_buf)`` with one record row per (slot,
-                  q-tile, kv-tile) grid step, decoded by ``obs.kprobe`` —
+                  q-tile, kv-tile), decoded by ``obs.kprobe`` —
                   stall attribution covers prefill steps exactly like
-                  decode ones. The probed build serializes every grid
-                  dimension (``arbitrary`` semantics) so record ordinals
-                  are deterministic.
+                  decode ones. Every grid dimension is ``arbitrary``, so
+                  record ordinals are deterministic.
 
     Returns (B, L, Hq, dh) in q.dtype. Bit-compatible with the reference
     ``paged_gather_kv`` + dense/flash composition (streaming softmax over
@@ -516,9 +614,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         raise ValueError("v_dim goes with a latent pool (v_pool=None) and "
                          "only with it")
     if latent:
-        if quant or probes:
+        if quant:
             raise NotImplementedError("the latent pool has no quantized "
-                                      "and no probed build")
+                                      "build")
         # One key head, shared by every query head; the arena keeps its
         # rank (a unit head axis would change its tiled layout).
         if k_pool.ndim == 3:
@@ -601,76 +699,31 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     # mask assumes.
     qh = qh.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, L_pad * g, dh)
 
-    kernel = functools.partial(_paged_attn_kernel, n_tiles=n_tiles,
-                               tile_blocks=tile_blocks, bs=bs,
-                               n_blocks=n_blocks, scale=scale, n_kv=Hkv,
-                               g=g, q_tile=q_tile, n_q_tiles=n_q_tiles)
-    dv = v_dim if latent else dh          # width of a value row
-    if latent:
-        # Positional wrapper: no V pool and no V staging in this build.
-        base_kernel = functools.partial(kernel, v_dim=v_dim)
-
-        def kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
-                   o_ref, k_buf, acc_ref, m_ref, l_ref, sems):
-            base_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref,
-                        kp_ref, None, o_ref, k_buf, None, acc_ref, m_ref,
-                        l_ref, sems)
+    arenas = (k_pool,) if latent else (k_pool, v_pool)
     if quant:
-        # Positional wrapper: the quantized pallas_call passes the scale
-        # pools after V and the scale staging after v_buf; the base kernel
-        # takes them as keywords so one body serves both builds.
-        base_kernel = kernel
-
-        def kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
-                   vp_ref, ks_ref, vs_ref, o_ref, k_buf, v_buf, ks_buf,
-                   vs_buf, acc_ref, m_ref, l_ref, sems, **kw):
-            base_kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref,
-                        kp_ref, vp_ref, o_ref, k_buf, v_buf, acc_ref, m_ref,
-                        l_ref, sems, ks_ref=ks_ref, vs_ref=vs_ref,
-                        ks_buf=ks_buf, vs_buf=vs_buf, **kw)
-
+        arenas += (k_scale, v_scale)
+    n_steps = B * n_q_tiles * n_tiles     # probe rows: (slot, q-tile, kv-tile)
+    kernel = functools.partial(
+        _paged_attn_kernel, n_arenas=len(arenas), n_tiles=n_tiles,
+        tile_blocks=tile_blocks, bs=bs, n_blocks=n_blocks, scale=scale,
+        n_kv=Hkv, g=g, q_tile=q_tile, n_q_tiles=n_q_tiles, v_dim=v_dim,
+        probe_steps=n_steps if probes else 0)
+    dv = v_dim if latent else dh          # width of a value row
     out_specs = pl.BlockSpec((1, Hkv, rows, dv),
-                             lambda b, qt, t, tbl, kl, ql, ly: (b, 0, qt, 0))
+                             lambda b, qt, tbl, kl, ql, ly: (b, 0, qt, 0))
     out_shape = jax.ShapeDtypeStruct((B, Hkv, L_pad * g, dv), jnp.float32)
     scratch_shapes = [
-        *([pltpu.VMEM((tile_blocks * bs, dh), k_pool.dtype)]    # row stage
-          if latent else
-          [pltpu.VMEM((tile_blocks * bs, Hkv, dh), k_pool.dtype),   # k stage
-           pltpu.VMEM((tile_blocks * bs, Hkv, dh), v_pool.dtype)]), # v stage
-        *([pltpu.VMEM((tile_blocks * bs, Hkv), jnp.float32),    # k scales
-           pltpu.VMEM((tile_blocks * bs, Hkv), jnp.float32)]    # v scales
-          if quant else []),
+        # Staging, two slots an arena: tile j + 1 lands in one while tile j
+        # is computed from the other.
+        *(pltpu.VMEM((2, tile_blocks * bs, *a.shape[3:]), a.dtype)
+          for a in arenas),
         pltpu.VMEM((Hkv, rows, dv), jnp.float32),   # acc
         pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # running max
         pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # denominator
-        common.dma_sems(4 if quant else 2),
+        common.dma_sems((2, len(arenas))),          # one a (slot, arena)
+        pltpu.SMEM((2,), jnp.int32),                # walk state across steps
     ]
-    # The probed build serializes every grid dimension so the single
-    # ordinal counter ticks in deterministic grid order.
-    dim_sems = ("arbitrary", "arbitrary", "arbitrary") if probes \
-        else ("parallel", "arbitrary", "arbitrary")
     if probes:
-        n_steps = B * n_q_tiles * n_tiles
-
-        if quant:
-            def body(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
-                     vp_ref, ks_ref, vs_ref, o_ref, pbuf, k_buf, v_buf,
-                     ks_buf, vs_buf, acc_ref, m_ref, l_ref, sems, pord,
-                     kernel=kernel):
-                kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref,
-                       kp_ref, vp_ref, ks_ref, vs_ref, o_ref, k_buf, v_buf,
-                       ks_buf, vs_buf, acc_ref, m_ref, l_ref, sems,
-                       probe=_probes.Probe(pbuf, pord, n_steps=n_steps))
-        else:
-            def body(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref, kp_ref,
-                     vp_ref, o_ref, pbuf, k_buf, v_buf, acc_ref, m_ref,
-                     l_ref, sems, pord, kernel=kernel):
-                kernel(tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref,
-                       kp_ref, vp_ref, o_ref, k_buf, v_buf, acc_ref, m_ref,
-                       l_ref, sems,
-                       probe=_probes.Probe(pbuf, pord, n_steps=n_steps))
-
-        kernel = body
         out_specs = [out_specs, _probes.out_spec()]
         scratch_shapes = [*scratch_shapes, _probes.ord_scratch()]
         out_shape = [out_shape, _probes.out_shape(n_steps)]
@@ -678,30 +731,25 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         # The block table stays the FIRST operand (the benchmark's trace
         # reader knows this kernel by it); the layer index goes last.
         num_scalar_prefetch=4,
-        grid=(B, n_q_tiles, n_tiles),
+        grid=(B, n_q_tiles),
         in_specs=[
             pl.BlockSpec((1, Hkv, rows, dh),
-                         lambda b, qt, t, tbl, kl, ql, ly: (b, 0, qt, 0)),
-            common.any_spec(),     # k arena: manual per-(layer, block) DMA
-            *([] if latent else [common.any_spec()]),           # v pool
-            *([common.any_spec(),  # k scale pool (quantized build)
-               common.any_spec()]  # v scale pool
-              if quant else []),
+                         lambda b, qt, tbl, kl, ql, ly: (b, 0, qt, 0)),
+            # the arenas: manual per-(layer, block) DMA
+            *(common.any_spec() for _ in arenas),
         ],
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    operands = (block_tables, kv_lens, q_lens, layer, qh, k_pool)
-    if not latent:
-        operands += (v_pool,)
-    if quant:
-        operands += (k_scale, v_scale)
     outs = pl.pallas_call(
         kernel,
         out_shape=out_shape,
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=dim_sems),
+            # In grid order on one core: a step starts the next step's
+            # first tile (and the probed build's ordinal counter ticks in
+            # that order).
+            dimension_semantics=("arbitrary", "arbitrary")),
         cost_estimate=paged_attn_cost(
             B, max_blocks, bs, Hkv, dh, n_q_heads=Hq,
             itemsize=(q.dtype.itemsize if quant
@@ -710,7 +758,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
             L=L, q_tile=q_tile),
         interpret=resolve_interpret(interpret),
         name="latent_paged_attention" if latent else None,
-    )(*operands)
+    )(block_tables, kv_lens, q_lens, layer, qh, *arenas)
     o = outs[0] if probes else outs
     o = o.reshape(B, Hkv, L_pad, g, dv).transpose(0, 2, 1, 3, 4)
     o = o.reshape(B, L_pad, Hq, dv)[:, :L].astype(q.dtype)
@@ -762,40 +810,25 @@ from triton_distributed_tpu.analysis import registry as _comm  # noqa: E402
 import numpy as _np  # noqa: E402
 
 
-def _paged_trace_body(tbl, kvlen, qlen, layer, q, kp, vp, o, k_buf, v_buf,
-                      acc, m_run, l_run, sems, **kw):
+def _paged_trace_body(*refs, **kw):
     # Apply the (1, Hkv, q_tile*g, dh) q/o BlockSpec windows by hand — the
     # tracer passes whole buffers, the real grid_spec passes per-(slot,
-    # q-tile) blocks.
+    # q-tile) blocks. Every build's refs arrive in ``pallas_call`` order,
+    # so the one body serves them all.
     b = int(pl.program_id(0))
     qt = int(pl.program_id(1))
     rows = kw["q_tile"] * kw["g"]
-    qw = q.at[pl.ds(b, 1), :, pl.ds(qt * rows, rows)]
-    ow = o.at[pl.ds(b, 1), :, pl.ds(qt * rows, rows)]
-    _paged_attn_kernel(tbl, kvlen, qlen, layer, qw, kp, vp, ow, k_buf, v_buf,
-                       acc, m_run, l_run, sems, **kw)
-
-
-def _paged_trace_body_kvq(tbl, kvlen, qlen, layer, q, kp, vp, ks, vs, o,
-                          k_buf, v_buf, ks_buf, vs_buf, acc, m_run, l_run,
-                          sems, **kw):
-    # Quantized arg order (scale pools after V, scale staging after v_buf)
-    # mapped onto the one kernel body — mirrors the positional wrapper in
-    # ``paged_attention``.
-    b = int(pl.program_id(0))
-    qt = int(pl.program_id(1))
-    rows = kw["q_tile"] * kw["g"]
-    qw = q.at[pl.ds(b, 1), :, pl.ds(qt * rows, rows)]
-    ow = o.at[pl.ds(b, 1), :, pl.ds(qt * rows, rows)]
-    _paged_attn_kernel(tbl, kvlen, qlen, layer, qw, kp, vp, ow, k_buf, v_buf,
-                       acc, m_run, l_run, sems, ks_ref=ks, vs_ref=vs,
-                       ks_buf=ks_buf, vs_buf=vs_buf, **kw)
+    refs = list(refs)
+    for at in (4, 5 + kw["n_arenas"]):                  # q, o
+        refs[at] = refs[at].at[pl.ds(b, 1), :, pl.ds(qt * rows, rows)]
+    _paged_attn_kernel(*refs, **kw)
 
 
 def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
                 n_kv: int = 2, g: int = 2, dh: int = 128,
                 max_blocks: int = 4, dtype: str = "float32", L: int = 1,
-                q_tile: int = 1, kvq: bool = False) -> "_comm.TraceSpec":
+                q_tile: int = 1, kvq: bool = False,
+                v_dim: int | None = None) -> "_comm.TraceSpec":
     B = 2
     dt = _np.dtype(jnp.dtype(dtype))
     n_blocks = B * max_blocks
@@ -806,6 +839,18 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
     # Queries/outputs stay in the COMPUTE dtype on a quantized pool (the
     # wire dtype only ever holds stored KV rows).
     qdt = _np.dtype(_np.float32) if kvq else dt
+    latent = v_dim is not None
+    # The arenas, (name, row shape, dtype), in operand order. Two layers,
+    # the second one read: the layer index is live in every DMA source the
+    # analyzer sees.
+    if latent:
+        n_kv = 1
+        arenas = [("kp", (dh,), dt)]
+    else:
+        arenas = [("kp", (n_kv, dh), dt), ("vp", (n_kv, dh), dt)]
+    if kvq:
+        arenas += [("ksp", (n_kv,), _np.float32),
+                   ("vsp", (n_kv,), _np.float32)]
 
     def tables(r, w):
         t = _np.zeros((B, tbl_w), _np.int32)
@@ -814,9 +859,9 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
         return t
 
     return _comm.TraceSpec(
-        body=_paged_trace_body_kvq if kvq else _paged_trace_body,
+        body=_paged_trace_body,
         ranks=1,
-        grid=(B, n_q_tiles, n_tiles),
+        grid=(B, n_q_tiles),
         args=[
             _comm.Buf("tbl", (B, tbl_w), _np.int32, space="smem",
                       init=tables),
@@ -825,38 +870,31 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
                                                  _np.int32)),
             _comm.Buf("qlen", (B,), _np.int32, space="smem",
                       init=lambda r, w: _np.full((B,), L, _np.int32)),
-            # Two layers, the second one read: the layer index is live in
-            # every DMA source the analyzer sees.
             _comm.Buf("layer", (1,), _np.int32, space="smem",
                       init=lambda r, w: _np.ones((1,), _np.int32)),
             _comm.Buf("q", (B, n_kv, n_q_tiles * rows, dh), qdt),
-            _comm.Buf("kp", (2, n_blocks, bs, n_kv, dh), dt),
-            _comm.Buf("vp", (2, n_blocks, bs, n_kv, dh), dt),
-            *([_comm.Buf("ksp", (2, n_blocks, bs, n_kv), _np.float32),
-               _comm.Buf("vsp", (2, n_blocks, bs, n_kv), _np.float32)]
-              if kvq else []),
+            *(_comm.Buf(name, (2, n_blocks, bs, *row), adt)
+              for name, row, adt in arenas),
             # One (1, Hkv, q_tile*g, dh) window of q and o is VMEM-resident
             # per grid step; billing the full B=2 buffers stays within a
             # few KiB of that and keeps the declaration honest.
-            _comm.Buf("o", (B, n_kv, n_q_tiles * rows, dh), _np.float32,
-                      space="vmem", covered=True),
-            _comm.Buf("k_buf", (tile_blocks * bs, n_kv, dh), dt,
+            _comm.Buf("o", (B, n_kv, n_q_tiles * rows, v_dim or dh),
+                      _np.float32, space="vmem", covered=True),
+            # Staging: two slots an arena, as the kernel allocates them.
+            *(_comm.Buf(f"{name}_stage", (2, tile_blocks * bs, *row), adt,
+                        space="vmem")
+              for name, row, adt in arenas),
+            _comm.Buf("acc", (n_kv, rows, v_dim or dh), _np.float32,
                       space="vmem"),
-            _comm.Buf("v_buf", (tile_blocks * bs, n_kv, dh), dt,
-                      space="vmem"),
-            *([_comm.Buf("ks_buf", (tile_blocks * bs, n_kv), _np.float32,
-                         space="vmem"),
-               _comm.Buf("vs_buf", (tile_blocks * bs, n_kv), _np.float32,
-                         space="vmem")]
-              if kvq else []),
-            _comm.Buf("acc", (n_kv, rows, dh), _np.float32, space="vmem"),
             _comm.Buf("m_run", (n_kv, rows, 1), _np.float32, space="vmem"),
             _comm.Buf("l_run", (n_kv, rows, 1), _np.float32, space="vmem"),
-            _comm.Sem("sems", (4 if kvq else 2,)),
+            _comm.Sem("sems", (2, len(arenas))),
+            _comm.Buf("walk", (2,), _np.int32, space="smem"),
         ],
-        kwargs=dict(n_tiles=n_tiles, tile_blocks=tile_blocks, bs=bs,
-                    n_blocks=n_blocks, scale=1.0, n_kv=n_kv, g=g,
-                    q_tile=q_tile, n_q_tiles=n_q_tiles),
+        kwargs=dict(n_arenas=len(arenas), n_tiles=n_tiles,
+                    tile_blocks=tile_blocks, bs=bs, n_blocks=n_blocks,
+                    scale=1.0, n_kv=n_kv, g=g, q_tile=q_tile,
+                    n_q_tiles=n_q_tiles, v_dim=v_dim),
     )
 
 
@@ -878,8 +916,8 @@ def _paged_spec_kvq(world: int, *, dtype: str = "int8",
 def _paged_spec_prefill(world: int, *, L: int = 8, q_tile: int = 4,
                         **kw) -> "_comm.TraceSpec":
     """The L > 1 (chunked-prefill / mixed step) shape: two query tiles by
-    default so the (B, n_q_tiles, n_kv_tiles) grid, the per-tile causal
-    frontier, and the DMA skip are all exercised; same config kwargs as
+    default so the (B, n_q_tiles) grid, the per-tile causal frontier, and
+    the walk that stops at it are all exercised; same config kwargs as
     ``paged.decode`` plus (L, q_tile) — the space the (tile_blocks, q_tile)
     autotuner pruner feeds."""
     return _paged_spec(world, L=L, q_tile=q_tile, **kw)
@@ -894,48 +932,37 @@ def _paged_spec_prefill_kvq(world: int, *, L: int = 8, q_tile: int = 4,
                        **kw)
 
 
-def _register_paged_probe(base_name: str, kvq: bool = False) -> None:
-    # The generic probes._register_probe_variant appends both probe refs at
-    # the end of the arg list; the real probed paged build places probe_buf
-    # right after the o output and probe_ord after the scratch refs — the
-    # wrapper here mirrors that exact order so the analyzer proves the
-    # choreography the hardware actually runs. Quantized variants carry the
-    # scale pools before o (probe_buf lands at index 10, not 8).
+@_comm.register("paged.latent")
+def _paged_spec_latent(world: int, *, L: int = 8, q_tile: int = 4,
+                       g: int = 4, dh: int = 256, v_dim: int = 128,
+                       **kw) -> "_comm.TraceSpec":
+    """The LATENT build (one arena of rows every query head shares, read
+    once and used as keys and as values), chunk shape: the same walk with
+    one copy a block."""
+    return _paged_spec(world, L=L, q_tile=q_tile, g=g, dh=dh, v_dim=v_dim,
+                       **kw)
+
+
+def _register_paged_probe(base_name: str) -> None:
+    # The real probed build places probe_buf right after the o output and
+    # probe_ord after the scratch refs — mirrored here so the analyzer
+    # proves the choreography the hardware actually runs.
     @_comm.register(f"{base_name}+probe")
     def _build(world: int, _base=base_name, **cfg) -> "_comm.TraceSpec":
         spec = _comm.get(_base).build(world, **cfg)
-        n_steps = 1
-        for n in spec.grid:
-            n_steps *= int(n)
-
-        if kvq:
-            def body(tbl, kvlen, qlen, layer, q, kp, vp, ks, vs, o, pbuf,
-                     k_buf, v_buf, ks_buf, vs_buf, acc, m_run, l_run, sems,
-                     pord, **kw):
-                _paged_trace_body_kvq(
-                    tbl, kvlen, qlen, layer, q, kp, vp, ks, vs, o, k_buf,
-                    v_buf, ks_buf, vs_buf, acc, m_run, l_run, sems,
-                    probe=_probes.Probe(pbuf, pord, n_steps=n_steps), **kw)
-        else:
-            def body(tbl, kvlen, qlen, layer, q, kp, vp, o, pbuf, k_buf,
-                     v_buf, acc, m_run, l_run, sems, pord, **kw):
-                _paged_trace_body(
-                    tbl, kvlen, qlen, layer, q, kp, vp, o, k_buf, v_buf,
-                    acc, m_run, l_run, sems,
-                    probe=_probes.Probe(pbuf, pord, n_steps=n_steps), **kw)
-
+        n_steps = (spec.grid[0] * spec.kwargs["n_q_tiles"]
+                   * spec.kwargs["n_tiles"])
         args = list(spec.args)
-        args.insert(10 if kvq else 8, _comm.Buf(
+        args.insert(6 + spec.kwargs["n_arenas"], _comm.Buf(
             "probe_buf", (_probes.n_rows(n_steps), _probes.N_FIELDS),
             _np.int32, space="smem"))
         args.append(_comm.Buf("probe_ord", (1,), _np.int32, space="smem"))
-        return _comm.TraceSpec(body=body, args=args, grid=spec.grid,
-                               kwargs=dict(spec.kwargs), ranks=spec.ranks,
-                               axes=spec.axes)
+        return _comm.TraceSpec(body=spec.body, args=args, grid=spec.grid,
+                               kwargs=dict(spec.kwargs, probe_steps=n_steps),
+                               ranks=spec.ranks, axes=spec.axes)
 
 
-for _base in ("paged.decode", "paged.prefill"):
+for _base in ("paged.decode", "paged.prefill", "paged.decode.kvq",
+              "paged.prefill.kvq"):
     _register_paged_probe(_base)
-for _base in ("paged.decode.kvq", "paged.prefill.kvq"):
-    _register_paged_probe(_base, kvq=True)
 del _base

@@ -108,11 +108,14 @@ class Probe:
     def _bump(self, field: int, amount):
         self._buf[self._row, field] = self._buf[self._row, field] + amount
 
-    def enter(self, step, rank, world):
+    def enter(self, step, rank, world, *, fresh=True):
         """Open the record for grid step ``step`` (0-based; static int or
         traced scalar). Zeroes the step row (Pallas outputs start
         uninitialized), writes the header + zeroes the ordinal counter at
-        step 0, then stamps this step's execution ordinal."""
+        step 0, then stamps this step's execution ordinal. ``fresh`` (a
+        bool, static or traced) False re-enters a row that is already open
+        — a loop whose first two iterations book into one record — and
+        keeps its counts and ordinal."""
         def _init():
             self._buf[0, H_MAGIC] = MAGIC
             self._buf[0, H_VERSION] = VERSION
@@ -131,10 +134,17 @@ class Probe:
 
         row = step + 1
         self._row = row
-        for f in range(N_FIELDS):
-            self._buf[row, f] = 0
-        self._ord[0] = self._ord[0] + 1
-        self._buf[row, F_ORD] = self._ord[0]
+
+        def _open():
+            for f in range(N_FIELDS):
+                self._buf[row, f] = 0
+            self._ord[0] = self._ord[0] + 1
+            self._buf[row, F_ORD] = self._ord[0]
+
+        if fresh is True:
+            _open()
+        else:
+            pl.when(fresh)(_open)
 
     def dma_issue(self, ref, *, remote: bool = False):
         """A DMA start whose source/payload is ``ref`` (remote = ICI put)."""
@@ -163,7 +173,7 @@ class NullProbe:
 
     enabled = False
 
-    def enter(self, step, rank, world):
+    def enter(self, step, rank, world, *, fresh=True):
         pass
 
     def dma_issue(self, ref, *, remote: bool = False):
