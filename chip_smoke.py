@@ -142,23 +142,21 @@ def paged_logits(be, prompts, next_tok):
     ``prompts``: chunked prefill through ``Engine._make_sm(paged="prefill")``
     (the mixed step's forward, which returns logits where the serving step
     returns sampled tokens), then one decode-shaped step
-    (``paged="decode"``) feeding ``next_tok``. Returns float32
+    (``paged="decode"``) feeding ``next_tok``; the pool's state goes in and
+    comes back whole, donated, as in the serving steps. Returns float32
     ``(prefill_last_position_logits, decode_logits)``, one row a prompt."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from triton_distributed_tpu.serving.kv_pool import PagedKVState
-
     eng, pool = be.engine, be.pool
     n, chunk, n_p, plen = be.n_slots, be.prefill_chunk, len(prompts), \
         len(prompts[0])
-    pre = jax.jit(eng._make_sm(eng.prefill_mode, paged="prefill",
-                               paged_attn=be.paged_attn),
-                  donate_argnums=(2, 3))
-    dec = jax.jit(eng._make_sm(eng.decode_mode, paged="decode",
-                               paged_attn=be.paged_attn),
-                  donate_argnums=(2, 3))
+    kw = dict(paged_attn=be.paged_attn, state_specs=pool.specs)
+    pre = jax.jit(eng._make_sm(eng.prefill_mode, paged="prefill", **kw),
+                  donate_argnums=(2,))
+    dec = jax.jit(eng._make_sm(eng.decode_mode, paged="decode", **kw),
+                  donate_argnums=(2,))
     sids = [f"smoke-ref-{i}" for i in range(n_p)]
     for sid in sids:
         check(pool.ensure(sid, plen + 1), "pool could not fund the "
@@ -167,24 +165,22 @@ def paged_logits(be, prompts, next_tok):
         tables = jnp.asarray(pool.padded_tables(sids + [None] * (n - n_p)))
         live = np.arange(n) < n_p
         mask = jnp.asarray(live)
-        k, v = pool.state.k, pool.state.v
         toks = np.asarray(prompts, np.int32)
         for off in range(0, plen, chunk):
             take = min(chunk, plen - off)
             ids = np.zeros((n, chunk), np.int32)
             ids[:n_p, :take] = toks[:, off:off + take]
-            pre_logits, k, v = pre(
-                eng.params, jnp.asarray(ids), k, v,
+            pre_logits, _, pool.state = pre(
+                eng.params, jnp.asarray(ids), pool.state,
                 jnp.asarray(np.where(live, off, 0).astype(np.int32)),
                 tables, mask,
                 jnp.asarray(np.where(live, take, 0).astype(np.int32)))
         ids = np.zeros((n, 1), np.int32)
         ids[:n_p, 0] = next_tok
-        dec_logits, k, v = dec(
-            eng.params, jnp.asarray(ids), k, v,
+        dec_logits, _, pool.state = dec(
+            eng.params, jnp.asarray(ids), pool.state,
             jnp.asarray(np.where(live, plen, 0).astype(np.int32)),
             tables, mask)
-        pool.state = PagedKVState(k=k, v=v)
     finally:
         for sid in sids:
             pool.release(sid)

@@ -552,7 +552,11 @@ def test_engine_defaults_on_bit_identical_and_snapshot(setup):
     _, config, engine = setup
     prompts = _prompts(config)
 
-    be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8)
+    # The gather path (its steps take milliseconds here, the fused kernel's
+    # under the interpreter 0.3 s and more on a busy machine): the snapshot
+    # below must find every request inside the trailing 10 s window.
+    be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
+                     paged_attn="gather")
     assert be.metrics.windowed and be.blackbox is not None \
         and be.sampler is not None
     for i, p in enumerate(prompts):
@@ -571,8 +575,8 @@ def test_engine_defaults_on_bit_identical_and_snapshot(setup):
     assert {"admit", "finish", "schedule_admit"} <= kinds
 
     be_off = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
-                         windowed_metrics=False, blackbox=False,
-                         tail_sampling=False)
+                         paged_attn="gather", windowed_metrics=False,
+                         blackbox=False, tail_sampling=False)
     assert be_off.blackbox is None and be_off.sampler is None
     for i, p in enumerate(prompts):
         be_off.submit(p, 5, req_id=f"r{i}")

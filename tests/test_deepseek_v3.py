@@ -22,7 +22,7 @@ from triton_distributed_tpu.models.config import DeepseekV3Config
 from triton_distributed_tpu.models.engine import Engine
 from triton_distributed_tpu.runtime.mesh import make_mesh
 from triton_distributed_tpu.serving.batch_engine import BatchEngine
-from triton_distributed_tpu.serving.kv_pool import KVPool
+from triton_distributed_tpu.serving.kv_pool import KVPool, PagedKVState
 
 SIZES = family.Sizes(
     vocab_size=256, d_model=64, n_layers=3, dense_layers=1, heads=4,
@@ -72,26 +72,27 @@ def test_prefill_then_decode_through_the_latent_pool_agrees_on_logits(served):
     assert pool.ensure("a", 22)
     tables = jnp.asarray(pool.padded_tables(["a", None]))
     mask = jnp.asarray([True, False])
-    pre = jax.jit(served._make_sm("dist", paged="prefill",
-                                  paged_attn="gather"))
-    dec = jax.jit(served._make_sm("dist", paged="decode",
-                                  paged_attn="gather"))
-    arena, got = pool.state.k, []
+    kw = dict(paged_attn="gather", state_specs=pool.specs)
+    pre = jax.jit(served._make_sm("dist", paged="prefill", **kw))
+    dec = jax.jit(served._make_sm("dist", paged="decode", **kw))
+    state, got = pool.state, []
     for lo, hi in ((0, 12), (12, 20)):            # chunks of 12 and 8
         ids = np.zeros((2, 12), np.int32)
         ids[0, :hi - lo] = tokens[lo:hi]
-        logits, arena, none, stats = pre(
-            served.params, jnp.asarray(ids), arena, None,
+        logits, aux, state = pre(
+            served.params, jnp.asarray(ids), state,
             jnp.asarray([lo, 0], jnp.int32), tables, mask,
             jnp.asarray([hi - lo, 0], jnp.int32))
-        assert none is None
+        assert set(aux) == {"stats"}
+        assert jax.tree.structure(state) == jax.tree.structure(pool.state)
     got.append(np.asarray(logits[0]))                       # position 19
-    assert int(stats[-1]) == 8 * m.n_layers                 # rows appended
+    assert int(aux["stats"][-1]) == 8 * m.n_layers          # rows appended
     for pos in (20, 21):
-        logits, arena, _, stats = dec(
+        logits, aux, state = dec(
             served.params, jnp.asarray([[tokens[pos]], [0]], jnp.int32),
-            arena, None, jnp.asarray([pos, 0], jnp.int32), tables, mask)
+            state, jnp.asarray([pos, 0], jnp.int32), tables, mask)
         got.append(np.asarray(logits[0]))                   # positions 20, 21
+    stats = aux["stats"]
     assert int(stats[0]) == (m.n_layers - 1) * m.topk       # one live row
     ref = ref_read(tokens + [0], 20)          # reads positions 19, 20, 21
     for i, logits in enumerate(got):
@@ -140,12 +141,13 @@ def test_absorbed_attention_equals_the_expanded_form(served):
     p = jax.tree.map(lambda a: a[1], served.params["layers"]["attn"])
     B, L = 2, 7
     x = jax.random.normal(jax.random.PRNGKey(1), (B, L, m.d_model))
-    pool = jnp.zeros((8, 4, attn.cache_row))
+    state = PagedKVState(k=jnp.zeros((8, 4, attn.cache_row)), v=None)
     tables = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
-    out, pool = attn.fwd(p, x, pool, jnp.zeros((B,), jnp.int32),
-                         block_tables=tables, paged_attn="gather")
+    out, state = attn.fwd(p, x, state, jnp.zeros((B,), jnp.int32),
+                          block_tables=tables, paged_attn="gather")
+    assert state.v is None
     # the cache row: the normalised latent, then the rotated key, then zeros
-    rows = np.asarray(pool).reshape(2, 16, -1)[:, :L]
+    rows = np.asarray(state.k).reshape(2, 16, -1)[:, :L]
     assert np.all(rows[..., m.cache_width:] == 0) and np.any(rows != 0)
 
     cq = nn.rms_norm(x @ p["w_qa"], p["q_a_norm"], m.eps)
@@ -319,12 +321,13 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_more_than_one_device_is_refused_by_name():
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2], set_default=False)
     engine = Engine(DeepseekV3Config.tiny(), mesh=mesh, mode="dist")
+    pool = KVPool(engine.config, n_blocks=8, block_size=4, mesh=mesh)
     step = jax.jit(engine._make_sm("dist", paged="decode",
-                                   paged_attn="gather"))
+                                   paged_attn="gather",
+                                   state_specs=pool.specs))
     with pytest.raises(NotImplementedError,
                        match="latent attention under tensor parallelism"):
-        step.lower(engine.params, jnp.zeros((2, 1), jnp.int32),
-                   jnp.zeros((3, 8, 4, 128)), None,
+        step.lower(engine.params, jnp.zeros((2, 1), jnp.int32), pool.state,
                    jnp.zeros((2,), jnp.int32), jnp.zeros((2, 16), jnp.int32),
                    jnp.ones((2,), bool))
 

@@ -18,9 +18,11 @@ import numpy as np
 import pytest
 
 from triton_distributed_tpu.models import Engine, ModelConfig
+from triton_distributed_tpu.models.config import DeepseekV3Config
 from triton_distributed_tpu.runtime.mesh import make_mesh
 from triton_distributed_tpu.serving import BatchEngine, KVPool, \
-    RadixPrefixCache, Request, Scheduler
+    PagedKVState, RadixPrefixCache, Request, Scheduler
+from triton_distributed_tpu.serving.kv_pool import paged_state_specs
 
 
 @pytest.fixture(scope="module")
@@ -29,6 +31,12 @@ def setup():
     config = ModelConfig.from_name("tiny")
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     return mesh, config, engine
+
+
+@pytest.fixture(scope="module")
+def latent_engine(setup):
+    """The second model class: a latent pool (one arena, no V)."""
+    return Engine(DeepseekV3Config.tiny(), mesh=setup[0], mode="dist")
 
 
 def _golden(engine, prompt, gen_len):
@@ -322,17 +330,20 @@ def test_priority_preempts_low_priority(setup):
 _BS, _NB = 4, 40      # block size and pool blocks of the hand-made steps
 
 
-def _paged_step(engine, kind, paged_attn, quant=False):
-    """One hand-made paged step of ``forward_device`` (through the step's
+def _paged_step(engine, kind, paged_attn, fmt="bf16"):
+    """One hand-made paged step of ``forward_paged`` (through the step's
     own shard_map): 4 slots with slot 2 DEAD but holding stale table rows,
-    shuffled tables, every arena filled with random data. Returns
-    ``(sm, args, written)``: ``written`` is the set of (block, line) the
-    step's tables address for live tokens — the same in every layer."""
+    shuffled tables, every arena of the pool's state filled with random
+    data. ``fmt``: "bf16" (K and V arenas in the model dtype), "int8" (plus
+    the two scale arenas) or "latent" (``engine`` a latent model: one
+    arena). Returns ``(sm, args, written)``: ``args[2]`` is the state and
+    ``written`` the set of (block, line) the step's tables address for live
+    tokens — the same in every layer."""
     c = engine.config
     rng = np.random.default_rng(7)
     B, L = 4, (1 if kind == "decode" else 4)
-    max_blocks = c.max_length // _BS
-    shape = (c.n_layers, _NB, _BS, c.n_kv_heads, c.head_dim)
+    max_blocks = min(c.max_length // _BS, _NB // B)
+    shape = (c.n_layers, _NB, _BS, *c.kv_row_shapes[0])
     tables = rng.permutation(_NB)[:B * max_blocks].reshape(B, max_blocks)
     offsets = np.asarray([5, 0, 9, 3], np.int32)
     mask = np.asarray([True, True, False, True])
@@ -340,68 +351,87 @@ def _paged_step(engine, kind, paged_attn, quant=False):
     written = {(int(tables[b, (offsets[b] + l) // _BS]),
                 int((offsets[b] + l) % _BS))
                for b in range(B) if mask[b] for l in range(seq_lens[b])}
-    if quant:
-        arenas = [jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
-                  for _ in "kv"]
-        arenas += [jnp.asarray(rng.uniform(0.01, 0.02, size=shape[:-1]),
-                               jnp.float32) for _ in "kv"]
+
+    def rows():
+        return jnp.asarray(rng.normal(size=shape), c.dtype)
+
+    if fmt == "int8":
+        state = PagedKVState(
+            *[jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+              for _ in "kv"],
+            *[jnp.asarray(rng.uniform(0.01, 0.02, size=shape[:-1]),
+                          jnp.float32) for _ in "kv"])
+    elif fmt == "latent":
+        state = PagedKVState(k=rows(), v=None)
     else:
-        arenas = [jnp.asarray(rng.normal(size=shape), c.dtype) for _ in "kv"]
+        state = PagedKVState(k=rows(), v=rows())
     ids = jnp.asarray(rng.integers(0, c.vocab_size, size=(B, L)), jnp.int32)
-    args = [engine.params, ids, *arenas, jnp.asarray(offsets),
+    args = [engine.params, ids, state, jnp.asarray(offsets),
             jnp.asarray(tables, jnp.int32), jnp.asarray(mask)]
     if kind == "prefill":
         args.append(jnp.asarray(seq_lens))
-    sm = engine._make_sm("xla", paged=kind, paged_attn=paged_attn,
-                         kv_quant=quant)
+    sm = engine._make_sm(
+        engine.decode_mode, paged=kind, paged_attn=paged_attn,
+        state_specs=paged_state_specs(c, quant=fmt == "int8"))
     return sm, args, written
 
 
+@pytest.mark.parametrize("fmt", ["bf16", "latent"])
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
-def test_paged_step_appends_in_place_fused_equals_gather(setup, kind):
+def test_paged_step_appends_in_place_fused_equals_gather(
+        setup, latent_engine, kind, fmt):
     """One decode step and one mixed step on a pool full of data: the
     append touches exactly the (layer, block, line) rows the tables
     address for live tokens — a dead slot's stale table rows and positions
     past ``seq_lens`` write NOTHING, every other byte of every layer is
     the input's — on the fused path (the kernel DMAs ``[layer, block]`` out
     of the carried arena) and on the gather oracle (``pool[layer]``)
-    alike. The two agree bit for bit on the first layer's appended rows
-    and, past it, to the float32 rounding of their two softmax orders."""
-    _, _, engine = setup
-    outs = {}
+    alike, for K and V arenas and for a latent pool's one arena. The two
+    agree bit for bit on the first layer's appended rows and, past it, to
+    the float32 rounding of their two softmax orders."""
+    engine = latent_engine if fmt == "latent" else setup[2]
+    logits, pools = {}, {}
     for paged_attn in ("fused", "gather"):
-        sm, args, written = _paged_step(engine, kind, paged_attn)
-        outs[paged_attn] = [np.asarray(o) for o in jax.jit(sm)(*args)]
+        sm, args, written = _paged_step(engine, kind, paged_attn, fmt)
+        out, _, state = jax.jit(sm)(*args)
+        assert jax.tree.structure(state) == jax.tree.structure(args[2])
+        logits[paged_attn] = np.asarray(out)
+        pools[paged_attn] = [np.asarray(a) for a in jax.tree.leaves(state)]
         assert written
-        for before, after in zip(args[2:4], outs[paged_attn][1:]):
+        for before, after in zip(jax.tree.leaves(args[2]),
+                                 pools[paged_attn]):
             before = np.asarray(before)
             touched = np.zeros(before.shape[:3], bool)
             for blk, line in written:
                 touched[:, blk, line] = True
             np.testing.assert_array_equal(after[~touched], before[~touched])
             # an appended row is new data, in every layer
-            assert (after[touched] != before[touched]).any(axis=(-1, -2)).all()
-    for f, g in zip(outs["fused"][1:], outs["gather"][1:]):
+            n = int(touched.sum())
+            assert (after[touched] != before[touched]).reshape(n, -1).any(
+                axis=-1).all()
+    for f, g in zip(pools["fused"], pools["gather"]):
         np.testing.assert_array_equal(f[0], g[0])
         np.testing.assert_allclose(f, g, rtol=0, atol=1e-5)
-    live = np.asarray(args[6]) & (np.asarray(args[-1]) > 0)
-    np.testing.assert_allclose(outs["fused"][0][live],
-                               outs["gather"][0][live], rtol=0, atol=1e-5)
+    live = np.asarray(args[5]) & (np.asarray(args[-1]) > 0)
+    np.testing.assert_allclose(logits["fused"][live],
+                               logits["gather"][live], rtol=0, atol=1e-5)
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("fmt", ["bf16", "int8", "latent"])
 @pytest.mark.parametrize("paged_attn", ["fused", "gather"])
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
-def test_paged_pool_rides_the_layer_scan_as_carry(setup, kind, paged_attn,
-                                                  quant):
-    """Structural guard (PERF.md, PR 26): in the paged ``forward_device``
-    the arenas — and a quantized pool's scale arenas — appear in the layer
-    scan ONLY among the carry. As ``xs``/``ys`` each layer of the pool is
-    sliced out to feed the Pallas call and stacked back, five passes over
-    both arenas a step on the chip; nothing else in tier-1 would notice."""
-    _, config, engine = setup
-    sm, args, _ = _paged_step(engine, kind, paged_attn, quant=quant)
-    arena = tuple(args[2].shape)
+def test_paged_pool_rides_the_layer_scan_as_carry(setup, latent_engine, kind,
+                                                  paged_attn, fmt):
+    """Structural guard (PERF.md, PR 26): in ``forward_paged`` the arenas
+    of the pool's state — K and V, a quantized pool's scale arenas, a
+    latent pool's one arena — appear in the layer scan ONLY among the
+    carry. As ``xs``/``ys`` each layer of the pool is sliced out to feed
+    the Pallas call and stacked back, five passes over both arenas a step
+    on the chip; nothing else in tier-1 would notice."""
+    engine = latent_engine if fmt == "latent" else setup[2]
+    config = engine.config
+    sm, args, _ = _paged_step(engine, kind, paged_attn, fmt)
+    arena = tuple(args[2].k.shape)
     pool_shapes = {arena, arena[1:], arena[:-1], arena[1:-1]}
 
     def scans(jaxpr):
@@ -418,14 +448,93 @@ def test_paged_pool_rides_the_layer_scan_as_carry(setup, kind, paged_attn,
                    if n_pool(e.invars)]
     assert len(layer_scans) == 1
     eqn, = layer_scans
-    assert eqn.params["length"] == config.n_layers
+    # the latent model's leading dense layers run before the scan
+    assert eqn.params["length"] == config.n_layers - getattr(
+        config, "n_dense_layers", 0)
     n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
     carry = eqn.invars[n_const:n_const + n_carry]
-    n_arenas = 4 if quant else 2
+    n_arenas = len(jax.tree.leaves(args[2]))
+    assert n_arenas == {"bf16": 2, "int8": 4, "latent": 1}[fmt]
     assert n_pool(carry) == n_arenas
     assert n_pool(eqn.invars) == n_arenas            # none in consts or xs
     assert n_pool(eqn.outvars[:n_carry]) == n_arenas
     assert n_pool(eqn.outvars[n_carry:]) == 0        # none in ys
+
+
+@pytest.mark.parametrize("fmt,speculative", [
+    ("model-dtype", False), ("model-dtype", True), ("int8", False),
+    ("int8", True), ("latent", False)])
+def test_the_steps_take_the_state_whole_and_return_one_record(
+        setup, latent_engine, fmt, speculative):
+    """The seam between ``KVPool``, ``BatchEngine`` and the model, for every
+    pool format and with speculation on and off (the latent model builds no
+    verify step): exactly two jitted steps, named ``decode_step`` and
+    ``mixed_step``; the pool's state is their one donated operand, every
+    leaf of it and nothing else; each returns ``(nxt, finite, greedy |
+    None, state)`` with the model's ``step_stats`` appended to ``nxt`` and
+    ``state`` of the structure of ``pool.state``; slots churn through both
+    without a second trace."""
+    engine = latent_engine if fmt == "latent" else setup[2]
+    n_slots, chunk = 2, 8
+    be = BatchEngine(engine, n_slots=n_slots, block_size=4,
+                     prefill_chunk=chunk, paged_attn="gather",
+                     kv_dtype="int8" if fmt == "int8" else None,
+                     speculative=speculative)
+    structure = jax.tree.structure(be.pool.state)
+    assert structure == jax.tree.structure(be.pool.specs)
+    assert len(jax.tree.leaves(be.pool.state)) == {
+        "model-dtype": 2, "int8": 4, "latent": 1}[fmt]
+    steps = {name: getattr(be, name)
+             for name in ("_decode_step", "_mixed_step")}
+    seen = {}
+
+    def recording(name):
+        step = steps[name]
+        assert step.__name__ == name.lstrip("_")      # jit_decode_step, ...
+
+        def call(*args):
+            out = step(*args)
+            assert isinstance(out, tuple) and len(out) == 4
+            nxt, finite, greedy, state = out
+            assert nxt.dtype == jnp.int32 and nxt.shape == (
+                n_slots + len(engine.model.step_stats),)
+            assert finite.dtype == bool and finite.shape == (n_slots,)
+            if speculative and name == "_mixed_step":
+                assert greedy.dtype == jnp.int32
+                assert greedy.shape == (n_slots, chunk)
+            else:
+                assert greedy is None
+            assert jax.tree.structure(state) == structure
+            seen[name] = args
+            return out
+        return call
+
+    be._decode_step = recording("_decode_step")
+    be._mixed_step = recording("_mixed_step")
+    rng = np.random.default_rng(5)
+    vocab = engine.config.vocab_size
+    # five requests over two slots, prompts longer and shorter than a chunk
+    rids = [be.submit(rng.integers(0, vocab, size=n).tolist(),
+                      max_new_tokens=4) for n in (11, 3, 9, 5, 13)]
+    out = be.run(max_steps=200)
+    assert set(out) == set(rids) and all(len(out[r]) == 4 for r in rids)
+    assert set(seen) == {"_decode_step", "_mixed_step"}
+    assert be.trace_counts == {"decode": 1, "prefill": 1}
+    assert jax.tree.structure(be.pool.state) == structure
+    if engine.model.step_stats:
+        assert be.metrics.counters["latent_rows_appended"] > 0
+
+    # Donation, as declared in the lowering (the platform does not matter):
+    # argument 2, the state, with every leaf of it; nothing else.
+    for name, args in seen.items():
+        args = args[:2] + (be.pool.state,) + args[3:]
+        lowered = steps[name].lower(*args)
+        infos, _ = lowered.args_info
+        assert all(i.donated for i in jax.tree.leaves(infos[2]))
+        assert len(jax.tree.leaves(infos[2])) == structure.num_leaves
+        rest = infos[:2] + infos[3:]
+        assert not any(i.donated for i in jax.tree.leaves(rest))
+        assert f"@jit_{name.lstrip('_')}" in lowered.as_text()
 
 
 def test_pool_sharded_over_kv_heads(mesh8):

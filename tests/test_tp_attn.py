@@ -81,13 +81,13 @@ def _run(layer, params, x, mesh, mode, offset=0, caches=None):
 
     def f(params, xl, kc, vc):
         off = jnp.int32(offset)
-        if mode == "dist":
-            return layer.dist_fwd(params, xl, kc, vc, off)
-        if mode == "xla":
-            return layer.xla_fwd(params, xl, kc, vc, off)
+        if mode in ("dist", "xla"):
+            fwd = layer.dist_fwd if mode == "dist" else layer.xla_fwd
+            out, (kc, vc) = fwd(params, xl, (kc, vc), off)
+            return out, kc, vc
         # ar: replicated activations; gather in, slice out to match layout.
         x_full = jax.lax.all_gather(xl, layer.axis, axis=0, tiled=True)
-        out, kc, vc = layer.ar_fwd(params, x_full, kc, vc, off)
+        out, (kc, vc) = layer.ar_fwd(params, x_full, (kc, vc), off)
         world = _axis_size(layer.axis)
         me = jax.lax.axis_index(layer.axis)
         bl = out.shape[0] // world
@@ -153,8 +153,9 @@ def test_dist_fwd_varlen_prefill(mesh8, layer_and_io):
     lens = np.array([4, 2, 1, 4, 3, 2, 4, 1], np.int32)
 
     def f(params, xl, kc, vc, seq_lens):
-        return layer.dist_fwd(params, xl, kc, vc, jnp.int32(0),
-                              seq_lens=seq_lens)
+        out, (kc, vc) = layer.dist_fwd(params, xl, (kc, vc), jnp.int32(0),
+                                       seq_lens=seq_lens)
+        return out, kc, vc
 
     specs = layer.param_specs()
     fn = jax.jit(shard_map(

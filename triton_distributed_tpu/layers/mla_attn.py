@@ -68,13 +68,15 @@ class MLAttn:
             "w_o": (H * self.v_dim, d),
         }
 
-    def fwd(self, params, x, pool, offset, *, block_tables, slot_mask=None,
+    def fwd(self, params, x, state, offset, *, block_tables, slot_mask=None,
             seq_lens=None, paged_attn: str = "fused", layer=None,
             interpret=None):
-        """x (B, L, d) -> (attention output (B, L, d), updated pool). The
-        pool is one layer of the latent pool or, with ``layer``, the stacked
-        arena: the new rows are appended where it lies and attention reads
-        them back through the block table."""
+        """x (B, L, d) -> (attention output (B, L, d), updated state).
+        ``state`` is the pool's state (``serving.kv_pool.PagedKVState``),
+        taken and returned whole; its one arena ``k`` is one layer of the
+        latent pool or, with ``layer``, the stacked arena: the new rows are
+        appended where it lies and attention reads them back through the
+        block table."""
         B, L, _ = x.shape
         H, r = self.n_heads, self.kv_lora_rank
         f32 = jnp.float32
@@ -102,7 +104,7 @@ class MLAttn:
         if seq_lens is not None:
             tok_valid = jnp.arange(L)[None] < seq_lens[:, None]
             wm = tok_valid if wm is None else (wm[:, None] & tok_valid)
-        pool = nn.paged_cache_update(pool, row, block_tables, offset, wm,
+        pool = nn.paged_cache_update(state.k, row, block_tables, offset, wm,
                                      layer=layer)
 
         q_lat = jnp.einsum("blhn,hnc->blhc", q_nope, params["w_kvb_k"],
@@ -116,4 +118,5 @@ class MLAttn:
             layer=layer)
         o = jnp.einsum("blhc,hcv->blhv", o_lat, params["w_kvb_v"],
                        preferred_element_type=f32).astype(x.dtype)
-        return dot(o.reshape(B, L, H * self.v_dim), params["w_o"]), pool
+        return (dot(o.reshape(B, L, H * self.v_dim), params["w_o"]),
+                dataclasses.replace(state, k=pool))

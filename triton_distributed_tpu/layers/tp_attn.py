@@ -128,46 +128,49 @@ class TPAttn:
 
     # -- shared core --------------------------------------------------------
 
-    def _qkv_to_attn(self, params, qkv, k_cache, v_cache, offset, world,
+    def _qkv_to_attn(self, params, qkv, cache, offset, world,
                      use_flash_decode: bool = True, seq_lens=None,
                      interpret=None, block_tables=None, slot_mask=None,
-                     paged_attn: str = "fused", kv_scales=None, layer=None):
+                     paged_attn: str = "fused", layer=None):
         """qkv (B, L, q_size+2*kv_size) local-head projection -> attention
-        output (B, L, q_size) plus updated caches. The qk-norm -> RoPE ->
-        cache-append -> GQA-attend pipeline shared by every mode
+        output (B, L, q_size) plus the updated ``cache``. The qk-norm ->
+        RoPE -> cache-append -> GQA-attend pipeline shared by every mode
         (reference tp_attn.py:217-233). Decode steps (L == 1) stream the KV
         cache through the split-KV Pallas kernel unless
         ``use_flash_decode=False`` (the xla golden mode stays dense jnp so
         mode-equality tests compare kernel against reference math).
 
         Two cache layouts, one pipeline:
-        - contiguous (``block_tables=None``): k/v_cache (B, S, Hkv, dh),
-          ``offset`` () scalar (the Engine path) or (B,) per-row.
-        - PAGED (serving): k/v_cache are one layer of the block pool
-          (n_blocks, block_size, Hkv, dh) or, with ``layer`` () int32, the
-          whole stacked arena (n_layers, n_blocks, block_size, Hkv, dh) —
-          what the model's layer scan carries, appended to and read at
-          ``[layer, block]`` where it lies; ``block_tables`` (B, max_blocks)
-          maps each slot's sequence onto pool blocks, ``offset`` is the
-          (B,) per-slot depth vector, and ``slot_mask`` (B,) drops dead
-          slots' cache writes. New K/V scatter into the pool; attention
-          reads back through ``nn.paged_attn_with_cache``, which routes
-          EVERY step shape — decode, chunked prefill, ragged mixed — to
-          the fused Pallas block-walk kernel (``paged_attn="fused"``,
-          the default — one pool pass, no materialized view; NOTE it
-          wins over ``use_flash_decode=False``, so the xla golden mode
-          exercises the same fused kernel). ``paged_attn="gather"`` is
-          the explicit paged_gather_kv escape hatch / test oracle —
-          either way arriving/finishing sequences are pure DATA changes
-          and the step never retraces.
+        - contiguous (``block_tables=None``): ``cache`` is the pair
+          ``(k_cache, v_cache)``, each (B, S, Hkv, dh); ``offset`` ()
+          scalar (the Engine path) or (B,) per-row.
+        - PAGED (serving): ``cache`` is the pool's state
+          (``serving.kv_pool.PagedKVState``), taken and returned whole;
+          this layer is where its arenas are read. ``k``/``v`` are one
+          layer of the block pool (n_blocks, block_size, Hkv, dh) or, with
+          ``layer`` () int32, the whole stacked arena (n_layers, n_blocks,
+          block_size, Hkv, dh) — what the model's layer scan carries,
+          appended to and read at ``[layer, block]`` where it lies;
+          ``block_tables`` (B, max_blocks) maps each slot's sequence onto
+          pool blocks, ``offset`` is the (B,) per-slot depth vector, and
+          ``slot_mask`` (B,) drops dead slots' cache writes. New K/V
+          scatter into the pool; attention reads back through
+          ``nn.paged_attn_with_cache``, which routes EVERY step shape —
+          decode, chunked prefill, ragged mixed — to the fused Pallas
+          block-walk kernel (``paged_attn="fused"``, the default — one
+          pool pass, no materialized view; NOTE it wins over
+          ``use_flash_decode=False``, so the xla golden mode exercises the
+          same fused kernel). ``paged_attn="gather"`` is the explicit
+          paged_gather_kv escape hatch / test oracle — either way
+          arriving/finishing sequences are pure DATA changes and the step
+          never retraces.
 
-        Quantized paged KV (``kv_scales`` = (k_scale, v_scale) pool
-        arenas, each (n_blocks, block_size, Hkv) f32): the pool arenas
-        hold int8/fp8 rows, new K/V are quantized per (row, kv head) at
-        append time (``nn.paged_cache_update(scale_pool=...)``), and the
+        Quantized paged KV (the state has ``k_scale``/``v_scale`` arenas,
+        the K/V arenas' shape minus dh, f32): the pool arenas hold
+        int8/fp8 rows, new K/V are quantized per (row, kv head) at append
+        time (``nn.paged_cache_update(scale_pool=...)``), and the
         attention read dequantizes — inside the fused kernel's VMEM
-        staging, or on the gathered view in gather mode. Returns an
-        extra 4th element, the updated ``(k_scale, v_scale)`` tuple.
+        staging, or on the gathered view in gather mode.
         """
         B, L, _ = qkv.shape
         qs, kvs = self.sizes(world)
@@ -186,109 +189,92 @@ class TPAttn:
         q = nn.apply_rope(q, cos, sin)
         k = nn.apply_rope(k, cos, sin)
         if block_tables is None:
-            if kv_scales is not None:
-                raise ValueError("kv_scales requires the paged cache "
-                                 "layout (block_tables)")
+            k_cache, v_cache = cache
             k_cache = nn.cache_update(k_cache, k, offset)
             v_cache = nn.cache_update(v_cache, v, offset)
             out = nn.attn_with_cache(q, k_cache, v_cache, offset,
                                      scale=dh ** -0.5,
                                      use_flash_decode=use_flash_decode,
                                      seq_lens=seq_lens, interpret=interpret)
-            return out.reshape(B, L, qs), k_cache, v_cache
+            return out.reshape(B, L, qs), (k_cache, v_cache)
 
         wm = slot_mask                              # (B,) or None
         if seq_lens is not None:
             tok_valid = jnp.arange(L)[None] < seq_lens[:, None]
             wm = tok_valid if wm is None else (wm[:, None] & tok_valid)
-        if kv_scales is not None:
-            k_cache, ks = nn.paged_cache_update(k_cache, k, block_tables,
-                                                offset, wm,
-                                                scale_pool=kv_scales[0],
-                                                layer=layer)
-            v_cache, vs = nn.paged_cache_update(v_cache, v, block_tables,
-                                                offset, wm,
-                                                scale_pool=kv_scales[1],
-                                                layer=layer)
-            out = nn.paged_attn_with_cache(
-                q, k_cache, v_cache, block_tables, offset, scale=dh ** -0.5,
-                slot_mask=slot_mask, use_flash_decode=use_flash_decode,
-                seq_lens=seq_lens, interpret=interpret,
-                paged_attn=paged_attn, kv_scales=(ks, vs), layer=layer)
-            return out.reshape(B, L, qs), k_cache, v_cache, (ks, vs)
-        k_cache = nn.paged_cache_update(k_cache, k, block_tables,
-                                        offset, wm, layer=layer)
-        v_cache = nn.paged_cache_update(v_cache, v, block_tables,
-                                        offset, wm, layer=layer)
-        out = nn.paged_attn_with_cache(q, k_cache, v_cache, block_tables,
-                                       offset, scale=dh ** -0.5,
-                                       slot_mask=slot_mask,
-                                       use_flash_decode=use_flash_decode,
-                                       seq_lens=seq_lens, interpret=interpret,
-                                       paged_attn=paged_attn, layer=layer)
-        return out.reshape(B, L, qs), k_cache, v_cache
+        state = cache
+        if state.k_scale is not None:
+            k_pool, ks = nn.paged_cache_update(
+                state.k, k, block_tables, offset, wm,
+                scale_pool=state.k_scale, layer=layer)
+            v_pool, vs = nn.paged_cache_update(
+                state.v, v, block_tables, offset, wm,
+                scale_pool=state.v_scale, layer=layer)
+            scales = (ks, vs)
+        else:
+            k_pool = nn.paged_cache_update(state.k, k, block_tables,
+                                           offset, wm, layer=layer)
+            v_pool = nn.paged_cache_update(state.v, v, block_tables,
+                                           offset, wm, layer=layer)
+            ks = vs = scales = None
+        out = nn.paged_attn_with_cache(
+            q, k_pool, v_pool, block_tables, offset, scale=dh ** -0.5,
+            slot_mask=slot_mask, use_flash_decode=use_flash_decode,
+            seq_lens=seq_lens, interpret=interpret, paged_attn=paged_attn,
+            kv_scales=scales, layer=layer)
+        return out.reshape(B, L, qs), dataclasses.replace(
+            state, k=k_pool, v=v_pool, k_scale=ks, v_scale=vs)
 
     # -- per-device forwards (inside shard_map) -----------------------------
+    # ``cache`` in, ``cache`` out, whatever its layout: the pair
+    # ``(k_cache, v_cache)`` of a contiguous cache or, with
+    # ``block_tables``, the paged pool's state (``_qkv_to_attn``).
 
-    def dist_fwd(self, params, x_local, k_cache, v_cache, offset, *,
+    def dist_fwd(self, params, x_local, cache, offset, *,
                  seq_lens=None, interpret=None, block_tables=None,
-                 slot_mask=None, paged_attn: str = "fused", kv_scales=None,
-                 layer=None):
+                 slot_mask=None, paged_attn: str = "fused", layer=None):
         """x_local: (B_local, L, d) batch-shard -> same layout out.
         AG-GEMM -> attention -> GEMM-RS (reference dist_triton_fwd :203).
         ``seq_lens``: (B,) varlen prefill lengths (nn.attn_with_cache).
         ``block_tables``/``slot_mask``/``paged_attn``: paged-KV serving
         path (``_qkv_to_attn``) — tables/mask cover the FULL batch,
-        replicated. ``kv_scales`` (quantized paged pool) appends the
-        updated (k_scale, v_scale) tuple as a 4th output. ``layer``: the
-        caches are the stacked paged arenas, read and appended at this
-        layer (``_qkv_to_attn``)."""
+        replicated. ``layer``: the state's arenas are the stacked ones,
+        read and appended at this layer (``_qkv_to_attn``)."""
         world = _axis_size(self.axis)
         Bl, L, d = x_local.shape
         qkv = ag_gemm_device(
             x_local.reshape(Bl * L, d), params["w_qkv"], axis=self.axis,
             config=AGGEMMConfig(block_n=self.block_n), interpret=interpret)
         qkv = qkv.reshape(world * Bl, L, -1)
-        res = self._qkv_to_attn(
-            params, qkv, k_cache, v_cache, offset, world, seq_lens=seq_lens,
+        out, cache = self._qkv_to_attn(
+            params, qkv, cache, offset, world, seq_lens=seq_lens,
             interpret=interpret, block_tables=block_tables,
-            slot_mask=slot_mask, paged_attn=paged_attn, kv_scales=kv_scales,
-            layer=layer)
-        out, k_cache, v_cache = res[:3]
+            slot_mask=slot_mask, paged_attn=paged_attn, layer=layer)
         out = gemm_rs_device(
             out.reshape(world * Bl * L, -1), params["w_o"], axis=self.axis,
             config=GEMMRSConfig(block_n=min(self.block_n, self.d_model)),
             interpret=interpret)
-        out = out.reshape(Bl, L, d)
-        if kv_scales is not None:
-            return out, k_cache, v_cache, res[3]
-        return out, k_cache, v_cache
+        return out.reshape(Bl, L, d), cache
 
-    def ar_fwd(self, params, x_full, k_cache, v_cache, offset, *,
+    def ar_fwd(self, params, x_full, cache, offset, *,
                interpret=None, seq_lens=None, block_tables=None,
-               slot_mask=None, paged_attn: str = "fused", kv_scales=None,
-               layer=None):
+               slot_mask=None, paged_attn: str = "fused", layer=None):
         """x_full: (B, L, d) replicated -> replicated out.
         Local GEMMs -> one-shot allreduce (reference dist_triton_AR_fwd)."""
         world = _axis_size(self.axis)
         B, L, d = x_full.shape
         qkv = x_full @ params["w_qkv"]
-        res = self._qkv_to_attn(
-            params, qkv, k_cache, v_cache, offset, world, interpret=interpret,
+        out, cache = self._qkv_to_attn(
+            params, qkv, cache, offset, world, interpret=interpret,
             seq_lens=seq_lens, block_tables=block_tables,
-            slot_mask=slot_mask, paged_attn=paged_attn, kv_scales=kv_scales,
-            layer=layer)
-        out, k_cache, v_cache = res[:3]
+            slot_mask=slot_mask, paged_attn=paged_attn, layer=layer)
         partial = out.reshape(B * L, -1) @ params["w_o"]
         out = oneshot_all_reduce(partial, axis=self.axis, interpret=interpret)
-        out = out.reshape(B, L, d)
-        if kv_scales is not None:
-            return out, k_cache, v_cache, res[3]
-        return out, k_cache, v_cache
+        return out.reshape(B, L, d), cache
 
-    def xla_fwd(self, params, x_local, k_cache, v_cache, offset, *,
+    def xla_fwd(self, params, x_local, cache, offset, *,
                 seq_lens=None, block_tables=None, slot_mask=None,
-                paged_attn: str = "fused", kv_scales=None, layer=None):
+                paged_attn: str = "fused", layer=None):
         """Golden/baseline path: same math via jnp + XLA collectives.
         Batch-sharded in/out like ``dist_fwd``. ``paged_attn`` still
         routes paged decode through the fused kernel (interpret mode on
@@ -299,16 +285,12 @@ class TPAttn:
         x_full = jax.lax.all_gather(x_local, self.axis, axis=0, tiled=True)
         qkv = x_full.reshape(world * Bl * L, d) @ params["w_qkv"]
         qkv = qkv.reshape(world * Bl, L, -1)
-        res = self._qkv_to_attn(
-            params, qkv, k_cache, v_cache, offset, world,
+        out, cache = self._qkv_to_attn(
+            params, qkv, cache, offset, world,
             use_flash_decode=False, seq_lens=seq_lens,
             block_tables=block_tables, slot_mask=slot_mask,
-            paged_attn=paged_attn, kv_scales=kv_scales, layer=layer)
-        out, k_cache, v_cache = res[:3]
+            paged_attn=paged_attn, layer=layer)
         partial = out.reshape(world * Bl * L, -1) @ params["w_o"]
         out = jax.lax.psum_scatter(partial, self.axis, scatter_dimension=0,
                                    tiled=True)
-        out = out.reshape(Bl, L, d)
-        if kv_scales is not None:
-            return out, k_cache, v_cache, res[3]
-        return out, k_cache, v_cache
+        return out.reshape(Bl, L, d), cache

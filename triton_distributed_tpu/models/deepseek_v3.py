@@ -3,24 +3,24 @@ leading dense layers, then expert layers with sigmoid routing and a shared
 expert. The second model class behind ``Engine`` (``models.engine`` picks it
 when it is given a ``DeepseekV3Config``), with the contract ``BatchEngine``
 and ``Engine._make_sm`` use: ``axis``, ``param_specs``, ``init``,
-``cache_specs``, ``step_stats`` and ``forward_device`` with
-``block_tables`` / ``slot_mask`` / ``seq_lens``.
+``step_stats`` and ``forward_paged`` (the pool's state in and out whole).
 
 Layers of two kinds: the ``n_dense_layers`` leading layers (a dense SwiGLU)
 run one by one, then ONE ``lax.scan`` walks the expert layers. The latent
-pool's stacked arena ``(n_layers, n_blocks, block_size, row)`` rides both as
-carry and every layer appends to and reads ``[layer, block]`` of it where it
-lies (as PR 26 left the K/V arenas of ``models.qwen``). The routed experts'
-weights stay out of the scan's ``xs``: the grouped-product kernel indexes
-``[layer, expert]`` of the stacked arrays itself.
+pool's state (one stacked arena ``(n_layers, n_blocks, block_size, row)``)
+rides both as carry and every layer appends to and reads ``[layer, block]``
+of it where it lies (as PR 26 left the K/V arenas of ``models.qwen``). The
+routed experts' weights stay out of the scan's ``xs``: the grouped-product
+kernel indexes ``[layer, expert]`` of the stacked arrays itself.
 
 What is not built, and refused by name: tensor parallelism (the latent
 attention's heads are not sharded, and the experts' exchange over ICI does
 not run under ``BatchEngine``: mesh ``{"tp": 1}`` only, this chip standing
 for one of a wide expert-parallel deployment's, see
-``DeepseekV3Config.experts_held``), the contiguous ``Engine.serve`` cache,
-speculative verify, a quantized pool, and the multi-token-prediction
-module (the main model's logits do not depend on it).
+``DeepseekV3Config.experts_held``), speculative verify and a quantized
+pool. Not built and not there to call: the contiguous ``Engine.serve`` cache
+(the class has no ``forward_device``) and the multi-token-prediction module
+(the main model's logits do not depend on it).
 
 Parameters (all replicated)::
 
@@ -63,8 +63,9 @@ class DeepseekV3:
     config: DeepseekV3Config
     axis: str = "tp"
 
-    #: Device-side counts a paged step returns after the pool (int32, this
-    #: order); ``BatchEngine`` adds them to its counters of the same names.
+    #: Device-side counts a paged step returns as ``aux["stats"]`` (int32,
+    #: this order); ``BatchEngine`` adds them to its counters of the same
+    #: names.
     step_stats = MOE_STATS + ("latent_rows_appended",)
 
     @functools.cached_property
@@ -119,11 +120,6 @@ class DeepseekV3:
     def param_specs(self):
         return jax.tree.map(lambda leaf: P(), self.param_shapes(),
                             is_leaf=lambda x: isinstance(x, tuple))
-
-    def cache_specs(self):
-        """PartitionSpecs of the paged pool's (K, V) arenas: one replicated
-        latent arena, no V arena."""
-        return P(), None
 
     def init(self, key, mesh: Mesh | None = None):
         """Random replicated params (tests): weights N(0, 1/fan_in) in the
@@ -193,21 +189,17 @@ class DeepseekV3:
 
     # -- per-device forward (inside shard_map) ------------------------------
 
-    def forward_device(self, params, ids, k_cache, v_cache, offset, *,
-                       mode: str = "dist", interpret=None,
-                       return_moe_stats: bool = False, seq_lens=None,
-                       block_tables=None, slot_mask=None,
-                       paged_attn: str = "fused", spec_verify: bool = False,
-                       kv_scales=None):
-        """One paged step on this device. ids (B, L) int32; ``k_cache`` is
-        the latent arena (n_layers, n_blocks, block_size, row) and
-        ``v_cache`` is None; ``offset`` (B,), ``block_tables``,
-        ``slot_mask``, ``seq_lens`` and ``paged_attn`` as in
-        ``Qwen3.forward_device``. Returns ``(logits (B, vocab) f32, arena,
-        None, stats)`` with ``stats`` the int32 counts ``step_stats`` over
-        the live tokens, summed over the layers. ``mode`` is accepted and
-        not read: on one device ``dist``, ``xla`` and ``ar`` are one path.
-        """
+    def forward_paged(self, params, ids, state, offsets, block_tables,
+                      slot_mask, seq_lens=None, *, mode: str = "dist",
+                      interpret=None, paged_attn: str = "fused",
+                      spec_verify: bool = False):
+        """One served step on this device, as ``Qwen3.forward_paged``:
+        ``(logits (B, vocab) f32, aux, state)``. ``state`` is the pool's
+        state, whose one arena is the latent (n_layers, n_blocks,
+        block_size, row); ``aux["stats"]`` the int32 counts ``step_stats``
+        over the live tokens, summed over the layers. ``mode`` is accepted
+        and not read: on one device ``dist``, ``xla`` and ``ar`` are one
+        path."""
         c = self.config
         if _axis_size(self.axis) != 1:
             raise NotImplementedError(
@@ -219,14 +211,14 @@ class DeepseekV3:
                 f"under BatchEngine). One device is one chip's share of "
                 f"the deployment (DeepseekV3Config.experts_held); no code "
                 f"stands in for the other chips.")
-        if block_tables is None or v_cache is not None:
+        if state.v is not None or state.k_scale is not None:
             raise NotImplementedError(
-                "the latent cache is the block-paged pool only (BatchEngine);"
-                " the contiguous Engine.serve cache is not built for it")
-        if spec_verify or kv_scales is not None or return_moe_stats:
+                "the latent attention reads a latent pool: one arena in the "
+                "model dtype, no V arena and no quantized build")
+        if spec_verify:
             raise NotImplementedError(
-                "speculative verify, a quantized pool and the EP drop audit "
-                "are not built for the latent/held-experts block")
+                "speculative verify is not built for the latent/"
+                "held-experts block")
         B, L = ids.shape
         d = c.d_model
         # The residual stream is carried in float32 (the sub-layers read it
@@ -243,20 +235,19 @@ class DeepseekV3:
                    seq_lens=seq_lens, paged_attn=paged_attn,
                    interpret=interpret)
 
-        def block(h, pool, lp, layer, ffn):
+        def block(h, state, lp, layer, ffn):
             hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
-            a, pool = self.attn.fwd(lp["attn"], hn.astype(c.dtype), pool,
-                                    offset, layer=layer, **akw)
+            a, state = self.attn.fwd(lp["attn"], hn.astype(c.dtype), state,
+                                     offsets, layer=layer, **akw)
             h = h + a
             hn = nn.rms_norm(h, lp["post_norm"], c.rms_eps)
             m, stats = ffn(hn.reshape(-1, d))
-            return h + m.reshape(h.shape), pool, stats
+            return h + m.reshape(h.shape), state, stats
 
-        pool = k_cache
         for i in range(c.n_dense_layers):
             lp = jax.tree.map(lambda a: a[i], params["dense"])
-            h, pool, _ = block(
-                h, pool, lp, jnp.int32(i),
+            h, state, _ = block(
+                h, state, lp, jnp.int32(i),
                 lambda x: (swiglu(x.astype(c.dtype), lp["mlp"]["w_gate_up"],
                                   lp["mlp"]["w_down"]), None))
 
@@ -267,18 +258,18 @@ class DeepseekV3:
         scan_layers["moe"] = light
 
         def body(carry, xs):
-            h, pool, stats = carry
+            h, state, stats = carry
             lp, i = xs
-            h, pool, st = block(
-                h, pool, lp, c.n_dense_layers + i,
+            h, state, st = block(
+                h, state, lp, c.n_dense_layers + i,
                 lambda x: self.moe.fwd(dict(lp["moe"], **heavy), x,
                                        valid.reshape(-1), layer_idx=i,
                                        interpret=interpret))
-            return (h, pool, stats + st), None
+            return (h, state, stats + st), None
 
         n_moe = c.n_layers - c.n_dense_layers
-        (h, pool, moe_stats), _ = jax.lax.scan(
-            body, (h, pool, jnp.zeros((len(MOE_STATS),), jnp.int32)),
+        (h, state, moe_stats), _ = jax.lax.scan(
+            body, (h, state, jnp.zeros((len(MOE_STATS),), jnp.int32)),
             (scan_layers, jnp.arange(n_moe, dtype=jnp.int32)))
 
         h = nn.rms_norm(h, params["final_norm"], c.rms_eps).astype(c.dtype)
@@ -292,4 +283,4 @@ class DeepseekV3:
         stats = jnp.concatenate([
             moe_stats,
             (jnp.sum(valid) * c.n_layers).astype(jnp.int32)[None]])
-        return logits, pool, None, stats
+        return logits, {"stats": stats}, state

@@ -82,97 +82,57 @@ class Engine:
 
     def _make_sm(self, mode: str, *, moe_stats: bool = False,
                  paged: str | None = None, paged_attn: str = "fused",
-                 spec_verify: bool = False, kv_quant: bool = False):
+                 spec_verify: bool = False, state_specs=None):
         """The per-mode shard_map of the model forward — the ONE definition
         of the step sharding, shared by the per-step jit (``_step_fn``),
         the scanned loop (``_serve_scanned_fn``), and the drop-stats audit
         (``moe_stats=True`` appends the replicated counters output).
 
         ``paged='decode'|'prefill'`` builds the continuous-batching serving
-        variants (``serving/batch_engine.py``): the caches become the
-        block-paged pool (same spec — kv-heads at index 3 either way) and
-        the call takes extra replicated data operands
-        (offsets, block_tables, slot_mask[, seq_lens]) so slot churn never
-        changes a shape. ``paged_attn`` selects the paged KV read path for
-        every step shape (fused block-walk kernel vs the gather escape
-        hatch — see ``nn.paged_attn_with_cache``); it is baked into the
-        trace, so a BatchEngine picks it once at construction.
+        step (``serving/batch_engine.py``) over ``model.forward_paged``:
+        ``(params, ids, state, offsets, block_tables, slot_mask[,
+        seq_lens]) -> (logits, aux, state)``. ``state`` is the pool's
+        device state, one pytree in and out; ``state_specs``
+        (``KVPool.specs``) is its PartitionSpecs, a pytree of the same
+        structure, and all this function knows of the pool's format. The
+        other operands are replicated data, so slot churn never changes a
+        shape; the two variants differ only in whether ``seq_lens`` is an
+        operand. ``paged_attn`` selects the paged KV read path for every
+        step shape (fused block-walk kernel vs the gather escape hatch —
+        see ``nn.paged_attn_with_cache``); it is baked into the trace, so
+        a BatchEngine picks it once at construction.
 
-        ``spec_verify=True`` (``paged='prefill'`` only) threads the
-        speculative batched-verify flag through to the model forward: the
-        step emits a second replicated ``greedy`` (B, L) int32 output —
-        the argmax continuation at every position — between the logits and
-        the donated pool arrays. Same shapes, same sharding, one extra
-        replicated output; a speculative BatchEngine bakes it into its one
-        mixed-step trace.
-
-        ``kv_quant=True`` (paged variants only) is the quantized-pool
-        shape of the same step: two per-row f32 scale arenas ride along
-        right after the K/V pools — same kv-head sharding minus head_dim
-        (``KVCache.scale_spec``) — both as operands and as outputs, so
-        the serving engine can donate them alongside the pools."""
+        ``aux`` is a dict of replicated outputs whose keys are fixed per
+        build: ``"greedy"`` (B, L) int32 under ``spec_verify=True``
+        (``paged='prefill'`` only) — the argmax continuation at every
+        position, which a speculative BatchEngine bakes into its one
+        mixed-step trace — and ``"stats"`` for a model with
+        ``step_stats``."""
         model = self.model
-        kspec, vspec = model.cache_specs()
-        sspec = KVCache.scale_spec(model.axis)
         if spec_verify and paged != "prefill":
             raise ValueError("spec_verify requires the paged='prefill' "
                              "(varlen mixed step) variant")
-        if kv_quant and paged is None:
-            raise ValueError("kv_quant requires a paged variant (the "
-                             "contiguous Engine cache is unquantized)")
-        kv_out = ((kspec, vspec, sspec, sspec) if kv_quant
-                  else (kspec, vspec))
-        if spec_verify:
-            out_specs = (P(), P()) + kv_out
-        else:
-            # A model with ``step_stats`` returns its replicated counts
-            # after the pool of a paged step, as the drop audit does.
-            stats = moe_stats or (paged is not None and model.step_stats)
-            out_specs = (P(),) + kv_out + ((P(),) if stats else ())
         if paged is None:
+            kspec, vspec = KVCache.spec(model.axis)[:2]
             fwd = functools.partial(model.forward_device, mode=mode,
                                     interpret=self.interpret,
                                     return_moe_stats=moe_stats)
             in_specs = (model.param_specs(), P(), kspec, vspec, P())
-        elif paged == "decode" and kv_quant:
-            def fwd(params, ids, kp, vp, ksp, vsp, offsets, block_tables,
-                    slot_mask):
-                return model.forward_device(
-                    params, ids, kp, vp, offsets, mode=mode,
-                    interpret=self.interpret, block_tables=block_tables,
-                    slot_mask=slot_mask, paged_attn=paged_attn,
-                    kv_scales=(ksp, vsp))
-            in_specs = (model.param_specs(), P(), kspec, vspec,
-                        sspec, sspec, P(), P(), P())
-        elif paged == "decode":
-            def fwd(params, ids, kp, vp, offsets, block_tables, slot_mask):
-                return model.forward_device(
-                    params, ids, kp, vp, offsets, mode=mode,
-                    interpret=self.interpret, block_tables=block_tables,
-                    slot_mask=slot_mask, paged_attn=paged_attn)
-            in_specs = (model.param_specs(), P(), kspec, vspec,
-                        P(), P(), P())
-        elif paged == "prefill" and kv_quant:
-            def fwd(params, ids, kp, vp, ksp, vsp, offsets, block_tables,
-                    slot_mask, seq_lens):
-                return model.forward_device(
-                    params, ids, kp, vp, offsets, mode=mode,
-                    interpret=self.interpret, block_tables=block_tables,
-                    slot_mask=slot_mask, seq_lens=seq_lens,
-                    paged_attn=paged_attn, spec_verify=spec_verify,
-                    kv_scales=(ksp, vsp))
-            in_specs = (model.param_specs(), P(), kspec, vspec,
-                        sspec, sspec, P(), P(), P(), P())
-        elif paged == "prefill":
-            def fwd(params, ids, kp, vp, offsets, block_tables, slot_mask,
-                    seq_lens):
-                return model.forward_device(
-                    params, ids, kp, vp, offsets, mode=mode,
-                    interpret=self.interpret, block_tables=block_tables,
-                    slot_mask=slot_mask, seq_lens=seq_lens,
-                    paged_attn=paged_attn, spec_verify=spec_verify)
-            in_specs = (model.param_specs(), P(), kspec, vspec,
-                        P(), P(), P(), P())
+            out_specs = (P(), kspec, vspec) + ((P(),) if moe_stats else ())
+        elif paged in ("decode", "prefill"):
+            if state_specs is None:
+                raise ValueError("a paged step needs state_specs, the "
+                                 "PartitionSpecs of the pool's state "
+                                 "(KVPool.specs)")
+            fwd = functools.partial(model.forward_paged, mode=mode,
+                                    interpret=self.interpret,
+                                    paged_attn=paged_attn,
+                                    spec_verify=spec_verify)
+            # offsets, block_tables, slot_mask[, seq_lens]
+            data = (P(),) * (3 if paged == "decode" else 4)
+            in_specs = (model.param_specs(), P(), state_specs, *data)
+            # logits, aux (whatever its keys: all replicated), state
+            out_specs = (P(), P(), state_specs)
         else:
             raise ValueError(f"unknown paged variant {paged!r}")
         return shard_map(

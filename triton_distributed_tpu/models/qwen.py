@@ -73,14 +73,9 @@ class Qwen3:
         return TPMLP(d_model=c.d_model, d_ff=c.d_ff, axis=self.axis,
                      dtype=c.dtype, block_n=self.block_n)
 
-    #: Device-side counts a paged step returns after the pool (none here).
+    #: Device-side counts a paged step returns as ``aux["stats"]`` (none
+    #: here).
     step_stats = ()
-
-    def cache_specs(self):
-        """PartitionSpecs of the paged pool's (K, V) arenas."""
-        from triton_distributed_tpu.models.kv_cache import KVCache
-
-        return KVCache.spec(self.axis)[:2]
 
     def step_flops(self, rows) -> float:
         """The analytic cost of a step over ``rows`` of (new tokens, cache
@@ -288,192 +283,101 @@ class Qwen3:
         return self._place(params, mesh)
 
     # -- per-device forward (inside shard_map) ------------------------------
+    # Two entries over one decoder: ``forward_device`` over ``Engine``'s own
+    # contiguous cache (arrays in, arrays out, the layers of the cache as
+    # the scan's ``xs``/``ys``) and ``forward_paged`` over the served pool
+    # (its state in and out whole, as the scan's carry). They share the
+    # embedding, the layer body and the head below.
 
-    def forward_device(self, params, ids, k_cache, v_cache, offset, *,
-                       mode: str = "dist", interpret=None,
-                       return_moe_stats: bool = False, seq_lens=None,
-                       block_tables=None, slot_mask=None,
-                       paged_attn: str = "fused", spec_verify: bool = False,
-                       kv_scales=None):
-        """One forward step on this device.
-
-        ids: (B, L) int32, replicated. k/v_cache: this device's shard
-        (n_layers, B, S, local_kv_heads, dh). offset: () int32.
-        Returns (logits (B, vocab) fp32 replicated, new_k, new_v).
-
-        Serving (continuous batching) extensions — all FULL-batch,
-        replicated, and pure data (fixed shapes, so slot churn never
-        retraces):
-          offset       may be a (B,) per-slot depth vector.
-          seq_lens     (B,) valid new-token counts per row (chunked varlen
-                       prefill); the returned logits row b comes from
-                       position ``seq_lens[b]-1`` instead of ``L-1``.
-          block_tables (B, max_blocks) int32 + ``slot_mask`` (B,) bool
-                       switch the caches to the block-paged pool layout
-                       (n_layers, n_blocks, block_size, local_kv_heads, dh)
-                       — see ``TPAttn._qkv_to_attn``. The arenas are
-                       carried through the layer scan whole and updated
-                       where they lie (no layer of them is ever sliced
-                       out or stacked back).
-          paged_attn   "fused" (default) routes every paged step shape
-                       through the fused block-walk kernel; "gather" pins
-                       the materialized-view escape hatch / test oracle
-                       (nn.paged_attn_with_cache).
-          kv_scales    (k_scale, v_scale) per-layer scale arenas
-                       (n_layers, n_blocks, block_size, local_kv_heads)
-                       f32 when the paged pool stores quantized int8/fp8
-                       KV; the updated pair comes back as two extra
-                       outputs right after (new_k, new_v).
-
-        ``spec_verify=True`` (speculative decoding's batched verify;
-        requires ``seq_lens``) inserts a SECOND output after ``logits``:
-        ``greedy`` (B, L) int32 — the argmax next-token prediction at EVERY
-        position of every row, not just the last valid one. Host-side
-        longest-prefix acceptance compares draft token j+1 against
-        ``greedy[b, j]``; position ``m`` doubles as the bonus token. The
-        last-position ``logits`` path is untouched (same gather-then-dot
-        arithmetic), so sampling stays bit-identical to the non-verify
-        step; the argmax sweep is one extra (B*L, d) x (d, vocab) matmul
-        reduced to int32 on device — no logits tensor is shipped back.
-
-        ``return_moe_stats=True`` (MoE + mode='dist' only) appends a 4th
-        output: ``{"n_dropped_dispatch", "n_dropped_expert"}`` int32 totals
-        summed over layers and psum'd over the EP axis — the capacity-audit
-        observable (ADVICE r4: the default ``capacity_factor`` can drop
-        (token, k) pairs under skewed routing, and HF semantics have no drop
-        concept; serving stacks must audit these at their real traffic via
-        ``Engine.moe_drop_stats`` and raise ``moe_capacity_factor`` or set
-        explicit capacities if nonzero).
-        """
+    def _embed(self, params, ids, mode: str):
+        """ids (B, L) replicated -> ``(h, rows)``: this device's rows of
+        the hidden state ((B/world, L, d) in dist/xla mode, all of them in
+        ar mode) and ``rows = (me, bl)``, this device's index and how many
+        rows each device holds, or None in ar."""
         c = self.config
-        world = _axis_size(self.axis)
-        B, L = ids.shape
-        if mode in ("dist", "xla"):
-            if B % world:
-                raise ValueError(f"batch {B} not divisible by world {world} "
-                                 f"(required in {mode} mode)")
-            bl = B // world
-            me = jax.lax.axis_index(self.axis)
-            my_ids = jax.lax.dynamic_slice_in_dim(ids, me * bl, bl, axis=0)
-            h = jnp.take(params["embed"], my_ids, axis=0)      # (bl, L, d)
-        elif mode == "ar":
-            h = jnp.take(params["embed"], ids, axis=0)         # (B, L, d)
-        else:
+        if mode == "ar":
+            if c.n_experts:
+                raise ValueError(
+                    "mode='ar' is a dense-TP latency path (GEMM + fused "
+                    "AllReduce); an MoE FFN's comm IS the expert dispatch — "
+                    "use mode='dist' (a2a kernels) or 'xla'")
+            return jnp.take(params["embed"], ids, axis=0), None
+        if mode not in ("dist", "xla"):
             raise ValueError(f"unknown mode {mode!r}")
+        world = _axis_size(self.axis)
+        B = ids.shape[0]
+        if B % world:
+            raise ValueError(f"batch {B} not divisible by world {world} "
+                             f"(required in {mode} mode)")
+        bl = B // world
+        me = jax.lax.axis_index(self.axis)
+        my_ids = jax.lax.dynamic_slice_in_dim(ids, me * bl, bl, axis=0)
+        return jnp.take(params["embed"], my_ids, axis=0), (me, bl)
 
-        if mode == "ar" and c.n_experts:
-            raise ValueError(
-                "mode='ar' is a dense-TP latency path (GEMM + fused "
-                "AllReduce); an MoE FFN's comm IS the expert dispatch — "
-                "use mode='dist' (a2a kernels) or 'xla'")
-        attn, mlp = self.attn, self.mlp
-        if return_moe_stats and (not c.n_experts or mode != "dist"):
-            raise ValueError("return_moe_stats requires an MoE config in "
-                             "mode='dist' (drops only exist on the EP "
-                             "dispatch path)")
-        if spec_verify and seq_lens is None:
-            raise ValueError("spec_verify requires seq_lens (the batched "
-                             "verify step is a varlen mixed step)")
-        quant = kv_scales is not None
-        if quant and block_tables is None:
-            raise ValueError("kv_scales requires the paged cache layout "
-                             "(block_tables)")
-        if spec_verify and return_moe_stats:
-            raise ValueError("spec_verify and return_moe_stats outputs "
-                             "are mutually exclusive")
-
-        # MoE dist mode: the heavy expert weights stay OUT of the scan's xs
-        # (closed over, full stacked (L, E, ...)) and the body passes a
-        # layer index instead — a scan-sliced (E, ...) weight operand would
-        # MATERIALIZE to feed the grouped-GEMM Pallas call (1.2 GB/layer at
-        # 30b-a3b; XLA fuses the slice for an einsum but not for a custom
-        # call), while the stacked form block-indexes the layer inside the
-        # kernel and keeps the empty-expert weight-fetch skip live e2e.
-        moe_dist = bool(c.n_experts) and mode == "dist"
+    def _scan_layers(self, params, mode: str):
+        """``(scan_layers, moe_heavy)``. MoE dist mode: the heavy expert
+        weights stay OUT of the scan's xs (closed over, full stacked
+        (L, E, ...)) and the body passes a layer index instead — a
+        scan-sliced (E, ...) weight operand would MATERIALIZE to feed the
+        grouped-GEMM Pallas call (1.2 GB/layer at 30b-a3b; XLA fuses the
+        slice for an einsum but not for a custom call), while the stacked
+        form block-indexes the layer inside the kernel and keeps the
+        empty-expert weight-fetch skip live e2e."""
         scan_layers = dict(params["layers"])
-        moe_heavy = None
-        if moe_dist:
-            lp_mlp = dict(scan_layers["mlp"])
-            moe_heavy = {"w_gate_up": lp_mlp.pop("w_gate_up"),
-                         "w_down": lp_mlp.pop("w_down")}
-            scan_layers["mlp"] = lp_mlp
+        if not (self.config.n_experts and mode == "dist"):
+            return scan_layers, None
+        lp_mlp = dict(scan_layers["mlp"])
+        moe_heavy = {"w_gate_up": lp_mlp.pop("w_gate_up"),
+                     "w_down": lp_mlp.pop("w_down")}
+        scan_layers["mlp"] = lp_mlp
+        return scan_layers, moe_heavy
 
-        # The PAGED pool follows the same rule: the stacked arenas (and a
-        # quantized pool's scale arenas) ride the scan as CARRY beside h
-        # and the body passes the layer index down — the append scatters
-        # its rows into ``[li, block, line]`` of the arena where it lies and
-        # the fused kernel DMAs ``[li, block]`` out of it. As ``xs``/``ys``
-        # each layer of the pool was sliced out to feed the Pallas call and
-        # stacked back: five passes over both arenas a step and a second
-        # pool of temporaries (PERF.md, PR 26). The contiguous cache
-        # (``Engine``'s own, no block tables, no served path) keeps
-        # ``xs``/``ys``.
-        paged = block_tables is not None
-
-        def body(carry, xs):
-            if paged:
-                h, kv = carry[0], carry[1:]   # (k, v[, k_scale, v_scale])
-                lp, li = xs
-            else:
-                h = carry
-                lp, kv, li = xs[0], xs[1:3], xs[3]
-            kc, vc = kv[:2]
-            sc = kv[2:] if quant else None
-            resid = h
-            hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
-            akw = dict(seq_lens=seq_lens, block_tables=block_tables,
-                       slot_mask=slot_mask, paged_attn=paged_attn,
-                       kv_scales=sc, layer=li if paged else None)
-            if mode == "dist":
-                res = attn.dist_fwd(lp["attn"], hn, kc, vc, offset,
-                                    interpret=interpret, **akw)
-            elif mode == "xla":
-                res = attn.xla_fwd(lp["attn"], hn, kc, vc, offset, **akw)
-            else:
-                res = attn.ar_fwd(lp["attn"], hn, kc, vc, offset,
-                                  interpret=interpret, **akw)
-            a = res[0]
-            kv = tuple(res[1:3]) + (tuple(res[3]) if quant else ())
-            h = resid + a
-            resid = h
-            hn = nn.rms_norm(h, lp["post_norm"], c.rms_eps)
-            flat = hn.reshape(-1, c.d_model)
-            stats = None
-            if mode == "dist":
-                mlp_params = (dict(lp["mlp"], **moe_heavy) if moe_dist
-                              else lp["mlp"])
-                kw = ({"layer_idx": li} if moe_dist else {})
-                if return_moe_stats:
-                    m, stats = mlp.dist_fwd(mlp_params, flat,
-                                            return_stats=True,
-                                            interpret=interpret, **kw)
-                else:
-                    m = mlp.dist_fwd(mlp_params, flat, interpret=interpret,
-                                     **kw)
-            elif mode == "xla":
-                m = mlp.xla_fwd(lp["mlp"], flat)
-            else:
-                m = mlp.ar_fwd(lp["mlp"], flat, interpret=interpret)
-            h = resid + m.reshape(hn.shape)
-            ys = (stats,) if return_moe_stats else ()
-            if paged:
-                return (h,) + kv, ys
-            return h, kv + ys
-
-        layer_ids = jnp.arange(c.n_layers, dtype=jnp.int32)
-        if paged:
-            carry, ys = jax.lax.scan(
-                body, (h, k_cache, v_cache) + tuple(kv_scales or ()),
-                (scan_layers, layer_ids))
-            h, kv_out = carry[0], carry[1:]
+    def _layer(self, lp, h, cache, offset, li, *, mode: str, interpret,
+               moe_heavy=None, return_moe_stats: bool = False, **paged):
+        """One decoder layer: ``(h, cache, stats)``. ``cache`` is this
+        layer's ``(k, v)`` of the contiguous cache or, with ``paged``
+        (block_tables, slot_mask, seq_lens, paged_attn, layer), the pool's
+        state — the attention layer reads it and hands it back."""
+        c = self.config
+        attn, mlp = self.attn, self.mlp
+        resid = h
+        hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
+        if mode == "dist":
+            a, cache = attn.dist_fwd(lp["attn"], hn, cache, offset,
+                                     interpret=interpret, **paged)
+        elif mode == "xla":
+            a, cache = attn.xla_fwd(lp["attn"], hn, cache, offset, **paged)
         else:
-            h, ys = jax.lax.scan(
-                body, h, (scan_layers, k_cache, v_cache, layer_ids))
-            kv_out, ys = ys[:2], ys[2:]
-        if return_moe_stats:
-            moe_stats = jax.tree.map(
-                lambda x: jax.lax.psum(jnp.sum(x), self.axis), ys[0])
+            a, cache = attn.ar_fwd(lp["attn"], hn, cache, offset,
+                                   interpret=interpret, **paged)
+        h = resid + a
+        resid = h
+        hn = nn.rms_norm(h, lp["post_norm"], c.rms_eps)
+        flat = hn.reshape(-1, c.d_model)
+        stats = None
+        if mode == "dist":
+            mlp_params = (dict(lp["mlp"], **moe_heavy) if moe_heavy
+                          else lp["mlp"])
+            kw = ({"layer_idx": li} if moe_heavy else {})
+            if return_moe_stats:
+                m, stats = mlp.dist_fwd(mlp_params, flat, return_stats=True,
+                                        interpret=interpret, **kw)
+            else:
+                m = mlp.dist_fwd(mlp_params, flat, interpret=interpret, **kw)
+        elif mode == "xla":
+            m = mlp.xla_fwd(lp["mlp"], flat)
+        else:
+            m = mlp.ar_fwd(lp["mlp"], flat, interpret=interpret)
+        return resid + m.reshape(hn.shape), cache, stats
 
+    def _head(self, params, h, rows, *, seq_lens=None,
+              spec_verify: bool = False):
+        """Final norm and LM head: ``(logits (B, vocab) fp32 replicated,
+        greedy)``. Row b's logits come from its last position or, with
+        ``seq_lens``, its last VALID one. ``greedy`` (B, L) int32 under
+        ``spec_verify`` — the argmax next-token prediction at EVERY
+        position — else None."""
+        c = self.config
         h = nn.rms_norm(h, params["final_norm"], c.rms_eps)
         lm_head = (params["embed"].T if c.tie_embeddings
                    else params["lm_head"])
@@ -488,8 +392,8 @@ class Qwen3:
             all_logits = jnp.dot(flat, lm_head,
                                  preferred_element_type=jnp.float32)
             greedy = (jnp.argmax(all_logits, axis=-1).astype(jnp.int32)
-                      .reshape(h.shape[0], L))
-            if mode in ("dist", "xla"):
+                      .reshape(h.shape[:2]))
+            if rows is not None:
                 greedy = jax.lax.all_gather(greedy, self.axis, axis=0,
                                             tiled=True)
         if seq_lens is None:
@@ -499,15 +403,118 @@ class Qwen3:
             # VALID position. Rows with seq_lens == 0 clamp to position 0
             # (garbage the caller masks out).
             idx = jnp.maximum(jnp.asarray(seq_lens, jnp.int32) - 1, 0)
-            if mode in ("dist", "xla"):
+            if rows is not None:
+                me, bl = rows
                 idx = jax.lax.dynamic_slice_in_dim(idx, me * bl, bl, axis=0)
             last = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0]
-        if mode in ("dist", "xla"):
+        if rows is not None:
             last = jax.lax.all_gather(last, self.axis, axis=0, tiled=True)
         # bf16 operands, fp32 accumulation — no materialized fp32 weight copy
         logits = jnp.dot(last, lm_head, preferred_element_type=jnp.float32)
-        if spec_verify:
-            return (logits, greedy) + kv_out
-        if return_moe_stats:
-            return (logits,) + kv_out + (moe_stats,)
-        return (logits,) + kv_out
+        return logits, greedy
+
+    def forward_device(self, params, ids, k_cache, v_cache, offset, *,
+                       mode: str = "dist", interpret=None,
+                       return_moe_stats: bool = False):
+        """One forward step on this device over the CONTIGUOUS cache
+        (``Engine.prefill`` / ``decode_step`` / ``serve_scanned``).
+
+        ids: (B, L) int32, replicated. k/v_cache: this device's shard
+        (n_layers, B, S, local_kv_heads, dh). offset: () int32.
+        Returns (logits (B, vocab) fp32 replicated, new_k, new_v).
+
+        ``return_moe_stats=True`` (MoE + mode='dist' only) appends a 4th
+        output: ``{"n_dropped_dispatch", "n_dropped_expert"}`` int32 totals
+        summed over layers and psum'd over the EP axis — the capacity-audit
+        observable (ADVICE r4: the default ``capacity_factor`` can drop
+        (token, k) pairs under skewed routing, and HF semantics have no drop
+        concept; serving stacks must audit these at their real traffic via
+        ``Engine.moe_drop_stats`` and raise ``moe_capacity_factor`` or set
+        explicit capacities if nonzero).
+        """
+        c = self.config
+        if return_moe_stats and (not c.n_experts or mode != "dist"):
+            raise ValueError("return_moe_stats requires an MoE config in "
+                             "mode='dist' (drops only exist on the EP "
+                             "dispatch path)")
+        h, rows = self._embed(params, ids, mode)
+        scan_layers, moe_heavy = self._scan_layers(params, mode)
+
+        def body(h, xs):
+            lp, kc, vc, li = xs
+            h, (kc, vc), stats = self._layer(
+                lp, h, (kc, vc), offset, li, mode=mode, interpret=interpret,
+                moe_heavy=moe_heavy, return_moe_stats=return_moe_stats)
+            return h, (kc, vc) + ((stats,) if return_moe_stats else ())
+
+        h, ys = jax.lax.scan(
+            body, h, (scan_layers, k_cache, v_cache,
+                      jnp.arange(c.n_layers, dtype=jnp.int32)))
+        moe_stats = (jax.tree.map(
+            lambda x: jax.lax.psum(jnp.sum(x), self.axis), ys[2]),
+        ) if return_moe_stats else ()
+        logits, _ = self._head(params, h, rows)
+        return (logits, ys[0], ys[1]) + moe_stats
+
+    def forward_paged(self, params, ids, state, offsets, block_tables,
+                      slot_mask, seq_lens=None, *, mode: str = "dist",
+                      interpret=None, paged_attn: str = "fused",
+                      spec_verify: bool = False):
+        """One served step on this device over the block-paged pool:
+        ``(logits (B, vocab) fp32 replicated, aux, state)``.
+
+        ``state`` is the pool's device state
+        (``serving.kv_pool.PagedKVState``, this device's shard of every
+        arena), passed through whole and returned with the structure it
+        came with; only the attention layer reads its fields. It rides the
+        layer scan as CARRY beside h and the body passes the layer index
+        down — the append scatters its rows into ``[li, block, line]`` of
+        an arena where it lies and the fused kernel DMAs ``[li, block]``
+        out of it. As ``xs``/``ys`` each layer of the pool was sliced out
+        to feed the Pallas call and stacked back: five passes over both
+        arenas a step and a second pool of temporaries (PERF.md, PR 26).
+
+        The operands are all FULL-batch, replicated, and pure data (fixed
+        shapes, so slot churn never retraces): ids (B, L) int32;
+        ``offsets`` (B,) per-slot depths; ``block_tables`` (B, max_blocks)
+        int32 and ``slot_mask`` (B,) bool (``TPAttn._qkv_to_attn``);
+        ``seq_lens`` (B,) valid new-token counts per row of a varlen mixed
+        step (row b's logits then come from position ``seq_lens[b]-1``),
+        None for the decode step. ``paged_attn`` "fused" (default) routes
+        every step shape through the fused block-walk kernel; "gather"
+        pins the materialized-view escape hatch / test oracle
+        (nn.paged_attn_with_cache).
+
+        ``aux`` is a dict whose keys are fixed per build: ``"greedy"``
+        (B, L) int32 under ``spec_verify`` (speculative decoding's batched
+        verify; requires ``seq_lens``) — the argmax next-token prediction
+        at EVERY position of every row. Host-side longest-prefix
+        acceptance compares draft token j+1 against ``greedy[b, j]``;
+        position ``m`` doubles as the bonus token. The last-position
+        ``logits`` path is untouched (same gather-then-dot arithmetic), so
+        sampling stays bit-identical to the non-verify step. A model with
+        ``step_stats`` adds ``"stats"``; this one has none.
+        """
+        c = self.config
+        if spec_verify and seq_lens is None:
+            raise ValueError("spec_verify requires seq_lens (the batched "
+                             "verify step is a varlen mixed step)")
+        h, rows = self._embed(params, ids, mode)
+        scan_layers, moe_heavy = self._scan_layers(params, mode)
+
+        def body(carry, xs):
+            h, state = carry
+            lp, li = xs
+            h, state, _ = self._layer(
+                lp, h, state, offsets, li, mode=mode, interpret=interpret,
+                moe_heavy=moe_heavy, seq_lens=seq_lens,
+                block_tables=block_tables, slot_mask=slot_mask,
+                paged_attn=paged_attn, layer=li)
+            return (h, state), None
+
+        (h, state), _ = jax.lax.scan(
+            body, (h, state),
+            (scan_layers, jnp.arange(c.n_layers, dtype=jnp.int32)))
+        logits, greedy = self._head(params, h, rows, seq_lens=seq_lens,
+                                    spec_verify=spec_verify)
+        return logits, ({"greedy": greedy} if spec_verify else {}), state

@@ -69,7 +69,7 @@ from triton_distributed_tpu.obs.slo import (
 from triton_distributed_tpu.obs.trace import TailSampler
 from triton_distributed_tpu.resilience import faults as _faults
 from triton_distributed_tpu.resilience import guards as _guards
-from triton_distributed_tpu.serving.kv_pool import KVPool, PagedKVState
+from triton_distributed_tpu.serving.kv_pool import KVPool
 from triton_distributed_tpu.serving.metrics import Metrics
 from triton_distributed_tpu.serving.prefix_cache import RadixPrefixCache
 from triton_distributed_tpu.serving.scheduler import Request, Scheduler
@@ -136,9 +136,10 @@ class BatchEngine:
                    check of it.
     ``kv_dtype``   wire format of the KV pool: None (default) stores KV
                    in the model dtype; "int8"/"fp8" store quantized rows
-                   plus per-(row, kv-head) f32 scales in two extra pool
-                   arenas that ride the compiled steps as donated
-                   operands. Quantization happens at append time inside
+                   plus per-(row, kv-head) f32 scales (``KVPool``: the
+                   pool's state then has two scale arenas; the steps
+                   take and return the state whole, whatever its
+                   format). Quantization happens at append time inside
                    the step; dequantization happens inside the fused
                    kernel's VMEM staging (or on the gathered view in
                    gather mode), so pool HBM traffic shrinks by the
@@ -163,8 +164,8 @@ class BatchEngine:
                    ``engine.prefix_cache.enabled = False`` toggles it off
                    at runtime without touching compiled state.
 
-    Always-on observability (bounded, defaults ON — bench --serve --slo
-    gates the total at <= 5% step-time overhead vs all three off):
+    Always-on observability (bounded, defaults ON; what it costs a step
+    on the chip is not measured):
     ``windowed_metrics`` feed every counter/histogram into trailing-window
                    rings so ``stats_snapshot()`` and the SLO engine can
                    answer "p99 over the last 10 s / 5 min".
@@ -358,121 +359,65 @@ class BatchEngine:
     def _build_steps(self):
         eng = self.engine
         V = eng.config.vocab_size
-        spec = self.spec is not None
-        quant = self.pool.kv_quant
-        sm_dec = eng._make_sm(eng.decode_mode, paged="decode",
-                              paged_attn=self.paged_attn, kv_quant=quant)
         # With speculation the ONE mixed step also emits the all-position
         # argmax continuation (``greedy``) — baked into the single trace,
         # so verify steps, chunked prefill, and plain mixed iterations all
         # share it and trace_counts stays {1,1}.
+        kw = dict(paged_attn=self.paged_attn, state_specs=self.pool.specs)
+        sm_dec = eng._make_sm(eng.decode_mode, paged="decode", **kw)
         sm_pre = eng._make_sm(eng.prefill_mode, paged="prefill",
-                              paged_attn=self.paged_attn, spec_verify=spec,
-                              kv_quant=quant)
+                              spec_verify=self.spec is not None, **kw)
         temperature, top_p = eng.temperature, eng.top_p
         trace_counts = self.trace_counts
 
+        # Both steps take the pool's state (``KVPool.state``, whatever its
+        # format) as their ONE donated operand and return the fixed record
+        # ``(nxt, finite, greedy | None, state)``.
+        #
         # ``corrupt`` (n_slots,) f32 is zeros on the healthy path: adding it
         # to the logits is an exact no-op for sampling, and swapping NaN
         # into one row on the host is how fault injection poisons a slot
         # WITHOUT a second compiled variant. ``finite`` is the matching
         # always-compiled guard (models/sampling.finite_logits_mask): every
         # rank computes it every step, only the host decides what to do.
-        #
-        # Quantized pools grow each step by two donated scale-arena
-        # operands/outputs right after the K/V pools — same fixed shapes,
-        # so it is still exactly ONE trace per step kind.
+        # NaN injected at the last position only poisons ``nxt``; a REAL
+        # non-finite at an interior verify position propagates through
+        # causal attention to the last position, so the row-level
+        # ``finite`` mask covers ``greedy`` too.
         #
         # A model with ``step_stats`` (device-side counts of the step, a
         # few int32) has them appended to ``nxt``, so they reach the host
         # in the transfer that brings the tokens and cost no further sync
-        # (``_take_stats`` splits them off). A latent pool has no V arena:
-        # ``v`` is then None, an empty pytree, in and out.
+        # (``_take_stats`` splits them off).
 
-        def with_stats(nxt, stats):
-            return jnp.concatenate([nxt, *stats]) if stats else nxt
+        def sample(logits, aux, corrupt, key):
+            logits = logits + corrupt[:, None]
+            finite = finite_logits_mask(logits)
+            nxt = sample_token(logits, key, temperature=temperature,
+                               top_p=top_p)
+            if "stats" in aux:
+                nxt = jnp.concatenate([nxt, aux["stats"]])
+            return nxt, finite, aux.get("greedy")
 
-        if quant:
-            @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5))
-            def decode_step(params, tok, k, v, ks, vs, offsets,
-                            block_tables, slot_mask, corrupt, key):
-                trace_counts["decode"] += 1
-                ids = jnp.clip(tok, 0, V - 1)[:, None]
-                logits, k, v, ks, vs = sm_dec(params, ids, k, v, ks, vs,
-                                              offsets, block_tables,
-                                              slot_mask)
-                logits = logits + corrupt[:, None]
-                finite = finite_logits_mask(logits)
-                nxt = sample_token(logits, key, temperature=temperature,
-                                   top_p=top_p)
-                return nxt, finite, k, v, ks, vs
-
-            @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5))
-            def mixed_step(params, ids, k, v, ks, vs, offsets, block_tables,
-                           slot_mask, seq_lens, corrupt, key):
-                trace_counts["prefill"] += 1
-                ids = jnp.clip(ids, 0, V - 1)
-                if spec:
-                    logits, greedy, k, v, ks, vs = sm_pre(
-                        params, ids, k, v, ks, vs, offsets, block_tables,
-                        slot_mask, seq_lens)
-                else:
-                    logits, k, v, ks, vs = sm_pre(
-                        params, ids, k, v, ks, vs, offsets, block_tables,
-                        slot_mask, seq_lens)
-                logits = logits + corrupt[:, None]
-                finite = finite_logits_mask(logits)
-                nxt = sample_token(logits, key, temperature=temperature,
-                                   top_p=top_p)
-                if spec:
-                    return nxt, finite, greedy, k, v, ks, vs
-                return nxt, finite, k, v, ks, vs
-
-            self._decode_step = decode_step
-            self._mixed_step = mixed_step
-            return
-
-        @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def decode_step(params, tok, k, v, offsets, block_tables, slot_mask,
-                        corrupt, key):
+        @functools.partial(jax.jit, donate_argnums=(2,))
+        def decode_step(params, tok, state, offsets, block_tables,
+                        slot_mask, corrupt, key):
             # Trace-time side effect: counts COMPILATIONS, not calls — the
             # one-compile-across-churn guarantee the tests assert on.
             trace_counts["decode"] += 1
             ids = jnp.clip(tok, 0, V - 1)[:, None]
-            logits, k, v, *stats = sm_dec(params, ids, k, v, offsets,
-                                          block_tables, slot_mask)
-            logits = logits + corrupt[:, None]
-            finite = finite_logits_mask(logits)
-            nxt = sample_token(logits, key, temperature=temperature,
-                               top_p=top_p)
-            return with_stats(nxt, stats), finite, k, v
+            logits, aux, state = sm_dec(params, ids, state, offsets,
+                                        block_tables, slot_mask)
+            return *sample(logits, aux, corrupt, key), state
 
-        @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def mixed_step(params, ids, k, v, offsets, block_tables, slot_mask,
+        @functools.partial(jax.jit, donate_argnums=(2,))
+        def mixed_step(params, ids, state, offsets, block_tables, slot_mask,
                        seq_lens, corrupt, key):
             trace_counts["prefill"] += 1
             ids = jnp.clip(ids, 0, V - 1)
-            if spec:
-                logits, greedy, k, v = sm_pre(params, ids, k, v, offsets,
-                                              block_tables, slot_mask,
-                                              seq_lens)
-            else:
-                logits, k, v, *stats = sm_pre(params, ids, k, v, offsets,
-                                              block_tables, slot_mask,
-                                              seq_lens)
-            logits = logits + corrupt[:, None]
-            finite = finite_logits_mask(logits)
-            nxt = sample_token(logits, key, temperature=temperature,
-                               top_p=top_p)
-            if not spec:
-                nxt = with_stats(nxt, stats)
-            # NaN injected at the last position (``corrupt``) only poisons
-            # ``nxt``; a REAL non-finite at an interior verify position
-            # propagates through causal attention to the last position, so
-            # the row-level ``finite`` mask covers ``greedy`` too.
-            if spec:
-                return nxt, finite, greedy, k, v
-            return nxt, finite, k, v
+            logits, aux, state = sm_pre(params, ids, state, offsets,
+                                        block_tables, slot_mask, seq_lens)
+            return *sample(logits, aux, corrupt, key), state
 
         self._decode_step = decode_step
         self._mixed_step = mixed_step
@@ -493,15 +438,21 @@ class BatchEngine:
         if other.engine is not self.engine:
             raise ValueError("share_steps_from requires the same model "
                              "Engine (one-model fleet design)")
+        def pool_format(be):
+            # Structure, shapes and dtypes of the donated operand.
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                be.pool.state)
+
         same = (self.n_slots == other.n_slots
                 and self.prefill_chunk == other.prefill_chunk
                 and self.paged_attn == other.paged_attn
-                and self.pool.kv_dtype == other.pool.kv_dtype
+                and pool_format(self) == pool_format(other)
                 and (self.spec is None) == (other.spec is None))
         if not same:
             raise ValueError("share_steps_from requires identical step "
                              "geometry (n_slots/prefill_chunk/paged_attn/"
-                             "kv_dtype/speculation)")
+                             "pool format/speculation)")
         self._decode_step = other._decode_step
         self._mixed_step = other._mixed_step
         self.trace_counts = other.trace_counts
@@ -1598,31 +1549,32 @@ class BatchEngine:
             self.metrics.inc(name, int(n))
         return nxt[:self.n_slots]
 
+    def _dispatch(self, site: str, step, span: str, ids, *extra, **attrs):
+        """What the two runners share: the slot operands, the call of the
+        compiled ``step`` through ``_call_step`` with the pool's state as
+        its donated operand (under the trace span ``span``), the model's
+        stats split off the tokens, and the state written back. Returns
+        ``(nxt, finite, greedy | None)`` on the host."""
+        offsets, tables, mask = self._operands()
+        state = self.pool.state
+        key = self._next_key()   # drawn ONCE — retries replay the same key
+        with _trace.span(span, **attrs,
+                         active=int(sum(s is not None for s in self._slots))):
+            nxt, finite, greedy, state = self._call_step(
+                site, lambda corrupt: step(
+                    self.engine.params, jnp.asarray(ids), state, offsets,
+                    tables, mask, *extra, corrupt, key))
+            greedy = jax.device_get(greedy)
+            nxt = self._take_stats(np.asarray(nxt))
+        self.pool.state = state
+        return nxt, finite, greedy
+
     def _run_decode(self):
         comm0 = self._eff_begin()
         tok = np.array([s.last_tok if s else 0 for s in self._slots],
                        np.int32)
-        offsets, tables, mask = self._operands()
-        st = self.pool.state
-        key = self._next_key()   # drawn ONCE — retries replay the same key
-        with _trace.span("decode_step",
-                         active=int(sum(s is not None for s in self._slots))):
-            if self.pool.kv_quant:
-                nxt, finite, k, v, ks, vs = self._call_step(
-                    "engine.decode",
-                    lambda corrupt: self._decode_step(
-                        self.engine.params, jnp.asarray(tok), st.k, st.v,
-                        st.k_scale, st.v_scale, offsets, tables, mask,
-                        corrupt, key))
-            else:
-                ks = vs = None
-                nxt, finite, k, v = self._call_step(
-                    "engine.decode",
-                    lambda corrupt: self._decode_step(
-                        self.engine.params, jnp.asarray(tok), st.k, st.v,
-                        offsets, tables, mask, corrupt, key))
-            nxt = self._take_stats(np.asarray(nxt))
-        self.pool.state = PagedKVState(k=k, v=v, k_scale=ks, v_scale=vs)
+        nxt, finite, _ = self._dispatch("engine.decode", self._decode_step,
+                                        "decode_step", tok)
         if self.efficiency is not None:
             rows, tenants = [], {}
             for s in self._slots:
@@ -1679,45 +1631,10 @@ class BatchEngine:
                     ids[i, 1:1 + len(props)] = props
                 seq_lens[i] = 1 + len(props)
                 dec_rows += 1
-        offsets, tables, mask = self._operands()
-        st = self.pool.state
-        key = self._next_key()   # drawn ONCE — retries replay the same key
-        greedy = None
-        with _trace.span("mixed_step",
-                         prefill_rows=int((seq_lens > 1).sum()),
-                         spec_rows=len(proposals),
-                         active=int(sum(s is not None for s in self._slots))):
-            quant = self.pool.kv_quant
-            ks = vs = None
-            if quant:
-                args = (st.k, st.v, st.k_scale, st.v_scale)
-            else:
-                args = (st.k, st.v)
-            if self.spec is not None:
-                out = self._call_step(
-                    "engine.prefill",
-                    lambda corrupt: self._mixed_step(
-                        self.engine.params, jnp.asarray(ids), *args,
-                        offsets, tables, mask, jnp.asarray(seq_lens),
-                        corrupt, key))
-                if quant:
-                    nxt, finite, greedy, k, v, ks, vs = out
-                else:
-                    nxt, finite, greedy, k, v = out
-                greedy = np.asarray(greedy)
-            else:
-                out = self._call_step(
-                    "engine.prefill",
-                    lambda corrupt: self._mixed_step(
-                        self.engine.params, jnp.asarray(ids), *args,
-                        offsets, tables, mask, jnp.asarray(seq_lens),
-                        corrupt, key))
-                if quant:
-                    nxt, finite, k, v, ks, vs = out
-                else:
-                    nxt, finite, k, v = out
-            nxt = self._take_stats(np.asarray(nxt))
-        self.pool.state = PagedKVState(k=k, v=v, k_scale=ks, v_scale=vs)
+        nxt, finite, greedy = self._dispatch(
+            "engine.prefill", self._mixed_step, "mixed_step", ids,
+            jnp.asarray(seq_lens), prefill_rows=int((seq_lens > 1).sum()),
+            spec_rows=len(proposals))
         if self.efficiency is not None:
             rows, tenants = [], {}
             for i, s in enumerate(self._slots):

@@ -19,8 +19,15 @@ request, so a fixed HBM budget serves many more concurrent sequences.
 Device arrays are a functional pytree (``PagedKVState``) updated in place
 under jit via buffer donation, exactly like ``KVCache``; the pool is
 sharded over the TP axis on the kv-head dim with the SAME PartitionSpec
-(``KVCache.spec``) — both layouts keep kv-heads at index 3, so the paged
-step's shard_map reuses the contiguous cache's one spec definition.
+(``KVCache.spec``) — both layouts keep kv-heads at index 3.
+
+``PagedKVState`` is the ONE description of the pool's format: which arenas
+exist and (``paged_state_specs``) how each is sharded. The compiled steps,
+``Engine._make_sm`` and the model classes pass it whole — in as the one
+donated operand, through the layer scan as carry, out as one result — and
+only the attention layers (``layers/tp_attn.py``, ``layers/mla_attn.py``),
+which need the arrays, read its fields. A format that needs another arena
+adds a field here and reads it in its own layer.
 
 The allocator is deliberately plain Python: allocation decisions are
 host-side control flow between compiled steps (the reference engine makes
@@ -53,6 +60,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from triton_distributed_tpu.models.kv_cache import KVCache
 from triton_distributed_tpu.resilience import faults as _faults
@@ -124,8 +132,9 @@ class PagedKVState:
 
     Quantized pools (``kv_dtype="int8"|"fp8"``) carry two extra arrays:
     per-row f32 dequantization scales, shaped like the K/V arenas minus
-    head_dim. ``None`` (the unquantized default) is an empty pytree
-    subtree, so existing two-array construction sites keep working.
+    head_dim. A field that is ``None`` is an arena the format does not
+    have (an empty pytree subtree): the tree's structure IS the format.
+    Every arena keeps (layer, block) as its two leading axes.
     """
 
     k: jax.Array   # (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
@@ -141,6 +150,22 @@ class PagedKVState:
     @property
     def block_size(self) -> int:
         return self.k.shape[2]
+
+
+def paged_state_specs(config, axis: str = "tp", *,
+                      quant: bool = False) -> PagedKVState:
+    """The pool's ``PartitionSpec``s as a ``PagedKVState`` of the structure
+    the pool's state has: K and V arenas sharded over ``axis`` on the
+    kv-head dim (``KVCache.spec``), a quantized pool's scale arenas the
+    same minus head_dim (``KVCache.scale_spec``); a latent pool
+    (``config.kv_row_shapes`` names no V row) is one replicated arena,
+    its row shared by every head. ``KVPool`` allocates under these and
+    the paged step's shard_map takes them as the state's in/out specs."""
+    latent = config.kv_row_shapes[1] is None
+    kv = PartitionSpec() if latent else KVCache.spec(axis)[0]
+    scale = KVCache.scale_spec(axis) if quant else None
+    return PagedKVState(k=kv, v=None if latent else kv,
+                        k_scale=scale, v_scale=scale)
 
 
 class KVPool:
@@ -181,27 +206,27 @@ class KVPool:
                 "a latent pool has no quantized build (its one row is both "
                 "key and value; the per-head row scales do not apply)")
         shape = (config.n_layers, n_blocks, block_size, *k_row)
-        sh = ssh = None
-        if mesh is not None:
-            from jax.sharding import PartitionSpec
-
-            from triton_distributed_tpu.runtime.mesh import sharding_for
-
-            # A latent row is shared by every head: replicated.
-            sh = sharding_for(PartitionSpec() if self.latent
-                              else KVCache.spec(axis)[0], mesh)
-            ssh = sharding_for(KVCache.scale_spec(axis), mesh)
+        #: PartitionSpecs of ``state``, leaf for leaf.
+        self.specs = paged_state_specs(config, axis, quant=self.kv_quant)
         # Each arena is born in its sharded layout: ``jnp.zeros`` +
         # ``device_put`` would build the WHOLE pool on the default device
         # first — on four chips, all of it on chip 0 beside its weight
         # shard (``Qwen3.init`` allocates the same way).
-        k = _zeros(shape, self.kv_dtype, sh)
-        v = None if self.latent else _zeros(shape, self.kv_dtype, sh)
-        ks = vs = None
-        if self.kv_quant:
-            ks = _zeros(shape[:-1], jnp.float32, ssh)
-            vs = _zeros(shape[:-1], jnp.float32, ssh)
-        self.state = PagedKVState(k=k, v=v, k_scale=ks, v_scale=vs)
+        def arena(spec, shape, dtype):
+            if spec is None:
+                return None             # an arena this format does not have
+            if mesh is None:
+                return _zeros(shape, dtype, None)
+            from triton_distributed_tpu.runtime.mesh import sharding_for
+
+            return _zeros(shape, dtype, sharding_for(spec, mesh))
+
+        sp = self.specs
+        self.state = PagedKVState(
+            k=arena(sp.k, shape, self.kv_dtype),
+            v=arena(sp.v, shape, self.kv_dtype),
+            k_scale=arena(sp.k_scale, shape[:-1], jnp.float32),
+            v_scale=arena(sp.v_scale, shape[:-1], jnp.float32))
         # LIFO free list, low block ids first out — recently freed blocks
         # are reused immediately (warm in whatever cache level they touched).
         self._free: list[int] = list(range(n_blocks - 1, -1, -1))
@@ -499,22 +524,14 @@ class KVPool:
         all pool arrays donated (the copy is in-place for HBM accounting,
         like the steps)."""
         if self._cow_jit is None:
-            @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-            def cow(k, v, ks, vs, s, d):
-                k = k.at[:, d].set(k[:, s])
-                if v is not None:
-                    v = v.at[:, d].set(v[:, s])
-                if ks is not None:
-                    ks = ks.at[:, d].set(ks[:, s])
-                    vs = vs.at[:, d].set(vs[:, s])
-                return k, v, ks, vs
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def cow(state, s, d):
+                return jax.tree.map(lambda a: a.at[:, d].set(a[:, s]), state)
 
             self._cow_jit = cow
-        st = self.state
-        k, v, ks, vs = self._cow_jit(
-            st.k, st.v, st.k_scale, st.v_scale,
-            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
-        self.state = PagedKVState(k=k, v=v, k_scale=ks, v_scale=vs)
+        self.state = self._cow_jit(
+            self.state, jnp.asarray(src, jnp.int32),
+            jnp.asarray(dst, jnp.int32))
 
     def fragmentation(self) -> dict:
         """Free-list fragmentation stats for the perf flight recorder:
