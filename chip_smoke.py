@@ -141,7 +141,9 @@ def paged_logits(be, prompts, next_tok):
     """Logits of the PAGED programs on ``be``'s own pool for equal-length
     ``prompts``: chunked prefill through ``Engine._make_sm(paged="prefill")``
     (the mixed step's forward, which returns logits where the serving step
-    returns sampled tokens), then one decode-shaped step
+    returns sampled tokens; its token batch the served one, an idle decode
+    block beside the prompts' rows of the prefill block), then one
+    decode-shaped step
     (``paged="decode"``) feeding ``next_tok``; the pool's state goes in and
     comes back whole, donated, as in the serving steps. Returns float32
     ``(prefill_last_position_logits, decode_logits)``, one row a prompt."""
@@ -166,12 +168,19 @@ def paged_logits(be, prompts, next_tok):
         live = np.arange(n) < n_p
         mask = jnp.asarray(live)
         toks = np.asarray(prompts, np.int32)
+        check(n_p <= be.prefill_rows, f"{n_p} reference prompts do not fit "
+              f"a prefill block of {be.prefill_rows} rows")
         for off in range(0, plen, chunk):
             take = min(chunk, plen - off)
-            ids = np.zeros((n, chunk), np.int32)
-            ids[:n_p, :take] = toks[:, off:off + take]
+            tok = np.zeros((n,), np.int32)
+            block = np.zeros((be.prefill_rows, chunk), np.int32)
+            if take == 1:        # one token rides the decode block
+                tok[:n_p] = toks[:, off]
+            else:
+                block[:n_p, :take] = toks[:, off:off + take]
             pre_logits, _, pool.state = pre(
-                eng.params, jnp.asarray(ids), pool.state,
+                eng.params, (jnp.asarray(tok), jnp.asarray(block)),
+                pool.state,
                 jnp.asarray(np.where(live, off, 0).astype(np.int32)),
                 tables, mask,
                 jnp.asarray(np.where(live, take, 0).astype(np.int32)))

@@ -72,13 +72,17 @@ import numpy as np
 
 
 def _wall_clock_arm_attn() -> str:
-    """``paged_attn`` for the two arms whose SLO windows and detectors are
-    wall-clock seconds sized for steps of milliseconds (``--adaptive``,
-    ``--incidents``): the fused kernel where it is compiled, its
-    bit-identical gather oracle where it would be interpreted. Interpreted,
-    a step costs ~0.3 s, ``main_adaptive``'s 2 s fast window never holds
-    ``min_count`` TTFT samples and WARN cannot fire. So on a CPU these two
-    arms do not cover the fused kernel; every other arm does."""
+    """``paged_attn`` for the arms whose verdicts are made of wall-clock
+    seconds sized for steps of milliseconds — the SLO windows and
+    detectors of ``--adaptive`` and ``--incidents``, ``--slo``'s verdicts,
+    and ``--whatif``'s cost model, calibrated from the seconds its steps
+    took: the fused kernel where it is compiled, its bit-identical gather
+    oracle where it would be interpreted. Interpreted, a kernel call costs
+    ~0.3 s (and a mixed step makes two): ``main_adaptive``'s 2 s fast
+    window never holds ``min_count`` TTFT samples and WARN cannot fire, a
+    first token waits for seconds of compilation on a busy machine, and a
+    prefilling step is priced by the interpreter, not by its work. So on a
+    CPU these arms do not cover the fused kernel; every other arm does."""
     from triton_distributed_tpu.runtime.platform import on_tpu
 
     return "fused" if on_tpu() else "gather"
@@ -747,7 +751,8 @@ def main_whatif(*, seed: int = 0, n_requests: int = 10,
     config = ModelConfig.from_name("tiny")
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     fleet = Fleet.build(engine, n_replicas=2, n_slots=4, n_blocks=24,
-                        block_size=4, prefill_chunk=8, seed=seed)
+                        block_size=4, prefill_chunk=8, seed=seed,
+                        paged_attn=_wall_clock_arm_attn())
     if fleet.serve_trace is None:
         raise RuntimeError("ServeTrace not attached — recording must be "
                            "always-on by default")
@@ -989,6 +994,7 @@ def main(duration_s: float = 30.0, *, rate_hz: float = 4.0, n_slots: int = 4,
     # not luck.
     be = BatchEngine(engine, n_slots=n_slots, n_blocks=n_blocks,
                      block_size=4, prefill_chunk=8,
+                     paged_attn=_wall_clock_arm_attn() if slo else "fused",
                      retry=RetryPolicy(retries=6, base_delay_s=0.001)
                      if chaos else None)
     slo_engine = None

@@ -105,10 +105,15 @@ def _tp4_compile(tp4, fn, in_specs, out_specs, *shapes):
 
 
 # Rows per device: a decode step at 8 slots over TP=4 (2 — below the
-# sublane tile, padded inside the kernel wrappers) and a mixed step
-# (8 slots x chunk 64 / 4).
-@pytest.mark.parametrize("rows", [SLOTS // 4, SLOTS * CHUNK // 4],
-                         ids=["decode-rows", "chunk-rows"])
+# sublane tile, padded inside the kernel wrappers), a dense chunk
+# (8 slots x chunk 64 / 4) and the served mixed step's two blocks (8 slots
+# + a prefill block of 7 rows x chunk 64, / 4 = 114: not a sublane multiple).
+ROWS = dict(argvalues=[SLOTS // 4, SLOTS * CHUNK // 4,
+                       (SLOTS + 7 * CHUNK) // 4],
+            ids=["decode-rows", "chunk-rows", "mixed-rows"])
+
+
+@pytest.mark.parametrize("rows", **ROWS)
 @pytest.mark.parametrize("proj", ["qkv", "gate_up"])
 def test_ag_gemm_compiles_tp4(tp4, proj, rows):
     from triton_distributed_tpu.kernels.allgather_gemm import (
@@ -127,8 +132,7 @@ def test_ag_gemm_compiles_tp4(tp4, proj, rows):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("rows", [SLOTS // 4, SLOTS * CHUNK // 4],
-                         ids=["decode-rows", "chunk-rows"])
+@pytest.mark.parametrize("rows", **ROWS)
 @pytest.mark.parametrize("proj", ["o", "down"])
 def test_gemm_rs_compiles_tp4(tp4, proj, rows):
     from triton_distributed_tpu.kernels.gemm_reduce_scatter import (

@@ -143,8 +143,11 @@ def test_absorbed_attention_equals_the_expanded_form(served):
     x = jax.random.normal(jax.random.PRNGKey(1), (B, L, m.d_model))
     state = PagedKVState(k=jnp.zeros((8, 4, attn.cache_row)), v=None)
     tables = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
-    out, state = attn.fwd(p, x, state, jnp.zeros((B,), jnp.int32),
-                          block_tables=tables, paged_attn="gather")
+    block = nn.TokenBlock(0, L, jnp.zeros((B,), jnp.int32), tables, None,
+                          None)
+    out, state = attn.fwd(p, x.reshape(B * L, -1), state, blocks=(block,),
+                          paged_attn="gather")
+    out = out.reshape(B, L, -1)
     assert state.v is None
     # the cache row: the normalised latent, then the rotated key, then zeros
     rows = np.asarray(state.k).reshape(2, 16, -1)[:, :L]

@@ -537,6 +537,257 @@ def test_the_steps_take_the_state_whole_and_return_one_record(
         assert f"@jit_{name.lstrip('_')}" in lowered.as_text()
 
 
+# -- 6. the mixed step's two blocks -------------------------------------------
+
+from triton_distributed_tpu.serving import batch_engine as _be_mod
+
+
+def _small_block(monkeypatch, n_slots, chunk, rows):
+    """Steer the derived height of the prefill block from the test: the
+    budget is a module constant, not an option."""
+    monkeypatch.setattr(_be_mod, "MIXED_STEP_TOKEN_BUDGET",
+                        n_slots + rows * chunk)
+
+
+def _recording(be, name="_mixed_step"):
+    """Wrap a compiled step of ``be``: every call's host-side operands."""
+    calls, step = [], getattr(be, name)
+
+    def call(*args):
+        calls.append(jax.tree.map(np.asarray, args[1:2] + args[3:7]))
+        return step(*args)
+    setattr(be, name, call)
+    return calls
+
+
+def _one_at_a_time(engine, prompts, gen, **kw):
+    """The oracle: the same engine class serving each request ALONE (one
+    row at a time: nothing waits, nothing shares a step)."""
+    out = []
+    be = BatchEngine(engine, **kw)
+    for p in prompts:
+        rid = be.submit(p, max_new_tokens=gen)
+        out.append(be.run(max_steps=400)[rid])
+    assert be.metrics.counters.get("prefill_rows_deferred", 0) == 0
+    return out
+
+
+@pytest.mark.parametrize("n_slots,chunk,speculative,budget,rows", [
+    (32, 64, False, 256, 3), (32, 64, False, 512, 7), (32, 64, False, 128, 1),
+    (32, 64, False, 64, 1), (4, 8, False, 256, 4), (8, 64, False, 256, 3),
+    (4, 8, True, 12, 4)])
+def test_the_prefill_blocks_height_is_derived(setup, monkeypatch, n_slots,
+                                              chunk, speculative, budget,
+                                              rows):
+    """``prefill_rows`` is what fits beside the decode block in the token
+    budget, at least one row and never more than the slots; with
+    speculation every slot has a row (any decode row may be a verify row).
+    Nothing is compiled until a step is called."""
+    monkeypatch.setattr(_be_mod, "MIXED_STEP_TOKEN_BUDGET", budget)
+    be = BatchEngine(setup[2], n_slots=n_slots, n_blocks=8, block_size=4,
+                     prefill_chunk=chunk, speculative=speculative)
+    assert be.prefill_rows == rows
+
+
+@pytest.mark.parametrize("model,paged_attn", [
+    ("qwen", "gather"), ("qwen", "fused"), ("latent", "gather"),
+    ("latent", "fused")])
+def test_more_rows_prefilling_than_the_block_holds(
+        setup, latent_engine, monkeypatch, model, paged_attn):
+    """Churn with MORE rows prefilling at once than the prefill block
+    holds (4 slots, a block of one row on the gather path and of two on
+    the fused one, where three prompts arrive together): rows wait their
+    turn, decode rows ride the decode block beside them, and every
+    request's greedy stream equals the one it gets served alone; one
+    compile a step across all of it."""
+    engine = latent_engine if model == "latent" else setup[2]
+    fused = paged_attn == "fused"
+    n_slots, chunk, rows = 4, 8, (2 if fused else 1)
+    _small_block(monkeypatch, n_slots, chunk, rows)
+    kw = dict(n_slots=n_slots, block_size=4, prefill_chunk=chunk,
+              paged_attn=paged_attn)
+    rng = np.random.default_rng(11)
+    specs = [(19, 2), (9, 2), (12, 2)] if fused else \
+        [(19, 5), (9, 4), (12, 6), (3, 4), (26, 3), (17, 4)]
+    prompts = [rng.integers(0, engine.config.vocab_size, size=n).tolist()
+               for n, _ in specs]
+    be = BatchEngine(engine, **kw)
+    assert be.prefill_rows == rows
+    calls = _recording(be)
+    rids = [be.submit(p, max_new_tokens=g)
+            for p, (_, g) in zip(prompts[:4], specs)]
+    be.step(), be.step()
+    rids += [be.submit(p, max_new_tokens=g)
+             for p, (_, g) in zip(prompts[4:], specs[4:])]
+    out = be.run(max_steps=400)
+    assert be.trace_counts == {"decode": 1, "prefill": 1}
+    c = be.metrics.counters
+    assert c["prefill_rows_deferred"] > 0
+    assert max(int((sl > 1).sum()) for _, _, _, _, sl in calls) == rows
+    # decode rows rode a step whose block was full
+    assert any((sl == 1).any() and (sl > 1).sum() == rows
+               for _, _, _, _, sl in calls)
+    want = {}
+    for g in sorted({g for _, g in specs}):
+        idx = [i for i, (_, gi) in enumerate(specs) if gi == g]
+        # the oracle on the gather path: token-identical to the fused one
+        # (tests/test_paged_attention.py) at a thousandth of its cost here
+        for i, o in zip(idx, _one_at_a_time(
+                engine, [prompts[i] for i in idx], g,
+                **dict(kw, paged_attn="gather"))):
+            want[i] = o
+    for i, rid in enumerate(rids):
+        assert out[rid] == want[i], f"request {i} diverged"
+    if model == "qwen":
+        np.testing.assert_array_equal(
+            np.asarray(out[rids[0]], np.int32),
+            _golden(engine, prompts[0], specs[0][1]))
+    be.pool.check_invariants()
+
+
+@pytest.mark.parametrize("model", ["qwen", "latent"])
+def test_deferral_is_oldest_admitted_first_and_counted(
+        setup, latent_engine, monkeypatch, model):
+    """Three prompts admitted together into a block of ONE row: each step
+    the oldest admitted row still prefilling takes a chunk, the others
+    take nothing and are counted; a decode row keeps its one token a step.
+    ``mixed_step_tokens`` and ``prefill_rows_deferred`` (counters, and
+    attributes of the ``mixed_step`` span) say what happened."""
+    from triton_distributed_tpu.obs import trace as _trace
+
+    engine = latent_engine if model == "latent" else setup[2]
+    n_slots, chunk = 4, 8
+    _small_block(monkeypatch, n_slots, chunk, 1)
+    be = BatchEngine(engine, n_slots=n_slots, block_size=4,
+                     prefill_chunk=chunk, paged_attn="gather")
+    calls = _recording(be)
+    rng = np.random.default_rng(13)
+    lens = (20, 12, 10)
+    rids = [be.submit(rng.integers(0, engine.config.vocab_size,
+                                   size=n).tolist(), max_new_tokens=3)
+            for n in lens]
+    with _trace.tracing() as tracer:
+        tracer.reset()
+        be.run(max_steps=100)
+        spans = [r for r in tracer.records if r.name == "mixed_step"]
+    assert set(be.finished) == set(rids)
+    # Admission order is submission order (FIFO, equal priority) and the
+    # slots fill in order: slot i holds request i.
+    takes = np.stack([sl for _, _, _, _, sl in calls])       # (steps, slots)
+    assert takes.shape[1] == n_slots and not takes[:, 3].any()
+    # 20 = 8+8+4, 12 = 8+4, 10 = 8+2: six steps with a row in the block
+    want = [[8, 0, 0], [8, 0, 0], [4, 0, 0], [1, 8, 0], [1, 4, 0],
+            [0, 1, 8], [0, 1, 2]]
+    assert takes[:7, :3].tolist() == want
+    deferred = [2, 2, 2, 1, 1, 0, 0]
+    c = be.metrics.counters
+    assert c["prefill_rows_deferred"] == sum(deferred)
+    assert c["mixed_step_tokens"] == takes.sum()
+    assert c["prefill_tokens"] == sum(lens)
+    assert c["prefill_steps"] == len(calls) == len(spans)
+    assert [s.attrs["prefill_rows_deferred"] for s in spans[:7]] == deferred
+    assert [s.attrs["mixed_step_tokens"] for s in spans] == \
+        takes.sum(axis=1).tolist()
+    assert all(s.attrs["prefill_rows"] <= 1 for s in spans)
+
+
+@pytest.mark.parametrize("model,paged_attn", [
+    ("qwen", "gather"), ("qwen", "fused"), ("latent", "gather")])
+def test_a_last_take_of_one_token_rides_the_decode_block(
+        setup, latent_engine, model, paged_attn):
+    """A prompt one token longer than a chunk: its second take is ONE
+    token, which needs no row of the prefill block — the step that serves
+    it carries an empty block — and the stream is the one served by whole
+    chunks of another width."""
+    engine = latent_engine if model == "latent" else setup[2]
+    chunk = 8
+    kw = dict(n_slots=2, block_size=4, paged_attn=paged_attn)
+    prompt = np.random.default_rng(17).integers(
+        0, engine.config.vocab_size, size=chunk + 1).tolist()
+    be = BatchEngine(engine, prefill_chunk=chunk, **kw)
+    calls = _recording(be)
+    rid = be.submit(prompt, max_new_tokens=3)
+    out = be.run(max_steps=50)[rid]
+    (_, chunk0), _, _, _, sl0 = calls[0]
+    (tok1, chunk1), offsets, _, _, sl1 = calls[1]
+    assert sl0.tolist() == [chunk, 0] and chunk0[0].tolist() == prompt[:chunk]
+    assert sl1.tolist() == [1, 0] and offsets[0] == chunk
+    assert tok1[0] == prompt[-1] and not chunk1.any()
+    assert len(calls) == 2 and be.metrics.counters["prefill_tokens"] == 9
+    other, = _one_at_a_time(engine, [prompt], 3, prefill_chunk=chunk + 4,
+                            **kw)
+    assert out == other
+
+
+@pytest.mark.parametrize("fmt", ["bf16", "int8", "latent"])
+@pytest.mark.parametrize("paged_attn", ["fused", "gather"])
+def test_two_blocks_equal_the_dense_block(setup, latent_engine, paged_attn,
+                                          fmt):
+    """One hand-made mixed step both ways, on a pool full of data: as the
+    dense (slots, L) block and as the pair (one token a slot, a block of
+    two rows of L for the slots that take more). The same rows are
+    appended, bit for bit in the first layer, the rest of the pool is the
+    input's, and the live slots' logits agree; a slot that takes nothing
+    and a dead slot with stale tables write nothing."""
+    engine = latent_engine if fmt == "latent" else setup[2]
+    sm, args, written = _paged_step(engine, "prefill", paged_attn, fmt)
+    ids, seq_lens = np.asarray(args[1]), np.asarray([4, 1, 3, 0], np.int32)
+    mask = np.asarray([True, True, False, True])
+    offsets, tables = np.asarray(args[3]), np.asarray(args[4])
+    written = {(int(tables[b, (offsets[b] + l) // _BS]),
+                int((offsets[b] + l) % _BS))
+               for b in range(4) if mask[b] for l in range(seq_lens[b])}
+    args[5], args[6] = jnp.asarray(mask), jnp.asarray(seq_lens)
+    dense_logits, _, dense = jax.jit(sm)(*args)
+    chunk = np.zeros((2, ids.shape[1]), np.int32)
+    chunk[0] = ids[0]                  # slot 0: the one row that takes > 1
+    pair = (jnp.asarray(ids[:, 0]), jnp.asarray(chunk))
+    logits, _, state = jax.jit(sm)(args[0], pair, *args[2:])
+    assert jax.tree.structure(state) == jax.tree.structure(args[2])
+    for before, d, t in zip(*map(jax.tree.leaves, (args[2], dense, state))):
+        before, d, t = map(np.asarray, (before, d, t))
+        touched = np.zeros(before.shape[:3], bool)
+        for blk, line in written:
+            touched[:, blk, line] = True
+        np.testing.assert_array_equal(t[~touched], before[~touched])
+        np.testing.assert_array_equal(t[0], d[0])
+        np.testing.assert_allclose(t.astype(np.float32),
+                                   d.astype(np.float32), rtol=0, atol=1e-5)
+    live = mask & (seq_lens > 0)
+    np.testing.assert_allclose(np.asarray(logits)[live],
+                               np.asarray(dense_logits)[live],
+                               rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("fmt", ["model-dtype", "int8", "latent"])
+def test_mixed_step_keeps_no_pool_sized_temporary(setup, latent_engine, fmt):
+    """The compiled two-block step (the CPU lowering: it says what is
+    compiled, no time): the state is donated leaf for leaf and aliased to
+    the output whole, and the temporaries are activations of ``T``
+    positions, far under one arena — two appends and two attention calls
+    on the carried state did not bring back a copy of the pool (PERF.md,
+    PR 26)."""
+    engine = latent_engine if fmt == "latent" else setup[2]
+    be = BatchEngine(engine, n_slots=4, n_blocks=8192, block_size=4,
+                     prefill_chunk=8, paged_attn="gather",
+                     kv_dtype="int8" if fmt == "int8" else None)
+    calls = []
+    step = be._mixed_step
+    be._mixed_step = lambda *a: calls.append(a) or step(*a)
+    be.submit([1, 2, 3, 4, 5], max_new_tokens=1)
+    be.run(max_steps=5)
+    args = calls[0][:2] + (be.pool.state,) + calls[0][3:]
+    lowered = step.lower(*args)
+    infos, _ = lowered.args_info
+    assert all(i.donated for i in jax.tree.leaves(infos[2]))
+    mem = lowered.compile().memory_analysis()
+    leaves = jax.tree.leaves(be.pool.state)
+    pool_bytes = sum(a.nbytes for a in leaves)
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < min(a.nbytes for a in leaves
+                                        if a.ndim == leaves[0].ndim) // 4
+
+
 def test_pool_sharded_over_kv_heads(mesh8):
     config = ModelConfig.from_name("tiny")
     pool = KVPool(config, n_blocks=16, block_size=4, mesh=mesh8)

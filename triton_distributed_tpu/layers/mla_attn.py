@@ -68,17 +68,23 @@ class MLAttn:
             "w_o": (H * self.v_dim, d),
         }
 
-    def fwd(self, params, x, state, offset, *, block_tables, slot_mask=None,
-            seq_lens=None, paged_attn: str = "fused", layer=None,
-            interpret=None):
-        """x (B, L, d) -> (attention output (B, L, d), updated state).
+    def fwd(self, params, x, state, *, blocks, paged_attn: str = "fused",
+            layer=None, interpret=None):
+        """x (T, d), a paged step's flat token batch -> (attention output
+        (T, d), updated state). ``blocks`` (``nn.TokenBlock``s) says which
+        runs of it are which sequences' new tokens: the projections run
+        over the flat batch; the rotation, the append and attention a block
+        at a time, each with its own offsets, tables, mask and lengths —
+        every block's rows are appended before any block is attended, as
+        in ``TPAttn._attend`` — and positions no block owns give zeros.
         ``state`` is the pool's state (``serving.kv_pool.PagedKVState``),
         taken and returned whole; its one arena ``k`` is one layer of the
-        latent pool or, with ``layer``, the stacked arena: the new rows are
-        appended where it lies and attention reads them back through the
-        block table."""
-        B, L, _ = x.shape
-        H, r = self.n_heads, self.kv_lora_rank
+        latent pool or, with ``layer``, the stacked arena: the new rows
+        are appended where it lies and attention reads them back through
+        the block table."""
+        T = x.shape[0]
+        H, r, rope = self.n_heads, self.kv_lora_rank, self.rope
+        pad = self.cache_row - r - rope
         f32 = jnp.float32
 
         def dot(a, w):
@@ -86,37 +92,42 @@ class MLAttn:
 
         cq = nn.rms_norm(dot(x, params["w_qa"]), params["q_a_norm"],
                          self.rms_eps)
-        q = dot(cq, params["w_qb"]).reshape(B, L, H, self.nope + self.rope)
-        q_nope, q_rope = q[..., :self.nope], q[..., self.nope:]
+        q = dot(cq, params["w_qb"]).reshape(T, H, self.nope + rope)
         ckv = dot(x, params["w_kva"])
         c_kv = nn.rms_norm(ckv[..., :r], params["kv_a_norm"], self.rms_eps)
-        k_r = ckv[..., r:][:, :, None, :]                    # (B, L, 1, rope)
-        offset = jnp.asarray(offset, jnp.int32)
-        positions = offset.reshape(-1, 1) + jnp.arange(L)
-        cos, sin = nn.rope_angles(positions, self.rope, self.rope_theta)
-        q_rope = nn.apply_rope_interleaved(q_rope, cos, sin)
-        k_r = nn.apply_rope_interleaved(k_r, cos, sin)[:, :, 0]
-
-        pad = self.cache_row - r - self.rope
-        row = jnp.concatenate(
-            [c_kv, k_r, jnp.zeros((B, L, pad), x.dtype)], axis=-1)
-        wm = slot_mask
-        if seq_lens is not None:
-            tok_valid = jnp.arange(L)[None] < seq_lens[:, None]
-            wm = tok_valid if wm is None else (wm[:, None] & tok_valid)
-        pool = nn.paged_cache_update(state.k, row, block_tables, offset, wm,
-                                     layer=layer)
-
-        q_lat = jnp.einsum("blhn,hnc->blhc", q_nope, params["w_kvb_k"],
+        q_lat = jnp.einsum("thn,hnc->thc", q[..., :self.nope],
+                           params["w_kvb_k"],
                            preferred_element_type=f32).astype(x.dtype)
-        q_full = jnp.concatenate(
-            [q_lat, q_rope, jnp.zeros((B, L, H, pad), x.dtype)], axis=-1)
-        o_lat = nn.latent_attn_with_cache(
-            q_full, pool, block_tables, offset, v_dim=r,
-            scale=(self.nope + self.rope) ** -0.5, slot_mask=slot_mask,
-            seq_lens=seq_lens, interpret=interpret, paged_attn=paged_attn,
-            layer=layer)
-        o = jnp.einsum("blhc,hcv->blhv", o_lat, params["w_kvb_v"],
+
+        pool, queries = state.k, []
+        for blk in blocks:
+            span, rows = slice(blk.start, blk.stop), blk.offsets.shape[0]
+            positions = blk.offsets[:, None] + jnp.arange(blk.L)
+            cos, sin = nn.rope_angles(positions, rope, self.rope_theta)
+            q_rope = nn.apply_rope_interleaved(
+                q[span, :, self.nope:].reshape(rows, blk.L, H, rope),
+                cos, sin)
+            k_r = nn.apply_rope_interleaved(
+                ckv[span, r:].reshape(rows, blk.L, 1, rope), cos, sin)
+            queries.append(jnp.concatenate(
+                [q_lat[span].reshape(rows, blk.L, H, r), q_rope,
+                 jnp.zeros((rows, blk.L, H, pad), x.dtype)], axis=-1))
+            row = jnp.concatenate(
+                [c_kv[span].reshape(rows, blk.L, r), k_r[:, :, 0],
+                 jnp.zeros((rows, blk.L, pad), x.dtype)], axis=-1)
+            pool = nn.paged_cache_update(
+                pool, row, blk.tables, blk.offsets,
+                blk.valid().reshape(rows, blk.L), layer=layer)
+        outs = [nn.latent_attn_with_cache(
+            q_full, pool, blk.tables, blk.offsets, v_dim=r,
+            scale=(self.nope + rope) ** -0.5, slot_mask=blk.mask,
+            seq_lens=blk.seq_lens, interpret=interpret,
+            paged_attn=paged_attn, layer=layer).reshape(-1, H, r)
+            for q_full, blk in zip(queries, blocks)]
+        if T > blocks[-1].stop:
+            outs.append(jnp.zeros((T - blocks[-1].stop, H, r), x.dtype))
+        o = jnp.einsum("thc,hcv->thv", jnp.concatenate(outs),
+                       params["w_kvb_v"],
                        preferred_element_type=f32).astype(x.dtype)
-        return (dot(o.reshape(B, L, H * self.v_dim), params["w_o"]),
+        return (dot(o.reshape(T, H * self.v_dim), params["w_o"]),
                 dataclasses.replace(state, k=pool))

@@ -9,6 +9,9 @@ the Pallas fast paths (flash decode) live in ``kernels/``.
 
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -44,6 +47,97 @@ def _warn_dense_fallback(B, L, Hq, dh, S, Hkv):
         f"aligned sizes to avoid this at long context.",
         stacklevel=3)
 
+
+
+class TokenBlock(NamedTuple):
+    """``offsets.shape[0]`` sequences of ``L`` new-token positions each,
+    lying row-major at ``[start, start + rows * L)`` of a paged step's flat
+    token batch. Everything outside attention (embedding, norms, linear
+    layers, experts, the residual stream) sees the flat batch; rope, the
+    append and attention see one block at a time, each with its own rows
+    of the step's slot operands."""
+
+    start: int                 # static
+    L: int                     # static
+    offsets: jax.Array         # (rows,) cache length before this step
+    tables: jax.Array          # (rows, max_blocks) int32
+    mask: jax.Array | None     # (rows,) live rows; None = all
+    seq_lens: jax.Array | None  # (rows,) valid new tokens; None = all L
+
+    @property
+    def stop(self) -> int:
+        return self.start + self.offsets.shape[0] * self.L
+
+    def valid(self):
+        """(rows * L,) bool: this block's live token positions."""
+        rows = self.offsets.shape[0]
+        v = jnp.ones((rows, self.L), bool)
+        if self.mask is not None:
+            v &= self.mask[:, None]
+        if self.seq_lens is not None:
+            v &= jnp.arange(self.L)[None] < self.seq_lens[:, None]
+        return v.reshape(-1)
+
+
+def paged_token_blocks(ids, offsets, block_tables, slot_mask, seq_lens=None,
+                       *, multiple: int = 1):
+    """The flat token batch of a paged step and the blocks it is made of:
+    ``(flat ids (T,), blocks, last (B,))``, ``last[b]`` the flat position
+    whose hidden state gives slot b's next-token logits.
+
+    ``ids`` an array (B, L): ONE block of B rows of L positions (the decode
+    step's (B, 1); a dense varlen chunk with ``seq_lens``), ``T = B * L``.
+
+    ``ids`` a pair ``(tok (B,), chunk (P, L))``: the mixed step's TWO
+    blocks. A DECODE block of B rows of one token — slot b is live in it
+    where ``seq_lens[b] == 1`` (a decode row, or a prefilling row whose take
+    is one token) — and a PREFILL block of P rows of L tokens, row k
+    belonging to the k-th slot (ascending) with ``seq_lens > 1``: its
+    block-table row, offset and length are gathered here, once a step. A
+    slot with ``seq_lens`` 0 (empty, or a prefilling row that waits for a
+    place in the block) is dead in both, as an empty slot is in the decode
+    step: nothing is appended for it, its attention walks no context
+    (cache length 0 before this step), and its logits are garbage the
+    host does not read. ``T = B + P * L``, rounded up to
+    ``multiple`` (a tensor-parallel mode shards the batch's rows) with
+    positions no block owns.
+    """
+    offsets = jnp.asarray(offsets, jnp.int32)
+    B = offsets.shape[0]
+    if not isinstance(ids, (tuple, list)):
+        L = ids.shape[1]
+        idx = (jnp.full((B,), L - 1, jnp.int32) if seq_lens is None
+               else jnp.maximum(jnp.asarray(seq_lens, jnp.int32) - 1, 0))
+        blocks = (TokenBlock(0, L, offsets, block_tables, slot_mask,
+                             seq_lens),)
+        flat, last = ids.reshape(-1), jnp.arange(B) * L + idx
+    else:
+        if seq_lens is None:
+            raise ValueError("the two-block batch is a varlen step: it "
+                             "needs seq_lens")
+        tok, chunk = ids
+        P, L = chunk.shape
+        seq_lens = jnp.asarray(seq_lens, jnp.int32)
+        if slot_mask is not None:
+            seq_lens = jnp.where(slot_mask, seq_lens, 0)
+        one, many = seq_lens == 1, seq_lens > 1
+        rows, = jnp.nonzero(many, size=P, fill_value=B)
+        held = rows < B
+        rows = jnp.minimum(rows, B - 1)
+        blocks = (
+            TokenBlock(0, 1, jnp.where(one, offsets, 0), block_tables, one,
+                       None),
+            TokenBlock(B, L, jnp.where(held, offsets[rows], 0),
+                       block_tables[rows], held,
+                       jnp.where(held, seq_lens[rows], 0)))
+        flat = jnp.concatenate([tok, chunk.reshape(-1)])
+        last = jnp.where(
+            many, B + (jnp.cumsum(many) - 1) * L + seq_lens - 1,
+            jnp.arange(B))
+    pad = -flat.shape[0] % multiple
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    return flat, blocks, last
 
 def rms_norm(x, w, eps: float = 1e-6):
     """RMSNorm over the last dim, fp32 math, cast back to x.dtype."""
@@ -258,6 +352,35 @@ def attn_with_cache(q, k_cache, v_cache, offset, *, scale: float,
     return out.reshape(B, L, Hq, dh).astype(q.dtype)
 
 
+_FUSED_TRACES: dict = {}
+
+
+def _fused_paged_attention(arrays: dict, **static):
+    """``kernels.paged_attention.paged_attention(**arrays, **static)``,
+    TRACED once for each set of operand shapes and static arguments and
+    replayed from its jaxpr after that. The kernel's body is some hundred
+    conditionals, most of a second of Python every time it is traced, and
+    a process traces the same call more than once: the decode step's and
+    the mixed step's decode block are one shape. Replaying binds the same
+    equations (one ``pallas_call``), so the compiled programs are what the
+    direct call gives. ``arrays`` holds the operands that are not None."""
+    from triton_distributed_tpu.kernels.paged_attention import (
+        paged_attention,
+    )
+
+    names = tuple(sorted(arrays))
+    flat = [jnp.asarray(arrays[k]) for k in names]
+    key = (names, tuple((a.shape, a.dtype) for a in flat),
+           tuple(sorted(static.items())))
+    if key not in _FUSED_TRACES:
+        _FUSED_TRACES[key] = jax.make_jaxpr(
+            lambda *a: paged_attention(**dict(zip(names, a)), **static))(
+                *flat)
+    closed = _FUSED_TRACES[key]
+    out, = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat)
+    return out
+
+
 def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
                           scale: float, slot_mask=None,
                           use_flash_decode: bool = True, seq_lens=None,
@@ -350,10 +473,6 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
             method=method, est_s=nbytes / pm.detect_hardware().hbm_bw)
 
     if fused:
-        from triton_distributed_tpu.kernels.paged_attention import (
-            paged_attention,
-        )
-
         off = jnp.broadcast_to(
             jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
         if seq_lens is None:
@@ -361,11 +480,14 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
         else:
             q_lens = jnp.broadcast_to(
                 jnp.asarray(seq_lens, jnp.int32).reshape(-1), (B,))
-        return paged_attention(
-            q, k_pool, v_pool, block_tables, off + q_lens, q_lens=q_lens,
-            slot_mask=slot_mask, scale=scale, interpret=interpret,
-            k_scale=kv_scales[0] if quant else None,
-            v_scale=kv_scales[1] if quant else None, layer=layer)
+        arrays = dict(q=q, k_pool=k_pool, v_pool=v_pool,
+                      block_tables=block_tables, kv_lens=off + q_lens,
+                      q_lens=q_lens, slot_mask=slot_mask, layer=layer)
+        if quant:
+            arrays.update(k_scale=kv_scales[0], v_scale=kv_scales[1])
+        return _fused_paged_attention(
+            {k: v for k, v in arrays.items() if v is not None},
+            scale=scale, interpret=interpret)
 
     from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
 
@@ -412,14 +534,12 @@ def latent_attn_with_cache(q, pool, block_tables, offset, *, v_dim: int,
     q_lens = (jnp.full((B,), L, jnp.int32) if seq_lens is None
               else jnp.asarray(seq_lens, jnp.int32))
     if paged_attn == "fused":
-        from triton_distributed_tpu.kernels.paged_attention import (
-            paged_attention,
-        )
-
-        return paged_attention(q, pool, None, block_tables, off + q_lens,
-                               q_lens=q_lens, slot_mask=slot_mask,
-                               scale=scale, interpret=interpret, layer=layer,
-                               v_dim=v_dim)
+        arrays = dict(q=q, k_pool=pool, block_tables=block_tables,
+                      kv_lens=off + q_lens, q_lens=q_lens,
+                      slot_mask=slot_mask, layer=layer)
+        return _fused_paged_attention(
+            {k: v for k, v in arrays.items() if v is not None}, v_pool=None,
+            scale=scale, interpret=interpret, v_dim=v_dim)
     from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
 
     if layer is not None:

@@ -128,50 +128,11 @@ class TPAttn:
 
     # -- shared core --------------------------------------------------------
 
-    def _qkv_to_attn(self, params, qkv, cache, offset, world,
-                     use_flash_decode: bool = True, seq_lens=None,
-                     interpret=None, block_tables=None, slot_mask=None,
-                     paged_attn: str = "fused", layer=None):
-        """qkv (B, L, q_size+2*kv_size) local-head projection -> attention
-        output (B, L, q_size) plus the updated ``cache``. The qk-norm ->
-        RoPE -> cache-append -> GQA-attend pipeline shared by every mode
-        (reference tp_attn.py:217-233). Decode steps (L == 1) stream the KV
-        cache through the split-KV Pallas kernel unless
-        ``use_flash_decode=False`` (the xla golden mode stays dense jnp so
-        mode-equality tests compare kernel against reference math).
-
-        Two cache layouts, one pipeline:
-        - contiguous (``block_tables=None``): ``cache`` is the pair
-          ``(k_cache, v_cache)``, each (B, S, Hkv, dh); ``offset`` ()
-          scalar (the Engine path) or (B,) per-row.
-        - PAGED (serving): ``cache`` is the pool's state
-          (``serving.kv_pool.PagedKVState``), taken and returned whole;
-          this layer is where its arenas are read. ``k``/``v`` are one
-          layer of the block pool (n_blocks, block_size, Hkv, dh) or, with
-          ``layer`` () int32, the whole stacked arena (n_layers, n_blocks,
-          block_size, Hkv, dh) — what the model's layer scan carries,
-          appended to and read at ``[layer, block]`` where it lies;
-          ``block_tables`` (B, max_blocks) maps each slot's sequence onto
-          pool blocks, ``offset`` is the (B,) per-slot depth vector, and
-          ``slot_mask`` (B,) drops dead slots' cache writes. New K/V
-          scatter into the pool; attention reads back through
-          ``nn.paged_attn_with_cache``, which routes EVERY step shape —
-          decode, chunked prefill, ragged mixed — to the fused Pallas
-          block-walk kernel (``paged_attn="fused"``, the default — one
-          pool pass, no materialized view; NOTE it wins over
-          ``use_flash_decode=False``, so the xla golden mode exercises the
-          same fused kernel). ``paged_attn="gather"`` is the explicit
-          paged_gather_kv escape hatch / test oracle — either way
-          arriving/finishing sequences are pure DATA changes and the step
-          never retraces.
-
-        Quantized paged KV (the state has ``k_scale``/``v_scale`` arenas,
-        the K/V arenas' shape minus dh, f32): the pool arenas hold
-        int8/fp8 rows, new K/V are quantized per (row, kv head) at append
-        time (``nn.paged_cache_update(scale_pool=...)``), and the
-        attention read dequantizes — inside the fused kernel's VMEM
-        staging, or on the gathered view in gather mode.
-        """
+    def _qkv_rope(self, params, qkv, offset, world):
+        """qkv (B, L, q_size+2*kv_size) local-head projection of B
+        sequences' L new tokens -> ``(q, k, v)``, each (B, L, heads, dh):
+        the split, the per-head qk-norm and RoPE at positions ``offset +
+        [0, L)``, ``offset`` () or (B,) per row."""
         B, L, _ = qkv.shape
         qs, kvs = self.sizes(world)
         dh = self.head_dim
@@ -181,116 +142,180 @@ class TPAttn:
         if self.qk_norm:
             q = nn.rms_norm(q, params["q_norm"], self.rms_eps)
             k = nn.rms_norm(k, params["k_norm"], self.rms_eps)
-        offset = jnp.asarray(offset, jnp.int32)
         # (1|B, L): per-row positions when offset is the per-slot vector.
-        positions = offset.reshape(-1, 1) + jnp.arange(L)
+        positions = jnp.asarray(offset, jnp.int32).reshape(-1, 1) \
+            + jnp.arange(L)
         cos, sin = nn.rope_angles(positions, dh, self.rope_theta,
                                   self.rope_scaling)
-        q = nn.apply_rope(q, cos, sin)
-        k = nn.apply_rope(k, cos, sin)
-        if block_tables is None:
+        return nn.apply_rope(q, cos, sin), nn.apply_rope(k, cos, sin), v
+
+    def _attend(self, params, qkv, cache, offset, world, *,
+                use_flash_decode: bool = True, seq_lens=None,
+                interpret=None, blocks=None, paged_attn: str = "fused",
+                layer=None):
+        """Local-head projection ``qkv`` -> attention output of the same
+        leading shape, (..., q_size), plus the updated ``cache``: the
+        qk-norm -> RoPE -> cache-append -> GQA-attend pipeline shared by
+        every mode (reference tp_attn.py:217-233). Decode steps (L == 1)
+        of a contiguous cache stream it through the split-KV Pallas kernel
+        unless ``use_flash_decode=False`` (the xla golden mode stays dense
+        jnp so mode-equality tests compare kernel against reference math).
+
+        Two cache layouts, one pipeline:
+        - contiguous (``blocks`` None): qkv (B, L, width); ``cache`` is the
+          pair ``(k_cache, v_cache)``, each (B, S, Hkv, dh); ``offset`` ()
+          scalar (the Engine path) or (B,) per-row; ``seq_lens`` (B,)
+          varlen prefill lengths.
+        - PAGED (serving): qkv is the step's FLAT token batch (T, width)
+          and ``blocks`` (``nn.TokenBlock``s, from
+          ``nn.paged_token_blocks``) says which runs of it are which
+          sequences' new tokens, each block with its own shape (rows, L),
+          offsets, block-table rows, live mask and lengths — the mixed
+          step's decode block at the decode step's shape, its prefill
+          block at chunk shape; positions no block owns give zeros.
+          ``cache`` is the pool's state
+          (``serving.kv_pool.PagedKVState``), taken and returned whole;
+          this layer is where its arenas are read. ``k``/``v`` are one
+          layer of the block pool (n_blocks, block_size, Hkv, dh) or, with
+          ``layer`` () int32, the whole stacked arena (n_layers, n_blocks,
+          block_size, Hkv, dh) — what the model's layer scan carries,
+          appended to and read at ``[layer, block]`` where it lies. EVERY
+          block's new K/V are scattered into the pool first, one scatter
+          after another on the carried arena, and only then is any block
+          attended (sequences own their blocks, so a block reads nothing
+          another wrote this step): an append that had to leave the pool
+          as an earlier read saw it would cost a copy of the arena.
+          Attention reads back through ``nn.paged_attn_with_cache``,
+          which routes EVERY block shape to the fused Pallas block-walk
+          kernel (``paged_attn="fused"``, the default — one pool pass, no
+          materialized view; NOTE it wins over ``use_flash_decode=False``,
+          so the xla golden mode exercises the same fused kernel).
+          ``paged_attn="gather"`` is the explicit paged_gather_kv escape
+          hatch / test oracle — either way arriving/finishing sequences
+          are pure DATA changes and the step never retraces.
+
+        Quantized paged KV (the state has ``k_scale``/``v_scale`` arenas,
+        the K/V arenas' shape minus dh, f32): the pool arenas hold
+        int8/fp8 rows, new K/V are quantized per (row, kv head) at append
+        time (``nn.paged_cache_update(scale_pool=...)``), and the
+        attention read dequantizes — inside the fused kernel's VMEM
+        staging, or on the gathered view in gather mode.
+        """
+        scale = self.head_dim ** -0.5
+        if blocks is None:
+            q, k, v = self._qkv_rope(params, qkv, offset, world)
             k_cache, v_cache = cache
             k_cache = nn.cache_update(k_cache, k, offset)
             v_cache = nn.cache_update(v_cache, v, offset)
             out = nn.attn_with_cache(q, k_cache, v_cache, offset,
-                                     scale=dh ** -0.5,
+                                     scale=scale,
                                      use_flash_decode=use_flash_decode,
                                      seq_lens=seq_lens, interpret=interpret)
-            return out.reshape(B, L, qs), (k_cache, v_cache)
+            return out.reshape(*qkv.shape[:2], -1), (k_cache, v_cache)
 
-        wm = slot_mask                              # (B,) or None
-        if seq_lens is not None:
-            tok_valid = jnp.arange(L)[None] < seq_lens[:, None]
-            wm = tok_valid if wm is None else (wm[:, None] & tok_valid)
-        state = cache
-        if state.k_scale is not None:
-            k_pool, ks = nn.paged_cache_update(
-                state.k, k, block_tables, offset, wm,
-                scale_pool=state.k_scale, layer=layer)
-            v_pool, vs = nn.paged_cache_update(
-                state.v, v, block_tables, offset, wm,
-                scale_pool=state.v_scale, layer=layer)
-            scales = (ks, vs)
-        else:
-            k_pool = nn.paged_cache_update(state.k, k, block_tables,
-                                           offset, wm, layer=layer)
-            v_pool = nn.paged_cache_update(state.v, v, block_tables,
-                                           offset, wm, layer=layer)
-            ks = vs = scales = None
-        out = nn.paged_attn_with_cache(
-            q, k_pool, v_pool, block_tables, offset, scale=dh ** -0.5,
-            slot_mask=slot_mask, use_flash_decode=use_flash_decode,
-            seq_lens=seq_lens, interpret=interpret, paged_attn=paged_attn,
-            kv_scales=scales, layer=layer)
-        return out.reshape(B, L, qs), dataclasses.replace(
-            state, k=k_pool, v=v_pool, k_scale=ks, v_scale=vs)
+        state, queries = cache, []
+        for blk in blocks:
+            part = qkv[blk.start:blk.stop].reshape(-1, blk.L, qkv.shape[-1])
+            q, k, v = self._qkv_rope(params, part, blk.offsets, world)
+            queries.append(q)
+            wm = blk.valid().reshape(-1, blk.L)
+            if state.k_scale is not None:
+                k_pool, ks = nn.paged_cache_update(
+                    state.k, k, blk.tables, blk.offsets, wm,
+                    scale_pool=state.k_scale, layer=layer)
+                v_pool, vs = nn.paged_cache_update(
+                    state.v, v, blk.tables, blk.offsets, wm,
+                    scale_pool=state.v_scale, layer=layer)
+            else:
+                k_pool = nn.paged_cache_update(state.k, k, blk.tables,
+                                               blk.offsets, wm, layer=layer)
+                v_pool = nn.paged_cache_update(state.v, v, blk.tables,
+                                               blk.offsets, wm, layer=layer)
+                ks = vs = None
+            state = dataclasses.replace(state, k=k_pool, v=v_pool,
+                                        k_scale=ks, v_scale=vs)
+        scales = (None if state.k_scale is None
+                  else (state.k_scale, state.v_scale))
+        outs = [nn.paged_attn_with_cache(
+            q, state.k, state.v, blk.tables, blk.offsets, scale=scale,
+            slot_mask=blk.mask, use_flash_decode=use_flash_decode,
+            seq_lens=blk.seq_lens, interpret=interpret,
+            paged_attn=paged_attn, kv_scales=scales,
+            layer=layer).reshape(blk.stop - blk.start, -1)
+            for q, blk in zip(queries, blocks)]
+        tail = qkv.shape[0] - blocks[-1].stop
+        if tail:
+            outs.append(jnp.zeros((tail, outs[0].shape[-1]), outs[0].dtype))
+        return jnp.concatenate(outs), state
 
     # -- per-device forwards (inside shard_map) -----------------------------
     # ``cache`` in, ``cache`` out, whatever its layout: the pair
-    # ``(k_cache, v_cache)`` of a contiguous cache or, with
-    # ``block_tables``, the paged pool's state (``_qkv_to_attn``).
+    # ``(k_cache, v_cache)`` of a contiguous cache, x (rows, L, d), or, with
+    # ``blocks``, the paged pool's state, x the flat token batch (T, d)
+    # (``_attend``).
 
-    def dist_fwd(self, params, x_local, cache, offset, *,
-                 seq_lens=None, interpret=None, block_tables=None,
-                 slot_mask=None, paged_attn: str = "fused", layer=None):
-        """x_local: (B_local, L, d) batch-shard -> same layout out.
+    def dist_fwd(self, params, x_local, cache, offset=None, *,
+                 seq_lens=None, interpret=None, blocks=None,
+                 paged_attn: str = "fused", layer=None):
+        """x_local: this device's rows of the batch — (B_local, L, d), or
+        (T_local, d) of a paged step's flat batch — -> same layout out.
         AG-GEMM -> attention -> GEMM-RS (reference dist_triton_fwd :203).
         ``seq_lens``: (B,) varlen prefill lengths (nn.attn_with_cache).
-        ``block_tables``/``slot_mask``/``paged_attn``: paged-KV serving
-        path (``_qkv_to_attn``) — tables/mask cover the FULL batch,
-        replicated. ``layer``: the state's arenas are the stacked ones,
-        read and appended at this layer (``_qkv_to_attn``)."""
+        ``blocks``/``paged_attn``: paged-KV serving path (``_attend``) — the
+        blocks cover the FULL batch, replicated. ``layer``: the state's
+        arenas are the stacked ones, read and appended at this layer
+        (``_attend``)."""
         world = _axis_size(self.axis)
-        Bl, L, d = x_local.shape
+        lead, d = x_local.shape[:-1], x_local.shape[-1]
         qkv = ag_gemm_device(
-            x_local.reshape(Bl * L, d), params["w_qkv"], axis=self.axis,
+            x_local.reshape(-1, d), params["w_qkv"], axis=self.axis,
             config=AGGEMMConfig(block_n=self.block_n), interpret=interpret)
-        qkv = qkv.reshape(world * Bl, L, -1)
-        out, cache = self._qkv_to_attn(
+        if blocks is None:
+            qkv = qkv.reshape(world * lead[0], *lead[1:], -1)
+        out, cache = self._attend(
             params, qkv, cache, offset, world, seq_lens=seq_lens,
-            interpret=interpret, block_tables=block_tables,
-            slot_mask=slot_mask, paged_attn=paged_attn, layer=layer)
+            interpret=interpret, blocks=blocks, paged_attn=paged_attn,
+            layer=layer)
         out = gemm_rs_device(
-            out.reshape(world * Bl * L, -1), params["w_o"], axis=self.axis,
+            out.reshape(-1, out.shape[-1]), params["w_o"], axis=self.axis,
             config=GEMMRSConfig(block_n=min(self.block_n, self.d_model)),
             interpret=interpret)
-        return out.reshape(Bl, L, d), cache
+        return out.reshape(*lead, d), cache
 
-    def ar_fwd(self, params, x_full, cache, offset, *,
-               interpret=None, seq_lens=None, block_tables=None,
-               slot_mask=None, paged_attn: str = "fused", layer=None):
-        """x_full: (B, L, d) replicated -> replicated out.
+    def ar_fwd(self, params, x_full, cache, offset=None, *,
+               interpret=None, seq_lens=None, blocks=None,
+               paged_attn: str = "fused", layer=None):
+        """x_full: (B, L, d) or flat (T, d), replicated -> replicated out.
         Local GEMMs -> one-shot allreduce (reference dist_triton_AR_fwd)."""
         world = _axis_size(self.axis)
-        B, L, d = x_full.shape
         qkv = x_full @ params["w_qkv"]
-        out, cache = self._qkv_to_attn(
+        out, cache = self._attend(
             params, qkv, cache, offset, world, interpret=interpret,
-            seq_lens=seq_lens, block_tables=block_tables,
-            slot_mask=slot_mask, paged_attn=paged_attn, layer=layer)
-        partial = out.reshape(B * L, -1) @ params["w_o"]
+            seq_lens=seq_lens, blocks=blocks, paged_attn=paged_attn,
+            layer=layer)
+        partial = out.reshape(-1, out.shape[-1]) @ params["w_o"]
         out = oneshot_all_reduce(partial, axis=self.axis, interpret=interpret)
-        return out.reshape(B, L, d), cache
+        return out.reshape(x_full.shape), cache
 
-    def xla_fwd(self, params, x_local, cache, offset, *,
-                seq_lens=None, block_tables=None, slot_mask=None,
-                paged_attn: str = "fused", layer=None):
+    def xla_fwd(self, params, x_local, cache, offset=None, *,
+                seq_lens=None, blocks=None, paged_attn: str = "fused",
+                layer=None):
         """Golden/baseline path: same math via jnp + XLA collectives.
         Batch-sharded in/out like ``dist_fwd``. ``paged_attn`` still
         routes paged decode through the fused kernel (interpret mode on
         CPU), so golden-vs-dist equality covers the block walk too; pass
         "gather" to pin the dense reference composition."""
         world = _axis_size(self.axis)
-        Bl, L, d = x_local.shape
         x_full = jax.lax.all_gather(x_local, self.axis, axis=0, tiled=True)
-        qkv = x_full.reshape(world * Bl * L, d) @ params["w_qkv"]
-        qkv = qkv.reshape(world * Bl, L, -1)
-        out, cache = self._qkv_to_attn(
+        d = x_full.shape[-1]
+        qkv = x_full.reshape(-1, d) @ params["w_qkv"]
+        if blocks is None:
+            qkv = qkv.reshape(*x_full.shape[:-1], -1)
+        out, cache = self._attend(
             params, qkv, cache, offset, world,
-            use_flash_decode=False, seq_lens=seq_lens,
-            block_tables=block_tables, slot_mask=slot_mask,
+            use_flash_decode=False, seq_lens=seq_lens, blocks=blocks,
             paged_attn=paged_attn, layer=layer)
-        partial = out.reshape(world * Bl * L, -1) @ params["w_o"]
+        partial = out.reshape(-1, out.shape[-1]) @ params["w_o"]
         out = jax.lax.psum_scatter(partial, self.axis, scatter_dimension=0,
                                    tiled=True)
-        return out.reshape(Bl, L, d), cache
+        return out.reshape(x_local.shape), cache

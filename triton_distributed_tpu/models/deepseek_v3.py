@@ -194,12 +194,16 @@ class DeepseekV3:
                       interpret=None, paged_attn: str = "fused",
                       spec_verify: bool = False):
         """One served step on this device, as ``Qwen3.forward_paged``:
-        ``(logits (B, vocab) f32, aux, state)``. ``state`` is the pool's
-        state, whose one arena is the latent (n_layers, n_blocks,
-        block_size, row); ``aux["stats"]`` the int32 counts ``step_stats``
-        over the live tokens, summed over the layers. ``mode`` is accepted
-        and not read: on one device ``dist``, ``xla`` and ``ar`` are one
-        path."""
+        ``(logits (B, vocab) f32, aux, state)``, ``ids`` an array (B, L) or
+        the mixed step's pair ``(tok (B,), chunk (P, L))``
+        (``nn.paged_token_blocks``): the projections, the shared expert and
+        the routed experts see the flat token batch (``HeldExpertsMoE``
+        sizes its buffer from it), latent attention one block at a time.
+        ``state`` is the pool's state, whose one arena is the latent
+        (n_layers, n_blocks, block_size, row); ``aux["stats"]`` the int32
+        counts ``step_stats`` over the live tokens, summed over the layers.
+        ``mode`` is accepted and not read: on one device ``dist``, ``xla``
+        and ``ar`` are one path."""
         c = self.config
         if _axis_size(self.axis) != 1:
             raise NotImplementedError(
@@ -219,30 +223,24 @@ class DeepseekV3:
             raise NotImplementedError(
                 "speculative verify is not built for the latent/"
                 "held-experts block")
-        B, L = ids.shape
-        d = c.d_model
+        flat, blocks, last = nn.paged_token_blocks(
+            ids, offsets, block_tables, slot_mask, seq_lens)
         # The residual stream is carried in float32 (the sub-layers read it
         # in the model dtype, the router as it is): in bfloat16 its rounding
         # at every add is the largest error of a step, and it moves
         # near-tied router scores across the top-k boundary.
-        h = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
-        valid = jnp.ones((B, L), bool)
-        if slot_mask is not None:
-            valid &= slot_mask[:, None]
-        if seq_lens is not None:
-            valid &= jnp.arange(L)[None] < seq_lens[:, None]
-        akw = dict(block_tables=block_tables, slot_mask=slot_mask,
-                   seq_lens=seq_lens, paged_attn=paged_attn,
-                   interpret=interpret)
+        h = jnp.take(params["embed"], flat, axis=0).astype(jnp.float32)
+        valid = jnp.concatenate([b.valid() for b in blocks])
+        akw = dict(blocks=blocks, paged_attn=paged_attn, interpret=interpret)
 
         def block(h, state, lp, layer, ffn):
             hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
             a, state = self.attn.fwd(lp["attn"], hn.astype(c.dtype), state,
-                                     offsets, layer=layer, **akw)
+                                     layer=layer, **akw)
             h = h + a
             hn = nn.rms_norm(h, lp["post_norm"], c.rms_eps)
-            m, stats = ffn(hn.reshape(-1, d))
-            return h + m.reshape(h.shape), state, stats
+            m, stats = ffn(hn)
+            return h + m, state, stats
 
         for i in range(c.n_dense_layers):
             lp = jax.tree.map(lambda a: a[i], params["dense"])
@@ -262,9 +260,8 @@ class DeepseekV3:
             lp, i = xs
             h, state, st = block(
                 h, state, lp, c.n_dense_layers + i,
-                lambda x: self.moe.fwd(dict(lp["moe"], **heavy), x,
-                                       valid.reshape(-1), layer_idx=i,
-                                       interpret=interpret))
+                lambda x: self.moe.fwd(dict(lp["moe"], **heavy), x, valid,
+                                       layer_idx=i, interpret=interpret))
             return (h, state, stats + st), None
 
         n_moe = c.n_layers - c.n_dense_layers
@@ -273,12 +270,7 @@ class DeepseekV3:
             (scan_layers, jnp.arange(n_moe, dtype=jnp.int32)))
 
         h = nn.rms_norm(h, params["final_norm"], c.rms_eps).astype(c.dtype)
-        if seq_lens is None:
-            last = h[:, -1]
-        else:
-            idx = jnp.maximum(jnp.asarray(seq_lens, jnp.int32) - 1, 0)
-            last = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0]
-        logits = jnp.dot(last, params["lm_head"],
+        logits = jnp.dot(jnp.take(h, last, axis=0), params["lm_head"],
                          preferred_element_type=jnp.float32)
         stats = jnp.concatenate([
             moe_stats,

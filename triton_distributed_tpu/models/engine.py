@@ -97,16 +97,18 @@ class Engine:
         structure, and all this function knows of the pool's format. The
         other operands are replicated data, so slot churn never changes a
         shape; the two variants differ only in whether ``seq_lens`` is an
-        operand. ``paged_attn`` selects the paged KV read path for every
+        operand. ``ids`` is an array (B, L) or, for the mixed step's two
+        blocks, the pair ``(tok (B,), chunk (P, L))``
+        (``nn.paged_token_blocks``). ``paged_attn`` selects the paged KV read path for every
         step shape (fused block-walk kernel vs the gather escape hatch —
         see ``nn.paged_attn_with_cache``); it is baked into the trace, so
         a BatchEngine picks it once at construction.
 
         ``aux`` is a dict of replicated outputs whose keys are fixed per
-        build: ``"greedy"`` (B, L) int32 under ``spec_verify=True``
+        build: ``"greedy"`` int32 under ``spec_verify=True``
         (``paged='prefill'`` only) — the argmax continuation at every
-        position, which a speculative BatchEngine bakes into its one
-        mixed-step trace — and ``"stats"`` for a model with
+        position of the last block's rows, which a speculative BatchEngine
+        bakes into its one mixed-step trace — and ``"stats"`` for a model with
         ``step_stats``."""
         model = self.model
         if spec_verify and paged != "prefill":

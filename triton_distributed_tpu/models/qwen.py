@@ -290,10 +290,11 @@ class Qwen3:
     # embedding, the layer body and the head below.
 
     def _embed(self, params, ids, mode: str):
-        """ids (B, L) replicated -> ``(h, rows)``: this device's rows of
-        the hidden state ((B/world, L, d) in dist/xla mode, all of them in
-        ar mode) and ``rows = (me, bl)``, this device's index and how many
-        rows each device holds, or None in ar."""
+        """ids replicated — (B, L), or a paged step's flat (T,) — ->
+        ``(h, rows)``: this device's rows of the hidden state (axis 0 cut
+        into ``world`` equal runs in dist/xla mode, all of it in ar mode)
+        and ``rows = (me, bl)``, this device's index and how many rows each
+        device holds, or None in ar."""
         c = self.config
         if mode == "ar":
             if c.n_experts:
@@ -335,9 +336,10 @@ class Qwen3:
     def _layer(self, lp, h, cache, offset, li, *, mode: str, interpret,
                moe_heavy=None, return_moe_stats: bool = False, **paged):
         """One decoder layer: ``(h, cache, stats)``. ``cache`` is this
-        layer's ``(k, v)`` of the contiguous cache or, with ``paged``
-        (block_tables, slot_mask, seq_lens, paged_attn, layer), the pool's
-        state — the attention layer reads it and hands it back."""
+        layer's ``(k, v)`` of the contiguous cache, h (rows, L, d), or,
+        with ``paged`` (blocks, paged_attn, layer), the pool's state, h the
+        flat token batch (T, d) — the attention layer reads the state and
+        hands it back."""
         c = self.config
         attn, mlp = self.attn, self.mlp
         resid = h
@@ -370,45 +372,44 @@ class Qwen3:
             m = mlp.ar_fwd(lp["mlp"], flat, interpret=interpret)
         return resid + m.reshape(hn.shape), cache, stats
 
-    def _head(self, params, h, rows, *, seq_lens=None,
-              spec_verify: bool = False):
+    def _head(self, params, h, rows, *, last=None, greedy_of=None):
         """Final norm and LM head: ``(logits (B, vocab) fp32 replicated,
-        greedy)``. Row b's logits come from its last position or, with
-        ``seq_lens``, its last VALID one. ``greedy`` (B, L) int32 under
-        ``spec_verify`` — the argmax next-token prediction at EVERY
-        position — else None."""
+        greedy)``. h (rows, L, d): row b's logits come from its last
+        position. A paged step's flat h (T, d) with ``last`` (B,): from
+        flat position ``last[b]``. ``greedy_of`` (a ``nn.TokenBlock``, the
+        speculative verify step's) asks for the argmax next-token
+        prediction at EVERY position of that block, (rows, L) int32; else
+        None."""
         c = self.config
         h = nn.rms_norm(h, params["final_norm"], c.rms_eps)
         lm_head = (params["embed"].T if c.tie_embeddings
                    else params["lm_head"])
         greedy = None
-        if spec_verify:
+        if greedy_of is not None:
             # Argmax prediction at EVERY position (draft-verify needs the
             # model's continuation after each consumed draft token). The
             # all-position matmul reduces to int32 on device; the
             # last-position logits below still go through the exact same
             # gather-then-dot path as the non-verify step.
-            flat = h.reshape(-1, h.shape[-1])
-            all_logits = jnp.dot(flat, lm_head,
+            all_logits = jnp.dot(h, lm_head,
                                  preferred_element_type=jnp.float32)
-            greedy = (jnp.argmax(all_logits, axis=-1).astype(jnp.int32)
-                      .reshape(h.shape[:2]))
+            greedy = jnp.argmax(all_logits, axis=-1).astype(jnp.int32)
             if rows is not None:
                 greedy = jax.lax.all_gather(greedy, self.axis, axis=0,
                                             tiled=True)
-        if seq_lens is None:
+            greedy = greedy[greedy_of.start:greedy_of.stop].reshape(
+                -1, greedy_of.L)
+        if last is None:
             last = h[:, -1]                                    # (*, d)
-        else:
-            # Varlen chunk: row b's next-token logits live at its last
-            # VALID position. Rows with seq_lens == 0 clamp to position 0
-            # (garbage the caller masks out).
-            idx = jnp.maximum(jnp.asarray(seq_lens, jnp.int32) - 1, 0)
             if rows is not None:
-                me, bl = rows
-                idx = jax.lax.dynamic_slice_in_dim(idx, me * bl, bl, axis=0)
-            last = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0]
-        if rows is not None:
-            last = jax.lax.all_gather(last, self.axis, axis=0, tiled=True)
+                last = jax.lax.all_gather(last, self.axis, axis=0,
+                                          tiled=True)
+        else:
+            # The positions wanted lie anywhere in the flat batch: gather
+            # it whole (T rows, once a step), then take.
+            if rows is not None:
+                h = jax.lax.all_gather(h, self.axis, axis=0, tiled=True)
+            last = jnp.take(h, last, axis=0)
         # bf16 operands, fp32 accumulation — no materialized fp32 weight copy
         logits = jnp.dot(last, lm_head, preferred_element_type=jnp.float32)
         return logits, greedy
@@ -475,21 +476,31 @@ class Qwen3:
         arenas a step and a second pool of temporaries (PERF.md, PR 26).
 
         The operands are all FULL-batch, replicated, and pure data (fixed
-        shapes, so slot churn never retraces): ids (B, L) int32;
-        ``offsets`` (B,) per-slot depths; ``block_tables`` (B, max_blocks)
-        int32 and ``slot_mask`` (B,) bool (``TPAttn._qkv_to_attn``);
-        ``seq_lens`` (B,) valid new-token counts per row of a varlen mixed
-        step (row b's logits then come from position ``seq_lens[b]-1``),
-        None for the decode step. ``paged_attn`` "fused" (default) routes
-        every step shape through the fused block-walk kernel; "gather"
-        pins the materialized-view escape hatch / test oracle
+        shapes, so slot churn never retraces). ``ids`` says what the step's
+        token batch is made of (``nn.paged_token_blocks``): an array
+        (B, L) int32 is B rows of L positions (the decode step's (B, 1));
+        a pair ``(tok (B,), chunk (P, L))`` is the MIXED step's two blocks,
+        one token a slot beside P rows of L prompt tokens for the slots
+        with ``seq_lens > 1``, ``T = B + P * L`` positions in place of
+        ``B * L``. The embedding, norms, linear layers and the residual
+        stream run over the flat (T, d) batch — cut into ``world`` runs of
+        rows in dist/xla mode — so each weight is read once a step; rope,
+        the append and attention run a block at a time
+        (``TPAttn._attend``). ``offsets`` (B,) per-slot depths;
+        ``block_tables`` (B, max_blocks) int32 and ``slot_mask`` (B,) bool;
+        ``seq_lens`` (B,) valid new-token counts per slot of a varlen step
+        (slot b's logits then come from its last valid position), None for
+        the decode step. ``paged_attn`` "fused" (default) routes every
+        block through the fused block-walk kernel; "gather" pins the
+        materialized-view escape hatch / test oracle
         (nn.paged_attn_with_cache).
 
         ``aux`` is a dict whose keys are fixed per build: ``"greedy"``
-        (B, L) int32 under ``spec_verify`` (speculative decoding's batched
+        int32 under ``spec_verify`` (speculative decoding's batched
         verify; requires ``seq_lens``) — the argmax next-token prediction
-        at EVERY position of every row. Host-side longest-prefix
-        acceptance compares draft token j+1 against ``greedy[b, j]``;
+        at EVERY position of every row of the LAST block ((P, L) of the
+        pair form, (B, L) of the array form). Host-side longest-prefix
+        acceptance compares draft token j+1 against ``greedy[row, j]``;
         position ``m`` doubles as the bonus token. The last-position
         ``logits`` path is untouched (same gather-then-dot arithmetic), so
         sampling stays bit-identical to the non-verify step. A model with
@@ -499,22 +510,25 @@ class Qwen3:
         if spec_verify and seq_lens is None:
             raise ValueError("spec_verify requires seq_lens (the batched "
                              "verify step is a varlen mixed step)")
-        h, rows = self._embed(params, ids, mode)
+        world = 1 if mode == "ar" else _axis_size(self.axis)
+        flat, blocks, last = nn.paged_token_blocks(
+            ids, offsets, block_tables, slot_mask, seq_lens, multiple=world)
+        h, rows = self._embed(params, flat, mode)
         scan_layers, moe_heavy = self._scan_layers(params, mode)
 
         def body(carry, xs):
             h, state = carry
             lp, li = xs
             h, state, _ = self._layer(
-                lp, h, state, offsets, li, mode=mode, interpret=interpret,
-                moe_heavy=moe_heavy, seq_lens=seq_lens,
-                block_tables=block_tables, slot_mask=slot_mask,
-                paged_attn=paged_attn, layer=li)
+                lp, h, state, None, li, mode=mode, interpret=interpret,
+                moe_heavy=moe_heavy, blocks=blocks, paged_attn=paged_attn,
+                layer=li)
             return (h, state), None
 
         (h, state), _ = jax.lax.scan(
             body, (h, state),
             (scan_layers, jnp.arange(c.n_layers, dtype=jnp.int32)))
-        logits, greedy = self._head(params, h, rows, seq_lens=seq_lens,
-                                    spec_verify=spec_verify)
+        logits, greedy = self._head(
+            params, h, rows, last=last,
+            greedy_of=blocks[-1] if spec_verify else None)
         return logits, ({"greedy": greedy} if spec_verify else {}), state

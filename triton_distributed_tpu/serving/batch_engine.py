@@ -4,10 +4,16 @@ The serving-side driver over ``models/engine.Engine``'s model + mesh: a
 fixed bank of ``n_slots`` sequence slots runs through TWO jitted programs —
 
   decode step  (n_slots, 1)-token ids      — one token for every slot
-  mixed step   (n_slots, prefill_chunk)    — chunked varlen prefill rows
-                                             AND 1-token decode rows in the
-                                             same iteration (Orca-style
-                                             iteration-level batching)
+  mixed step   n_slots + P * prefill_chunk — a DECODE block of one token a
+               token positions               slot beside a PREFILL block of
+                                             ``P = prefill_rows`` rows of
+                                             ``prefill_chunk`` prompt
+                                             tokens, in the same iteration
+                                             (Orca-style iteration-level
+                                             batching); a step with more
+                                             than P rows prefilling serves
+                                             the oldest admitted and defers
+                                             the rest
 
 — whose operands (active-slot mask, per-slot offsets, block tables,
 per-row seq_lens) are plain DATA. Requests arriving, finishing, getting
@@ -75,6 +81,20 @@ from triton_distributed_tpu.serving.prefix_cache import RadixPrefixCache
 from triton_distributed_tpu.serving.scheduler import Request, Scheduler
 from triton_distributed_tpu.serving.speculative import as_speculative
 
+# Token positions a mixed step may carry: ``n_slots`` decode positions and
+# as many whole prefill rows of ``prefill_chunk`` as fit beside them
+# (``BatchEngine.prefill_rows``: 7 at 32 slots and chunks of 64). Both ends
+# cost (read on a v5e at 96, 224, 288, 352 and 480 positions; PERF.md
+# section 6, PR 31). Every row of the block, live or not, goes through the
+# linear layers, which are compute-bound past about 240 positions (197
+# TFLOP/s / 819 GB/s / 2 bytes): +1.8 ms on every decoding client's token
+# gap a row for Qwen3-1.7B, +3.4 ms for the latent/expert block. And a
+# lower block prefills a waiting population in more steps: 32 contexts of
+# 35,889 tokens took 225 steps through 3 rows (6.6 s), 116 through 7 (4.2
+# s), 51 through the dense (32, 64) block this replaced (3.5 s). 512 is the
+# least at which that bulk case costs about what it did.
+MIXED_STEP_TOKEN_BUDGET = 512
+
 # The trailing windows every stats snapshot reports ("last 10 s" for the
 # live dashboard's now-view, "last 5 min" for trends) over these series.
 _SNAPSHOT_WINDOWS = ((10.0, "10s"), (300.0, "5m"))
@@ -120,8 +140,13 @@ class BatchEngine:
     ``n_blocks``   KV pool size; defaults to full residency for all slots
                    (no preemption pressure). Size it below
                    ``n_slots * ceil(max_seq_len/block_size)`` to oversubscribe.
-    ``prefill_chunk`` tokens of prompt consumed per mixed step and the
-                   mixed step's fixed ids width.
+    ``prefill_chunk`` tokens of prompt a prefilling row consumes per mixed
+                   step, and the width of the mixed step's prefill block.
+                   The block's height ``prefill_rows`` (how many rows may
+                   prefill in one step) is derived: what fits beside the
+                   decode block in ``MIXED_STEP_TOKEN_BUDGET`` positions
+                   or, with ``speculative``, ``n_slots`` (every decode
+                   row may be a verify row of several tokens).
     ``admission_pressure`` fraction of the pool that must be free to admit
                    NEW requests while at least one slot is running (0.0 =
                    off). Backpressure trades queue wait for fewer
@@ -232,11 +257,15 @@ class BatchEngine:
                              f"{world} (required in dist/xla modes)")
         self.n_slots = n_slots
         self.prefill_chunk = prefill_chunk
+        # Rows of the mixed step's prefill block (module docstring).
+        self.prefill_rows = n_slots if self.spec is not None else min(
+            n_slots, max(1, (MIXED_STEP_TOKEN_BUDGET - n_slots)
+                         // prefill_chunk))
         # Runtime chunked-prefill token budget: how much of the compiled
         # ``prefill_chunk`` ids width a mixed step may actually consume per
-        # row. The adaptive controller (serving/controller.py) moves this
-        # as pure per-step data — ``seq_lens`` narrows, the ids shape never
-        # changes, so the compiled mixed step is untouched.
+        # prefill-block row. The adaptive controller (serving/controller.py)
+        # moves this as pure per-step data — ``seq_lens`` narrows, the ids
+        # shape never changes, so the compiled mixed step is untouched.
         self.prefill_budget = prefill_chunk
         max_seq_len = max_seq_len or engine.max_length
         if n_blocks is None:
@@ -413,8 +442,12 @@ class BatchEngine:
         @functools.partial(jax.jit, donate_argnums=(2,))
         def mixed_step(params, ids, state, offsets, block_tables, slot_mask,
                        seq_lens, corrupt, key):
+            # ``ids`` is the pair (tok (n_slots,), chunk (prefill_rows,
+            # prefill_chunk)): the decode block and the prefill block,
+            # whose row k is the k-th slot with ``seq_lens > 1``
+            # (``nn.paged_token_blocks`` finds them on the device).
             trace_counts["prefill"] += 1
-            ids = jnp.clip(ids, 0, V - 1)
+            ids = jax.tree.map(lambda a: jnp.clip(a, 0, V - 1), ids)
             logits, aux, state = sm_pre(params, ids, state, offsets,
                                         block_tables, slot_mask, seq_lens)
             return *sample(logits, aux, corrupt, key), state
@@ -446,6 +479,7 @@ class BatchEngine:
 
         same = (self.n_slots == other.n_slots
                 and self.prefill_chunk == other.prefill_chunk
+                and self.prefill_rows == other.prefill_rows
                 and self.paged_attn == other.paged_attn
                 and pool_format(self) == pool_format(other)
                 and (self.spec is None) == (other.spec is None))
@@ -1493,12 +1527,14 @@ class BatchEngine:
         return (jnp.asarray(offsets), jnp.asarray(tables),
                 jnp.asarray(mask))
 
-    def _guard_rows(self, finite) -> None:
+    def _guard_rows(self, finite, rows=None) -> None:
         """Host half of the NaN/Inf guard: quarantine every active row
+        (of ``rows``, the slots that took tokens this step; default all)
         whose logits failed the compiled finite check. Costs a device
         transfer, so it only runs while guarding (fault plan installed or
         ``nan_guard=True``) — the mask itself is computed every step."""
-        active = [i for i, s in enumerate(self._slots) if s is not None]
+        active = [i for i in (range(self.n_slots) if rows is None else rows)
+                  if self._slots[i] is not None]
         for i in _guards.bad_rows(np.asarray(finite), active):
             self._quarantine(i, "non-finite logits (NaN/Inf guard)")
 
@@ -1562,8 +1598,8 @@ class BatchEngine:
                          active=int(sum(s is not None for s in self._slots))):
             nxt, finite, greedy, state = self._call_step(
                 site, lambda corrupt: step(
-                    self.engine.params, jnp.asarray(ids), state, offsets,
-                    tables, mask, *extra, corrupt, key))
+                    self.engine.params, ids, state, offsets, tables, mask,
+                    *extra, corrupt, key))
             greedy = jax.device_get(greedy)
             nxt = self._take_stats(np.asarray(nxt))
         self.pool.state = state
@@ -1574,7 +1610,7 @@ class BatchEngine:
         tok = np.array([s.last_tok if s else 0 for s in self._slots],
                        np.int32)
         nxt, finite, _ = self._dispatch("engine.decode", self._decode_step,
-                                        "decode_step", tok)
+                                        "decode_step", jnp.asarray(tok))
         if self.efficiency is not None:
             rows, tenants = [], {}
             for s in self._slots:
@@ -1599,42 +1635,59 @@ class BatchEngine:
 
     def _run_mixed(self):
         comm0 = self._eff_begin()
-        L = self.prefill_chunk
+        L, P = self.prefill_chunk, self.prefill_rows
         proposals = self._proposals
-        ids = np.zeros((self.n_slots, L), np.int32)
-        seq_lens = np.zeros((self.n_slots,), np.int32)
-        pre_toks = dec_rows = 0
+        # The controller's runtime budget narrows a prefilling row's take
+        # without touching the compiled (P, L) block: ids stays
+        # zero-padded, seq_lens carries the smaller take.
+        budget = min(max(int(self.prefill_budget), 1), L)
+        wants: dict[int, list[int]] = {}    # slot -> this step's new tokens
         for i, s in enumerate(self._slots):
             if s is None:
                 continue
             if s.prefilling:
-                # The controller's runtime budget narrows the consumed
-                # chunk without touching the compiled (n_slots, L) width:
-                # ids stays zero-padded, seq_lens carries the smaller take.
-                budget = min(max(int(self.prefill_budget), 1), L)
-                take = min(budget, len(s.ctx) - s.offset)
-                ids[i, :take] = s.ctx[s.offset:s.offset + take]
-                seq_lens[i] = take
-                pre_toks += take
-                if self.journey is not None:
-                    # Chunk consumption keyed by the budget in force, so
-                    # controller narrowing shows up per request.
-                    self.journey.event(s.req.req_id, "prefill_chunk",
-                                       tokens=take, budget=budget)
+                wants[i] = s.ctx[s.offset:s.offset + budget]
             else:
                 # Decode row, possibly a speculative verify row: the ids
                 # are [last_tok, d_1..d_p] and seq_lens = 1+p — churn in
                 # draft width is pure operand data, same compiled step.
-                props = proposals.get(i, ())
-                ids[i, 0] = s.last_tok
-                if props:
-                    ids[i, 1:1 + len(props)] = props
-                seq_lens[i] = 1 + len(props)
+                wants[i] = [s.last_tok, *proposals.get(i, ())]
+        # A row of ONE token (a decode row; a prompt's last token) rides
+        # the decode block. A longer one needs a row of the prefill block:
+        # the P oldest admitted get one, in slot order (row k is the k-th
+        # slot with seq_lens > 1 — the device finds them so), the rest
+        # take nothing this step and go next.
+        many = sorted((i for i, t in wants.items() if len(t) > 1),
+                      key=lambda i: self._slots[i].admit_seq)
+        block_row = {i: k for k, i in enumerate(sorted(many[:P]))}
+        for i in many[P:]:
+            del wants[i]
+        tok = np.zeros((self.n_slots,), np.int32)
+        chunk = np.zeros((P, L), np.int32)
+        seq_lens = np.zeros((self.n_slots,), np.int32)
+        pre_toks = dec_rows = 0
+        for i, t in wants.items():
+            seq_lens[i] = len(t)
+            if i in block_row:
+                chunk[block_row[i], :len(t)] = t
+            else:
+                tok[i] = t[0]
+            s = self._slots[i]
+            if not s.prefilling:
                 dec_rows += 1
+                continue
+            pre_toks += len(t)
+            if self.journey is not None:
+                # Chunk consumption keyed by the budget in force, so
+                # controller narrowing shows up per request.
+                self.journey.event(s.req.req_id, "prefill_chunk",
+                                   tokens=len(t), budget=budget)
+        n_deferred, n_tokens = len(many) - len(block_row), int(seq_lens.sum())
         nxt, finite, greedy = self._dispatch(
-            "engine.prefill", self._mixed_step, "mixed_step", ids,
-            jnp.asarray(seq_lens), prefill_rows=int((seq_lens > 1).sum()),
-            spec_rows=len(proposals))
+            "engine.prefill", self._mixed_step, "mixed_step",
+            (jnp.asarray(tok), jnp.asarray(chunk)), jnp.asarray(seq_lens),
+            prefill_rows=len(block_row), spec_rows=len(proposals),
+            prefill_rows_deferred=n_deferred, mixed_step_tokens=n_tokens)
         if self.efficiency is not None:
             rows, tenants = [], {}
             for i, s in enumerate(self._slots):
@@ -1654,14 +1707,21 @@ class BatchEngine:
         self.metrics.inc("prefill_tokens", pre_toks)
         if dec_rows:
             self.metrics.inc("decode_rows", dec_rows)
+        # How full the step was (live tokens of its n_slots + P * L
+        # positions) and how often the prefill block was: a prefilling row
+        # that wanted tokens and got none.
+        self.metrics.inc("mixed_step_tokens", n_tokens)
+        self.metrics.inc("prefill_rows_deferred", n_deferred)
         if self._guarding:
-            self._guard_rows(finite)
+            # A row that took nothing has no logits to judge.
+            self._guard_rows(finite, np.flatnonzero(seq_lens))
         for i, s in enumerate(self._slots):
             if s is None:
                 continue            # freed mid-loop (quarantined by guard)
             props = proposals.get(i)
             if props and s.offset >= len(s.ctx):
-                self._accept_row(i, s, props, greedy[i], int(nxt[i]))
+                self._accept_row(i, s, props, greedy[block_row[i]],
+                                 int(nxt[i]))
                 continue
             took = int(seq_lens[i])
             was_prefilling = s.offset < len(s.ctx)
