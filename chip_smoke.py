@@ -10,7 +10,13 @@ fails the run; nothing continues on the CPU or the interpreter). It then
 FAILS unless every request finished with the tokens it asked for, the
 fleet recorded no replica failure, nothing retraced, the pool's
 invariants hold, the prefix cache hit, and the paged step's logits agree
-with ``Engine``'s contiguous-cache forward on the same chip.
+with ``Engine``'s contiguous-cache forward on the same chip. The same call
+then serves the HYBRID block (``models/granite_hybrid.py``:
+granite-4.0-h-micro's widths, one period of its ten layers: nine Mamba-2
+layers that keep a state a slot and one attention layer over packed rows)
+through the same entry points, and fails unless the one-token state update
+(the ``ssm_state_update`` kernel, compiled by Mosaic) and the chunk scan
+give the same logits for the same tokens.
 
 ``--chips 4`` (a four-chip host) runs only the tensor-parallel phase:
 Qwen3-8B over ``make_mesh({"tp": 4})`` through ``BatchEngine``, once in
@@ -47,6 +53,16 @@ FOUR_CHIPS = dict(
     model="qwen3-8b", interpret=False, block_n=256, seed=0,
     n_slots=8, block_size=16, prefill_chunk=64,
     n_requests=10, prompt_range=(200, 1500), new_tokens=64, ref_len=320,
+)
+
+# The hybrid phase: the published widths and vocabulary, ONE period of the
+# published layer pattern (2.3 GB of weights beside 8 slots of state).
+HYBRID = dict(
+    overrides=dict(layer_types=("mamba",) * 5 + ("attention",)
+                   + ("mamba",) * 4),
+    interpret=False, paged_attn="fused", seed=0, n_slots=8, block_size=16,
+    prefill_chunk=64, n_requests=6, prompt_range=(100, 400), new_tokens=16,
+    walk_len=80,        # tokens fed one at a time through the kernel
 )
 
 # Largest |difference| of two logit rows over the largest |reference logit|.
@@ -291,9 +307,11 @@ def drain_fleet(fleet, caches: _CacheEvents, max_steps: int = 50_000) -> dict:
             "first_call": first}
 
 
-def check_fleet(fleet, rids: list, n_new: int) -> None:
+def check_fleet(fleet, rids: list, n_new: int, *,
+                prefix_hit: bool = True) -> None:
     """The checks the replica error boundary cannot swallow: every request
-    in ``rids`` finished with exactly ``n_new`` tokens on a healthy fleet."""
+    in ``rids`` finished with exactly ``n_new`` tokens on a healthy fleet
+    (and, with ``prefix_hit``, the prefix cache was hit)."""
     rep = fleet.replicas[0]
     eng = rep.engine
     fm = fleet.metrics.as_dict()
@@ -320,7 +338,8 @@ def check_fleet(fleet, rids: list, n_new: int) -> None:
     check(eng.trace_counts == {"decode": 1, "prefill": 1},
           f"trace_counts {eng.trace_counts} != {{1, 1}} (a step retraced)")
     fleet.check_invariants()      # every replica pool's invariants too
-    check(eng.metrics.counters.get("prefix_hits", 0.0) > 0,
+    check(not prefix_hit
+          or eng.metrics.counters.get("prefix_hits", 0.0) > 0,
           "the shared-prefix wave produced no prefix-cache hit")
 
 
@@ -429,6 +448,121 @@ def run_one_chip(devices, geo: dict, caches: _CacheEvents) -> None:
                         for s, a in zip(served, alone)])
     emit(phase="memory", peak_bytes_in_use=peak_bytes(devices[:1]),
          **caches.snapshot())
+
+
+# -- one chip: the hybrid block (per-slot state beside paged rows) ------------
+
+
+def decode_walk_logits(be, prompts, next_tok):
+    """What ``paged_logits`` gives, with every token of the prompts fed ONE
+    AT A TIME through the decode-shaped step: the state advances through
+    the one-token update's kernel alone, never through the chunk scan."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    eng, pool = be.engine, be.pool
+    n, n_p, plen = be.n_slots, len(prompts), len(prompts[0])
+    dec = jax.jit(eng._make_sm(eng.decode_mode, paged="decode",
+                               paged_attn=be.paged_attn,
+                               state_specs=pool.specs), donate_argnums=(2,))
+    sids = [f"smoke-walk-{i}" for i in range(n_p)]
+    for sid in sids:
+        check(pool.ensure(sid, plen + 1), "pool could not fund the walked "
+              "sequences on an idle engine")
+    try:
+        tables = jnp.asarray(pool.padded_tables(sids + [None] * (n - n_p)))
+        live = np.arange(n) < n_p
+        toks = np.concatenate([np.asarray(prompts, np.int32),
+                               np.asarray(next_tok, np.int32)[:, None]], 1)
+        rows = []
+        for pos in range(plen + 1):
+            ids = np.zeros((n, 1), np.int32)
+            ids[:n_p, 0] = toks[:, pos]
+            logits, _, pool.state = dec(
+                eng.params, jnp.asarray(ids), pool.state,
+                jnp.asarray(np.where(live, pos, 0).astype(np.int32)),
+                tables, jnp.asarray(live))
+            rows.append(logits)
+    finally:
+        for sid in sids:
+            pool.release(sid)
+    return (np.asarray(rows[-2], np.float32)[:n_p],
+            np.asarray(rows[-1], np.float32)[:n_p])
+
+
+def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
+    import jax
+    import numpy as np
+
+    from triton_distributed_tpu.models.config import GraniteHybridConfig
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.runtime.mesh import make_mesh
+    from triton_distributed_tpu.serving.fleet import Fleet
+
+    cfg = GraniteHybridConfig(**geo["overrides"])
+    mesh = make_mesh({"tp": 1}, devices=devices[:1], set_default=False)
+    engine = Engine(cfg, mesh=mesh, mode="dist",
+                    key=jax.random.PRNGKey(geo["seed"]),
+                    interpret=geo["interpret"])
+    fleet = Fleet.build(engine, n_replicas=1, n_slots=geo["n_slots"],
+                        block_size=geo["block_size"],
+                        prefill_chunk=geo["prefill_chunk"],
+                        paged_attn=geo["paged_attn"])
+    be = fleet.replicas[0].engine
+    jax.block_until_ready(be.pool.state)
+    emit(phase="hybrid_build", model=cfg.model_name, n_layers=cfg.n_layers,
+         state_layers=cfg.n_state_layers, cache_layers=cfg.n_cache_layers,
+         d_model=cfg.d_model, vocab=cfg.vocab_size,
+         kv_rows=list(be.pool.state.k.shape),
+         slot_state_bytes=be.pool.slot_state_bytes)
+    check(be.prefix_cache is None, "a model with per-slot state was given "
+          "a prefix cache")
+
+    rng = np.random.default_rng(geo["seed"])
+    lo, hi = geo["prompt_range"]
+    prompts = [[int(t) for t in rng.integers(0, cfg.vocab_size,
+                                             int(rng.integers(lo, hi)))]
+               for _ in range(geo["n_requests"])]
+    rids = [fleet.submit(p, geo["new_tokens"]) for p in prompts]
+    wave = drain_fleet(fleet, caches)
+    check_fleet(fleet, rids, geo["new_tokens"], prefix_hit=False)
+    c = be.metrics.counters
+    tokens = sum(len(p) for p in prompts) \
+        + geo["n_requests"] * (geo["new_tokens"] - 1)
+    emit(phase="hybrid_serve", requests=len(rids), steps=wave["steps"],
+         wall_s=round(wave["wall_s"], 3), first_call=wave["first_call"],
+         ssm_rows_advanced=c.get("ssm_rows_advanced", 0.0),
+         ssm_states_reset=c.get("ssm_states_reset", 0.0),
+         kv_rows_appended=c.get("kv_rows_appended", 0.0),
+         trace_counts=be.trace_counts)
+    check(c.get("ssm_rows_advanced") == tokens * cfg.n_state_layers
+          and c.get("kv_rows_appended") == tokens * cfg.n_cache_layers
+          and c.get("ssm_states_reset") == geo["n_requests"],
+          f"the step's counts do not add up to {tokens} tokens of "
+          f"{geo['n_requests']} requests")
+
+    # Numbers: the same tokens through the chunk scan (chunked prefill,
+    # then one decode step) and through the kernel alone.
+    walked = [p[:geo["walk_len"]] for p in prompts[:2]]
+    next_tok = [p[0] for p in walked]
+    compare_logits("hybrid chunked prefill + decode step vs the one-token "
+                   "state update alone",
+                   paged_logits(be, walked, next_tok),
+                   decode_walk_logits(be, walked, next_tok),
+                   logit_tolerance(cfg))
+    be.pool.check_invariants()
+    emit(phase="hybrid_memory", peak_bytes_in_use=peak_bytes(devices[:1]))
+
+
+def run_served_blocks(devices, geo: dict, caches: _CacheEvents) -> None:
+    """The one-chip smoke: the dense model, then (its buffers dropped) the
+    hybrid block."""
+    import gc
+
+    run_one_chip(devices, geo, caches)
+    gc.collect()
+    run_hybrid(devices, HYBRID, caches)
 
 
 # -- four chips: TP=4 dist against xla ---------------------------------------
@@ -543,7 +677,7 @@ def main(argv=None) -> int:
             return 2
         if args.chips == 4:
             return smoke(run_four_chips, devices[:4], FOUR_CHIPS)
-        return smoke(run_one_chip, devices[:1], ONE_CHIP)
+        return smoke(run_served_blocks, devices[:1], ONE_CHIP)
     finally:
         faulthandler.cancel_dump_traceback_later()
 

@@ -219,3 +219,86 @@ def test_grouped_product_over_expert_tiles_compiles(one_chip, monkeypatch,
         _sds((), jnp.int32, one_chip)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "moe_grouped_gemm" in text
+
+
+# granite-4.0-h-micro's cell (perfbench/configs/granite-4.0-h-micro.json):
+# 32 slots, 36 layers of per-slot state (64 heads x 64 x 128 float32), a
+# 3,328-block pool of 4 stacked attention layers whose 8 key heads of 64
+# lie two to a row of 128, a prefill block of 7 rows of 64.
+HYB_SLOTS, HYB_BLOCKS, HYB_PREFILL_ROWS = 32, 3328, 7
+
+
+def test_ssm_state_update_compiles_in_place(one_chip):
+    from triton_distributed_tpu.kernels.ssm_update import ssm_state_update
+
+    f32 = jnp.float32
+    arena = _sds((36, HYB_SLOTS, 64, 64, 128), f32, one_chip)
+    compiled = jax.jit(
+        lambda ar, ly, a, u, b, c: ssm_state_update(ar, ly, a, u, b, c,
+                                                    interpret=False),
+        donate_argnums=0).lower(
+        arena, _sds((), jnp.int32, one_chip),
+        _sds((HYB_SLOTS, 64), f32, one_chip),
+        _sds((HYB_SLOTS, 64, 64), f32, one_chip),
+        _sds((HYB_SLOTS, 1, 128), f32, one_chip),
+        _sds((HYB_SLOTS, 1, 128), f32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_state_update" in text
+    mem = compiled.memory_analysis()
+    # the arena is the result: no second one, and nothing beside it
+    assert mem.alias_size_in_bytes == 36 * HYB_SLOTS * 64 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_hybrid_step_compiles_with_its_state_in_place(topo, kind):
+    """The whole served step of the published configuration (all 40 layers,
+    every width), as ``BatchEngine`` builds it around ``forward_paged``:
+    it compiles, every arena of the pool's state (rows and per-slot) is
+    aliased in to out, and the step's temporaries hold no copy of one (the
+    smallest arena is 30 MB, the recurrence's 2.4 GB)."""
+    from triton_distributed_tpu.models.config import GraniteHybridConfig
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.models.granite_hybrid import GraniteHybrid
+    from triton_distributed_tpu.serving.kv_pool import (
+        paged_state_shapes,
+        paged_state_specs,
+    )
+
+    cfg = GraniteHybridConfig()
+    mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
+    here = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, here), tree)
+
+    params = placed(jax.eval_shape(
+        lambda k: GraniteHybrid(cfg).init(k, mesh), jax.random.PRNGKey(0)))
+    state = placed(paged_state_shapes(
+        cfg, n_blocks=HYB_BLOCKS, block_size=BLOCK, n_slots=HYB_SLOTS))
+    state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    assert 2.8e9 < state_bytes < 2.95e9
+    engine = Engine(cfg, mesh=mesh, params=params, mode="dist",
+                    interpret=False)
+    step = jax.jit(
+        engine._make_sm("dist", paged=kind, paged_attn="fused",
+                        state_specs=paged_state_specs(cfg)),
+        donate_argnums=(2,))
+    slots = (_sds((HYB_SLOTS,), jnp.int32, here),
+             _sds((HYB_SLOTS, MAX_BLOCKS), jnp.int32, here),
+             _sds((HYB_SLOTS,), bool, here))
+    if kind == "decode":
+        args = (_sds((HYB_SLOTS, 1), jnp.int32, here), state, *slots)
+    else:
+        ids = (_sds((HYB_SLOTS,), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here))
+        args = (ids, state, *slots, _sds((HYB_SLOTS,), jnp.int32, here))
+    compiled = step.lower(params, *args).compile()
+    text = compiled.as_text()
+    # nine state updates and one block walk a period of ten layers
+    assert text.count("tpu_custom_call") >= 10 and "ssm_state_update" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes

@@ -73,6 +73,33 @@ def test_smoke_passes_at_tiny_and_prints_the_ok_line(
     assert records[phases.index("numeric")]["prefill_rel"] < 1e-4
 
 
+def test_hybrid_phase_passes_at_tiny(capsys, restore_compile_cache_config):
+    """The smoke's hybrid phase at tiny float32 sizes: requests served, the
+    step's counts adding up, and the chunk scan against the one-token
+    update on the same tokens."""
+    import dataclasses
+
+    from triton_distributed_tpu.models.config import GraniteHybridConfig
+
+    # (the gather path: the fused block walk under the interpreter is
+    # tests/test_granite_hybrid.py's)
+    geo = dict(chip_smoke.HYBRID, interpret=None, paged_attn="gather",
+               n_slots=2, block_size=4,
+               prefill_chunk=8, n_requests=3, prompt_range=(10, 20),
+               new_tokens=3, walk_len=10,
+               overrides=dataclasses.asdict(GraniteHybridConfig.tiny()))
+    rc = chip_smoke.smoke(chip_smoke.run_hybrid, jax.devices()[:1], geo)
+    records = [json.loads(line)
+               for line in capsys.readouterr().out.strip().splitlines()]
+    assert rc == 0 and records[-1]["ok"] is True
+    phases = [r.get("phase") for r in records[:-1]]
+    assert phases == ["hybrid_build", "hybrid_serve", "numeric",
+                      "hybrid_memory"]
+    assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
+    assert records[1]["ssm_states_reset"] == 3
+    assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+
+
 def test_forced_step_exception_fails_the_smoke(
         monkeypatch, capsys, restore_compile_cache_config):
     """A step that raises at run time is absorbed by the replica error

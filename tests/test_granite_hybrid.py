@@ -1,0 +1,515 @@
+"""The Granite-4.0-H block (``models/granite_hybrid.py``: Mamba-2 layers that
+keep a fixed-size state a slot beside attention layers that keep rows in
+the paged pool) against the benchmark's plain reference
+(``perfbench/families/granite_hybrid.py``: one sequential scan over the
+positions), at tiny float32 sizes on the CPU. ``paged_attn="gather"``
+wherever the fused kernel is not the thing tested.
+"""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench import reference, weights
+from perfbench.families import granite_hybrid as family
+from triton_distributed_tpu.kernels.ssm_update import (
+    ssm_state_update,
+    ssm_state_update_reference,
+)
+from triton_distributed_tpu.layers import nn
+from triton_distributed_tpu.models.config import (
+    GraniteHybridConfig,
+    ModelConfig,
+)
+from triton_distributed_tpu.models.engine import Engine
+from triton_distributed_tpu.obs import trace as _trace
+from triton_distributed_tpu.runtime.mesh import make_mesh
+from triton_distributed_tpu.serving.batch_engine import BatchEngine
+from triton_distributed_tpu.serving.kv_pool import KVPool
+
+# Two periods of (Mamba-2, attention, Mamba-2); two key heads of 16 packed
+# into one row of 32.
+SIZES = family.Sizes(
+    vocab_size=256, d_model=64, layer_types=("mamba", "attention", "mamba") * 2,
+    heads=4, kv_heads=2, mlp_width=96, ssm_heads=4, ssm_head_width=8,
+    ssm_state=16, ssm_conv=4, ssm_groups=2, embedding_multiplier=12.0,
+    residual_multiplier=0.22, attention_multiplier=1 / 16,
+    logits_scaling=8.0, eps=1e-5, max_length=64, dtype="float32")
+SEED = 41
+MULTIPLIERS = ("embedding_multiplier", "residual_multiplier",
+               "attention_multiplier", "logits_scaling")
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    return make_mesh({"tp": 1}, devices=jax.devices()[:1], set_default=False)
+
+
+@pytest.fixture(scope="module")
+def served(mesh):
+    mcfg, params = family.program({"source": "t"}, SIZES, SEED, mesh, {})
+    return Engine(mcfg, mesh=mesh, params=params, mode="dist")
+
+
+def ref_read(tokens, first):
+    w = weights.Weights(family, SIZES, SEED)
+    return reference.forward_positions(w, [(tokens, first)])[0]
+
+
+_DONORS: dict = {}
+
+
+def batch_engine(served, **kw):
+    """A ``BatchEngine`` at the tests' geometry. Engines of one geometry
+    share their compiled steps (``share_steps_from``, what an elastic spawn
+    does), so a test that builds several compiles once."""
+    kw = {**dict(n_slots=4, n_blocks=48, block_size=4, prefill_chunk=8,
+                 paged_attn="gather"), **kw}
+    be = BatchEngine(served, **kw)
+    donor = _DONORS.setdefault(
+        (id(served), kw["n_slots"], kw["paged_attn"]), be)
+    if donor is not be:
+        be.share_steps_from(donor)
+    return be
+
+
+def prompts(seed, *lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, SIZES.vocab_size, n).tolist() for n in lengths]
+
+
+def alone(served, prompt, n_new):
+    """What a request gives in an engine it has to itself."""
+    be = batch_engine(served, n_slots=2)
+    rid = be.submit(prompt, n_new)
+    be.run()
+    return be.finished[rid].output
+
+
+def assert_served_is_the_references_best(prompt, out):
+    ref = ref_read(prompt + out, len(prompt))
+    assert ref["best_token"].tolist() == out
+    assert np.all(ref["best"] - ref["picked"] <= 1e-5)
+
+
+def test_engine_picks_the_model_from_the_configuration_object(served):
+    from triton_distributed_tpu.models.granite_hybrid import GraniteHybrid
+    from triton_distributed_tpu.models.qwen import Qwen3
+
+    assert isinstance(served.model, GraniteHybrid)
+    assert served.model.pattern == ("mamba", "attention", "mamba")
+    assert isinstance(Engine(ModelConfig.from_name("tiny"), mesh=served.mesh,
+                             mode="xla").model, Qwen3)
+    # the published pattern is one period of ten
+    assert GraniteHybrid(GraniteHybridConfig()).pattern == \
+        ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+def paged_steps(served, n_slots, n_blocks=24):
+    pool = KVPool(served.config, n_blocks=n_blocks, block_size=4,
+                  mesh=served.mesh, n_slots=n_slots)
+    kw = dict(paged_attn="gather", state_specs=pool.specs)
+    return (pool, jax.jit(served._make_sm("dist", paged="prefill", **kw)),
+            jax.jit(served._make_sm("dist", paged="decode", **kw)))
+
+
+def logits_of_a_staggered_batch(engine, decode: bool = True):
+    """Three slots through the step functions ``BatchEngine`` compiles, the
+    mixed step in its two-block form: sequence a prefills in chunks of 8
+    (8, 8, 3) and then decodes; b is admitted one step later (5 tokens,
+    then decodes beside a's prefill: a decode-block row beside a
+    prefill-block row); slot 1 stays empty. Returns the logits of a at
+    positions 18, 19, 20 and of b at 4, 5, 6, 7 (without ``decode``, those
+    the three mixed steps give: a at 18, b at 4, 5)."""
+    a, b = TOKENS_A, TOKENS_B
+    pool, pre, dec = paged_steps(engine, 3)
+    assert pool.ensure("a", 21) and pool.ensure("b", 8)
+    tables = jnp.asarray(pool.padded_tables(["a", None, "b"]))
+    state, got_a, got_b = pool.state, [], []
+
+    def mixed(state, off, lens, tok, chunk_rows):
+        chunk = np.zeros((2, 8), np.int32)
+        for k, row in enumerate(chunk_rows):
+            chunk[k, :len(row)] = row
+        live = jnp.asarray([n > 0 for n in lens])
+        return pre(engine.params,
+                   (jnp.asarray(tok, jnp.int32), jnp.asarray(chunk)), state,
+                   jnp.asarray(off, jnp.int32), tables, live,
+                   jnp.asarray(lens, jnp.int32))
+
+    # step 1: a[0:8] alone
+    logits, aux, state = mixed(state, [0, 0, 0], [8, 0, 0], [0, 0, 0],
+                               [a[0:8]])
+    assert aux["stats"].tolist() == [8 * 4, 1, 8 * 2]
+    # step 2: a[8:16] beside b[0:5] (two rows of the prefill block)
+    logits, aux, state = mixed(state, [8, 0, 0], [8, 0, 5], [0, 0, 0],
+                               [a[8:16], b[0:5]])
+    assert aux["stats"].tolist() == [13 * 4, 1, 13 * 2]
+    got_b.append(logits[2])                                # b position 4
+    # step 3: a[16:19] in the prefill block, b[5] in the decode block
+    logits, aux, state = mixed(state, [16, 0, 5], [3, 0, 1], [0, 0, b[5]],
+                               [a[16:19]])
+    assert aux["stats"].tolist() == [4 * 4, 0, 4 * 2]
+    got_a.append(logits[0])                                # a position 18
+    got_b.append(logits[2])                                # b position 5
+    # steps 4, 5: both decode
+    for k in range(2 if decode else 0):
+        logits, aux, state = dec(
+            engine.params,
+            jnp.asarray([[a[19 + k]], [0], [b[6 + k]]], jnp.int32), state,
+            jnp.asarray([19 + k, 0, 6 + k], jnp.int32), tables,
+            jnp.asarray([True, False, True]))
+        got_a.append(logits[0])
+        got_b.append(logits[2])
+    assert aux["stats"].tolist()[0] == (2 if decode else 4) * 4
+    assert jax.tree.structure(state) == jax.tree.structure(pool.state)
+    return np.asarray(got_a), np.asarray(got_b)
+
+
+TOKENS_A, TOKENS_B = prompts(3, 21, 8)
+
+
+def assert_logits_agree(got, tokens, first):
+    ref = ref_read(tokens + [0], first)
+    for i, logits in enumerate(got):
+        assert ref["best_token"][i] == int(logits.argmax())
+        assert ref["best"][i] == pytest.approx(float(logits.max()), abs=2e-5)
+        assert ref["std"][i] == pytest.approx(float(logits.std()), rel=1e-3)
+        nxt = (tokens + [0])[first + i]
+        assert ref["picked"][i] == pytest.approx(float(logits[nxt]),
+                                                 abs=2e-5)
+
+
+def test_prefill_then_decode_of_rows_admitted_at_different_steps_agrees_on_logits(
+        served):
+    """Chunked prefill with a ragged last chunk, a decode row beside a
+    prefilling one, then decode steps, against the reference's ONE full
+    forward pass of each sequence: the best logit, its token, the next
+    token's logit and the row's spread at each position read."""
+    got_a, got_b = logits_of_a_staggered_batch(served)
+    assert_logits_agree(got_a, TOKENS_A, 19)       # positions 18, 19, 20
+    assert_logits_agree(got_b, TOKENS_B, 5)        # positions 4, 5, 6, 7
+
+
+@pytest.mark.parametrize("name", MULTIPLIERS)
+def test_each_multiplier_changes_the_result(served, name):
+    """The same batch with ONE of the four multipliers set to 1 no longer
+    agrees with the reference."""
+    wrong = Engine(dataclasses.replace(served.config, **{name: 1.0}),
+                   mesh=served.mesh, params=served.params, mode="dist")
+    got_a, _ = logits_of_a_staggered_batch(wrong, decode=False)
+    with pytest.raises(AssertionError):
+        assert_logits_agree(got_a, TOKENS_A, 19)
+
+
+@pytest.mark.parametrize("paged_attn", ["gather", "fused"])
+def test_batch_engine_serves_what_the_reference_puts_first(served,
+                                                           paged_attn):
+    """Requests of several lengths through ``BatchEngine``, one of them
+    submitted after the others have started (admission, chunked prefill
+    beside decode rows, the state update's kernel under the interpreter,
+    the packed rows' block tables): every served token is the reference's
+    best at its position."""
+    _trace.get_tracer().reset()
+    _trace.enable()
+    try:
+        be = batch_engine(served, paged_attn=paged_attn)
+        ps = prompts(5, 5, 11, 17, 9)
+        reqs = [be.submit(p, 6) for p in ps[:3]]
+        for _ in range(3):
+            be.step()
+        reqs.append(be.submit(ps[3], 6))
+        be.run()
+        spans = [r for r in _trace.get_tracer().records
+                 if r.name in ("decode_step", "mixed_step")]
+    finally:
+        _trace.disable()
+        _trace.get_tracer().reset()
+    be.pool.check_invariants()
+    assert be.trace_counts == {"decode": 1, "prefill": 1}
+    assert be.prefix_cache is None
+    c = be.metrics.counters
+    tokens = sum(len(p) for p in ps) + 4 * 5
+    assert c["ssm_rows_advanced"] == tokens * 4
+    assert c["kv_rows_appended"] == tokens * 2
+    assert c["ssm_states_reset"] == 4
+    assert "prefix_cached_tokens" not in c
+    assert sum(r.attrs["ssm_rows_advanced"] for r in spans) == tokens * 4
+    snap = be.stats_snapshot()["pool"]
+    assert snap["slot_state_bytes"] == be.pool.state.ssm.nbytes \
+        + be.pool.state.conv.nbytes > 0
+    for rid, prompt in zip(reqs, ps):
+        assert_served_is_the_references_best(prompt, be.finished[rid].output)
+
+
+def test_the_chunk_scan_equals_the_sequential_one_with_a_ragged_last_chunk(
+        served):
+    """One Mamba-2 layer over 21 positions in chunks of 8 (8, 8, 5 live of
+    8), its state and window carried from chunk to chunk in slot 1 of a
+    3-slot arena, against the reference's sequential scan of the whole
+    sequence; then the same through single-token steps (the kernel)."""
+    layer = served.model.mamba
+    lp = jax.tree.map(lambda a: a[1, 0],
+                      served.params["periods"]["mamba"]["mixer"])
+    lw = reference.f32(family.plain_layer(
+        SIZES, weights.keys(SEED, SIZES.n_layers)[1][3], True))
+    np.testing.assert_array_equal(lp["w_in"], lw["w_in"])
+    x = jax.random.normal(jax.random.PRNGKey(2), (21, SIZES.d_model))
+    with jax.default_matmul_precision("highest"):
+        want = family.ssm_mixer(SIZES, x, lw, "float32")
+    pool = KVPool(served.config, n_blocks=4, block_size=4, n_slots=3)
+    dirty = jax.tree.map(lambda a: None if a is None else a + 3.0,
+                         pool.state)      # what an earlier tenant left
+
+    def fwd(part, state, offsets, lens, *, L):
+        blk = nn.TokenBlock(0, L, offsets, None, lens > 0,
+                            None if L == 1 else lens)
+        return layer.fwd(lp, part, state, blocks=(blk,), layer=jnp.int32(2),
+                         interpret=True)
+
+    fwd = jax.jit(fwd, static_argnames="L")
+
+    def run(take):
+        state, outs, done = dirty, [], 0
+        while done < 21:
+            n = min(take, 21 - done)
+            L = take if take > 1 else 1
+            part = jnp.zeros((3, L, SIZES.d_model)).at[1, :n].set(
+                x[done:done + n])
+            out, state = fwd(part.reshape(3 * L, -1), state,
+                             jnp.asarray([0, done, 0], jnp.int32),
+                             jnp.asarray([0, n, 0], jnp.int32), L=L)
+            outs.append(out.reshape(3, L, -1)[1, :n])
+            done += n
+        return jnp.concatenate(outs), state
+
+    got, state = run(8)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    one, state1 = run(1)
+    np.testing.assert_allclose(one, want, atol=2e-5)
+    np.testing.assert_allclose(state1.ssm[2, 1], state.ssm[2, 1], atol=1e-5)
+    np.testing.assert_allclose(state1.conv[2, 1], state.conv[2, 1],
+                               atol=1e-6)
+    # the other slots and the other layers are as they were, to the bit
+    for s in (state, state1):
+        for got_a, was in ((s.ssm, dirty.ssm), (s.conv, dirty.conv)):
+            keep = np.ones(got_a.shape[:2], bool)
+            keep[2, 1] = False
+            np.testing.assert_array_equal(np.asarray(got_a)[keep],
+                                          np.asarray(was)[keep])
+
+
+@pytest.mark.parametrize("groups,tile", [(1, 2), (2, 4), (1, 8)])
+def test_the_state_update_kernel_equals_plain_jnp(groups, tile):
+    """``ssm_state_update`` under the interpreter: layer 1 of a 3-layer
+    arena advanced, the others untouched; a slot with decay 1 and no input
+    keeps its state to the bit."""
+    rng = np.random.default_rng(tile)
+    n_layers, n_slots, H, P, N = 3, 4, 8, 16, 128
+    arena = jnp.asarray(rng.standard_normal((n_layers, n_slots, H, P, N)),
+                        jnp.float32)
+    a = jnp.asarray(rng.uniform(0.5, 1, (n_slots, H)), jnp.float32)
+    u = jnp.asarray(rng.standard_normal((n_slots, H, P)), jnp.float32)
+    a, u = a.at[2].set(1.0), u.at[2].set(0.0)              # a dead slot
+    b = jnp.asarray(rng.standard_normal((n_slots, groups, N)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((n_slots, groups, N)), jnp.float32)
+    got, y = ssm_state_update(arena, jnp.int32(1), a, u, b, c,
+                              head_tile=tile, interpret=True)
+    want, y_want = ssm_state_update_reference(arena, 1, a, u, b, c)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    np.testing.assert_allclose(y, y_want, atol=2e-5)
+    np.testing.assert_array_equal(got[1, 2], arena[1, 2])
+    np.testing.assert_array_equal(got[0], arena[0])
+    np.testing.assert_array_equal(got[2], arena[2])
+
+
+def test_a_slot_reused_by_a_second_request_gives_what_that_request_gives_alone(
+        served):
+    be = batch_engine(served, n_slots=1)
+    first, second = prompts(7, 13, 9)
+    ra = be.submit(first, 5)
+    rb = be.submit(second, 7)
+    be.run()
+    assert be.metrics.counters["ssm_states_reset"] == 2
+    assert be.finished[ra].output == alone(served, first, 5)
+    out = be.finished[rb].output
+    assert out == alone(served, second, 7)
+    assert_served_is_the_references_best(second, out)
+
+
+def test_a_preempted_and_readmitted_request_gives_what_an_undisturbed_one_gives(
+        served):
+    be = batch_engine(served, n_slots=2)
+    p, q = prompts(9, 12, 7)
+    rp, rq = be.submit(p, 9), be.submit(q, 9)
+    for _ in range(5):                   # both prefilled, some tokens out
+        be.step()
+    victim = next(i for i, s in enumerate(be._slots)
+                  if s is not None and s.req.req_id == rp)
+    assert 0 < len(be._slots[victim].req.output) < 9
+    be._preempt(victim)
+    be.run()
+    be.pool.check_invariants()
+    assert be.metrics.counters["preemptions"] == 1
+    assert be.metrics.counters["ssm_states_reset"] == 3     # p twice
+    assert be.finished[rp].output == alone(served, p, 9)
+    assert be.finished[rq].output == alone(served, q, 9)
+    assert_served_is_the_references_best(p, be.finished[rp].output)
+
+
+def test_a_common_prefix_is_not_matched_and_each_gives_what_it_gives_alone(
+        served):
+    """The prefix cache asked for (the default) and two requests that share
+    three whole blocks with a finished one: no block is adopted (a block
+    holds rows, not the state at its boundary), each prefills from 0."""
+    be = batch_engine(served, prefix_cache=True)
+    donor, = prompts(11, 14)
+    be.submit(donor, 3)
+    be.run()
+    tails = prompts(12, 4, 6)
+    reqs = [be.submit(donor[:12] + t, 5) for t in tails]
+    be.run()
+    c = be.metrics.counters
+    assert be.prefix_cache is None and be.pool.n_cached == 0
+    assert c.get("prefix_cached_tokens", 0) == 0 == c.get("prefix_hits", 0)
+    assert c["ssm_states_reset"] == 3
+    for rid, t in zip(reqs, tails):
+        out = be.finished[rid].output
+        assert out == alone(served, donor[:12] + t, 5)
+        assert_served_is_the_references_best(donor[:12] + t, out)
+
+
+def test_drain_and_quarantine_leave_nothing_the_next_request_reads(served):
+    be = batch_engine(served, n_slots=1)
+    p, q = prompts(13, 10, 6)
+    be.submit(p, 8)
+    for _ in range(4):
+        be.step()
+    drained = be.drain("test")
+    assert [r.prompt for r in drained] == [p] and drained[0].output
+    rq = be.submit(q, 4)                 # takes the slot p's state is in
+    for _ in range(2):
+        be.step()
+    be._quarantine(0, "test")
+    assert be.failed[rq].status == "failed"
+    rp = be.adopt(drained[0])            # p again, recomputed from 0
+    be.run()
+    be.pool.check_invariants()
+    assert be.finished[rp].output == alone(served, p, 8)
+
+
+def test_the_pool_holds_two_kinds_of_state(served):
+    cfg = served.config
+    with pytest.raises(ValueError, match="needs n_slots"):
+        KVPool(cfg, n_blocks=6, block_size=4)
+    with pytest.raises(NotImplementedError, match="quantized"):
+        KVPool(cfg, n_blocks=6, block_size=4, n_slots=2, kv_dtype="int8")
+    pool = KVPool(cfg, n_blocks=6, block_size=4, n_slots=3)
+    st = pool.state
+    # rows: as deep as the model has attention layers, two heads to a row
+    assert st.k.shape == st.v.shape == (2, 6, 4, 1, 32)
+    assert st.ssm.shape == (4, 3, 4, 8, 16) and st.ssm.dtype == jnp.float32
+    assert st.conv.shape == (4, 3, 3 * (32 + 2 * 2 * 16))
+    assert pool.slot_state_bytes == st.ssm.nbytes + st.conv.nbytes
+    assert pool.kv_fingerprint() == "float32:none:slot[conv+ssm]"
+    assert pool.geometry()["slot_state"] == {
+        "conv": [4, 3, 288], "ssm": [4, 3, 4, 8, 16]}
+    assert jax.tree.structure(pool.specs) == jax.tree.structure(st)
+    # a block's copy moves rows and leaves the per-slot arenas alone
+    pool.state = dataclasses.replace(
+        st, k=st.k.at[:, 2].set(7.0), ssm=st.ssm.at[:, 2].set(5.0))
+    pool._copy_block_device(2, 5)
+    assert np.all(np.asarray(pool.state.k[:, 5]) == 7.0)
+    assert np.all(np.asarray(pool.state.ssm[:, 1]) == 0.0)
+    assert np.all(np.asarray(pool.state.ssm[:, 2]) == 5.0)
+    pool.check_invariants()
+    pool.state = dataclasses.replace(pool.state, conv=None)
+    with pytest.raises(AssertionError, match="conv"):
+        pool.check_invariants()
+    # a pool of rows only has none of it
+    rows = KVPool(ModelConfig.from_name("tiny"), n_blocks=6, block_size=4)
+    assert rows.state.ssm is None and rows.slot_state_bytes == 0
+    assert "slot_state" not in rows.geometry()
+
+
+def test_steps_are_shared_only_between_pools_of_one_format(served):
+    a = batch_engine(served, n_slots=2)
+    b = batch_engine(served, n_slots=2)
+    b.share_steps_from(a)
+    assert b._decode_step is a._decode_step
+    c = batch_engine(served, n_slots=2)
+    c.pool.state = dataclasses.replace(
+        c.pool.state, ssm=c.pool.state.ssm.astype(jnp.bfloat16))
+    with pytest.raises(ValueError, match="pool format"):
+        c.share_steps_from(a)
+
+
+def test_what_is_not_built_is_refused_by_name(served):
+    pool = KVPool(served.config, n_blocks=8, block_size=4, n_slots=2)
+    args = (served.params, jnp.zeros((2, 8), jnp.int32), pool.state,
+            jnp.zeros((2,), jnp.int32), jnp.zeros((2, 16), jnp.int32),
+            jnp.ones((2,), bool), jnp.ones((2,), jnp.int32))
+    step = jax.jit(served._make_sm(
+        "dist", paged="prefill", paged_attn="gather", spec_verify=True,
+        state_specs=pool.specs))
+    with pytest.raises(NotImplementedError, match="roll the state back"):
+        step.lower(*args)
+    mesh2 = make_mesh({"tp": 2}, devices=jax.devices()[:2], set_default=False)
+    # (heads of 64: two to a row, two rows a token, one a device)
+    engine = Engine(GraniteHybridConfig.tiny(d_model=256, n_kv_heads=4),
+                    mesh=mesh2, mode="dist")
+    pool2 = KVPool(engine.config, n_blocks=8, block_size=4, mesh=mesh2,
+                   n_slots=2)
+    step = jax.jit(engine._make_sm("dist", paged="decode",
+                                   paged_attn="gather",
+                                   state_specs=pool2.specs))
+    with pytest.raises(NotImplementedError,
+                       match="per-slot state under tensor parallelism"):
+        step.lower(engine.params, jnp.zeros((2, 1), jnp.int32), pool2.state,
+                   jnp.zeros((2,), jnp.int32), jnp.zeros((2, 16), jnp.int32),
+                   jnp.ones((2,), bool))
+
+
+def test_counts_of_the_published_configuration():
+    """The family's counts at granite-4.0-h-micro's sizes against the
+    issue's hand count: 3.19 B parameters (6.38 GB), 8,192 B of rows a
+    token, and 76.4 MB of state a slot: the issue's 77.4 MB counted the
+    convolution's window in float32; it is held in the served dtype."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "perfbench/configs/granite-4.0-h-micro.json")) as f:
+        cfg = json.load(f)
+    m = family.sizes(cfg)
+    assert (m.n_layers, family.n_ssm_layers(m), m.d_inner, m.conv_width) == \
+        (40, 36, 4096, 4352)
+    assert [i for i in range(40) if not family.is_ssm(m, i)] == \
+        [5, 15, 25, 35]
+    assert family.layer_params(m, True) == pytest.approx(76.2e6, rel=2e-3)
+    assert family.layer_params(m, False) == pytest.approx(60.8e6, rel=2e-3)
+    assert family.weight_params(m) == pytest.approx(3.19e9, rel=2e-3)
+    assert family.kv_bytes_per_token(m) == 8192
+    per_slot = family.state_bytes_per_slot(m)
+    assert per_slot == 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+    assert per_slot == pytest.approx(77.4e6, rel=0.015)
+    assert family.ssm_update_min_bytes(m, 32) == 32 * 36 * 2 * 2_097_152
+    assert family.ssm_update_flops(m, 1) == 5 * 36 * 64 * 64 * 128
+    step = family.decode_step_min_bytes(m, [1800] * 32)
+    assert step == (2 * family.weight_params(m) + 32 * 2 * per_slot
+                    + 32 * 1800 * 8192)
+    assert step == pytest.approx(11.8e9, rel=0.01)
+    # the program's own configuration object, and what its pool would hold
+    mcfg = family.program_config(cfg, m)
+    assert (mcfg.kv_pack, mcfg.kv_row_shapes[0]) == (2, (4, 128))
+    assert (mcfg.n_cache_layers, mcfg.n_state_layers) == (4, 36)
+    assert mcfg.slot_state_shapes["ssm"][0] == (64, 64, 128)
+    assert mcfg.slot_state_shapes["conv"][0] == (3 * 4352,)
+    from triton_distributed_tpu.models.granite_hybrid import GraniteHybrid
+    model = GraniteHybrid(mcfg)
+    n = sum(int(np.prod(leaf[0])) for leaf in jax.tree.leaves(
+        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple)))
+    assert n == family.weight_params(m)

@@ -66,6 +66,10 @@ OF_A_FAMILY = {
         r"deepseek|joyai|DeepseekV3Config|kv_lora|q_lora|qk_nope|qk_rope|"
         r"n_routed|routed_scaling|first_k_dense|kv_rank|router_width",
         re.IGNORECASE),
+    "granite_hybrid": re.compile(
+        r"granite|GraniteHybrid|mamba_|layer_types|embedding_multiplier|"
+        r"residual_multiplier|attention_multiplier|logits_scaling",
+        re.IGNORECASE),
 }
 
 

@@ -1,0 +1,216 @@
+"""The Mamba-2 mixer over per-slot state (one device).
+
+A layer keeps, for each SLOT of the serving batch, a state of fixed size:
+the recurrence's ``S`` (heads, head width, state width) in float32 and the
+convolution's window, the last ``d_conv - 1`` inputs. Both live in the
+paged pool's state (``serving.kv_pool.PagedKVState.ssm`` / ``.conv``: one
+arena each over (state layers, slots)), which this layer is handed whole
+with its own index and hands back, as the attention layers do with the row
+arenas.
+
+The equations (Mamba-2; HF ``GraniteMoeHybridMambaLayer``), for one token
+``x`` of the stream::
+
+    [z ; xBC ; dt] = x W_in                     (d_inner ; conv_dim ; H)
+    xBC <- silu(conv(xBC))     causal depthwise, the last d_conv positions
+    [x_s ; B ; C] = xBC        x_s: H heads of P; B, C: G groups of N
+    D_t = softplus(dt + dt_bias)                a_t = exp(D_t * A),  A = -exp(A_log)
+    S_t = a_t S_{t-1} + D_t x_s (x) B           y = S_t C + D x_s    (a head)
+    out = RMSNorm(y * silu(z)) W_out            (gate BEFORE the norm)
+
+Two step shapes, by the block of the paged step's token batch
+(``nn.TokenBlock``): one token a slot (``L == 1``; the decode block) runs
+the recurrence in ``kernels.ssm_update.ssm_state_update``, in place on the
+arena; a chunk of ``L`` positions a row runs ``chunk_scan``, plain
+``jax.numpy``, with the row's state gathered out of the arena and scattered
+back. Either way a slot is advanced over its LIVE positions only: a dead
+row, and a chunk's positions past the row's length, have ``D_t = 0`` (so
+``a_t = 1`` and nothing is added to ``S``) and do not enter the window. A
+row whose cache length before the step is 0 starts from a zero state and a
+zero window, whatever the arena holds: the request before it in the slot
+leaves nothing a new one could read, and nothing has to be cleared from the
+host.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from triton_distributed_tpu.kernels.ssm_update import ssm_state_update
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def chunk_scan(x, dt, a_log_step, b, c, s0):
+    """The recurrence over a chunk, state in and out (all float32).
+
+    x (R, L, H, P); dt (R, L, H) the step ``D_t`` (0 at a dead position);
+    a_log_step (R, L, H) = ``D_t * A`` (the log of the decay, <= 0);
+    b, c (R, L, G, N); s0 (R, H, P, N). Returns ``(y (R, L, H, P), s_L)``.
+    With ``la_t`` the running sum of the log decays,
+
+        y_t = exp(la_t) S_0 C_t + sum_{s<=t} exp(la_t - la_s) (C_t . B_s) D_s x_s
+        S_L = exp(la_L) S_0 + sum_s exp(la_L - la_s) D_s x_s (x) B_s
+
+    the second line's products as matrix products over the chunk (the
+    structured-state-space duality). Every product runs at ``HIGHEST``: the
+    state is carried for thousands of steps."""
+    R, L, H, P = x.shape
+    G = b.shape[2]
+    la = jnp.cumsum(a_log_step, axis=1)                        # (R, L, H)
+    u = dt[..., None] * x                                      # D_s x_s
+    hb = jnp.repeat(b, H // G, axis=2)                         # (R, L, H, N)
+    hc = jnp.repeat(c, H // G, axis=2)
+    # within the chunk
+    cb = jnp.einsum("rthn,rshn->rhts", hc, hb, precision=HIGHEST)
+    diff = la.transpose(0, 2, 1)[..., :, None] \
+        - la.transpose(0, 2, 1)[..., None, :]                  # (R, H, t, s)
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    w = jnp.where(causal, jnp.exp(jnp.where(causal, diff, 0.0)), 0.0) * cb
+    y = jnp.einsum("rhts,rshp->rthp", w, u, precision=HIGHEST)
+    # from the state the chunk started with
+    y += jnp.exp(la)[..., None] * jnp.einsum(
+        "rthn,rhpn->rthp", hc, s0, precision=HIGHEST)
+    to_end = jnp.exp(la[:, -1:, :] - la)                       # (R, L, H)
+    s = (jnp.exp(la[:, -1])[..., None, None] * s0
+         + jnp.einsum("rshp,rshn->rhpn", to_end[..., None] * u, hb,
+                      precision=HIGHEST))
+    return y, s
+
+
+@dataclasses.dataclass(frozen=True)
+class Mamba2:
+    d_model: int
+    n_heads: int            # H
+    d_head: int             # P
+    d_state: int            # N
+    d_conv: int = 4
+    n_groups: int = 1       # G
+    rms_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+    def param_shapes(self) -> dict:
+        """name -> (shape, fan_in); fan_in None marks what is not a matrix
+        (``models.granite_hybrid`` says how each is drawn)."""
+        d, di, C, H = self.d_model, self.d_inner, self.conv_dim, self.n_heads
+        return {"w_in": ((d, 2 * di + 2 * self.n_groups * self.d_state + H),
+                         d),
+                "conv_w": ((self.d_conv, C), self.d_conv),
+                "conv_b": ((C,), None), "dt_bias": ((H,), None),
+                "a_log": ((H,), None), "d_skip": ((H,), None),
+                "norm": ((di,), None), "w_out": ((di, d), di)}
+
+    # -- the pieces ---------------------------------------------------------
+
+    def _conv(self, params, window, xbc, n_live):
+        """Causal depthwise convolution over ``[window ; xbc]`` and the
+        window it leaves. window (R, K-1, C), oldest first; xbc (R, L, C);
+        n_live (R,) live positions of each row (its first ``n_live``).
+        Returns ``(silu(conv) (R, L, C) float32, window (R, K-1, C))``: the
+        last K-1 LIVE inputs, which for a row with none is the window it
+        came with."""
+        K, L = self.d_conv, xbc.shape[1]
+        seq = jnp.concatenate([window.astype(jnp.float32),
+                               xbc.astype(jnp.float32)], axis=1)
+        w = params["conv_w"].astype(jnp.float32)
+        out = params["conv_b"].astype(jnp.float32) + sum(
+            w[k] * seq[:, k:k + L] for k in range(K))
+        take = n_live[:, None] + jnp.arange(K - 1)[None]          # (R, K-1)
+        window = jnp.take_along_axis(seq, take[..., None], axis=1)
+        return jax.nn.silu(out), window
+
+    def _block(self, params, zxbcdt, state, blk, layer, interpret):
+        """One block of the token batch: ``(y (rows * L, d_inner) float32,
+        state)``, y before the gate and the norm."""
+        R, L = blk.offsets.shape[0], blk.L
+        H, P, N, G = self.n_heads, self.d_head, self.d_state, self.n_groups
+        di, C, K = self.d_inner, self.conv_dim, self.d_conv
+        part = zxbcdt[blk.start:blk.stop].reshape(R, L, -1)
+        xbc, dt = part[..., di:di + C], part[..., di + C:]
+        live = blk.valid().reshape(R, L)
+        n_live = jnp.sum(live, axis=1)
+        fresh = (blk.offsets == 0) & (n_live > 0)  # starts from zero
+        slots = jnp.arange(R) if blk.slots is None else blk.slots
+        # a row with nothing live writes nothing (a dead row of a gathered
+        # block names no slot of its own): out of range, dropped
+        put = jnp.where(n_live > 0, slots, state.conv.shape[1])
+
+        # Where row b is slot b (the decode block) this layer's windows are
+        # one slice of the arena: read and written as a slice, the dead
+        # rows' put back as they were. As a gather and a scatter of 32 rows
+        # the same cost 0.9 ms a decode step of 36 layers on the chip.
+        whole = blk.slots is None
+        held = (jax.lax.dynamic_index_in_dim(state.conv, layer, 0, False)
+                if whole else state.conv[layer, slots])
+        window = jnp.where(fresh[:, None, None], 0,
+                           held.reshape(R, K - 1, C))
+        conv, window = self._conv(params, window, xbc, n_live)
+        window = window.reshape(R, -1).astype(held.dtype)
+        if whole:
+            conv_arena = jax.lax.dynamic_update_index_in_dim(
+                state.conv, jnp.where((n_live > 0)[:, None], window, held),
+                layer, 0)
+        else:
+            conv_arena = state.conv.at[layer, put].set(window, mode="drop")
+
+        x = conv[..., :di].reshape(R, L, H, P)
+        b = conv[..., di:di + G * N].reshape(R, L, G, N)
+        c = conv[..., di + G * N:].reshape(R, L, G, N)
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + params["dt_bias"].astype(jnp.float32))
+        dt = jnp.where(live[..., None], dt, 0.0)                 # (R, L, H)
+        a_log_step = dt * -jnp.exp(params["a_log"].astype(jnp.float32))
+        if L == 1 and whole:
+            # one token a slot: in place on the arena. A fresh row's old
+            # state is multiplied by 0 and not by its decay; a dead row's
+            # by 1 (its step is 0).
+            decay = jnp.where(fresh[:, None], 0.0, jnp.exp(a_log_step[:, 0]))
+            ssm, y = ssm_state_update(
+                state.ssm, layer, decay, dt[:, 0, :, None] * x[:, 0],
+                b[:, 0], c[:, 0], interpret=interpret)
+            y = y[:, None]
+        else:
+            s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                           state.ssm[layer, slots])
+            y, s = chunk_scan(x, dt, a_log_step, b, c, s0)
+            ssm = state.ssm.at[layer, put].set(s, mode="drop")
+        y = y + params["d_skip"].astype(jnp.float32)[:, None] * x
+        state = dataclasses.replace(state, ssm=ssm, conv=conv_arena)
+        return y.reshape(R * L, di), state
+
+    # -- the layer ----------------------------------------------------------
+
+    def fwd(self, params, x, state, *, blocks, layer, interpret=None):
+        """x: the paged step's flat token batch (T, d_model) in the model
+        dtype -> ``(out (T, d_model), state)``. ``layer`` () int32: this
+        layer's index among the layers that keep a state (the arenas'
+        leading axis)."""
+        di = self.d_inner
+        zxbcdt = jnp.dot(x, params["w_in"])
+        ys = []
+        for blk in blocks:
+            y, state = self._block(params, zxbcdt, state, blk, layer,
+                                   interpret)
+            ys.append(y)
+        tail = x.shape[0] - blocks[-1].stop
+        if tail:
+            ys.append(jnp.zeros((tail, di), jnp.float32))
+        y = jnp.concatenate(ys) * jax.nn.silu(
+            zxbcdt[:, :di].astype(jnp.float32))
+        # the gated norm: each group's columns normalised on their own
+        grouped = y.reshape(y.shape[0], self.n_groups, -1)
+        y = (grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+            + self.rms_eps)).reshape(y.shape) * params["norm"]
+        return jnp.dot(y.astype(x.dtype), params["w_out"]), state

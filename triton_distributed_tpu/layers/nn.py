@@ -63,6 +63,9 @@ class TokenBlock(NamedTuple):
     tables: jax.Array          # (rows, max_blocks) int32
     mask: jax.Array | None     # (rows,) live rows; None = all
     seq_lens: jax.Array | None  # (rows,) valid new tokens; None = all L
+    slots: jax.Array | None = None  # (rows,) the slot each row belongs to
+                                    # (a layer with per-slot state reads
+                                    # it); None = row b is slot b
 
     @property
     def stop(self) -> int:
@@ -129,7 +132,7 @@ def paged_token_blocks(ids, offsets, block_tables, slot_mask, seq_lens=None,
                        None),
             TokenBlock(B, L, jnp.where(held, offsets[rows], 0),
                        block_tables[rows], held,
-                       jnp.where(held, seq_lens[rows], 0)))
+                       jnp.where(held, seq_lens[rows], 0), rows))
         flat = jnp.concatenate([tok, chunk.reshape(-1)])
         last = jnp.where(
             many, B + (jnp.cumsum(many) - 1) * L + seq_lens - 1,
@@ -138,6 +141,28 @@ def paged_token_blocks(ids, offsets, block_tables, slot_mask, seq_lens=None,
     if pad:
         flat = jnp.pad(flat, (0, pad))
     return flat, blocks, last
+
+def pack_query_heads(q, pack: int, g: int):
+    """Queries for a pool whose rows hold ``pack`` key heads side by side
+    (``[k_0 ; k_1]``): q (B, L, Hq, dh) -> (B, L, Hq, pack * dh), each
+    query in the columns of its own key head and zero in the others', so
+    that its dot with the packed row is its dot with its own key. ``g``
+    query heads share a key head; the ``pack * g`` heads of one packed row
+    stay contiguous."""
+    B, L, Hq, dh = q.shape
+    q = q.reshape(B, L, Hq // (pack * g), pack, g, 1, dh)
+    own = jnp.eye(pack, dtype=q.dtype).reshape(pack, 1, pack, 1)
+    return (q * own).reshape(B, L, Hq, pack * dh)
+
+
+def unpack_output_heads(o, pack: int, g: int):
+    """The inverse for the attention's output over packed value rows:
+    o (B, L, Hq, pack * dh) -> (B, L, Hq, dh), each head's own columns."""
+    B, L, Hq, w = o.shape
+    o = o.reshape(B, L, Hq // (pack * g), pack, g, pack, w // pack)
+    own = jnp.eye(pack, dtype=o.dtype).reshape(pack, 1, pack, 1)
+    return jnp.sum(o * own, axis=-2).reshape(B, L, Hq, w // pack)
+
 
 def rms_norm(x, w, eps: float = 1e-6):
     """RMSNorm over the last dim, fp32 math, cast back to x.dtype."""

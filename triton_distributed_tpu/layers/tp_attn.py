@@ -66,6 +66,11 @@ class TPAttn:
     qk_norm: bool = True
     rms_eps: float = 1e-6
     block_n: int = 256
+    rope: bool = True           # False: no position embedding ("nope")
+    scale: float | None = None  # of the scores; None = head_dim ** -0.5
+    # Key heads that share one row of the PAGED pool (``_attend``): heads
+    # narrower than the lane count lie side by side in a lane-wide row.
+    kv_pack: int = 1
 
     def sizes(self, world: int):
         """(q_size, kv_size) per device."""
@@ -142,6 +147,8 @@ class TPAttn:
         if self.qk_norm:
             q = nn.rms_norm(q, params["q_norm"], self.rms_eps)
             k = nn.rms_norm(k, params["k_norm"], self.rms_eps)
+        if not self.rope:
+            return q, k, v
         # (1|B, L): per-row positions when offset is the per-slot vector.
         positions = jnp.asarray(offset, jnp.int32).reshape(-1, 1) \
             + jnp.arange(L)
@@ -201,7 +208,7 @@ class TPAttn:
         attention read dequantizes — inside the fused kernel's VMEM
         staging, or on the gathered view in gather mode.
         """
-        scale = self.head_dim ** -0.5
+        scale = self.head_dim ** -0.5 if self.scale is None else self.scale
         if blocks is None:
             q, k, v = self._qkv_rope(params, qkv, offset, world)
             k_cache, v_cache = cache
@@ -217,6 +224,14 @@ class TPAttn:
         for blk in blocks:
             part = qkv[blk.start:blk.stop].reshape(-1, blk.L, qkv.shape[-1])
             q, k, v = self._qkv_rope(params, part, blk.offsets, world)
+            if self.kv_pack > 1:
+                # ``kv_pack`` key heads to a row of the pool, side by side
+                # (a free reshape of the rows); each query in its own key
+                # head's columns.
+                pk, g = self.kv_pack, self.n_heads // self.n_kv_heads
+                q = nn.pack_query_heads(q, pk, g)
+                k = k.reshape(*k.shape[:2], -1, pk * self.head_dim)
+                v = v.reshape(*v.shape[:2], -1, pk * self.head_dim)
             queries.append(q)
             wm = blk.valid().reshape(-1, blk.L)
             if state.k_scale is not None:
@@ -236,12 +251,17 @@ class TPAttn:
                                         k_scale=ks, v_scale=vs)
         scales = (None if state.k_scale is None
                   else (state.k_scale, state.v_scale))
-        outs = [nn.paged_attn_with_cache(
+        def own_heads(o):
+            # each query head's own columns of the packed value rows
+            return o if self.kv_pack == 1 else nn.unpack_output_heads(
+                o, self.kv_pack, self.n_heads // self.n_kv_heads)
+
+        outs = [own_heads(nn.paged_attn_with_cache(
             q, state.k, state.v, blk.tables, blk.offsets, scale=scale,
             slot_mask=blk.mask, use_flash_decode=use_flash_decode,
             seq_lens=blk.seq_lens, interpret=interpret,
             paged_attn=paged_attn, kv_scales=scales,
-            layer=layer).reshape(blk.stop - blk.start, -1)
+            layer=layer)).reshape(blk.stop - blk.start, -1)
             for q, blk in zip(queries, blocks)]
         tail = qkv.shape[0] - blocks[-1].stop
         if tail:
@@ -281,6 +301,17 @@ class TPAttn:
             config=GEMMRSConfig(block_n=min(self.block_n, self.d_model)),
             interpret=interpret)
         return out.reshape(*lead, d), cache
+
+    def local_fwd(self, params, x, state, *, blocks, paged_attn: str = "fused",
+                  layer=None, interpret=None):
+        """One device holding every head, over the paged pool: x the flat
+        token batch (T, d) -> (T, d), local GEMMs and no collective (what
+        ``ar_fwd`` is without its all-reduce)."""
+        qkv = jnp.dot(x, params["w_qkv"])
+        out, state = self._attend(params, qkv, state, None, 1, blocks=blocks,
+                                  paged_attn=paged_attn, layer=layer,
+                                  interpret=interpret)
+        return jnp.dot(out, params["w_o"]), state
 
     def ar_fwd(self, params, x_full, cache, offset=None, *,
                interpret=None, seq_lens=None, blocks=None,

@@ -15,9 +15,15 @@ class from the class of the object it is given:
 - ``DeepseekV3Config``: the DeepSeek-V3 block (latent attention over a
   latent cache, sigmoid-routed experts with a shared expert, leading dense
   layers), run by ``models.deepseek_v3.DeepseekV3``.
+- ``GraniteHybridConfig``: the Granite-4.0-H block (Mamba-2 layers that keep
+  a fixed-size state a sequence beside a few attention layers that keep
+  rows, a SwiGLU after each), run by ``models.granite_hybrid.GraniteHybrid``.
 
-Both state ``kv_row_shapes``: what one token's row of each arena of the
-paged pool looks like (``serving.kv_pool.KVPool`` builds the pool from it).
+Each states what ``serving.kv_pool.KVPool`` builds the pool's state from:
+``kv_row_shapes`` (what one token's row of each row arena looks like),
+``n_cache_layers`` (how many layers keep such rows) and
+``slot_state_shapes`` (the arenas that hold a fixed-size state for each
+SLOT, none for a model whose every layer keeps rows).
 """
 
 from __future__ import annotations
@@ -61,6 +67,14 @@ class ModelConfig:
         per-head keys and values."""
         row = (self.n_kv_heads, self.head_dim)
         return row, row
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Every layer keeps rows in the paged pool."""
+        return self.n_layers
+
+    #: No layer keeps a state a slot.
+    slot_state_shapes = None
 
     @classmethod
     def from_name(cls, name: str, **overrides) -> "ModelConfig":
@@ -146,6 +160,12 @@ class DeepseekV3Config:
         its first ``kv_lora_rank`` columns."""
         return (self.cache_row,), None
 
+    @property
+    def n_cache_layers(self) -> int:
+        return self.n_layers
+
+    slot_state_shapes = None
+
     @classmethod
     def tiny(cls, **overrides) -> "DeepseekV3Config":
         """Tiny float32 sizes for tests (not a real checkpoint)."""
@@ -155,6 +175,112 @@ class DeepseekV3Config:
             qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
             d_ff=96, moe_d_ff=32, n_experts=16, n_experts_per_tok=4,
             rope_theta=1e4, max_length=64, dtype=jnp.float32), **overrides})
+
+
+_GRANITE_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteHybridConfig:
+    """The Granite-4.0-H decoder (HF ``GraniteMoeHybrid`` with no routed
+    experts; HF key in brackets). Defaults are granite-4.0-h-micro's public
+    ``config.json``. ``layer_types`` names each layer's mixer: ``"mamba"``
+    (a Mamba-2 layer, whose state is a fixed size a sequence) or
+    ``"attention"`` (grouped-query attention without rotary embedding,
+    whose keys and values are rows of the paged pool); every layer has the
+    same SwiGLU after its mixer."""
+
+    model_name: str = "ibm-granite/granite-4.0-h-micro"
+    vocab_size: int = 100_352
+    d_model: int = 2048                # hidden_size
+    layer_types: tuple = _GRANITE_PERIOD * 4
+    n_heads: int = 32                  # num_attention_heads
+    n_kv_heads: int = 8                # num_key_value_heads
+    d_ff: int = 8192                   # shared_intermediate_size
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.015625
+    logits_scaling: float = 8.0
+    rms_eps: float = 1e-5
+    max_length: int = 4096
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - {"mamba", "attention"}
+        if bad or not self.layer_types:
+            raise ValueError(f"layer_types names {sorted(bad)}; a layer is "
+                             f"'mamba' or 'attention'")
+        if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads \
+                or self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("heads do not divide the widths given")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
+    @property
+    def d_inner(self) -> int:
+        """[mamba_expand x hidden_size] = heads x head width."""
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        """What the convolution runs over: x, B and C."""
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def kv_pack(self) -> int:
+        """Key heads that share one row of the pool: heads narrower than the
+        chip's lane count are packed side by side into a lane-wide row
+        (8 heads of 64 are 4 rows of 128), so that the pool holds no padding
+        and the block walk moves whole lane tiles."""
+        return max(p for p in range(1, max(1, LANE // self.head_dim) + 1)
+                   if self.n_kv_heads % p == 0)
+
+    @property
+    def kv_row_shapes(self):
+        row = (self.n_kv_heads // self.kv_pack, self.kv_pack * self.head_dim)
+        return row, row
+
+    @property
+    def n_cache_layers(self) -> int:
+        return self.layer_types.count("attention")
+
+    @property
+    def n_state_layers(self) -> int:
+        return self.layer_types.count("mamba")
+
+    @property
+    def slot_state_shapes(self):
+        """What a Mamba-2 layer keeps for one sequence, ``{arena: (shape,
+        dtype)}``: the recurrence's state in float32 (it accumulates over
+        every token of the sequence) and the convolution's window, the last
+        ``mamba_d_conv - 1`` inputs, oldest first, side by side."""
+        return {
+            "ssm": ((self.mamba_n_heads, self.mamba_d_head,
+                     self.mamba_d_state), jnp.float32),
+            "conv": (((self.mamba_d_conv - 1) * self.conv_dim,), self.dtype),
+        }
+
+    @classmethod
+    def tiny(cls, **overrides) -> "GraniteHybridConfig":
+        """Tiny float32 sizes for tests (not a real checkpoint): two
+        periods of (mamba, attention, mamba)."""
+        return cls(**{**dict(
+            model_name="tiny-granite-hybrid", vocab_size=128, d_model=64,
+            layer_types=("mamba", "attention", "mamba") * 2, n_heads=4,
+            n_kv_heads=2, d_ff=96, mamba_n_heads=4, mamba_d_head=8,
+            mamba_d_state=16, max_length=64, dtype=jnp.float32),
+            **overrides})
 
 
 # Public Qwen3 architecture hyper-parameters (HF config.json values).

@@ -23,7 +23,11 @@ from triton_distributed_tpu.runtime.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from triton_distributed_tpu.models.config import DeepseekV3Config, ModelConfig
+from triton_distributed_tpu.models.config import (
+    DeepseekV3Config,
+    GraniteHybridConfig,
+    ModelConfig,
+)
 from triton_distributed_tpu.models.kv_cache import KVCache
 from triton_distributed_tpu.models.qwen import Qwen3
 from triton_distributed_tpu.models.sampling import sample_token
@@ -38,11 +42,16 @@ def model_for(config, *, block_n: int = 256):
         from triton_distributed_tpu.models.deepseek_v3 import DeepseekV3
 
         return DeepseekV3(config)
+    if isinstance(config, GraniteHybridConfig):
+        from triton_distributed_tpu.models.granite_hybrid import GraniteHybrid
+
+        return GraniteHybrid(config)
     return Qwen3(config, block_n=block_n)
 
 
 class Engine:
-    def __init__(self, config: ModelConfig | DeepseekV3Config, *,
+    def __init__(self, config: ModelConfig | DeepseekV3Config
+                 | GraniteHybridConfig, *,
                  mesh: Mesh | None = None,
                  mode: str = "dist", prefill_mode: str | None = None,
                  temperature: float = 0.0, top_p: float = 1.0,

@@ -187,7 +187,12 @@ class BatchEngine:
                    bit-identical to a cold pool (the KV a request would
                    have computed IS the cached KV, token for token).
                    ``engine.prefix_cache.enabled = False`` toggles it off
-                   at runtime without touching compiled state.
+                   at runtime without touching compiled state. A model
+                   some of whose layers keep a fixed-size state a slot
+                   (``config.slot_state_shapes``) gets none, whatever is
+                   asked: blocks hold rows, not the state at their
+                   boundary, so every admission of such a model prefills
+                   from offset 0 (docs/serving.md).
 
     Always-on observability (bounded, defaults ON; what it costs a step
     on the chip is not measured):
@@ -273,7 +278,7 @@ class BatchEngine:
         self.pool = KVPool(engine.config, n_blocks=n_blocks,
                            block_size=block_size, max_seq_len=max_seq_len,
                            mesh=engine.mesh, axis=engine.model.axis,
-                           kv_dtype=kv_dtype)
+                           kv_dtype=kv_dtype, n_slots=n_slots)
         self.scheduler = Scheduler()
         self.metrics = Metrics(windowed=windowed_metrics)
         if blackbox:
@@ -347,9 +352,15 @@ class BatchEngine:
         self._stats_stream = None
         self._stats_interval_s = 1.0
         self._stats_next_emit = 0.0
+        # A model with per-slot state gets NO prefix cache, asked for or
+        # not: a cached block holds rows, not the state at its boundary, so
+        # a request that adopted blocks would start past tokens its state
+        # has never seen. Every such admission starts at offset 0, where
+        # the layer that keeps the state starts it from zero.
         self.prefix_cache = (RadixPrefixCache(self.pool,
                                               metrics=self.metrics)
-                             if prefix_cache else None)
+                             if prefix_cache and not self.pool.slot_state
+                             else None)
         self.trace_counts = {"decode": 0, "prefill": 0}
         # Fault sites ("engine.decode"/"engine.prefill") whose jitted step
         # has returned at least once; until then a failure of the call is a
@@ -734,7 +745,8 @@ class BatchEngine:
                      "n_free": self.pool.n_free,
                      "n_used": self.pool.n_used,
                      "n_cached": self.pool.n_cached,
-                     "n_reclaimable": self.pool.n_reclaimable},
+                     "n_reclaimable": self.pool.n_reclaimable,
+                     "slot_state_bytes": self.pool.slot_state_bytes},
             "counters": {k: m.get(k, 0.0) for k in (
                 "requests_admitted", "requests_completed",
                 "requests_failed", "tokens_generated", "preemptions",
@@ -1575,14 +1587,19 @@ class BatchEngine:
             comm_s=comm_s, tokens=tokens, tenants=tenants,
             stall_summary=stall)
 
-    def _take_stats(self, nxt):
+    def _take_stats(self, nxt, span):
         """Split the model's ``step_stats`` off the end of the step's
-        token vector and add them to the counters of the same names."""
+        token vector, add them to the counters of the same names and give
+        them to the step's trace span (None: tracing is off) as
+        attributes."""
         if nxt.shape[0] == self.n_slots:
             return nxt
-        for name, n in zip(self.engine.model.step_stats,
-                           nxt[self.n_slots:]):
-            self.metrics.inc(name, int(n))
+        stats = {name: int(n) for name, n in zip(
+            self.engine.model.step_stats, nxt[self.n_slots:])}
+        for name, n in stats.items():
+            self.metrics.inc(name, n)
+        if span is not None:
+            span.set(**stats)
         return nxt[:self.n_slots]
 
     def _dispatch(self, site: str, step, span: str, ids, *extra, **attrs):
@@ -1595,13 +1612,14 @@ class BatchEngine:
         state = self.pool.state
         key = self._next_key()   # drawn ONCE — retries replay the same key
         with _trace.span(span, **attrs,
-                         active=int(sum(s is not None for s in self._slots))):
+                         active=int(sum(s is not None for s in self._slots))
+                         ) as sp:
             nxt, finite, greedy, state = self._call_step(
                 site, lambda corrupt: step(
                     self.engine.params, ids, state, offsets, tables, mask,
                     *extra, corrupt, key))
             greedy = jax.device_get(greedy)
-            nxt = self._take_stats(np.asarray(nxt))
+            nxt = self._take_stats(np.asarray(nxt), sp)
         self.pool.state = state
         return nxt, finite, greedy
 

@@ -10,7 +10,20 @@ or, for a model whose cache is one latent row a token
 
     k: (n_layers, n_blocks, block_size, row)
 
-plus a HOST-side free-list allocator mapping sequences onto blocks. A
+``n_layers`` here is the model's ``n_cache_layers``: the layers that keep
+rows. A model some of whose layers keep a state of FIXED size a sequence
+instead (``config.slot_state_shapes``: a recurrence's state, a
+convolution's window) gets a second kind of arena beside these, indexed by
+SLOT of the serving batch and not by block,
+
+    ssm, conv, ...: (state layers, n_slots, *shape)
+
+as deep as it has such layers. The allocator below knows nothing of them: a
+slot's state belongs to whatever sequence sits in the slot, and the layer
+that reads it starts a sequence at cache length 0 from zero
+(``layers/mamba2.py``).
+
+Plus a HOST-side free-list allocator mapping sequences onto blocks. A
 sequence of ``n`` tokens owns ``ceil(n / block_size)`` blocks, listed in
 order in its block table; internal fragmentation is bounded by one block
 per sequence (the vLLM argument) instead of one ``max_length`` row per
@@ -134,7 +147,9 @@ class PagedKVState:
     per-row f32 dequantization scales, shaped like the K/V arenas minus
     head_dim. A field that is ``None`` is an arena the format does not
     have (an empty pytree subtree): the tree's structure IS the format.
-    Every arena keeps (layer, block) as its two leading axes.
+    A ROW arena (``ROW_ARENAS``) keeps (layer, block) as its two leading
+    axes, a PER-SLOT arena (the fields after them, named as the model's
+    ``slot_state_shapes`` names them) keeps (state layer, slot).
     """
 
     k: jax.Array   # (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
@@ -142,6 +157,12 @@ class PagedKVState:
                            # (n_layers, n_blocks, block_size, row) is both
     k_scale: jax.Array | None = None   # (n_layers, n_blocks, bs, n_kv_heads)
     v_scale: jax.Array | None = None
+    # (state layers, n_slots, heads, head width, state width) float32: the
+    # state of a recurrence, one a slot and layer
+    ssm: jax.Array | None = None
+    # (state layers, n_slots, (d_conv - 1) * conv width): the last inputs
+    # of a causal convolution, oldest first
+    conv: jax.Array | None = None
 
     @property
     def n_blocks(self) -> int:
@@ -152,6 +173,9 @@ class PagedKVState:
         return self.k.shape[2]
 
 
+ROW_ARENAS = ("k", "v", "k_scale", "v_scale")
+
+
 def paged_state_specs(config, axis: str = "tp", *,
                       quant: bool = False) -> PagedKVState:
     """The pool's ``PartitionSpec``s as a ``PagedKVState`` of the structure
@@ -159,13 +183,45 @@ def paged_state_specs(config, axis: str = "tp", *,
     kv-head dim (``KVCache.spec``), a quantized pool's scale arenas the
     same minus head_dim (``KVCache.scale_spec``); a latent pool
     (``config.kv_row_shapes`` names no V row) is one replicated arena,
-    its row shared by every head. ``KVPool`` allocates under these and
-    the paged step's shard_map takes them as the state's in/out specs."""
+    its row shared by every head. The per-slot arenas a model states
+    (``config.slot_state_shapes``) are replicated: no model shards them
+    yet. ``KVPool`` allocates under these and the paged step's shard_map
+    takes them as the state's in/out specs."""
     latent = config.kv_row_shapes[1] is None
     kv = PartitionSpec() if latent else KVCache.spec(axis)[0]
     scale = KVCache.scale_spec(axis) if quant else None
     return PagedKVState(k=kv, v=None if latent else kv,
-                        k_scale=scale, v_scale=scale)
+                        k_scale=scale, v_scale=scale,
+                        **dict.fromkeys(config.slot_state_shapes or (),
+                                        PartitionSpec()))
+
+
+def paged_state_shapes(config, *, n_blocks: int, block_size: int,
+                       n_slots: int | None = None,
+                       kv_dtype=None) -> PagedKVState:
+    """The pool's state as ``jax.ShapeDtypeStruct``s, a ``PagedKVState`` of
+    the structure ``paged_state_specs`` gives: what ``KVPool`` allocates,
+    and what a compile rehearsal hands the step in place of arrays. Row
+    arenas ``(n_cache_layers, n_blocks, block_size, *row)`` in the wire
+    dtype (scale arenas the same minus the row's width, float32), per-slot
+    arenas ``(n_state_layers, n_slots, *shape)`` as the model states them."""
+    dtype, quant = resolve_kv_dtype(config, kv_dtype)
+    k_row, v_row = config.kv_row_shapes
+    rows = jax.ShapeDtypeStruct(
+        (config.n_cache_layers, n_blocks, block_size, *k_row), dtype)
+    scale = (jax.ShapeDtypeStruct(rows.shape[:-1], jnp.float32)
+             if quant else None)
+    slot_state = config.slot_state_shapes or {}
+    if slot_state and n_slots is None:
+        raise ValueError(
+            f"{sorted(slot_state)}: the model keeps a state for each slot; "
+            f"the pool needs n_slots to build its arenas")
+    return PagedKVState(
+        k=rows, v=None if v_row is None else rows, k_scale=scale,
+        v_scale=scale,
+        **{name: jax.ShapeDtypeStruct(
+            (config.n_state_layers, n_slots, *shape), jnp.dtype(dt))
+           for name, (shape, dt) in slot_state.items()})
 
 
 class KVPool:
@@ -174,11 +230,13 @@ class KVPool:
     ``n_blocks`` blocks of ``block_size`` tokens each; ``max_seq_len``
     bounds any one sequence (sets the fixed block-table width the compiled
     step sees). ``mesh``/``axis`` shard the kv-head dim like ``KVCache``.
+    ``n_slots``: the serving batch's width, which a model with per-slot
+    state (``config.slot_state_shapes``) needs its arenas built for.
     """
 
     def __init__(self, config, *, n_blocks: int, block_size: int = 16,
                  max_seq_len: int | None = None, mesh=None, axis: str = "tp",
-                 kv_dtype=None):
+                 kv_dtype=None, n_slots: int | None = None):
         if n_blocks <= 0 or block_size <= 0:
             raise ValueError(f"bad pool geometry ({n_blocks=}, {block_size=})")
         self.block_size = block_size
@@ -205,28 +263,29 @@ class KVPool:
             raise NotImplementedError(
                 "a latent pool has no quantized build (its one row is both "
                 "key and value; the per-head row scales do not apply)")
-        shape = (config.n_layers, n_blocks, block_size, *k_row)
+        # ... and what each SLOT holds beside them, if anything.
+        self.slot_state = dict(config.slot_state_shapes or {})
+        if self.slot_state and self.kv_quant:
+            raise NotImplementedError(
+                "a pool with per-slot state has no quantized build")
         #: PartitionSpecs of ``state``, leaf for leaf.
         self.specs = paged_state_specs(config, axis, quant=self.kv_quant)
         # Each arena is born in its sharded layout: ``jnp.zeros`` +
         # ``device_put`` would build the WHOLE pool on the default device
         # first — on four chips, all of it on chip 0 beside its weight
         # shard (``Qwen3.init`` allocates the same way).
-        def arena(spec, shape, dtype):
-            if spec is None:
-                return None             # an arena this format does not have
+        def arena(spec, a):
             if mesh is None:
-                return _zeros(shape, dtype, None)
+                return _zeros(a.shape, a.dtype, None)
             from triton_distributed_tpu.runtime.mesh import sharding_for
 
-            return _zeros(shape, dtype, sharding_for(spec, mesh))
+            return _zeros(a.shape, a.dtype, sharding_for(spec, mesh))
 
-        sp = self.specs
-        self.state = PagedKVState(
-            k=arena(sp.k, shape, self.kv_dtype),
-            v=arena(sp.v, shape, self.kv_dtype),
-            k_scale=arena(sp.k_scale, shape[:-1], jnp.float32),
-            v_scale=arena(sp.v_scale, shape[:-1], jnp.float32))
+        # (an arena the format does not have is None in both trees: an
+        # empty subtree, which the map passes over)
+        self.state = jax.tree.map(arena, self.specs, paged_state_shapes(
+            config, n_blocks=n_blocks, block_size=block_size,
+            n_slots=n_slots, kv_dtype=kv_dtype))
         # LIFO free list, low block ids first out — recently freed blocks
         # are reused immediately (warm in whatever cache level they touched).
         self._free: list[int] = list(range(n_blocks - 1, -1, -1))
@@ -298,10 +357,21 @@ class KVPool:
         requests recompute them via prefill), but mismatched geometry
         would change admission/preemption decisions and break the
         bit-identical-resume contract."""
-        return {"n_blocks": self.n_blocks, "block_size": self.block_size,
-                "max_seq_len": self.max_seq_len,
-                "max_blocks_per_seq": self.max_blocks_per_seq,
-                "kv_dtype": self.kv_dtype.name}
+        geo = {"n_blocks": self.n_blocks, "block_size": self.block_size,
+               "max_seq_len": self.max_seq_len,
+               "max_blocks_per_seq": self.max_blocks_per_seq,
+               "kv_dtype": self.kv_dtype.name}
+        if self.slot_state:
+            geo["slot_state"] = {
+                name: list(getattr(self.state, name).shape)
+                for name in sorted(self.slot_state)}
+        return geo
+
+    @property
+    def slot_state_bytes(self) -> int:
+        """Bytes of the per-slot arenas (0 for a pool of rows only)."""
+        return sum(getattr(self.state, name).nbytes
+                   for name in self.slot_state)
 
     def kv_fingerprint(self) -> str:
         """Wire-format identity of this pool's KV bytes: ``dtype:scheme``
@@ -312,6 +382,8 @@ class KVPool:
         scheme = KV_QUANT_SCHEME if self.kv_quant else "none"
         if self.latent:
             scheme += f":latent{self._row_width}"
+        if self.slot_state:
+            scheme += ":slot[" + "+".join(sorted(self.slot_state)) + "]"
         return f"{self.kv_dtype.name}:{scheme}"
 
     def owned(self, seq_id) -> int:
@@ -526,7 +598,11 @@ class KVPool:
         if self._cow_jit is None:
             @functools.partial(jax.jit, donate_argnums=(0,))
             def cow(state, s, d):
-                return jax.tree.map(lambda a: a.at[:, d].set(a[:, s]), state)
+                # the ROW arenas only: a per-slot arena's second axis is
+                # the slot, and a block's copy leaves it alone
+                return dataclasses.replace(state, **{
+                    f: a.at[:, d].set(a[:, s]) for f in ROW_ARENAS
+                    if (a := getattr(state, f)) is not None})
 
             self._cow_jit = cow
         self.state = self._cow_jit(
@@ -633,3 +709,18 @@ class KVPool:
         else:
             assert st.v is not None and st.v.shape == st.k.shape, (
                 "K and V arenas differ")
+        # The per-slot arenas are the ones the model states, one entry a
+        # (state layer, slot) each.
+        for f in dataclasses.fields(st):
+            if f.name in ROW_ARENAS:
+                continue
+            a = getattr(st, f.name)
+            if f.name not in self.slot_state:
+                assert a is None, f"pool carrying an unasked arena {f.name}"
+                continue
+            shp, dtype = self.slot_state[f.name]
+            assert a is not None, f"pool missing its per-slot arena {f.name}"
+            assert a.shape[2:] == tuple(shp) and a.dtype == jnp.dtype(dtype), (
+                f"per-slot arena {f.name}: {a.shape} {a.dtype}")
+        assert len({getattr(st, n).shape[:2] for n in self.slot_state}) <= 1, (
+            "per-slot arenas differ in (state layers, slots)")
