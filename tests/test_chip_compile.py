@@ -28,8 +28,12 @@ BLOCK, SLOTS, CHUNK, MAX_LEN = 16, 8, 64, 4096
 MAX_BLOCKS = MAX_LEN // BLOCK
 N_BLOCKS = SLOTS * MAX_BLOCKS
 DH = 128
-# (Hq, Hkv) per device: Qwen3-1.7B whole, Qwen3-8B's TP=4 shard.
-HEADS = {"qwen3-1.7b": (16, 8), "qwen3-8b-tp4": (8, 2)}
+# (Hq, Hkv) per device: Qwen3-1.7B whole, Qwen3-8B's TP=4 shard, and
+# granite-4.0-h-micro's PACKED rows (two key heads of 64 to a row of 128:
+# 4 key rows, 8 query heads each). The decode shape of each takes the
+# folded tile arithmetic (16, 8 and 32 query rows in one operand).
+HEADS = {"qwen3-1.7b": (16, 8), "qwen3-8b-tp4": (8, 2),
+         "granite-packed": (32, 4)}
 # Qwen3-8B: d_model, fused qkv width, d_ff.
 D8, QKV8, FF8 = 4096, (32 + 2 * 8) * DH, 12_288
 

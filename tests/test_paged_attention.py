@@ -291,15 +291,23 @@ def _walk_case(rng, shape, tile_blocks, poison=False):
     the full table (its last tile ragged, the table padded) — and a dead
     slot between live ones, over a shuffled table. ``shape``: ``decode``
     (L = 1), ``chunk`` (ragged ``q_lens``, two query tiles), ``latent``
-    (one arena, chunk shape). Returns the call's arguments, the oracle and
-    the live mask. ``poison`` writes NaN over every pool row that no live
-    slot owns, so a prefetch that stages what the mask does not scrub
-    shows."""
+    (one arena, chunk shape), ``bf16-8x2`` / ``bf16-4x8`` (the decode shape
+    over a bf16 pool at ``Hkv`` 8, ``g`` 2 and at ``Hkv`` 4, ``g`` 8: the
+    FOLDED arithmetic with bf16 operands, as the chip runs it). Returns the
+    call's arguments, the oracle, the live mask and the tolerance. ``poison``
+    writes NaN over every pool row that no live slot owns, so a prefetch
+    that stages what the mask does not scrub shows."""
     bs, max_blocks, n_layers, li = 8, 7, 2, 1
     span = tile_blocks * bs
     latent = shape == "latent"
-    Hkv, g, dh, v_dim = (1, 4, 32, 16) if latent else (2, 2, 16, None)
-    L = 1 if shape == "decode" else 6
+    bf16 = shape.startswith("bf16-")
+    dtype = jnp.bfloat16 if bf16 else jnp.float32
+    if bf16:
+        Hkv, g = (int(x) for x in shape[len("bf16-"):].split("x"))
+        dh, v_dim = 16, None
+    else:
+        Hkv, g, dh, v_dim = (1, 4, 32, 16) if latent else (2, 2, 16, None)
+    L = 6 if shape in ("chunk", "latent") else 1
     kv = [1, bs, span, span + 1, min(3 * span, max_blocks * bs),
           max_blocks * bs, 5 * bs, 2 * bs + 3]
     slot_mask = np.array([True] * 6 + [False, True])
@@ -307,8 +315,10 @@ def _walk_case(rng, shape, tile_blocks, poison=False):
     q_lens = np.minimum(np.array([1, L, 1, L // 2, L, 1, L, 2])[:B], L)
     kv_lens = np.maximum(np.array(kv), q_lens)
     n_blocks = B * max_blocks + 5
-    rows = rng.normal(size=(n_layers, n_blocks, bs, Hkv, dh))
-    vrows = rng.normal(size=(n_layers, n_blocks, bs, Hkv, dh))
+    # the pool's own values (rounded to its dtype) are what the oracle reads
+    rows, vrows = (np.array(jnp.asarray(
+        rng.normal(size=(n_layers, n_blocks, bs, Hkv, dh)), dtype),
+        np.float32) for _ in "kv")
     tables = rng.permutation(n_blocks)[:B * max_blocks].reshape(
         B, max_blocks).astype(np.int32)
     if poison:
@@ -320,57 +330,85 @@ def _walk_case(rng, shape, tile_blocks, poison=False):
         # away, NaN and all
         rows[:, ~live] = np.nan
         vrows[:, ~live] = np.nan
-    q = jnp.asarray(rng.normal(size=(B, L, Hkv * g, dh)), jnp.float32)
-    kp, vp = jnp.asarray(rows, jnp.float32), jnp.asarray(vrows, jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, L, Hkv * g, dh)), dtype)
+    kp, vp = jnp.asarray(rows, dtype), jnp.asarray(vrows, dtype)
     if latent:
         kp, vp = kp[:, :, :, 0], None
         ref_k, ref_v = rows[li], rows[li][..., :v_dim]
     else:
         ref_k, ref_v = rows[li], vrows[li]
-    ref = _ref_attn_chunk(q, jnp.asarray(ref_k, jnp.float32),
-                          jnp.asarray(ref_v, jnp.float32),
-                          jnp.asarray(tables), kv_lens, q_lens)
+
+    def oracle(values):
+        return _ref_attn_chunk(q.astype(jnp.float32),
+                               jnp.asarray(ref_k, jnp.float32),
+                               jnp.asarray(values, jnp.float32),
+                               jnp.asarray(tables), kv_lens, q_lens)
+
+    ref = oracle(ref_v)
+    # float32 operands: summation order. bf16 operands: q . k is a sum of
+    # exact products either way, so what the folded arithmetic adds is ONE
+    # rounding of p to bf16 before the PV dot: a relative 2^-8 on each
+    # weight (bf16 keeps 8 significant bits; the denominator sums the
+    # unrounded p), hence at most 2^-8 of the softmax-weighted mean of |v|.
+    # The output's own rounding to bf16 is 2^-8 of itself. Nothing wider.
+    tol = 1e-5 + 1e-5 * np.abs(ref)
+    if bf16:
+        tol += 2.0 ** -8 * (oracle(np.abs(ref_v)) + np.abs(ref))
     kw = dict(q_lens=jnp.asarray(q_lens, jnp.int32),
               slot_mask=jnp.asarray(slot_mask), tile_blocks=tile_blocks,
               q_tile=min(L, 4), interpret=True, v_dim=v_dim)
     args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(kv_lens, jnp.int32))
-    return args, kw, li, ref, slot_mask
+    return args, kw, li, ref, slot_mask, tol
+
+
+def _assert_within(out, ref, tol):
+    """|out - ref| <= tol elementwise (``tol`` an array: ``atol`` and
+    ``rtol`` 1e-5 for float32 operands, plus the stated bf16 bound of
+    ``_walk_case`` otherwise)."""
+    err = np.abs(np.asarray(out, np.float32) - ref)
+    worst = np.unravel_index(np.argmax(err - tol), err.shape)
+    assert (err <= tol).all(), (
+        f"at {worst}: |out - ref| = {err[worst]:.3g} > {tol[worst]:.3g}")
+
+
+WALK_SHAPES = ["decode", "chunk", "latent", "bf16-8x2", "bf16-4x8"]
 
 
 @pytest.mark.parametrize("tile_blocks", [1, 2, 3])
-@pytest.mark.parametrize("shape", ["decode", "chunk", "latent"])
+@pytest.mark.parametrize("shape", WALK_SHAPES)
 def test_pipelined_walk_matches_gather_reference(rng, shape, tile_blocks):
     """The walk's trip count is each slot's own: every edge length in one
     batch, dead slot included, through the stacked arena with a TRACED
     layer index, equals the gather oracle — for the K+V build in the decode
-    and the chunk shape and for the latent build."""
-    (q, kp, vp, tables, kv_lens), kw, li, ref, live = _walk_case(
+    and the chunk shape, for the latent build, and for the folded
+    arithmetic over a bf16 pool within the rounding of ``p``."""
+    (q, kp, vp, tables, kv_lens), kw, li, ref, live, tol = _walk_case(
         rng, shape, tile_blocks)
 
     @jax.jit
     def traced(layer):
         return paged_attention(q, kp, vp, tables, kv_lens, layer=layer, **kw)
 
-    out = np.asarray(traced(jnp.int32(li)))
-    np.testing.assert_allclose(out[live], ref[live], atol=1e-5, rtol=1e-5)
+    out = np.asarray(traced(jnp.int32(li)), np.float32)
+    _assert_within(out[live], ref[live], tol[live])
     assert np.isfinite(out).all(), \
         "dead slots must emit finite garbage, not NaN"
     static = paged_attention(q, kp, vp, tables, kv_lens, layer=li, **kw)
-    np.testing.assert_array_equal(np.asarray(static), out)
+    np.testing.assert_array_equal(np.asarray(static, np.float32), out)
 
 
-@pytest.mark.parametrize("shape", ["decode", "chunk", "latent"])
+@pytest.mark.parametrize("shape", WALK_SHAPES)
 def test_prefetch_stages_nothing_the_mask_does_not_scrub(rng, shape):
     """NaN in every pool block that no live slot owns and in every row past
     a slot's frontier: whatever either staging slot held — this tile's dead
     rows, the last tile's leftovers, a neighbour's blocks — the output of
     the live slots is finite and the oracle's."""
-    (q, kp, vp, tables, kv_lens), kw, li, ref, live = _walk_case(
+    (q, kp, vp, tables, kv_lens), kw, li, ref, live, tol = _walk_case(
         rng, shape, 2, poison=True)
     out = np.asarray(paged_attention(q, kp, vp, tables, kv_lens, layer=li,
-                                     **kw))
+                                     **kw), np.float32)
     assert np.isfinite(out[live]).all()
-    np.testing.assert_allclose(out[live], ref[live], atol=1e-5, rtol=1e-5)
+    _assert_within(out[live], ref[live], tol[live])
 
 
 @pytest.mark.parametrize("kernel", ["paged.decode", "paged.prefill",
@@ -515,6 +553,42 @@ def test_paged_attn_with_cache_fused_equals_gather(rng):
         assert entry["bytes_total"] == expect, method
 
 
+@pytest.mark.parametrize("shape,want", [
+    ("decode", "folded"),           # one token a slot: every head, one dot
+    ("chunk", "per_head"),          # folded scores would not fit
+    ("decode-int8", "per_head"),    # dequantization is a head's
+    ("decode-1kv", "per_head"),     # one kv head: nothing to fold
+    ("latent", "per_head"),
+])
+def test_trace_record_names_the_arithmetic(rng, shape, want):
+    """The arithmetic of a staged tile is static a call site;
+    ``nn._fused_paged_attention`` keeps it beside each shape's trace and
+    ``nn.fused_paged_arithmetic`` reads it back, keyed by the query's shape
+    and the pool's dtype."""
+    B, bs, max_blocks, dh = 5, 8, 2, 16      # B = 5: no other test's shape
+    Hkv, g = (1, 4) if shape in ("decode-1kv", "latent") else (2, 2)
+    L = 3 if shape in ("chunk", "latent") else 1
+    q3, kp, vp, tables, kv_lens = _pool_case(rng, B, bs, Hkv, g, dh,
+                                             max_blocks)
+    q = jnp.broadcast_to(q3[:, None], (B, L, Hkv * g, dh))
+    offset = jnp.maximum(kv_lens - L, 0)
+    if shape == "latent":
+        nn.latent_attn_with_cache(q, kp[:, :, 0], tables, offset, v_dim=8,
+                                  scale=1.0, interpret=True)
+        key = f"q{B}x{L}x{Hkv * g}x{dh}:float32"
+    elif shape == "decode-int8":
+        (kq, ks), (vq, vs) = (nn.quantize_kv_rows(x, jnp.int8)
+                              for x in (kp, vp))
+        nn.paged_attn_with_cache(q, kq, vq, tables, offset, scale=1.0,
+                                 kv_scales=(ks, vs), interpret=True)
+        key = f"q{B}x{L}x{Hkv * g}x{dh}:int8"
+    else:
+        nn.paged_attn_with_cache(q, kp, vp, tables, offset, scale=1.0,
+                                 interpret=True)
+        key = f"q{B}x{L}x{Hkv * g}x{dh}:float32"
+    assert nn.fused_paged_arithmetic()[key] == want
+
+
 def test_paged_attn_with_cache_prefill_routes_fused(rng):
     """L > 1 (chunked prefill, ragged seq_lens, nonzero offsets) routes to
     the fused kernel — the automatic gather fallback is retired — and the
@@ -643,6 +717,15 @@ def test_batch_engine_fused_matches_gather_and_golden(engine):
         assert be.metrics.as_dict()["preemptions"] > 0, \
             "pool was sized to force preemption"
         assert be.trace_counts == {"decode": 1, "prefill": 1}
+        snap = be.stats_snapshot()
+        assert snap["trace_counts"] == be.trace_counts
+        if method == "fused":
+            # the decode step's and the mixed step's decode block are ONE
+            # shape, folded; the prefill block's chunk shape is per head
+            Hq, dh = config.n_heads, config.head_dim
+            took = snap["paged_arithmetic"]
+            assert took[f"q3x1x{Hq}x{dh}:float32"] == "folded"
+            assert took[f"q3x8x{Hq}x{dh}:float32"] == "per_head"
         be.pool.check_invariants()
         sample = be.perfdb_sample()
         for key in ("pool_free_blocks", "pool_largest_free_run",

@@ -15,8 +15,10 @@ decode step. Runs on any backend (interpret mode off-TPU).
 16-row blocks, 8 KV heads x 128, 32 slots, contexts drawn 300-3,300 with
 77% of the pool live, shuffled block tables), decode shape (L=1) and chunk
 shape (one slot prefilling ``--chunk`` tokens beside 31 decoding rows, the
-mixed step), one line a (shape, ``--tiles`` entry): ms a step of all
-layers, us a live kv tile, GB/s of live pool bytes. ``--latent W,V`` times
+mixed step), one line a (shape, ``--tiles`` entry): the arithmetic the
+shape's tiles take (``folded`` / ``per_head``), ms a step of all layers, us
+a live kv tile, GB/s of live pool bytes. ``--layers 4 --hkv 4 --g 8`` is
+the granite cell's geometry (packed key rows). ``--latent W,V`` times
 the latent build (one arena of W-wide rows, values the first V columns;
 ``--hkv 1``). No cell runs it; it is ROADMAP S5's yardstick."""
 import functools, time
@@ -73,7 +75,7 @@ def _kernel_mode():
     import argparse
     import numpy as np
     from triton_distributed_tpu.kernels.paged_attention import (
-        _feasible_tiles, paged_attention)
+        paged_attention)
     from triton_distributed_tpu.runtime.utils import dist_print
 
     ap = argparse.ArgumentParser()
@@ -148,13 +150,16 @@ def _kernel_mode():
         q = jax.random.normal(jax.random.fold_in(key, 7),
                               (B, L, Hq, dh), jnp.bfloat16)
         for tile in [int(t) for t in a.tiles.split(",") if t] or [None]:
+            resolved = {}       # what the call chose: tile and arithmetic
+
             @jax.jit
             def step(q, arenas):
                 def layer(q, li):
                     out = paged_attention(
                         q, arenas[0], arenas[1], jnp.asarray(tables),
                         jnp.asarray(kv_lens), q_lens=jnp.asarray(q_lens),
-                        layer=li, tile_blocks=tile, **kw)
+                        layer=li, tile_blocks=tile, resolved=resolved,
+                        **kw)
                     # the next layer's queries hang on this layer's output
                     return q + (out[..., :1] * 1e-9).astype(q.dtype), None
                 return jax.lax.scan(layer, q,
@@ -170,13 +175,13 @@ def _kernel_mode():
             ms = (time.perf_counter() - t0) * 1e3 / a.iters
             # Under jit the kernel takes the heuristic default (an eager
             # call on a TPU would tune: twenty minutes, PERF.md section 6).
-            t_used = tile or _feasible_tiles(
-                bs, 1 if a.latent else a.hkv, dh, max_blocks, 2)[0]
+            t_used = resolved["tile_blocks"]
             live_blocks = -(-kv_lens.astype(np.int64) // bs)
             n_tiles = int((-(-live_blocks // t_used)).sum())
             live_bytes = int(live_blocks.sum()) * block_bytes * a.layers
             dist_print(
-                f"{shape:6s} tile_blocks={t_used:3d}: {ms:8.3f} ms a step "
+                f"{shape:6s} tile_blocks={t_used:3d} "
+                f"{resolved['arithmetic']:8s}: {ms:8.3f} ms a step "
                 f"({ms / a.layers * 1e3:7.1f} us a layer), "
                 f"{n_tiles} live tiles a layer, "
                 f"{ms * 1e3 / a.layers / n_tiles:6.2f} us a tile, "

@@ -255,7 +255,8 @@ def tuned_paged_tile(block_size: int, n_kv_heads: int, head_dim: int,
 def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                        bs: int, n_blocks: int, scale: float, n_kv: int,
                        g: int, q_tile: int, n_q_tiles: int,
-                       probe_steps: int = 0, v_dim: int | None = None):
+                       probe_steps: int = 0, v_dim: int | None = None,
+                       folded: bool = False, compiled: bool = False):
     """One (slot, query-tile) grid step of fused paged attention: the kv
     tiles of the slot are walked by a loop INSIDE the step, two staging
     slots deep.
@@ -297,21 +298,37 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
 
     Blocks past this query tile's causal frontier are never copied; the
     row-liveness mask zeroes whatever stale staging rows the skipped fetch
-    left behind (``jnp.where`` before the PV dot and the ``* valid`` guard
-    on p scrub any NaN/Inf garbage) — in EITHER slot, so the prefetch can
-    stage nothing the mask does not scrub.
+    left behind — the score-side select scrubs stale K, a select over V
+    before the PV dot scrubs stale V (``0 * NaN`` is NaN) — in EITHER slot,
+    so the prefetch can stage nothing the mask does not scrub.
+
+    THE ARITHMETIC OF A STAGED TILE is streaming softmax with float32
+    scores, max, denominator and accumulator in every build; what differs
+    is the operands of the two dots (``tile_arithmetic`` names the choice,
+    static a call site). ``folded`` — the K+V build at one query token a
+    grid step, the decode shape: ONE dot of all ``Hkv * g`` query rows
+    against the tile read as ``(span * Hkv, dh)`` key-head rows, operands
+    in the pool's dtype (``compute_folded``): with a bf16 pool nothing is
+    cast, ``q . k`` sums the same exact products a float32 dot would, and
+    ``p`` is rounded to bf16 for the PV dot (a relative 2^-8 a weight, what
+    the latent build and every published bf16 attention do); a float32 pool
+    keeps float32 operands. V is scrubbed in a slot's last tile only, the
+    one place dead rows exist. ``per_head`` — every other shape: a pair of
+    dots a kv head over that head's sublane-strided slice of the staging,
+    cast to float32 (bf16 operands of a few query rows hit Mosaic's
+    relayout path), V selected tile by tile.
 
     Builds, by ``n_arenas``: 2 — K and V ``(..., Hkv, dh)``. 4 — a
     QUANTIZED pool (int8/fp8 wire dtype): the per-row f32 scale arenas
     ``(..., Hkv)`` ride the same pipeline and dequant happens HERE, right
     after staging — the wire cast to f32 times the staged scale column —
-    so HBM only ever moves wire bytes while the streaming-softmax math
-    stays the exact f32 accumulation of the unquantized build. 1 — a
-    LATENT pool (``v_dim`` given): ONE arena ``(..., W)`` of rows shared by
-    every query head (absorbed latent attention is multi-query attention
-    with one key head); each block is copied ONCE and used twice: the whole
-    staged row is the key, its first ``v_dim`` columns the value, and the
-    two dots take the rows in the pool's dtype with float32 accumulation.
+    so HBM only ever moves wire bytes while the arithmetic is the per-head
+    float32 one. 1 — a LATENT pool (``v_dim`` given): ONE arena ``(..., W)``
+    of rows shared by every query head (absorbed latent attention is
+    multi-query attention with one key head); each block is copied ONCE and
+    used twice: the whole staged row is the key, its first ``v_dim``
+    columns the value, and the two dots take the rows in the pool's dtype
+    with float32 accumulation.
     """
     latent = v_dim is not None
     tbl_ref, kvlen_ref, qlen_ref, layer_ref, q_ref = refs[:5]
@@ -413,7 +430,98 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                 probe.dma_wait(src)
         for_live_blocks(tile, limit, wait)
 
+    def accumulate(h, q, k, v, valid, guard):
+        """One streaming-softmax turn of accumulator row ``h``: the scores
+        of ``q`` against the staged rows ``k``, masked by ``valid``, into
+        the running (max, denominator, acc) with the values ``v``. The two
+        dots take their operands as handed (the caller chooses the dtype)
+        and accumulate in float32; ``p`` is cast to the values' dtype for
+        the PV dot — with a bf16 pool the one rounding this kernel adds."""
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(valid, scores, _NEG_INF)
+        seg_max = jnp.max(scores, axis=-1, keepdims=True)
+        new_max = jnp.maximum(m_ref[h], seg_max)
+        corr = jnp.exp(m_ref[h] - new_max)
+        p = jnp.exp(scores - new_max)
+        if guard:
+            # A fully-masked row has scores == new_max == _NEG_INF and
+            # exp(0) == 1 would poison the denominator.
+            p = p * valid.astype(jnp.float32)
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = new_max
+
+    def compute_folded(tile, slot):
+        """The K+V build's decode shape (``q_tile == 1``): ALL ``n_kv * g``
+        query rows in one left operand against the staged tile as it lies.
+        The tile's ``(span, n_kv, dh)`` rows are read as ``(span * n_kv,
+        dh)`` key-head rows — the same bytes — so column ``c`` of the
+        scores is key ``c // n_kv`` of head ``c % n_kv``; a static mask
+        keeps each query row to its own head's columns, ``p`` is zero on
+        the others, and the PV dot over all ``span * n_kv`` value rows is
+        already each head's own sum. No slice a head, no cast of a staged
+        row: the operands stay in the pool's dtype."""
+        base = tile * span
+        width, dh = span * n_kv, q_ref.shape[3]
+
+        # Compiled, a 16-bit tile is read as the 32-bit words it is stored in
+        # (two rows to a word, both of one key while n_kv is even) and
+        # bitcast back: the load lands in the packed layout the MXU takes,
+        # where the load of (n_kv, dh) sub-tiles is relaid out vreg by vreg
+        # (twice the kernel's code, 2.2 x the time at n_kv 4). The
+        # interpreter has no view of a ref, nor has the analyzer's tracer:
+        # they reshape the value, the same rows.
+        if compiled and stages[0].dtype.itemsize == 2 and n_kv % 2 == 0:
+            words = [st.bitcast(jnp.uint32).reshape(2, width // 2, dh)
+                     for st in stages]
+
+            def rows_of(a):
+                return pltpu.bitcast(words[a][slot], stages[a].dtype)
+
+            def scrub_v():
+                r = jax.lax.broadcasted_iota(jnp.int32, (width // 2, dh), 0)
+                w = words[1][slot]
+                words[1][slot] = jnp.where(
+                    r < (limit - base) * (n_kv // 2), w, jnp.zeros_like(w))
+        else:
+            def rows_of(a):
+                return stages[a][slot].reshape(width, dh)
+
+            def scrub_v():
+                row_pos = base + jax.lax.broadcasted_iota(
+                    jnp.int32, (span, 1, 1), 0)
+                v = stages[1][slot]
+                stages[1][slot] = jnp.where(row_pos < limit, v,
+                                            jnp.zeros_like(v))
+
+        # Rows past the causal frontier hold whatever the pool or a skipped
+        # fetch left there, and ``0 * NaN`` is NaN in the PV dot. They exist
+        # only in a slot's last, ragged tile: scrub V there and nowhere else.
+        pl.when(base + span > limit)(scrub_v)
+
+        q = q_ref[0, 0]                                  # (n_kv*g, dh)
+        k, v = rows_of(0), rows_of(1)
+        dt = jnp.promote_types(q.dtype, k.dtype)
+        col = jax.lax.broadcasted_iota(jnp.int32, (n_kv * g, width), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (n_kv * g, width), 0)
+        # One query token a step (q_tile == 1): it attends keys up to
+        # itself, and ``limit`` is one past its own position, so the live
+        # columns of this tile are those below (limit - base) * n_kv. A
+        # walked tile has a live key for every row (base < limit), so no
+        # row is fully masked and exp(_NEG_INF - max) is an exact zero: no
+        # guard on p.
+        valid = (col % n_kv == row // g) & (col < (limit - base) * n_kv)
+        accumulate(0, q.astype(dt), k.astype(dt), v.astype(dt), valid,
+                   guard=False)
+        probe.compute(4 * n_kv * g * width * dh)
+
     def compute_tile(tile, slot):
+        if folded:
+            return compute_folded(tile, slot)
         base = tile * span
         # Staging rows whose block was never fetched hold garbage (NaN in
         # interpret mode, stale VMEM on hardware). The score-side causal
@@ -423,15 +531,27 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         row_pos = base + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
         row_live = row_pos < limit                           # (T*bs, 1) bool
 
+        rows = q_ref.shape[2]
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+        # Row r of the q block is query token j = qt*q_tile + r//g (the
+        # g query heads of one token share a kv head group); it may
+        # attend keys up to its own absolute position
+        # kv_len - q_len + j. Padding rows (j >= q_len) mask every key
+        # and emit exact zeros at the end — the varlen contract.
+        j = (qt * q_tile
+             + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0) // g)
+        valid = (j < q_len) & (pos <= kv_len - q_len + j)
         for h in range(n_kv):
             if latent:
                 q = q_ref[0, 0]                              # (q_tile*g, W)
                 k = stages[0][slot]                          # (T*bs, W)
                 v = stages[0][slot, :, :v_dim]
             else:
-                # f32 casts deliberate — see _flash_decode_kernel: bf16
-                # g-row sub-tiles hit Mosaic's relayout path and measured
-                # slower.
+                # A head's rows are a sublane-strided slice of the staging;
+                # the f32 casts are deliberate — see _flash_decode_kernel:
+                # bf16 g-row sub-tiles hit Mosaic's relayout path and
+                # measured slower. (The decode shape takes compute_folded
+                # and casts nothing.)
                 q = q_ref[0, h].astype(jnp.float32)          # (q_tile*g, dh)
                 k = stages[0][slot, :, h, :].astype(jnp.float32)  # (T*bs, dh)
                 v = stages[1][slot, :, h, :].astype(jnp.float32)
@@ -445,30 +565,7 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                 v = v * stages[3][slot, :, h:h + 1]
             # where, not multiply: 0 * NaN is still NaN.
             v = jnp.where(row_live, v, jnp.zeros_like(v))
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (q_tile*g, T*bs)
-            pos = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            # Row r of the q block is query token j = qt*q_tile + r//g (the
-            # g query heads of one token share a kv head group); it may
-            # attend keys up to its own absolute position
-            # kv_len - q_len + j. Padding rows (j >= q_len) mask every key
-            # and emit exact zeros at the end — the varlen contract.
-            j = (qt * q_tile
-                 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // g)
-            valid = (j < q_len) & (pos <= kv_len - q_len + j)
-            scores = jnp.where(valid, scores, _NEG_INF)
-            seg_max = jnp.max(scores, axis=-1, keepdims=True)
-            new_max = jnp.maximum(m_ref[h], seg_max)
-            corr = jnp.exp(m_ref[h] - new_max)
-            # ``* valid``: a fully-masked row has scores == new_max ==
-            # _NEG_INF and exp(0) == 1 would poison the denominator.
-            p = jnp.exp(scores - new_max) * valid.astype(jnp.float32)
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # (q_tile*g, dh)
-            m_ref[h] = new_max
+            accumulate(h, q, k, v, valid, guard=True)
         # QK^T + PV dots over the staged rows, all kv heads this tile.
         probe.compute(4 * n_kv * (q_ref.shape[2]) * span * q_ref.shape[3])
 
@@ -510,6 +607,21 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
+def tile_arithmetic(n_kv_heads: int, q_tile: int, *, latent: bool = False,
+                    quant: bool = False) -> str:
+    """Which arithmetic a staged kv tile gets, from what a call site can
+    see: ``"folded"`` — the K+V build at one query token a grid step
+    (the decode shape), every kv head's query rows in ONE operand against
+    the tile as it lies, operands in the pool's dtype — or ``"per_head"``:
+    a pair of dots a kv head over that head's slice of the staging (the
+    chunk shape, whose folded scores would not fit; the quantized build,
+    whose dequantization is a head's; the latent build, which has one head
+    and nothing to fold)."""
+    if latent or quant or q_tile != 1 or n_kv_heads < 2:
+        return "per_head"
+    return "folded"
+
+
 def paged_attn_cost(B: int, max_blocks: int, block_size: int,
                     n_kv_heads: int, head_dim: int, *, n_q_heads: int,
                     itemsize: int = 2, L: int = 1,
@@ -539,7 +651,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                     tile_blocks: int | None = None,
                     q_tile: int | None = None, interpret=None,
                     probes: bool = False, k_scale=None, v_scale=None,
-                    layer=None, v_dim: int | None = None):
+                    layer=None, v_dim: int | None = None,
+                    resolved: dict | None = None):
     """GQA attention of an L-token query block per slot directly over a
     block-paged KV pool — decode (L=1), chunked prefill, and ragged mixed
     steps all through ONE kernel.
@@ -592,18 +705,28 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                   scales of a QUANTIZED pool (int8/fp8 wire dtype, written
                   by ``nn.paged_cache_update``'s quantizing append). Given,
                   each staged block's scale rows DMA with it and the kernel
-                  dequantizes in VMEM before the f32 streaming softmax —
-                  storage precision is the ONLY thing that changes.
+                  dequantizes in VMEM before the per-head float32
+                  arithmetic — storage precision is the ONLY thing that
+                  changes against a float32 pool.
     probes:       device-telemetry build (a separate compile): returns
                   ``(out, probe_buf)`` with one record row per (slot,
                   q-tile, kv-tile), decoded by ``obs.kprobe`` —
                   stall attribution covers prefill steps exactly like
                   decode ones. Every grid dimension is ``arbitrary``, so
                   record ordinals are deterministic.
+    resolved:     a dict to fill, or None: what this call chose statically
+                  from its operands — ``tile_blocks``, ``q_tile`` and the
+                  ``arithmetic`` of a staged tile (``tile_arithmetic``) —
+                  for a caller that keeps a record of it.
 
-    Returns (B, L, Hq, dh) in q.dtype. Bit-compatible with the reference
-    ``paged_gather_kv`` + dense/flash composition (streaming softmax over
-    the same masked positions); verified in tests/test_paged_attention.py.
+    Returns (B, L, Hq, dh) in q.dtype: streaming softmax over the same
+    masked positions as the reference ``paged_gather_kv`` + dense/flash
+    composition, float32 scores and accumulators. With float32 operands it
+    equals the reference to summation order (1e-5, every shape); with a
+    bf16 pool the decode shape's folded arithmetic adds one rounding of
+    ``p`` to bf16 before the PV dot (at most 2^-8 of the softmax-weighted
+    mean of |v|), as the gather path's own decode shape does; both bounds
+    are held in tests/test_paged_attention.py.
     """
     B, L, Hq, dh = q.shape
     quant = k_scale is not None
@@ -673,6 +796,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         q_lens = jnp.broadcast_to(
             jnp.asarray(q_lens, jnp.int32).reshape(-1), (B,))
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    interpret = resolve_interpret(interpret)
     if tile_blocks is None or q_tile is None:
         t_cfg, qt_cfg = tuned_paged_tile(bs, Hkv, dh, max_blocks,
                                          str(k_pool.dtype), L=L, g=g)
@@ -689,15 +813,33 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
 
     L_pad = n_q_tiles * q_tile
-    rows = q_tile * g
-    qh = q.reshape(B, L, Hkv, g, dh)
-    if L_pad != L:
-        qh = jnp.pad(qh, ((0, 0), (0, L_pad - L), (0, 0), (0, 0), (0, 0)))
-    # (B, Hkv, L_pad*g, dh): kv-head major so one (1, Hkv, q_tile*g, dh)
-    # block serves each (slot, q-tile) grid step; row r of a block is query
-    # token r // g, head group r % g — the layout the in-kernel GQA causal
-    # mask assumes.
-    qh = qh.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, L_pad * g, dh)
+    arithmetic = tile_arithmetic(Hkv, q_tile, latent=latent, quant=quant)
+    folded = arithmetic == "folded"
+    if resolved is not None:
+        resolved.update(tile_blocks=tile_blocks, q_tile=q_tile,
+                        arithmetic=arithmetic)
+    if folded:
+        # One token a grid step, every query head in one operand: q as it
+        # arrives, (B, L, Hq, dh) with head h * g + j in kv head h's group,
+        # is already one (1, 1, Hq, dh) block a (slot, token).
+        heads, rows, qh = 1, Hq, q
+
+        def q_index(b, qt, tbl, kl, ql, ly):
+            return (b, qt, 0, 0)
+    else:
+        heads, rows = Hkv, q_tile * g
+        qh = q.reshape(B, L, Hkv, g, dh)
+        if L_pad != L:
+            qh = jnp.pad(qh,
+                         ((0, 0), (0, L_pad - L), (0, 0), (0, 0), (0, 0)))
+        # (B, Hkv, L_pad*g, dh): kv-head major so one (1, Hkv, q_tile*g,
+        # dh) block serves each (slot, q-tile) grid step; row r of a block
+        # is query token r // g, head group r % g — the layout the
+        # in-kernel GQA causal mask assumes.
+        qh = qh.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, L_pad * g, dh)
+
+        def q_index(b, qt, tbl, kl, ql, ly):
+            return (b, 0, qt, 0)
 
     arenas = (k_pool,) if latent else (k_pool, v_pool)
     if quant:
@@ -707,19 +849,19 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         _paged_attn_kernel, n_arenas=len(arenas), n_tiles=n_tiles,
         tile_blocks=tile_blocks, bs=bs, n_blocks=n_blocks, scale=scale,
         n_kv=Hkv, g=g, q_tile=q_tile, n_q_tiles=n_q_tiles, v_dim=v_dim,
-        probe_steps=n_steps if probes else 0)
+        probe_steps=n_steps if probes else 0, folded=folded,
+        compiled=not interpret)
     dv = v_dim if latent else dh          # width of a value row
-    out_specs = pl.BlockSpec((1, Hkv, rows, dv),
-                             lambda b, qt, tbl, kl, ql, ly: (b, 0, qt, 0))
-    out_shape = jax.ShapeDtypeStruct((B, Hkv, L_pad * g, dv), jnp.float32)
+    out_specs = pl.BlockSpec((1, heads, rows, dv), q_index)
+    out_shape = jax.ShapeDtypeStruct((*qh.shape[:3], dv), jnp.float32)
     scratch_shapes = [
         # Staging, two slots an arena: tile j + 1 lands in one while tile j
         # is computed from the other.
         *(pltpu.VMEM((2, tile_blocks * bs, *a.shape[3:]), a.dtype)
           for a in arenas),
-        pltpu.VMEM((Hkv, rows, dv), jnp.float32),   # acc
-        pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # running max
-        pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # denominator
+        pltpu.VMEM((heads, rows, dv), jnp.float32),  # acc
+        pltpu.VMEM((heads, rows, 1), jnp.float32),   # running max
+        pltpu.VMEM((heads, rows, 1), jnp.float32),   # denominator
         common.dma_sems((2, len(arenas))),          # one a (slot, arena)
         pltpu.SMEM((2,), jnp.int32),                # walk state across steps
     ]
@@ -733,8 +875,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         num_scalar_prefetch=4,
         grid=(B, n_q_tiles),
         in_specs=[
-            pl.BlockSpec((1, Hkv, rows, dh),
-                         lambda b, qt, tbl, kl, ql, ly: (b, 0, qt, 0)),
+            pl.BlockSpec((1, heads, rows, dh), q_index),
             # the arenas: manual per-(layer, block) DMA
             *(common.any_spec() for _ in arenas),
         ],
@@ -756,12 +897,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                       else k_pool.dtype.itemsize),
             kv_itemsize=k_pool.dtype.itemsize, kv_scales=quant,
             L=L, q_tile=q_tile),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
         name="latent_paged_attention" if latent else None,
     )(block_tables, kv_lens, q_lens, layer, qh, *arenas)
     o = outs[0] if probes else outs
-    o = o.reshape(B, Hkv, L_pad, g, dv).transpose(0, 2, 1, 3, 4)
-    o = o.reshape(B, L_pad, Hq, dv)[:, :L].astype(q.dtype)
+    if not folded:
+        o = o.reshape(B, Hkv, L_pad, g, dv).transpose(0, 2, 1, 3, 4)
+        o = o.reshape(B, L_pad, Hq, dv)[:, :L]
+    o = o.astype(q.dtype)
     if probes:
         return o, outs[1]
     return o
@@ -820,7 +963,10 @@ def _paged_trace_body(*refs, **kw):
     rows = kw["q_tile"] * kw["g"]
     refs = list(refs)
     for at in (4, 5 + kw["n_arenas"]):                  # q, o
-        refs[at] = refs[at].at[pl.ds(b, 1), :, pl.ds(qt * rows, rows)]
+        if kw["folded"]:            # (B, L, Hq, dh): one token a window
+            refs[at] = refs[at].at[pl.ds(b, 1), pl.ds(qt, 1)]
+        else:
+            refs[at] = refs[at].at[pl.ds(b, 1), :, pl.ds(qt * rows, rows)]
     _paged_attn_kernel(*refs, **kw)
 
 
@@ -834,7 +980,6 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
     n_blocks = B * max_blocks
     n_tiles = -(-max_blocks // tile_blocks)
     n_q_tiles = -(-L // q_tile)
-    rows = q_tile * g
     tbl_w = n_tiles * tile_blocks     # host-side right padding, never read
     # Queries/outputs stay in the COMPUTE dtype on a quantized pool (the
     # wire dtype only ever holds stored KV rows).
@@ -851,6 +996,13 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
     if kvq:
         arenas += [("ksp", (n_kv,), _np.float32),
                    ("vsp", (n_kv,), _np.float32)]
+    # The q/o blocks and the accumulators, as the wrapper lays them out for
+    # the arithmetic this shape takes.
+    folded = tile_arithmetic(n_kv, q_tile, latent=latent,
+                             quant=kvq) == "folded"
+    heads, rows = (1, n_kv * g) if folded else (n_kv, q_tile * g)
+    qo = ((B, n_q_tiles, rows) if folded
+          else (B, n_kv, n_q_tiles * rows))
 
     def tables(r, w):
         t = _np.zeros((B, tbl_w), _np.int32)
@@ -872,29 +1024,29 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
                       init=lambda r, w: _np.full((B,), L, _np.int32)),
             _comm.Buf("layer", (1,), _np.int32, space="smem",
                       init=lambda r, w: _np.ones((1,), _np.int32)),
-            _comm.Buf("q", (B, n_kv, n_q_tiles * rows, dh), qdt),
+            _comm.Buf("q", (*qo, dh), qdt),
             *(_comm.Buf(name, (2, n_blocks, bs, *row), adt)
               for name, row, adt in arenas),
             # One (1, Hkv, q_tile*g, dh) window of q and o is VMEM-resident
             # per grid step; billing the full B=2 buffers stays within a
             # few KiB of that and keeps the declaration honest.
-            _comm.Buf("o", (B, n_kv, n_q_tiles * rows, v_dim or dh),
-                      _np.float32, space="vmem", covered=True),
+            _comm.Buf("o", (*qo, v_dim or dh), _np.float32, space="vmem",
+                      covered=True),
             # Staging: two slots an arena, as the kernel allocates them.
             *(_comm.Buf(f"{name}_stage", (2, tile_blocks * bs, *row), adt,
                         space="vmem")
               for name, row, adt in arenas),
-            _comm.Buf("acc", (n_kv, rows, v_dim or dh), _np.float32,
+            _comm.Buf("acc", (heads, rows, v_dim or dh), _np.float32,
                       space="vmem"),
-            _comm.Buf("m_run", (n_kv, rows, 1), _np.float32, space="vmem"),
-            _comm.Buf("l_run", (n_kv, rows, 1), _np.float32, space="vmem"),
+            _comm.Buf("m_run", (heads, rows, 1), _np.float32, space="vmem"),
+            _comm.Buf("l_run", (heads, rows, 1), _np.float32, space="vmem"),
             _comm.Sem("sems", (2, len(arenas))),
             _comm.Buf("walk", (2,), _np.int32, space="smem"),
         ],
         kwargs=dict(n_arenas=len(arenas), n_tiles=n_tiles,
                     tile_blocks=tile_blocks, bs=bs, n_blocks=n_blocks,
                     scale=1.0, n_kv=n_kv, g=g, q_tile=q_tile,
-                    n_q_tiles=n_q_tiles, v_dim=v_dim),
+                    n_q_tiles=n_q_tiles, v_dim=v_dim, folded=folded),
     )
 
 

@@ -388,7 +388,10 @@ def _fused_paged_attention(arrays: dict, **static):
     a process traces the same call more than once: the decode step's and
     the mixed step's decode block are one shape. Replaying binds the same
     equations (one ``pallas_call``), so the compiled programs are what the
-    direct call gives. ``arrays`` holds the operands that are not None."""
+    direct call gives. ``arrays`` holds the operands that are not None.
+    Beside each shape's trace the record keeps the ARITHMETIC its staged
+    tiles take (``folded`` / ``per_head``), a choice that is static a call
+    site: ``fused_paged_arithmetic`` reads it back."""
     from triton_distributed_tpu.kernels.paged_attention import (
         paged_attention,
     )
@@ -398,11 +401,25 @@ def _fused_paged_attention(arrays: dict, **static):
     key = (names, tuple((a.shape, a.dtype) for a in flat),
            tuple(sorted(static.items())))
     if key not in _FUSED_TRACES:
-        _FUSED_TRACES[key] = jax.make_jaxpr(
-            lambda *a: paged_attention(**dict(zip(names, a)), **static))(
-                *flat)
-    closed = _FUSED_TRACES[key]
+        resolved = {}
+        closed = jax.make_jaxpr(
+            lambda *a: paged_attention(**dict(zip(names, a)), **static,
+                                       resolved=resolved))(*flat)
+        _FUSED_TRACES[key] = closed, resolved["arithmetic"]
+    closed, _ = _FUSED_TRACES[key]
     out, = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat)
+    return out
+
+
+def fused_paged_arithmetic() -> dict:
+    """The arithmetic each traced shape of the fused paged-attention call
+    took, ``{"q<shape>:<pool dtype>": "folded" | "per_head"}`` — this
+    process's record, written when a shape is first traced."""
+    out = {}
+    for (names, avals, _), (_, arithmetic) in _FUSED_TRACES.items():
+        by_name = dict(zip(names, avals))
+        q_shape = "x".join(str(d) for d in by_name["q"][0])
+        out[f"q{q_shape}:{by_name['k_pool'][1].name}"] = arithmetic
     return out
 
 

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from triton_distributed_tpu.layers import nn
 from triton_distributed_tpu.models.engine import Engine
 from triton_distributed_tpu.models.sampling import finite_logits_mask, sample_token
 from triton_distributed_tpu.obs import comm_ledger as _comm
@@ -753,6 +754,11 @@ class BatchEngine:
                 "admission_backpressure", "slo_breaches")},
             "windows": self._window_summary(),
             "trace_dropped_spans": tracer_dropped,
+            # Compiles of each step, and the arithmetic each shape of the
+            # fused paged-attention call took (static a call site, so
+            # recorded when the shape is traced).
+            "trace_counts": dict(self.trace_counts),
+            "paged_arithmetic": nn.fused_paged_arithmetic(),
         }
         lookups = m.get("prefix_lookups", 0.0)
         if lookups:
