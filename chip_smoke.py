@@ -16,7 +16,11 @@ granite-4.0-h-micro's widths, one period of its ten layers: nine Mamba-2
 layers that keep a state a slot and one attention layer over packed rows)
 through the same entry points, and fails unless the one-token state update
 (the ``ssm_state_update`` kernel, compiled by Mosaic) and the chunk scan
-give the same logits for the same tokens.
+give the same logits for the same tokens; and the NEMOTRON-H block
+(``models/nemotron_h.py``: NVIDIA-Nemotron-3-Nano-30B-A3B's widths, the
+first seven of its 52 layers, 16 of 128 experts held: Mamba-2 in eight
+groups, ungated relu² experts through the grouped product, attention over
+two key heads) the same way, no routed pair dropped.
 
 ``--chips 4`` (a four-chip host) runs only the tensor-parallel phase:
 Qwen3-8B over ``make_mesh({"tp": 4})`` through ``BatchEngine``, once in
@@ -58,12 +62,19 @@ FOUR_CHIPS = dict(
 # The hybrid phase: the published widths and vocabulary, ONE period of the
 # published layer pattern (2.3 GB of weights beside 8 slots of state).
 HYBRID = dict(
+    config="GraniteHybridConfig",
     overrides=dict(layer_types=("mamba",) * 5 + ("attention",)
                    + ("mamba",) * 4),
     interpret=False, paged_attn="fused", seed=0, n_slots=8, block_size=16,
     prefill_chunk=64, n_requests=6, prompt_range=(100, 400), new_tokens=16,
     walk_len=80,        # tokens fed one at a time through the kernel
 )
+
+# The same phase over the Nemotron-H block: the published widths and
+# vocabulary, the pattern's first seven layers (all three kinds), one chip's
+# share of the experts (2.8 GB of weights).
+NEMOTRON_H = dict(HYBRID, config="NemotronHConfig",
+                  overrides=dict(pattern="MEMEM*E", experts_held=16))
 
 # Largest |difference| of two logit rows over the largest |reference logit|.
 # bf16 keeps 8 mantissa bits (2^-8 per rounded op); over 28-36 layers of
@@ -495,12 +506,12 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
     import jax
     import numpy as np
 
-    from triton_distributed_tpu.models.config import GraniteHybridConfig
+    from triton_distributed_tpu.models import config as configs
     from triton_distributed_tpu.models.engine import Engine
     from triton_distributed_tpu.runtime.mesh import make_mesh
     from triton_distributed_tpu.serving.fleet import Fleet
 
-    cfg = GraniteHybridConfig(**geo["overrides"])
+    cfg = getattr(configs, geo["config"])(**geo["overrides"])
     mesh = make_mesh({"tp": 1}, devices=devices[:1], set_default=False)
     engine = Engine(cfg, mesh=mesh, mode="dist",
                     key=jax.random.PRNGKey(geo["seed"]),
@@ -535,12 +546,15 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
          ssm_rows_advanced=c.get("ssm_rows_advanced", 0.0),
          ssm_states_reset=c.get("ssm_states_reset", 0.0),
          kv_rows_appended=c.get("kv_rows_appended", 0.0),
+         moe_pairs_held=c.get("moe_pairs_held"),
+         moe_dropped_pairs=c.get("moe_dropped_pairs"),
          trace_counts=be.trace_counts)
     check(c.get("ssm_rows_advanced") == tokens * cfg.n_state_layers
           and c.get("kv_rows_appended") == tokens * cfg.n_cache_layers
           and c.get("ssm_states_reset") == geo["n_requests"],
           f"the step's counts do not add up to {tokens} tokens of "
           f"{geo['n_requests']} requests")
+    check(not c.get("moe_dropped_pairs"), "a routed pair was dropped")
 
     # Numbers: the same tokens through the chunk scan (chunked prefill,
     # then one decode step) and through the kernel alone.
@@ -557,12 +571,14 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
 
 def run_served_blocks(devices, geo: dict, caches: _CacheEvents) -> None:
     """The one-chip smoke: the dense model, then (its buffers dropped) the
-    hybrid block."""
+    hybrid block, then the Nemotron-H block."""
     import gc
 
     run_one_chip(devices, geo, caches)
     gc.collect()
     run_hybrid(devices, HYBRID, caches)
+    gc.collect()
+    run_hybrid(devices, NEMOTRON_H, caches)
 
 
 # -- four chips: TP=4 dist against xla ---------------------------------------

@@ -28,12 +28,13 @@ BLOCK, SLOTS, CHUNK, MAX_LEN = 16, 8, 64, 4096
 MAX_BLOCKS = MAX_LEN // BLOCK
 N_BLOCKS = SLOTS * MAX_BLOCKS
 DH = 128
-# (Hq, Hkv) per device: Qwen3-1.7B whole, Qwen3-8B's TP=4 shard, and
+# (Hq, Hkv) per device: Qwen3-1.7B whole, Qwen3-8B's TP=4 shard,
 # granite-4.0-h-micro's PACKED rows (two key heads of 64 to a row of 128:
-# 4 key rows, 8 query heads each). The decode shape of each takes the
-# folded tile arithmetic (16, 8 and 32 query rows in one operand).
+# 4 key rows, 8 query heads each) and Nemotron-3-Nano's two key heads under
+# sixteen query heads each. The decode shape of each takes the folded tile
+# arithmetic (16, 8, 32 and 32 query rows in one operand).
 HEADS = {"qwen3-1.7b": (16, 8), "qwen3-8b-tp4": (8, 2),
-         "granite-packed": (32, 4)}
+         "granite-packed": (32, 4), "nemotron-2kv": (32, 2)}
 # Qwen3-8B: d_model, fused qkv width, d_ff.
 D8, QKV8, FF8 = 4096, (32 + 2 * 8) * DH, 12_288
 
@@ -197,14 +198,18 @@ def test_latent_paged_attention_compiles(one_chip, L):
 
 @pytest.mark.parametrize("tiles,rows", [(32, 16), (144, 128)],
                          ids=["decode-tiles", "chunk-tiles"])
-@pytest.mark.parametrize("d,f", [(2048, 1536), (768, 2048)],
-                         ids=["gate_up", "down"])
+@pytest.mark.parametrize("d,f", [(2048, 1536), (768, 2048), (2688, 1920),
+                                 (1920, 2688)],
+                         ids=["gate_up", "down", "relu2-up", "relu2-down"])
 def test_grouped_product_over_expert_tiles_compiles(one_chip, monkeypatch,
                                                     tiles, rows, d, f):
-    """The routed experts' product at the cell's sizes: tiles of rows sorted
-    by expert against 16 held experts of 39 stacked layers. The wrapper asks
-    ``on_tpu()`` before it hands Mosaic a kernel; the test answers for the
-    described chip."""
+    """The routed experts' product at the cells' sizes: tiles of rows sorted
+    by expert against 16 held experts of 39 stacked layers (JoyAI's gated
+    pair; Nemotron-3-Nano's ungated pair at its stored width of 1,920, whose
+    f-tile is 384: 512 divides neither 1,920 nor 2,688, and without a tile
+    the wrapper would fall back to an einsum over gathered weights). The
+    wrapper asks ``on_tpu()`` before it hands Mosaic a kernel; the test
+    answers for the described chip."""
     from triton_distributed_tpu.kernels import moe_utils
     from triton_distributed_tpu.runtime import platform
 
@@ -232,11 +237,15 @@ def test_grouped_product_over_expert_tiles_compiles(one_chip, monkeypatch,
 HYB_SLOTS, HYB_BLOCKS, HYB_PREFILL_ROWS = 32, 3328, 7
 
 
-def test_ssm_state_update_compiles_in_place(one_chip):
+@pytest.mark.parametrize("layers,groups", [(36, 1), (23, 8)],
+                         ids=["granite-1-group", "nemotron-8-groups"])
+def test_ssm_state_update_compiles_in_place(one_chip, layers, groups):
+    """At 8 groups of 8 heads a block of 32 heads spans four groups; both
+    geometries run a grid of 32 slots x 2 head tiles."""
     from triton_distributed_tpu.kernels.ssm_update import ssm_state_update
 
     f32 = jnp.float32
-    arena = _sds((36, HYB_SLOTS, 64, 64, 128), f32, one_chip)
+    arena = _sds((layers, HYB_SLOTS, 64, 64, 128), f32, one_chip)
     compiled = jax.jit(
         lambda ar, ly, a, u, b, c: ssm_state_update(ar, ly, a, u, b, c,
                                                     interpret=False),
@@ -244,13 +253,13 @@ def test_ssm_state_update_compiles_in_place(one_chip):
         arena, _sds((), jnp.int32, one_chip),
         _sds((HYB_SLOTS, 64), f32, one_chip),
         _sds((HYB_SLOTS, 64, 64), f32, one_chip),
-        _sds((HYB_SLOTS, 1, 128), f32, one_chip),
-        _sds((HYB_SLOTS, 1, 128), f32, one_chip)).compile()
+        _sds((HYB_SLOTS, groups, 128), f32, one_chip),
+        _sds((HYB_SLOTS, groups, 128), f32, one_chip)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_state_update" in text
     mem = compiled.memory_analysis()
     # the arena is the result: no second one, and nothing beside it
-    assert mem.alias_size_in_bytes == 36 * HYB_SLOTS * 64 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes == layers * HYB_SLOTS * 64 * 64 * 128 * 4
     assert mem.temp_size_in_bytes < 1 << 20
 
 
@@ -306,3 +315,65 @@ def test_hybrid_step_compiles_with_its_state_in_place(topo, kind):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == state_bytes
     assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_nemotron_step_compiles_with_its_state_in_place(topo, monkeypatch,
+                                                        kind):
+    """The whole served step of nemotron-3-nano-30b-a3b-ep8 (all 52 layers,
+    every width, 16 of 128 experts held), as ``BatchEngine`` builds it
+    around ``forward_paged``: it compiles, every arena of the pool's state
+    (rows 6 layers deep, per-slot 23 deep) is aliased in to out, and the
+    step's temporaries hold no copy of an arena or of a weight stack (the
+    smallest stack of matrices is the attention layers' 0.28 GB, the
+    experts' two are 3.8 GB each)."""
+    from triton_distributed_tpu.models.config import NemotronHConfig
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.models.nemotron_h import NemotronH
+    from triton_distributed_tpu.runtime import platform
+    from triton_distributed_tpu.serving.kv_pool import (
+        paged_state_shapes,
+        paged_state_specs,
+    )
+
+    # the grouped product asks ``on_tpu()`` before it hands Mosaic a kernel
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    cfg = NemotronHConfig(experts_held=16)
+    mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
+    here = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, here), tree)
+
+    params = placed(jax.eval_shape(
+        lambda k: NemotronH(cfg).init(k, mesh), jax.random.PRNGKey(0)))
+    state = placed(paged_state_shapes(
+        cfg, n_blocks=HYB_BLOCKS, block_size=BLOCK, n_slots=HYB_SLOTS))
+    state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    assert 1.85e9 < state_bytes < 1.95e9
+    engine = Engine(cfg, mesh=mesh, params=params, mode="dist",
+                    interpret=False)
+    step = jax.jit(
+        engine._make_sm("dist", paged=kind, paged_attn="fused",
+                        state_specs=paged_state_specs(cfg)),
+        donate_argnums=(2,))
+    slots = (_sds((HYB_SLOTS,), jnp.int32, here),
+             _sds((HYB_SLOTS, MAX_BLOCKS), jnp.int32, here),
+             _sds((HYB_SLOTS,), bool, here))
+    if kind == "decode":
+        args = (_sds((HYB_SLOTS, 1), jnp.int32, here), state, *slots)
+    else:
+        ids = (_sds((HYB_SLOTS,), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here))
+        args = (ids, state, *slots, _sds((HYB_SLOTS,), jnp.int32, here))
+    compiled = step.lower(params, *args).compile()
+    text = compiled.as_text()
+    # 14 layer bodies (MEMEM*E, ME, M*E, ME): 6 state updates, two grouped
+    # products in each of 6 expert layers, 2 block walks
+    assert text.count("tpu_custom_call") >= 20
+    assert "ssm_state_update" in text and "moe_grouped_gemm" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes

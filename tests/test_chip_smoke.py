@@ -100,6 +100,28 @@ def test_hybrid_phase_passes_at_tiny(capsys, restore_compile_cache_config):
     assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
 
 
+def test_nemotron_h_phase_passes_at_tiny(capsys, restore_compile_cache_config):
+    """The same phase over the Nemotron-H block at tiny float32 sizes: all
+    three kinds of layer served, no routed pair dropped."""
+    import dataclasses
+
+    from triton_distributed_tpu.models.config import NemotronHConfig
+
+    geo = dict(chip_smoke.NEMOTRON_H, interpret=None, paged_attn="gather",
+               n_slots=2, block_size=4,
+               prefill_chunk=8, n_requests=3, prompt_range=(10, 20),
+               new_tokens=3, walk_len=10,
+               overrides=dataclasses.asdict(NemotronHConfig.tiny()))
+    rc = chip_smoke.smoke(chip_smoke.run_hybrid, jax.devices()[:1], geo)
+    records = [json.loads(line)
+               for line in capsys.readouterr().out.strip().splitlines()]
+    assert rc == 0 and records[-1]["ok"] is True
+    assert records[0]["state_layers"] == 4 and records[0]["cache_layers"] == 1
+    assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
+    assert records[1]["moe_pairs_held"] > 0 == records[1]["moe_dropped_pairs"]
+    assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+
+
 def test_forced_step_exception_fails_the_smoke(
         monkeypatch, capsys, restore_compile_cache_config):
     """A step that raises at run time is absorbed by the replica error

@@ -304,12 +304,16 @@ def test_the_chunk_scan_equals_the_sequential_one_with_a_ragged_last_chunk(
                                           np.asarray(was)[keep])
 
 
-@pytest.mark.parametrize("groups,tile", [(1, 2), (2, 4), (1, 8)])
+@pytest.mark.parametrize("groups,tile", [(1, 2), (2, 4), (1, 8), (2, 8),
+                                         (8, 4), (8, 8), (4, None)])
 def test_the_state_update_kernel_equals_plain_jnp(groups, tile):
     """``ssm_state_update`` under the interpreter: layer 1 of a 3-layer
     arena advanced, the others untouched; a slot with decay 1 and no input
-    keeps its state to the bit."""
-    rng = np.random.default_rng(tile)
+    keeps its state to the bit. A block is part of one group (1, 2), one
+    whole group (2, 4), or SPANS groups: both of two (2, 8), four and all of
+    eight (8, 4 and 8, 8: a group of one head), all four under the module's
+    own tile (4, None)."""
+    rng = np.random.default_rng(tile or 0)
     n_layers, n_slots, H, P, N = 3, 4, 8, 16, 128
     arena = jnp.asarray(rng.standard_normal((n_layers, n_slots, H, P, N)),
                         jnp.float32)

@@ -70,6 +70,9 @@ OF_A_FAMILY = {
         r"granite|GraniteHybrid|mamba_|layer_types|embedding_multiplier|"
         r"residual_multiplier|attention_multiplier|logits_scaling",
         re.IGNORECASE),
+    "nemotron_h": re.compile(
+        r"nemotron|hybrid_override|relu2|shared_expert_intermediate|"
+        r"ssm_state_size|conv_kernel|time_step_", re.IGNORECASE),
 }
 
 

@@ -303,7 +303,12 @@ def grouped_gemm_skip(grouped, weights, counts, *, layer_idx=None,
         weights = weights[None]
         layer_idx = 0
     f = weights.shape[-1]
-    bn = min(block_n, f)
+    # The f-tile: all of a narrow f, else the widest lane multiple up to
+    # ``block_n`` that divides f (512 of 1,536 and 2,048; 384 of 1,920 and
+    # 2,688, which 512 does not divide).
+    bn = f if f <= block_n else next(
+        (b for b in range(block_n // 128 * 128, 0, -128) if f % b == 0),
+        block_n)
     # cap < 16 falls back: sub-16-sublane bf16 operands hit Mosaic's
     # packed-tile relayout path (measured 2x SLOWER end-to-end at a cap=8
     # decode shape than the einsum despite the skip) — capacity sizing

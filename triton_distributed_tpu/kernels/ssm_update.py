@@ -7,10 +7,15 @@ each of the layer's ``H`` heads, a float32 state ``S`` of ``(P, N)``
     S' = a * S + u (x) B          a = exp(dt * A)  a scalar a head,
     y  = S' . C                   u = dt * x       (P,) a head,
 
-with ``B`` and ``C`` (N,) shared by the heads of a group. The states of all
+with ``B`` and ``C`` (N,) shared by the heads of a group. A grid step's
+block of heads does not stop at a group's edge: where a group has fewer
+heads than the tile (8 groups of 8 under a tile of 32) the block SPANS
+groups and is handed the ``B`` and ``C`` rows of each, so that a model of
+many groups moves its state in blocks as large as a model of one; where a
+group has more, the block is a whole part of one group. The states of all
 layers and slots live in ONE arena ``(state layers, n_slots, H, P, N)``
 (``serving.kv_pool.PagedKVState.ssm``), the paged step's donated operand
-and the layer scan's carry; this kernel is handed the whole arena with the
+and the layer walk's carry; this kernel is handed the whole arena with the
 layer's index, aliased in to out, and reads and writes block
 ``[layer, slot, head tile]`` of it where it lies: each slot's state moves
 once each way a layer and a step, and no copy of the arena exists. A dead
@@ -42,15 +47,17 @@ from triton_distributed_tpu.runtime.platform import resolve_interpret
 NAME = "ssm_state_update"
 # Heads a grid step: 32 x 64 x 128 x 4 B = 1 MiB a block. Read on a v5e at the
 # cell's geometry (36 layers x 32 slots; PERF.md, PR 32): 8 heads 435 GB/s of
-# state moved, 16 497, 32 527, 64 532.
+# state moved, 16 497, 32 527, 64 532. Whatever the number of groups.
 HEAD_TILE = 32
 
 
 def _kernel(layer_ref, a_ref, u_ref, b_ref, c_ref, s_ref, o_ref, y_ref, *,
-            heads: int):
+            heads: int, groups: int):
     del layer_ref                       # read by the index maps
-    b, c = b_ref[...], c_ref[...]       # (1, N) rows, broadcast over P
+    # each group's (1, N) rows, broadcast over P
+    rows = [(b_ref[g:g + 1], c_ref[g:g + 1]) for g in range(groups)]
     for j in range(heads):
+        b, c = rows[j * groups // heads]
         s = (a_ref[:, j:j + 1] * s_ref[j] + u_ref[:, j:j + 1] * b)
         o_ref[j] = s
         y_ref[:, j:j + 1] = jnp.sum(s * c, axis=1, keepdims=True)
@@ -66,12 +73,14 @@ def ssm_state_update(arena, layer, a, u, b, c, *, head_tile: int | None = None,
     the operand is aliased to the result) and ``y`` (n_slots, H, P)."""
     n_slots, H, P, N = arena.shape[1:]
     G = b.shape[1]
-    ht = min(head_tile or HEAD_TILE, H // G)
-    if H % ht or (H // G) % ht:
+    ht, hpg = min(head_tile or HEAD_TILE, H), H // G
+    # A block is whole groups or a whole part of one: it carries the B and
+    # C rows of the ``gpt`` groups its heads read.
+    gpt = max(1, ht // hpg)
+    if H % ht or (ht % hpg and hpg % ht):
         raise ValueError(f"a head tile of {ht} does not divide {H} heads in "
-                         f"{G} group(s)")
+                         f"{G} group(s) of {hpg}")
     n_ht = H // ht
-    per_group = H // G // ht            # head tiles a group
 
     def cols(x):                        # (n_slots, H, P) -> (.., n_ht, P, ht)
         return x.reshape(n_slots, n_ht, ht, P).transpose(0, 1, 3, 2)
@@ -79,12 +88,12 @@ def ssm_state_update(arena, layer, a, u, b, c, *, head_tile: int | None = None,
     a_cols = cols(jnp.broadcast_to(a[:, :, None], (n_slots, H, P)))
     small = pl.BlockSpec((None, None, P, ht),
                          lambda s, h, ly: (s, h, 0, 0))
-    row = pl.BlockSpec((None, None, 1, N),
-                       lambda s, h, ly: (s, h // per_group, 0, 0))
+    row = pl.BlockSpec((None, None, gpt, N),
+                       lambda s, h, ly: (s, h * ht // hpg // gpt, 0, 0))
     state = pl.BlockSpec((None, None, ht, P, N),
                          lambda s, h, ly: (ly[0], s, h, 0, 0))
     arena, y = pl.pallas_call(
-        functools.partial(_kernel, heads=ht),
+        functools.partial(_kernel, heads=ht, groups=gpt),
         out_shape=(jax.ShapeDtypeStruct(arena.shape, arena.dtype),
                    jax.ShapeDtypeStruct((n_slots, n_ht, P, ht), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -101,7 +110,8 @@ def ssm_state_update(arena, layer, a, u, b, c, *, head_tile: int | None = None,
         interpret=resolve_interpret(interpret),
         name=NAME,
     )(jnp.asarray(layer, jnp.int32).reshape(1), a_cols, cols(u),
-      b[:, :, None, :], c[:, :, None, :], arena)
+      b.reshape(n_slots, G // gpt, gpt, N),
+      c.reshape(n_slots, G // gpt, gpt, N), arena)
     return arena, y.transpose(0, 1, 3, 2).reshape(n_slots, H, P)
 
 

@@ -35,6 +35,7 @@ host.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +82,22 @@ def chunk_scan(x, dt, a_log_step, b, c, s0):
     return y, s
 
 
+def draw_own(name: str, key, shape):
+    """A random draw of one of the layer's parameters that is not a matrix
+    (a model's ``init``), as Mamba-2 initialises them: ``a_log = log U(1,
+    16)``, ``dt_bias`` the inverse softplus of a step drawn log-uniformly
+    from [1e-3, 1e-1], the convolution's bias 0, ``d_skip`` and the norm 1."""
+    if name == "a_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if name == "dt_bias":
+        step = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return step + jnp.log(-jnp.expm1(-step))
+    if name == "conv_b":
+        return jnp.zeros(shape, jnp.float32)
+    return jnp.ones(shape, jnp.float32)
+
+
 @dataclasses.dataclass(frozen=True)
 class Mamba2:
     d_model: int
@@ -102,7 +119,7 @@ class Mamba2:
 
     def param_shapes(self) -> dict:
         """name -> (shape, fan_in); fan_in None marks what is not a matrix
-        (``models.granite_hybrid`` says how each is drawn)."""
+        (``draw_own`` says how each of those is drawn)."""
         d, di, C, H = self.d_model, self.d_inner, self.conv_dim, self.n_heads
         return {"w_in": ((d, 2 * di + 2 * self.n_groups * self.d_state + H),
                          d),

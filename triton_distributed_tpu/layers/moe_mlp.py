@@ -264,8 +264,8 @@ MOE_STATS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
 
 @dataclasses.dataclass(frozen=True)
 class HeldExpertsMoE:
-    """The DeepSeek-V3 expert layer as ONE chip of a wide expert-parallel
-    deployment sees it: the router keeps its published width, this device
+    """A sigmoid-routed expert layer (DeepSeek-V3's, Nemotron-H's) as ONE
+    chip of a wide expert-parallel deployment sees it: the router keeps its published width, this device
     holds the routed experts ``[lo, lo + n_held)`` and computes the part of
     the result they give; what the absent experts would add is left out
     (their chips would add it), and no code stands in for them or for the
@@ -282,10 +282,21 @@ class HeldExpertsMoE:
     (``moe_utils.rows_by_expert``), and the two grouped products walk its
     tiles, so the work follows the pairs routed.
 
+    Two expert forms, a property of the layer (``gated``), the shared
+    expert alike:
+
+    - gated SwiGLU (DeepSeek-V3): ``w_down (silu(x w_gate) * (x w_up))``,
+      gate and up halves one matrix ``w_gate_up`` (d, 2 * d_ff);
+    - ungated relu² (Nemotron-H): ``w_down relu(x w_up)²``, TWO matrices an
+      expert, ``w_up`` (d, d_ff). A zero column of ``w_up`` against a zero
+      row of ``w_down`` adds nothing, so a model may store ``d_ff`` padded
+      to what the grouped product tiles by.
+
     Parameters: ``router`` (d, n_experts) f32, ``bias`` (n_experts,) f32,
-    ``w_gate_up`` (n_held, d, 2*d_ff) / ``w_down`` (n_held, d_ff, d) — or
-    layer-stacked with ``layer_idx``, as ``grouped_gemm_skip`` wants them
-    under a scan — and ``shared`` {``w_gate_up`` (d, 2*ff_s), ``w_down``}.
+    ``w_gate_up`` (n_held, d, 2*d_ff) or ``w_up`` (n_held, d, d_ff) /
+    ``w_down`` (n_held, d_ff, d) — or layer-stacked with ``layer_idx``, as
+    ``grouped_gemm_skip`` wants them under a scan — and ``shared`` {the
+    same two names, (d, 2*ff_s) or (d, ff_s), ``w_down`` (ff_s, d)}.
     """
 
     d_model: int
@@ -297,6 +308,20 @@ class HeldExpertsMoE:
     routed_scaling: float = 1.0
     norm_topk_prob: bool = True
     dtype: jnp.dtype = jnp.bfloat16
+    gated: bool = True
+
+    @property
+    def w_in(self) -> str:
+        """The name of an expert's first matrix."""
+        return "w_gate_up" if self.gated else "w_up"
+
+    def activation(self, h):
+        """What stands between an expert's two products, in float32."""
+        if not self.gated:
+            return jnp.square(jax.nn.relu(h.astype(jnp.float32)))
+        ff = h.shape[-1] // 2
+        return (jax.nn.silu(h[..., :ff].astype(jnp.float32))
+                * h[..., ff:].astype(jnp.float32))
 
     def route(self, router, bias, x):
         """x (n, d) -> (weights (n, k) f32, ids (n, k) int32). The scores
@@ -336,13 +361,11 @@ class HeldExpertsMoE:
         tile_live = (jnp.arange(R // tile) < n_tiles).astype(jnp.int32)
         kw = dict(layer_idx=layer_idx, interpret=interpret,
                   group_of=tile_expert, name="moe_grouped_gemm")
-        h = moe_utils.grouped_gemm_skip(rows, params["w_gate_up"], tile_live,
+        h = moe_utils.grouped_gemm_skip(rows, params[self.w_in], tile_live,
                                         **kw)
-        ff = h.shape[-1] // 2
-        act = (jax.nn.silu(h[..., :ff].astype(jnp.float32))
-               * h[..., ff:].astype(jnp.float32)).astype(h.dtype)
-        out = moe_utils.grouped_gemm_skip(act, params["w_down"], tile_live,
-                                          **kw).reshape(R, -1)
+        out = moe_utils.grouped_gemm_skip(
+            self.activation(h).astype(h.dtype), params["w_down"], tile_live,
+            **kw).reshape(R, -1)
         pair_out = out.at[row_of_pair].get(                     # (n, k, d)
             mode="fill", fill_value=0).astype(jnp.float32)
         y = jnp.sum(pair_out * jnp.where(held, w, 0.0)[..., None], axis=1)
@@ -356,6 +379,8 @@ class HeldExpertsMoE:
         """x (n, d) -> (shared(x) + the held experts' part, stats)."""
         y, stats = self.routed(params, x, valid, layer_idx=layer_idx,
                                interpret=interpret)
-        sh = params["shared"]
-        return y + swiglu(x.astype(self.dtype), sh["w_gate_up"],
-                          sh["w_down"]), stats
+        sh, x = params["shared"], x.astype(self.dtype)
+        h = jnp.dot(x, sh[self.w_in], preferred_element_type=jnp.float32)
+        return y + jnp.dot(
+            self.activation(h).astype(x.dtype), sh["w_down"],
+            preferred_element_type=jnp.float32).astype(x.dtype), stats

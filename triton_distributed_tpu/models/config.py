@@ -18,6 +18,9 @@ class from the class of the object it is given:
 - ``GraniteHybridConfig``: the Granite-4.0-H block (Mamba-2 layers that keep
   a fixed-size state a sequence beside a few attention layers that keep
   rows, a SwiGLU after each), run by ``models.granite_hybrid.GraniteHybrid``.
+- ``NemotronHConfig``: the Nemotron-H block (every layer ONE mixer: Mamba-2,
+  routed relu² experts with a shared expert, or attention, in the order a
+  pattern string gives), run by ``models.nemotron_h.NemotronH``.
 
 Each states what ``serving.kv_pool.KVPool`` builds the pool's state from:
 ``kv_row_shapes`` (what one token's row of each row arena looks like),
@@ -280,6 +283,131 @@ class GraniteHybridConfig:
             layer_types=("mamba", "attention", "mamba") * 2, n_heads=4,
             n_kv_heads=2, d_ff=96, mamba_n_heads=4, mamba_d_head=8,
             mamba_d_state=16, max_length=64, dtype=jnp.float32),
+            **overrides})
+
+
+#: A layer's kind by its letter in ``hybrid_override_pattern``.
+NEMOTRON_H_KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    """The Nemotron-H decoder (HF ``nemotron_h``; HF key in brackets).
+    Defaults are NVIDIA-Nemotron-3-Nano-30B-A3B's public ``config.json``.
+    A layer is ONE mixer under one norm, ``h <- h + Mixer_i(RMSNorm_i(h))``,
+    and ``pattern`` [hybrid_override_pattern] names each layer's: ``M`` a
+    Mamba-2 layer (its state a fixed size a sequence), ``E`` routed experts
+    with a shared expert (ungated, relu²), ``*`` grouped-query attention with
+    no position embedding (its keys and values rows of the paged pool).
+
+    ``experts_held`` / ``experts_lo`` as ``DeepseekV3Config`` has them: this
+    device is one chip's share of an expert-parallel deployment and holds
+    the routed experts ``[experts_lo, experts_lo + experts_held)`` of every
+    expert layer (a configuration's file states them as
+    ``n_routed_experts_held`` / ``n_routed_experts_lo`` beside
+    ``n_routed_experts_published``, the router's width ``n_experts``)."""
+
+    model_name: str = "nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16"
+    vocab_size: int = 131_072
+    d_model: int = 2688                # hidden_size
+    pattern: str = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    n_heads: int = 32                  # num_attention_heads
+    n_kv_heads: int = 2                # num_key_value_heads
+    head_dim: int = 128
+    mamba_n_heads: int = 64            # mamba_num_heads
+    mamba_d_head: int = 64             # mamba_head_dim
+    mamba_d_state: int = 128           # ssm_state_size
+    mamba_d_conv: int = 4              # conv_kernel
+    mamba_n_groups: int = 8            # n_groups
+    moe_d_ff: int = 1856               # moe_intermediate_size
+    shared_d_ff: int = 3712            # moe_shared_expert_intermediate_size
+    n_experts: int = 128               # n_routed_experts: the router's width
+    n_experts_per_tok: int = 6         # num_experts_per_tok
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    experts_held: int | None = None
+    experts_lo: int = 0
+    rms_eps: float = 1e-5              # layer_norm_epsilon
+    max_length: int = 4096
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def __post_init__(self):
+        bad = set(self.pattern) - set(NEMOTRON_H_KINDS)
+        if bad or not self.pattern:
+            raise ValueError(f"pattern names {sorted(bad)}; a layer is one "
+                             f"of {sorted(NEMOTRON_H_KINDS)}")
+        if self.n_heads % self.n_kv_heads \
+                or self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("heads do not divide the widths given")
+        if not (0 <= self.experts_lo and self.n_held >= 1
+                and self.experts_lo + self.n_held <= self.n_experts):
+            raise ValueError(
+                f"experts held [{self.experts_lo}, "
+                f"{self.experts_lo + self.n_held}) do not lie inside the "
+                f"router's {self.n_experts}")
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """``"mamba"`` | ``"moe"`` | ``"attention"``, one a layer."""
+        return tuple(NEMOTRON_H_KINDS[ch] for ch in self.pattern)
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def moe_d_ff_stored(self) -> int:
+        """A routed expert's width as its matrices are STORED: zero-padded
+        to a lane multiple (1,856 -> 1,920), so that the grouped product
+        over expert tiles walks whole lane tiles (1,856 has no divisor that
+        is one). relu² of a zero column is zero and meets a zero row of
+        ``w_down``: the padding changes no result."""
+        return -(-self.moe_d_ff // LANE) * LANE
+
+    @property
+    def kv_row_shapes(self):
+        row = (self.n_kv_heads, self.head_dim)
+        return row, row
+
+    @property
+    def n_cache_layers(self) -> int:
+        return self.pattern.count("*")
+
+    @property
+    def n_state_layers(self) -> int:
+        return self.pattern.count("M")
+
+    @property
+    def conv_dim(self) -> int:
+        """What the convolution runs over: x, B and C."""
+        return (self.mamba_n_heads * self.mamba_d_head
+                + 2 * self.mamba_n_groups * self.mamba_d_state)
+
+    @property
+    def slot_state_shapes(self):
+        """What a Mamba-2 layer keeps for one sequence, as
+        ``GraniteHybridConfig.slot_state_shapes``."""
+        return {
+            "ssm": ((self.mamba_n_heads, self.mamba_d_head,
+                     self.mamba_d_state), jnp.float32),
+            "conv": (((self.mamba_d_conv - 1) * self.conv_dim,), self.dtype),
+        }
+
+    @classmethod
+    def tiny(cls, **overrides) -> "NemotronHConfig":
+        """Tiny float32 sizes for tests (not a real checkpoint): all three
+        kinds in a pattern no period divides, two groups, 8 experts top-2."""
+        return cls(**{**dict(
+            model_name="tiny-nemotron-h", vocab_size=128, d_model=64,
+            pattern="MEM*EMEME", n_heads=4, n_kv_heads=2, head_dim=16,
+            mamba_n_heads=4, mamba_d_head=8, mamba_d_state=16,
+            mamba_n_groups=2, moe_d_ff=24, shared_d_ff=48, n_experts=8,
+            n_experts_per_tok=2, max_length=64, dtype=jnp.float32),
             **overrides})
 
 

@@ -27,6 +27,7 @@ from triton_distributed_tpu.models.config import (
     DeepseekV3Config,
     GraniteHybridConfig,
     ModelConfig,
+    NemotronHConfig,
 )
 from triton_distributed_tpu.models.kv_cache import KVCache
 from triton_distributed_tpu.models.qwen import Qwen3
@@ -46,12 +47,16 @@ def model_for(config, *, block_n: int = 256):
         from triton_distributed_tpu.models.granite_hybrid import GraniteHybrid
 
         return GraniteHybrid(config)
+    if isinstance(config, NemotronHConfig):
+        from triton_distributed_tpu.models.nemotron_h import NemotronH
+
+        return NemotronH(config)
     return Qwen3(config, block_n=block_n)
 
 
 class Engine:
     def __init__(self, config: ModelConfig | DeepseekV3Config
-                 | GraniteHybridConfig, *,
+                 | GraniteHybridConfig | NemotronHConfig, *,
                  mesh: Mesh | None = None,
                  mode: str = "dist", prefill_mode: str | None = None,
                  temperature: float = 0.0, top_p: float = 1.0,

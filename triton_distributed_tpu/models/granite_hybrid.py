@@ -47,14 +47,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from triton_distributed_tpu.layers import nn
-from triton_distributed_tpu.layers.mamba2 import Mamba2
+from triton_distributed_tpu.layers.mamba2 import Mamba2, draw_own
 from triton_distributed_tpu.layers.moe_mlp import swiglu
 from triton_distributed_tpu.layers.tp_attn import TPAttn
 from triton_distributed_tpu.models.config import GraniteHybridConfig
@@ -89,6 +88,12 @@ class GraniteHybrid:
         """One period's layer kinds."""
         lt = tuple(self.config.layer_types)
         return lt[:shortest_period(lt)]
+
+    @functools.cached_property
+    def layer_counts(self) -> dict:
+        """Layers by kind (``BatchEngine.stats_snapshot()["layers"]``)."""
+        lt = self.config.layer_types
+        return {k: lt.count(k) for k in KINDS if k in lt}
 
     @functools.cached_property
     def mamba(self) -> Mamba2:
@@ -159,16 +164,7 @@ class GraniteHybrid:
                     scale /= c.embedding_multiplier
                 return (jax.random.normal(k, shape, c.dtype)
                         * jnp.asarray(scale, c.dtype))
-            if name == "a_log":
-                return jnp.log(jax.random.uniform(k, shape, jnp.float32,
-                                                  1.0, 16.0))
-            if name == "dt_bias":
-                step = jnp.exp(jax.random.uniform(
-                    k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
-                return step + jnp.log(-jnp.expm1(-step))
-            if name == "conv_b":
-                return jnp.zeros(shape, jnp.float32)
-            return jnp.ones(shape, jnp.float32)     # norms, d_skip
+            return draw_own(name, k, shape)         # norms: 1
 
         @functools.partial(jax.jit, out_shardings=shardings)
         def make(key):

@@ -760,6 +760,11 @@ class BatchEngine:
             "trace_counts": dict(self.trace_counts),
             "paged_arithmetic": nn.fused_paged_arithmetic(),
         }
+        # A model whose layers are of several kinds says how many of each
+        # (what the ``step_stats`` counts of a step are sums over).
+        kinds = getattr(self.engine.model, "layer_counts", None)
+        if kinds:
+            snap["layers"] = dict(kinds)
         lookups = m.get("prefix_lookups", 0.0)
         if lookups:
             snap["prefix_hit_rate"] = round(
