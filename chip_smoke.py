@@ -282,23 +282,22 @@ def token_agreement(a: list[int], b: list[int]) -> int:
 
 def drain_fleet(fleet, caches: _CacheEvents, max_steps: int = 50_000) -> dict:
     """Step ``fleet`` until idle. Returns step/wall counts and, for the
-    first call of each compiled step, its wall seconds (compile + one run)
-    with the compile-cache counters at that point."""
+    first call of each compiled step, its wall seconds (trace, compile and
+    dispatch) with the compile-cache counters at that point."""
     eng = fleet.replicas[0].engine
     first: dict = {}
     steps = idle = 0
     t_all = time.perf_counter()
     while steps < max_steps:
-        before = {k: eng.metrics.counters.get(k, 0.0)
-                  for k in ("prefill_steps", "decode_steps")}
+        # The call that TRACES a step is its first (the engine counts a
+        # step a call later, when it reads the step's tokens).
+        before = dict(eng.trace_counts)
         c0 = caches.snapshot()
         t0 = time.perf_counter()
         busy = fleet.step()
         dt = time.perf_counter() - t0
-        for kind, key in (("mixed", "prefill_steps"),
-                          ("decode", "decode_steps")):
-            if kind not in first and \
-                    eng.metrics.counters.get(key, 0.0) > before[key]:
+        for kind, key in (("mixed", "prefill"), ("decode", "decode")):
+            if kind not in first and eng.trace_counts[key] > before[key]:
                 c1 = caches.snapshot()
                 first[kind] = {
                     "seconds": round(dt, 3),

@@ -127,7 +127,7 @@ def test_forced_step_exception_fails_the_smoke(
     """A step that raises at run time is absorbed by the replica error
     boundary (a quarantined replica, failed requests, no exception) — the
     smoke must turn that into a non-zero exit and no ``ok`` line."""
-    def boom(self):
+    def boom(self, live):
         raise RuntimeError("forced decode failure")
 
     monkeypatch.setattr(_be.BatchEngine, "_run_decode", boom)

@@ -197,7 +197,12 @@ class EfficiencyLedger:
         now = self.clock() if now is None else now
         t_start = now if self._t_start is None else self._t_start
         self._t_start = None
-        bubble_s = (max(0.0, t_start - self._last_end)
+        if self._last_end is not None:
+            # A step dispatched before the one before it ended (the
+            # serving loop keeps one in flight) could start only then:
+            # its interval opens there, with no bubble.
+            t_start = max(t_start, self._last_end)
+        bubble_s = (t_start - self._last_end
                     if self._last_end is not None else 0.0)
         wall_s = max(0.0, now - t_start)
         self._last_end = now

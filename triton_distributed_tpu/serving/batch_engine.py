@@ -23,6 +23,16 @@ assert on it). The reference engine gets this from CUDA-Graph replay over
 a fixed batch; here XLA executable replay plays that role with the
 dynamism pushed into masks — the TPU-idiomatic translation.
 
+One step is kept IN FLIGHT: ``step()`` dispatches step N+1 from what the
+host knows by count and only then reads step N's tokens, so the host's
+work between two steps runs while the device computes (the section
+"iteration" below; docs/serving.md has the contract). A token is visible
+in the call after the one that dispatched it; ``flush()``, and everything
+that reads or moves requests between two steps, reads the step in flight
+first. Where the next plan needs the tokens' values (speculation, an
+installed fault plan, the NaN guard, an eviction) the step is read before
+anything is planned behind it — the same loop, nothing left in flight.
+
 KV lives in the block-paged ``KVPool`` (vLLM-style), so HBM holds
 sequences at their actual lengths; when the pool runs dry the scheduler
 evicts by recompute (``serving/scheduler.py``) and the victim's re-prefill
@@ -49,10 +59,12 @@ attribute check per site and emits bit-identical tokens.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
 import time
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -102,6 +114,14 @@ _SNAPSHOT_WINDOWS = ((10.0, "10s"), (300.0, "5m"))
 _SNAPSHOT_SERIES = ("ttft_s", "tbt_s", "queue_wait_s")
 
 
+# Why a step is read with nothing dispatched behind it (the label of the
+# ``pipeline_flushes`` counter): the three standing conditions of
+# ``BatchEngine._serial_reason``, an eviction, a caller that reads or moves
+# requests between two steps, and a step with no row left to follow it.
+FLUSH_REASONS = ("speculation", "fault_plan", "guard", "preempt", "caller",
+                 "idle")
+
+
 class StepBuildError(RuntimeError):
     """A compiled step failed on its FIRST call — where it is traced,
     lowered, compiled (Mosaic/XLA) and first given device memory. That is
@@ -127,10 +147,45 @@ class _Slot:
     offset: int = 0         # tokens written into the pool so far
     last_tok: int = 0       # pending decode input (valid once offset>=len(ctx))
     last_token_t: float | None = None   # wall of previous emitted token (TBT)
+    in_flight: int = 0      # tokens dispatched steps emit for this row that
+                            # the host has not read (0 or 1 between calls)
 
     @property
     def prefilling(self) -> bool:
         return self.offset < len(self.ctx)
+
+    @property
+    def ended(self) -> bool:
+        """Every token the request may take is dispatched (a request ends
+        by count): the row takes no part in a further step."""
+        return self.req.remaining_new <= self.in_flight
+
+
+class _Row(typing.NamedTuple):
+    """One row of a dispatched step."""
+
+    idx: int        # its slot
+    slot: _Slot
+    take: int       # token positions it takes
+    written: int    # of them, tokens its offset moves by at dispatch (a
+                    # verify row's is acceptance's to move, once read)
+    emits: bool     # the step's token of this row is the request's next
+    first: bool     # ... and the first of this residency (prefill ends)
+
+
+@dataclasses.dataclass(eq=False)
+class _Step:
+    """A dispatched compiled step whose tokens the host has not read."""
+
+    span: str       # "decode_step" | "mixed_step": its trace span
+    nxt: object     # on the device: a token a slot, then the step_stats
+    finite: object  # on the device: the finite mask of its logits
+    greedy: object  # on the device: every position's argmax | None
+    rows: list      # of _Row: the rows that took part
+    attrs: dict     # attributes of the span
+    counts: dict    # counters that move when the step is read
+    eff: tuple | None   # what the efficiency ledger is told of it
+    verify: dict    # speculation: slot -> (draft tokens, row of ``greedy``)
 
 
 class BatchEngine:
@@ -208,6 +263,14 @@ class BatchEngine:
                    fraction; pass a configured ``TailSampler`` or False.
     ``attach_slo()`` adds the OK/WARN/BREACH state machine on top; a
                    BREACH fires the attached watchdog's snapshot path.
+    ``step()``     dispatches one compiled step and reads the one the call
+                   before dispatched (module docstring): tokens, finished
+                   requests and the step counters appear a call after the
+                   dispatch. ``flush()`` reads the step in flight;
+                   ``run()``, ``drain()``, ``finished`` and ``failed``
+                   call it. No option: ``speculative``, ``nan_guard`` and
+                   an installed fault plan read every step at once, as
+                   ``stats_snapshot()["pipeline"]`` counts.
     ``speculative`` draft-then-verify decoding (serving/speculative.py):
                    True = n-gram drafter + default adaptive-k controller,
                    or pass a ``Drafter`` / a ``Speculative`` plan. The
@@ -387,6 +450,15 @@ class BatchEngine:
         # Per-step draft proposals, slot index -> token list; rebuilt by
         # ``step()`` every iteration (never carried across steps).
         self._proposals: dict[int, list[int]] = {}
+        # The dispatched step whose tokens the host has not read (at most
+        # one), and the newest step's token vector on the device: the
+        # operand a decode row's input comes from while its token is in
+        # flight. Zeros, placed as a step's output is, until a step ran.
+        self._inflight: _Step | None = None
+        self._prev0 = self._prev = jax.device_put(
+            np.zeros((n_slots + len(engine.model.step_stats),), np.int32),
+            jax.sharding.NamedSharding(engine.mesh,
+                                       jax.sharding.PartitionSpec()))
         # Write-ahead journal (resilience/checkpoint.py), attached by
         # ``Fleet.attach_journal``: emit/finish/fail records flow through
         # ``_journal`` below. None = journaling off (zero overhead).
@@ -431,6 +503,15 @@ class BatchEngine:
         # in the transfer that brings the tokens and cost no further sync
         # (``_take_stats`` splits them off).
 
+        # ``fed = (prev, from_prev)``: the token vector the step before
+        # returned, still on the device, and a host-made mask of the
+        # decode rows whose input token is in it and not yet on the host
+        # (``step()``); every other row's token is the host's ``tok``.
+
+        def feed(tok, fed):
+            prev, from_prev = fed
+            return jnp.where(from_prev, prev[:tok.shape[0]], tok)
+
         def sample(logits, aux, corrupt, key):
             logits = logits + corrupt[:, None]
             finite = finite_logits_mask(logits)
@@ -442,23 +523,24 @@ class BatchEngine:
 
         @functools.partial(jax.jit, donate_argnums=(2,))
         def decode_step(params, tok, state, offsets, block_tables,
-                        slot_mask, corrupt, key):
+                        slot_mask, corrupt, key, fed):
             # Trace-time side effect: counts COMPILATIONS, not calls — the
             # one-compile-across-churn guarantee the tests assert on.
             trace_counts["decode"] += 1
-            ids = jnp.clip(tok, 0, V - 1)[:, None]
+            ids = jnp.clip(feed(tok, fed), 0, V - 1)[:, None]
             logits, aux, state = sm_dec(params, ids, state, offsets,
                                         block_tables, slot_mask)
             return *sample(logits, aux, corrupt, key), state
 
         @functools.partial(jax.jit, donate_argnums=(2,))
         def mixed_step(params, ids, state, offsets, block_tables, slot_mask,
-                       seq_lens, corrupt, key):
+                       seq_lens, corrupt, key, fed):
             # ``ids`` is the pair (tok (n_slots,), chunk (prefill_rows,
             # prefill_chunk)): the decode block and the prefill block,
             # whose row k is the k-th slot with ``seq_lens > 1``
             # (``nn.paged_token_blocks`` finds them on the device).
             trace_counts["prefill"] += 1
+            ids = (feed(ids[0], fed), ids[1])
             ids = jax.tree.map(lambda a: jnp.clip(a, 0, V - 1), ids)
             logits, aux, state = sm_pre(params, ids, state, offsets,
                                         block_tables, slot_mask, seq_lens)
@@ -759,6 +841,14 @@ class BatchEngine:
             # recorded when the shape is traced).
             "trace_counts": dict(self.trace_counts),
             "paged_arithmetic": nn.fused_paged_arithmetic(),
+            # Steps dispatched while the step before was still unread,
+            # and the times a step was read with nothing dispatched
+            # behind it, by what made it so.
+            "pipeline": {
+                "steps_overlapped": m.get("steps_overlapped", 0.0),
+                "flushes": {r: m[k] for r in FLUSH_REASONS if (
+                    k := f"pipeline_flushes{{reason={r}}}") in m},
+            },
         }
         # A model whose layers are of several kinds says how many of each
         # (what the ``step_stats`` counts of a step are sums over).
@@ -1118,7 +1208,15 @@ class BatchEngine:
         this on a quarantined replica; the engine is left empty (pool
         invariants intact) and can be stepped or probed safely afterwards.
         Requests stay ``status='pending'`` — draining is displacement, not
-        failure."""
+        failure. A step in flight is read first; where the device fails
+        under that read its tokens are lost as a failed step's are, and
+        the requests leave with what they have."""
+        try:
+            self.flush()
+        except Exception as e:  # noqa: BLE001 — a failed replica is drained
+            self.metrics.inc("drain_flush_failures")
+            _trace.instant("drain_flush_failed", reason=reason,
+                           error=f"{type(e).__name__}: {e}")
         out: list[Request] = []
         for i, s in enumerate(self._slots):
             if s is None:
@@ -1273,6 +1371,10 @@ class BatchEngine:
             self._journal("admit", req_id=req.req_id, ctx_len=len(ctx))
 
     def _preempt(self, idx: int):
+        # An eviction requeues its victim with the tokens it has: the one
+        # in flight has to be among them. (Who is evicted does not depend
+        # on it: ``select_victim`` reads priorities and admission order.)
+        self.flush("preempt")
         s = self._slots[idx]
         self.pool.release(s.req.req_id)
         s.req.n_preemptions += 1
@@ -1341,20 +1443,37 @@ class BatchEngine:
         except _faults.TransientFault:
             self.metrics.inc("journal_faults")
 
-    def _finish(self, idx: int):
+    def _release_slot(self, idx: int):
+        """The half of a finish that the SCHEDULE depends on: the row's
+        blocks go to the prefix cache and back to the pool, the slot is
+        free. It needs no token's value (a request ends by count, and the
+        last token is never written back), so ``step()`` does it for a row
+        whose last token is still in flight, before it admits: admission
+        and eviction then see what they would see had the step been read.
+        On the device the step in flight runs before any later one (the
+        donated state chains them), so a block may be handed on at once."""
         s = self._slots[idx]
-        s.req.finish_t = time.monotonic()
-        s.req.status = "ok"
         if self.prefix_cache is not None and self.prefix_cache.enabled:
             # Donate this sequence's KV to the radix tree BEFORE release:
             # pool positions 0..offset-1 hold the KV of the full token
-            # stream's first ``offset`` tokens (the final emitted token was
+            # stream's first ``offset`` tokens (the final emitted token is
             # never written back). Insert promotes those blocks to cached;
             # the release below then drops them to resident-only.
             toks = (s.req.prompt + s.req.output)[:s.offset]
             self.prefix_cache.insert(s.req.req_id, toks)
         self.pool.release(s.req.req_id)
         self._slots[idx] = None
+
+    def _finish(self, idx: int):
+        s = self._slots[idx]
+        self._release_slot(idx)
+        self._complete(s)
+
+    def _complete(self, s: _Slot):
+        """The other half: the request, with its last token on it, is
+        finished for everyone who asks."""
+        s.req.finish_t = time.monotonic()
+        s.req.status = "ok"
         self._finished[s.req.req_id] = s.req
         if self.spec is not None:
             self.spec.drafter.release(s.req.req_id)
@@ -1476,10 +1595,58 @@ class BatchEngine:
         return out
 
     # -- iteration ----------------------------------------------------------
+    # One step in flight. A call of ``step()`` plans and DISPATCHES step
+    # N+1 from what the host knows by count (offsets, takes, the tokens a
+    # request may still take, the block a row needs next), and only then
+    # READS step N: its tokens, the journal's records, the finishes, the
+    # counters. The Python between two steps so runs while the device
+    # works. Where the next plan needs the VALUES of the tokens
+    # (``_serial_reason``; an eviction) the step is read before anything
+    # is planned behind it: the same loop with nothing left in flight.
+
+    def _serial_reason(self) -> str | None:
+        """Why a step must be read before the next is planned, as long as
+        the condition holds: acceptance decides a verify row's offset; a
+        fault plan's retry must find the state it started from; the guard
+        quarantines a row before it takes another token."""
+        if self.spec is not None:
+            return "speculation"
+        if _faults._PLAN is not None:
+            return "fault_plan"
+        if self.nan_guard:
+            return "guard"
+        return None
+
+    def flush(self, reason: str = "caller") -> None:
+        """Read the step in flight, if there is one: afterwards every
+        dispatched token is on its request and every finished request in
+        ``finished``. What reads or moves requests between two steps
+        (``drain``, ``finished``, ``failed``, the end of ``run()``,
+        ``Fleet.checkpoint``) calls it first."""
+        st, self._inflight = self._inflight, None
+        if st is not None:
+            self.metrics.inc("pipeline_flushes", labels={"reason": reason})
+            self._retire(st, flush=reason)
 
     def step(self) -> bool:
-        """One scheduler iteration: admit, then run one compiled step.
-        Returns False when there is nothing to do (idle)."""
+        """One scheduler iteration: admit, dispatch one compiled step,
+        then read the step dispatched by the call before (the one just
+        dispatched, where ``_serial_reason`` holds). A token is therefore
+        visible in the call AFTER the one that dispatched it. Returns
+        False when there is nothing to do (idle)."""
+        serial = self._serial_reason()
+        if serial and self._inflight is not None:
+            # The condition came up between two calls: this call only
+            # reads, so that no call ever moves two steps' counters.
+            self.flush(serial)
+            return True
+        if self._inflight is not None:
+            # A request whose last token is in flight has ended BY COUNT:
+            # its slot and blocks are free for this call's admission, as
+            # they would be had the step been read (``_release_slot``).
+            for r in self._inflight.rows:
+                if r.slot.ended and self._slots[r.idx] is r.slot:
+                    self._release_slot(r.idx)
         self._admit()
         self._proposals = self._draft() if self.spec is not None else {}
         # Decode rows write one token this step — make room first (prefill
@@ -1504,9 +1671,9 @@ class BatchEngine:
                 del self._proposals[i]
                 self.metrics.inc("spec_drafts_dropped")
             self._ensure_or_preempt(i)
-        active = [i for i, s in enumerate(self._slots) if s is not None]
+        live = [i for i, s in enumerate(self._slots) if s is not None]
         self.metrics.set_gauge("queue_depth", len(self.scheduler))
-        self.metrics.set_gauge("active_slots", len(active))
+        self.metrics.set_gauge("active_slots", len(live))
         self.metrics.set_gauge("pool_free_blocks", self.pool.n_free)
         self.metrics.set_gauge("pool_reclaimable_blocks",
                                self.pool.n_reclaimable)
@@ -1517,10 +1684,10 @@ class BatchEngine:
         # evaluating. Same for the incident detectors: a stall shows up
         # as signals going quiet, not as a step that runs.
         self._obs_tick()
-        self._incident_tick(busy=bool(active))
+        self._incident_tick(busy=bool(live))
         if self._controller is not None:
             self._controller.on_step()
-        if not active:
+        if not live and self._inflight is None:
             return False
         # Draft proposals ride the mixed step (ragged verify rows need
         # seq_lens); a step with neither prefill rows nor proposals uses
@@ -1528,70 +1695,92 @@ class BatchEngine:
         self._proposals = {i: p for i, p in self._proposals.items()
                            if self._slots[i] is not None}
         run = (self._run_mixed
-               if (any(self._slots[i].prefilling for i in active)
+               if (any(self._slots[i].prefilling for i in live)
                    or self._proposals)
                else self._run_decode)
-        if self._watchdog is not None:
-            with self._watchdog.deadline("serving_step",
-                                         self._step_deadline_s):
-                run()
-            if self._heartbeat is not None:
-                self._heartbeat.beat()
-        else:
-            run()
+
+        deadline = (contextlib.nullcontext() if self._watchdog is None
+                    else self._watchdog.deadline("serving_step",
+                                                 self._step_deadline_s))
+        with deadline:
+            before = self._inflight
+            if not live:
+                self.flush("idle")      # nothing to dispatch behind it
+            else:
+                self._inflight = run(live)
+                if before is not None:
+                    self.metrics.inc("steps_overlapped")
+                    self._retire(before)
+                elif serial:
+                    self.flush(serial)
+        if self._heartbeat is not None:
+            self._heartbeat.beat()
         return True
 
-    def _operands(self):
-        sids = [s.req.req_id if s is not None else None for s in self._slots]
-        offsets = np.array([s.offset if s else 0 for s in self._slots],
-                           np.int32)
-        mask = np.array([s is not None for s in self._slots], bool)
+    def _operands(self, live):
+        """Offsets, block tables and mask of the occupied slots."""
+        sids = [None] * self.n_slots
+        offsets = np.zeros((self.n_slots,), np.int32)
+        mask = np.zeros((self.n_slots,), bool)
+        for i in live:
+            s = self._slots[i]
+            sids[i], offsets[i], mask[i] = s.req.req_id, s.offset, True
         tables = self.pool.padded_tables(sids)
         return (jnp.asarray(offsets), jnp.asarray(tables),
                 jnp.asarray(mask))
 
-    def _guard_rows(self, finite, rows=None) -> None:
-        """Host half of the NaN/Inf guard: quarantine every active row
-        (of ``rows``, the slots that took tokens this step; default all)
-        whose logits failed the compiled finite check. Costs a device
-        transfer, so it only runs while guarding (fault plan installed or
+    def _guard_rows(self, finite, rows) -> None:
+        """Host half of the NaN/Inf guard: quarantine every row of
+        ``rows`` (the slots that took tokens in the step) whose logits
+        failed the compiled finite check. Costs a device transfer, so it
+        only runs while guarding (fault plan installed or
         ``nan_guard=True``) — the mask itself is computed every step."""
-        active = [i for i in (range(self.n_slots) if rows is None else rows)
-                  if self._slots[i] is not None]
+        active = [i for i in rows if self._slots[i] is not None]
         for i in _guards.bad_rows(np.asarray(finite), active):
             self._quarantine(i, "non-finite logits (NaN/Inf guard)")
 
     # -- efficiency-ledger hooks --------------------------------------------
-    # step_begin at the top of each run function and step_end immediately
-    # after the device sync: everything between one step's sync and the
-    # next step's dispatch — admission, gauge updates, SLO/controller
-    # ticks, token post-processing — lands in the inter-step gap the
-    # ledger accounts as HOST BUBBLE, which is exactly the ISSUE's
-    # definition of it.
+    # The ledger's interval of a step opens where the device could start
+    # it (its dispatch, or the end of the step before it where that came
+    # later: ``step_end`` clamps) and closes where its tokens are on the
+    # host. Host work that ran while an earlier step was on the device is
+    # in no interval's BUBBLE; what is, is the time the device waited.
 
-    def _eff_begin(self) -> float:
-        """Mark dispatch start; returns the comm-ledger wall baseline the
-        matching ``_eff_end`` diffs (0.0 when either ledger is off)."""
+    def _eff_begin(self, rows) -> tuple | None:
+        """At dispatch: the ledger's clock, the comm ledger's wall, the
+        (new_tokens, kv_len) pairs the step computes, its tokens and the
+        token positions each tenant is billed."""
         if self.efficiency is None:
-            return 0.0
-        self.efficiency.step_begin()
-        return _comm.wall_s_total() if _comm.enabled() else 0.0
+            return None
+        pairs, tenants = [], {}
+        for r in rows:
+            # kv_len at the step's end: the row attends its whole context
+            # up to and including the tokens just written.
+            pairs.append((r.take, r.slot.offset + r.take))
+            t = r.slot.req.tenant or "default"
+            tenants[t] = tenants.get(t, 0) + r.take
+        # Tokens of the step: a prompt's, and one for each decoding row.
+        tokens = sum(r.take if r.slot.prefilling else 1 for r in rows)
+        return (self.efficiency.clock(),
+                _comm.wall_s_total() if _comm.enabled() else 0.0,
+                pairs, tokens, tenants)
 
-    def _eff_end(self, comm0: float, rows, tokens: int,
-                 tenants: dict) -> None:
-        """Account one completed step: model its FLOPs / HBM bytes from
-        the (new_tokens, kv_len) ``rows`` it actually computed, diff the
-        comm ledger, and bill ``tenants`` (tenant -> token positions)."""
-        if self.efficiency is None:
+    def _eff_end(self, eff: tuple | None) -> None:
+        """Account one step that has been read: model its FLOPs / HBM
+        bytes from the rows it computed, diff the comm ledger, bill the
+        tenants."""
+        if eff is None:
             return
+        t0, comm0, pairs, tokens, tenants = eff
         comm_s = ((_comm.wall_s_total() - comm0)
                   if _comm.enabled() else 0.0)
         model = self.engine.model
         stall = self.eff_stall_source() if self.eff_stall_source else None
+        self.efficiency.step_begin(t0)
         self.efficiency.step_end(
-            flops=model.step_flops(rows),
+            flops=model.step_flops(pairs),
             hbm_bytes=model.step_hbm_bytes(
-                rows, block_size=self.pool.block_size,
+                pairs, block_size=self.pool.block_size,
                 itemsize=self._eff_itemsize, method=self.paged_attn,
                 kv_itemsize=self._eff_kv_itemsize,
                 kv_scales=self.pool.kv_quant),
@@ -1613,57 +1802,51 @@ class BatchEngine:
             span.set(**stats)
         return nxt[:self.n_slots]
 
-    def _dispatch(self, site: str, step, span: str, ids, *extra, **attrs):
+    def _dispatch(self, site: str, step, span: str, ids, live, rows,
+                  counts, *extra, verify=(), **attrs) -> _Step:
         """What the two runners share: the slot operands, the call of the
         compiled ``step`` through ``_call_step`` with the pool's state as
-        its donated operand (under the trace span ``span``), the model's
-        stats split off the tokens, and the state written back. Returns
-        ``(nxt, finite, greedy | None)`` on the host."""
-        offsets, tables, mask = self._operands()
+        its donated operand, the state written back, the copy of the
+        tokens to the host started, and the host's count of each row
+        moved on. Returns the step, unread."""
+        offsets, tables, mask = self._operands(live)
+        # A decode row whose newest token is still in flight takes it
+        # from the step before, on the device.
+        from_prev = np.zeros((self.n_slots,), bool)
+        for r in rows:
+            from_prev[r.idx] = r.slot.in_flight
         state = self.pool.state
         key = self._next_key()   # drawn ONCE — retries replay the same key
-        with _trace.span(span, **attrs,
-                         active=int(sum(s is not None for s in self._slots))
-                         ) as sp:
-            nxt, finite, greedy, state = self._call_step(
-                site, lambda corrupt: step(
-                    self.engine.params, ids, state, offsets, tables, mask,
-                    *extra, corrupt, key))
-            greedy = jax.device_get(greedy)
-            nxt = self._take_stats(np.asarray(nxt), sp)
+        eff = self._eff_begin(rows)
+        nxt, finite, greedy, state = self._call_step(
+            site, lambda corrupt: step(
+                self.engine.params, ids, state, offsets, tables, mask,
+                *extra, corrupt, key, (self._prev, jnp.asarray(from_prev))))
         self.pool.state = state
-        return nxt, finite, greedy
+        self._prev = nxt
+        nxt.copy_to_host_async()
+        for r in rows:
+            r.slot.offset += r.written
+            r.slot.in_flight += r.emits
+        return _Step(span=span, nxt=nxt, finite=finite, greedy=greedy,
+                     rows=rows, counts=counts, eff=eff,
+                     verify=dict(verify), attrs={
+                         **attrs, "active": len(live),
+                         "overlapped": self._inflight is not None})
 
-    def _run_decode(self):
-        comm0 = self._eff_begin()
-        tok = np.array([s.last_tok if s else 0 for s in self._slots],
-                       np.int32)
-        nxt, finite, _ = self._dispatch("engine.decode", self._decode_step,
-                                        "decode_step", jnp.asarray(tok))
-        if self.efficiency is not None:
-            rows, tenants = [], {}
-            for s in self._slots:
-                if s is None:
-                    continue
-                rows.append((1, s.offset + 1))
-                t = s.req.tenant or "default"
-                tenants[t] = tenants.get(t, 0) + 1
-            self._eff_end(comm0, rows, len(rows), tenants)
-        self.metrics.inc("decode_steps")
-        self.metrics.inc("decode_rows",
-                         sum(s is not None for s in self._slots))
-        if self._guarding:
-            self._guard_rows(finite)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            s.offset += 1
-            self._record_token(s, int(nxt[i]))
-            if s.req.remaining_new == 0:
-                self._finish(i)
+    def _run_decode(self, live) -> _Step:
+        tok = np.zeros((self.n_slots,), np.int32)
+        rows = []
+        for i in live:
+            s = self._slots[i]
+            tok[i] = 0 if s.in_flight else s.last_tok
+            rows.append(_Row(i, s, 1, 1, True, False))
+        return self._dispatch(
+            "engine.decode", self._decode_step, "decode_step",
+            jnp.asarray(tok), live, rows,
+            {"decode_steps": 1, "decode_rows": len(rows)})
 
-    def _run_mixed(self):
-        comm0 = self._eff_begin()
+    def _run_mixed(self, live) -> _Step:
         L, P = self.prefill_chunk, self.prefill_rows
         proposals = self._proposals
         # The controller's runtime budget narrows a prefilling row's take
@@ -1671,16 +1854,16 @@ class BatchEngine:
         # zero-padded, seq_lens carries the smaller take.
         budget = min(max(int(self.prefill_budget), 1), L)
         wants: dict[int, list[int]] = {}    # slot -> this step's new tokens
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
+        for i in live:
+            s = self._slots[i]
             if s.prefilling:
                 wants[i] = s.ctx[s.offset:s.offset + budget]
             else:
                 # Decode row, possibly a speculative verify row: the ids
                 # are [last_tok, d_1..d_p] and seq_lens = 1+p — churn in
                 # draft width is pure operand data, same compiled step.
-                wants[i] = [s.last_tok, *proposals.get(i, ())]
+                wants[i] = [0 if s.in_flight else s.last_tok,
+                            *proposals.get(i, ())]
         # A row of ONE token (a decode row; a prompt's last token) rides
         # the decode block. A longer one needs a row of the prefill block:
         # the P oldest admitted get one, in slot order (row k is the k-th
@@ -1694,6 +1877,7 @@ class BatchEngine:
         tok = np.zeros((self.n_slots,), np.int32)
         chunk = np.zeros((P, L), np.int32)
         seq_lens = np.zeros((self.n_slots,), np.int32)
+        rows = []
         pre_toks = dec_rows = 0
         for i, t in wants.items():
             seq_lens[i] = len(t)
@@ -1704,66 +1888,95 @@ class BatchEngine:
             s = self._slots[i]
             if not s.prefilling:
                 dec_rows += 1
+                # A verify row's offset is acceptance's to move.
+                rows.append(_Row(i, s, len(t), 0 if i in proposals else 1,
+                                 True, False))
                 continue
             pre_toks += len(t)
+            last = s.offset + len(t) >= len(s.ctx)
+            rows.append(_Row(i, s, len(t), len(t), last, last))
             if self.journey is not None:
                 # Chunk consumption keyed by the budget in force, so
                 # controller narrowing shows up per request.
                 self.journey.event(s.req.req_id, "prefill_chunk",
                                    tokens=len(t), budget=budget)
         n_deferred, n_tokens = len(many) - len(block_row), int(seq_lens.sum())
-        nxt, finite, greedy = self._dispatch(
-            "engine.prefill", self._mixed_step, "mixed_step",
-            (jnp.asarray(tok), jnp.asarray(chunk)), jnp.asarray(seq_lens),
-            prefill_rows=len(block_row), spec_rows=len(proposals),
-            prefill_rows_deferred=n_deferred, mixed_step_tokens=n_tokens)
-        if self.efficiency is not None:
-            rows, tenants = [], {}
-            for i, s in enumerate(self._slots):
-                if s is None or not seq_lens[i]:
-                    continue
-                take = int(seq_lens[i])
-                # kv_len at this step's end: the row attends its whole
-                # context up to and including the tokens just written.
-                rows.append((take, s.offset + take))
-                t = s.req.tenant or "default"
-                tenants[t] = tenants.get(t, 0) + take
-            self._eff_end(comm0, rows, pre_toks + dec_rows, tenants)
-        self.metrics.inc("prefill_steps")
         # Per-step work accounting (prompt tokens actually consumed vs
         # 1-token decode rows riding the mixed step) — what the adaptive
-        # bench's deterministic cost model and serve_top's rate lines read.
-        self.metrics.inc("prefill_tokens", pre_toks)
-        if dec_rows:
-            self.metrics.inc("decode_rows", dec_rows)
-        # How full the step was (live tokens of its n_slots + P * L
+        # bench's deterministic cost model and serve_top's rate lines
+        # read; how full the step was (live tokens of its n_slots + P * L
         # positions) and how often the prefill block was: a prefilling row
         # that wanted tokens and got none.
-        self.metrics.inc("mixed_step_tokens", n_tokens)
-        self.metrics.inc("prefill_rows_deferred", n_deferred)
+        counts = {"prefill_steps": 1, "prefill_tokens": pre_toks,
+                  "decode_rows": dec_rows, "mixed_step_tokens": n_tokens,
+                  "prefill_rows_deferred": n_deferred}
+        return self._dispatch(
+            "engine.prefill", self._mixed_step, "mixed_step",
+            (jnp.asarray(tok), jnp.asarray(chunk)), live, rows, counts,
+            jnp.asarray(seq_lens),
+            verify={i: (p, block_row[i]) for i, p in proposals.items()},
+            prefill_rows=len(block_row), spec_rows=len(proposals),
+            prefill_rows_deferred=n_deferred, mixed_step_tokens=n_tokens)
+
+    def _retire(self, st: _Step, flush: str | None = None) -> None:
+        """Read a dispatched step: its tokens (with the ``step_stats``
+        that ride them) under the step's trace span, then everything that
+        follows from their values or is counted a step: the efficiency
+        ledger, the counters, the guard, the tokens on their requests (the
+        journal's records, ``ttft_s`` / ``tbt_s``), the finishes."""
+        attrs = {**st.attrs, "flush": flush} if flush else st.attrs
+        with _trace.span(st.span, **attrs) as sp:
+            try:
+                nxt, greedy = jax.device_get((st.nxt, st.greedy))
+            except Exception:
+                self._unwind(st)
+                raise
+            nxt = self._take_stats(nxt, sp)
+        self._eff_end(st.eff)
+        for name, n in st.counts.items():
+            self.metrics.inc(name, n)
         if self._guarding:
-            # A row that took nothing has no logits to judge.
-            self._guard_rows(finite, np.flatnonzero(seq_lens))
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue            # freed mid-loop (quarantined by guard)
-            props = proposals.get(i)
-            if props and s.offset >= len(s.ctx):
-                self._accept_row(i, s, props, greedy[block_row[i]],
-                                 int(nxt[i]))
+            self._guard_rows(st.finite, [r.idx for r in st.rows])
+        for i, s, _, _, emits, first in st.rows:
+            if s.req.status == "failed":
+                continue            # quarantined by the guard just above
+            s.in_flight -= emits
+            if i in st.verify:
+                props, k = st.verify[i]
+                self._accept_row(i, s, props, greedy[k], int(nxt[i]))
                 continue
-            took = int(seq_lens[i])
-            was_prefilling = s.offset < len(s.ctx)
-            s.offset += took
-            if s.offset < len(s.ctx):
+            if not emits:
                 continue            # still mid-prompt; logits row is interim
-            if was_prefilling and self.journey is not None:
+            if first and self.journey is not None:
                 # This residency's prefill just completed: the journey
                 # phase flips to decode at the first emitted token.
                 self.journey.event(s.req.req_id, "decode_start")
             self._record_token(s, int(nxt[i]))
             if s.req.remaining_new == 0:
-                self._finish(i)
+                if self._slots[i] is s:     # else ``step()`` freed it
+                    self._release_slot(i)
+                self._complete(s)
+
+    def _unwind(self, st: _Step) -> None:
+        """The device failed under a dispatched step (the error comes up
+        where its tokens are read). Its tokens are lost, and so are those
+        of a step dispatched behind it, whose operands were this one's
+        results: take back the host's count of both, so that every row
+        stands where its request's tokens say, as after a failed step of
+        the flushed loop. A request that ended in the failed step and has
+        given up its slot already goes back to the queue (its re-prefill
+        emits the token that was lost)."""
+        behind, self._inflight = self._inflight, None
+        self._prev = self._prev0
+        for step in (st,) if behind is None else (st, behind):
+            for r in step.rows:
+                r.slot.offset -= r.written
+                r.slot.in_flight -= r.emits
+                if self._slots[r.idx] is not r.slot \
+                        and r.slot.req.status == "pending" and r.emits:
+                    r.slot.req.n_preemptions += 1
+                    self.scheduler.requeue(r.slot.req)
+                    self.metrics.inc("preemptions")
 
     def _accept_row(self, idx: int, s: _Slot, props: list[int],
                     greedy_row, nxt_i: int) -> None:
@@ -1828,15 +2041,18 @@ class BatchEngine:
                         "admission made no progress for 1000 consecutive "
                         "idle steps (fault plan blocking all admission?)")
             steps += 1
-        return {rid: list(req.output)
-                for rid, req in self._finished.items()}
+        return {rid: list(req.output) for rid, req in self.finished.items()}
 
     @property
     def finished(self) -> dict:
+        """Finished requests, those of a step still in flight among them
+        (it is read first)."""
+        self.flush()
         return dict(self._finished)
 
     @property
     def failed(self) -> dict:
         """Quarantined requests: ``{req_id: Request}`` with
         ``status='failed'`` and ``error`` set."""
+        self.flush()
         return dict(self._failed)

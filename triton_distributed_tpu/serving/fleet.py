@@ -549,6 +549,11 @@ class Fleet:
         the controller knob state. The manifest pins the journal sequence
         number at the snapshot barrier, so ``restore`` replays exactly
         the suffix written afterwards. Returns the manifest."""
+        # A step in flight is read first: its tokens are on the requests
+        # and in the journal before the barrier.
+        for rep in self.replicas:
+            if rep.state in ROUTABLE:
+                rep.engine.flush()
         journal_seq, journal_path = -1, None
         if self.journal is not None:
             self.journal.flush(fsync=True)
@@ -1015,6 +1020,9 @@ class Fleet:
         owner: dict = {}
         for rep in self.replicas:
             eng = rep.engine
+            # A request whose last token is in flight has left its slot
+            # and is not finished yet: read the step first.
+            eng.flush()
             eng.pool.check_invariants()
             held = ([s.req.req_id for s in eng._slots if s is not None]
                     + [r.req_id for r in eng.scheduler.pending()])
