@@ -98,7 +98,11 @@ def test_paged_attention_compiles(one_chip, model, L):
         _sds((SLOTS, MAX_BLOCKS), jnp.int32, one_chip),
         _sds((SLOTS,), jnp.int32, one_chip),
         _sds((SLOTS,), jnp.int32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    # the name a device trace knows the K+V build by; the latent build's
+    # is its own (a reader's pattern must be able to tell them apart)
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    assert "latent_paged_attention" not in text
 
 
 def _tp4_compile(tp4, fn, in_specs, out_specs, *shapes):

@@ -53,8 +53,8 @@ def _take(module: str):
     return loaded
 
 
-for _module in ("test_arithmetic", "test_harness", "test_reference",
-                "test_xplane"):
+for _module in ("test_arithmetic", "test_harness", "test_program_spans",
+                "test_reference", "test_xplane"):
     _take(_module)
 _cases = _take("test_families")
 

@@ -663,7 +663,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     shares: the keys are the whole rows, the values their first ``v_dim``
     columns, so each block is read once and used as both. q is
     ``(B, L, Hq, W)`` and the result ``(B, L, Hq, v_dim)``. The call is
-    named ``latent_paged_attention`` in a device trace.
+    named ``latent_paged_attention`` in a device trace, the K+V build's
+    ``paged_attention``.
 
     q:            (B, L, Hq, dh) new (rope'd) query rows per slot; the new
                   tokens' K/V are already in the pool
@@ -898,7 +899,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
             kv_itemsize=k_pool.dtype.itemsize, kv_scales=quant,
             L=L, q_tile=q_tile),
         interpret=interpret,
-        name="latent_paged_attention" if latent else None,
+        # What the device trace calls the kernel's events.
+        name="latent_paged_attention" if latent else "paged_attention",
     )(block_tables, kv_lens, q_lens, layer, qh, *arenas)
     o = outs[0] if probes else outs
     if not folded:

@@ -2,10 +2,12 @@
 
 One substrate, three views, threaded through every layer of the stack:
 
-  obs.trace        host-side span tracer (nested spans, monotonic + wall
-                   time, per-process ring buffer). Spans emit
-                   ``jax.profiler.TraceAnnotation`` scopes so they land
-                   inside XProf captures; the ring buffer exports merged
+  obs.trace        host-side span tracer (nested spans on
+                   ``time.monotonic()``, per-process ring buffer; records
+                   while enabled or while a profiler capture is live).
+                   Spans emit ``jax.profiler.TraceAnnotation`` scopes with
+                   their attributes so they land inside XProf captures;
+                   the ring buffer exports merged
                    per-rank Chrome trace-event JSON for Perfetto. Also owns
                    ``group_profile`` (the XProf capture context re-exported
                    via ``runtime/utils.py``).

@@ -44,7 +44,7 @@ fleet-level windowed percentiles (``journey.queue_frac_p99`` ...);
 ``export_chrome_trace`` writes ``trace.p{rank}.journey.json`` — matched
 by ``merge_chrome_traces``'s ``trace.p*.json`` glob, so journey rows land
 next to the host-span and device-probe rows in ``trace.merged.json``.
-Same timebase as the host tracer (``time.perf_counter``), so the rows
+Same timebase as the host tracer (``time.monotonic``), so the rows
 align. ``tools/explain_request.py`` renders one journey as a forensic
 markdown report. Design note: docs/observability.md.
 """
@@ -319,12 +319,12 @@ class JourneyRecorder:
     One recorder per serving plant: a standalone ``BatchEngine`` owns one;
     a ``Fleet`` owns one SHARED across its replicas so cross-replica
     requeues stay one journey. Same timebase as the host tracer
-    (``time.perf_counter``) so exported Chrome rows align; tests and the
+    (``time.monotonic``) so exported Chrome rows align; tests and the
     deterministic ``explain_request --chaos`` demo swap ``clock`` for a
     virtual step counter, which makes every timestamp — and therefore the
     whole report — reproducible byte-for-byte."""
 
-    def __init__(self, *, clock=time.perf_counter, keep: int = 256,
+    def __init__(self, *, clock=time.monotonic, keep: int = 256,
                  summary_cap: int = 1024, max_events: int = 256,
                  global_cap: int = 512, max_pending: int = 4096,
                  slowest_k: int = 16):

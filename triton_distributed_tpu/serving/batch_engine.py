@@ -187,6 +187,11 @@ class _Step:
     eff: tuple | None   # what the efficiency ledger is told of it
     verify: dict    # speculation: slot -> (draft tokens, row of ``greedy``)
 
+    @property
+    def kind(self) -> str:
+        """"decode" | "mixed"."""
+        return self.span.removesuffix("_step")
+
 
 class BatchEngine:
     """Continuous-batching server over an ``Engine``'s model/params/mesh.
@@ -1628,26 +1633,57 @@ class BatchEngine:
             self.metrics.inc("pipeline_flushes", labels={"reason": reason})
             self._retire(st, flush=reason)
 
+    def _moved(self, before: dict, **names) -> dict:
+        """How far counters moved since ``before`` (a copy of
+        ``metrics.counters``), as ``attribute=counter``: what a trace span
+        says of its phase, with no second count kept beside the counter."""
+        c = self.metrics.counters
+        return {attr: int(c.get(name, 0.0) - before.get(name, 0.0))
+                for attr, name in names.items()}
+
     def step(self) -> bool:
         """One scheduler iteration: admit, dispatch one compiled step,
         then read the step dispatched by the call before (the one just
         dispatched, where ``_serial_reason`` holds). A token is therefore
         visible in the call AFTER the one that dispatched it. Returns
-        False when there is nothing to do (idle)."""
+        False when there is nothing to do (idle).
+
+        While the tracer records (``obs.trace``: enabled, or a profiler
+        capture live) the call is the span ``engine.step`` and its phases
+        are ``engine.admit``, ``engine.blocks``, ``engine.observe``,
+        ``engine.dispatch``, the wait for the tokens (``decode_step`` /
+        ``mixed_step``) and ``engine.retire`` (``sp`` and ``phase`` below
+        are None when it does not, and nothing is counted for them)."""
+        with _trace.span("engine.step") as sp:
+            return self._step(sp)
+
+    def _step(self, sp) -> bool:
         serial = self._serial_reason()
+        if sp is not None and serial:
+            sp.set(serial=serial)
         if serial and self._inflight is not None:
             # The condition came up between two calls: this call only
             # reads, so that no call ever moves two steps' counters.
+            if sp is not None:
+                sp.set(dispatched="none", read=self._inflight.kind)
             self.flush(serial)
             return True
-        if self._inflight is not None:
-            # A request whose last token is in flight has ended BY COUNT:
-            # its slot and blocks are free for this call's admission, as
-            # they would be had the step been read (``_release_slot``).
-            for r in self._inflight.rows:
-                if r.slot.ended and self._slots[r.idx] is r.slot:
-                    self._release_slot(r.idx)
-        self._admit()
+        with _trace.span("engine.admit") as phase:
+            c0 = phase and dict(self.metrics.counters)
+            released = 0
+            if self._inflight is not None:
+                # A request whose last token is in flight has ended BY
+                # COUNT: its slot and blocks are free for this call's
+                # admission, as they would be had the step been read
+                # (``_release_slot``).
+                for r in self._inflight.rows:
+                    if r.slot.ended and self._slots[r.idx] is r.slot:
+                        self._release_slot(r.idx)
+                        released += 1
+            self._admit()
+            if phase is not None:
+                phase.set(waiting=len(self.scheduler), released=released,
+                          **self._moved(c0, admitted="requests_admitted"))
         self._proposals = self._draft() if self.spec is not None else {}
         # Decode rows write one token this step — make room first (prefill
         # rows were fully funded at admission). A slot with draft
@@ -1655,38 +1691,45 @@ class BatchEngine:
         # NEVER preempts a neighbor for that — if the wider allocation
         # doesn't fit, the proposal is dropped and the slot falls back to
         # the plain one-token path.
-        for i in range(self.n_slots):
-            s = self._slots[i]
-            if s is None or s.prefilling:
-                continue
-            props = self._proposals.get(i)
-            if props:
-                try:
-                    ok = self._ensure_blocks(
-                        s.req.req_id, s.offset + 1 + len(props))
-                except _faults.TransientFault:
-                    ok = False
-                if ok:
+        with _trace.span("engine.blocks") as phase:
+            c0 = phase and dict(self.metrics.counters)
+            for i in range(self.n_slots):
+                s = self._slots[i]
+                if s is None or s.prefilling:
                     continue
-                del self._proposals[i]
-                self.metrics.inc("spec_drafts_dropped")
-            self._ensure_or_preempt(i)
+                props = self._proposals.get(i)
+                if props:
+                    try:
+                        ok = self._ensure_blocks(
+                            s.req.req_id, s.offset + 1 + len(props))
+                    except _faults.TransientFault:
+                        ok = False
+                    if ok:
+                        continue
+                    del self._proposals[i]
+                    self.metrics.inc("spec_drafts_dropped")
+                self._ensure_or_preempt(i)
+            if phase is not None:
+                phase.set(**self._moved(
+                    c0, preempted="preemptions",
+                    drafts_dropped="spec_drafts_dropped"))
         live = [i for i, s in enumerate(self._slots) if s is not None]
-        self.metrics.set_gauge("queue_depth", len(self.scheduler))
-        self.metrics.set_gauge("active_slots", len(live))
-        self.metrics.set_gauge("pool_free_blocks", self.pool.n_free)
-        self.metrics.set_gauge("pool_reclaimable_blocks",
-                               self.pool.n_reclaimable)
-        self.metrics.set_gauge("pool_occupancy",
-                               self.pool.n_used / self.pool.n_blocks)
-        # SLO evaluation + stats stream run even on idle iterations — an
-        # engine starved by a fault is exactly when the SLO must keep
-        # evaluating. Same for the incident detectors: a stall shows up
-        # as signals going quiet, not as a step that runs.
-        self._obs_tick()
-        self._incident_tick(busy=bool(live))
-        if self._controller is not None:
-            self._controller.on_step()
+        with _trace.span("engine.observe"):
+            self.metrics.set_gauge("queue_depth", len(self.scheduler))
+            self.metrics.set_gauge("active_slots", len(live))
+            self.metrics.set_gauge("pool_free_blocks", self.pool.n_free)
+            self.metrics.set_gauge("pool_reclaimable_blocks",
+                                   self.pool.n_reclaimable)
+            self.metrics.set_gauge("pool_occupancy",
+                                   self.pool.n_used / self.pool.n_blocks)
+            # SLO evaluation + stats stream run even on idle iterations —
+            # an engine starved by a fault is exactly when the SLO must
+            # keep evaluating. Same for the incident detectors: a stall
+            # shows up as signals going quiet, not as a step that runs.
+            self._obs_tick()
+            self._incident_tick(busy=bool(live))
+            if self._controller is not None:
+                self._controller.on_step()
         if not live and self._inflight is None:
             return False
         # Draft proposals ride the mixed step (ragged verify rows need
@@ -1703,16 +1746,27 @@ class BatchEngine:
                     else self._watchdog.deadline("serving_step",
                                                  self._step_deadline_s))
         with deadline:
-            before = self._inflight
+            before, st = self._inflight, None
             if not live:
                 self.flush("idle")      # nothing to dispatch behind it
             else:
-                self._inflight = run(live)
+                with _trace.span("engine.dispatch") as phase:
+                    self._inflight = st = run(live)
+                    if phase is not None:
+                        phase.set(
+                            kind=st.kind, overlapped=st.attrs["overlapped"],
+                            prefill_rows=st.attrs.get("prefill_rows", 0),
+                            **{name: n for name, n in st.counts.items()
+                               if not name.endswith("_steps")})
                 if before is not None:
                     self.metrics.inc("steps_overlapped")
                     self._retire(before)
                 elif serial:
                     self.flush(serial)
+            if sp is not None:
+                read = before or (st if serial else None)
+                sp.set(dispatched=st.kind if st else "none",
+                       read=read.kind if read else "none")
         if self._heartbeat is not None:
             self._heartbeat.beat()
         return True
@@ -1832,6 +1886,7 @@ class BatchEngine:
                      rows=rows, counts=counts, eff=eff,
                      verify=dict(verify), attrs={
                          **attrs, "active": len(live),
+                         "decode_rows": counts["decode_rows"],
                          "overlapped": self._inflight is not None})
 
     def _run_decode(self, live) -> _Step:
@@ -1932,30 +1987,35 @@ class BatchEngine:
                 self._unwind(st)
                 raise
             nxt = self._take_stats(nxt, sp)
-        self._eff_end(st.eff)
-        for name, n in st.counts.items():
-            self.metrics.inc(name, n)
-        if self._guarding:
-            self._guard_rows(st.finite, [r.idx for r in st.rows])
-        for i, s, _, _, emits, first in st.rows:
-            if s.req.status == "failed":
-                continue            # quarantined by the guard just above
-            s.in_flight -= emits
-            if i in st.verify:
-                props, k = st.verify[i]
-                self._accept_row(i, s, props, greedy[k], int(nxt[i]))
-                continue
-            if not emits:
-                continue            # still mid-prompt; logits row is interim
-            if first and self.journey is not None:
-                # This residency's prefill just completed: the journey
-                # phase flips to decode at the first emitted token.
-                self.journey.event(s.req.req_id, "decode_start")
-            self._record_token(s, int(nxt[i]))
-            if s.req.remaining_new == 0:
-                if self._slots[i] is s:     # else ``step()`` freed it
-                    self._release_slot(i)
-                self._complete(s)
+        with _trace.span("engine.retire") as phase:
+            c0 = phase and dict(self.metrics.counters)
+            self._eff_end(st.eff)
+            for name, n in st.counts.items():
+                self.metrics.inc(name, n)
+            if self._guarding:
+                self._guard_rows(st.finite, [r.idx for r in st.rows])
+            for i, s, _, _, emits, first in st.rows:
+                if s.req.status == "failed":
+                    continue        # quarantined by the guard just above
+                s.in_flight -= emits
+                if i in st.verify:
+                    props, k = st.verify[i]
+                    self._accept_row(i, s, props, greedy[k], int(nxt[i]))
+                    continue
+                if not emits:
+                    continue        # still mid-prompt; logits row is interim
+                if first and self.journey is not None:
+                    # This residency's prefill just completed: the journey
+                    # phase flips to decode at the first emitted token.
+                    self.journey.event(s.req.req_id, "decode_start")
+                self._record_token(s, int(nxt[i]))
+                if s.req.remaining_new == 0:
+                    if self._slots[i] is s:     # else ``step()`` freed it
+                        self._release_slot(i)
+                    self._complete(s)
+            if phase is not None:
+                phase.set(**self._moved(c0, tokens="tokens_generated",
+                                        finished="requests_completed"))
 
     def _unwind(self, st: _Step) -> None:
         """The device failed under a dispatched step (the error comes up

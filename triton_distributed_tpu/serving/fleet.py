@@ -937,24 +937,34 @@ class Fleet:
     def step(self) -> bool:
         """One fleet iteration: health poll -> drain/teardown -> route ->
         step every routable replica. Returns False when nothing happened
-        (fleet idle)."""
-        self.n_steps += 1
-        self._update_health()
-        if self.incidents is not None:
-            fm = self.metrics.as_dict()
-            self.incidents.observe({
-                "quarantines": fm.get("replica_quarantines", 0.0),
-                "requeues": fm.get("requeues", 0.0),
-                "requests_failed": fm.get("requests_failed", 0.0),
-            })
-        if self._controller is not None:
-            self._controller.on_step()
-        moved = self._drain()
-        routed = self._route_pending()
-        busy = self._step_replicas()
-        if self.serve_trace is not None:
-            self.serve_trace.on_step(self)
-        return moved or routed or busy
+        (fleet idle). While the tracer records, the call is the span
+        ``fleet.step``; ``fleet.route`` and each replica's ``engine.step``
+        lie inside it, and what is left is the fleet's own turn (health,
+        incidents, controller, drain, ``serve_trace``)."""
+        with _trace.span("fleet.step", replicas=len(self.replicas),
+                         pending=len(self._pending)):
+            self.n_steps += 1
+            self._update_health()
+            if self.incidents is not None:
+                fm = self.metrics.as_dict()
+                self.incidents.observe({
+                    "quarantines": fm.get("replica_quarantines", 0.0),
+                    "requeues": fm.get("requeues", 0.0),
+                    "requests_failed": fm.get("requests_failed", 0.0),
+                })
+            if self._controller is not None:
+                self._controller.on_step()
+            moved = self._drain()
+            with _trace.span("fleet.route") as sp:
+                c = self.metrics.counters
+                n0 = sp and c.get("requests_routed", 0.0)
+                routed = self._route_pending()
+                if sp is not None:
+                    sp.set(routed=int(c.get("requests_routed", 0.0) - n0))
+            busy = self._step_replicas()
+            if self.serve_trace is not None:
+                self.serve_trace.on_step(self)
+            return moved or routed or busy
 
     def run(self, max_steps: int | None = None) -> dict:
         """Step until idle (or ``max_steps``); returns ``{req_id:
@@ -1005,6 +1015,14 @@ class Fleet:
     @property
     def pending(self) -> list[Request]:
         return list(self._pending)
+
+    def request(self, req_id) -> Request:
+        """The handle a caller keeps for a request it submitted: the
+        ``Request`` itself, whose ``output`` grows as tokens are read,
+        ``status`` ends as "ok" or "failed" and ``finish_t`` is set when it
+        has finished, wherever it is (pending, on a replica, requeued,
+        done). Raises ``KeyError`` for an id this fleet was never given."""
+        return self._submitted[req_id]
 
     def requeue_chain(self, req_id) -> list[str]:
         """The displacement reason chain recorded for ``req_id`` (empty if
