@@ -201,12 +201,17 @@ def paged_logits(be, prompts, next_tok):
             take = min(chunk, plen - off)
             tok = np.zeros((n,), np.int32)
             block = np.zeros((be.prefill_rows, chunk), np.int32)
+            # the block's rows as the host deals them: (slot, cache length
+            # before the row, live tokens), a dead row naming no slot
+            dealt = np.tile(np.int32([-1, 0, 0]), (be.prefill_rows, 1))
             if take == 1:        # one token rides the decode block
                 tok[:n_p] = toks[:, off]
             else:
                 block[:n_p, :take] = toks[:, off:off + take]
+                dealt[:n_p] = [(i, off, take) for i in range(n_p)]
             pre_logits, _, pool.state = pre(
-                eng.params, (jnp.asarray(tok), jnp.asarray(block)),
+                eng.params,
+                (jnp.asarray(tok), jnp.asarray(block), jnp.asarray(dealt)),
                 pool.state,
                 jnp.asarray(np.where(live, off, 0).astype(np.int32)),
                 tables, mask,

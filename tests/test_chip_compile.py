@@ -310,7 +310,8 @@ def test_hybrid_step_compiles_with_its_state_in_place(topo, kind):
         args = (_sds((HYB_SLOTS, 1), jnp.int32, here), state, *slots)
     else:
         ids = (_sds((HYB_SLOTS,), jnp.int32, here),
-               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here))
+               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, 3), jnp.int32, here))
         args = (ids, state, *slots, _sds((HYB_SLOTS,), jnp.int32, here))
     compiled = step.lower(params, *args).compile()
     text = compiled.as_text()
@@ -370,7 +371,8 @@ def test_nemotron_step_compiles_with_its_state_in_place(topo, monkeypatch,
         args = (_sds((HYB_SLOTS, 1), jnp.int32, here), state, *slots)
     else:
         ids = (_sds((HYB_SLOTS,), jnp.int32, here),
-               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here))
+               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, 3), jnp.int32, here))
         args = (ids, state, *slots, _sds((HYB_SLOTS,), jnp.int32, here))
     compiled = step.lower(params, *args).compile()
     text = compiled.as_text()

@@ -137,8 +137,12 @@ def logits_of_a_staggered_batch(engine, decode: bool = True):
         for k, row in enumerate(chunk_rows):
             chunk[k, :len(row)] = row
         live = jnp.asarray([n > 0 for n in lens])
+        # one row a slot that takes more than a token, in slot order
+        dealt = [[i, off[i], n] for i, n in enumerate(lens) if n > 1]
+        dealt += [[-1, 0, 0]] * (2 - len(dealt))
         return pre(engine.params,
-                   (jnp.asarray(tok, jnp.int32), jnp.asarray(chunk)), state,
+                   (jnp.asarray(tok, jnp.int32), jnp.asarray(chunk),
+                    jnp.asarray(dealt, jnp.int32)), state,
                    jnp.asarray(off, jnp.int32), tables, live,
                    jnp.asarray(lens, jnp.int32))
 
@@ -330,6 +334,38 @@ def test_the_state_update_kernel_equals_plain_jnp(groups, tile):
     np.testing.assert_array_equal(got[1, 2], arena[1, 2])
     np.testing.assert_array_equal(got[0], arena[0])
     np.testing.assert_array_equal(got[2], arena[2])
+
+
+def test_a_model_with_per_slot_state_keeps_one_row_a_slot(served):
+    """The host deals the prefill block's rows, and a prompt alone would
+    take every free one; not here: every row of a slot starts from the
+    arena's state, so two rows of one slot would both start from the same
+    state. Two prompts into a block of four rows take ONE row each a step
+    (8, 8, 4 and 8, 4), as before the deal, and give what each gives alone."""
+    batch_engine(served)            # the donor of the steps, never wrapped
+    be = batch_engine(served)
+    assert be.prefill_rows == 4 and be.pool.slot_state
+    calls, step = [], be._mixed_step
+
+    def recording(*args):
+        calls.append((np.asarray(args[1][2]).tolist(),
+                      np.asarray(args[6]).tolist()))
+        return step(*args)
+
+    be._mixed_step = recording
+    a, b = prompts(41, 20, 12)
+    rids = [be.submit(a, 3), be.submit(b, 3)]
+    be.run()
+    dead = [-1, 0, 0]
+    assert calls[:3] == [
+        ([[0, 0, 8], [1, 0, 8], dead, dead], [8, 8, 0, 0]),
+        ([[0, 8, 8], [1, 8, 4], dead, dead], [8, 4, 0, 0]),
+        ([[0, 16, 4], dead, dead, dead], [4, 1, 0, 0])]
+    c = be.metrics.counters
+    assert c["prefill_rows_filled"] == 5 and c["prefill_rows_extra"] == 0
+    assert c["prefill_steps"] == 3 and c["prefill_tokens"] == 32
+    for rid, prompt in zip(rids, (a, b)):
+        assert be.finished[rid].output == alone(served, prompt, 3)
 
 
 def test_a_slot_reused_by_a_second_request_gives_what_that_request_gives_alone(

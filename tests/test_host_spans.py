@@ -31,6 +31,9 @@ ATTRS = {
     "engine.dispatch": {"kind", "decode_rows", "prefill_rows", "overlapped"},
     "engine.retire": {"tokens", "finished"},
 }
+# what a mixed step's plan adds to ``engine.dispatch`` and ``mixed_step``
+MIXED_PLAN = {"prefill_rows", "mixed_step_tokens", "prefill_rows_filled",
+              "prefill_rows_extra", "prefill_rows_deferred"}
 
 
 @pytest.fixture(scope="module")
@@ -90,7 +93,7 @@ def test_off_a_step_records_nothing_and_hooks_nothing(fleet):
 
 
 def test_one_fleet_step_yields_the_tables_spans_nested(fleet):
-    serve_some(fleet, 3)        # two chunks of the prompt read, one in flight
+    serve_some(fleet, 3)        # the prompt read (two rows of one step)
     with trace.tracing() as tracer:
         tracer.reset()
         fleet.step()
@@ -139,13 +142,21 @@ def test_a_mixed_step_says_what_it_dispatched_and_admitted(fleet):
                                      "released": 0}
     assert first["engine.step"] == {"dispatched": "mixed", "read": "none"}
     assert "mixed_step" not in first and "engine.retire" not in first
+    # 19 tokens through a block of 4 rows of 8: one request, three rows
     d = first["engine.dispatch"]
+    assert MIXED_PLAN | {"prefill_tokens"} <= set(d)
     assert (d["kind"], d["prefill_rows"], d["prefill_tokens"],
             d["decode_rows"], d["mixed_step_tokens"],
+            d["prefill_rows_filled"], d["prefill_rows_extra"],
             d["prefill_rows_deferred"], d["overlapped"]) == (
-                "mixed", 1, 8, 0, 8, 0, False)
-    assert second["engine.step"] == {"dispatched": "mixed", "read": "mixed"}
-    assert second["mixed_step"]["decode_rows"] == 0
+                "mixed", 1, 19, 0, 19, 3, 2, 0, False)
+    # the prompt went in one step: the next call dispatches its first
+    # decode step and reads the mixed one
+    assert second["engine.step"] == {"dispatched": "decode", "read": "mixed"}
+    m = second["mixed_step"]
+    assert MIXED_PLAN <= set(m)
+    assert (m["decode_rows"], m["prefill_rows_filled"],
+            m["prefill_rows_extra"]) == (0, 3, 2)
     assert second["engine.dispatch"]["overlapped"] is True
 
 

@@ -694,13 +694,14 @@ def test_deferral_is_oldest_admitted_first_and_counted(
 @pytest.mark.parametrize("model,paged_attn", [
     ("qwen", "gather"), ("qwen", "fused"), ("latent", "gather")])
 def test_a_last_take_of_one_token_rides_the_decode_block(
-        setup, latent_engine, model, paged_attn):
-    """A prompt one token longer than a chunk: its second take is ONE
-    token, which needs no row of the prefill block — the step that serves
-    it carries an empty block — and the stream is the one served by whole
-    chunks of another width."""
+        setup, latent_engine, monkeypatch, model, paged_attn):
+    """A prompt one token longer than a chunk through a block of ONE row:
+    its second take is ONE token, which needs no row of the prefill block
+    — the step that serves it carries an empty block — and the stream is
+    the one served by whole chunks of another width."""
     engine = latent_engine if model == "latent" else setup[2]
     chunk = 8
+    _small_block(monkeypatch, 2, chunk, 1)
     kw = dict(n_slots=2, block_size=4, paged_attn=paged_attn)
     prompt = np.random.default_rng(17).integers(
         0, engine.config.vocab_size, size=chunk + 1).tolist()
@@ -708,11 +709,13 @@ def test_a_last_take_of_one_token_rides_the_decode_block(
     calls = _recording(be)
     rid = be.submit(prompt, max_new_tokens=3)
     out = be.run(max_steps=50)[rid]
-    (_, chunk0), _, _, _, sl0 = calls[0]
-    (tok1, chunk1), offsets, _, _, sl1 = calls[1]
+    (_, chunk0, dealt0), _, _, _, sl0 = calls[0]
+    (tok1, chunk1, dealt1), offsets, _, _, sl1 = calls[1]
     assert sl0.tolist() == [chunk, 0] and chunk0[0].tolist() == prompt[:chunk]
+    assert dealt0.tolist() == [[0, 0, chunk]]
     assert sl1.tolist() == [1, 0] and offsets[0] == chunk
     assert tok1[0] == prompt[-1] and not chunk1.any()
+    assert dealt1.tolist() == [[-1, 0, 0]]
     assert len(calls) == 2 and be.metrics.counters["prefill_tokens"] == 9
     other, = _one_at_a_time(engine, [prompt], 3, prefill_chunk=chunk + 4,
                             **kw)
@@ -721,14 +724,17 @@ def test_a_last_take_of_one_token_rides_the_decode_block(
 
 @pytest.mark.parametrize("fmt", ["bf16", "int8", "latent"])
 @pytest.mark.parametrize("paged_attn", ["fused", "gather"])
+@pytest.mark.parametrize("rows_a_slot", [1, 2])
 def test_two_blocks_equal_the_dense_block(setup, latent_engine, paged_attn,
-                                          fmt):
+                                          fmt, rows_a_slot):
     """One hand-made mixed step both ways, on a pool full of data: as the
-    dense (slots, L) block and as the pair (one token a slot, a block of
-    two rows of L for the slots that take more). The same rows are
-    appended, bit for bit in the first layer, the rest of the pool is the
-    input's, and the live slots' logits agree; a slot that takes nothing
-    and a dead slot with stale tables write nothing."""
+    dense (slots, L) block and as the two blocks (one token a slot, a block
+    of rows the host has dealt to the slots that take more): the one row of
+    L for slot 0 or, ``rows_a_slot`` 2, TWO rows of L / 2, consecutive
+    chunks of the same sequence in one step. The same rows are appended,
+    bit for bit in the first layer, the rest of the pool is the input's,
+    and the live slots' logits agree; a slot that takes nothing and a dead
+    slot with stale tables write nothing."""
     engine = latent_engine if fmt == "latent" else setup[2]
     sm, args, written = _paged_step(engine, "prefill", paged_attn, fmt)
     ids, seq_lens = np.asarray(args[1]), np.asarray([4, 1, 3, 0], np.int32)
@@ -739,10 +745,17 @@ def test_two_blocks_equal_the_dense_block(setup, latent_engine, paged_attn,
                for b in range(4) if mask[b] for l in range(seq_lens[b])}
     args[5], args[6] = jnp.asarray(mask), jnp.asarray(seq_lens)
     dense_logits, _, dense = jax.jit(sm)(*args)
-    chunk = np.zeros((2, ids.shape[1]), np.int32)
-    chunk[0] = ids[0]                  # slot 0: the one row that takes > 1
-    pair = (jnp.asarray(ids[:, 0]), jnp.asarray(chunk))
-    logits, _, state = jax.jit(sm)(args[0], pair, *args[2:])
+    # slot 0 is the one slot that takes more than a token: its 4 tokens
+    # as rows of ``width``, beside a dead row and (to be refused by the
+    # mask) a row dealt to the dead slot 2
+    width = ids.shape[1] // rows_a_slot
+    chunk = np.zeros((rows_a_slot + 2, width), np.int32)
+    chunk[:rows_a_slot] = ids[0].reshape(rows_a_slot, width)
+    dealt = [[0, offsets[0] + j * width, width] for j in range(rows_a_slot)]
+    dealt += [[-1, 0, 0], [2, offsets[2], width]]
+    three = (jnp.asarray(ids[:, 0]), jnp.asarray(chunk),
+             jnp.asarray(dealt, jnp.int32))
+    logits, _, state = jax.jit(sm)(args[0], three, *args[2:])
     assert jax.tree.structure(state) == jax.tree.structure(args[2])
     for before, d, t in zip(*map(jax.tree.leaves, (args[2], dense, state))):
         before, d, t = map(np.asarray, (before, d, t))
@@ -788,6 +801,231 @@ def test_mixed_step_keeps_no_pool_sized_temporary(setup, latent_engine, fmt):
                                         if a.ndim == leaves[0].ndim) // 4
 
 
+# -- 7. the host deals the prefill block's rows -------------------------------
+# A prompt takes every free row of the block: the rows are dealt by the host
+# as (slot, cache length before the row, live tokens) and several may be
+# consecutive chunks of ONE sequence (``BatchEngine._run_mixed``,
+# ``nn.paged_token_blocks``).
+
+_DEAL_CASES = pytest.mark.parametrize("model,paged_attn", [
+    ("qwen", "gather"), ("qwen", "fused"), ("latent", "gather")])
+
+
+def _dealt_engine(monkeypatch, engine, paged_attn, rows, n_slots=4, chunk=8):
+    """An engine whose prefill block has ``rows`` rows, and the host-side
+    operands of every mixed step it dispatches."""
+    _small_block(monkeypatch, n_slots, chunk, rows)
+    be = BatchEngine(engine, n_slots=n_slots, block_size=4,
+                     prefill_chunk=chunk, paged_attn=paged_attn)
+    assert be.prefill_rows == rows
+    return be, _recording(be)
+
+
+def test_the_blocks_rows_come_from_the_host():
+    """``nn.paged_token_blocks`` alone, two-block form: two rows of one
+    slot get offsets ``o`` and ``o + L``, the slot's table twice, and
+    ``last`` on the second row's last live token; a slot of one row and a
+    decode row beside them; a dead row names no slot and a row dealt to a
+    masked slot is dead."""
+    from triton_distributed_tpu.layers import nn
+
+    B, P, L = 4, 5, 4
+    rng = np.random.default_rng(3)
+    tables = rng.permutation(B * 6).reshape(B, 6).astype(np.int32)
+    offsets = np.asarray([0, 5, 7, 2], np.int32)
+    seq_lens = np.asarray([3, 6, 1, 4], np.int32)
+    mask = np.asarray([True, True, True, False])
+    dealt = np.asarray([[1, 5, 4], [1, 9, 2], [-1, 0, 0], [0, 0, 3],
+                        [3, 2, 4]], np.int32)
+    tok = np.arange(B, dtype=np.int32) + 100
+    chunk = np.arange(P * L, dtype=np.int32).reshape(P, L)
+    flat, (dec, pre), last = nn.paged_token_blocks(
+        (tok, chunk, dealt), offsets, tables, mask, seq_lens, multiple=8)
+    assert flat.shape == (24,) and flat[:B + P * L].tolist() == [
+        *tok, *chunk.reshape(-1)]
+    assert (dec.start, dec.L, pre.start, pre.L) == (0, 1, B, L)
+    assert dec.mask.tolist() == [False, False, True, False]
+    assert dec.offsets.tolist() == [0, 0, 7, 0]
+    np.testing.assert_array_equal(dec.tables, tables)
+    live = [True, True, False, True, False]
+    assert pre.mask.tolist() == live
+    assert pre.offsets.tolist() == [5, 9, 0, 0, 0]
+    assert pre.seq_lens.tolist() == [4, 2, 0, 3, 0]
+    assert np.asarray(pre.slots)[live].tolist() == [1, 1, 0]
+    np.testing.assert_array_equal(np.asarray(pre.tables)[live],
+                                  tables[[1, 1, 0]])
+    # slot 1's last live token ends its SECOND row; slot 0's its one row;
+    # the decode row's and the masked slot's are their own positions
+    assert last.tolist() == [B + 3 * L + 2, B + 1 * L + 1, 2, 3]
+    assert pre.valid().reshape(P, L).sum(axis=1).tolist() == [4, 2, 0, 3, 0]
+    with pytest.raises(ValueError):
+        nn.paged_token_blocks((tok, chunk), offsets, tables, mask, seq_lens)
+
+
+@_DEAL_CASES
+def test_a_prompt_takes_every_free_row_of_the_block(
+        setup, latent_engine, monkeypatch, model, paged_attn):
+    """A prompt of 20 tokens at chunks of 8: through a block of ONE row it
+    takes 8, 8, 4 in three mixed steps; through a block of THREE rows it
+    takes all 20 in one, as rows (0, 8), (8, 8), (16, 4) of the one slot.
+    Token for token the same stream, and the golden's."""
+    engine = latent_engine if model == "latent" else setup[2]
+    prompt = np.random.default_rng(19).integers(
+        0, engine.config.vocab_size, size=20).tolist()
+    outs, takes, steps = {}, {}, {}
+    for rows in (1, 3):
+        be, calls = _dealt_engine(monkeypatch, engine, paged_attn, rows)
+        rid = be.submit(prompt, max_new_tokens=4)
+        outs[rows] = be.run(max_steps=50)[rid]
+        takes[rows] = [int(sl[0]) for *_, sl in calls]
+        steps[rows] = dict(be.metrics.counters)
+        assert be.trace_counts == {"decode": 1, "prefill": 1}
+        be.pool.check_invariants()
+    assert takes == {1: [8, 8, 4], 3: [20]}
+    assert outs[3] == outs[1] and len(outs[3]) == 4
+    if model == "qwen":
+        np.testing.assert_array_equal(np.asarray(outs[3], np.int32),
+                                      _golden(engine, prompt, 4))
+    (_, chunk, dealt), offsets, _, _, sl = calls[0]
+    assert dealt.tolist() == [[0, 0, 8], [0, 8, 8], [0, 16, 4]]
+    assert chunk.reshape(-1)[:20].tolist() == prompt
+    assert not offsets.any() and sl.tolist() == [20, 0, 0, 0]
+    for rows, filled, extra in ((1, 3, 0), (3, 3, 2)):
+        c = steps[rows]
+        assert c["prefill_steps"] == len(takes[rows])
+        assert c["prefill_tokens"] == c["mixed_step_tokens"] == 20
+        assert (c["prefill_rows_filled"], c["prefill_rows_extra"],
+                c["prefill_rows_deferred"]) == (filled, extra, 0)
+
+
+@_DEAL_CASES
+def test_the_deal_is_one_row_each_then_the_free_rows_to_the_oldest(
+        setup, latent_engine, monkeypatch, model, paged_attn):
+    """Three prompts admitted together into a block of FOUR rows: one row
+    each first, and the fourth goes to the oldest that can fill it; no
+    request's take is ever below the one row it took before; into a block
+    of TWO rows the same prompts are dealt as they were (the two oldest a
+    row each, the third waits and is counted). The counters and the
+    ``mixed_step`` span say what happened, and every stream is the one
+    the request gives alone."""
+    from triton_distributed_tpu.obs import trace as _trace
+
+    engine = latent_engine if model == "latent" else setup[2]
+    rng = np.random.default_rng(23)
+    lens, chunk = (28, 12, 10), 8
+    prompts = [rng.integers(0, engine.config.vocab_size, size=n).tolist()
+               for n in lens]
+    want = _one_at_a_time(engine, prompts, 3, n_slots=4, block_size=4,
+                          prefill_chunk=chunk, paged_attn="gather")
+    plans = {
+        # rows: (takes a step, rows filled / extra / deferred a step)
+        4: ([[16, 8, 8], [12, 4, 2]], [(4, 1, 0), (4, 1, 0)]),
+        2: ([[8, 8, 0], [8, 4, 0], [8, 1, 8], [4, 1, 2]],
+            [(2, 0, 1), (2, 0, 1), (2, 0, 0), (2, 0, 0)]),
+    }
+    for rows, (takes, filled) in plans.items():
+        be, calls = _dealt_engine(monkeypatch, engine, paged_attn, rows)
+        rids = [be.submit(p, max_new_tokens=3) for p in prompts]
+        with _trace.tracing() as tracer:
+            tracer.reset()
+            out = be.run(max_steps=100)
+            spans = [r.attrs for r in tracer.records
+                     if r.name == "mixed_step"]
+        assert [out[r] for r in rids] == want
+        got = np.stack([sl for *_, sl in calls])
+        assert got[:len(takes), :3].tolist() == takes and not got[:, 3].any()
+        # never less than the one (narrowed) row a request took before
+        left = np.asarray(lens)
+        for step in got[:, :3]:
+            served = step > 0
+            assert (step[served] >= np.minimum(chunk, left)[served]).all()
+            left = np.maximum(left - step, 0)
+        assert [(a["prefill_rows_filled"], a["prefill_rows_extra"],
+                 a["prefill_rows_deferred"])
+                for a in spans[:len(filled)]] == filled
+        c = be.metrics.counters
+        for k, name in enumerate(("prefill_rows_filled",
+                                  "prefill_rows_extra",
+                                  "prefill_rows_deferred")):
+            assert c[name] == sum(f[k] for f in filled), name
+        assert c["prefill_tokens"] == sum(lens)
+        assert c["mixed_step_tokens"] == got.sum()
+        assert c["prefill_steps"] == len(calls) == len(spans)
+        snap = be.stats_snapshot()["prefill_block"]
+        assert snap["rows"] == rows
+        assert snap["prefill_rows_extra"] == c["prefill_rows_extra"]
+        if rows == 4:
+            dealt = [ids[2].tolist() for ids, *_ in calls[:2]]
+            assert dealt == [
+                [[0, 0, 8], [0, 8, 8], [1, 0, 8], [2, 0, 8]],
+                [[0, 16, 8], [0, 24, 4], [1, 8, 4], [2, 8, 2]]]
+        be.pool.check_invariants()
+
+
+@_DEAL_CASES
+def test_a_take_of_several_rows_is_taken_back_whole(
+        setup, latent_engine, monkeypatch, model, paged_attn):
+    """In the middle of a prompt that takes three rows a step: a read that
+    fails takes the host's count back by the WHOLE take of both steps in
+    flight (``_unwind``), and an eviction requeues the request to prefill
+    from the start; either way the stream is the undisturbed one."""
+    engine = latent_engine if model == "latent" else setup[2]
+    prompt = np.random.default_rng(29).integers(
+        0, engine.config.vocab_size, size=28).tolist()
+    calm, _ = _dealt_engine(monkeypatch, engine, "gather", 3, chunk=4)
+    rid = calm.submit(prompt, max_new_tokens=3)
+    want = calm.run(max_steps=50)[rid]
+
+    be, calls = _dealt_engine(monkeypatch, engine, paged_attn, 3, chunk=4)
+    rid = be.submit(prompt, max_new_tokens=3)
+    be.step()                                   # 12 tokens in flight
+    assert be._slots[0].offset == 12 and be._inflight.rows[0].written == 12
+
+    def lost(x):
+        raise RuntimeError("device lost")
+
+    with monkeypatch.context() as m:
+        m.setattr(jax, "device_get", lost)
+        with pytest.raises(RuntimeError, match="device lost"):
+            be.step()                           # 12 more, then the read fails
+    assert be._slots[0].offset == 0 and be._inflight is None
+    be.step(), be.step()
+    assert be._slots[0].offset == 24
+    be._preempt(0)                              # reads the step in flight
+    assert be._slots[0] is None
+    assert be.scheduler.pending()[0].n_preemptions == 1
+    assert be.run(max_steps=50)[rid] == want
+    assert [int(sl[0]) for *_, sl in calls if sl[0] > 1] == [12] * 6 + [4]
+    c = be.metrics.counters
+    # the failed step and the one behind it were never read (nor counted)
+    assert c["prefill_tokens"] == 24 + 28 and c["preemptions"] == 1
+    be.pool.check_invariants()
+
+
+def test_a_narrowed_budget_keeps_one_narrowed_row(setup, monkeypatch):
+    """With the controller's ``prefill_budget`` below the chunk a request
+    keeps ONE narrowed row a step, as before the deal (the knob says how
+    much prompt a step may take beside the decode rows); back at the
+    chunk's width the prompt takes the free rows again."""
+    engine = setup[2]
+    prompt = np.random.default_rng(43).integers(
+        0, engine.config.vocab_size, size=26).tolist()
+    be, calls = _dealt_engine(monkeypatch, engine, "gather", 4)
+    be.prefill_budget = 3
+    rid = be.submit(prompt, max_new_tokens=3)
+    be.step(), be.step()
+    be.prefill_budget = 8
+    out = be.run(max_steps=50)[rid]
+    assert [int(sl[0]) for *_, sl in calls] == [3, 3, 20]
+    assert [ids[2][:3].tolist() for ids, *_ in calls] == [
+        [[0, 0, 3], [-1, 0, 0], [-1, 0, 0]],
+        [[0, 3, 3], [-1, 0, 0], [-1, 0, 0]],
+        [[0, 6, 8], [0, 14, 8], [0, 22, 4]]]
+    assert be.metrics.counters["prefill_rows_extra"] == 2
+    np.testing.assert_array_equal(np.asarray(out, np.int32),
+                                  _golden(engine, prompt, 3))
+
+
 def test_pool_sharded_over_kv_heads(mesh8):
     config = ModelConfig.from_name("tiny")
     pool = KVPool(config, n_blocks=16, block_size=4, mesh=mesh8)
@@ -812,4 +1050,24 @@ def test_batched_matches_engine_batch_tp8(mesh8):
     out = be.run(max_steps=100)
     got = np.stack([np.asarray(out[r], np.int32) for r in rids])
     np.testing.assert_array_equal(got, golden)
+    assert be.trace_counts == {"decode": 1, "prefill": 1}
+
+
+def test_rows_of_one_slot_under_tp8(mesh8):
+    """The same under TP=8 (the flat batch of ``8 + 8 * 8`` positions cut
+    into eight runs of rows): two prompts of 19 tokens take three rows
+    each in ONE step, and serve what the contiguous ``Engine`` serves."""
+    config = ModelConfig.from_name("tiny")
+    engine = Engine(config, mesh=mesh8, mode="xla", block_n=8)
+    prompts = (np.arange(8 * 19, dtype=np.int32).reshape(8, 19)
+               * 5 % config.vocab_size)
+    golden = np.asarray(engine.serve(prompts, gen_len=3))
+    be = BatchEngine(engine, n_slots=8, block_size=4, prefill_chunk=8)
+    calls = _recording(be)
+    rids = [be.submit(p, max_new_tokens=3) for p in prompts[:2]]
+    out = be.run(max_steps=100)
+    got = np.stack([np.asarray(out[r], np.int32) for r in rids])
+    np.testing.assert_array_equal(got, golden[:2])
+    assert [sl.tolist()[:2] for *_, sl in calls] == [[19, 19]]
+    assert be.metrics.counters["prefill_rows_extra"] == 4
     assert be.trace_counts == {"decode": 1, "prefill": 1}

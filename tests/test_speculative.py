@@ -545,3 +545,54 @@ def test_fleet_requeue_drafter_fingerprint_matches_fresh_adopt():
     assert migrated.fingerprint("r") == original.fingerprint("r")
     for k in (1, 2, 4, 8):
         assert migrated.propose("r", k) == original.propose("r", k)
+
+
+def test_a_verify_row_stays_one_row_and_a_prompt_beside_it_takes_more(setup):
+    """Every slot of a speculative engine has a row of the prefill block. A
+    verify row is ONE row of ``1 + proposed`` tokens at the slot's offset,
+    as it was before the host dealt the rows; a prompt admitted beside it
+    takes the rows that are free, several a step; both streams are the
+    golden's."""
+    _, config, engine = setup
+    rng = np.random.default_rng(37)
+    prompts = [rng.integers(0, config.vocab_size, size=n).tolist()
+               for n in (6, 27)]
+    gens = (12, 3)
+    drafter, gold = _golden_drafter(engine, prompts, gens)
+    plan = Speculative(drafter=drafter,
+                       controller=SpecController(k_init=3, adaptive=False))
+    be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
+                     speculative=plan, paged_attn="gather")
+    assert be.prefill_rows == 4
+    calls, step = [], be._mixed_step
+
+    def recording(*args):
+        calls.append(jax.tree.map(np.asarray, (args[1][2], args[3], args[6])))
+        return step(*args)
+
+    be._mixed_step = recording
+    rids = [be.submit(prompts[0], gens[0], req_id=0)]
+    be.step(), be.step()
+    rids.append(be.submit(prompts[1], gens[1], req_id=1))
+    out = be.run(max_steps=100)
+    for rid, g in zip(rids, gens):
+        assert out[rid] == gold[rid][:g]
+    assert be.trace_counts["prefill"] == 1
+    verify = beside = 0
+    for dealt, offsets, takes in calls:
+        rows_of = {}
+        for slot, before, n in dealt.tolist():
+            if slot >= 0:
+                rows_of.setdefault(slot, []).append((before, n))
+        for slot, rows in rows_of.items():
+            assert rows[0][0] == offsets[slot]
+            assert sum(n for _, n in rows) == takes[slot]
+        for slot, rows in rows_of.items():      # slot i holds request i
+            if offsets[slot] >= len(prompts[slot]):
+                assert len(rows) == 1 and 1 < takes[slot] <= 1 + 3
+                verify += 1
+                beside += any(len(r) > 1 for r in rows_of.values())
+    c = be.metrics.counters
+    assert verify == c["spec_verify_rows"] > 0 and beside > 0
+    assert c["prefill_rows_extra"] == 2     # 27 tokens: 24 beside, then 3
+    be.pool.check_invariants()

@@ -26,6 +26,13 @@ the other side of a pair). Two uses:
   thread alone, something in this process (the interpreter's lock held,
   the process stopped); neither, the loop's own thread or the device.
 
+Either way the line also carries every program counter that moved while
+the window was driven (``counters``: the step kinds, and of the mixed
+step's plan ``prefill_rows_filled``, ``prefill_rows_extra``,
+``prefill_rows_deferred``, ``mixed_step_tokens``, which no reader of the
+benchmark takes), so ``--record 0`` is also the plain run with the
+program's own account of it.
+
 Prints the run's own lines, then one ``{"probe": "host_turn", ...}`` line.
 Run from the root of a checkout, on the chip (it refuses a CPU as the
 benchmark does).
@@ -119,13 +126,19 @@ def main(argv=None) -> int:
     driven = {}
     drive = core.drive
 
-    def kept_drive(*a, **kw):
+    def kept_drive(served, *a, **kw):
         witnesses = Witnesses(args.stall_ms / 4e3) if args.witness else None
+        counters = served.be.metrics.counters
+        at_open = dict(counters)
         try:
-            driven.update(drive(*a, **kw))
+            driven.update(drive(served, *a, **kw))
         finally:
             if witnesses is not None:
                 driven["gaps"] = witnesses.close()
+        # the program's counters over the drive (the window and its drain)
+        driven["counters"] = {
+            k: n - at_open.get(k, 0.0) for k, n in sorted(counters.items())
+            if n != at_open.get(k, 0.0)}
         return driven
 
     core.drive = kept_drive
@@ -156,6 +169,7 @@ def main(argv=None) -> int:
            "correct": result["correct"], "failed": result["failed"],
            "metrics": {k: v["value"] for k, v in result["metrics"].items()},
            "longest_step_ms": max(walls, default=0.0),
+           "counters": driven["counters"],
            "steps_over_stall": [
                {"at_s": s[0] - driven["t_open"], "ms": (s[1] - s[0]) * 1e3,
                 "kind": s[2],

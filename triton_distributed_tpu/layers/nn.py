@@ -91,13 +91,22 @@ def paged_token_blocks(ids, offsets, block_tables, slot_mask, seq_lens=None,
     ``ids`` an array (B, L): ONE block of B rows of L positions (the decode
     step's (B, 1); a dense varlen chunk with ``seq_lens``), ``T = B * L``.
 
-    ``ids`` a pair ``(tok (B,), chunk (P, L))``: the mixed step's TWO
-    blocks. A DECODE block of B rows of one token — slot b is live in it
-    where ``seq_lens[b] == 1`` (a decode row, or a prefilling row whose take
-    is one token) — and a PREFILL block of P rows of L tokens, row k
-    belonging to the k-th slot (ascending) with ``seq_lens > 1``: its
-    block-table row, offset and length are gathered here, once a step. A
-    slot with ``seq_lens`` 0 (empty, or a prefilling row that waits for a
+    ``ids`` a triple ``(tok (B,), chunk (P, L), dealt (P, 3))``: the mixed
+    step's TWO blocks. A DECODE block of B rows of one token — slot b is
+    live in it where ``seq_lens[b] == 1`` (a decode row, or a prefilling
+    row whose take is one token) — and a PREFILL block of P rows of L
+    tokens whose rows the HOST has dealt (``BatchEngine._run_mixed``):
+    ``dealt[k] = (slot, cache length before the row, live tokens)``, a dead
+    row naming no slot (-1, length 0). Several rows may belong to ONE slot,
+    consecutive chunks of its prompt in consecutive rows, every row but
+    its last full: row j of a slot at offset ``o`` starts at ``o + j * L``
+    and carries the slot's block-table row again, so it attends exactly
+    the keys it would have seen j steps later (every row's new K/V is
+    appended before any row is attended, and a row's read ends at its own
+    ``offset + seq_len`` under the causal mask). ``seq_lens[b]`` is the
+    slot's whole take of the step (up to ``P * L``), and ``last[b]`` the
+    flat position of its last live token, the end of its last row. A slot
+    with ``seq_lens`` 0 (empty, or a prefilling row that waits for a
     place in the block) is dead in both, as an empty slot is in the decode
     step: nothing is appended for it, its attention walks no context
     (cache length 0 before this step), and its logits are garbage the
@@ -118,25 +127,27 @@ def paged_token_blocks(ids, offsets, block_tables, slot_mask, seq_lens=None,
         if seq_lens is None:
             raise ValueError("the two-block batch is a varlen step: it "
                              "needs seq_lens")
-        tok, chunk = ids
+        tok, chunk, dealt = ids
         P, L = chunk.shape
         seq_lens = jnp.asarray(seq_lens, jnp.int32)
         if slot_mask is not None:
             seq_lens = jnp.where(slot_mask, seq_lens, 0)
-        one, many = seq_lens == 1, seq_lens > 1
-        rows, = jnp.nonzero(many, size=P, fill_value=B)
-        held = rows < B
-        rows = jnp.minimum(rows, B - 1)
+        one = seq_lens == 1
+        slot, before, length = jnp.asarray(dealt, jnp.int32).T
+        held = (slot >= 0) & (slot < B) & (length > 0)
+        rows = jnp.where(held, slot, B - 1)
+        if slot_mask is not None:
+            held &= slot_mask[rows]
         blocks = (
             TokenBlock(0, 1, jnp.where(one, offsets, 0), block_tables, one,
                        None),
-            TokenBlock(B, L, jnp.where(held, offsets[rows], 0),
-                       block_tables[rows], held,
-                       jnp.where(held, seq_lens[rows], 0), rows))
+            TokenBlock(B, L, jnp.where(held, before, 0), block_tables[rows],
+                       held, jnp.where(held, length, 0), rows))
         flat = jnp.concatenate([tok, chunk.reshape(-1)])
-        last = jnp.where(
-            many, B + (jnp.cumsum(many) - 1) * L + seq_lens - 1,
-            jnp.arange(B))
+        # A slot's rows are consecutive: its last live token ends the one
+        # that lies furthest into the block. A dead row scatters nowhere.
+        last = jnp.arange(B).at[jnp.where(held, slot, B)].max(
+            B + jnp.arange(P) * L + length - 1, mode="drop")
     pad = -flat.shape[0] % multiple
     if pad:
         flat = jnp.pad(flat, (0, pad))

@@ -195,7 +195,7 @@ class DeepseekV3:
                       spec_verify: bool = False):
         """One served step on this device, as ``Qwen3.forward_paged``:
         ``(logits (B, vocab) f32, aux, state)``, ``ids`` an array (B, L) or
-        the mixed step's pair ``(tok (B,), chunk (P, L))``
+        the mixed step's triple ``(tok (B,), chunk (P, L), dealt (P, 3))``
         (``nn.paged_token_blocks``): the projections, the shared expert and
         the routed experts see the flat token batch (``HeldExpertsMoE``
         sizes its buffer from it), latent attention one block at a time.

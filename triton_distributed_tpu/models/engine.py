@@ -112,7 +112,7 @@ class Engine:
         other operands are replicated data, so slot churn never changes a
         shape; the two variants differ only in whether ``seq_lens`` is an
         operand. ``ids`` is an array (B, L) or, for the mixed step's two
-        blocks, the pair ``(tok (B,), chunk (P, L))``
+        blocks, the triple ``(tok (B,), chunk (P, L), dealt (P, 3))``
         (``nn.paged_token_blocks``). ``paged_attn`` selects the paged KV read path for every
         step shape (fused block-walk kernel vs the gather escape hatch —
         see ``nn.paged_attn_with_cache``); it is baked into the trace, so

@@ -218,11 +218,13 @@ class GraniteHybrid:
                       spec_verify: bool = False):
         """One served step on this device, as ``Qwen3.forward_paged``:
         ``(logits (B, vocab) f32, aux, state)``, ``ids`` an array (B, L) or
-        the mixed step's pair ``(tok (B,), chunk (P, L))``
+        the mixed step's triple ``(tok (B,), chunk (P, L), dealt (P, 3))``
         (``nn.paged_token_blocks``). The projections, the SwiGLU and the
         residual stream see the flat token batch; the mixers one block at
-        a time, each slot's state advanced in the ONE block it is live in
-        and over its live positions only (``layers.mamba2``).
+        a time, each slot's state advanced in the ONE block it is live in,
+        in ONE row of it (``BatchEngine`` deals a model with per-slot
+        state one row a slot) and over its live positions only
+        (``layers.mamba2``).
         ``aux["stats"]`` the int32 counts ``step_stats``. ``mode`` is
         accepted and not read: on one device ``dist``, ``xla`` and ``ar``
         are one path."""

@@ -257,7 +257,7 @@ class NemotronH:
                       spec_verify: bool = False):
         """One served step on this device, as ``Qwen3.forward_paged``:
         ``(logits (B, vocab) f32, aux, state)``, ``ids`` an array (B, L) or
-        the mixed step's pair ``(tok (B,), chunk (P, L))``
+        the mixed step's triple ``(tok (B,), chunk (P, L), dealt (P, 3))``
         (``nn.paged_token_blocks``). The projections, the experts and the
         residual stream see the flat token batch; Mamba-2 and attention one
         block at a time. ``aux["stats"]`` the int32 counts ``step_stats``.

@@ -479,27 +479,29 @@ class Qwen3:
         shapes, so slot churn never retraces). ``ids`` says what the step's
         token batch is made of (``nn.paged_token_blocks``): an array
         (B, L) int32 is B rows of L positions (the decode step's (B, 1));
-        a pair ``(tok (B,), chunk (P, L))`` is the MIXED step's two blocks,
-        one token a slot beside P rows of L prompt tokens for the slots
-        with ``seq_lens > 1``, ``T = B + P * L`` positions in place of
-        ``B * L``. The embedding, norms, linear layers and the residual
-        stream run over the flat (T, d) batch — cut into ``world`` runs of
-        rows in dist/xla mode — so each weight is read once a step; rope,
-        the append and attention run a block at a time
-        (``TPAttn._attend``). ``offsets`` (B,) per-slot depths;
+        a triple ``(tok (B,), chunk (P, L), dealt (P, 3))`` is the MIXED
+        step's two blocks, one token a slot beside P rows of L prompt
+        tokens that the host has dealt to the slots that take more (row k
+        is ``dealt[k] = (slot, cache length before it, live tokens)``;
+        several rows may be consecutive chunks of one slot), ``T = B + P *
+        L`` positions in place of ``B * L``. The embedding, norms, linear
+        layers and the residual stream run over the flat (T, d) batch —
+        cut into ``world`` runs of rows in dist/xla mode — so each weight
+        is read once a step; rope, the append and attention run a block at
+        a time (``TPAttn._attend``). ``offsets`` (B,) per-slot depths;
         ``block_tables`` (B, max_blocks) int32 and ``slot_mask`` (B,) bool;
-        ``seq_lens`` (B,) valid new-token counts per slot of a varlen step
-        (slot b's logits then come from its last valid position), None for
-        the decode step. ``paged_attn`` "fused" (default) routes every
-        block through the fused block-walk kernel; "gather" pins the
-        materialized-view escape hatch / test oracle
+        ``seq_lens`` (B,) valid new-token counts per slot of a varlen
+        step, over all its rows (slot b's logits then come from its last
+        valid position), None for the decode step. ``paged_attn`` "fused"
+        (default) routes every block through the fused block-walk kernel;
+        "gather" pins the materialized-view escape hatch / test oracle
         (nn.paged_attn_with_cache).
 
         ``aux`` is a dict whose keys are fixed per build: ``"greedy"``
         int32 under ``spec_verify`` (speculative decoding's batched
         verify; requires ``seq_lens``) — the argmax next-token prediction
         at EVERY position of every row of the LAST block ((P, L) of the
-        pair form, (B, L) of the array form). Host-side longest-prefix
+        two-block form, (B, L) of the array form). Host-side longest-prefix
         acceptance compares draft token j+1 against ``greedy[row, j]``;
         position ``m`` doubles as the bonus token. The last-position
         ``logits`` path is untouched (same gather-then-dot arithmetic), so

@@ -10,18 +10,25 @@ fixed bank of ``n_slots`` sequence slots runs through TWO jitted programs —
                                              ``prefill_chunk`` prompt
                                              tokens, in the same iteration
                                              (Orca-style iteration-level
-                                             batching); a step with more
-                                             than P rows prefilling serves
-                                             the oldest admitted and defers
-                                             the rest
+                                             batching). The host DEALS the
+                                             block's rows (``_run_mixed``):
+                                             one to each prompt, the P
+                                             oldest admitted first and the
+                                             rest deferred; then the rows
+                                             still free to the same
+                                             prompts, oldest first, as
+                                             consecutive chunks of the one
+                                             sequence: a prompt alone takes
+                                             up to ``P * prefill_chunk``
+                                             tokens a step
 
 — whose operands (active-slot mask, per-slot offsets, block tables,
-per-row seq_lens) are plain DATA. Requests arriving, finishing, getting
-preempted or re-admitted never change a shape, so each step compiles
-exactly once for the slot bank (``trace_counts`` proves it; the tests
-assert on it). The reference engine gets this from CUDA-Graph replay over
-a fixed batch; here XLA executable replay plays that role with the
-dynamism pushed into masks — the TPU-idiomatic translation.
+per-slot seq_lens, the dealt rows) are plain DATA. Requests arriving,
+finishing, getting preempted or re-admitted never change a shape, so each
+step compiles exactly once for the slot bank (``trace_counts`` proves it;
+the tests assert on it). The reference engine gets this from CUDA-Graph
+replay over a fixed batch; here XLA executable replay plays that role with
+the dynamism pushed into masks — the TPU-idiomatic translation.
 
 One step is kept IN FLIGHT: ``step()`` dispatches step N+1 from what the
 host knows by count and only then reads step N's tokens, so the host's
@@ -105,7 +112,15 @@ from triton_distributed_tpu.serving.speculative import as_speculative
 # lower block prefills a waiting population in more steps: 32 contexts of
 # 35,889 tokens took 225 steps through 3 rows (6.6 s), 116 through 7 (4.2
 # s), 51 through the dense (32, 64) block this replaced (3.5 s). 512 is the
-# least at which that bulk case costs about what it did.
+# least at which that bulk case costs about what it did. Those counts
+# are of one row a prompt. Since a prompt takes the free rows too (PR 38)
+# the ordering between heights holds and the bulk case got cheaper: while
+# more than P prompts wait each holds one row, and the last, long ones
+# take the rows the finished leave (the same 32 contexts: 112 steps by
+# one row each, 83 with the deal, counted on the host; 2.8 -> 2.1 s of
+# the cell's set-up on the chip). What changed most is the case the
+# block is sized AGAINST: one prompt beside 32 decoding rows used one row
+# of seven; now it uses what it can fill.
 MIXED_STEP_TOKEN_BUDGET = 512
 
 # The trailing windows every stats snapshot reports ("last 10 s" for the
@@ -201,13 +216,17 @@ class BatchEngine:
     ``n_blocks``   KV pool size; defaults to full residency for all slots
                    (no preemption pressure). Size it below
                    ``n_slots * ceil(max_seq_len/block_size)`` to oversubscribe.
-    ``prefill_chunk`` tokens of prompt a prefilling row consumes per mixed
-                   step, and the width of the mixed step's prefill block.
-                   The block's height ``prefill_rows`` (how many rows may
-                   prefill in one step) is derived: what fits beside the
+    ``prefill_chunk`` width of the mixed step's prefill block: the tokens
+                   of prompt ONE of its rows holds. The block's height
+                   ``prefill_rows`` is derived: what fits beside the
                    decode block in ``MIXED_STEP_TOKEN_BUDGET`` positions
                    or, with ``speculative``, ``n_slots`` (every decode
-                   row may be a verify row of several tokens).
+                   row may be a verify row of several tokens). A prompt
+                   takes one row a step and, where rows are free, as
+                   many more as it can fill (``_run_mixed``); one row a
+                   slot is the limit for a model with per-slot state,
+                   for a verify row, and while ``prefill_budget`` is
+                   below the chunk.
     ``admission_pressure`` fraction of the pool that must be free to admit
                    NEW requests while at least one slot is running (0.0 =
                    off). Backpressure trades queue wait for fewer
@@ -340,6 +359,7 @@ class BatchEngine:
         # prefill-block row. The adaptive controller (serving/controller.py)
         # moves this as pure per-step data — ``seq_lens`` narrows, the ids
         # shape never changes, so the compiled mixed step is untouched.
+        # Below the chunk's width it also holds a prompt to ONE row a step.
         self.prefill_budget = prefill_chunk
         max_seq_len = max_seq_len or engine.max_length
         if n_blocks is None:
@@ -540,13 +560,14 @@ class BatchEngine:
         @functools.partial(jax.jit, donate_argnums=(2,))
         def mixed_step(params, ids, state, offsets, block_tables, slot_mask,
                        seq_lens, corrupt, key, fed):
-            # ``ids`` is the pair (tok (n_slots,), chunk (prefill_rows,
-            # prefill_chunk)): the decode block and the prefill block,
-            # whose row k is the k-th slot with ``seq_lens > 1``
-            # (``nn.paged_token_blocks`` finds them on the device).
+            # ``ids`` is the triple (tok (n_slots,), chunk (prefill_rows,
+            # prefill_chunk), dealt (prefill_rows, 3)): the decode block,
+            # the prefill block, and whose each of its rows is, as
+            # ``_run_mixed`` dealt them (``nn.paged_token_blocks``).
             trace_counts["prefill"] += 1
-            ids = (feed(ids[0], fed), ids[1])
-            ids = jax.tree.map(lambda a: jnp.clip(a, 0, V - 1), ids)
+            tok, chunk, dealt = ids
+            ids = (jnp.clip(feed(tok, fed), 0, V - 1),
+                   jnp.clip(chunk, 0, V - 1), dealt)
             logits, aux, state = sm_pre(params, ids, state, offsets,
                                         block_tables, slot_mask, seq_lens)
             return *sample(logits, aux, corrupt, key), state
@@ -854,6 +875,15 @@ class BatchEngine:
                 "flushes": {r: m[k] for r in FLUSH_REASONS if (
                     k := f"pipeline_flushes{{reason={r}}}") in m},
             },
+            # The mixed step's prefill block: its compiled height, the
+            # steps that ran it, their live rows, those of them that were
+            # a slot's second or later (the deal's second pass), and the
+            # rows that waited for a place.
+            "prefill_block": {"rows": self.prefill_rows, **{
+                k: m.get(k, 0.0) for k in (
+                    "prefill_steps", "prefill_rows_filled",
+                    "prefill_rows_extra", "prefill_rows_deferred",
+                    "mixed_step_tokens")}},
         }
         # A model whose layers are of several kinds says how many of each
         # (what the ``step_stats`` counts of a step are sums over).
@@ -1920,25 +1950,48 @@ class BatchEngine:
                 wants[i] = [0 if s.in_flight else s.last_tok,
                             *proposals.get(i, ())]
         # A row of ONE token (a decode row; a prompt's last token) rides
-        # the decode block. A longer one needs a row of the prefill block:
-        # the P oldest admitted get one, in slot order (row k is the k-th
-        # slot with seq_lens > 1 — the device finds them so), the rest
-        # take nothing this step and go next.
+        # the decode block. A longer one needs rows of the prefill block,
+        # which are dealt in two passes. First every such slot gets ONE
+        # row, the P oldest admitted; the rest take nothing this step and
+        # go next. Then the rows still free go to the prompts that hold
+        # one, oldest first, each as many further whole chunks as it can
+        # fill: so no request takes less than its one row, and a prompt
+        # alone takes up to P * L tokens a step. One row a slot stays the
+        # limit where a second row of the slot could not start where the
+        # first ends: a model with per-slot state (every row of a slot
+        # starts from the arena's state), a verify row, a narrowed budget.
         many = sorted((i for i, t in wants.items() if len(t) > 1),
                       key=lambda i: self._slots[i].admit_seq)
-        block_row = {i: k for k, i in enumerate(sorted(many[:P]))}
         for i in many[P:]:
             del wants[i]
+        served = many[:P]
+        free = P - len(served)
+        if not self.pool.slot_state and budget == L:
+            for i in served:
+                s = self._slots[i]
+                more = min(free, -(-(len(s.ctx) - s.offset) // L) - 1)
+                if s.prefilling and more > 0:
+                    free -= more
+                    wants[i] = s.ctx[s.offset:s.offset + (1 + more) * L]
+        # The block as the device takes it: a slot's rows one after
+        # another, oldest admitted first, each named by (slot, cache length
+        # before the row, live tokens); a dead row names no slot.
         tok = np.zeros((self.n_slots,), np.int32)
         chunk = np.zeros((P, L), np.int32)
+        dealt = np.tile(np.int32([-1, 0, 0]), (P, 1))
+        block_row, k = {}, 0
+        for i in served:
+            t, block_row[i] = wants[i], k
+            chunk.reshape(-1)[k * L:k * L + len(t)] = t
+            for at in range(0, len(t), L):
+                dealt[k] = i, self._slots[i].offset + at, min(L, len(t) - at)
+                k += 1
         seq_lens = np.zeros((self.n_slots,), np.int32)
         rows = []
         pre_toks = dec_rows = 0
         for i, t in wants.items():
             seq_lens[i] = len(t)
-            if i in block_row:
-                chunk[block_row[i], :len(t)] = t
-            else:
+            if i not in block_row:
                 tok[i] = t[0]
             s = self._slots[i]
             if not s.prefilling:
@@ -1955,23 +2008,25 @@ class BatchEngine:
                 # controller narrowing shows up per request.
                 self.journey.event(s.req.req_id, "prefill_chunk",
                                    tokens=len(t), budget=budget)
-        n_deferred, n_tokens = len(many) - len(block_row), int(seq_lens.sum())
         # Per-step work accounting (prompt tokens actually consumed vs
         # 1-token decode rows riding the mixed step) — what the adaptive
         # bench's deterministic cost model and serve_top's rate lines
         # read; how full the step was (live tokens of its n_slots + P * L
-        # positions) and how often the prefill block was: a prefilling row
-        # that wanted tokens and got none.
+        # positions; live rows of the block, and those of them that are a
+        # slot's second or later) and how often the block was: a
+        # prefilling row that wanted tokens and got none.
+        block = {"mixed_step_tokens": int(seq_lens.sum()),
+                 "prefill_rows_filled": k,
+                 "prefill_rows_extra": k - len(served),
+                 "prefill_rows_deferred": len(many) - len(served)}
         counts = {"prefill_steps": 1, "prefill_tokens": pre_toks,
-                  "decode_rows": dec_rows, "mixed_step_tokens": n_tokens,
-                  "prefill_rows_deferred": n_deferred}
+                  "decode_rows": dec_rows, **block}
         return self._dispatch(
             "engine.prefill", self._mixed_step, "mixed_step",
-            (jnp.asarray(tok), jnp.asarray(chunk)), live, rows, counts,
-            jnp.asarray(seq_lens),
+            (jnp.asarray(tok), jnp.asarray(chunk), jnp.asarray(dealt)),
+            live, rows, counts, jnp.asarray(seq_lens),
             verify={i: (p, block_row[i]) for i, p in proposals.items()},
-            prefill_rows=len(block_row), spec_rows=len(proposals),
-            prefill_rows_deferred=n_deferred, mixed_step_tokens=n_tokens)
+            prefill_rows=len(served), spec_rows=len(proposals), **block)
 
     def _retire(self, st: _Step, flush: str | None = None) -> None:
         """Read a dispatched step: its tokens (with the ``step_stats``
