@@ -20,7 +20,12 @@ give the same logits for the same tokens; and the NEMOTRON-H block
 (``models/nemotron_h.py``: NVIDIA-Nemotron-3-Nano-30B-A3B's widths, the
 first seven of its 52 layers, 16 of 128 experts held: Mamba-2 in eight
 groups, ungated relu² experts through the grouped product, attention over
-two key heads) the same way, no routed pair dropped.
+two key heads) the same way, no routed pair dropped; and the EXAONE-MoE
+block (``models/exaone_moe.py``: K-EXAONE-236B-A23B's widths, two layers,
+one over a WINDOW of 128 keys whose rows live in a ring a slot and one over
+every key, 16 of 128 experts held, an eighth of the vocabulary): the window
+build of the block walk at its chunk shape against its decode shape, over a
+walk long enough to pass the window and to wrap the ring.
 
 ``--chips 4`` (a four-chip host) runs only the tensor-parallel phase:
 Qwen3-8B over ``make_mesh({"tp": 4})`` through ``BatchEngine``, once in
@@ -75,6 +80,18 @@ HYBRID = dict(
 # share of the experts (2.8 GB of weights).
 NEMOTRON_H = dict(HYBRID, config="NemotronHConfig",
                   overrides=dict(pattern="MEMEM*E", experts_held=16))
+
+# And over the EXAONE-MoE block: the published widths, one window layer and
+# one full layer (both with experts), one chip's share of the experts and of
+# the vocabulary (3.5 GB of weights). The walk passes the window (128) and
+# wraps the ring (576 lines at a prefill block of 7 rows of 64).
+EXAONE_MOE = dict(HYBRID, config="ExaoneMoeConfig",
+                  overrides=dict(
+                      layer_types=("sliding_attention", "full_attention"),
+                      sliding_windows=(128, 0),
+                      mlp_layer_types=("sparse", "sparse"),
+                      experts_held=16, vocab_size=19_200),
+                  prompt_range=(650, 900), walk_len=640)
 
 # Largest |difference| of two logit rows over the largest |reference logit|.
 # bf16 keeps 8 mantissa bits (2^-8 per rounded op); over 28-36 layers of
@@ -526,13 +543,16 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
                         paged_attn=geo["paged_attn"])
     be = fleet.replicas[0].engine
     jax.block_until_ready(be.pool.state)
+    state_layers = getattr(cfg, "n_state_layers", 0)
+    window_layers = getattr(cfg, "n_window_layers", 0)
     emit(phase="hybrid_build", model=cfg.model_name, n_layers=cfg.n_layers,
-         state_layers=cfg.n_state_layers, cache_layers=cfg.n_cache_layers,
-         d_model=cfg.d_model, vocab=cfg.vocab_size,
-         kv_rows=list(be.pool.state.k.shape),
-         slot_state_bytes=be.pool.slot_state_bytes)
-    check(be.prefix_cache is None, "a model with per-slot state was given "
-          "a prefix cache")
+         state_layers=state_layers, cache_layers=cfg.n_cache_layers,
+         window_layers=window_layers, d_model=cfg.d_model,
+         vocab=cfg.vocab_size, kv_rows=list(be.pool.state.k.shape),
+         slot_state_bytes=be.pool.slot_state_bytes,
+         window=be.pool.geometry().get("window"))
+    check(be.prefix_cache is None, "a model with per-slot state or window "
+          "layers was given a prefix cache")
 
     rng = np.random.default_rng(geo["seed"])
     lo, hi = geo["prompt_range"]
@@ -550,22 +570,29 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
          ssm_rows_advanced=c.get("ssm_rows_advanced", 0.0),
          ssm_states_reset=c.get("ssm_states_reset", 0.0),
          kv_rows_appended=c.get("kv_rows_appended", 0.0),
+         prefill_rows_extra=c.get("prefill_rows_extra", 0.0),
          moe_pairs_held=c.get("moe_pairs_held"),
          moe_dropped_pairs=c.get("moe_dropped_pairs"),
          trace_counts=be.trace_counts)
-    check(c.get("ssm_rows_advanced") == tokens * cfg.n_state_layers
-          and c.get("kv_rows_appended") == tokens * cfg.n_cache_layers
-          and c.get("ssm_states_reset") == geo["n_requests"],
+    want = {"kv_rows_appended":
+            tokens * (cfg.n_cache_layers + window_layers)}
+    if state_layers:
+        want.update(ssm_rows_advanced=tokens * state_layers,
+                    ssm_states_reset=geo["n_requests"])
+    check(all(c.get(k) == n for k, n in want.items()),
           f"the step's counts do not add up to {tokens} tokens of "
-          f"{geo['n_requests']} requests")
+          f"{geo['n_requests']} requests: {want}")
     check(not c.get("moe_dropped_pairs"), "a routed pair was dropped")
+    if window_layers:
+        check(c.get("prefill_rows_extra", 0) > 0, "no prompt took a second "
+              "row of the prefill block: the deal did not engage")
 
     # Numbers: the same tokens through the chunk scan (chunked prefill,
     # then one decode step) and through the kernel alone.
     walked = [p[:geo["walk_len"]] for p in prompts[:2]]
     next_tok = [p[0] for p in walked]
-    compare_logits("hybrid chunked prefill + decode step vs the one-token "
-                   "state update alone",
+    compare_logits(f"{geo['config']}: chunked prefill + decode step vs "
+                   f"the same tokens one at a time",
                    paged_logits(be, walked, next_tok),
                    decode_walk_logits(be, walked, next_tok),
                    logit_tolerance(cfg))
@@ -575,14 +602,13 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
 
 def run_served_blocks(devices, geo: dict, caches: _CacheEvents) -> None:
     """The one-chip smoke: the dense model, then (its buffers dropped) the
-    hybrid block, then the Nemotron-H block."""
+    hybrid block, then the Nemotron-H block, then the EXAONE-MoE block."""
     import gc
 
     run_one_chip(devices, geo, caches)
-    gc.collect()
-    run_hybrid(devices, HYBRID, caches)
-    gc.collect()
-    run_hybrid(devices, NEMOTRON_H, caches)
+    for block in (HYBRID, NEMOTRON_H, EXAONE_MOE):
+        gc.collect()
+        run_hybrid(devices, block, caches)
 
 
 # -- four chips: TP=4 dist against xla ---------------------------------------

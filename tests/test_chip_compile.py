@@ -105,6 +105,51 @@ def test_paged_attention_compiles(one_chip, model, L):
     assert "latent_paged_attention" not in text
 
 
+# K-EXAONE-236B-A23B's cell (perfbench/configs/k-exaone-236b-a23b-ep8.json):
+# 32 slots, 64 query heads on 8 key heads, contexts up to 24,576 (a block
+# table 1,536 wide: 196 KB of scalar prefetch where the other cells' is
+# 32 KB), one full layer over a 28,672-block pool, four window layers (128)
+# over a ring of 36 blocks a slot (the window and a step's 7 rows of 64).
+EXA_SLOTS, EXA_BLOCKS, EXA_TABLE, EXA_RING, EXA_HEADS = \
+    32, 28_672, 24_576 // BLOCK, 36, (64, 8)
+
+
+@pytest.mark.parametrize("L", [1, CHUNK], ids=["decode", "chunk"])
+@pytest.mark.parametrize("build", ["window", "full-wide-table"])
+def test_paged_attention_compiles_at_long_contexts(one_chip, build, L):
+    """The two builds a model with window layers runs, at the published
+    heads (the decode shape folds 64 query rows into one operand): the
+    window build over ring storage, known by its own name, and the K+V
+    build with the 1,536-wide table in SMEM."""
+    from triton_distributed_tpu.kernels.paged_attention import (
+        paged_attention,
+    )
+
+    hq, hkv = EXA_HEADS
+    rows = EXA_SLOTS if L == 1 else 7
+    window = 128 if build == "window" else None
+
+    def fn(q, kp, vp, tables, kv_lens, q_lens, layer):
+        return paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
+                               interpret=False, layer=layer, window=window)
+
+    if window:
+        pool = _sds((4, EXA_SLOTS, EXA_RING, BLOCK, hkv, DH), jnp.bfloat16,
+                    one_chip)
+        tables = _sds((rows, 1), jnp.int32, one_chip)
+    else:
+        pool = _sds((1, EXA_BLOCKS, BLOCK, hkv, DH), jnp.bfloat16, one_chip)
+        tables = _sds((rows, EXA_TABLE), jnp.int32, one_chip)
+    text = jax.jit(fn).lower(
+        _sds((rows, L, hq, DH), jnp.bfloat16, one_chip), pool, pool, tables,
+        _sds((rows,), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("window_paged_attention" in text) == bool(window)
+    assert "paged_attention" in text
+
+
 def _tp4_compile(tp4, fn, in_specs, out_specs, *shapes):
     sm = shard_map(fn, mesh=tp4, in_specs=in_specs, out_specs=out_specs,
                    check_vma=False)
@@ -380,6 +425,82 @@ def test_nemotron_step_compiles_with_its_state_in_place(topo, monkeypatch,
     # products in each of 6 expert layers, 2 block walks
     assert text.count("tpu_custom_call") >= 20
     assert "ssm_state_update" in text and "moe_grouped_gemm" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
+                                                      kind):
+    """The whole served step of k-exaone-236b-a23b-ep8 (layers 0-4, every
+    width, 16 of 128 experts held, an eighth of the vocabulary), as
+    ``BatchEngine`` builds it around ``forward_paged``: it compiles with the
+    1,536-wide block table, every arena of the pool's state (the full
+    layer's rows, the window layers' rings) is aliased in to out, and the
+    step's temporaries hold no copy of an arena or of a weight stack (the
+    smallest stack of matrices is the shared experts' 0.3 GB; the chunk's
+    expert buffer and activations are 0.11 GB)."""
+    import json
+
+    from perfbench.families import exaone_moe as family
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.models.exaone_moe import ExaoneMoe
+    from triton_distributed_tpu.runtime import platform
+    from triton_distributed_tpu.serving.kv_pool import (
+        paged_state_shapes,
+        paged_state_specs,
+    )
+
+    # the grouped product asks ``on_tpu()`` before it hands Mosaic a kernel
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "perfbench/configs/k-exaone-236b-a23b-ep8.json")) as f:
+        file = json.load(f)
+    cfg = family.program_config(file, family.sizes(file))
+    fleet = file["serve"]["fleet"]
+    assert (fleet["n_slots"], fleet["n_blocks"], fleet["block_size"]) == \
+        (EXA_SLOTS, EXA_BLOCKS, BLOCK)
+    mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
+    here = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, here), tree)
+
+    params = placed(jax.eval_shape(
+        lambda k: ExaoneMoe(cfg).init(k, mesh), jax.random.PRNGKey(0)))
+    state = placed(paged_state_shapes(
+        cfg, n_blocks=EXA_BLOCKS, block_size=BLOCK, n_slots=EXA_SLOTS,
+        max_take=HYB_PREFILL_ROWS * CHUNK))
+    assert state.wk.shape == (4, EXA_SLOTS, EXA_RING, BLOCK, 8, DH)
+    state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    assert 2.15e9 < state_bytes < 2.2e9 < 2.5e9
+    engine = Engine(cfg, mesh=mesh, params=params, mode="dist",
+                    interpret=False)
+    step = jax.jit(
+        engine._make_sm("dist", paged=kind, paged_attn="fused",
+                        state_specs=paged_state_specs(cfg)),
+        donate_argnums=(2,))
+    slots = (_sds((EXA_SLOTS,), jnp.int32, here),
+             _sds((EXA_SLOTS, EXA_TABLE), jnp.int32, here),
+             _sds((EXA_SLOTS,), bool, here))
+    if kind == "decode":
+        args = (_sds((EXA_SLOTS, 1), jnp.int32, here), state, *slots)
+    else:
+        ids = (_sds((EXA_SLOTS,), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, 3), jnp.int32, here))
+        args = (ids, state, *slots, _sds((EXA_SLOTS,), jnp.int32, here))
+    compiled = step.lower(params, *args).compile()
+    text = compiled.as_text()
+    # five layer bodies: four window walks and a full one, two grouped
+    # products in each of four expert layers (each walk twice in the mixed
+    # step: the decode block and the prefill block)
+    assert text.count("tpu_custom_call") >= 10
+    assert "window_paged_attention" in text and "moe_grouped_gemm" in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == state_bytes
     assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes

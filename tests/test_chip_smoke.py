@@ -122,6 +122,32 @@ def test_nemotron_h_phase_passes_at_tiny(capsys, restore_compile_cache_config):
     assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
 
 
+def test_exaone_moe_phase_passes_at_tiny(capsys, restore_compile_cache_config):
+    """The same phase over the EXAONE-MoE block at tiny float32 sizes:
+    window layers (6) beside a full one, the prompts pass the window and the
+    walk wraps the ring (6 - 1 + 2 rows of 8 = 6 blocks of 4: 24 lines),
+    the deal engages, no routed pair dropped."""
+    import dataclasses
+
+    from triton_distributed_tpu.models.config import ExaoneMoeConfig
+
+    geo = dict(chip_smoke.EXAONE_MOE, interpret=None, paged_attn="gather",
+               n_slots=2, block_size=4,
+               prefill_chunk=8, n_requests=3, prompt_range=(30, 40),
+               new_tokens=3, walk_len=30,
+               overrides=dataclasses.asdict(ExaoneMoeConfig.tiny()))
+    rc = chip_smoke.smoke(chip_smoke.run_hybrid, jax.devices()[:1], geo)
+    records = [json.loads(line)
+               for line in capsys.readouterr().out.strip().splitlines()]
+    assert rc == 0 and records[-1]["ok"] is True
+    assert records[0]["window_layers"] == 3 and records[0]["cache_layers"] == 1
+    assert records[0]["window"]["ring_blocks"] == 6
+    assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
+    assert records[1]["moe_pairs_held"] > 0 == records[1]["moe_dropped_pairs"]
+    assert records[1]["prefill_rows_extra"] > 0
+    assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+
+
 def test_forced_step_exception_fails_the_smoke(
         monkeypatch, capsys, restore_compile_cache_config):
     """A step that raises at run time is absorbed by the replica error

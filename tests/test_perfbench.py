@@ -12,6 +12,13 @@ hand (``python -m pytest perfbench/tests``) it flags
 ``families/deepseek_v3.py`` (PERF.md section 7). It is written here for
 SEVERAL families.
 
+One case is written here anew (``BY_POSITION``): PR 36's check of its four
+``per_layer`` entries reads them as the LAST four of the list, and a later
+PR appends its own behind them (PR 39: two; the driver's contract takes an
+entry put anywhere but at the end of a list as a change to what was there,
+so they cannot go in front of the four). Here they are found by name, and
+what is checked of each is what the original checks.
+
 One case stays out (``NOT_STEADY``): the open-loop run of ``tiny.open``
 counts a request as failed that has no first token 30 s after its 2 s
 window, and beside five other busy workers the interpreter's steps are slow
@@ -31,6 +38,7 @@ BENCH = os.path.join(ROOT, "perfbench")
 
 WRITTEN_HERE = "test_only_its_own_file_names_a_model_or_reads_its_fields"
 NOT_STEADY = "test_open_loop_run_reports_the_contract_keys_and_fails_the_control"
+BY_POSITION = "test_the_new_entries_name_their_cells_and_find_their_readers"
 # The fixtures those modules define (a case that asks for one this does not
 # name fails by that name).
 FIXTURES = ("root", "restore_compile_cache_config")
@@ -46,7 +54,7 @@ def _take(module: str):
     spec.loader.exec_module(loaded)
     for name, value in vars(loaded).items():
         if (name.startswith("test_")
-                and name not in (WRITTEN_HERE, NOT_STEADY)) \
+                and name not in (WRITTEN_HERE, NOT_STEADY, BY_POSITION)) \
                 or name in FIXTURES:
             assert name not in globals(), name
             globals()[name] = value
@@ -73,6 +81,9 @@ OF_A_FAMILY = {
     "nemotron_h": re.compile(
         r"nemotron|hybrid_override|relu2|shared_expert_intermediate|"
         r"ssm_state_size|conv_kernel|time_step_", re.IGNORECASE),
+    "exaone_moe": re.compile(
+        r"exaone|sliding_window|mlp_layer_types|num_experts_published|"
+        r"num_shared_experts|rope_parameters", re.IGNORECASE),
 }
 
 
@@ -117,3 +128,133 @@ def test_only_a_familys_own_file_names_its_model_or_reads_its_fields(family):
             assert hit is None, f"perfbench/{rel}: {hit.group(0)!r}"
         seen += 1
     assert seen > 30
+
+
+def test_the_long_reasoning_mix_opens_on_268k_tokens_and_ends_inside_20k():
+    """``traffic/reasoning-long.json`` as the new cell runs it (its
+    configuration's vocabulary, positions and slots): 32 standing requests
+    caught part-way, 268,278 tokens of context at the opening, the same
+    sizes for every seed; prompts 1,024-4,096 and answers 8,192-16,384, so
+    no request ends past 20,480 of the 24,576 positions, and the full
+    layer's pool (28,672 blocks of 16) holds the opening with every request
+    grown by a window's worth of tokens."""
+    from perfbench import core, families
+    from perfbench.traffic_kinds.closed_loop import Plan
+
+    spec = core.load_cell("k-exaone-236b-a23b-ep8.reasoning-long")
+    assert spec["cell"]["chips"] == 1
+    assert spec["cell"]["traffic"] == "reasoning-long"
+    mix = spec["traffic"]
+    assert (mix["kind"], mix["clients"], mix["rounds"]) == \
+        ("closed_loop", "n_slots", 3)
+    assert mix["prompt"] == {"median": 2048, "sigma": 0.4, "lo": 1024,
+                             "hi": 4096}
+    assert mix["output"] == {"median": 12288, "sigma": 0.3, "lo": 8192,
+                             "hi": 16384}
+    sizes = families.load_family(spec["config"]).sizes(spec["config"])
+    fleet = spec["config"]["serve"]["fleet"]
+
+    def plan(seed):
+        return Plan(mix, seed=seed, seconds=40, vocab=sizes.vocab_size,
+                    max_total=sizes.max_length, n_slots=fleet["n_slots"])
+
+    first = plan(5).standing()
+    assert len(first) == fleet["n_slots"] == 32
+    contexts = [len(p.prompt) for p in first]
+    assert sum(contexts) == 268_278
+    assert min(contexts) == 2_678 and max(contexts) == 16_957
+    assert contexts == [len(p.prompt) for p in plan(3_900_100_001).standing()]
+    assert all(0 <= t < sizes.vocab_size for p in first for t in p.prompt[:64])
+    ends = [len(p.prompt) + p.max_new_tokens for p in first] \
+        + [p + o for p, o in plan(5)._later]
+    assert max(ends) <= 20_480 < sizes.max_length
+    assert all(1024 <= p <= 4096 and 8192 <= o <= 16384
+               for p, o in plan(5)._later)
+    # a window of 40 s at 10-12 ms a step grows each context by under 4,000
+    assert sum(min(e, c + 4_000) for e, c in zip(ends, contexts)) \
+        < fleet["n_blocks"] * fleet["block_size"] == 458_752
+    # the reference reads the longest finished request whole
+    assert spec["sample"]["token_budget"] >= 20_480
+
+
+def test_the_span_readers_entries_name_their_cells_and_find_their_readers():
+    """``perfbench/tests/test_program_spans.py``'s case of the same
+    subject, its four entries found BY NAME (and still side by side, in
+    their order): each with its cells, each cell reporting the end-to-end
+    metric the entry moves; ``host_turn_ms`` one reader under two entries.
+    And the same of every entry a later PR appended behind them."""
+    from perfbench import core
+
+    bench = core.load_json(core.ROOT, "BENCHMARK.json")
+    names = [m["name"] for m in bench["per_layer"]]
+    four = ["host_turn_ms.reasoning", "host_turn_ms.chat",
+            "host_dispatch_ms", "host_observe_ms"]
+    at = names.index(four[0])
+    assert names[at:at + 4] == four
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in names[at:]:
+        m = by_name[name]
+        if name in four:
+            assert (m["source"], m["unit"], m["better"], m["layer"]) == (
+                "program_span", "ms", "lower", "scheduler and admission")
+        assert m["workloads"] and set(m["workloads"]) <= set(
+            e2e[m["moves"]]["workloads"])
+        mod = core.reader_module("layer_metrics", name)
+        assert mod.endswith(name.split(".")[0])
+        assert callable(__import__(mod, fromlist=["read"]).read)
+    assert by_name["host_turn_ms.chat"]["workloads"] == ["qwen3-1.7b.chat"]
+    for cell in by_name["host_dispatch_ms"]["workloads"]:
+        cells = [m["name"] for m in core.load_cell(cell)["per_layer"]]
+        assert "host_turn_ms.reasoning" in cells
+        assert "host_turn_ms.chat" not in cells
+    assert names[at + 4:] == ["window_attn_device_share",
+                              "window_attn_roofline"]
+
+
+def test_the_window_readers_arithmetic_and_silence_on_an_older_program():
+    """The two readers PR 39 adds, on a hand-made record at the published
+    sizes: the kernel's device time by its ``name=``, the family's bytes
+    over the rows that decoded in the steps wholly inside the span. On a
+    program that has no such kernel (the parent commit), on a family without
+    the count, and on an untraced run each returns None and does not
+    raise."""
+    import types
+
+    from perfbench import core, families
+    from perfbench.layer_metrics import (
+        window_attn_device_share,
+        window_attn_roofline,
+    )
+
+    spec = core.load_cell("k-exaone-236b-a23b-ep8.reasoning-long")
+    family = families.load_family(spec["config"])
+    sizes = family.sizes(spec["config"])
+
+    def record(ops_s, family=family, trace=True):
+        steps = [(10.0 + 0.01 * i, 10.009 + 0.01 * i, "decode", 32, 32,
+                  300_000) for i in range(100)] \
+            + [(9.995, 10.004, "decode", 32, 32, 300_000)]   # crosses the edge
+        return core.Records(
+            t_open=0.0, t_close=11.0, t_end=12.0, setup_s=1.0, tracked=[],
+            steps=steps, kv_live=[], counters={}, queue_wait_s=[],
+            sizes=sizes, family=family, n_slots=32, n_chips=1,
+            device_kind="TPU v5 lite",
+            trace={"host_window": (10.0, 11.0), "busy_s": 1.0,
+                   "ops_s": ops_s} if trace else None)
+
+    ops = {"window_paged_attention.3": 0.020,
+           "window_paged_attention.9": 0.005, "paged_attention.4": 0.1}
+    rec = record(ops)
+    assert window_attn_device_share.read(rec) == pytest.approx(2.5)
+    # 100 steps x 32 rows x 128 rows x 4,096 B x 4 layers at 819 GB/s
+    floor_s = 100 * 32 * 128 * 4096 * 4 / 819e9
+    assert window_attn_roofline.read(rec) == pytest.approx(
+        100 * floor_s / 0.025)
+    # the parent: no such kernel in the trace
+    old = record({"paged_attention.4": 0.1})
+    for reader in (window_attn_device_share, window_attn_roofline):
+        assert reader.read(old) is None
+        assert reader.read(record(ops, trace=False)) is None
+    assert window_attn_roofline.read(
+        record(ops, family=types.SimpleNamespace())) is None

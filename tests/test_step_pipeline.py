@@ -16,6 +16,7 @@ import pytest
 
 from triton_distributed_tpu.models.config import (
     DeepseekV3Config,
+    ExaoneMoeConfig,
     GraniteHybridConfig,
     ModelConfig,
     NemotronHConfig,
@@ -31,7 +32,8 @@ from triton_distributed_tpu.serving.fleet import Fleet
 
 N_SLOTS, CHUNK, BLOCK = 4, 8, 4
 N_BLOCKS = 18       # of 64 for full residency: ``churn(reuse=True)`` evicts
-# dense, held experts over a latent pool, per-slot state, the pattern walk
+# dense, held experts over a latent pool, per-slot state, the pattern walk,
+# window layers over a ring a slot
 CLASSES = {
     "dense": lambda mesh: Engine(ModelConfig.from_name("tiny"), mesh=mesh,
                                  mode="xla", block_n=8),
@@ -41,6 +43,8 @@ CLASSES = {
                                       mode="dist"),
     "pattern_walk": lambda mesh: Engine(NemotronHConfig.tiny(), mesh=mesh,
                                         mode="dist"),
+    "window_layers": lambda mesh: Engine(ExaoneMoeConfig.tiny(), mesh=mesh,
+                                         mode="dist"),
 }
 
 

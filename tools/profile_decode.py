@@ -94,6 +94,10 @@ def _kernel_mode():
                     help="comma list of tile_blocks; empty = the default")
     ap.add_argument("--latent", default="",
                     help="W,V: one latent arena of W-wide rows")
+    ap.add_argument("--window", type=int, default=0,
+                    help="the WINDOW build: ring storage a slot, a walk "
+                         "over the last WINDOW keys (--layers window "
+                         "layers; --n-blocks and --live are not read)")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     a = ap.parse_args()
@@ -128,8 +132,16 @@ def _kernel_mode():
         row, Hq, dh, kw = (W,), a.g, W, dict(v_dim=V)
     else:
         row, Hq, dh, kw = (a.hkv, a.dh), a.hkv * a.g, a.dh, {}
+    pool = (a.layers, nb, bs)
+    if a.window:
+        # a ring a slot that holds the window and a step's 7 rows of a chunk
+        # (serving.kv_pool.window_ring_blocks); the table names slots
+        ring = -(-(a.window - 1 + 7 * a.chunk) // bs)
+        pool, nb = (a.layers, B, ring, bs), B * ring
+        tables = np.arange(B, dtype=np.int32)[:, None]
+        kw = dict(window=a.window)
     arenas = [jax.random.normal(jax.random.fold_in(key, i),
-                                (a.layers, nb, bs, *row), jnp.bfloat16)
+                                (*pool, *row), jnp.bfloat16)
               for i in range(1 if a.latent else 2)]
     if a.latent:
         arenas.append(None)
@@ -177,6 +189,9 @@ def _kernel_mode():
             # call on a TPU would tune: twenty minutes, PERF.md section 6).
             t_used = resolved["tile_blocks"]
             live_blocks = -(-kv_lens.astype(np.int64) // bs)
+            if a.window:        # the blocks that hold a visible key
+                first = np.maximum(kv_lens - q_lens - a.window + 1, 0) // bs
+                live_blocks = live_blocks - first
             n_tiles = int((-(-live_blocks // t_used)).sum())
             live_bytes = int(live_blocks.sum()) * block_bytes * a.layers
             dist_print(

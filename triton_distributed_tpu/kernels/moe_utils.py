@@ -251,6 +251,11 @@ def _grouped_gemm_skip_kernel(scal_ref, x_ref, w_ref, o_ref):
         o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
 
+# What the blocks of ``grouped_gemm_skip`` may hold of a kernel's 16 MB of
+# VMEM, double-buffered: Mosaic's own scratch takes about 1.5 MB beside them.
+_GROUPED_VMEM_BUDGET = 14 * 2 ** 20
+
+
 def grouped_gemm_skip(grouped, weights, counts, *, layer_idx=None,
                       block_n: int = 512, interpret=None, group_of=None,
                       name: str | None = None):
@@ -305,10 +310,17 @@ def grouped_gemm_skip(grouped, weights, counts, *, layer_idx=None,
     f = weights.shape[-1]
     # The f-tile: all of a narrow f, else the widest lane multiple up to
     # ``block_n`` that divides f (512 of 1,536 and 2,048; 384 of 1,920 and
-    # 2,688, which 512 does not divide).
+    # 2,688, which 512 does not divide) and whose blocks, two of each in
+    # flight, fit the kernel's VMEM (at d 6,144 a chunk's 128 rows against
+    # a 512-wide weight block are 16.7 MB of the 16 MB a kernel may take:
+    # 256 there; the decode shape's 16 rows keep 512).
+    def fits(b):
+        return 2 * grouped.dtype.itemsize * (d * b + cap * (d + b)) \
+            <= _GROUPED_VMEM_BUDGET
+
     bn = f if f <= block_n else next(
-        (b for b in range(block_n // 128 * 128, 0, -128) if f % b == 0),
-        block_n)
+        (b for b in range(block_n // 128 * 128, 0, -128)
+         if f % b == 0 and fits(b)), block_n)
     # cap < 16 falls back: sub-16-sublane bf16 operands hit Mosaic's
     # packed-tile relayout path (measured 2x SLOWER end-to-end at a cap=8
     # decode shape than the einsum despite the skip) — capacity sizing

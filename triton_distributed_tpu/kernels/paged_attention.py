@@ -41,6 +41,21 @@ routed to block 0 on the HOST (same semantics as the gather path) and their
 outputs discarded by the caller; padding query rows (j >= q_lens[b]) emit
 exact zeros, matching ``attn_with_cache``'s varlen contract.
 
+Three things are static a call site, and each is one choice of the ONE
+kernel body: the BUILD (K+V arenas, their quantized form with scale arenas,
+or one latent arena), the ARITHMETIC of a staged tile (``tile_arithmetic``:
+folded at the decode shape, per head elsewhere) and, since the model with
+window layers, where the WALK STARTS: at block 0 with the causal frontier
+its only limit, or (``window=w``) at the tile that holds the query tile's
+oldest visible key, over a window layer's ring storage (``(layers, slots,
+ring blocks, ...)``, the table one slot id a row and a logical block's
+place in the ring arithmetic), with a lower bound in the score-side select
+and the V scrub. A block wholly behind the window costs no copy and no
+wait, so a window layer's step reads ``window`` rows a sequence whatever
+its context; the fetch pipeline and both arithmetics are shared. That build
+is named ``window_paged_attention`` in a device trace (the latent one
+``latent_paged_attention``).
+
 The (kv-tile, q-tile) pair is a ``ContextualAutotuner`` config keyed on
 (block_size, Hkv, dh, max_blocks, L, g, dtype) — ``tuned_paged_tile`` —
 with a VMEM-bounded heuristic default off-TPU / under trace that covers
@@ -256,7 +271,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                        bs: int, n_blocks: int, scale: float, n_kv: int,
                        g: int, q_tile: int, n_q_tiles: int,
                        probe_steps: int = 0, v_dim: int | None = None,
-                       folded: bool = False, compiled: bool = False):
+                       folded: bool = False, compiled: bool = False,
+                       window: int | None = None):
     """One (slot, query-tile) grid step of fused paged attention: the kv
     tiles of the slot are walked by a loop INSIDE the step, two staging
     slots deep.
@@ -318,6 +334,19 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     cast to float32 (bf16 operands of a few query rows hit Mosaic's
     relayout path), V selected tile by tile.
 
+    THE WINDOW BUILD (``window`` given; K+V arenas only) is the third static
+    choice of the walk, beside the build and the arithmetic: a query at
+    position ``p`` sees the keys ``p - window < j <= p``. The walk STARTS at
+    the tile that holds the query tile's oldest visible key, so a block
+    wholly behind the window is neither copied nor waited for; the
+    score-side select and the V scrub take that lower bound beside the
+    causal one. The arenas are a window layer's RING storage ``(layers,
+    slots, ring blocks, bs, Hkv, dh)`` (``serving.kv_pool``): the table is
+    ``(B, 1)``, the SLOT each row belongs to, and logical block ``j`` of
+    that slot is ring block ``j % ring blocks`` of it: arithmetic, no
+    table of blocks. The fetch pipeline and both arithmetics are the K+V
+    build's own.
+
     Builds, by ``n_arenas``: 2 — K and V ``(..., Hkv, dh)``. 4 — a
     QUANTIZED pool (int8/fp8 wire dtype): the per-row f32 scale arenas
     ``(..., Hkv)`` ride the same pipeline and dequant happens HERE, right
@@ -363,14 +392,31 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
             jnp.clip(pl.cdiv(limit, span), 0, n_tiles), 0)
         return kv_len, q_len, limit, n_live
 
+    def behind(b, qt, n_live):
+        """The window build's lower end of a grid step: (the oldest key
+        position any query of the tile sees, the tile that holds it, the
+        tiles the walk visits from there). The tile's first query sits at
+        ``kv_len - q_len + qt * q_tile`` and sees ``window`` keys up to
+        itself. Without a window the walk starts at tile 0 and nothing
+        here is traced."""
+        if window is None:
+            return 0, 0, n_live
+        lo = jnp.maximum(
+            kvlen_ref[b] - qlen_ref[b] + qt * q_tile - (window - 1), 0)
+        first = lo // span
+        return lo, first, jnp.maximum(n_live - first, 0)
+
     kv_len, q_len, limit, n_live = frontier(b, qt)
+    lo, first, n_walk = behind(b, qt, n_live)
     # The grid step after this one: its first tile is started under this
     # step's last, so no step but the kernel's first waits for a cold fetch.
     step = b * n_q_tiles + qt
     wraps = qt + 1 == n_q_tiles
     b_nx = jnp.minimum(jnp.where(wraps, b + 1, b), pl.num_programs(0) - 1)
-    _, _, limit_nx, n_live_nx = frontier(b_nx, jnp.where(wraps, 0, qt + 1))
-    has_nx = (step + 1 < pl.num_programs(0) * n_q_tiles) & (n_live_nx > 0)
+    qt_nx = jnp.where(wraps, 0, qt + 1)
+    _, _, limit_nx, n_live_nx = frontier(b_nx, qt_nx)
+    lo_nx, first_nx, n_walk_nx = behind(b_nx, qt_nx, n_live_nx)
+    has_nx = (step + 1 < pl.num_programs(0) * n_q_tiles) & (n_walk_nx > 0)
 
     @pl.when(step == 0)
     def _cold():
@@ -391,18 +437,33 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         staging rows, semaphore) copy an arena into staging slot ``slot``.
         ``b`` None builds a copy to WAIT for: that needs its size and
         semaphore, not its source, so it reads no table entry."""
-        blk = 0
-        if b is not None:
+        if window is None:
             # Same defensive clamp as the gather path's mode="clip".
-            blk = jnp.clip(tbl_ref[b, tile * tile_blocks + i], 0,
-                           n_blocks - 1)
-        return [(arena.at[layer, blk], stage.at[slot, pl.ds(i * bs, bs)],
+            src = (layer, 0 if b is None else jnp.clip(
+                tbl_ref[b, tile * tile_blocks + i], 0, n_blocks - 1))
+        else:
+            # Ring storage: the row's slot, then the logical block's place
+            # in the slot's ring.
+            src = (layer, 0, 0) if b is None else (
+                layer, jnp.clip(tbl_ref[b, 0], 0, n_blocks - 1),
+                jax.lax.rem(tile * tile_blocks + i, arenas[0].shape[2]))
+        return [(arena.at[src], stage.at[slot, pl.ds(i * bs, bs)],
                  sems.at[slot, a])
                 for a, (arena, stage) in enumerate(zip(arenas, stages))]
 
-    def for_live_blocks(tile, limit, fn):
-        # Every tile but a slot's last is whole: no test a block there.
+    def for_live_blocks(tile, limit, lo, fn):
+        # Every tile but a slot's last is whole: no test a block there. (In
+        # the window build the walk's first tile is ragged at its start
+        # too: a block whose last row lies behind ``lo`` is not live.)
         whole = (tile + 1) * span <= limit
+        if window is not None:
+            whole &= tile * span + bs > lo
+
+        def live(i):
+            ok = tile * span + i * bs < limit
+            if window is not None:
+                ok &= tile * span + (i + 1) * bs > lo
+            return ok
 
         @pl.when(whole)
         def _all():
@@ -412,23 +473,23 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         @pl.when(jnp.logical_not(whole))
         def _ragged():
             for i in range(tile_blocks):
-                @pl.when(tile * span + i * bs < limit)
+                @pl.when(live(i))
                 def _(i=i):
                     fn(i)
 
-    def start_tile(b, tile, limit, slot):
+    def start_tile(b, tile, limit, lo, slot):
         def start(i):
             for src, dst, sem in block_copies(b, tile, slot, i):
                 probe.dma_issue(src)
                 pltpu.make_async_copy(src, dst, sem).start()
-        for_live_blocks(tile, limit, start)
+        for_live_blocks(tile, limit, lo, start)
 
     def wait_tile(tile, slot):
         def wait(i):
             for src, dst, sem in block_copies(None, tile, slot, i):
                 pltpu.make_async_copy(src, dst, sem).wait()
                 probe.dma_wait(src)
-        for_live_blocks(tile, limit, wait)
+        for_live_blocks(tile, limit, lo, wait)
 
     def accumulate(h, q, k, v, valid, guard):
         """One streaming-softmax turn of accumulator row ``h``: the scores
@@ -485,8 +546,10 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
             def scrub_v():
                 r = jax.lax.broadcasted_iota(jnp.int32, (width // 2, dh), 0)
                 w = words[1][slot]
-                words[1][slot] = jnp.where(
-                    r < (limit - base) * (n_kv // 2), w, jnp.zeros_like(w))
+                keep = r < (limit - base) * (n_kv // 2)
+                if window is not None:
+                    keep &= r >= (lo - base) * (n_kv // 2)
+                words[1][slot] = jnp.where(keep, w, jnp.zeros_like(w))
         else:
             def rows_of(a):
                 return stages[a][slot].reshape(width, dh)
@@ -495,13 +558,19 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                 row_pos = base + jax.lax.broadcasted_iota(
                     jnp.int32, (span, 1, 1), 0)
                 v = stages[1][slot]
-                stages[1][slot] = jnp.where(row_pos < limit, v,
-                                            jnp.zeros_like(v))
+                keep = row_pos < limit
+                if window is not None:
+                    keep &= row_pos >= lo
+                stages[1][slot] = jnp.where(keep, v, jnp.zeros_like(v))
 
         # Rows past the causal frontier hold whatever the pool or a skipped
         # fetch left there, and ``0 * NaN`` is NaN in the PV dot. They exist
-        # only in a slot's last, ragged tile: scrub V there and nowhere else.
-        pl.when(base + span > limit)(scrub_v)
+        # only in a slot's last, ragged tile (and, behind a window, in the
+        # walk's first): scrub V there and nowhere else.
+        ragged = base + span > limit
+        if window is not None:
+            ragged |= base < lo
+        pl.when(ragged)(scrub_v)
 
         q = q_ref[0, 0]                                  # (n_kv*g, dh)
         k, v = rows_of(0), rows_of(1)
@@ -515,6 +584,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         # row is fully masked and exp(_NEG_INF - max) is an exact zero: no
         # guard on p.
         valid = (col % n_kv == row // g) & (col < (limit - base) * n_kv)
+        if window is not None:
+            valid &= col >= (lo - base) * n_kv
         accumulate(0, q.astype(dt), k.astype(dt), v.astype(dt), valid,
                    guard=False)
         probe.compute(4 * n_kv * g * width * dh)
@@ -530,6 +601,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         # rows explicitly before contracting.
         row_pos = base + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
         row_live = row_pos < limit                           # (T*bs, 1) bool
+        if window is not None:
+            row_live &= row_pos >= lo
 
         rows = q_ref.shape[2]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
@@ -541,6 +614,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         j = (qt * q_tile
              + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0) // g)
         valid = (j < q_len) & (pos <= kv_len - q_len + j)
+        if window is not None:
+            valid &= pos > kv_len - q_len + j - window
         for h in range(n_kv):
             if latent:
                 q = q_ref[0, 0]                              # (q_tile*g, W)
@@ -574,27 +649,35 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         # j, or after its last the next step's tile 0 — then waits for and
         # computes tile j - 1 from the other slot. Probe row t holds tile
         # t's waits and compute and the starts issued beside them.
+        # (The window build's tiles are numbered from ``first``: turn j
+        # starts tile first + j and works on tile first + j - 1.)
         tile = j - 1
         probe.enter(row0 + jnp.maximum(tile, 0), 0, 1,
                     fresh=(j != 1) | (started == 1))
-        own = j < n_live
+        own = j < n_walk
 
         @pl.when(own | has_nx)
         def _start():
-            start_tile(jnp.where(own, b, b_nx), jnp.where(own, j, 0),
-                       jnp.where(own, limit, limit_nx),
+            whose = jnp.where(own, b, b_nx)
+            if window is None:
+                nth, lo_j = jnp.where(own, j, 0), 0
+            else:
+                nth = jnp.where(own, first + j, first_nx)
+                lo_j = jnp.where(own, lo, lo_nx)
+            start_tile(whose, nth, jnp.where(own, limit, limit_nx), lo_j,
                        jax.lax.rem(slot0 + j, 2))
 
         @pl.when(j > 0)
         def _work():
             slot = jax.lax.rem(slot0 + tile, 2)
-            wait_tile(tile, slot)
-            compute_tile(tile, slot)
+            nth = tile if window is None else first + tile
+            wait_tile(nth, slot)
+            compute_tile(nth, slot)
         return carry
 
-    jax.lax.fori_loop(started, n_live + 1, walk, 0)
+    jax.lax.fori_loop(started, n_walk + 1, walk, 0)
     walk_ref[0] = has_nx.astype(jnp.int32)
-    walk_ref[1] = jax.lax.rem(slot0 + n_live, 2)
+    walk_ref[1] = jax.lax.rem(slot0 + n_walk, 2)
     if probe_steps:
         # Rows of the tiles the walk never reached (outputs start
         # uninitialized): open them empty.
@@ -652,10 +735,22 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                     q_tile: int | None = None, interpret=None,
                     probes: bool = False, k_scale=None, v_scale=None,
                     layer=None, v_dim: int | None = None,
-                    resolved: dict | None = None):
+                    resolved: dict | None = None,
+                    window: int | None = None):
     """GQA attention of an L-token query block per slot directly over a
     block-paged KV pool — decode (L=1), chunked prefill, and ragged mixed
     steps all through ONE kernel.
+
+    WINDOW form (``window`` given): a query at position ``p`` sees the keys
+    ``p - window < j <= p``. ``k_pool`` / ``v_pool`` are a window layer's
+    ring storage ``(n_layers, n_slots, ring_blocks, block_size, Hkv, dh)``
+    (``serving.kv_pool``: token ``p`` of the sequence in slot ``s`` lies in
+    ring block ``(p // block_size) % ring_blocks`` of ``s``) with ``layer``,
+    and ``block_tables`` is ``(B, 1)`` int32: the SLOT each row reads. The
+    walk starts at the tile that holds the oldest visible key; what lies
+    behind the window costs no copy. The call is named
+    ``window_paged_attention`` in a device trace. No quantized or latent
+    build, no probes.
 
     LATENT form (``v_pool=None`` with ``v_dim``): ``k_pool`` is the one
     latent arena ``(n_blocks, block_size, W)`` — stacked ``(n_layers,
@@ -737,7 +832,19 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     if latent != (v_dim is not None):
         raise ValueError("v_dim goes with a latent pool (v_pool=None) and "
                          "only with it")
-    if latent:
+    if window is not None:
+        if latent or quant or probes:
+            raise NotImplementedError(
+                "the window build walks K and V arenas in the model dtype: "
+                "no latent, quantized or probed build")
+        if k_pool.ndim != 6 or layer is None or window < 1:
+            raise ValueError(
+                "the window build reads ring storage (n_layers, n_slots, "
+                "ring_blocks, block_size, Hkv, dh) at a layer, over a "
+                "window of at least one key")
+        block_tables = block_tables.reshape(B, 1)
+        _, n_blocks, ring, bs, Hkv, _ = k_pool.shape   # n_blocks: slots
+    elif latent:
         if quant:
             raise NotImplementedError("the latent pool has no quantized "
                                       "build")
@@ -764,7 +871,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     elif layer is None:
         raise ValueError("a stacked (n_layers, n_blocks, ...) arena needs "
                          "the layer to read")
-    if not latent:
+    if not latent and window is None:
         _, n_blocks, bs, Hkv, _ = k_pool.shape
     if k_pool.shape[-1] != dh:
         raise ValueError(f"pool rows are {k_pool.shape[-1]} wide, queries "
@@ -777,7 +884,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
             f"scalar-prefetch index path does no implicit cast, and a "
             f"float/int64 table silently truncating would read the wrong "
             f"blocks")
-    _, max_blocks = block_tables.shape
+    # The window build's table names slots, not blocks: what bounds its walk
+    # is the window and the step's take, and its tiles span about a window.
+    max_blocks = (block_tables.shape[1] if window is None
+                  else min(ring, pl.cdiv(window + L - 1, bs) + 1))
     g = Hq // Hkv
     scale = dh ** -0.5 if scale is None else scale
     if quant:
@@ -807,9 +917,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     q_tile = max(1, min(int(q_tile), L))
     n_tiles = pl.cdiv(max_blocks, tile_blocks)
     n_q_tiles = pl.cdiv(L, q_tile)
+    if window is not None:
+        # Logical tiles run as far as the longest sequence: the lengths
+        # bound the walk, no table does.
+        tile_blocks = max(1, min(tile_blocks, window // bs))
+        n_tiles = jnp.iinfo(jnp.int32).max // (tile_blocks * bs)
     # Pad the table on the right so the last tile's static fetch loop can
     # index it; padded entries sit past every kv_len and never DMA.
-    pad = n_tiles * tile_blocks - max_blocks
+    pad = n_tiles * tile_blocks - max_blocks if window is None else 0
     if pad:
         block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
 
@@ -818,7 +933,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     folded = arithmetic == "folded"
     if resolved is not None:
         resolved.update(tile_blocks=tile_blocks, q_tile=q_tile,
-                        arithmetic=arithmetic)
+                        arithmetic=arithmetic, window=window)
     if folded:
         # One token a grid step, every query head in one operand: q as it
         # arrives, (B, L, Hq, dh) with head h * g + j in kv head h's group,
@@ -851,14 +966,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         tile_blocks=tile_blocks, bs=bs, n_blocks=n_blocks, scale=scale,
         n_kv=Hkv, g=g, q_tile=q_tile, n_q_tiles=n_q_tiles, v_dim=v_dim,
         probe_steps=n_steps if probes else 0, folded=folded,
-        compiled=not interpret)
+        compiled=not interpret, window=window)
     dv = v_dim if latent else dh          # width of a value row
     out_specs = pl.BlockSpec((1, heads, rows, dv), q_index)
     out_shape = jax.ShapeDtypeStruct((*qh.shape[:3], dv), jnp.float32)
     scratch_shapes = [
         # Staging, two slots an arena: tile j + 1 lands in one while tile j
         # is computed from the other.
-        *(pltpu.VMEM((2, tile_blocks * bs, *a.shape[3:]), a.dtype)
+        *(pltpu.VMEM((2, tile_blocks * bs,
+                      *a.shape[3 if window is None else 4:]), a.dtype)
           for a in arenas),
         pltpu.VMEM((heads, rows, dv), jnp.float32),  # acc
         pltpu.VMEM((heads, rows, 1), jnp.float32),   # running max
@@ -900,7 +1016,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
             L=L, q_tile=q_tile),
         interpret=interpret,
         # What the device trace calls the kernel's events.
-        name="latent_paged_attention" if latent else "paged_attention",
+        name=("latent_paged_attention" if latent else
+              "paged_attention" if window is None else
+              "window_paged_attention"),
     )(block_tables, kv_lens, q_lens, layer, qh, *arenas)
     o = outs[0] if probes else outs
     if not folded:
@@ -976,7 +1094,8 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
                 n_kv: int = 2, g: int = 2, dh: int = 128,
                 max_blocks: int = 4, dtype: str = "float32", L: int = 1,
                 q_tile: int = 1, kvq: bool = False,
-                v_dim: int | None = None) -> "_comm.TraceSpec":
+                v_dim: int | None = None,
+                window: int | None = None) -> "_comm.TraceSpec":
     B = 2
     dt = _np.dtype(jnp.dtype(dtype))
     n_blocks = B * max_blocks
@@ -1012,6 +1131,16 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
             B, max_blocks)
         return t
 
+    # The window build: ring storage (layers, slots, ring blocks, ...) read
+    # by slot, the table one slot id a row; ``max_blocks`` is the ring.
+    pool = (2, n_blocks, bs)
+    if window is not None:
+        pool, tbl_w, n_blocks = (2, B, max_blocks, bs), 1, B
+        n_tiles = 1 << 20
+
+        def tables(r, w):                                   # noqa: F811
+            return _np.arange(B, dtype=_np.int32).reshape(B, 1)
+
     return _comm.TraceSpec(
         body=_paged_trace_body,
         ranks=1,
@@ -1027,7 +1156,7 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
             _comm.Buf("layer", (1,), _np.int32, space="smem",
                       init=lambda r, w: _np.ones((1,), _np.int32)),
             _comm.Buf("q", (*qo, dh), qdt),
-            *(_comm.Buf(name, (2, n_blocks, bs, *row), adt)
+            *(_comm.Buf(name, (*pool, *row), adt)
               for name, row, adt in arenas),
             # One (1, Hkv, q_tile*g, dh) window of q and o is VMEM-resident
             # per grid step; billing the full B=2 buffers stays within a
@@ -1048,7 +1177,8 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
         kwargs=dict(n_arenas=len(arenas), n_tiles=n_tiles,
                     tile_blocks=tile_blocks, bs=bs, n_blocks=n_blocks,
                     scale=1.0, n_kv=n_kv, g=g, q_tile=q_tile,
-                    n_q_tiles=n_q_tiles, v_dim=v_dim, folded=folded),
+                    n_q_tiles=n_q_tiles, v_dim=v_dim, folded=folded,
+                    window=window),
     )
 
 
@@ -1095,6 +1225,18 @@ def _paged_spec_latent(world: int, *, L: int = 8, q_tile: int = 4,
     one copy a block."""
     return _paged_spec(world, L=L, q_tile=q_tile, g=g, dh=dh, v_dim=v_dim,
                        **kw)
+
+
+@_comm.register("paged.window")
+def _paged_spec_window(world: int, *, window: int = 24, bs: int = 8,
+                       tile_blocks: int = 2, max_blocks: int = 6,
+                       **kw) -> "_comm.TraceSpec":
+    """The WINDOW build (ring storage read by slot, the walk started at the
+    window's first tile; ``max_blocks`` is the ring's blocks), decode shape:
+    contexts of the whole ring, so the first tile lies behind the window
+    and is neither copied nor waited for."""
+    return _paged_spec(world, window=window, bs=bs, tile_blocks=tile_blocks,
+                       max_blocks=max_blocks, **kw)
 
 
 def _register_paged_probe(base_name: str) -> None:

@@ -425,12 +425,15 @@ def _fused_paged_attention(arrays: dict, **static):
 def fused_paged_arithmetic() -> dict:
     """The arithmetic each traced shape of the fused paged-attention call
     took, ``{"q<shape>:<pool dtype>": "folded" | "per_head"}`` — this
-    process's record, written when a shape is first traced."""
+    process's record, written when a shape is first traced. A call of the
+    WINDOW build is named by it: ``"q<shape>:<pool dtype>:window<n>"``."""
     out = {}
-    for (names, avals, _), (_, arithmetic) in _FUSED_TRACES.items():
+    for (names, avals, static), (_, arithmetic) in _FUSED_TRACES.items():
         by_name = dict(zip(names, avals))
         q_shape = "x".join(str(d) for d in by_name["q"][0])
-        out[f"q{q_shape}:{by_name['k_pool'][1].name}"] = arithmetic
+        window = dict(static).get("window")
+        out[f"q{q_shape}:{by_name['k_pool'][1].name}"
+            + (f":window{window}" if window else "")] = arithmetic
     return out
 
 
@@ -440,7 +443,8 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
                           interpret=None, paged_attn: str = "fused",
                           kv_scales=None, layer=None):
     """GQA attention of new queries against a BLOCK-PAGED KV pool — the
-    paged twin of ``attn_with_cache``.
+    paged twin of ``attn_with_cache``. (A WINDOW layer's ring storage is
+    read by ``window_attn_with_cache``.)
 
     EVERY step routes through ``kernels.paged_attention.paged_attention``:
     the kernel walks the scalar-prefetched block table itself, so the pool
@@ -561,6 +565,91 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
     return attn_with_cache(q, k_view, v_view, offset, scale=scale,
                            use_flash_decode=use_flash_decode,
                            seq_lens=seq_lens, interpret=interpret)
+
+
+def window_attn_with_cache(q, k_ring, v_ring, slots, offset, *, window: int,
+                           layer, scale: float, slot_mask=None,
+                           seq_lens=None, interpret=None,
+                           paged_attn: str = "fused"):
+    """GQA attention of new queries over a WINDOW layer's ring storage: a
+    query at position ``p`` sees the keys ``p - window < j <= p``.
+
+    q: (B, L, Hq, dh); k/v_ring: ``(window layers, n_slots, ring_blocks,
+    block_size, Hkv, dh)`` (``serving.kv_pool``), read at ``layer``; the new
+    tokens' rows are already in it (``window_cache_update``).
+    ``slots`` (B,) int32: the slot each row belongs to. Line ``r`` of a
+    slot's ring (``ring_blocks * block_size`` lines) holds the NEWEST
+    position congruent to ``r``; what a line held before (an older lap, the
+    slot's last request) is never in a window, so the mask is by position
+    and nothing is cleared. Offsets, ``seq_lens`` and ``slot_mask`` as in
+    ``paged_attn_with_cache``; padding query rows give zeros. -> (B, L, Hq,
+    dh). ``paged_attn="fused"`` walks the ring inside
+    ``kernels.paged_attention`` from the tile that holds the oldest visible
+    key; ``"gather"`` is the plain-jnp oracle over the whole ring."""
+    if paged_attn not in ("fused", "gather"):
+        raise ValueError(
+            f"paged_attn must be 'fused' or 'gather', got {paged_attn!r}")
+    B, L, Hq, dh = q.shape
+    off = jnp.broadcast_to(jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
+    q_lens = (jnp.full((B,), L, jnp.int32) if seq_lens is None
+              else jnp.asarray(seq_lens, jnp.int32))
+    slots = jnp.asarray(slots, jnp.int32)
+    if paged_attn == "fused":
+        arrays = dict(q=q, k_pool=k_ring, v_pool=v_ring,
+                      block_tables=slots[:, None], kv_lens=off + q_lens,
+                      q_lens=q_lens, slot_mask=slot_mask, layer=layer)
+        return _fused_paged_attention(
+            {k: v for k, v in arrays.items() if v is not None},
+            scale=scale, interpret=interpret, window=int(window))
+    if slot_mask is not None:
+        slots = jnp.where(slot_mask, slots, 0)
+
+    def view(ring):
+        rows = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
+        rows = jnp.take(rows, slots, axis=0, mode="clip")
+        return rows.reshape(B, -1, *rows.shape[3:])      # (B, lines, Hkv, dh)
+
+    k, v = view(k_ring), view(v_ring)
+    lines, Hkv = k.shape[1:3]
+    last = (off + q_lens - 1)[:, None]                             # (B, 1)
+    # the newest position <= last that line r can hold; below 0: none yet
+    key_pos = last - (last - jnp.arange(lines)[None]) % lines      # (B, lines)
+    # (a line never written holds whatever the arena was born with: its
+    # weight is zero, and ``0 * NaN`` is NaN)
+    v = jnp.where((key_pos >= 0)[..., None, None], v, jnp.zeros_like(v))
+    q_pos = off[:, None] + jnp.arange(L)                           # (B, L)
+    live = (jnp.arange(L)[None] < q_lens[:, None])[..., None]      # (B, L, 1)
+    kp = key_pos[:, None, :]
+    mask = ((kp >= 0) & (kp <= q_pos[..., None])
+            & (kp > q_pos[..., None] - window) & live)
+    scores = jnp.einsum("blhgd,bshd->blhgs",
+                        q.reshape(B, L, Hkv, Hq // Hkv, dh), k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask[:, :, None, None], scores, _NEG_INF)
+    p = jnp.where(live[:, :, None, None], jax.nn.softmax(scores, axis=-1),
+                  0.0)
+    out = jnp.einsum("blhgs,bshd->blhgd", p, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, L, Hq, dh).astype(q.dtype)
+
+
+def window_cache_update(ring, new, slots, offsets, write_mask, layer):
+    """Write ``new`` (B, L, H, dh) into a window layer's RING storage
+    ``(n_layers, n_slots, ring_blocks, block_size, H, dh)``
+    (``serving.kv_pool``) at ``layer``: token (b, l) lands in ring block
+    ``((offsets[b] + l) // block_size) % ring_blocks`` of slot ``slots[b]``,
+    line ``(offsets[b] + l) % block_size``, over whatever an older lap left
+    there. ``write_mask`` (B,) or (B, L) drops masked writes, as
+    ``paged_cache_update`` does. Functional: returns the new storage."""
+    n_slots, n_ring, bs = ring.shape[1:4]
+    B, L = new.shape[:2]
+    pos = (jnp.asarray(offsets, jnp.int32)[:, None]
+           + jnp.arange(L, dtype=jnp.int32)[None])                 # (B, L)
+    slot = jnp.broadcast_to(jnp.asarray(slots, jnp.int32)[:, None], (B, L))
+    wm = write_mask if write_mask.ndim == 2 else write_mask[:, None]
+    slot = jnp.where(wm, slot, n_slots)             # out of range -> dropped
+    return ring.at[layer, slot, (pos // bs) % n_ring, pos % bs].set(
+        new.astype(ring.dtype), mode="drop")
 
 
 def latent_attn_with_cache(q, pool, block_tables, offset, *, v_dim: int,

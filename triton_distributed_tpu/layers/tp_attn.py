@@ -71,6 +71,11 @@ class TPAttn:
     # Key heads that share one row of the PAGED pool (``_attend``): heads
     # narrower than the lane count lie side by side in a lane-wide row.
     kv_pack: int = 1
+    # A WINDOW layer (paged path only): a query sees the last ``window``
+    # keys up to itself, and the layer's rows live in the pool's ring
+    # storage (``state.wk`` / ``state.wv``), read and appended at ``layer``
+    # of the WINDOW layers. None: every key, the block arenas.
+    window: int | None = None
 
     def sizes(self, world: int):
         """(q_size, kv_size) per device."""
@@ -220,6 +225,9 @@ class TPAttn:
                                      seq_lens=seq_lens, interpret=interpret)
             return out.reshape(*qkv.shape[:2], -1), (k_cache, v_cache)
 
+        if self.window is not None:
+            return self._attend_window(params, qkv, cache, world, blocks,
+                                       scale, paged_attn, layer, interpret)
         state, queries = cache, []
         for blk in blocks:
             part = qkv[blk.start:blk.stop].reshape(-1, blk.L, qkv.shape[-1])
@@ -263,6 +271,62 @@ class TPAttn:
             paged_attn=paged_attn, kv_scales=scales,
             layer=layer)).reshape(blk.stop - blk.start, -1)
             for q, blk in zip(queries, blocks)]
+        tail = qkv.shape[0] - blocks[-1].stop
+        if tail:
+            outs.append(jnp.zeros((tail, outs[0].shape[-1]), outs[0].dtype))
+        return jnp.concatenate(outs), state
+
+    def _attend_window(self, params, qkv, state, world, blocks, scale,
+                       paged_attn, layer, interpret):
+        """``_attend``'s paged path for a window layer: the same order
+        (every block's rows appended, then every block attended), over the
+        pool's ring storage. A row is found by its SLOT (``blk.slots``; row
+        b of the decode block is slot b), not by a table of blocks: several
+        rows of the prefill block may be one slot's consecutive chunks, and
+        the ring holds the window and a step's largest take, so the later
+        rows' appends overwrite nothing the first row reads."""
+        if state.wk is None:
+            raise ValueError(
+                "the pool's state has no window storage: build the pool "
+                "from this model's configuration (KVPool(config, ..., "
+                "n_slots=...))")
+        if self.kv_pack > 1 or world != 1:
+            raise NotImplementedError(
+                "a window layer is built for one device and unpacked rows")
+        # All of a step's appends come before any read, and the reader masks
+        # by position: a line overwritten under the first row would be read
+        # as a valid key. A block that names its rows' slots may give ONE
+        # slot every row; one that names none gives slot b row b.
+        take = max(blk.L * (1 if blk.slots is None else blk.offsets.shape[0])
+                   for blk in blocks)
+        lines = state.wk.shape[2] * state.wk.shape[3]
+        if self.window - 1 + take > lines:
+            raise ValueError(
+                f"a slot's ring holds {lines} lines, and a step that gives "
+                f"one slot {take} tokens behind a window of {self.window} "
+                f"needs {self.window - 1 + take}: build the pool with "
+                f"max_take >= {take} (KVPool(config, ..., max_take=))")
+        # the slot of each row: row b of a block that names none is slot b
+        slots = [jnp.arange(blk.offsets.shape[0], dtype=jnp.int32)
+                 if blk.slots is None else blk.slots for blk in blocks]
+        queries = []
+        for blk, at in zip(blocks, slots):
+            part = qkv[blk.start:blk.stop].reshape(-1, blk.L, qkv.shape[-1])
+            q, k, v = self._qkv_rope(params, part, blk.offsets, world)
+            queries.append(q)
+            wm = blk.valid().reshape(-1, blk.L)
+            state = dataclasses.replace(
+                state,
+                wk=nn.window_cache_update(state.wk, k, at, blk.offsets, wm,
+                                          layer),
+                wv=nn.window_cache_update(state.wv, v, at, blk.offsets, wm,
+                                          layer))
+        outs = [nn.window_attn_with_cache(
+            q, state.wk, state.wv, at, blk.offsets, window=self.window,
+            layer=layer, scale=scale, slot_mask=blk.mask,
+            seq_lens=blk.seq_lens, interpret=interpret,
+            paged_attn=paged_attn).reshape(blk.stop - blk.start, -1)
+            for q, blk, at in zip(queries, blocks, slots)]
         tail = qkv.shape[0] - blocks[-1].stop
         if tail:
             outs.append(jnp.zeros((tail, outs[0].shape[-1]), outs[0].dtype))

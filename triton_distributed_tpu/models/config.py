@@ -21,12 +21,18 @@ class from the class of the object it is given:
 - ``NemotronHConfig``: the Nemotron-H block (every layer ONE mixer: Mamba-2,
   routed relu² experts with a shared expert, or attention, in the order a
   pattern string gives), run by ``models.nemotron_h.NemotronH``.
+- ``ExaoneMoeConfig``: the EXAONE-MoE block (attention in every layer, some
+  layers over a WINDOW of the last keys with rope and the others over every
+  key without; a dense SwiGLU or sigmoid-routed experts with a shared expert
+  after it), run by ``models.exaone_moe.ExaoneMoe``.
 
 Each states what ``serving.kv_pool.KVPool`` builds the pool's state from:
 ``kv_row_shapes`` (what one token's row of each row arena looks like),
-``n_cache_layers`` (how many layers keep such rows) and
+``n_cache_layers`` (how many layers keep such rows for the WHOLE context),
 ``slot_state_shapes`` (the arenas that hold a fixed-size state for each
-SLOT, none for a model whose every layer keeps rows).
+SLOT, none for a model whose every layer keeps rows) and, where some layers
+keep rows for a window only, ``n_window_layers`` and ``window`` (a ring of
+rows for each slot, ``serving.kv_pool``).
 """
 
 from __future__ import annotations
@@ -409,6 +415,137 @@ class NemotronHConfig:
             mamba_n_groups=2, moe_d_ff=24, shared_d_ff=48, n_experts=8,
             n_experts_per_tok=2, max_length=64, dtype=jnp.float32),
             **overrides})
+
+
+_EXAONE_PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExaoneMoeConfig:
+    """The EXAONE-MoE decoder (HF ``exaone_moe``; HF key in brackets).
+    Defaults are K-EXAONE-236B-A23B's public ``config.json``. Every layer is
+    grouped-query attention with a per-head RMSNorm on queries and keys and
+    then an FFN. ``layer_types`` names each layer's attention:
+    ``"sliding_attention"`` sees the last ``sliding_windows[i]`` keys up to
+    itself and takes rope; ``"full_attention"`` (window 0) sees every key
+    and takes no position embedding. ``mlp_layer_types`` names each layer's
+    FFN: ``"dense"`` (a SwiGLU of ``d_ff``) or ``"sparse"`` (sigmoid-routed
+    SwiGLU experts of ``moe_d_ff`` beside ``n_shared_experts`` shared ones).
+    Nothing here assumes a period: the walk is read from the two tuples.
+
+    ``experts_held`` / ``experts_lo`` as ``DeepseekV3Config`` has them: this
+    device is one chip's share of an expert-parallel deployment and holds
+    the routed experts ``[experts_lo, experts_lo + experts_held)`` of every
+    sparse layer; the router keeps its published width.
+
+    The pool it describes: ``kv_row_shapes`` rows of ``n_kv_heads x
+    head_dim`` for K and for V; ``n_cache_layers`` layers (the full ones)
+    keep them for the whole context, ``n_window_layers`` keep them for a
+    window of ``window`` tokens in a ring a slot."""
+
+    model_name: str = "LGAI-EXAONE/K-EXAONE-236B-A23B"
+    vocab_size: int = 153_600
+    d_model: int = 6144                # hidden_size
+    layer_types: tuple = _EXAONE_PERIOD * 12
+    sliding_windows: tuple = (128, 128, 128, 0) * 12
+    mlp_layer_types: tuple = ("dense",) + ("sparse",) * 47
+    n_heads: int = 64                  # num_attention_heads
+    n_kv_heads: int = 8                # num_key_value_heads
+    head_dim: int = 128
+    d_ff: int = 18_432                 # intermediate_size (dense layers)
+    moe_d_ff: int = 2048               # moe_intermediate_size
+    n_experts: int = 128               # num_experts: the router's width
+    n_experts_per_tok: int = 8         # num_experts_per_tok
+    n_shared_experts: int = 1          # num_shared_experts
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    experts_held: int | None = None
+    experts_lo: int = 0
+    rope_theta: float = 1e6            # rope_parameters.rope_theta, default
+    rms_eps: float = 1e-5              # rms_norm_eps
+    max_length: int = 4096
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def __post_init__(self):
+        n = len(self.layer_types)
+        if not n or len(self.sliding_windows) != n \
+                or len(self.mlp_layer_types) != n:
+            raise ValueError("layer_types, sliding_windows and "
+                             "mlp_layer_types name every layer, each once")
+        bad = (set(self.layer_types)
+               - {"sliding_attention", "full_attention"}) \
+            | (set(self.mlp_layer_types) - {"dense", "sparse"})
+        if bad:
+            raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        windows = {w for t, w in zip(self.layer_types, self.sliding_windows)
+                   if t == "sliding_attention"}
+        if len(windows) > 1 or 0 in windows or any(
+                w for t, w in zip(self.layer_types, self.sliding_windows)
+                if t == "full_attention"):
+            raise ValueError(
+                "window layers share ONE window of at least one key (the "
+                "pool keeps one ring geometry) and full layers state 0")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError("heads do not divide the widths given")
+        if not (0 <= self.experts_lo and self.n_held >= 1
+                and self.experts_lo + self.n_held <= self.n_experts):
+            raise ValueError(
+                f"experts held [{self.experts_lo}, "
+                f"{self.experts_lo + self.n_held}) do not lie inside the "
+                f"router's {self.n_experts}")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """``(attention, ffn)`` a layer: ``"window"`` | ``"full"`` and
+        ``"dense"`` | ``"moe"``."""
+        return tuple(
+            ("window" if t == "sliding_attention" else "full",
+             "dense" if m == "dense" else "moe")
+            for t, m in zip(self.layer_types, self.mlp_layer_types))
+
+    @property
+    def kv_row_shapes(self):
+        row = (self.n_kv_heads, self.head_dim)
+        return row, row
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Layers whose rows are kept for the whole context."""
+        return self.layer_types.count("full_attention")
+
+    @property
+    def n_window_layers(self) -> int:
+        """Layers whose rows are kept for a window only."""
+        return self.layer_types.count("sliding_attention")
+
+    @property
+    def window(self) -> int:
+        return max(self.sliding_windows)
+
+    slot_state_shapes = None
+
+    @classmethod
+    def tiny(cls, **overrides) -> "ExaoneMoeConfig":
+        """Tiny float32 sizes for tests (not a real checkpoint): a dense
+        layer then experts, window (6) and full layers in no period."""
+        return cls(**{**dict(
+            model_name="tiny-exaone-moe", vocab_size=128, d_model=64,
+            layer_types=("sliding_attention", "sliding_attention",
+                         "full_attention", "sliding_attention"),
+            sliding_windows=(6, 6, 0, 6),
+            mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
+            n_heads=4, n_kv_heads=2, head_dim=16, d_ff=96, moe_d_ff=32,
+            n_experts=8, n_experts_per_tok=2, rope_theta=1e4, max_length=64,
+            dtype=jnp.float32), **overrides})
 
 
 # Public Qwen3 architecture hyper-parameters (HF config.json values).

@@ -25,6 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_distributed_tpu.models.config import (
     DeepseekV3Config,
+    ExaoneMoeConfig,
     GraniteHybridConfig,
     ModelConfig,
     NemotronHConfig,
@@ -51,12 +52,17 @@ def model_for(config, *, block_n: int = 256):
         from triton_distributed_tpu.models.nemotron_h import NemotronH
 
         return NemotronH(config)
+    if isinstance(config, ExaoneMoeConfig):
+        from triton_distributed_tpu.models.exaone_moe import ExaoneMoe
+
+        return ExaoneMoe(config)
     return Qwen3(config, block_n=block_n)
 
 
 class Engine:
     def __init__(self, config: ModelConfig | DeepseekV3Config
-                 | GraniteHybridConfig | NemotronHConfig, *,
+                 | GraniteHybridConfig | NemotronHConfig
+                 | ExaoneMoeConfig, *,
                  mesh: Mesh | None = None,
                  mode: str = "dist", prefill_mode: str | None = None,
                  temperature: float = 0.0, top_p: float = 1.0,

@@ -269,10 +269,12 @@ class BatchEngine:
                    ``engine.prefix_cache.enabled = False`` toggles it off
                    at runtime without touching compiled state. A model
                    some of whose layers keep a fixed-size state a slot
-                   (``config.slot_state_shapes``) gets none, whatever is
-                   asked: blocks hold rows, not the state at their
-                   boundary, so every admission of such a model prefills
-                   from offset 0 (docs/serving.md).
+                   (``config.slot_state_shapes``) or a window of rows a
+                   slot (``config.n_window_layers``) gets none, whatever
+                   is asked (``KVPool.prefix_cacheable``): blocks hold
+                   rows, not the state or the window at their boundary,
+                   so every admission of such a model prefills from
+                   offset 0 (docs/serving.md).
 
     Always-on observability (bounded, defaults ON; what it costs a step
     on the chip is not measured):
@@ -367,7 +369,8 @@ class BatchEngine:
         self.pool = KVPool(engine.config, n_blocks=n_blocks,
                            block_size=block_size, max_seq_len=max_seq_len,
                            mesh=engine.mesh, axis=engine.model.axis,
-                           kv_dtype=kv_dtype, n_slots=n_slots)
+                           kv_dtype=kv_dtype, n_slots=n_slots,
+                           max_take=self.prefill_rows * prefill_chunk)
         self.scheduler = Scheduler()
         self.metrics = Metrics(windowed=windowed_metrics)
         if blackbox:
@@ -445,10 +448,14 @@ class BatchEngine:
         # not: a cached block holds rows, not the state at its boundary, so
         # a request that adopted blocks would start past tokens its state
         # has never seen. Every such admission starts at offset 0, where
-        # the layer that keeps the state starts it from zero.
+        # the layer that keeps the state starts it from zero. Nor does a
+        # model with window layers: a cached block does not carry their
+        # last rows at its boundary (``KVPool.prefix_cacheable``), and an
+        # evicted request's ring is another's by the time it comes back,
+        # so admission and re-admission alike prefill from offset 0.
         self.prefix_cache = (RadixPrefixCache(self.pool,
                                               metrics=self.metrics)
-                             if prefix_cache and not self.pool.slot_state
+                             if prefix_cache and self.pool.prefix_cacheable
                              else None)
         self.trace_counts = {"decode": 0, "prefill": 0}
         # Fault sites ("engine.decode"/"engine.prefill") whose jitted step
@@ -855,7 +862,8 @@ class BatchEngine:
                      "n_used": self.pool.n_used,
                      "n_cached": self.pool.n_cached,
                      "n_reclaimable": self.pool.n_reclaimable,
-                     "slot_state_bytes": self.pool.slot_state_bytes},
+                     "slot_state_bytes": self.pool.slot_state_bytes,
+                     "window_bytes": self.pool.window_bytes},
             "counters": {k: m.get(k, 0.0) for k in (
                 "requests_admitted", "requests_completed",
                 "requests_failed", "tokens_generated", "preemptions",

@@ -23,6 +23,29 @@ slot's state belongs to whatever sequence sits in the slot, and the layer
 that reads it starts a sequence at cache length 0 from zero
 (``layers/mamba2.py``).
 
+A model some of whose attention layers see only a WINDOW of the last ``w``
+keys (``config.n_window_layers`` of them, ``config.window``) keeps those
+layers' rows in a third kind of arena, by the window and not by the context:
+a RING a slot,
+
+    wk, wv: (window layers, n_slots, ring_blocks, block_size, n_kv_heads,
+             head_dim)
+
+Token ``p`` of the sequence in slot ``s`` lies in ring block ``(p //
+block_size) % ring_blocks`` of ``s``, line ``p % block_size``, over whatever
+an older lap (or the slot's last request) left there. So the window layers
+need no allocator, no table beyond arithmetic, and nothing to free; their
+bytes do not move with ``max_seq_len`` or ``n_blocks``; and what a line held
+before is never seen, because a reader masks by POSITION (line ``r`` holds
+the newest position congruent to ``r`` that the sequence has written). All
+appends of a step come before any read and one slot may take several rows
+of the mixed step's prefill block, so the ring holds the window AND a step's
+largest take (``window_ring_blocks``): the later rows' appends then land on
+lines that the first row's window has left behind. ``n_layers`` of the block
+arenas is then the count of FULL layers only. What such a pool cannot give:
+a prefix cache (a cached block would have to carry the window layers' last
+``w`` rows at its boundary: ``prefix_cacheable``).
+
 Plus a HOST-side free-list allocator mapping sequences onto blocks. A
 sequence of ``n`` tokens owns ``ceil(n / block_size)`` blocks, listed in
 order in its block table; internal fragmentation is bounded by one block
@@ -163,6 +186,10 @@ class PagedKVState:
     # (state layers, n_slots, (d_conv - 1) * conv width): the last inputs
     # of a causal convolution, oldest first
     conv: jax.Array | None = None
+    # (window layers, n_slots, ring_blocks, block_size, n_kv_heads,
+    # head_dim): the ring storage of the layers that see a window of keys
+    wk: jax.Array | None = None
+    wv: jax.Array | None = None
 
     @property
     def n_blocks(self) -> int:
@@ -174,6 +201,21 @@ class PagedKVState:
 
 
 ROW_ARENAS = ("k", "v", "k_scale", "v_scale")
+WINDOW_ARENAS = ("wk", "wv")
+
+
+def window_kind(config) -> tuple[int, int]:
+    """(layers that keep a window of rows, the window) as the model's
+    configuration states them; (0, 0) for a model that states none."""
+    n = int(getattr(config, "n_window_layers", 0) or 0)
+    return (n, int(config.window)) if n else (0, 0)
+
+
+def window_ring_blocks(window: int, block_size: int, max_take: int) -> int:
+    """Blocks of one slot's ring: the positions live in a step are the
+    ``window - 1`` behind the step's first query and its ``max_take`` new
+    tokens, and no two of them may share a line."""
+    return blocks_needed(window - 1 + max(1, max_take), block_size)
 
 
 def paged_state_specs(config, axis: str = "tp", *,
@@ -190,23 +232,45 @@ def paged_state_specs(config, axis: str = "tp", *,
     latent = config.kv_row_shapes[1] is None
     kv = PartitionSpec() if latent else KVCache.spec(axis)[0]
     scale = KVCache.scale_spec(axis) if quant else None
+    # (the ring storage of window layers is replicated too: no model runs
+    # them on more than one device yet)
+    ring = PartitionSpec() if window_kind(config)[0] else None
     return PagedKVState(k=kv, v=None if latent else kv,
-                        k_scale=scale, v_scale=scale,
+                        k_scale=scale, v_scale=scale, wk=ring, wv=ring,
                         **dict.fromkeys(config.slot_state_shapes or (),
                                         PartitionSpec()))
 
 
 def paged_state_shapes(config, *, n_blocks: int, block_size: int,
-                       n_slots: int | None = None,
-                       kv_dtype=None) -> PagedKVState:
+                       n_slots: int | None = None, kv_dtype=None,
+                       max_take: int | None = None) -> PagedKVState:
     """The pool's state as ``jax.ShapeDtypeStruct``s, a ``PagedKVState`` of
     the structure ``paged_state_specs`` gives: what ``KVPool`` allocates,
     and what a compile rehearsal hands the step in place of arrays. Row
     arenas ``(n_cache_layers, n_blocks, block_size, *row)`` in the wire
     dtype (scale arenas the same minus the row's width, float32), per-slot
-    arenas ``(n_state_layers, n_slots, *shape)`` as the model states them."""
+    arenas ``(n_state_layers, n_slots, *shape)`` as the model states them,
+    window storage ``(n_window_layers, n_slots, ring_blocks, block_size,
+    *row)`` with ``ring_blocks`` from the window and ``max_take``, the most
+    tokens one slot appends in a step: a model with window layers has no
+    default for it (a ring built for a smaller take than a step's is
+    overwritten under the step's first row, and the mask by position reads
+    the newer rows as valid keys)."""
     dtype, quant = resolve_kv_dtype(config, kv_dtype)
     k_row, v_row = config.kv_row_shapes
+    n_window, window = window_kind(config)
+    ring = None
+    if n_window:
+        if n_slots is None or max_take is None or quant or v_row is None:
+            raise ValueError(
+                "window layers keep a ring of K and V rows for each slot in "
+                "the model dtype: the pool needs n_slots and max_take (the "
+                "most tokens one slot appends in a step), and has no "
+                "quantized or latent build of them")
+        ring = jax.ShapeDtypeStruct(
+            (n_window, n_slots,
+             window_ring_blocks(window, block_size, max_take), block_size,
+             *k_row), dtype)
     rows = jax.ShapeDtypeStruct(
         (config.n_cache_layers, n_blocks, block_size, *k_row), dtype)
     scale = (jax.ShapeDtypeStruct(rows.shape[:-1], jnp.float32)
@@ -218,7 +282,7 @@ def paged_state_shapes(config, *, n_blocks: int, block_size: int,
             f"the pool needs n_slots to build its arenas")
     return PagedKVState(
         k=rows, v=None if v_row is None else rows, k_scale=scale,
-        v_scale=scale,
+        v_scale=scale, wk=ring, wv=ring,
         **{name: jax.ShapeDtypeStruct(
             (config.n_state_layers, n_slots, *shape), jnp.dtype(dt))
            for name, (shape, dt) in slot_state.items()})
@@ -231,12 +295,16 @@ class KVPool:
     bounds any one sequence (sets the fixed block-table width the compiled
     step sees). ``mesh``/``axis`` shard the kv-head dim like ``KVCache``.
     ``n_slots``: the serving batch's width, which a model with per-slot
-    state (``config.slot_state_shapes``) needs its arenas built for.
+    state (``config.slot_state_shapes``) or window layers needs its arenas
+    built for. ``max_take``: the most tokens one slot appends in a step
+    (the mixed step's prefill block, whole), which sizes a window layer's
+    ring: required of a model with window layers, not read of any other.
     """
 
     def __init__(self, config, *, n_blocks: int, block_size: int = 16,
                  max_seq_len: int | None = None, mesh=None, axis: str = "tp",
-                 kv_dtype=None, n_slots: int | None = None):
+                 kv_dtype=None, n_slots: int | None = None,
+                 max_take: int | None = None):
         if n_blocks <= 0 or block_size <= 0:
             raise ValueError(f"bad pool geometry ({n_blocks=}, {block_size=})")
         self.block_size = block_size
@@ -268,6 +336,9 @@ class KVPool:
         if self.slot_state and self.kv_quant:
             raise NotImplementedError(
                 "a pool with per-slot state has no quantized build")
+        # ... and which layers keep only a window of rows, in a ring a slot.
+        self.window_layers, self.window = window_kind(config)
+        self.max_take = max_take
         #: PartitionSpecs of ``state``, leaf for leaf.
         self.specs = paged_state_specs(config, axis, quant=self.kv_quant)
         # Each arena is born in its sharded layout: ``jnp.zeros`` +
@@ -285,7 +356,7 @@ class KVPool:
         # empty subtree, which the map passes over)
         self.state = jax.tree.map(arena, self.specs, paged_state_shapes(
             config, n_blocks=n_blocks, block_size=block_size,
-            n_slots=n_slots, kv_dtype=kv_dtype))
+            n_slots=n_slots, kv_dtype=kv_dtype, max_take=max_take))
         # LIFO free list, low block ids first out — recently freed blocks
         # are reused immediately (warm in whatever cache level they touched).
         self._free: list[int] = list(range(n_blocks - 1, -1, -1))
@@ -365,6 +436,12 @@ class KVPool:
             geo["slot_state"] = {
                 name: list(getattr(self.state, name).shape)
                 for name in sorted(self.slot_state)}
+        if self.window_layers:
+            geo["window"] = {
+                "layers": self.window_layers, "window": self.window,
+                "max_take": self.max_take,
+                "ring_blocks": self.state.wk.shape[2],
+                "bytes": self.window_bytes}
         return geo
 
     @property
@@ -372,6 +449,23 @@ class KVPool:
         """Bytes of the per-slot arenas (0 for a pool of rows only)."""
         return sum(getattr(self.state, name).nbytes
                    for name in self.slot_state)
+
+    @property
+    def window_bytes(self) -> int:
+        """Bytes of the window layers' ring storage (0 without any): fixed
+        by the window, the step's take and the slots, whatever
+        ``max_seq_len`` and ``n_blocks`` are."""
+        return sum(getattr(self.state, name).nbytes
+                   for name in WINDOW_ARENAS) if self.window_layers else 0
+
+    @property
+    def prefix_cacheable(self) -> bool:
+        """Whether a cached block is all that a later request needs of a
+        prefix. Not where a layer keeps a state a slot (the block holds
+        rows, not the state at its boundary), nor where a layer keeps a
+        window of rows a slot (the block would have to carry that layer's
+        last ``window`` rows at its boundary)."""
+        return not self.slot_state and not self.window_layers
 
     def kv_fingerprint(self) -> str:
         """Wire-format identity of this pool's KV bytes: ``dtype:scheme``
@@ -384,6 +478,8 @@ class KVPool:
             scheme += f":latent{self._row_width}"
         if self.slot_state:
             scheme += ":slot[" + "+".join(sorted(self.slot_state)) + "]"
+        if self.window_layers:
+            scheme += f":window{self.window}x{self.window_layers}"
         return f"{self.kv_dtype.name}:{scheme}"
 
     def owned(self, seq_id) -> int:
@@ -711,8 +807,22 @@ class KVPool:
                 "K and V arenas differ")
         # The per-slot arenas are the ones the model states, one entry a
         # (state layer, slot) each.
+        # The window storage exists iff the model has window layers: K and V
+        # rings alike, one a (window layer, slot), rows as the K arena's.
+        for name in WINDOW_ARENAS:
+            a = getattr(st, name)
+            if not self.window_layers:
+                assert a is None, f"pool carrying an unasked arena {name}"
+                continue
+            ring = window_ring_blocks(self.window, self.block_size,
+                                      self.max_take)
+            assert a is not None and a.dtype == self.kv_dtype, (
+                f"pool missing its window storage {name}")
+            assert (a.shape[0], a.shape[2]) == (self.window_layers, ring) \
+                and a.shape[3:] == st.k.shape[2:], (
+                    f"window storage {name}: {a.shape}")
         for f in dataclasses.fields(st):
-            if f.name in ROW_ARENAS:
+            if f.name in ROW_ARENAS or f.name in WINDOW_ARENAS:
                 continue
             a = getattr(st, f.name)
             if f.name not in self.slot_state:
