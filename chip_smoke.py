@@ -25,7 +25,11 @@ block (``models/exaone_moe.py``: K-EXAONE-236B-A23B's widths, two layers,
 one over a WINDOW of 128 keys whose rows live in a ring a slot and one over
 every key, 16 of 128 experts held, an eighth of the vocabulary): the window
 build of the block walk at its chunk shape against its decode shape, over a
-walk long enough to pass the window and to wrap the ring.
+walk long enough to pass the window and to wrap the ring. In all three the
+chunk shape takes every walked prompt several rows of the prefill block a
+step, every prompt is held to the tolerance, and where the model routes both
+programs' routers are on record: a prompt is left out only where its token
+was routed to other experts at a shown tie (``ROUTER_TIE``).
 
 ``--chips 4`` (a four-chip host) runs only the tensor-parallel phase:
 Qwen3-8B over ``make_mesh({"tp": 4})`` through ``BatchEngine``, once in
@@ -42,6 +46,7 @@ performance results.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import faulthandler
 import json
 import sys
@@ -101,6 +106,23 @@ EXAONE_MOE = dict(HYBRID, config="ExaoneMoeConfig",
 # their own scale (ratio near 1). float32 (the CPU test) gets 1e-4.
 TOL_BF16 = 0.1
 TOL_F32 = 1e-4
+# One program over the same tokens, its prefill rows dealt two ways: the
+# hand-over from row to row is bit for bit what the arenas' round trip gives
+# (0.0 in every arena and logit on the chip, PR 40).
+DEAL_TOL = 1e-4
+# A router's margin is its topk-th largest biased score less the next one
+# (sigmoid scores). Two correct programs whose hidden states differ by their
+# bfloat16 rounding choose differently where it is small, and a token so
+# routed is compared with another function of its input: the one case in
+# which a prompt is left out of a comparison, by the run's own record
+# (``routing_difference``). On the chip (PR 40, the Nemotron-H block, chunk
+# shapes against one-token shapes, 66 compared tokens x layers under each of
+# the parent's and this PR's programs): rounding alone moves a score by up
+# to 1.34e-3 (median 4.2e-4), and the three tokens it routed apart had
+# margins of 7e-5 to 6.8e-4 and sides 2.7e-4 to 9.9e-4 apart; the next
+# token after one of them, its state off by one expert and not by rounding,
+# read 5.7e-3 apart; the median margin is 6.8e-3.
+ROUTER_TIE = 2.5e-3
 RUN_LIMIT_S = 1150
 
 
@@ -181,16 +203,62 @@ def peak_bytes(devices) -> list:
 # -- numeric references ------------------------------------------------------
 
 
-def paged_logits(be, prompts, next_tok):
+@contextlib.contextmanager
+def routing_recorded():
+    """While open, a step traced also tells the host what the router of
+    every expert layer saw: ``HeldExpertsMoE.routed`` is wrapped so that the
+    ``topk + 1`` largest biased scores of every row of the flat token batch,
+    and their experts, leave through ``jax.debug.callback`` beside the
+    layer's index; nothing the step computes changes. Yields ``at(rows) ->
+    {layer: (scores, experts)}``, both (len(rows), topk + 1), of the LAST
+    step run."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from triton_distributed_tpu.layers.moe_mlp import HeldExpertsMoE
+
+    seen: dict = {}
+    inner = HeldExpertsMoE.routed
+
+    def routed(self, params, x, valid=None, *, layer_idx=None, **kw):
+        s = jax.nn.sigmoid(jnp.dot(                 # as ``route`` has them
+            x.astype(jnp.float32), params["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        jax.debug.callback(
+            lambda layer, *top: seen.__setitem__(
+                int(layer), tuple(np.asarray(a) for a in top)),
+            -1 if layer_idx is None else layer_idx,
+            *jax.lax.top_k(s + params["bias"].astype(jnp.float32),
+                           self.topk + 1))
+        return inner(self, params, x, valid, layer_idx=layer_idx, **kw)
+
+    def at(rows):
+        jax.effects_barrier()
+        return {layer: tuple(a[np.asarray(rows)] for a in top)
+                for layer, top in sorted(seen.items())}
+
+    HeldExpertsMoE.routed = routed
+    try:
+        yield at
+    finally:
+        HeldExpertsMoE.routed = inner
+
+
+def paged_logits(be, prompts, next_tok, rows=None, routing=None):
     """Logits of the PAGED programs on ``be``'s own pool for equal-length
     ``prompts``: chunked prefill through ``Engine._make_sm(paged="prefill")``
     (the mixed step's forward, which returns logits where the serving step
     returns sampled tokens; its token batch the served one, an idle decode
-    block beside the prompts' rows of the prefill block), then one
+    block beside the prompts' rows of the prefill block, every prompt
+    ``rows`` consecutive rows a step, by default its share of the block: a
+    model with per-slot state chains them), then one
     decode-shaped step
     (``paged="decode"``) feeding ``next_tok``; the pool's state goes in and
     comes back whole, donated, as in the serving steps. Returns float32
-    ``(prefill_last_position_logits, decode_logits)``, one row a prompt."""
+    ``(prefill_last_position_logits, decode_logits)``, one row a prompt;
+    with ``routing`` (``routing_recorded``'s ``at``, open around the call)
+    a third entry, what the routers saw at those two tokens."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -212,20 +280,30 @@ def paged_logits(be, prompts, next_tok):
         live = np.arange(n) < n_p
         mask = jnp.asarray(live)
         toks = np.asarray(prompts, np.int32)
-        check(n_p <= be.prefill_rows, f"{n_p} reference prompts do not fit "
-              f"a prefill block of {be.prefill_rows} rows")
-        for off in range(0, plen, chunk):
-            take = min(chunk, plen - off)
+        # the block's rows as the host deals them: every prompt its share
+        # of consecutive rows, each but the last full, named by (slot, cache
+        # length before the row, live tokens); a dead row names no slot
+        rows = rows or be.prefill_rows // n_p
+        check(1 <= rows <= be.prefill_rows // n_p, f"{n_p} reference "
+              f"prompts of {rows} rows each do not fit a prefill block of "
+              f"{be.prefill_rows} rows")
+        for off in range(0, plen, rows * chunk):
+            take = min(rows * chunk, plen - off)
             tok = np.zeros((n,), np.int32)
             block = np.zeros((be.prefill_rows, chunk), np.int32)
-            # the block's rows as the host deals them: (slot, cache length
-            # before the row, live tokens), a dead row naming no slot
             dealt = np.tile(np.int32([-1, 0, 0]), (be.prefill_rows, 1))
-            if take == 1:        # one token rides the decode block
+            ends = list(range(n_p))   # a prompt's last token in the flat
+            if take == 1:             # batch: one rides the decode block
                 tok[:n_p] = toks[:, off]
             else:
-                block[:n_p, :take] = toks[:, off:off + take]
-                dealt[:n_p] = [(i, off, take) for i in range(n_p)]
+                k = 0
+                for i in range(n_p):
+                    for at in range(off, off + take, chunk):
+                        n_k = min(chunk, off + take - at)
+                        dealt[k] = i, at, n_k
+                        block[k, :n_k] = toks[i, at:at + n_k]
+                        ends[i] = n + chunk * k + n_k - 1
+                        k += 1
             pre_logits, _, pool.state = pre(
                 eng.params,
                 (jnp.asarray(tok), jnp.asarray(block), jnp.asarray(dealt)),
@@ -233,17 +311,20 @@ def paged_logits(be, prompts, next_tok):
                 jnp.asarray(np.where(live, off, 0).astype(np.int32)),
                 tables, mask,
                 jnp.asarray(np.where(live, take, 0).astype(np.int32)))
+        seen = [routing(ends)] if routing else []
         ids = np.zeros((n, 1), np.int32)
         ids[:n_p, 0] = next_tok
         dec_logits, _, pool.state = dec(
             eng.params, jnp.asarray(ids), pool.state,
             jnp.asarray(np.where(live, plen, 0).astype(np.int32)),
             tables, mask)
+        seen += [routing(range(n_p))] if routing else []
     finally:
         for sid in sids:
             pool.release(sid)
     return (np.asarray(pre_logits, np.float32)[:n_p],
-            np.asarray(dec_logits, np.float32)[:n_p])
+            np.asarray(dec_logits, np.float32)[:n_p]) + (
+                (seen,) if routing else ())
 
 
 def contiguous_logits(engine, prompts, next_tok):
@@ -259,27 +340,64 @@ def contiguous_logits(engine, prompts, next_tok):
             np.asarray(dec_logits, np.float32))
 
 
+def routing_difference(a: dict, b: dict, i: int) -> dict | None:
+    """The first expert layer, in depth order, whose router chose other
+    experts for token ``i`` on side ``a`` than on side ``b`` (two records of
+    ``routing_recorded``), or None where every layer chose alike: the
+    ``topk + 1`` best experts of each side, each side's margin, the largest
+    distance between the sides' sorted scores, and ``tie``: all three under
+    ``ROUTER_TIE``, so the routers saw the same scores and the boundary lay
+    within what the sides differ by."""
+    import numpy as np
+
+    for layer in a:
+        (sa, ea), (sb, eb) = a[layer], b[layer]
+        if set(ea[i, :-1]) != set(eb[i, :-1]):
+            margin = [float(s[i, -2] - s[i, -1]) for s in (sa, sb)]
+            shift = float(np.abs(sa[i] - sb[i]).max())
+            return {"layer": layer, "experts": [ea[i].tolist(),
+                                                eb[i].tolist()],
+                    "margin": margin, "shift": shift,
+                    "tie": max(*margin, shift) < ROUTER_TIE}
+    return None
+
+
 def compare_logits(what: str, got, ref, tol: float) -> None:
+    """``got`` and ``ref``: (prefill logits, decode logits), one row a
+    prompt, every row held to ``tol``. Where BOTH bring their routers'
+    record as a third entry, a prompt whose compared token was routed
+    differently at a shown tie (``routing_difference``) is left out of that
+    token's comparison, and the line says so."""
     import numpy as np
 
     out = {"phase": "numeric", "compared": what, "tolerance": tol}
-    for name, g, r in (("prefill", got[0], ref[0]),
-                       ("decode", got[1], ref[1])):
+    judged = {}
+    for at, name in enumerate(("prefill", "decode")):
+        g, r = got[at], ref[at]
         check(g.shape == r.shape, f"{what}: {name} logits shape {g.shape} "
               f"!= reference {r.shape}")
         check(bool(np.isfinite(g).all() and np.isfinite(r).all()),
               f"{what}: non-finite {name} logits")
         out[f"{name}_max_abs_diff"] = float(np.abs(g - r).max())
         out[f"{name}_max_abs_ref"] = float(np.abs(r).max())
-        out[f"{name}_rel"] = (out[f"{name}_max_abs_diff"]
-                              / max(out[f"{name}_max_abs_ref"], 1e-30))
+        by_prompt = np.abs(g - r).max(-1) / max(out[f"{name}_max_abs_ref"],
+                                                1e-30)
+        out[f"{name}_rel"] = float(by_prompt.max())
+        out[f"{name}_rel_by_prompt"] = by_prompt.tolist()
         out[f"{name}_argmax_equal"] = int(
             (g.argmax(-1) == r.argmax(-1)).sum())
+        held = np.ones(len(g), bool)
+        if len(got) > 2 and len(ref) > 2:
+            out[f"{name}_routed_apart"] = apart = [
+                dict(d, prompt=i) for i in range(len(g))
+                if (d := routing_difference(got[2][at], ref[2][at], i))]
+            held[[d["prompt"] for d in apart if d["tie"]]] = False
+        judged[name] = float(by_prompt[held].max(initial=0.0))
     emit(**out)
-    for name in ("prefill", "decode"):
-        check(out[f"{name}_rel"] <= tol,
-              f"{what}: {name} logits differ by {out[f'{name}_rel']:.4g} "
-              f"of the reference scale (tolerance {tol})")
+    for name, rel in judged.items():
+        check(rel <= tol,
+              f"{what}: {name} logits differ by {rel:.4g} of the "
+              f"reference scale (tolerance {tol})")
 
 
 def logit_tolerance(config) -> float:
@@ -485,7 +603,7 @@ def run_one_chip(devices, geo: dict, caches: _CacheEvents) -> None:
 # -- one chip: the hybrid block (per-slot state beside paged rows) ------------
 
 
-def decode_walk_logits(be, prompts, next_tok):
+def decode_walk_logits(be, prompts, next_tok, routing=None):
     """What ``paged_logits`` gives, with every token of the prompts fed ONE
     AT A TIME through the decode-shaped step: the state advances through
     the one-token update's kernel alone, never through the chunk scan."""
@@ -507,7 +625,7 @@ def decode_walk_logits(be, prompts, next_tok):
         live = np.arange(n) < n_p
         toks = np.concatenate([np.asarray(prompts, np.int32),
                                np.asarray(next_tok, np.int32)[:, None]], 1)
-        rows = []
+        rows, seen = [], []
         for pos in range(plen + 1):
             ids = np.zeros((n, 1), np.int32)
             ids[:n_p, 0] = toks[:, pos]
@@ -516,11 +634,14 @@ def decode_walk_logits(be, prompts, next_tok):
                 jnp.asarray(np.where(live, pos, 0).astype(np.int32)),
                 tables, jnp.asarray(live))
             rows.append(logits)
+            if routing and pos >= plen - 1:
+                seen.append(routing(range(n_p)))
     finally:
         for sid in sids:
             pool.release(sid)
     return (np.asarray(rows[-2], np.float32)[:n_p],
-            np.asarray(rows[-1], np.float32)[:n_p])
+            np.asarray(rows[-1], np.float32)[:n_p]) + (
+                (seen,) if routing else ())
 
 
 def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
@@ -583,19 +704,31 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
           f"the step's counts do not add up to {tokens} tokens of "
           f"{geo['n_requests']} requests: {want}")
     check(not c.get("moe_dropped_pairs"), "a routed pair was dropped")
-    if window_layers:
-        check(c.get("prefill_rows_extra", 0) > 0, "no prompt took a second "
-              "row of the prefill block: the deal did not engage")
+    check(c.get("prefill_rows_extra", 0) > 0, "no prompt took a second "
+          "row of the prefill block: the deal did not engage")
 
-    # Numbers: the same tokens through the chunk scan (chunked prefill,
-    # then one decode step) and through the kernel alone.
-    walked = [p[:geo["walk_len"]] for p in prompts[:2]]
+    # Numbers: the same tokens through the chunk shapes (chunked prefill,
+    # every prompt several rows of the block a step, then one decode step)
+    # and through the one-token shapes alone. Two programs: where the model
+    # routes, the routers' record of both says whether a token that differs
+    # was routed apart at a tie. And, where a slot keeps a state that its
+    # rows hand on, the same tokens with one row a step, through the arenas:
+    # one program, so nothing is routed apart and a state handed on wrongly
+    # has nowhere to hide.
+    walked = [p[:geo["walk_len"]] for p in prompts[:min(3, be.n_slots)]]
     next_tok = [p[0] for p in walked]
+    with routing_recorded() as routing:
+        dealt = paged_logits(be, walked, next_tok, routing=routing)
+        walk = decode_walk_logits(be, walked, next_tok, routing=routing)
+        one_row = be.pool.slot_state and paged_logits(be, walked, next_tok,
+                                                      rows=1)
     compare_logits(f"{geo['config']}: chunked prefill + decode step vs "
                    f"the same tokens one at a time",
-                   paged_logits(be, walked, next_tok),
-                   decode_walk_logits(be, walked, next_tok),
-                   logit_tolerance(cfg))
+                   dealt, walk, logit_tolerance(cfg))
+    if one_row:
+        compare_logits(f"{geo['config']}: a prompt's rows dealt "
+                       f"{be.prefill_rows // len(walked)} a step vs one a "
+                       f"step", dealt[:2], one_row, DEAL_TOL)
     be.pool.check_invariants()
     emit(phase="hybrid_memory", peak_bytes_in_use=peak_bytes(devices[:1]))
 

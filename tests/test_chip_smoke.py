@@ -84,7 +84,7 @@ def test_hybrid_phase_passes_at_tiny(capsys, restore_compile_cache_config):
     # (the gather path: the fused block walk under the interpreter is
     # tests/test_granite_hybrid.py's)
     geo = dict(chip_smoke.HYBRID, interpret=None, paged_attn="gather",
-               n_slots=2, block_size=4,
+               n_slots=6, block_size=4,
                prefill_chunk=8, n_requests=3, prompt_range=(10, 20),
                new_tokens=3, walk_len=10,
                overrides=dataclasses.asdict(GraniteHybridConfig.tiny()))
@@ -93,11 +93,16 @@ def test_hybrid_phase_passes_at_tiny(capsys, restore_compile_cache_config):
                for line in capsys.readouterr().out.strip().splitlines()]
     assert rc == 0 and records[-1]["ok"] is True
     phases = [r.get("phase") for r in records[:-1]]
-    assert phases == ["hybrid_build", "hybrid_serve", "numeric",
+    assert phases == ["hybrid_build", "hybrid_serve", "numeric", "numeric",
                       "hybrid_memory"]
     assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
     assert records[1]["ssm_states_reset"] == 3
+    assert records[1]["prefill_rows_extra"] > 0     # a slot's rows chained
+    # chunks against tokens, then rows dealt two a step against one a step
     assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+    assert records[2]["decode_routed_apart"] == []  # no router, none apart
+    assert "2 a step vs one a step" in records[3]["compared"]
+    assert records[3]["prefill_rel"] == 0 == records[3]["decode_rel"]
 
 
 def test_nemotron_h_phase_passes_at_tiny(capsys, restore_compile_cache_config):
@@ -108,7 +113,7 @@ def test_nemotron_h_phase_passes_at_tiny(capsys, restore_compile_cache_config):
     from triton_distributed_tpu.models.config import NemotronHConfig
 
     geo = dict(chip_smoke.NEMOTRON_H, interpret=None, paged_attn="gather",
-               n_slots=2, block_size=4,
+               n_slots=6, block_size=4,
                prefill_chunk=8, n_requests=3, prompt_range=(10, 20),
                new_tokens=3, walk_len=10,
                overrides=dataclasses.asdict(NemotronHConfig.tiny()))
@@ -119,7 +124,12 @@ def test_nemotron_h_phase_passes_at_tiny(capsys, restore_compile_cache_config):
     assert records[0]["state_layers"] == 4 and records[0]["cache_layers"] == 1
     assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
     assert records[1]["moe_pairs_held"] > 0 == records[1]["moe_dropped_pairs"]
+    assert records[1]["prefill_rows_extra"] > 0     # a slot's rows chained
     assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+    # both programs' routers were read, and chose alike at float32
+    assert records[2]["prefill_routed_apart"] == [] \
+        == records[2]["decode_routed_apart"]
+    assert records[3]["prefill_rel"] == 0 == records[3]["decode_rel"]
 
 
 def test_exaone_moe_phase_passes_at_tiny(capsys, restore_compile_cache_config):
@@ -146,6 +156,87 @@ def test_exaone_moe_phase_passes_at_tiny(capsys, restore_compile_cache_config):
     assert records[1]["moe_pairs_held"] > 0 == records[1]["moe_dropped_pairs"]
     assert records[1]["prefill_rows_extra"] > 0
     assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+    assert records[2]["decode_routed_apart"] == []
+    # no state handed from row to row: no second comparison
+    assert [r.get("phase") for r in records].count("numeric") == 1
+
+
+def _routing(margin, experts=(4, 9, 2), base=0.5):
+    """One layer's record for three prompts: prompt 0's router ``margin``
+    apart at its top-2 boundary and choosing ``experts[:2]``, the others'
+    boundaries wide."""
+    scores = np.tile(np.float32([0.9, 0.6, 0.3]), (3, 1))
+    scores[0] = [0.9, base, base - margin]
+    chosen = np.tile(np.int32([1, 5, 7]), (3, 1))
+    chosen[0] = experts
+    return {0: (scores, chosen)}
+
+
+@pytest.mark.parametrize("case,off,got,ref,tie,ok", [
+    ("equal logits, no record", 0, None, None, None, True),
+    ("one prompt apart, no record", 1, None, None, None, False),
+    ("apart, routed alike", 1, _routing(1e-5), _routing(1e-5), None, False),
+    ("apart, routed apart at a tie", 1,
+     _routing(1e-5), _routing(2e-5, (4, 2, 9)), True, True),
+    ("apart, one side's margin wide", 1,
+     _routing(1e-5), _routing(0.05, (4, 2, 9)), False, False),
+    ("apart, margins small but other scores", 1,
+     _routing(1e-5), _routing(2e-5, (4, 2, 9), base=0.4), False, False),
+    ("two apart, one tie", 2,
+     _routing(1e-5), _routing(2e-5, (4, 2, 9)), True, False),
+])
+def test_only_a_prompt_routed_apart_at_a_tie_is_left_out(
+        capsys, case, off, got, ref, tie, ok):
+    """``compare_logits`` holds every prompt to the tolerance; with both
+    sides' routers on record, a prompt is left out only where its token was
+    routed to other experts, both margins are under ``ROUTER_TIE`` and the
+    two routers saw the same scores."""
+    logits = np.linspace(-1.0, 1.0, 3 * 8, dtype=np.float32).reshape(3, 8)
+    moved = logits.copy()
+    moved[:off] += 0.5
+    sides = [(moved, moved) + ((r, r),) * (r is not None)
+             for moved, r in ((moved, got), (logits, ref))]
+    if ok:
+        chip_smoke.compare_logits(case, *sides, 0.1)
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match="prefill logits"):
+            chip_smoke.compare_logits(case, *sides, 0.1)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["prefill_rel"] == pytest.approx(0.5 if off else 0.0)
+    assert len(line["prefill_rel_by_prompt"]) == 3
+    if got is not None:
+        assert [(d["prompt"], d["layer"], d["tie"])
+                for d in line["decode_routed_apart"]] == (
+            [] if tie is None else [(0, 0, tie)])
+
+
+def test_the_routers_record_is_what_route_chose():
+    """``routing_recorded``: the experts it hands the host are the ones
+    ``HeldExpertsMoE.route`` chose, in the step's own order, with the next
+    best beside them; closed, the layer is its own again."""
+    import jax.numpy as jnp
+
+    from triton_distributed_tpu.layers.moe_mlp import HeldExpertsMoE
+
+    moe = HeldExpertsMoE(d_model=16, d_ff=16, n_experts=8, topk=2, n_held=4,
+                         dtype=jnp.float32, gated=False)
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    params = {"router": jax.random.normal(k[0], (16, 8)),
+              "bias": 0.1 * jax.random.normal(k[1], (8,)),
+              "w_up": jax.random.normal(k[2], (4, 4, 16, 16)),  # by layer
+              "w_down": jax.random.normal(k[3], (4, 4, 16, 16))}
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, 16))
+    inner = HeldExpertsMoE.routed
+    with chip_smoke.routing_recorded() as at:
+        y, _ = jax.jit(lambda p, x: moe.routed(p, x, layer_idx=3))(params, x)
+        (scores, experts), = at([0, 5, 15]).values()
+        assert list(at([0])) == [3]
+    assert HeldExpertsMoE.routed is inner
+    _, ids = moe.route(params["router"], params["bias"], x)
+    np.testing.assert_array_equal(experts[:, :2], np.asarray(ids)[[0, 5, 15]])
+    assert experts.shape == (3, 3) and (np.diff(scores, axis=1) <= 0).all()
+    np.testing.assert_allclose(y, moe.routed(params, x, layer_idx=3)[0],
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_forced_step_exception_fails_the_smoke(

@@ -7,6 +7,7 @@ wherever the fused kernel is not the thing tested.
 """
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -22,6 +23,7 @@ from triton_distributed_tpu.kernels.ssm_update import (
     ssm_state_update_reference,
 )
 from triton_distributed_tpu.layers import nn
+from triton_distributed_tpu.layers.mamba2 import Mamba2, draw_own
 from triton_distributed_tpu.models.config import (
     GraniteHybridConfig,
     ModelConfig,
@@ -30,7 +32,7 @@ from triton_distributed_tpu.models.engine import Engine
 from triton_distributed_tpu.obs import trace as _trace
 from triton_distributed_tpu.runtime.mesh import make_mesh
 from triton_distributed_tpu.serving.batch_engine import BatchEngine
-from triton_distributed_tpu.serving.kv_pool import KVPool
+from triton_distributed_tpu.serving.kv_pool import KVPool, PagedKVState
 
 # Two periods of (Mamba-2, attention, Mamba-2); two key heads of 16 packed
 # into one row of 32.
@@ -308,6 +310,141 @@ def test_the_chunk_scan_equals_the_sequential_one_with_a_ragged_last_chunk(
                                           np.asarray(was)[keep])
 
 
+# Rows of one slot chained through the chunk scan. A case is the prefill
+# block as the host could deal it: runs ``(slot, cache length before the
+# run, tokens)`` and dead rows (None), rows of 8 in a block of 7 over an
+# arena of 4 slots (a dead row names the last, which no run uses).
+CHAIN_L, CHAIN_P, CHAIN_SLOTS = 8, 7, 4
+CHAINS = {
+    "one slot: full, full, ragged last": [(1, 0, 21)],
+    "two slots' runs side by side": [(0, 0, 20), (2, 0, 13)],
+    "a dead row between and after runs": [(0, 0, 16), None, (1, 0, 11),
+                                          None],
+    "one run from zero, one mid-prompt from the arena": [(0, 0, 12),
+                                                         (1, 8, 17)],
+    "the last full row ends at the prompt's end": [(2, 0, 24), (0, 0, 8)],
+}
+
+
+@functools.cache
+def chain_layer(groups):
+    """One ``Mamba2`` layer of 8 heads in ``groups`` groups with drawn
+    weights, the reference's view of the same weights, and the layer's
+    forward over one gathered block (state layer 1 of 2)."""
+    m = dataclasses.replace(SIZES, d_model=32, ssm_heads=8, ssm_head_width=4,
+                            ssm_groups=groups)
+    layer = Mamba2(d_model=32, n_heads=8, d_head=4, d_state=m.ssm_state,
+                   d_conv=m.ssm_conv, n_groups=groups, rms_eps=m.eps,
+                   dtype=jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(groups), 8)
+    lp = {name: (draw_own(name, k, shape) if fan_in is None else
+                 jax.random.normal(k, shape) / np.sqrt(fan_in))
+          for k, (name, (shape, fan_in))
+          in zip(keys, layer.param_shapes().items())}
+    lp["conv_b"] = 0.1 * jax.random.normal(keys[0], lp["conv_b"].shape)
+
+    @jax.jit
+    def fwd(x, state, slots, offsets, lens):
+        blk = nn.TokenBlock(0, CHAIN_L, offsets, None, lens > 0, lens, slots)
+        return layer.fwd(lp, x, state, blocks=(blk,), layer=jnp.int32(1),
+                         interpret=True)
+
+    return m, {**lp, "gate_norm": lp["norm"]}, fwd
+
+
+@pytest.mark.parametrize("groups", [1, 8])
+@pytest.mark.parametrize("case", sorted(CHAINS))
+def test_rows_of_one_slot_are_chained_through_the_chunk_scan(case, groups):
+    """A slot's run of rows in ONE call of the layer gives the ``y``, the
+    final state and the final window that the same rows give one call a
+    row (no row chained: the path a block of distinct slots takes) and that
+    the reference's sequential scan of the whole sequence gives. The arena
+    ends on each run's LAST row; dead rows and rows that are followed write
+    nothing, so every other entry is as it was, to the bit."""
+    m, lw, fwd = chain_layer(groups)
+    L, P, B = CHAIN_L, CHAIN_P, CHAIN_SLOTS
+    K, C = m.ssm_conv, m.conv_width
+    rng = np.random.default_rng(len(case))
+    dirty = PagedKVState(
+        k=jnp.zeros((1, 1, 1, 1, 1)), v=jnp.zeros((1, 1, 1, 1, 1)),
+        ssm=jnp.asarray(rng.standard_normal(
+            (2, B, m.ssm_heads, m.ssm_head_width, m.ssm_state)), jnp.float32),
+        conv=jnp.asarray(rng.standard_normal((2, B, (K - 1) * C)),
+                         jnp.float32))
+    runs = [r for r in CHAINS[case] if r is not None]
+    seqs = {slot: jnp.asarray(rng.standard_normal((before + n, m.d_model)),
+                              jnp.float32) for slot, before, n in runs}
+    rows = []                      # (slot, cache length before, live) a row
+    for run in CHAINS[case]:
+        if run is None:
+            rows.append(None)
+            continue
+        slot, before, n = run
+        rows += [(slot, before + at, min(L, n - at)) for at in range(0, n, L)]
+    rows += [None] * (P - len(rows))
+    assert len(rows) == P
+
+    def call(state, placed):
+        """The block with the rows ``placed`` (row of the block -> (slot,
+        cache length before, live)); every other row dead."""
+        x = np.zeros((P, L, m.d_model), np.float32)
+        ops = np.tile(np.int32([B - 1, 0, 0]), (P, 1))
+        for k, (slot, at, n) in placed.items():
+            ops[k] = slot, at, n
+            x[k, :n] = seqs[slot][at:at + n]
+        y, state = fwd(jnp.asarray(x.reshape(P * L, -1)), state,
+                       *jnp.asarray(ops.T))
+        return np.asarray(y).reshape(P, L, -1), state
+
+    # a run that starts mid-prompt: its slot's arena holds what came before
+    start = dirty
+    for slot, before, _ in runs:
+        for at in range(0, before, L):
+            _, start = call(start, {0: (slot, at, min(L, before - at))})
+    live = {k: r for k, r in enumerate(rows) if r is not None}
+    got, state = call(start, live)
+    apart, state1 = np.zeros_like(got), start
+    for k, row in live.items():
+        y, state1 = call(state1, {k: row})
+        apart[k] = y[k]
+    with jax.default_matmul_precision("highest"):
+        want = {slot: np.asarray(family.ssm_mixer(m, x, lw, "float32"))
+                for slot, x in seqs.items()}
+    for k, (slot, at, n) in live.items():
+        np.testing.assert_allclose(got[k, :n], apart[k, :n], atol=1e-5)
+        np.testing.assert_allclose(got[k, :n], want[slot][at:at + n],
+                                   atol=2e-5)
+    touched = np.zeros((2, B), bool)
+    for slot, before, n in runs:
+        touched[1, slot] = True
+        np.testing.assert_allclose(state.ssm[1, slot], state1.ssm[1, slot],
+                                   atol=1e-5)
+        # the window: the run's last K-1 raw inputs of the convolution
+        xbc = jnp.dot(seqs[slot][-(K - 1):], lw["w_in"],
+                      precision="highest")[:, m.d_inner:m.d_inner + C]
+        np.testing.assert_allclose(state.conv[1, slot], xbc.reshape(-1),
+                                   atol=1e-5)
+        np.testing.assert_allclose(state.conv[1, slot], state1.conv[1, slot],
+                                   atol=1e-6)
+    for got_a, was in ((state.ssm, dirty.ssm), (state.conv, dirty.conv)):
+        np.testing.assert_array_equal(np.asarray(got_a)[~touched],
+                                      np.asarray(was)[~touched])
+
+
+def test_a_prefill_row_shorter_than_the_window_is_refused_by_name():
+    """A chained row takes its window from the row before alone: a block
+    whose rows are shorter than ``d_conv - 1`` is refused when the step is
+    traced."""
+    m, _, _ = chain_layer(1)
+    layer = Mamba2(d_model=32, n_heads=8, d_head=4, d_state=m.ssm_state,
+                   d_conv=m.ssm_conv, dtype=jnp.float32)
+    blk = nn.TokenBlock(0, 2, jnp.zeros(3, jnp.int32), None, None,
+                        jnp.full(3, 2), jnp.arange(3))
+    with pytest.raises(ValueError, match="prefill_chunk = 2 is below"):
+        layer._block(None, jnp.zeros((6, layer.param_shapes()["w_in"][0][1])),
+                     None, blk, 0, True)
+
+
 @pytest.mark.parametrize("groups,tile", [(1, 2), (2, 4), (1, 8), (2, 8),
                                          (8, 4), (8, 8), (4, None)])
 def test_the_state_update_kernel_equals_plain_jnp(groups, tile):
@@ -336,15 +473,36 @@ def test_the_state_update_kernel_equals_plain_jnp(groups, tile):
     np.testing.assert_array_equal(got[2], arena[2])
 
 
-def test_a_model_with_per_slot_state_keeps_one_row_a_slot(served):
-    """The host deals the prefill block's rows, and a prompt alone would
-    take every free one; not here: every row of a slot starts from the
-    arena's state, so two rows of one slot would both start from the same
-    state. Two prompts into a block of four rows take ONE row each a step
-    (8, 8, 4 and 8, 4), as before the deal, and give what each gives alone."""
+DEAD = [-1, 0, 0]
+DEALS = {
+    # the whole chunk a row: a takes three rows of the first step
+    8: dict(steps=[([[0, 0, 8], [0, 8, 8], [0, 16, 4], [1, 0, 8]],
+                    [20, 8, 0, 0]),
+                   ([[1, 8, 4], DEAD, DEAD, DEAD], [1, 4, 0, 0])],
+            filled=5, extra=2, prefill_steps=2),
+    # a narrowed budget: a row cut short could not be followed, so one row
+    # a slot, as before the deal
+    4: dict(steps=[([[0, 0, 4], [1, 0, 4], DEAD, DEAD], [4, 4, 0, 0]),
+                   ([[0, 4, 4], [1, 4, 4], DEAD, DEAD], [4, 4, 0, 0]),
+                   ([[0, 8, 4], [1, 8, 4], DEAD, DEAD], [4, 4, 0, 0]),
+                   ([[0, 12, 4], DEAD, DEAD, DEAD], [4, 1, 0, 0]),
+                   ([[0, 16, 4], DEAD, DEAD, DEAD], [4, 1, 0, 0])],
+            filled=8, extra=0, prefill_steps=5),
+}
+
+
+@pytest.mark.parametrize("budget", sorted(DEALS, reverse=True))
+def test_a_model_with_per_slot_state_takes_every_free_row(served, budget):
+    """The host deals the prefill block's rows and a prompt takes every
+    free one, with per-slot state too: the state layers chain a slot's rows
+    (``layers.mamba2``). Two prompts (20 and 12 tokens) into a block of four
+    rows of 8: the older takes three rows of the first step, and each gives
+    what it gives alone; still two programs. Under a ``prefill_budget``
+    below the chunk a slot keeps to one (narrowed) row a step."""
     batch_engine(served)            # the donor of the steps, never wrapped
     be = batch_engine(served)
     assert be.prefill_rows == 4 and be.pool.slot_state
+    be.prefill_budget = budget
     calls, step = [], be._mixed_step
 
     def recording(*args):
@@ -356,14 +514,14 @@ def test_a_model_with_per_slot_state_keeps_one_row_a_slot(served):
     a, b = prompts(41, 20, 12)
     rids = [be.submit(a, 3), be.submit(b, 3)]
     be.run()
-    dead = [-1, 0, 0]
-    assert calls[:3] == [
-        ([[0, 0, 8], [1, 0, 8], dead, dead], [8, 8, 0, 0]),
-        ([[0, 8, 8], [1, 8, 4], dead, dead], [8, 4, 0, 0]),
-        ([[0, 16, 4], dead, dead, dead], [4, 1, 0, 0])]
+    want = DEALS[budget]
+    assert calls[:len(want["steps"])] == want["steps"]
     c = be.metrics.counters
-    assert c["prefill_rows_filled"] == 5 and c["prefill_rows_extra"] == 0
-    assert c["prefill_steps"] == 3 and c["prefill_tokens"] == 32
+    assert (c["prefill_rows_filled"], c["prefill_rows_extra"]) == \
+        (want["filled"], want["extra"])
+    assert c["prefill_steps"] == want["prefill_steps"]
+    assert c["prefill_tokens"] == 32 and c["ssm_states_reset"] == 2
+    assert be.trace_counts == {"decode": 1, "prefill": 1}
     for rid, prompt in zip(rids, (a, b)):
         assert be.finished[rid].output == alone(served, prompt, 3)
 

@@ -211,6 +211,70 @@ def test_prefill_then_decode_of_rows_admitted_at_different_steps_agrees_on_logit
     assert_logits_agree(got_b, TOKENS_B, 5)        # positions 4, 5, 6, 7
 
 
+# The prefill block (4 rows of 8) as the host could deal it for slots a
+# (0) and b (2): ``(slot, cache length before the row, live tokens)``.
+CHAINED_DEALS = {
+    "one slot, three rows": [(0, 0, 8), (0, 8, 8), (0, 16, 5)],
+    "two slots side by side": [(0, 0, 8), (0, 8, 8), (0, 16, 5), (2, 0, 8)],
+    "a dead row between": [(0, 0, 8), (0, 8, 8), None, (2, 0, 8)],
+    "a run from the arena": [(2, 0, 8), (0, 8, 8), (0, 16, 5)],
+}
+
+
+@pytest.mark.parametrize("deal", sorted(CHAINED_DEALS))
+def test_a_slots_rows_in_one_mixed_step_give_what_a_row_a_step_gives(served,
+                                                                     deal):
+    """The whole model's mixed step with several rows of the block dealt to
+    ONE slot (its four state layers chain them; its attention layer appends
+    all and reads each to its own end) against the same rows one a step:
+    the logits, every state layer's state and window, and the reference's
+    full forward pass. "A run from the arena": a's first row went alone, a
+    step before."""
+    a, b = TOKENS_A, TOKENS_B
+    rows = CHAINED_DEALS[deal]
+    pool, pre, _ = paged_steps(served, 3)
+    assert pool.ensure("a", 21) and pool.ensure("b", 8)
+    tables = jnp.asarray(pool.padded_tables(["a", None, "b"]))
+    seqs = {0: a, 2: b}
+
+    def mixed(state, rows):
+        chunk = np.zeros((4, 8), np.int32)
+        dealt = np.tile(np.int32([-1, 0, 0]), (4, 1))
+        lens, off = np.zeros(3, np.int32), np.zeros(3, np.int32)
+        for k, row in enumerate(rows):
+            if row is not None:
+                slot, at, n = dealt[k] = row
+                chunk[k, :n] = seqs[slot][at:at + n]
+                off[slot] = at if lens[slot] == 0 else off[slot]
+                lens[slot] += n
+        logits, aux, state = pre(
+            served.params, (jnp.zeros((3,), jnp.int32), jnp.asarray(chunk),
+                            jnp.asarray(dealt)), state, jnp.asarray(off),
+            tables, jnp.asarray(lens > 0), jnp.asarray(lens))
+        assert aux["stats"].tolist()[4:] == [
+            lens.sum() * N_M, sum(r is not None and r[1] == 0 for r in rows),
+            lens.sum() * N_A]
+        return np.asarray(logits), state
+
+    start = pool.state
+    if rows[0] != (0, 0, 8) and (0, 8, 8) in rows:
+        _, start = mixed(start, [(0, 0, 8)])
+    got, state = mixed(start, rows)
+    state1, apart = start, {}
+    for row in filter(None, rows):
+        logits, state1 = mixed(state1, [row])
+        apart[row[0]] = logits[row[0]]
+    for slot, logits in apart.items():
+        np.testing.assert_allclose(got[slot], logits, atol=2e-5)
+    done = {slot: max(at + n for s, at, n in filter(None, rows) if s == slot)
+            for slot in apart}
+    for slot, end in done.items():
+        assert_logits_agree([got[slot]], seqs[slot][:end], end)
+    np.testing.assert_allclose(state.ssm, state1.ssm, atol=1e-5)
+    np.testing.assert_allclose(state.conv, state1.conv, atol=1e-6)
+    np.testing.assert_allclose(state.k, state1.k, atol=1e-5)
+
+
 FAULTS = {
     # the last expert layer's shared expert gives nothing
     "moe": lambda t: dict(t, moe=dict(t["moe"], shared=dict(
@@ -518,15 +582,36 @@ def test_counts_of_the_published_configuration():
         (2688, 1856, 3712, 6, 2.5, 8, 128, 4, 32, 2, 128, 131072, 52)
 
 
-def test_a_model_with_per_slot_state_keeps_one_row_a_slot(served):
-    """The host deals the prefill block's rows, and a prompt alone would
-    take every free one; not here: every row of a slot starts from the
-    arena's state, so two rows of one slot would both start from the same
-    state. Two prompts into a block of four rows take ONE row each a step
-    (8, 8, 4 and 8, 4), as before the deal, and give what each gives alone."""
+DEAD = [-1, 0, 0]
+DEALS = {
+    # the whole chunk a row: a takes three rows of the first step
+    8: dict(steps=[([[0, 0, 8], [0, 8, 8], [0, 16, 4], [1, 0, 8]],
+                    [20, 8, 0, 0]),
+                   ([[1, 8, 4], DEAD, DEAD, DEAD], [1, 4, 0, 0])],
+            filled=5, extra=2, prefill_steps=2),
+    # a narrowed budget: a row cut short could not be followed, so one row
+    # a slot, as before the deal
+    4: dict(steps=[([[0, 0, 4], [1, 0, 4], DEAD, DEAD], [4, 4, 0, 0]),
+                   ([[0, 4, 4], [1, 4, 4], DEAD, DEAD], [4, 4, 0, 0]),
+                   ([[0, 8, 4], [1, 8, 4], DEAD, DEAD], [4, 4, 0, 0]),
+                   ([[0, 12, 4], DEAD, DEAD, DEAD], [4, 1, 0, 0]),
+                   ([[0, 16, 4], DEAD, DEAD, DEAD], [4, 1, 0, 0])],
+            filled=8, extra=0, prefill_steps=5),
+}
+
+
+@pytest.mark.parametrize("budget", sorted(DEALS, reverse=True))
+def test_a_model_with_per_slot_state_takes_every_free_row(served, budget):
+    """The host deals the prefill block's rows and a prompt takes every
+    free one, with per-slot state too: the state layers chain a slot's rows
+    (``layers.mamba2``). Two prompts (20 and 12 tokens) into a block of four
+    rows of 8: the older takes three rows of the first step, and each gives
+    what it gives alone; still two programs. Under a ``prefill_budget``
+    below the chunk a slot keeps to one (narrowed) row a step."""
     batch_engine(served)            # the donor of the steps, never wrapped
     be = batch_engine(served)
     assert be.prefill_rows == 4 and be.pool.slot_state
+    be.prefill_budget = budget
     calls, step = [], be._mixed_step
 
     def recording(*args):
@@ -538,13 +623,13 @@ def test_a_model_with_per_slot_state_keeps_one_row_a_slot(served):
     a, b = prompts(41, 20, 12)
     rids = [be.submit(a, 3), be.submit(b, 3)]
     be.run()
-    dead = [-1, 0, 0]
-    assert calls[:3] == [
-        ([[0, 0, 8], [1, 0, 8], dead, dead], [8, 8, 0, 0]),
-        ([[0, 8, 8], [1, 8, 4], dead, dead], [8, 4, 0, 0]),
-        ([[0, 16, 4], dead, dead, dead], [4, 1, 0, 0])]
+    want = DEALS[budget]
+    assert calls[:len(want["steps"])] == want["steps"]
     c = be.metrics.counters
-    assert c["prefill_rows_filled"] == 5 and c["prefill_rows_extra"] == 0
-    assert c["prefill_steps"] == 3 and c["prefill_tokens"] == 32
+    assert (c["prefill_rows_filled"], c["prefill_rows_extra"]) == \
+        (want["filled"], want["extra"])
+    assert c["prefill_steps"] == want["prefill_steps"]
+    assert c["prefill_tokens"] == 32 and c["ssm_states_reset"] == 2
+    assert be.trace_counts == {"decode": 1, "prefill": 1}
     for rid, prompt in zip(rids, (a, b)):
         assert be.finished[rid].output == alone(served, prompt, 3)

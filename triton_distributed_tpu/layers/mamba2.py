@@ -30,6 +30,18 @@ row whose cache length before the step is 0 starts from a zero state and a
 zero window, whatever the arena holds: the request before it in the slot
 leaves nothing a new one could read, and nothing has to be cleared from the
 host.
+
+Several rows of a gathered block (the mixed step's prefill block, whose
+rows the host deals: ``nn.paged_token_blocks``) may belong to ONE slot,
+consecutive chunks of its prompt in consecutive rows, every row but the
+last full. Such rows are CHAINED (``chained_rows``, read from the block's
+own slots, offsets and live lengths): row k starts from the state row k-1
+ENDS on (Mamba-2's own recurrence from chunk to chunk, ``chunk_scan``'s
+pass over the rows, float32 throughout) and from the window row k-1 leaves,
+its last ``d_conv - 1`` inputs; only the FIRST row of a run reads the arena
+(or starts from zero) and only the LAST writes it. A block whose live rows
+are all different slots has no chained row and is one gather, one scan of
+independent rows and one scatter.
 """
 
 from __future__ import annotations
@@ -45,7 +57,20 @@ from triton_distributed_tpu.kernels.ssm_update import ssm_state_update
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def chunk_scan(x, dt, a_log_step, b, c, s0):
+def chained_rows(slots, offsets, n_live, L: int):
+    """(R,) bool: row k of a gathered block goes on where row k-1 ends,
+    read from the block's own operands: the same slot, both rows live, row
+    k-1 full, and row k's cache length the one row k-1 leaves. A dead row
+    (no live position) is never chained and nothing is chained to it."""
+    def before(a):
+        return jnp.roll(a, 1, axis=0)
+
+    return ((jnp.arange(slots.shape[0]) > 0) & (slots == before(slots))
+            & (n_live > 0) & (before(n_live) == L)
+            & (offsets == before(offsets) + L))
+
+
+def chunk_scan(x, dt, a_log_step, b, c, s0, chained=None):
     """The recurrence over a chunk, state in and out (all float32).
 
     x (R, L, H, P); dt (R, L, H) the step ``D_t`` (0 at a dead position);
@@ -58,7 +83,13 @@ def chunk_scan(x, dt, a_log_step, b, c, s0):
 
     the second line's products as matrix products over the chunk (the
     structured-state-space duality). Every product runs at ``HIGHEST``: the
-    state is carried for thousands of steps."""
+    state is carried for thousands of steps.
+
+    ``chained`` (R,) bool or None: row k's ``S_0`` is row k-1's ``S_L`` and
+    not ``s0[k]``. What needs no incoming state (the sums over ``s``, the
+    chunk's whole decay ``exp(la_L)``) is computed for all rows at once;
+    the states then pass from row to row, ``S_L = exp(la_L) S_0 + local``,
+    elementwise on (H, P, N)."""
     R, L, H, P = x.shape
     G = b.shape[2]
     la = jnp.cumsum(a_log_step, axis=1)                        # (R, L, H)
@@ -72,14 +103,23 @@ def chunk_scan(x, dt, a_log_step, b, c, s0):
     causal = jnp.tril(jnp.ones((L, L), bool))
     w = jnp.where(causal, jnp.exp(jnp.where(causal, diff, 0.0)), 0.0) * cb
     y = jnp.einsum("rhts,rshp->rthp", w, u, precision=HIGHEST)
+    to_end = jnp.exp(la[:, -1:, :] - la)                       # (R, L, H)
+    decay = jnp.exp(la[:, -1])[..., None, None]                # (R, H, 1, 1)
+    local = jnp.einsum("rshp,rshn->rhpn", to_end[..., None] * u, hb,
+                       precision=HIGHEST)
+    if chained is not None:
+        # the state each row starts with: the row before's last, down a run
+        def row(s_before, xs):
+            go_on, s0_k, decay_k, local_k = xs
+            s_in = jnp.where(go_on, s_before, s0_k)
+            return decay_k * s_in + local_k, s_in
+
+        _, s0 = jax.lax.scan(row, jnp.zeros_like(s0[0]),
+                             (chained, s0, decay, local))
     # from the state the chunk started with
     y += jnp.exp(la)[..., None] * jnp.einsum(
         "rthn,rhpn->rthp", hc, s0, precision=HIGHEST)
-    to_end = jnp.exp(la[:, -1:, :] - la)                       # (R, L, H)
-    s = (jnp.exp(la[:, -1])[..., None, None] * s0
-         + jnp.einsum("rshp,rshn->rhpn", to_end[..., None] * u, hb,
-                      precision=HIGHEST))
-    return y, s
+    return y, decay * s0 + local
 
 
 def draw_own(name: str, key, shape):
@@ -158,20 +198,41 @@ class Mamba2:
         live = blk.valid().reshape(R, L)
         n_live = jnp.sum(live, axis=1)
         fresh = (blk.offsets == 0) & (n_live > 0)  # starts from zero
-        slots = jnp.arange(R) if blk.slots is None else blk.slots
-        # a row with nothing live writes nothing (a dead row of a gathered
-        # block names no slot of its own): out of range, dropped
-        put = jnp.where(n_live > 0, slots, state.conv.shape[1])
-
         # Where row b is slot b (the decode block) this layer's windows are
         # one slice of the arena: read and written as a slice, the dead
         # rows' put back as they were. As a gather and a scatter of 32 rows
         # the same cost 0.9 ms a decode step of 36 layers on the chip.
         whole = blk.slots is None
+        slots = jnp.arange(R) if whole else blk.slots
+        # a row with nothing live writes nothing (a dead row of a gathered
+        # block names no slot of its own): out of range, dropped
+        writes = n_live > 0
+        chained = None
+        if not whole:
+            if L < K - 1:
+                raise ValueError(
+                    f"prefill_chunk = {L} is below d_conv - 1 = {K - 1}: a "
+                    f"row of the prefill block has to hold the whole window "
+                    f"it hands to the slot's next row")
+            # Of a slot's run of rows only the last writes the arenas (a
+            # scatter with a repeated index has no defined winner): not a
+            # row that is followed (row 0 is never chained, so the roll
+            # brings the last row a False).
+            chained = chained_rows(slots, blk.offsets, n_live, L)
+            writes &= ~jnp.roll(chained, -1)
+        put = jnp.where(writes, slots, state.conv.shape[1])
+
         held = (jax.lax.dynamic_index_in_dim(state.conv, layer, 0, False)
                 if whole else state.conv[layer, slots])
         window = jnp.where(fresh[:, None, None], 0,
                            held.reshape(R, K - 1, C))
+        if chained is not None:
+            # a chained row's window is the full row before's last K-1
+            # inputs, in the arena's dtype as through the arena
+            window = jnp.where(
+                chained[:, None, None],
+                jnp.roll(xbc[:, L - (K - 1):], 1, axis=0).astype(held.dtype),
+                window)
         conv, window = self._conv(params, window, xbc, n_live)
         window = window.reshape(R, -1).astype(held.dtype)
         if whole:
@@ -200,7 +261,7 @@ class Mamba2:
         else:
             s0 = jnp.where(fresh[:, None, None, None], 0.0,
                            state.ssm[layer, slots])
-            y, s = chunk_scan(x, dt, a_log_step, b, c, s0)
+            y, s = chunk_scan(x, dt, a_log_step, b, c, s0, chained)
             ssm = state.ssm.at[layer, put].set(s, mode="drop")
         y = y + params["d_skip"].astype(jnp.float32)[:, None] * x
         state = dataclasses.replace(state, ssm=ssm, conv=conv_arena)

@@ -222,9 +222,9 @@ class GraniteHybrid:
         (``nn.paged_token_blocks``). The projections, the SwiGLU and the
         residual stream see the flat token batch; the mixers one block at
         a time, each slot's state advanced in the ONE block it is live in,
-        in ONE row of it (``BatchEngine`` deals a model with per-slot
-        state one row a slot) and over its live positions only
-        (``layers.mamba2``).
+        over its live positions only and, where the host dealt the slot
+        several rows of the prefill block, from row to row down the run
+        (``layers.mamba2``: the rows are chained, the last writes).
         ``aux["stats"]`` the int32 counts ``step_stats``. ``mode`` is
         accepted and not read: on one device ``dist``, ``xla`` and ``ar``
         are one path."""

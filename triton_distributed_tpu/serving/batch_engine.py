@@ -224,9 +224,8 @@ class BatchEngine:
                    row may be a verify row of several tokens). A prompt
                    takes one row a step and, where rows are free, as
                    many more as it can fill (``_run_mixed``); one row a
-                   slot is the limit for a model with per-slot state,
-                   for a verify row, and while ``prefill_budget`` is
-                   below the chunk.
+                   slot is the limit for a verify row and while
+                   ``prefill_budget`` is below the chunk.
     ``admission_pressure`` fraction of the pool that must be free to admit
                    NEW requests while at least one slot is running (0.0 =
                    off). Backpressure trades queue wait for fewer
@@ -1964,17 +1963,20 @@ class BatchEngine:
         # go next. Then the rows still free go to the prompts that hold
         # one, oldest first, each as many further whole chunks as it can
         # fill: so no request takes less than its one row, and a prompt
-        # alone takes up to P * L tokens a step. One row a slot stays the
-        # limit where a second row of the slot could not start where the
-        # first ends: a model with per-slot state (every row of a slot
-        # starts from the arena's state), a verify row, a narrowed budget.
+        # alone takes up to P * L tokens a step. A slot's rows lie one
+        # after another, every one but the last full, which is what lets
+        # the second start where the first ends: attention appends every
+        # row's keys before any row is read, a layer with per-slot state
+        # chains the rows (``layers.mamba2``). One row a slot stays the
+        # limit where that layout cannot be kept: a verify row, a narrowed
+        # budget (a row cut short that is not the slot's last).
         many = sorted((i for i, t in wants.items() if len(t) > 1),
                       key=lambda i: self._slots[i].admit_seq)
         for i in many[P:]:
             del wants[i]
         served = many[:P]
         free = P - len(served)
-        if not self.pool.slot_state and budget == L:
+        if budget == L:
             for i in served:
                 s = self._slots[i]
                 more = min(free, -(-(len(s.ctx) - s.offset) // L) - 1)
