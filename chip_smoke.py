@@ -25,7 +25,11 @@ block (``models/exaone_moe.py``: K-EXAONE-236B-A23B's widths, two layers,
 one over a WINDOW of 128 keys whose rows live in a ring a slot and one over
 every key, 16 of 128 experts held, an eighth of the vocabulary): the window
 build of the block walk at its chunk shape against its decode shape, over a
-walk long enough to pass the window and to wrap the ring. In all three the
+walk long enough to pass the window and to wrap the ring; and the
+SMALLTHINKER block (the same class with four things read otherwise from its
+configuration: SmallThinker-21BA3B's widths, one full layer and one over a
+window of 4,096, 28 query heads on 4 key heads, all 64 ReGLU experts routed
+from the layer's input) the same way. In all four the
 chunk shape takes every walked prompt several rows of the prefill block a
 step, every prompt is held to the tolerance, and where the model routes both
 programs' routers are on record: a prompt is left out only where its token
@@ -46,8 +50,10 @@ performance results.
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import faulthandler
+import functools
 import json
 import sys
 import time
@@ -97,6 +103,24 @@ EXAONE_MOE = dict(HYBRID, config="ExaoneMoeConfig",
                       mlp_layer_types=("sparse", "sparse"),
                       experts_held=16, vocab_size=19_200),
                   prompt_range=(650, 900), walk_len=640)
+
+# And over the SmallThinker block, which the same class serves with four
+# things read otherwise from its configuration: the published widths and
+# vocabulary, one full layer (no position embedding) and one window layer
+# (4,096, rope), 28 query heads on 4 key heads with no QK norm, all 64 ReGLU
+# experts routed from the layer's input (3.2 GB of weights). The walk passes
+# the window and wraps the ring (4,544 lines at a prefill block of 7 rows of
+# 64); its rows are attention's alone, so no second comparison is asked.
+# Its routers read the un-normed stream: the table is scaled to unit entries,
+# as a trained one has (at ``init``'s 1 / sqrt(d) the first layers' logits
+# lie within 0.05 of each other and every choice is a ``ROUTER_TIE``).
+SMALLTHINKER = dict(HYBRID, config="ExaoneMoeConfig.smallthinker",
+                    unit_embedding=True,
+                    overrides=dict(
+                        layer_types=("full_attention", "sliding_attention"),
+                        sliding_windows=(0, 4096),
+                        mlp_layer_types=("sparse", "sparse")),
+                    prompt_range=(4700, 5000), walk_len=4640)
 
 # Largest |difference| of two logit rows over the largest |reference logit|.
 # bf16 keeps 8 mantissa bits (2^-8 per rounded op); over 28-36 layers of
@@ -213,7 +237,6 @@ def routing_recorded():
     {layer: (scores, experts)}``, both (len(rows), topk + 1), of the LAST
     step run."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from triton_distributed_tpu.layers.moe_mlp import HeldExpertsMoE
@@ -221,17 +244,18 @@ def routing_recorded():
     seen: dict = {}
     inner = HeldExpertsMoE.routed
 
-    def routed(self, params, x, valid=None, *, layer_idx=None, **kw):
-        s = jax.nn.sigmoid(jnp.dot(                 # as ``route`` has them
-            x.astype(jnp.float32), params["router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+    def routed(self, params, x, valid=None, route_from=None, *,
+               layer_idx=None, **kw):
+        by, _ = self.scores(                        # as ``route`` has them
+            params["router"], params.get("bias"),
+            x if route_from is None else route_from)
         jax.debug.callback(
             lambda layer, *top: seen.__setitem__(
                 int(layer), tuple(np.asarray(a) for a in top)),
             -1 if layer_idx is None else layer_idx,
-            *jax.lax.top_k(s + params["bias"].astype(jnp.float32),
-                           self.topk + 1))
-        return inner(self, params, x, valid, layer_idx=layer_idx, **kw)
+            *jax.lax.top_k(by, self.topk + 1))
+        return inner(self, params, x, valid, route_from,
+                     layer_idx=layer_idx, **kw)
 
     def at(rows):
         jax.effects_barrier()
@@ -625,7 +649,9 @@ def decode_walk_logits(be, prompts, next_tok, routing=None):
         live = np.arange(n) < n_p
         toks = np.concatenate([np.asarray(prompts, np.int32),
                                np.asarray(next_tok, np.int32)[:, None]], 1)
-        rows, seen = [], []
+        # the last two steps' logits are all that is read: a walk of
+        # thousands of steps must not keep a row of logits a step
+        rows, seen = collections.deque(maxlen=2), []
         for pos in range(plen + 1):
             ids = np.zeros((n, 1), np.int32)
             ids[:n_p, 0] = toks[:, pos]
@@ -653,11 +679,16 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
     from triton_distributed_tpu.runtime.mesh import make_mesh
     from triton_distributed_tpu.serving.fleet import Fleet
 
-    cfg = getattr(configs, geo["config"])(**geo["overrides"])
+    # a configuration class, or a preset of one (``Class.preset``)
+    cfg = functools.reduce(getattr, geo["config"].split("."),
+                           configs)(**geo["overrides"])
     mesh = make_mesh({"tp": 1}, devices=devices[:1], set_default=False)
     engine = Engine(cfg, mesh=mesh, mode="dist",
                     key=jax.random.PRNGKey(geo["seed"]),
                     interpret=geo["interpret"])
+    if geo.get("unit_embedding"):
+        engine.params = dict(engine.params, embed=engine.params["embed"]
+                             * cfg.d_model ** 0.5)
     fleet = Fleet.build(engine, n_replicas=1, n_slots=geo["n_slots"],
                         block_size=geo["block_size"],
                         prefill_chunk=geo["prefill_chunk"],
@@ -735,11 +766,12 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
 
 def run_served_blocks(devices, geo: dict, caches: _CacheEvents) -> None:
     """The one-chip smoke: the dense model, then (its buffers dropped) the
-    hybrid block, then the Nemotron-H block, then the EXAONE-MoE block."""
+    hybrid block, then the Nemotron-H block, then the EXAONE-MoE block, then
+    the SmallThinker block."""
     import gc
 
     run_one_chip(devices, geo, caches)
-    for block in (HYBRID, NEMOTRON_H, EXAONE_MOE):
+    for block in (HYBRID, NEMOTRON_H, EXAONE_MOE, SMALLTHINKER):
         gc.collect()
         run_hybrid(devices, block, caches)
 

@@ -112,34 +112,48 @@ def test_paged_attention_compiles(one_chip, model, L):
 # over a ring of 36 blocks a slot (the window and a step's 7 rows of 64).
 EXA_SLOTS, EXA_BLOCKS, EXA_TABLE, EXA_RING, EXA_HEADS = \
     32, 28_672, 24_576 // BLOCK, 36, (64, 8)
+# SmallThinker-21BA3B's cell (perfbench/configs/smallthinker-21ba3b-l8.json):
+# 28 query heads on 4 key heads (a group of 7, no power of two: the decode
+# shape folds 28 rows, a chunk tile is 64 x 7), two full layers over a
+# 20,480-block pool at the model's whole context of 16,384 (a table 1,024
+# wide), six window layers (4,096) over a ring of 284 blocks a slot.
+LONG = {
+    "k-exaone": dict(heads=EXA_HEADS, window=128, window_layers=4,
+                     ring=EXA_RING, blocks=EXA_BLOCKS, table=EXA_TABLE),
+    "smallthinker": dict(heads=(28, 4), window=4096, window_layers=6,
+                         ring=284, blocks=20_480, table=16_384 // BLOCK),
+}
 
 
 @pytest.mark.parametrize("L", [1, CHUNK], ids=["decode", "chunk"])
 @pytest.mark.parametrize("build", ["window", "full-wide-table"])
-def test_paged_attention_compiles_at_long_contexts(one_chip, build, L):
+@pytest.mark.parametrize("model", sorted(LONG))
+def test_paged_attention_compiles_at_long_contexts(one_chip, model, build, L):
     """The two builds a model with window layers runs, at the published
-    heads (the decode shape folds 64 query rows into one operand): the
+    heads (the decode shape folds 64 or 28 query rows into one operand): the
     window build over ring storage, known by its own name, and the K+V
-    build with the 1,536-wide table in SMEM."""
+    build with the wide table in SMEM."""
     from triton_distributed_tpu.kernels.paged_attention import (
         paged_attention,
     )
 
-    hq, hkv = EXA_HEADS
+    geo = LONG[model]
+    hq, hkv = geo["heads"]
     rows = EXA_SLOTS if L == 1 else 7
-    window = 128 if build == "window" else None
+    window = geo["window"] if build == "window" else None
 
     def fn(q, kp, vp, tables, kv_lens, q_lens, layer):
         return paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
                                interpret=False, layer=layer, window=window)
 
     if window:
-        pool = _sds((4, EXA_SLOTS, EXA_RING, BLOCK, hkv, DH), jnp.bfloat16,
-                    one_chip)
+        pool = _sds((geo["window_layers"], EXA_SLOTS, geo["ring"], BLOCK,
+                     hkv, DH), jnp.bfloat16, one_chip)
         tables = _sds((rows, 1), jnp.int32, one_chip)
     else:
-        pool = _sds((1, EXA_BLOCKS, BLOCK, hkv, DH), jnp.bfloat16, one_chip)
-        tables = _sds((rows, EXA_TABLE), jnp.int32, one_chip)
+        pool = _sds((1, geo["blocks"], BLOCK, hkv, DH), jnp.bfloat16,
+                    one_chip)
+        tables = _sds((rows, geo["table"]), jnp.int32, one_chip)
     text = jax.jit(fn).lower(
         _sds((rows, L, hq, DH), jnp.bfloat16, one_chip), pool, pool, tables,
         _sds((rows,), jnp.int32, one_chip),
@@ -430,20 +444,33 @@ def test_nemotron_step_compiles_with_its_state_in_place(topo, monkeypatch,
     assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes
 
 
+# (configuration file, its family, the pool's state in GB, the weights in GB)
+WINDOWED = {
+    "k-exaone": ("k-exaone-236b-a23b-ep8", "exaone_moe", (2.15, 2.2), 7.43),
+    "smallthinker": ("smallthinker-21ba3b-l8", "smallthinker", (3.1, 3.15),
+                     7.93),
+}
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("model", sorted(WINDOWED))
 def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
-                                                      kind):
-    """The whole served step of k-exaone-236b-a23b-ep8 (layers 0-4, every
-    width, 16 of 128 experts held, an eighth of the vocabulary), as
-    ``BatchEngine`` builds it around ``forward_paged``: it compiles with the
-    1,536-wide block table, every arena of the pool's state (the full
-    layer's rows, the window layers' rings) is aliased in to out, and the
-    step's temporaries hold no copy of an arena or of a weight stack (the
-    smallest stack of matrices is the shared experts' 0.3 GB; the chunk's
-    expert buffer and activations are 0.11 GB)."""
+                                                      model, kind):
+    """The whole served step of the two configurations the EXAONE walk
+    serves, as ``BatchEngine`` builds it around ``forward_paged``:
+    k-exaone-236b-a23b-ep8 (layers 0-4, every width, 16 of 128 experts held,
+    an eighth of the vocabulary; a 1,536-wide block table) and
+    smallthinker-21ba3b-l8 (layers 0-7 at the published widths: a ring of
+    284 blocks a slot behind a window of 4,096, 28 query heads on 4 key
+    heads, all 64 experts held, the whole vocabulary and context; the
+    weights it reports beside the 7.93 GB reckoned). Each compiles, every
+    arena of the pool's state (the full layers' rows, the window layers'
+    rings) is aliased in to out, and the step's temporaries hold no copy of
+    an arena or of a weight stack (the smallest stack of matrices is
+    0.3 GB; a chunk's expert buffers and activations stay under 0.2 GB)."""
+    import importlib
     import json
 
-    from perfbench.families import exaone_moe as family
     from triton_distributed_tpu.models.engine import Engine
     from triton_distributed_tpu.models.exaone_moe import ExaoneMoe
     from triton_distributed_tpu.runtime import platform
@@ -452,16 +479,19 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
         paged_state_specs,
     )
 
+    name, family, state_gb, weights_gb = WINDOWED[model]
+    family = importlib.import_module(f"perfbench.families.{family}")
+    geo = LONG[model]
     # the grouped product asks ``on_tpu()`` before it hands Mosaic a kernel
     monkeypatch.setattr(platform, "on_tpu", lambda: True)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-            root, "perfbench/configs/k-exaone-236b-a23b-ep8.json")) as f:
+    with open(os.path.join(root, f"perfbench/configs/{name}.json")) as f:
         file = json.load(f)
     cfg = family.program_config(file, family.sizes(file))
     fleet = file["serve"]["fleet"]
     assert (fleet["n_slots"], fleet["n_blocks"], fleet["block_size"]) == \
-        (EXA_SLOTS, EXA_BLOCKS, BLOCK)
+        (EXA_SLOTS, geo["blocks"], BLOCK)
+    assert cfg.max_length // BLOCK == geo["table"]
     mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
     here = NamedSharding(mesh, P())
 
@@ -472,12 +502,18 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
     params = placed(jax.eval_shape(
         lambda k: ExaoneMoe(cfg).init(k, mesh), jax.random.PRNGKey(0)))
     state = placed(paged_state_shapes(
-        cfg, n_blocks=EXA_BLOCKS, block_size=BLOCK, n_slots=EXA_SLOTS,
+        cfg, n_blocks=geo["blocks"], block_size=BLOCK, n_slots=EXA_SLOTS,
         max_take=HYB_PREFILL_ROWS * CHUNK))
-    assert state.wk.shape == (4, EXA_SLOTS, EXA_RING, BLOCK, 8, DH)
-    state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                      for a in jax.tree.leaves(state))
-    assert 2.15e9 < state_bytes < 2.2e9 < 2.5e9
+    assert state.wk.shape == (geo["window_layers"], EXA_SLOTS, geo["ring"],
+                              BLOCK, geo["heads"][1], DH)
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    state_bytes = nbytes(state)
+    assert state_gb[0] * 1e9 < state_bytes < state_gb[1] * 1e9
+    assert nbytes(params) == pytest.approx(weights_gb * 1e9, rel=2e-3)
     engine = Engine(cfg, mesh=mesh, params=params, mode="dist",
                     interpret=False)
     step = jax.jit(
@@ -485,7 +521,7 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
                         state_specs=paged_state_specs(cfg)),
         donate_argnums=(2,))
     slots = (_sds((EXA_SLOTS,), jnp.int32, here),
-             _sds((EXA_SLOTS, EXA_TABLE), jnp.int32, here),
+             _sds((EXA_SLOTS, geo["table"]), jnp.int32, here),
              _sds((EXA_SLOTS,), bool, here))
     if kind == "decode":
         args = (_sds((EXA_SLOTS, 1), jnp.int32, here), state, *slots)
@@ -496,11 +532,14 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
         args = (ids, state, *slots, _sds((EXA_SLOTS,), jnp.int32, here))
     compiled = step.lower(params, *args).compile()
     text = compiled.as_text()
-    # five layer bodies: four window walks and a full one, two grouped
-    # products in each of four expert layers (each walk twice in the mixed
-    # step: the decode block and the prefill block)
+    # the layer bodies: a walk a layer, two grouped products in each expert
+    # layer (each walk twice in the mixed step: the decode block and the
+    # prefill block)
     assert text.count("tpu_custom_call") >= 10
     assert "window_paged_attention" in text and "moe_grouped_gemm" in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == state_bytes
     assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes
+    # weights, the pool and the step's own buffers fit the chip's 16 GB
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.75e9

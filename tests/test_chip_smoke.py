@@ -161,6 +161,40 @@ def test_exaone_moe_phase_passes_at_tiny(capsys, restore_compile_cache_config):
     assert [r.get("phase") for r in records].count("numeric") == 1
 
 
+def test_smallthinker_phase_passes_at_tiny(capsys,
+                                           restore_compile_cache_config):
+    """The same phase over the SmallThinker block (the EXAONE walk with the
+    router before attention, softmax over the chosen logits, ReGLU, no
+    shared expert, no QK norm) at tiny float32 sizes, three query heads to a
+    key head: every pair held, both programs' routers read and alike."""
+    import dataclasses
+
+    from triton_distributed_tpu.models.config import ExaoneMoeConfig
+
+    tiny = ExaoneMoeConfig.tiny(
+        layer_types=("full_attention", "sliding_attention"),
+        sliding_windows=(0, 6), mlp_layer_types=("sparse", "sparse"),
+        n_heads=6, n_shared_experts=0, qk_norm=False, scoring="softmax_topk",
+        expert_activation="reglu", router_input="layer_input")
+    geo = dict(chip_smoke.SMALLTHINKER, interpret=None, paged_attn="gather",
+               n_slots=2, block_size=4, prefill_chunk=8, n_requests=3,
+               prompt_range=(30, 40), new_tokens=3, walk_len=30,
+               overrides=dataclasses.asdict(tiny))
+    assert chip_smoke.SMALLTHINKER["config"] == "ExaoneMoeConfig.smallthinker"
+    rc = chip_smoke.smoke(chip_smoke.run_hybrid, jax.devices()[:1], geo)
+    records = [json.loads(line)
+               for line in capsys.readouterr().out.strip().splitlines()]
+    assert rc == 0 and records[-1]["ok"] is True
+    assert records[0]["window_layers"] == 1 and records[0]["cache_layers"] == 1
+    assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
+    assert records[1]["moe_pairs_held"] > 0 == records[1]["moe_dropped_pairs"]
+    assert records[1]["prefill_rows_extra"] > 0
+    assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+    assert records[2]["prefill_routed_apart"] == [] \
+        == records[2]["decode_routed_apart"]
+    assert [r.get("phase") for r in records].count("numeric") == 1
+
+
 def _routing(margin, experts=(4, 9, 2), base=0.5):
     """One layer's record for three prompts: prompt 0's router ``margin``
     apart at its top-2 boundary and choosing ``experts[:2]``, the others'
@@ -219,7 +253,7 @@ def test_the_routers_record_is_what_route_chose():
     from triton_distributed_tpu.layers.moe_mlp import HeldExpertsMoE
 
     moe = HeldExpertsMoE(d_model=16, d_ff=16, n_experts=8, topk=2, n_held=4,
-                         dtype=jnp.float32, gated=False)
+                         dtype=jnp.float32, activation="relu2")
     k = jax.random.split(jax.random.PRNGKey(0), 4)
     params = {"router": jax.random.normal(k[0], (16, 8)),
               "bias": 0.1 * jax.random.normal(k[1], (8,)),

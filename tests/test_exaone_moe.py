@@ -505,16 +505,20 @@ WALKS = {
 }
 
 
-@pytest.mark.parametrize("L", [1, 5], ids=["decode", "chunk"])
-@pytest.mark.parametrize("walk", sorted(WALKS))
-def test_the_window_build_equals_plain_numpy(walk, L):
+@pytest.mark.parametrize(
+    "walk,L,Hq",
+    [(w, L, 4) for w in sorted(WALKS) for L in (1, 5)]
+    + [("edges-off-blocks", 1, 14), ("edges-off-blocks", 5, 14)],
+    ids=lambda v: {1: "decode", 5: "chunk", 4: "g2", 14: "g7"}.get(v, v))
+def test_the_window_build_equals_plain_numpy(walk, L, Hq):
     """``paged_attention(window=...)`` under the interpreter, both step
     shapes (the decode shape's folded arithmetic, the chunk shape's per
     head, ragged ``q_lens``), rows in shuffled slots, against plain numpy
-    over the whole sequences; and the gather oracle the same."""
+    over the whole sequences; and the gather oracle the same. Two query
+    heads to a key head, and seven (a group that is no power of two)."""
     window, bs, ring_blocks, ctx = WALKS[walk]
     rng = np.random.default_rng(len(walk) + L)
-    B, Hq, Hkv, dh, S = len(ctx), 4, 2, 16, max(ctx)
+    B, Hkv, dh, S = len(ctx), 2, 16, max(ctx)
     k = rng.standard_normal((B, S, Hkv, dh)).astype(np.float32)
     v = rng.standard_normal((B, S, Hkv, dh)).astype(np.float32)
     q = rng.standard_normal((B, L, Hq, dh)).astype(np.float32)

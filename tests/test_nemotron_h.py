@@ -441,11 +441,11 @@ def relu2_layer(m):
     return HeldExpertsMoE(
         d_model=m.d_model, d_ff=m.expert_width, n_experts=m.router_width,
         topk=m.topk, n_held=m.held, lo=m.lo, routed_scaling=m.scaling,
-        dtype=jnp.float32, gated=False)
+        dtype=jnp.float32, activation="relu2")
 
 
 def test_the_ungated_relu2_form_equals_plain_jnp():
-    """``HeldExpertsMoE(gated=False)``: TWO matrices an expert, ``w_down
+    """``HeldExpertsMoE(activation="relu2")``: TWO matrices an expert, ``w_down
     relu(w_up x)^2``, the shared expert alike, against ``jax.numpy`` written
     out here; zero-padding the width (24 -> 128) changes nothing to the bit
     of the tolerance; the gated form of the same layer differs."""
@@ -476,7 +476,7 @@ def test_the_ungated_relu2_form_equals_plain_jnp():
     wide, _ = dataclasses.replace(layer, d_ff=128).fwd(padded, x)
     np.testing.assert_allclose(wide, want, atol=2e-5)
     # the gated form reads the same first matrix as two halves: another layer
-    gated = dataclasses.replace(layer, gated=True, d_ff=12)
+    gated = dataclasses.replace(layer, activation="swiglu", d_ff=12)
     assert gated.w_in == "w_gate_up"
     other, _ = gated.fwd(
         {**params, "w_gate_up": lw["e_up"], "w_down": lw["e_d"][:, :12],

@@ -291,9 +291,11 @@ def _walk_case(rng, shape, tile_blocks, poison=False):
     the full table (its last tile ragged, the table padded) — and a dead
     slot between live ones, over a shuffled table. ``shape``: ``decode``
     (L = 1), ``chunk`` (ragged ``q_lens``, two query tiles), ``latent``
-    (one arena, chunk shape), ``bf16-8x2`` / ``bf16-4x8`` (the decode shape
-    over a bf16 pool at ``Hkv`` 8, ``g`` 2 and at ``Hkv`` 4, ``g`` 8: the
-    FOLDED arithmetic with bf16 operands, as the chip runs it). Returns the
+    (one arena, chunk shape), ``bf16-8x2`` / ``bf16-4x8`` / ``bf16-4x7``
+    (the decode shape over a bf16 pool at ``Hkv`` 8, ``g`` 2, at ``Hkv`` 4,
+    ``g`` 8 and at a group that is no power of two, 28 query rows in the
+    folded operand: the FOLDED arithmetic with bf16 operands, as the chip
+    runs it), ``chunk-g7`` (the chunk shape at a group of 7). Returns the
     call's arguments, the oracle, the live mask and the tolerance. ``poison``
     writes NaN over every pool row that no live slot owns, so a prefetch
     that stages what the mask does not scrub shows."""
@@ -306,8 +308,9 @@ def _walk_case(rng, shape, tile_blocks, poison=False):
         Hkv, g = (int(x) for x in shape[len("bf16-"):].split("x"))
         dh, v_dim = 16, None
     else:
-        Hkv, g, dh, v_dim = (1, 4, 32, 16) if latent else (2, 2, 16, None)
-    L = 6 if shape in ("chunk", "latent") else 1
+        Hkv, g, dh, v_dim = (1, 4, 32, 16) if latent else (
+            2, 7 if shape == "chunk-g7" else 2, 16, None)
+    L = 6 if shape in ("chunk", "latent", "chunk-g7") else 1
     kv = [1, bs, span, span + 1, min(3 * span, max_blocks * bs),
           max_blocks * bs, 5 * bs, 2 * bs + 3]
     slot_mask = np.array([True] * 6 + [False, True])
@@ -374,8 +377,14 @@ def _assert_within(out, ref, tol):
 WALK_SHAPES = ["decode", "chunk", "latent", "bf16-8x2", "bf16-4x8"]
 
 
-@pytest.mark.parametrize("tile_blocks", [1, 2, 3])
-@pytest.mark.parametrize("shape", WALK_SHAPES)
+# A group of 7 (28 query heads over 4 key heads: the first served group that
+# is no power of two), the decode shape's folded operand and the chunk shape.
+GROUP_OF_7 = [("bf16-4x7", 2), ("chunk-g7", 2)]
+
+
+@pytest.mark.parametrize(
+    "shape,tile_blocks",
+    [(s, t) for s in WALK_SHAPES for t in (1, 2, 3)] + GROUP_OF_7)
 def test_pipelined_walk_matches_gather_reference(rng, shape, tile_blocks):
     """The walk's trip count is each slot's own: every edge length in one
     batch, dead slot included, through the stacked arena with a TRACED

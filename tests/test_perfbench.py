@@ -84,6 +84,9 @@ OF_A_FAMILY = {
     "exaone_moe": re.compile(
         r"exaone|sliding_window|mlp_layer_types|num_experts_published|"
         r"num_shared_experts|rope_parameters", re.IGNORECASE),
+    "smallthinker": re.compile(
+        r"smallthinker|primary_experts|primary_router|rope_layout|"
+        r"moe_ffn_hidden|reglu", re.IGNORECASE),
 }
 
 
@@ -209,7 +212,7 @@ def test_the_span_readers_entries_name_their_cells_and_find_their_readers():
         assert "host_turn_ms.reasoning" in cells
         assert "host_turn_ms.chat" not in cells
     assert names[at + 4:] == ["window_attn_device_share",
-                              "window_attn_roofline"]
+                              "window_attn_roofline", "mixed_steps_share"]
 
 
 def test_the_window_readers_arithmetic_and_silence_on_an_older_program():
@@ -258,3 +261,138 @@ def test_the_window_readers_arithmetic_and_silence_on_an_older_program():
         assert reader.read(record(ops, trace=False)) is None
     assert window_attn_roofline.read(
         record(ops, family=types.SimpleNamespace())) is None
+
+
+DOCUMENT_QA = "smallthinker-21ba3b-l8.document-qa"
+
+
+def test_the_document_mix_opens_past_the_window_and_ends_inside_the_context():
+    """``traffic/document-qa.json`` as the new cell runs it (its
+    configuration's whole vocabulary, its whole 16,384 positions, 32 slots):
+    32 standing requests caught part-way, the same sizes for every seed;
+    prompts 4,096-12,288 and answers 1,024-3,072, so EVERY context that
+    decodes is at least a window (4,096) long, which is what makes the
+    window build's count of bytes exact in this cell, and no request ends
+    past 15,360; the two full layers' pool holds the opening with every
+    request grown to its end."""
+    from perfbench import core, families
+    from perfbench.traffic_kinds.closed_loop import Plan
+
+    spec = core.load_cell(DOCUMENT_QA)
+    assert spec["cell"]["chips"] == 1
+    assert spec["cell"]["traffic"] == "document-qa"
+    mix = spec["traffic"]
+    assert (mix["kind"], mix["clients"], mix["rounds"],
+            mix["drain_limit_s"]) == ("closed_loop", "n_slots", 3, 5)
+    assert mix["prompt"] == {"median": 6144, "sigma": 0.35, "lo": 4096,
+                             "hi": 12288}
+    assert mix["output"] == {"median": 1536, "sigma": 0.3, "lo": 1024,
+                             "hi": 3072}
+    family = families.load_family(spec["config"])
+    assert family.__name__ == "perfbench.families.smallthinker"
+    sizes = family.sizes(spec["config"])
+    fleet = spec["config"]["serve"]["fleet"]
+
+    def plan(seed):
+        return Plan(mix, seed=seed, seconds=40, vocab=sizes.vocab_size,
+                    max_total=sizes.max_length, n_slots=fleet["n_slots"])
+
+    first = plan(5).standing()
+    assert len(first) == fleet["n_slots"] == 32
+    contexts = [len(p.prompt) for p in first]
+    assert sum(contexts) == 236_191
+    assert min(contexts) == 4_368 and max(contexts) == 13_183
+    assert min(contexts) >= sizes.window == 4096
+    assert contexts == [len(p.prompt) for p in plan(4_100_100_001).standing()]
+    assert all(0 <= t < sizes.vocab_size for p in first for t in p.prompt[:64])
+    ends = [len(p.prompt) + p.max_new_tokens for p in first] \
+        + [p + o for p, o in plan(5)._later]
+    assert max(ends) <= 15_360 < sizes.max_length == 16_384
+    assert all(4096 <= p <= 12288 and 1024 <= o <= 3072
+               for p, o in plan(5)._later)
+    # a life is 9-28 mixed steps of 448 prompt tokens and 1,024-3,072 steps
+    # that decode it: about a quarter of the steps are mixed
+    # (while its prompt is taken a row does not decode)
+    chunks = sum(-(-p // 448) for p, _ in plan(5)._later)
+    steps = (sum(o for _, o in plan(5)._later) + chunks) / fleet["n_slots"]
+    assert 0.25 < chunks / steps < 0.35
+    # every standing request grown to its end fits the full layers' pool
+    assert sum(ends[:32]) == 261_710 \
+        < fleet["n_blocks"] * fleet["block_size"] == 327_680
+    # the reference reads the longest finished request whole, and one more
+    assert spec["sample"] == {"requests": 2, "token_budget": 27_000}
+    assert set(spec["limits"]) == {"gap_max", "gap_mean"}
+    reported = {m["name"] for m in spec["per_layer"]}
+    assert reported == {
+        "decode_occupancy", "kv_used_share_peak", "preemptions",
+        "decode_step_ms", "mixed_step_ms.reasoning",
+        "paged_attn_device_share.reasoning", "decode_step_roofline",
+        "moe_ffn_device_share", "moe_ffn_roofline",
+        "window_attn_device_share", "window_attn_roofline",
+        "host_turn_ms.reasoning", "host_dispatch_ms", "host_observe_ms",
+        "mixed_steps_share",
+        # its users wait 10-28 mixed steps for a first token: the wait is
+        # judged here too, with the readers that move it
+        "queue_wait_p50_ms", "ttft_p50_ms", "ttft_p95_ms"}
+    assert {m["name"] for m in spec["end_to_end"]} == {
+        "itl_p95_ms", "out_tokens_per_s", "ttft_mean_ms", "setup_s"}
+
+
+def test_the_configuration_states_every_published_key_and_its_cut():
+    """``configs/smallthinker-21ba3b-l8.json`` against the catalog's row as
+    the issue copies it: every published number under its own key at its
+    published value, the layouts whole, the depth alone reduced, and the
+    cut written out (``source``, ``reduced``, ``assumed``, ``deployment``,
+    ``num_hidden_layers_published``)."""
+    from perfbench import core
+
+    bench = core.load_json(core.ROOT, "BENCHMARK.json")
+    entry, = [c for c in bench["configs"]
+              if c["name"] == "smallthinker-21ba3b-l8"]
+    assert entry["reduced"] == ["num_hidden_layers"]
+    cfg = core.load_json(core.ROOT, entry["file"])
+    assert cfg["source"] == entry["source"] and cfg["family"] == "smallthinker"
+    published = {
+        "head_dim": 128, "hidden_size": 2560,
+        "max_position_embeddings": 16384,
+        "model_name": "smallthinker_21b_instruct",
+        "moe_ffn_hidden_size": 768, "moe_num_active_primary_experts": 6,
+        "moe_num_primary_experts": 64,
+        "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+        "num_attention_heads": 28, "num_key_value_heads": 4,
+        "rms_norm_eps": 1e-06, "rope_layout": [0, 1, 1, 1] * 13,
+        "rope_scaling": None, "rope_theta": 1500000,
+        "sliding_window_layout": [0, 1, 1, 1] * 13,
+        "sliding_window_size": 4096, "tie_word_embeddings": False,
+        "vocab_size": 151936}
+    assert {k: cfg[k] for k in published} == published
+    assert (cfg["num_hidden_layers"], cfg["num_hidden_layers_published"]) == \
+        (8, 52)
+    assert cfg["reduced"] == ["num_hidden_layers"]
+    assert {"num_hidden_layers", "moe_enable_early_router",
+            "moe_enable_secondary_experts", "routing", "attention",
+            "weights"} <= set(cfg["assumed"])
+    assert "WHOLE layers" in cfg["deployment"] and cfg["chips"] == 1
+    assert cfg["serve"]["fleet"] == {
+        "n_replicas": 1, "n_slots": 32, "block_size": 16,
+        "prefill_chunk": 64, "n_blocks": 20480, "paged_attn": "fused"}
+
+
+def test_the_mixed_steps_share_reads_the_programs_counters():
+    """The one reader this PR adds, on hand-made records: mixed steps over
+    all steps of the window, from counters every program has had; nothing
+    to read where no step ran or a counter is not there."""
+    import types
+
+    from perfbench.layer_metrics import mixed_steps_share
+
+    def rec(**counters):
+        return types.SimpleNamespace(counters=counters)
+
+    assert mixed_steps_share.read(
+        rec(prefill_steps=560.0, decode_steps=1440.0)) == pytest.approx(28.0)
+    assert mixed_steps_share.read(
+        rec(prefill_steps=0.0, decode_steps=10.0)) == 0.0
+    assert mixed_steps_share.read(
+        rec(prefill_steps=0.0, decode_steps=0.0)) is None
+    assert mixed_steps_share.read(rec(decode_steps=5.0)) is None

@@ -264,39 +264,55 @@ MOE_STATS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
 
 @dataclasses.dataclass(frozen=True)
 class HeldExpertsMoE:
-    """A sigmoid-routed expert layer (DeepSeek-V3's, Nemotron-H's) as ONE
-    chip of a wide expert-parallel deployment sees it: the router keeps its published width, this device
+    """A routed expert layer (DeepSeek-V3's, Nemotron-H's, EXAONE-MoE's,
+    SmallThinker's) as ONE chip of an expert-parallel deployment sees it:
+    the router keeps its published width, this device
     holds the routed experts ``[lo, lo + n_held)`` and computes the part of
     the result they give; what the absent experts would add is left out
     (their chips would add it), and no code stands in for them or for the
-    exchange. A shared expert (every chip computes it alike) is added whole.
+    exchange. ``n_held == n_experts`` is the whole layer. A shared expert
+    (every chip computes it alike) is added whole where the parameters hold
+    one (``shared``).
 
-    Routing (HF ``DeepseekV3TopkRouter``, ``noaux_tc`` with one group):
-    ``s = sigmoid(x @ router)`` in float32 over ALL experts; the ``topk``
-    largest of ``s + bias`` are chosen; their weights are the UNBIASED
-    scores, normalised over all chosen (``norm_topk_prob``), times
-    ``routed_scaling``.
+    Two score forms, a property of the layer (``scoring``):
+
+    - ``"sigmoid"`` (HF ``DeepseekV3TopkRouter``, ``noaux_tc`` with one
+      group): ``s = sigmoid(x @ router)`` in float32 over ALL experts; the
+      ``topk`` largest of ``s + bias`` are chosen; their weights are the
+      UNBIASED scores, normalised over all chosen (``norm_topk_prob``),
+      times ``routed_scaling``;
+    - ``"softmax_topk"`` (SmallThinker): the ``topk`` largest raw logits
+      ``x @ router`` are chosen and their weights are the softmax over the
+      chosen alone (they sum to 1), times ``routed_scaling``; no bias.
+
+    The router's input need not be the experts' input: ``routed(params, x,
+    valid, route_from)`` routes from ``route_from`` (a model whose router
+    stands before attention hands it the layer's input) and feeds the
+    experts ``x``; None routes from ``x``.
 
     No pair is dropped at any routing: the held pairs are sorted by expert
     into a buffer that has room for every pair the tokens could route here
     (``moe_utils.rows_by_expert``), and the two grouped products walk its
     tiles, so the work follows the pairs routed.
 
-    Two expert forms, a property of the layer (``gated``), the shared
-    expert alike:
+    Three expert forms, a property of the layer (``activation``), the
+    shared expert alike:
 
-    - gated SwiGLU (DeepSeek-V3): ``w_down (silu(x w_gate) * (x w_up))``,
+    - ``"swiglu"`` (DeepSeek-V3): ``w_down (silu(x w_gate) * (x w_up))``,
       gate and up halves one matrix ``w_gate_up`` (d, 2 * d_ff);
-    - ungated relu² (Nemotron-H): ``w_down relu(x w_up)²``, TWO matrices an
-      expert, ``w_up`` (d, d_ff). A zero column of ``w_up`` against a zero
-      row of ``w_down`` adds nothing, so a model may store ``d_ff`` padded
-      to what the grouped product tiles by.
+    - ``"reglu"`` (SmallThinker): ``w_down (relu(x w_gate) * (x w_up))``,
+      the same two matrices;
+    - ``"relu2"`` (Nemotron-H), ungated: ``w_down relu(x w_up)²``, TWO
+      matrices an expert, ``w_up`` (d, d_ff). A zero column of ``w_up``
+      against a zero row of ``w_down`` adds nothing, so a model may store
+      ``d_ff`` padded to what the grouped product tiles by.
 
-    Parameters: ``router`` (d, n_experts) f32, ``bias`` (n_experts,) f32,
-    ``w_gate_up`` (n_held, d, 2*d_ff) or ``w_up`` (n_held, d, d_ff) /
-    ``w_down`` (n_held, d_ff, d) — or layer-stacked with ``layer_idx``, as
-    ``grouped_gemm_skip`` wants them under a scan — and ``shared`` {the
-    same two names, (d, 2*ff_s) or (d, ff_s), ``w_down`` (ff_s, d)}.
+    Parameters: ``router`` (d, n_experts) f32, ``bias`` (n_experts,) f32
+    (the sigmoid form only), ``w_gate_up`` (n_held, d, 2*d_ff) or ``w_up``
+    (n_held, d, d_ff) / ``w_down`` (n_held, d_ff, d) — or layer-stacked
+    with ``layer_idx``, as ``grouped_gemm_skip`` wants them under a scan —
+    and, where the layer has a shared expert, ``shared`` {the same two
+    names, (d, 2*ff_s) or (d, ff_s), ``w_down`` (ff_s, d)}.
     """
 
     d_model: int
@@ -308,44 +324,73 @@ class HeldExpertsMoE:
     routed_scaling: float = 1.0
     norm_topk_prob: bool = True
     dtype: jnp.dtype = jnp.bfloat16
-    gated: bool = True
+    activation: str = "swiglu"
+    scoring: str = "sigmoid"
+
+    def __post_init__(self):
+        if self.activation not in ("swiglu", "reglu", "relu2") \
+                or self.scoring not in ("sigmoid", "softmax_topk"):
+            raise ValueError(
+                f"unknown expert form {self.activation!r} or score form "
+                f"{self.scoring!r}")
 
     @property
     def w_in(self) -> str:
         """The name of an expert's first matrix."""
-        return "w_gate_up" if self.gated else "w_up"
+        return "w_up" if self.activation == "relu2" else "w_gate_up"
 
-    def activation(self, h):
+    @property
+    def forms(self) -> dict:
+        """What the layer is made of, by name
+        (``BatchEngine.stats_snapshot()["moe"]``)."""
+        return {"scoring": self.scoring, "activation": self.activation}
+
+    def act(self, h):
         """What stands between an expert's two products, in float32."""
-        if not self.gated:
+        if self.activation == "relu2":
             return jnp.square(jax.nn.relu(h.astype(jnp.float32)))
         ff = h.shape[-1] // 2
-        return (jax.nn.silu(h[..., :ff].astype(jnp.float32))
+        gate = jax.nn.silu if self.activation == "swiglu" else jax.nn.relu
+        return (gate(h[..., :ff].astype(jnp.float32))
                 * h[..., ff:].astype(jnp.float32))
 
+    def scores(self, router, bias, x):
+        """x (n, d) -> ``(what the choice is made by, what the weights are
+        made from)``, each (n, n_experts) float32: the biased and the plain
+        sigmoid scores, or the raw logits twice. Float32 in earnest:
+        ``HIGHEST`` keeps the chip from taking the float32 product in one
+        bfloat16 pass, which moves near-tied scores across the top-k
+        boundary."""
+        s = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+        if self.scoring == "softmax_topk":
+            return s, s
+        s = jax.nn.sigmoid(s)
+        return s + bias.astype(jnp.float32), s
+
     def route(self, router, bias, x):
-        """x (n, d) -> (weights (n, k) f32, ids (n, k) int32). The scores
-        are float32 in earnest: ``HIGHEST`` keeps the chip from taking the
-        float32 product in one bfloat16 pass, which moves near-tied scores
-        across the top-k boundary."""
-        s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                                   router.astype(jnp.float32),
-                                   precision=jax.lax.Precision.HIGHEST))
-        _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), self.topk)
+        """x (n, d) -> (weights (n, k) f32, ids (n, k) int32); ``bias`` is
+        read by the sigmoid form alone."""
+        by, s = self.scores(router, bias, x)
+        _, ids = jax.lax.top_k(by, self.topk)
         w = jnp.take_along_axis(s, ids, axis=-1)
-        if self.norm_topk_prob:
+        if self.scoring == "softmax_topk":
+            w = jax.nn.softmax(w, axis=-1)
+        elif self.norm_topk_prob:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return w * self.routed_scaling, ids.astype(jnp.int32)
 
-    def routed(self, params, x, valid=None, *, layer_idx=None,
-               interpret=None):
+    def routed(self, params, x, valid=None, route_from=None, *,
+               layer_idx=None, interpret=None):
         """The held experts' part of the result for x (n, d), and the
         counts ``MOE_STATS`` over the valid tokens (``valid`` (n,) bool:
-        padding rows route nowhere and cost nothing). x may come in
-        float32 (a float32 residual stream): the router reads it as it is,
-        the experts read it in the layer's dtype."""
+        padding rows route nowhere and cost nothing). The router reads
+        ``route_from`` (n, d), or ``x`` where there is none. Either may
+        come in float32 (a float32 residual stream): the router reads its
+        input as it is, the experts read ``x`` in the layer's dtype."""
         n = x.shape[0]
-        w, ids = self.route(params["router"], params["bias"], x)
+        w, ids = self.route(params["router"], params.get("bias"),
+                            x if route_from is None else route_from)
         x = x.astype(self.dtype)
         live = jnp.ones((n, 1), bool) if valid is None else valid[:, None]
         local = ids - self.lo
@@ -364,7 +409,7 @@ class HeldExpertsMoE:
         h = moe_utils.grouped_gemm_skip(rows, params[self.w_in], tile_live,
                                         **kw)
         out = moe_utils.grouped_gemm_skip(
-            self.activation(h).astype(h.dtype), params["w_down"], tile_live,
+            self.act(h).astype(h.dtype), params["w_down"], tile_live,
             **kw).reshape(R, -1)
         pair_out = out.at[row_of_pair].get(                     # (n, k, d)
             mode="fill", fill_value=0).astype(jnp.float32)
@@ -375,12 +420,16 @@ class HeldExpertsMoE:
             n_held - jnp.sum(pair_of_row < n * self.topk)]).astype(jnp.int32)
         return y.astype(x.dtype), stats
 
-    def fwd(self, params, x, valid=None, *, layer_idx=None, interpret=None):
-        """x (n, d) -> (shared(x) + the held experts' part, stats)."""
-        y, stats = self.routed(params, x, valid, layer_idx=layer_idx,
-                               interpret=interpret)
+    def fwd(self, params, x, valid=None, route_from=None, *, layer_idx=None,
+            interpret=None):
+        """x (n, d) -> (shared(x) + the held experts' part, stats); the
+        held experts' part alone where ``params`` holds no shared expert."""
+        y, stats = self.routed(params, x, valid, route_from,
+                               layer_idx=layer_idx, interpret=interpret)
+        if "shared" not in params:
+            return y, stats
         sh, x = params["shared"], x.astype(self.dtype)
         h = jnp.dot(x, sh[self.w_in], preferred_element_type=jnp.float32)
         return y + jnp.dot(
-            self.activation(h).astype(x.dtype), sh["w_down"],
+            self.act(h).astype(x.dtype), sh["w_down"],
             preferred_element_type=jnp.float32).astype(x.dtype), stats

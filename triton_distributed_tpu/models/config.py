@@ -433,6 +433,16 @@ class ExaoneMoeConfig:
     SwiGLU experts of ``moe_d_ff`` beside ``n_shared_experts`` shared ones).
     Nothing here assumes a period: the walk is read from the two tuples.
 
+    Four things a model of this walk may state otherwise (SmallThinker
+    does, all four): ``qk_norm`` False (no per-head norm; its two weights
+    are not parameters then), ``n_shared_experts`` 0 (no shared expert),
+    the experts' forms ``scoring`` / ``expert_activation``
+    (``layers.moe_mlp.HeldExpertsMoE``: ``"softmax_topk"``, ``"reglu"``) and
+    ``router_input`` ``"layer_input"``: the router stands BEFORE attention
+    and reads the layer's input stream as it is, un-normed, while the
+    experts still read the post-attention norm. A walk with no dense layer
+    has no ``dense`` parameters and reads no ``d_ff``.
+
     ``experts_held`` / ``experts_lo`` as ``DeepseekV3Config`` has them: this
     device is one chip's share of an expert-parallel deployment and holds
     the routed experts ``[experts_lo, experts_lo + experts_held)`` of every
@@ -465,8 +475,19 @@ class ExaoneMoeConfig:
     rms_eps: float = 1e-5              # rms_norm_eps
     max_length: int = 4096
     dtype: jnp.dtype = jnp.bfloat16
+    qk_norm: bool = True
+    scoring: str = "sigmoid"           # | "softmax_topk"
+    expert_activation: str = "swiglu"  # | "reglu"
+    router_input: str = "post_attn_norm"   # | "layer_input"
 
     def __post_init__(self):
+        if self.router_input not in ("post_attn_norm", "layer_input") \
+                or self.expert_activation not in ("swiglu", "reglu") \
+                or self.scoring not in ("sigmoid", "softmax_topk"):
+            raise ValueError(
+                f"unknown router_input {self.router_input!r}, "
+                f"expert_activation {self.expert_activation!r} or scoring "
+                f"{self.scoring!r}")
         n = len(self.layer_types)
         if not n or len(self.sliding_windows) != n \
                 or len(self.mlp_layer_types) != n:
@@ -532,6 +553,28 @@ class ExaoneMoeConfig:
         return max(self.sliding_windows)
 
     slot_state_shapes = None
+
+    @classmethod
+    def smallthinker(cls, **overrides) -> "ExaoneMoeConfig":
+        """SmallThinker-21BA3B-Instruct's public ``config.json``
+        (PowerInfer/SmallThinker-21BA3B-Instruct): 52 layers ``full, window,
+        window, window`` x 13, window 4,096 with rope on the window layers
+        and none on the full ones, 28 query / 4 key heads and no QK norm,
+        every layer 64 ReGLU experts of 768 with no shared expert, the 6
+        largest logits of a router that reads the layer's input, softmax
+        over the chosen."""
+        period = ("full_attention",) + ("sliding_attention",) * 3
+        return cls(**{**dict(
+            model_name="PowerInfer/SmallThinker-21BA3B-Instruct",
+            vocab_size=151_936, d_model=2560, layer_types=period * 13,
+            sliding_windows=(0, 4096, 4096, 4096) * 13,
+            mlp_layer_types=("sparse",) * 52, n_heads=28, n_kv_heads=4,
+            head_dim=128, d_ff=0, moe_d_ff=768, n_experts=64,
+            n_experts_per_tok=6, n_shared_experts=0,
+            routed_scaling_factor=1.0, rope_theta=1.5e6, rms_eps=1e-6,
+            max_length=16_384, qk_norm=False, scoring="softmax_topk",
+            expert_activation="reglu", router_input="layer_input"),
+            **overrides})
 
     @classmethod
     def tiny(cls, **overrides) -> "ExaoneMoeConfig":

@@ -31,6 +31,26 @@ a repeated unit (the published 48 layers: the dense layer, then ``window,
 window, full, window`` x 11 and three more: 8 layer bodies traced, not 48);
 a run of more than one is a ``lax.scan`` whose body is the unit written out.
 
+FOUR THINGS ARE READ FROM THE CONFIGURATION and are not the block's own
+(SmallThinker-21BA3B states all four otherwise: ``ExaoneMoeConfig
+.smallthinker()``): the per-head norm (``qk_norm``; without it ``q_norm`` /
+``k_norm`` are not parameters), the shared expert (``n_shared_experts`` 0:
+no ``shared`` parameters, nothing added), the experts' score and expert
+forms (``scoring``, ``expert_activation``: the ``topk`` largest raw logits
+with a softmax over the chosen, gated ReGLU) and WHERE THE ROUTER READS
+(``router_input``). ``"layer_input"`` is a router that stands before
+attention::
+
+    r = x W_r                     # the layer's INPUT, no norm
+    h = x + Attn_l(RMSNorm(x));   y = h + Experts(RMSNorm(h); routed by r)
+
+``HeldExpertsMoE.routed(..., route_from=x)`` routes from the stream the
+layer came in with and feeds the experts the post-attention norm. In
+program order the router's product and the sort stand where the experts
+are called, after attention; they depend on nothing attention computes, so
+the compiler is free to move them (nothing is claimed from where it puts
+them). A walk with no dense layer has no ``dense`` stack.
+
 What is not built, and refused by name: more than one device (the ring
 storage is not sharded, and the experts' exchange over ICI does not run
 under ``BatchEngine``), speculative verify (a rejected draft's rows would
@@ -42,11 +62,12 @@ Parameters (all replicated)::
 
     embed (V, d), final_norm (d,), lm_head (d, V)
     attn:  stacked over ALL layers
-        input_norm, post_norm, attn {w_qkv, w_o, q_norm, k_norm}
+        input_norm, post_norm, attn {w_qkv, w_o[, q_norm, k_norm]}
     dense: stacked over the dense layers   {w_gate_up (d, 2 ff), w_down}
+           (absent where the walk has none)
     moe:   stacked over the sparse layers
-        router (d, E) f32, w_gate_up (held, d, 2 ffe), w_down (held, ffe, d),
-        shared {w_gate_up (d, 2 ffs), w_down (ffs, d)}
+        router (d, E) f32, w_gate_up (held, d, 2 ffe), w_down (held, ffe, d)
+        [, shared {w_gate_up (d, 2 ffs), w_down (ffs, d)}]
 """
 
 from __future__ import annotations
@@ -94,6 +115,15 @@ class ExaoneMoe:
                 if (n := sum(k in pair for pair in kinds))}
 
     @functools.cached_property
+    def moe_forms(self) -> dict:
+        """The expert layers by name (``stats_snapshot()["moe"]``): the
+        score form, the expert form, whether a shared expert is added, and
+        where the router reads."""
+        return {**self.moe.forms,
+                "shared": self.config.n_shared_experts > 0,
+                "router_input": self.config.router_input}
+
+    @functools.cached_property
     def segments(self) -> tuple:
         return pattern_segments(self.config.layer_kinds)
 
@@ -102,7 +132,7 @@ class ExaoneMoe:
         return TPAttn(d_model=c.d_model, n_heads=c.n_heads,
                       n_kv_heads=c.n_kv_heads, head_dim=c.head_dim,
                       axis=self.axis, dtype=c.dtype, rope_theta=c.rope_theta,
-                      qk_norm=True, rms_eps=c.rms_eps,
+                      qk_norm=c.qk_norm, rms_eps=c.rms_eps,
                       rope=window is not None, window=window)
 
     @functools.cached_property
@@ -118,7 +148,8 @@ class ExaoneMoe:
             d_model=c.d_model, d_ff=c.moe_d_ff, n_experts=c.n_experts,
             topk=c.n_experts_per_tok, n_held=c.n_held, lo=c.experts_lo,
             routed_scaling=c.routed_scaling_factor,
-            norm_topk_prob=c.norm_topk_prob, dtype=c.dtype)
+            norm_topk_prob=c.norm_topk_prob, dtype=c.dtype,
+            activation=c.expert_activation, scoring=c.scoring)
 
     # -- parameters ---------------------------------------------------------
 
@@ -137,20 +168,24 @@ class ExaoneMoe:
         attn = {"input_norm": ((d,), None), "post_norm": ((d,), None),
                 "attn": {
                     "w_qkv": ((d, (c.n_heads + 2 * c.n_kv_heads) * dh), d),
-                    "w_o": ((c.n_heads * dh, d), c.n_heads * dh),
-                    "q_norm": ((dh,), None), "k_norm": ((dh,), None)}}
+                    "w_o": ((c.n_heads * dh, d), c.n_heads * dh)}}
+        if c.qk_norm:
+            attn["attn"].update(q_norm=((dh,), None), k_norm=((dh,), None))
         dense = {"w_gate_up": ((d, 2 * c.d_ff), d),
                  "w_down": ((c.d_ff, d), c.d_ff)}
         moe = {"router": ((d, c.n_experts), d),
                "w_gate_up": ((c.n_held, d, 2 * c.moe_d_ff), d),
-               "w_down": ((c.n_held, c.moe_d_ff, d), c.moe_d_ff),
-               "shared": {"w_gate_up": ((d, 2 * ffs), d),
-                          "w_down": ((ffs, d), ffs)}}
-        return {"embed": ((c.vocab_size, d), d), "final_norm": ((d,), None),
+               "w_down": ((c.n_held, c.moe_d_ff, d), c.moe_d_ff)}
+        if ffs:
+            moe["shared"] = {"w_gate_up": ((d, 2 * ffs), d),
+                             "w_down": ((ffs, d), ffs)}
+        tree = {"embed": ((c.vocab_size, d), d), "final_norm": ((d,), None),
                 "lm_head": ((d, c.vocab_size), d),
                 "attn": stacked(c.n_layers, attn),
-                "dense": stacked(n.get("dense", 0), dense),
                 "moe": stacked(n.get("moe", 0), moe)}
+        if "dense" in n:
+            tree["dense"] = stacked(n["dense"], dense)
+        return tree
 
     def param_specs(self):
         return jax.tree.map(lambda leaf: P(), self.param_shapes(),
@@ -273,10 +308,12 @@ class ExaoneMoe:
         # ``[layer, expert]`` of them itself. Every other leaf is read at
         # ``[layer of its kind]`` of its stack where it lies (a slice of a
         # stack handed to a scan as ``xs`` is copied out first).
-        light = {k: params[k] for k in ("attn", "dense")}
+        light = {k: params[k] for k in ("attn", "dense") if k in params}
         light["moe"] = dict(params["moe"])
         heavy = {k: light["moe"].pop(k) for k in ("w_gate_up", "w_down")}
-        no_bias = jnp.zeros((c.n_experts,), jnp.float32)
+        if c.scoring == "sigmoid":          # this block has no selection bias
+            heavy["bias"] = jnp.zeros((c.n_experts,), jnp.float32)
+        early_router = c.router_input == "layer_input"
 
         def at(tree, idx):
             return jax.tree.map(
@@ -289,6 +326,7 @@ class ExaoneMoe:
             attn_kind, ffn_kind = kinds
             idx = {k: jnp.asarray(v, jnp.int32) for k, v in idx.items()}
             lp = at(light["attn"], idx["layer"])
+            x_in = h
             hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
             a, state = self.attn[attn_kind].local_fwd(
                 lp["attn"], hn.astype(c.dtype), state, blocks=blocks,
@@ -300,9 +338,10 @@ class ExaoneMoe:
                 mp = at(light["dense"], idx["dense"])
                 m = swiglu(hn.astype(c.dtype), mp["w_gate_up"], mp["w_down"])
             else:
-                mp = dict(at(light["moe"], idx["moe"]), **heavy,
-                          bias=no_bias)
-                m, st = self.moe.fwd(mp, hn, valid, layer_idx=idx["moe"],
+                mp = dict(at(light["moe"], idx["moe"]), **heavy)
+                m, st = self.moe.fwd(mp, hn, valid,
+                                     x_in if early_router else None,
+                                     layer_idx=idx["moe"],
                                      interpret=interpret)
                 stats = stats + st
             return h + m, state, stats

@@ -14,7 +14,7 @@ The block (HF ``nemotron_h``)::
     h_0 = E[ids];   h <- h + Mixer_i(RMSNorm_i(h));   logits = RMSNorm(h) W_head
 
 ``Mixer_i`` is ``layers.mamba2.Mamba2`` (``M``), ``layers.moe_mlp
-.HeldExpertsMoE`` with ``gated=False`` (``E``: this device one chip's share
+.HeldExpertsMoE`` with ``activation="relu2"`` (``E``: this device one chip's share
 of an expert-parallel deployment, ``NemotronHConfig.experts_held``) or
 ``layers.tp_attn.TPAttn`` with ``rope=False`` (``*``).
 
@@ -136,7 +136,8 @@ class NemotronH:
             d_model=c.d_model, d_ff=c.moe_d_ff_stored, n_experts=c.n_experts,
             topk=c.n_experts_per_tok, n_held=c.n_held, lo=c.experts_lo,
             routed_scaling=c.routed_scaling_factor,
-            norm_topk_prob=c.norm_topk_prob, dtype=c.dtype, gated=False)
+            norm_topk_prob=c.norm_topk_prob, dtype=c.dtype,
+            activation="relu2")
 
     # -- parameters ---------------------------------------------------------
 

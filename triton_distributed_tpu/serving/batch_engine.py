@@ -897,6 +897,11 @@ class BatchEngine:
         kinds = getattr(self.engine.model, "layer_counts", None)
         if kinds:
             snap["layers"] = dict(kinds)
+        # ... and one with routed experts what they are made of: the score
+        # form, the expert form, where the router reads.
+        forms = getattr(self.engine.model, "moe_forms", None)
+        if forms:
+            snap["moe"] = dict(forms)
         lookups = m.get("prefix_lookups", 0.0)
         if lookups:
             snap["prefix_hit_rate"] = round(
