@@ -497,11 +497,19 @@ def plain_window_attention(q, k, v, kv_lens, q_lens, window, scale):
 # (window, block size, ring blocks, context lengths a row): contexts below,
 # at and above the window; window edges on a block boundary (8 | 16), off it
 # (6 in blocks of 4), on a tile boundary (the tile is window // bs blocks);
-# a ring that has wrapped (contexts past ring_blocks * bs).
+# a ring that has wrapped (contexts past ring_blocks * bs). The last two
+# pass ``tile_blocks`` (a fifth entry), so a window is several tiles and a
+# WHOLE tile is one copy: the cells' own 284 / 32 and 36 / 8 scaled down to
+# rings of 19 and 9 blocks under tiles of 2, no multiple of the tile, so
+# that in one call a tile is whole (contexts 64, 150), whole and wrapping
+# the ring (tile 9 of context 77, tile 28 of 251 and 233; tile 4 of 40),
+# ragged behind ``lo`` (251; 71) and ragged at ``limit`` (150, 251; 17, 90).
 WALKS = {
     "edges-on-blocks": (8, 4, 6, (3, 8, 9, 16, 24, 41)),
     "edges-off-blocks": (6, 4, 5, (2, 6, 7, 13, 21, 38)),
     "one-block-window": (4, 4, 4, (1, 4, 5, 12, 17, 30)),
+    "whole-tiles-ring-19": (64, 4, 19, (20, 64, 77, 150, 251, 233), 2),
+    "whole-tiles-ring-9": (16, 4, 9, (5, 16, 17, 40, 71, 90), 2),
 }
 
 
@@ -516,7 +524,7 @@ def test_the_window_build_equals_plain_numpy(walk, L, Hq):
     head, ragged ``q_lens``), rows in shuffled slots, against plain numpy
     over the whole sequences; and the gather oracle the same. Two query
     heads to a key head, and seven (a group that is no power of two)."""
-    window, bs, ring_blocks, ctx = WALKS[walk]
+    window, bs, ring_blocks, ctx, *tile = WALKS[walk]
     rng = np.random.default_rng(len(walk) + L)
     B, Hkv, dh, S = len(ctx), 2, 16, max(ctx)
     k = rng.standard_normal((B, S, Hkv, dh)).astype(np.float32)
@@ -534,7 +542,7 @@ def test_the_window_build_equals_plain_numpy(walk, L, Hq):
     got = paged_attention(
         jnp.asarray(q), kr, vr, jnp.asarray(slots)[:, None],
         jnp.asarray(kv_lens), q_lens=jnp.asarray(q_lens), layer=0,
-        window=window, interpret=True)
+        window=window, interpret=True, tile_blocks=tile[0] if tile else None)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
     oracle = nn.window_attn_with_cache(
         jnp.asarray(q), kr, vr, jnp.asarray(slots),
@@ -557,25 +565,78 @@ def test_the_window_build_refuses_what_it_has_not():
                         probes=True)
 
 
-@pytest.mark.parametrize("shape", [{}, {"L": 8, "q_tile": 4}],
-                         ids=["decode", "chunk"])
+def copies_of_a_step(kv_len, q_len, qt, q_tile, window, bs, tile, ring):
+    """What one grid step of the window walk fetches, plainly: (whole tiles
+    that lie side by side in the ring, whole tiles that wrap it, live
+    blocks of the ragged tiles). A block is live where it holds a key that
+    some query of the tile sees; a tile is whole where every block of it is
+    and its last line is a key (the kernel's test)."""
+    if qt * q_tile >= q_len:
+        return 0, 0, 0
+    first_q = kv_len - q_len + qt * q_tile
+    limit = kv_len - q_len + min((qt + 1) * q_tile, q_len)
+    lo = max(first_q - window + 1, 0)
+    tiles = {}
+    for j in range(-(-limit // bs)):
+        if (j + 1) * bs > lo:
+            tiles.setdefault(j // tile, []).append(j)
+    side_by_side = wrapping = ragged = 0
+    for t, blocks in tiles.items():
+        if len(blocks) < tile or (t + 1) * tile * bs > limit:
+            ragged += len(blocks)
+        elif (t * tile) % ring + tile <= ring:
+            side_by_side += 1
+        else:
+            wrapping += 1
+    return side_by_side, wrapping, ragged
+
+
+ROUND_THE_RING = dict(max_blocks=7, window=40, kv_len=(90, 131))
+
+
+@pytest.mark.parametrize("shape", [
+    {}, {"L": 8, "q_tile": 4}, ROUND_THE_RING,
+    dict(ROUND_THE_RING, L=8, q_tile=4)],
+    ids=["decode", "chunk", "decode-round-the-ring", "chunk-round-the-ring"])
 def test_a_block_behind_the_window_is_neither_copied_nor_waited_for(shape):
     """The analyzer's event log of the window build (``paged.window``: a
     ring of 6 blocks of 8, a window of 24, contexts of 48, tiles of 2
-    blocks): a grid step starts copies for the blocks that hold a visible
-    key and for no other (the decode shape: keys 24..47, three blocks of six;
-    a chunk's query tile of 4: four), every started copy is waited for, and
-    the sweeps of every registered kernel stay clean with it."""
+    blocks): a grid step moves the blocks that hold a visible key and no
+    other (the decode shape: keys 24..47, three blocks of six; a chunk's
+    query tile of 4: four), a WHOLE tile of them whose blocks lie side by
+    side in the ring as ONE copy an arena (the decode shape: blocks 4-5;
+    block 3 alone, its tile ragged at the start), every other live block as
+    its own; every started copy is waited for, at its size, and the sweeps
+    of every registered kernel stay clean with it. Round a ring of 7 blocks
+    (no multiple of the tile; a window of 40; contexts of 90 and 131) a
+    walk has whole tiles, whole tiles that wrap (a copy a block) and ragged
+    ones at both ends."""
     from triton_distributed_tpu.analysis import checks, events, resources
     from triton_distributed_tpu.analysis import registry as reg
 
     spec = reg.get("paged.window").build(1, **shape)
+    kw, (B, n_q_tiles) = spec.kwargs, spec.grid
+    tile, bs, L = kw["tile_blocks"], kw["bs"], shape.get("L", 1)
+    ring = shape.get("max_blocks", 6)
+    kv_lens = np.broadcast_to(shape.get("kv_len", ring * bs), (B,))
+    walks = np.array([
+        copies_of_a_step(int(kv_lens[b]), L, qt, kw["q_tile"], kw["window"],
+                         bs, tile, ring)
+        for b in range(B) for qt in range(n_q_tiles)])
+    side_by_side, wrapping, ragged = (int(n) for n in walks.sum(0))
+    assert side_by_side > 0 and ragged > 0
+    assert (wrapping > 0) == ("kv_len" in shape)
+    if "kv_len" not in shape:           # the numbers the docstring gives
+        assert (side_by_side, ragged) == ((2, 2) if L == 1 else (6, 4))
     log = events.trace_kernel(spec, 1).logs[0]
-    started = sum(1 for e in log if e.kind == "inc")
-    waited = sum(1 for e in log if e.kind == "wait")
-    steps = int(np.prod(spec.grid))                   # slots x query tiles
-    per_step = 3 if not shape else 4                  # of 6 live blocks
-    assert started == waited == 2 * per_step * steps  # K and V
+    block_bytes = bs * kw["n_kv"] * 128 * 4               # a K or V block
+    for kind in ("inc", "wait"):
+        sizes = sorted(e.amount for e in log if e.kind == kind)
+        # K and V: one copy a tile that is whole and side by side, one a
+        # live block of any other; a block behind the window moves no byte
+        assert sizes == sorted(
+            [block_bytes] * (2 * (wrapping * tile + ragged))
+            + [tile * block_bytes] * (2 * side_by_side)), kind
     assert checks.check_kernel("paged.window", 1) == []
     assert resources.check_kernel("paged.window", 1, shape) == []
 

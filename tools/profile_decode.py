@@ -20,7 +20,14 @@ shape's tiles take (``folded`` / ``per_head``), ms a step of all layers, us
 a live kv tile, GB/s of live pool bytes. ``--layers 4 --hkv 4 --g 8`` is
 the granite cell's geometry (packed key rows). ``--latent W,V`` times
 the latent build (one arena of W-wide rows, values the first V columns;
-``--hkv 1``). No cell runs it; it is ROADMAP S5's yardstick."""
+``--hkv 1``). ``--window W`` times the window build over ring storage:
+contexts drawn 300 .. ``--max-len`` less a chunk (there is no pool to fill),
+so ``--window 4096 --max-len 16384 --layers 6 --hkv 4 --g 7`` is the
+SmallThinker cell's geometry (a ring of 284 blocks a slot, contexts past the
+window and round the ring); its lines also say how many copies a layer's
+walks start: two a WHOLE tile whose blocks lie side by side in the ring,
+two a live block of any other tile (ragged at an end, or wrapping the
+ring). No cell runs it; it is ROADMAP S5's yardstick."""
 import functools, time
 import os, sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -71,6 +78,24 @@ def _probes_mode():
     dist_print(f"device trace rows -> {paths[0]}")
 
 
+def _window_copies(kv_len, window, bs, tile, ring):
+    """What a decoding row's window walk fetches, by the kernel's rule: (whole
+    tiles whose blocks lie side by side in the ring: ONE copy an arena; live
+    blocks of every other tile, ragged at an end or wrapping the ring: a copy
+    each)."""
+    lo, span = max(kv_len - window, 0), tile * bs
+    whole = blocks = 0
+    for t in range(lo // span, -(-kv_len // span)):
+        live = sum((t * tile + i + 1) * bs > lo and (t * tile + i) * bs < kv_len
+                   for i in range(tile))
+        if (live == tile and (t + 1) * span <= kv_len
+                and (t * tile) % ring + tile <= ring):
+            whole += 1
+        else:
+            blocks += live
+    return whole, blocks
+
+
 def _kernel_mode():
     import argparse
     import numpy as np
@@ -97,7 +122,8 @@ def _kernel_mode():
     ap.add_argument("--window", type=int, default=0,
                     help="the WINDOW build: ring storage a slot, a walk "
                          "over the last WINDOW keys (--layers window "
-                         "layers; --n-blocks and --live are not read)")
+                         "layers; contexts 300 .. --max-len less a chunk; "
+                         "--n-blocks and --live are not read)")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     a = ap.parse_args()
@@ -119,6 +145,8 @@ def _kernel_mode():
         mid = (lo + hi) / 2
         lo, hi = (mid, hi) if drawn(mid)[1].sum() < a.live * nb else (lo, mid)
     lens, n_live_blocks = drawn(lo)
+    if a.window:        # a ring a slot: no pool bounds the contexts
+        lens = (300 + (a.max_len - a.chunk - 300) * u).astype(np.int64)
     perm = rng.permutation(nb)                       # shuffled tables
     tables = np.zeros((B, max_blocks), np.int32)
     at = 0
@@ -150,9 +178,12 @@ def _kernel_mode():
     dist_print(
         f"geometry: {a.layers} layers, pool {nb} x {bs} rows, row {row}, "
         f"{B} slots, contexts {lens.min()}-{lens.max()} "
-        f"({n_live_blocks.sum()} blocks live, "
-        f"{n_live_blocks.sum() / nb:.1%} of the pool), {block_bytes} B a "
-        f"block, device {jax.devices()[0].device_kind}")
+        + (f"(a ring of {ring} blocks a slot, a window of {a.window})"
+           if a.window else
+           f"({n_live_blocks.sum()} blocks live, "
+           f"{n_live_blocks.sum() / nb:.1%} of the pool)")
+        + f", {block_bytes} B a block, "
+        f"device {jax.devices()[0].device_kind}")
 
     for shape, L in (("decode", 1), ("chunk", a.chunk)):
         q_lens = np.ones((B,), np.int32)
@@ -194,13 +225,28 @@ def _kernel_mode():
                 live_blocks = live_blocks - first
             n_tiles = int((-(-live_blocks // t_used)).sum())
             live_bytes = int(live_blocks.sum()) * block_bytes * a.layers
+            copies = ""
+            if a.window:
+                # A slot's walk is one query tile's unless it prefills (the
+                # chunk's query tiles each walk their own window: the line
+                # counts the decoding rows, 31 of 32).
+                whole = other = 0
+                for b in np.flatnonzero(q_lens == 1):
+                    w, o = _window_copies(int(kv_lens[b]), a.window, bs,
+                                          t_used, ring)
+                    whole, other = whole + w, other + o
+                copies = (f", {2 * (whole + other)} copies a layer for the "
+                          f"decoding rows ({whole} whole tiles x 2, {other} "
+                          f"blocks x 2; a copy a block: "
+                          f"{2 * (whole * t_used + other)})")
             dist_print(
                 f"{shape:6s} tile_blocks={t_used:3d} "
                 f"{resolved['arithmetic']:8s}: {ms:8.3f} ms a step "
                 f"({ms / a.layers * 1e3:7.1f} us a layer), "
                 f"{n_tiles} live tiles a layer, "
                 f"{ms * 1e3 / a.layers / n_tiles:6.2f} us a tile, "
-                f"{live_bytes / ms / 1e6:6.1f} GB/s of live bytes")
+                f"{live_bytes / ms / 1e6:6.1f} GB/s of live bytes"
+                + copies)
 
 
 if "--probes" in sys.argv:

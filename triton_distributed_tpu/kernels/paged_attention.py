@@ -341,10 +341,21 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     wholly behind the window is neither copied nor waited for; the
     score-side select and the V scrub take that lower bound beside the
     causal one. The arenas are a window layer's RING storage ``(layers,
-    slots, ring blocks, bs, Hkv, dh)`` (``serving.kv_pool``): the table is
-    ``(B, 1)``, the SLOT each row belongs to, and logical block ``j`` of
-    that slot is ring block ``j % ring blocks`` of it: arithmetic, no
-    table of blocks. The fetch pipeline and both arithmetics are the K+V
+    slots, ring blocks, bs, Hkv, dh)`` (``serving.kv_pool``), handed to the
+    kernel as a slot's lines ``(layers, slots, ring blocks * bs, Hkv, dh)``
+    (the same bytes): the table is ``(B, 1)``, the SLOT each row belongs
+    to, and logical block ``j`` of that slot is ring block ``j % ring
+    blocks`` of it: arithmetic, no table of blocks. So the blocks of a tile
+    lie SIDE BY SIDE in HBM, which no paged pool's do, and the fetch takes
+    its copy size from that: a WHOLE tile (every block live: none behind
+    ``lo``, none past ``limit``) that does not wrap the ring is ONE copy an
+    arena, started once and waited for once at the tile's size; a tile
+    ragged at either end, or one that wraps, goes a copy a live block like
+    any other build's. A copy costs about 37 ns whatever it carries
+    (PERF.md section 6, PR 42), so a tile of 32 blocks of 16 KiB is bound by
+    its 64 copies, and by its bytes only as 2. No whole copy reads a block
+    the walk by blocks would not.
+    The pipeline's order, the semaphores and both arithmetics are the K+V
     build's own.
 
     Builds, by ``n_arenas``: 2 — K and V ``(..., Hkv, dh)``. 4 — a
@@ -376,6 +387,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     qt = pl.program_id(1)
     layer = layer_ref[0]
     span = tile_blocks * bs
+    if window is not None:
+        ring = arenas[0].shape[2] // bs     # blocks of a slot's ring
 
     def frontier(b, qt):
         """(kv_len, q_len, fetch ceiling, live kv tiles) of a grid step.
@@ -432,23 +445,26 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    def block_copies(b, tile, slot, i):
-        """Block ``i`` of slot ``b``'s kv tile ``tile``: one (source,
-        staging rows, semaphore) copy an arena into staging slot ``slot``.
-        ``b`` None builds a copy to WAIT for: that needs its size and
-        semaphore, not its source, so it reads no table entry."""
+    def block_copies(b, tile, slot, i, n=1):
+        """Blocks ``i .. i + n`` of slot ``b``'s kv tile ``tile``: one
+        (source, staging rows, semaphore) copy an arena into staging slot
+        ``slot``. A paged pool scatters its blocks, so there ``n`` is 1; a
+        ring's lie side by side and ``n`` of them are one copy. ``b`` None
+        builds a copy to WAIT for: that needs its size and semaphore, not
+        its source, so it reads no table entry."""
+        rows = pl.ds(i * bs, n * bs)
         if window is None:
             # Same defensive clamp as the gather path's mode="clip".
             src = (layer, 0 if b is None else jnp.clip(
                 tbl_ref[b, tile * tile_blocks + i], 0, n_blocks - 1))
         else:
-            # Ring storage: the row's slot, then the logical block's place
-            # in the slot's ring.
-            src = (layer, 0, 0) if b is None else (
+            # Ring storage: the row's slot, then the lines of the logical
+            # block's place in the slot's ring.
+            src = (layer, 0, rows) if b is None else (
                 layer, jnp.clip(tbl_ref[b, 0], 0, n_blocks - 1),
-                jax.lax.rem(tile * tile_blocks + i, arenas[0].shape[2]))
-        return [(arena.at[src], stage.at[slot, pl.ds(i * bs, bs)],
-                 sems.at[slot, a])
+                pl.ds(jax.lax.rem(tile * tile_blocks + i, ring) * bs,
+                      n * bs))
+        return [(arena.at[src], stage.at[slot, rows], sems.at[slot, a])
                 for a, (arena, stage) in enumerate(zip(arenas, stages))]
 
     def for_live_blocks(tile, limit, lo, fn):
@@ -456,8 +472,6 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         # the window build the walk's first tile is ragged at its start
         # too: a block whose last row lies behind ``lo`` is not live.)
         whole = (tile + 1) * span <= limit
-        if window is not None:
-            whole &= tile * span + bs > lo
 
         def live(i):
             ok = tile * span + i * bs < limit
@@ -465,10 +479,22 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                 ok &= tile * span + (i + 1) * bs > lo
             return ok
 
-        @pl.when(whole)
-        def _all():
-            for i in range(tile_blocks):
-                fn(i)
+        if window is None:
+            @pl.when(whole)
+            def _all():
+                for i in range(tile_blocks):
+                    fn(i)
+        else:
+            # A whole tile whose blocks do not wrap the ring is ONE run of
+            # lines in HBM: one copy an arena. One that wraps goes block by
+            # block with the ragged ones.
+            whole &= tile * span + bs > lo
+            whole &= (jax.lax.rem(tile * tile_blocks, ring) + tile_blocks
+                      <= ring)
+
+            @pl.when(whole)
+            def _one():
+                fn(0, tile_blocks)
 
         @pl.when(jnp.logical_not(whole))
         def _ragged():
@@ -478,15 +504,15 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                     fn(i)
 
     def start_tile(b, tile, limit, lo, slot):
-        def start(i):
-            for src, dst, sem in block_copies(b, tile, slot, i):
+        def start(i, n=1):
+            for src, dst, sem in block_copies(b, tile, slot, i, n):
                 probe.dma_issue(src)
                 pltpu.make_async_copy(src, dst, sem).start()
         for_live_blocks(tile, limit, lo, start)
 
     def wait_tile(tile, slot):
-        def wait(i):
-            for src, dst, sem in block_copies(None, tile, slot, i):
+        def wait(i, n=1):
+            for src, dst, sem in block_copies(None, tile, slot, i, n):
                 pltpu.make_async_copy(src, dst, sem).wait()
                 probe.dma_wait(src)
         for_live_blocks(tile, limit, lo, wait)
@@ -844,6 +870,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                 "window of at least one key")
         block_tables = block_tables.reshape(B, 1)
         _, n_blocks, ring, bs, Hkv, _ = k_pool.shape   # n_blocks: slots
+        # The kernel reads a slot's ring as its LINES, (layers, slots, ring
+        # blocks * bs, Hkv, dh) (the same bytes): blocks side by side are
+        # one run of lines, which is what one copy can take.
+        k_pool, v_pool = (a.reshape(*a.shape[:2], ring * bs, *a.shape[4:])
+                          for a in (k_pool, v_pool))
     elif latent:
         if quant:
             raise NotImplementedError("the latent pool has no quantized "
@@ -973,8 +1004,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     scratch_shapes = [
         # Staging, two slots an arena: tile j + 1 lands in one while tile j
         # is computed from the other.
-        *(pltpu.VMEM((2, tile_blocks * bs,
-                      *a.shape[3 if window is None else 4:]), a.dtype)
+        *(pltpu.VMEM((2, tile_blocks * bs, *a.shape[3:]), a.dtype)
           for a in arenas),
         pltpu.VMEM((heads, rows, dv), jnp.float32),  # acc
         pltpu.VMEM((heads, rows, 1), jnp.float32),   # running max
@@ -1095,7 +1125,8 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
                 max_blocks: int = 4, dtype: str = "float32", L: int = 1,
                 q_tile: int = 1, kvq: bool = False,
                 v_dim: int | None = None,
-                window: int | None = None) -> "_comm.TraceSpec":
+                window: int | None = None,
+                kv_len=None) -> "_comm.TraceSpec":
     B = 2
     dt = _np.dtype(jnp.dtype(dtype))
     n_blocks = B * max_blocks
@@ -1135,7 +1166,7 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
     # by slot, the table one slot id a row; ``max_blocks`` is the ring.
     pool = (2, n_blocks, bs)
     if window is not None:
-        pool, tbl_w, n_blocks = (2, B, max_blocks, bs), 1, B
+        pool, tbl_w, n_blocks = (2, B, max_blocks * bs), 1, B
         n_tiles = 1 << 20
 
         def tables(r, w):                                   # noqa: F811
@@ -1148,9 +1179,12 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
         args=[
             _comm.Buf("tbl", (B, tbl_w), _np.int32, space="smem",
                       init=tables),
+            # Contexts of the whole table unless given (one, or one a slot:
+            # the window build's may run past its ring).
             _comm.Buf("kvlen", (B,), _np.int32, space="smem",
-                      init=lambda r, w: _np.full((B,), max_blocks * bs,
-                                                 _np.int32)),
+                      init=lambda r, w: _np.broadcast_to(_np.asarray(
+                          max_blocks * bs if kv_len is None else kv_len,
+                          _np.int32), (B,)).copy()),
             _comm.Buf("qlen", (B,), _np.int32, space="smem",
                       init=lambda r, w: _np.full((B,), L, _np.int32)),
             _comm.Buf("layer", (1,), _np.int32, space="smem",
@@ -1234,7 +1268,9 @@ def _paged_spec_window(world: int, *, window: int = 24, bs: int = 8,
     """The WINDOW build (ring storage read by slot, the walk started at the
     window's first tile; ``max_blocks`` is the ring's blocks), decode shape:
     contexts of the whole ring, so the first tile lies behind the window
-    and is neither copied nor waited for."""
+    and is neither copied nor waited for, the second is ragged at its start
+    (a copy a live block) and the third whole: ONE copy an arena.
+    ``kv_len`` (one, or one a slot) takes the contexts round the ring."""
     return _paged_spec(world, window=window, bs=bs, tile_blocks=tile_blocks,
                        max_blocks=max_blocks, **kw)
 

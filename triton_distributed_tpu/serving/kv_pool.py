@@ -35,7 +35,11 @@ Token ``p`` of the sequence in slot ``s`` lies in ring block ``(p //
 block_size) % ring_blocks`` of ``s``, line ``p % block_size``, over whatever
 an older lap (or the slot's last request) left there. So the window layers
 need no allocator, no table beyond arithmetic, and nothing to free; their
-bytes do not move with ``max_seq_len`` or ``n_blocks``; and what a line held
+bytes do not move with ``max_seq_len`` or ``n_blocks``; consecutive blocks of
+a slot lie side by side in HBM, so the window build of the block walk
+fetches a whole tile of them that does not wrap the ring in ONE copy an
+arena where a paged pool's scattered blocks are a copy each
+(``kernels/paged_attention.py``); and what a line held
 before is never seen, because a reader masks by POSITION (line ``r`` holds
 the newest position congruent to ``r`` that the sequence has written). All
 appends of a step come before any read and one slot may take several rows
