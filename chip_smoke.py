@@ -38,7 +38,8 @@ was routed to other experts at a shown tie (``ROUTER_TIE``).
 ``--chips 4`` (a four-chip host) runs only the tensor-parallel phase:
 Qwen3-8B over ``make_mesh({"tp": 4})`` through ``BatchEngine``, once in
 ``mode="dist"`` (AG-GEMM / GEMM-RS over ICI) and once in ``mode="xla"`` on
-the same weights, compared numerically.
+the same weights, compared numerically; its last line also carries
+``collectives``, each mode's ``stats_snapshot()["collectives"]``.
 
 Every line of standard output is one JSON object. The last one is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``;
@@ -779,7 +780,7 @@ def run_served_blocks(devices, geo: dict, caches: _CacheEvents) -> None:
 # -- four chips: TP=4 dist against xla ---------------------------------------
 
 
-def run_four_chips(devices, geo: dict, caches: _CacheEvents) -> None:
+def run_four_chips(devices, geo: dict, caches: _CacheEvents) -> dict:
     import jax
     import numpy as np
 
@@ -812,7 +813,7 @@ def run_four_chips(devices, geo: dict, caches: _CacheEvents) -> None:
     prompts = make_prompts(rng, cfg.vocab_size, geo)
     ref_prompts = prompts[:2]
     next_tok = [p[0] for p in ref_prompts]
-    outputs, logits = {}, {}
+    outputs, logits, collectives = {}, {}, {}
     for mode, engine in engines.items():
         t0 = time.perf_counter()
         # nan_guard: a non-finite logit row quarantines its request, which
@@ -834,6 +835,7 @@ def run_four_chips(devices, geo: dict, caches: _CacheEvents) -> None:
         be.pool.check_invariants()
         outputs[mode] = [done[r] for r in rids]
         logits[mode] = paged_logits(be, ref_prompts, next_tok)
+        collectives[mode] = be.stats_snapshot()["collectives"]
         emit(phase="serve", mode=mode, requests=len(rids),
              steps=int(be.metrics.counters.get("prefill_steps", 0.0)
                        + be.metrics.counters.get("decode_steps", 0.0)),
@@ -848,21 +850,25 @@ def run_four_chips(devices, geo: dict, caches: _CacheEvents) -> None:
                         zip(outputs["dist"], outputs["xla"])])
     emit(phase="memory", peak_bytes_in_use=peak_bytes(devices),
          **caches.snapshot())
+    # A record, not a gate: what the program itself counts an execution
+    # of each compiled step moving over the mesh, by mode.
+    return {"collectives": collectives}
 
 
 def smoke(run, devices, geo: dict) -> int:
-    """Run one phase. Prints the ``ok`` line and returns 0 only if every
-    check of it held; any other exception propagates (non-zero exit, no
-    ``ok`` line)."""
+    """Run one phase. Prints the ``ok`` line (with what the phase returned
+    for the record, if anything) and returns 0 only if every check of it
+    held; any other exception propagates (non-zero exit, no ``ok``
+    line)."""
     caches = _CacheEvents()
     try:
-        run(devices, geo, caches)
+        record = run(devices, geo, caches) or {}
     except SmokeFailure as e:
         print(f"chip_smoke FAILED: {e}", file=sys.stderr)
         return 1
     finally:
         caches.close()
-    print(json.dumps({"ok": True, "device": device_info(devices)}),
+    print(json.dumps({"ok": True, "device": device_info(devices), **record}),
           flush=True)
     return 0
 

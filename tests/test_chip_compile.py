@@ -230,6 +230,73 @@ def test_oneshot_allreduce_compiles_tp4(tp4):
     assert "tpu_custom_call" in text
 
 
+# The four-chip cell (perfbench/configs/qwen3-8b-tp4.json): Qwen3-8B whole
+# over TP=4, 32 slots, a 3,328-block pool, the AG-GEMM tile its VMEM allows.
+TP4_SLOTS, TP4_BLOCKS, TP4_BLOCK_N, TP4_PREFILL_ROWS = 32, 3328, 128, 7
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_the_four_chip_cells_step_compiles_with_its_kernels_named(tp4, kind):
+    """The served step of ``qwen3-8b-tp4.reasoning`` as ``BatchEngine``
+    builds it around ``forward_paged``, all 36 layers at the published
+    widths: it compiles for the four chips, the pool's arenas are aliased
+    in to out, a chip's arguments are its 5.96 GB of weights (the layers'
+    quarter, the table and the head whole) and 1.96 GB of pool, and the
+    compiled text knows the fused kernels by name."""
+    import dataclasses
+
+    from triton_distributed_tpu.models.config import ModelConfig
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.models.qwen import Qwen3
+    from triton_distributed_tpu.serving.kv_pool import (
+        paged_state_shapes,
+        paged_state_specs,
+    )
+
+    cfg = dataclasses.replace(ModelConfig.from_name("qwen3-8b"),
+                              max_length=MAX_LEN)
+
+    def placed(tree, specs):
+        return jax.tree.map(
+            lambda a, spec: _sds(a.shape, a.dtype, NamedSharding(tp4, spec)),
+            tree, specs)
+
+    def everywhere(shape, dtype):
+        return _sds(shape, dtype, NamedSharding(tp4, P()))
+
+    model = Qwen3(cfg, block_n=TP4_BLOCK_N)
+    params = placed(jax.eval_shape(lambda k: model.init(k, tp4),
+                                   jax.random.PRNGKey(0)),
+                    model.param_specs())
+    specs = paged_state_specs(cfg)
+    state = placed(paged_state_shapes(cfg, n_blocks=TP4_BLOCKS,
+                                      block_size=BLOCK, n_slots=TP4_SLOTS),
+                   specs)
+    engine = Engine(cfg, mesh=tp4, params=params, mode="dist",
+                    block_n=TP4_BLOCK_N, interpret=False)
+    step = jax.jit(engine._make_sm("dist", paged=kind, paged_attn="fused",
+                                   state_specs=specs), donate_argnums=(2,))
+    slots = (everywhere((TP4_SLOTS,), jnp.int32),
+             everywhere((TP4_SLOTS, MAX_BLOCKS), jnp.int32),
+             everywhere((TP4_SLOTS,), bool))
+    if kind == "decode":
+        args = (everywhere((TP4_SLOTS, 1), jnp.int32), state, *slots)
+    else:
+        ids = (everywhere((TP4_SLOTS,), jnp.int32),
+               everywhere((TP4_PREFILL_ROWS, CHUNK), jnp.int32),
+               everywhere((TP4_PREFILL_ROWS, 3), jnp.int32))
+        args = (ids, state, *slots, everywhere((TP4_SLOTS,), jnp.int32))
+    compiled = step.lower(params, *args).compile()
+    text = compiled.as_text()
+    for name in ("ag_gemm", "ag_gemm_tail", "gemm_rs", "paged_attention"):
+        assert f"%{name}." in text or f"%{name} =" in text, name
+    assert "all-gather" in text
+    mem = compiled.memory_analysis()
+    assert 1.95e9 < mem.alias_size_in_bytes < 1.97e9
+    assert 7.9e9 < mem.argument_size_in_bytes < 7.95e9
+    assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+
+
 # JoyAI-LLM-Flash's cell (perfbench/configs/joyai-llm-flash-ep16.json): 32
 # slots, a 3,328-block pool of 40 stacked layers, latent rows of 576 padded
 # to 640, 32 query heads on the one shared key head.

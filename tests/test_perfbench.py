@@ -212,7 +212,9 @@ def test_the_span_readers_entries_name_their_cells_and_find_their_readers():
         assert "host_turn_ms.reasoning" in cells
         assert "host_turn_ms.chat" not in cells
     assert names[at + 4:] == ["window_attn_device_share",
-                              "window_attn_roofline", "mixed_steps_share"]
+                              "window_attn_roofline", "mixed_steps_share",
+                              "collective_device_share",
+                              "ici_bytes_per_step"]
 
 
 def test_the_window_readers_arithmetic_and_silence_on_an_older_program():
@@ -396,3 +398,156 @@ def test_the_mixed_steps_share_reads_the_programs_counters():
     assert mixed_steps_share.read(
         rec(prefill_steps=0.0, decode_steps=0.0)) is None
     assert mixed_steps_share.read(rec(decode_steps=5.0)) is None
+
+
+TP4 = "qwen3-8b-tp4.reasoning"
+
+
+def test_the_four_chip_cell_is_the_dense_family_on_a_mesh_of_four():
+    """``qwen3-8b-tp4.reasoning`` by name: the published widths of
+    Qwen3-8B, all 36 layers and the whole untied vocabulary under the
+    benchmark's own ``reasoning`` mix, on a mesh ``{"tp": 4}`` with the
+    AG-GEMM tile the kernel's VMEM allows; the benchmark's one cell on four
+    chips; the readers it reports found by name; and the family's counts at
+    TP=4 (a chip's share of a decode step's least bytes)."""
+    from perfbench import core, families
+    from perfbench.traffic_kinds.closed_loop import Plan
+
+    bench = core.load_json(core.ROOT, "BENCHMARK.json")
+    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] == [TP4]
+    entry, = [c for c in bench["configs"] if c["name"] == "qwen3-8b-tp4"]
+    assert entry["reduced"] == ["max_position_embeddings"]
+    spec = core.load_cell(TP4)
+    assert spec["cell"]["chips"] == 4 and spec["cell"]["traffic"] == "reasoning"
+    cfg = spec["config"]
+    assert cfg["source"] == entry["source"] == \
+        "https://huggingface.co/Qwen/Qwen3-8B/blob/main/config.json"
+    published = {
+        "hidden_size": 4096, "intermediate_size": 12288, "head_dim": 128,
+        "num_attention_heads": 32, "num_key_value_heads": 8,
+        "num_hidden_layers": 36, "vocab_size": 151936,
+        "tie_word_embeddings": False, "rope_theta": 1000000,
+        "rms_norm_eps": 1e-06, "torch_dtype": "bfloat16"}
+    assert {k: cfg[k] for k in published} == published
+    assert cfg["max_position_embeddings"] == 4096 and cfg["chips"] == 4
+    assert cfg["serve"] == {
+        "mesh": {"tp": 4},
+        "engine": {"mode": "dist", "block_n": 128, "interpret": False},
+        "fleet": {"n_replicas": 1, "n_slots": 32, "block_size": 16,
+                  "prefill_chunk": 64, "n_blocks": 3328,
+                  "paged_attn": "fused"}}
+    # the mix is the file the five ``reasoning`` cells share, unchanged
+    assert spec["traffic"] == core.load_cell("qwen3-1.7b.reasoning")["traffic"]
+    family = families.load_family(cfg)
+    assert family.__name__ == "perfbench.families.qwen3"
+    sizes = family.sizes(cfg)
+    standing = Plan(spec["traffic"], seed=5, seconds=40,
+                    vocab=sizes.vocab_size, max_total=sizes.max_length,
+                    n_slots=32).standing()
+    assert sum(len(p.prompt) for p in standing) == 35_889
+    assert set(spec["limits"]) == {"gap_max", "gap_mean"}
+    assert {m["name"] for m in spec["end_to_end"]} == {
+        "itl_p95_ms", "out_tokens_per_s", "setup_s"}
+    reported = {m["name"] for m in spec["per_layer"]}
+    assert reported == {
+        "decode_occupancy", "kv_used_share_peak", "preemptions",
+        "decode_step_ms", "mixed_step_ms.reasoning",
+        "paged_attn_device_share.reasoning", "decode_step_roofline",
+        "host_turn_ms.reasoning", "host_dispatch_ms", "host_observe_ms",
+        "collective_device_share", "ici_bytes_per_step"}
+    for name in reported:
+        mod = core.reader_module("layer_metrics", name)
+        assert callable(__import__(mod, fromlist=["read"]).read)
+    # 8.19B parameters, 16.4 GB in bfloat16: more than a chip with a cache;
+    # a chip's share of a decode step over 32 rows of 1,120 tokens
+    params = family.layer_matmul_params(sizes) + 2 * family.head_params(sizes)
+    assert 8.18e9 < params < 8.20e9
+    assert family.kv_bytes_per_token(sizes) == 147_456
+    step = family.decode_step_min_bytes(sizes, [35_889])
+    assert step / 4 == pytest.approx(
+        (6.95e9 + 0.622e9) * 2 / 4 + 35_889 * 147_456 / 4, rel=2e-3)
+
+
+def test_the_collective_readers_know_the_kernels_by_name():
+    """``collective_device_share`` on a small recorded plane with names as
+    the chip's trace has them (the fused kernels with their tuple results,
+    the tail, XLA's own all-gather, a named kernel that is no collective and
+    a fusion): the named collectives and XLA's, nothing else; silent on a
+    program without the names (the parent: only XLA's all-gather matches)
+    and on an untraced run. ``ici_bytes_per_step`` reads the attribute of
+    the program's ``engine.dispatch`` spans and is silent without it."""
+    import types
+
+    from perfbench import xplane
+    from perfbench.layer_metrics import (
+        collective_device_share,
+        ici_bytes_per_step,
+    )
+
+    ms = 1e6
+    named = [
+        ('%ag_gemm.3 = (bf16[64,1536]{1,0}, bf16[4,16,4096]{2,1,0}) '
+         'custom-call(s32[1]{0} %a, bf16[16,4096]{1,0} %b), '
+         'custom_call_target="tpu_custom_call"', 2.0),
+        ('%ag_gemm_tail.4 = bf16[64,1536]{1,0} custom-call(bf16[64,128] %c)'
+         ', custom_call_target="tpu_custom_call"', 1.0),
+        ('%gemm_rs.5 = (bf16[16,4096]{1,0}, bf16[3,16,4096]{2,1,0}) '
+         'custom-call(s32[1]{0} %a), custom_call_target="tpu_custom_call"',
+         3.0),
+        ('%all-gather.7 = bf16[480,4096]{1,0} all-gather(bf16[120,4096] %h)',
+         0.5),
+    ]
+    others = [
+        ('%paged_attention.8 = f32[32,1,8,128]{3,2,1,0} custom-call('
+         's32[32,256]{1,0} %t), custom_call_target="tpu_custom_call"', 2.5),
+        ('%matmul_single_chip.2 = bf16[64,64]{1,0} custom-call(bf16[64,64] '
+         '%a), custom_call_target="tpu_custom_call"', 0.5),
+        ('%fusion.9 = bf16[64,12288]{1,0} fusion(bf16[64,12288] %x)', 0.5),
+    ]
+
+    def plane(i, events):
+        at, ops = 0.0, []
+        for name, length in events:
+            ops.append((name, at * ms, length * ms))
+            at += length
+        return {"name": f"/device:TPU:{i}", "lines": [
+            {"name": "XLA Ops", "events": ops},
+            {"name": "XLA Modules", "events": []}]}
+
+    def record(events):
+        trace = xplane.reduce_profile(
+            {"planes": [plane(i, events) for i in range(4)]})
+        return types.SimpleNamespace(trace=trace)
+
+    rec = record(named + others)
+    assert rec.trace["busy_s"] == pytest.approx(10e-3)
+    assert collective_device_share.read(rec) == pytest.approx(65.0)
+    rows = [name for name, _ in rec.trace["breakdown"]["device_ops"]]
+    # (``xplane.short_name`` does not shorten a call with a tuple result:
+    # the row is the first 120 characters of the name, which start with it)
+    assert rows[0].startswith("%gemm_rs.5 = (bf16[16,4096]")
+    assert any(r.startswith("%ag_gemm.3 = ") for r in rows)
+    assert any(r.startswith("ag_gemm_tail.4 bf16[64,1536]") for r in rows)
+    # the parent: the same calls without a name
+    old = record([(re.sub(r"^%[a-z_]+\.", "%custom-call.", n), s)
+                  for n, s in named[:3]] + named[3:] + others)
+    assert collective_device_share.read(old) is None
+    assert collective_device_share.read(
+        types.SimpleNamespace(trace=None)) is None
+
+    def span(name, **attrs):
+        return types.SimpleNamespace(name=name, attrs=attrs or None)
+
+    def spans(records):
+        return types.SimpleNamespace(trace={
+            "host_window": (0.0, 1.0), "program_spans": records})
+
+    sent = spans([span("engine.dispatch", kind="decode", ici_bytes=56_819_712),
+                  span("decode_step", ici_bytes=1),
+                  span("engine.dispatch", kind="mixed",
+                       ici_bytes=455_933_952),
+                  span("engine.observe")])
+    assert ici_bytes_per_step.read(sent) == pytest.approx(256.376832)
+    assert ici_bytes_per_step.read(
+        spans([span("engine.dispatch", kind="decode")])) is None
+    assert ici_bytes_per_step.read(types.SimpleNamespace(trace=None)) is None

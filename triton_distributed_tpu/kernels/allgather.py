@@ -157,8 +157,8 @@ def _a2a_ag_kernel(x_ref, o_ref, send_sems, recv_sems, copy_sem, *, axis: str,
 # ---------------------------------------------------------------------------
 
 
-def _ag_call(kernel, x_local, *, axis: str, interpret, collective_id: int,
-             probes: bool = False):
+def _ag_call(kernel, x_local, *, name: str, axis: str, interpret,
+             collective_id: int, probes: bool = False):
     world = _axis_size(axis)
     if world == 1:
         return (x_local, _probes.host_stub_buffer()) if probes else x_local
@@ -189,6 +189,7 @@ def _ag_call(kernel, x_local, *, axis: str, interpret, collective_id: int,
         out_specs=out_specs,
         scratch_shapes=scratch,
         collective_id=collective_id,
+        name=name,
         interpret=interpret,
     )(x_local)
 
@@ -199,7 +200,8 @@ def ring_all_gather(x_local, *, axis: str = "tp", interpret=None,
     → ``(world*m, ...)``, segment ``r`` holding rank ``r``'s shard.
     ``probes=True`` builds the instrumented variant and returns
     ``(out, probe_buf)`` (see kernels/probes.py)."""
-    return _ag_call(_ring_ag_kernel, x_local, axis=axis, interpret=interpret,
+    return _ag_call(_ring_ag_kernel, x_local, name="allgather_ring",
+                    axis=axis, interpret=interpret,
                     collective_id=common.collective_id_for("ag_ring"),
                     probes=probes)
 
@@ -208,7 +210,8 @@ def a2a_all_gather(x_local, *, axis: str = "tp", interpret=None,
                    probes: bool = False):
     """Latency-optimal direct-push allgather (see module docstring);
     ``probes=True`` → ``(out, probe_buf)``."""
-    return _ag_call(_a2a_ag_kernel, x_local, axis=axis, interpret=interpret,
+    return _ag_call(_a2a_ag_kernel, x_local, name="allgather_push",
+                    axis=axis, interpret=interpret,
                     collective_id=common.collective_id_for("ag_a2a"),
                     probes=probes)
 

@@ -297,6 +297,7 @@ def matmul_tail_into(c, a, b, col_start: int, *, block_n: int,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="ag_gemm_tail",
         interpret=resolve_interpret(interpret),
     )(c, a, b)
 
@@ -409,6 +410,16 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
         out = (res[0] if probes else res).reshape(world, m_pad, n_local)
         out = out[:, :m].reshape(world * m, n_local)
         return (out, res[1]) if probes else out
+    from triton_distributed_tpu.runtime import perf_model as pm
+
+    # The gather of A is the op's only traffic (the rows as they travel:
+    # after the Mosaic row pad above). A series of its own beside the host
+    # wrapper's "overlap", which times the same traffic when it is the
+    # caller.
+    _ledger.record_traced(
+        "ag_gemm", axis=axis, world=world, method="device",
+        nbytes=pm.wire_bytes_all_gather(
+            m * k * a_local.dtype.itemsize, world))
     out_dtype = jnp.promote_types(a_local.dtype, b_local.dtype)
     config, bn_tail = _split_blocks(config, m, k, n_local,
                                     a_local.dtype.itemsize,
@@ -483,6 +494,7 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
                             + k * cols * b_local.dtype.itemsize
                             + world * m * cols * out_dtype.itemsize),
             remote_bytes=(world - 1) * m * k * a_local.dtype.itemsize),
+        name="ag_gemm",
         interpret=resolve_interpret(interpret),
     )(me, a_local, b_local)
     out1, a_full = outs[0], outs[1]
@@ -600,6 +612,7 @@ def ag_gemm_loopback(a, b, *, segments: int = 8,
             has_side_effects=True,
             vmem_limit_bytes=_overlap_vlim(
                 m, k, bn, a.dtype.itemsize, out_dtype.itemsize)),
+        name="ag_gemm_loopback",
         interpret=resolve_interpret(interpret),
     )(a, b)
     if cols == n:
@@ -683,6 +696,7 @@ def ag_gemm_segmented_bare(a, b, *, segments: int = 8,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_overlap_vlim(
                 m, k, bn, a.dtype.itemsize, out_dtype.itemsize)),
+        name="ag_gemm_segmented_bare",
         interpret=resolve_interpret(interpret),
     )(a, b)
     if cols == n:
@@ -879,6 +893,7 @@ def ag_gemm_single_chip(a, b, *, block_m: int | None = None,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vlim,
         ),
+        name="matmul_single_chip",
         interpret=resolve_interpret(interpret),
     )(a, b)
 
@@ -961,6 +976,7 @@ def fused_matmul_step(c, a, b, s=None, *, block_m: int = 512,
         input_output_aliases={1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics, vmem_limit_bytes=vlim),
+        name="fused_matmul_step",
         interpret=resolve_interpret(interpret),
     )(s, c, a, b)
 

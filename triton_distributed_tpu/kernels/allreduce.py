@@ -116,6 +116,15 @@ def oneshot_all_reduce(x_local, *, axis: str = "tp", interpret=None,
         return (x_local, _probes.host_stub_buffer()) if probes else x_local
     shape = x_local.shape
     rest = shape[1:]
+    from triton_distributed_tpu.runtime import perf_model as pm
+
+    # A series of its own beside the host wrapper's "one_shot", which
+    # times the same traffic when it is the caller.
+    _ledger.record_traced(
+        "all_reduce", axis=axis, world=world, method="one_shot_device",
+        nbytes=pm.wire_bytes_all_reduce(
+            x_local.size * x_local.dtype.itemsize, world,
+            AllReduceMethod.ONE_SHOT.value))
     br = common.stage_row_tile(shape[0], rest, x_local.dtype.itemsize)
     body = functools.partial(_oneshot_ar_kernel, axis=axis, world=world,
                              br=br)
@@ -150,6 +159,7 @@ def oneshot_all_reduce(x_local, *, axis: str = "tp", interpret=None,
         out_specs=out_specs,
         scratch_shapes=scratch,
         collective_id=common.collective_id_for("ar_oneshot"),
+        name="allreduce_one_shot",
         interpret=interpret,
     )(x_local)
     return (outs[0], outs[2]) if probes else outs[0]
@@ -197,6 +207,7 @@ def oneshot_ar_loopback(x, *, world: int = 8, interpret=None):
             pltpu.VMEM((br, *rest), x.dtype),
         ],
         collective_id=None,
+        name="allreduce_one_shot_loopback",
         interpret=interpret,
     )(x)[0]
 
@@ -285,6 +296,7 @@ def twoshot_all_reduce(x_local, *, axis: str = "tp", interpret=None):
             pltpu.VMEM((br, *rest), x_local.dtype),
         ],
         collective_id=common.collective_id_for("ar_twoshot"),
+        name="allreduce_two_shot",
         interpret=interpret,
     )(x_local)[0]
 

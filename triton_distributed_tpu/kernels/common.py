@@ -313,11 +313,14 @@ def reduce_rows_tiled(x_ref, x_off, staging, stage_idx, dst_ref, dst_off, *,
                    probe=probe)
 
 
-def make_pallas_call(kernel, *, out_shape, in_specs, out_specs, scratch_shapes,
-                     collective_id, interpret=None, grid=None, grid_spec=None):
+def make_pallas_call(kernel, *, name, out_shape, in_specs, out_specs,
+                     scratch_shapes, collective_id, interpret=None, grid=None,
+                     grid_spec=None):
     """Uniform ``pl.pallas_call`` wrapper: ANY-space refs by default,
     side-effectful, interpret-resolved (compiled on real TPU, interpreted with
-    faithful remote-DMA simulation elsewhere — see runtime/platform.py)."""
+    faithful remote-DMA simulation elsewhere — see runtime/platform.py).
+    ``name`` is the kernel's name in the lowered program and so in a device
+    trace."""
     kwargs = {}
     if grid is not None:
         kwargs["grid"] = grid
@@ -331,6 +334,7 @@ def make_pallas_call(kernel, *, out_shape, in_specs, out_specs, scratch_shapes,
         scratch_shapes=scratch_shapes,
         compiler_params=compiler_params(collective_id),
         interpret=resolve_interpret(interpret),
+        name=name,
         **kwargs,
     )
 

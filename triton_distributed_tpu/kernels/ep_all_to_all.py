@@ -213,7 +213,7 @@ def fast_all_to_all(payloads, send_counts, *, ctx: AllToAllContext,
         if pay.shape[0] != world or pay.shape[1] != ctx.capacity:
             raise ValueError(f"payload {pay.shape} != (world={world}, "
                              f"capacity={ctx.capacity}, ...)")
-    if _ledger.enabled():
+    if _ledger.recording():
         # Device-level entry: fires at trace time (counts compilations).
         # Bytes are the capacity-shaped upper bound — occupancy-predicated
         # chunk sends move less at runtime; the static bound is what the
@@ -279,6 +279,7 @@ def fast_all_to_all(payloads, send_counts, *, ctx: AllToAllContext,
         grid_spec=grid_spec,
         compiler_params=common.compiler_params(
             common.collective_id_for(f"ep_a2a_{direction}")),
+        name=f"ep_all_to_all_{direction}",
         interpret=resolve_interpret(interpret),
     )(send_counts, *payloads, counts_block)
     if probes:
@@ -422,6 +423,7 @@ def a2a_loopback(payloads, send_counts, *, ctx: AllToAllContext,
         ),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        name="ep_all_to_all_loopback",
         interpret=resolve_interpret(interpret),
     )(send_counts, *payloads, counts_block)
     *out, rcounts_block = result

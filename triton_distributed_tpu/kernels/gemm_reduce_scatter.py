@@ -203,6 +203,15 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
             config=config, interpret=interpret, probes=probes)
         return (res[0][:m], res[1]) if probes else res[:m]
     out_dtype = jnp.promote_types(a_local.dtype, b_local.dtype)
+    from triton_distributed_tpu.runtime import perf_model as pm
+
+    # Each device scatters its whole (M, n) partial product (M as it
+    # travels: after the Mosaic row pad above). A series of its own beside
+    # the host wrapper's "overlap" (see ag_gemm_device).
+    _ledger.record_traced(
+        "gemm_rs", axis=axis, world=world, method="device",
+        nbytes=pm.wire_bytes_reduce_scatter(
+            M * n * out_dtype.itemsize, world))
     config = config.resolve(m, k_local, n, a_local.dtype.itemsize,
                             out_dtype.itemsize)
     n_tiles = config.n_tiles(n)
@@ -272,6 +281,7 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
                             + world * k_local * n * b_local.dtype.itemsize
                             + M * n * out_dtype.itemsize),
             remote_bytes=(world - 1) * m * n * out_dtype.itemsize),
+        name="gemm_rs",
         interpret=resolve_interpret(interpret),
     )(me, a_local, b_local)
     return (outs[0], outs[2]) if probes else outs[0]
@@ -403,6 +413,7 @@ def gemm_rs_loopback(a, b, *, segments: int = 8,
             pltpu.SemaphoreType.DMA(()),
         ],
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        name="gemm_rs_loopback",
         interpret=resolve_interpret(interpret),
     )(a, b)
     return out

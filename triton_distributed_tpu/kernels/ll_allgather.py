@@ -131,6 +131,7 @@ def ll_all_gather_device(x_local, staging, epoch, *, axis: str = "tp",
         # No barrier semaphore is ever touched (that is the LL protocol's
         # point), so no collective_id (Mosaic rejects an unused one).
         compiler_params=common.compiler_params(None),
+        name="ll_allgather",
         interpret=resolve_interpret(interpret),
     )(p, x_local, staging)
     return out, staging

@@ -181,7 +181,7 @@ def ag_group_gemm_device(x_local, topk_ids_local, w_up_local, *,
             return up, state, _probes.host_stub_buffer()
         return up, state
 
-    if _ledger.enabled():
+    if _ledger.recording():
         from triton_distributed_tpu.runtime import perf_model as pm
 
         _ledger.record_traced(
@@ -252,6 +252,7 @@ def ag_group_gemm_device(x_local, topk_ids_local, w_up_local, *,
                             * out_dtype.itemsize),
             remote_bytes=(world - 1) * E * capacity * d
             * x_local.dtype.itemsize),
+        name="ag_group_gemm",
         interpret=resolve_interpret(interpret),
     )(me, grid_x, w_up_local)
     if probes:
@@ -368,7 +369,7 @@ def group_gemm_rs_device(act, w_down_local, *, capacity: int,
         return jnp.einsum("ecf,efd->ecd", act, w_down_local,
                           preferred_element_type=jnp.float32).astype(out_dtype)
 
-    if _ledger.enabled():
+    if _ledger.recording():
         from triton_distributed_tpu.runtime import perf_model as pm
 
         # Each device scatters its (E, world*cap, d) partial down-product.
@@ -423,6 +424,7 @@ def group_gemm_rs_device(act, w_down_local, *, capacity: int,
                             * out_dtype.itemsize),
             remote_bytes=(world - 1) * E * capacity * d
             * out_dtype.itemsize),
+        name="group_gemm_rs",
         interpret=resolve_interpret(interpret),
     )(me, act, w_down_local)
     return out

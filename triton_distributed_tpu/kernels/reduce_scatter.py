@@ -133,8 +133,8 @@ def _ring_rs_kernel(x_ref, o_ref, staging, send_hbm, send_sems, recv_sems,
 # ---------------------------------------------------------------------------
 
 
-def _rs_call(kernel, x_local, *, axis: str, interpret, collective_id: int,
-             n_staging_key: str, probes: bool = False):
+def _rs_call(kernel, x_local, *, name: str, axis: str, interpret,
+             collective_id: int, n_staging_key: str, probes: bool = False):
     world = _axis_size(axis)
     if world == 1:
         return (x_local, _probes.host_stub_buffer()) if probes else x_local
@@ -185,6 +185,7 @@ def _rs_call(kernel, x_local, *, axis: str, interpret, collective_id: int,
         out_specs=out_specs,
         scratch_shapes=scratch,
         collective_id=collective_id,
+        name=name,
         interpret=interpret,
     )(x_local)
     return (outs[0], outs[-1]) if probes else outs[0]
@@ -195,7 +196,9 @@ def oneshot_reduce_scatter(x_local, *, axis: str = "tp", interpret=None,
     """Scatter+local-reduce RS of ``x_local (world*m, ...)`` → ``(m, ...)``:
     returns sum over ranks of segment ``me``. ``probes=True`` builds the
     instrumented variant and returns ``(out, probe_buf)``."""
-    return _rs_call(_oneshot_rs_kernel, x_local, axis=axis, interpret=interpret,
+    return _rs_call(_oneshot_rs_kernel, x_local,
+                    name="reduce_scatter_one_shot", axis=axis,
+                    interpret=interpret,
                     collective_id=common.collective_id_for("rs_oneshot"),
                     n_staging_key="oneshot", probes=probes)
 
@@ -204,7 +207,8 @@ def ring_reduce_scatter(x_local, *, axis: str = "tp", interpret=None,
                         probes: bool = False):
     """Bandwidth-optimal ring RS (see module docstring); ``probes=True`` →
     ``(out, probe_buf)``."""
-    return _rs_call(_ring_rs_kernel, x_local, axis=axis, interpret=interpret,
+    return _rs_call(_ring_rs_kernel, x_local, name="reduce_scatter_ring",
+                    axis=axis, interpret=interpret,
                     collective_id=common.collective_id_for("rs_ring"),
                     n_staging_key="ring", probes=probes)
 

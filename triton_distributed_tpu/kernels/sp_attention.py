@@ -168,7 +168,7 @@ def sp_ag_attention_device(q_local, k_local, v_local, *, axis: str = "sp",
                                    scale=scale)
     m_kv = k_local.shape[1]
 
-    if world > 1 and _ledger.enabled():
+    if world > 1 and _ledger.recording():
         from triton_distributed_tpu.runtime import perf_model as pm
 
         shard = k_local.nbytes + v_local.nbytes  # the KV gather is the comm
@@ -230,6 +230,7 @@ def sp_ag_attention_device(q_local, k_local, v_local, *, axis: str = "sp",
                             + H * m * dh * q_local.dtype.itemsize),
             remote_bytes=2 * (world - 1) * H * m_kv * dh
             * k_local.dtype.itemsize),
+        name="sp_ag_attention",
         interpret=resolve_interpret(interpret),
     )(scalars, q_local, k_local, v_local)
     if return_partials:
@@ -497,6 +498,7 @@ def flash_prefill(q, k_cache, v_cache, *, offset=None, kv_len=None,
             bytes_accessed=(2 * B * Hq * L * dh * q.dtype.itemsize
                             + 2 * B * Hkv * S * dh
                             * k_cache.dtype.itemsize)),
+        name="flash_prefill",
         interpret=resolve_interpret(interpret),
     )(scalars, q_r, k_cache, v_cache)
     return out.reshape(B, Hkv, L, g, dh).transpose(0, 2, 1, 3, 4
@@ -748,6 +750,7 @@ def flash_decode_local(q, k_cache, v_cache, *, kv_len=None,
                                 + 2 * B * Hkv * m_kv * dh
                                 * k_cache.dtype.itemsize
                                 + B * Hq * (dh + 1) * 4)),
+            name="flash_decode_block_diag",
             interpret=resolve_interpret(interpret),
         )(kv_len, q_bd, k_cache, v_cache)
         return out.reshape(B, Hq, dh), lse.reshape(B, Hq)
@@ -787,6 +790,7 @@ def flash_decode_local(q, k_cache, v_cache, *, kv_len=None,
                             + 2 * B * Hkv * m_kv * dh
                             * k_cache.dtype.itemsize
                             + B * Hq * (dh + 1) * 4)),
+        name="flash_decode",
         interpret=resolve_interpret(interpret),
     )(kv_len, qg, k_cache, v_cache)
     return out.reshape(B, Hq, dh), lse.reshape(B, Hq)
@@ -897,7 +901,7 @@ def flash_decode_device(q, k_cache_local, v_cache_local, *, axis: str = "sp",
             f"make_ll_staging((B*H, decode_partial_feat(dh)), ...) — the "
             f"packed (out, lse) rows are lane-padded")
     packed = _pack_decode_partial(out_local, lse_local, dh)
-    if _ledger.enabled():
+    if _ledger.recording():
         from triton_distributed_tpu.runtime import perf_model as pm
 
         _ledger.record_traced(

@@ -37,6 +37,8 @@ from triton_distributed_tpu.layers import nn
 from triton_distributed_tpu.layers.tp_attn import TPAttn
 from triton_distributed_tpu.layers.tp_mlp import TPMLP
 from triton_distributed_tpu.models.config import ModelConfig
+from triton_distributed_tpu.obs import comm_ledger as _ledger
+from triton_distributed_tpu.runtime import perf_model as _pm
 from triton_distributed_tpu.runtime.mesh import get_default_mesh
 
 
@@ -372,6 +374,17 @@ class Qwen3:
             m = mlp.ar_fwd(lp["mlp"], flat, interpret=interpret)
         return resid + m.reshape(hn.shape), cache, stats
 
+    def _gather_rows(self, x):
+        """Every device's rows of ``x`` in rank order (XLA's own
+        all-gather), entered in the comm ledger like the kernels'."""
+        world = _axis_size(self.axis)
+        if world > 1:
+            _ledger.record_traced(
+                "all_gather", axis=self.axis, world=world, method="xla",
+                nbytes=_pm.wire_bytes_all_gather(
+                    x.size * x.dtype.itemsize, world))
+        return jax.lax.all_gather(x, self.axis, axis=0, tiled=True)
+
     def _head(self, params, h, rows, *, last=None, greedy_of=None):
         """Final norm and LM head: ``(logits (B, vocab) fp32 replicated,
         greedy)``. h (rows, L, d): row b's logits come from its last
@@ -395,20 +408,18 @@ class Qwen3:
                                  preferred_element_type=jnp.float32)
             greedy = jnp.argmax(all_logits, axis=-1).astype(jnp.int32)
             if rows is not None:
-                greedy = jax.lax.all_gather(greedy, self.axis, axis=0,
-                                            tiled=True)
+                greedy = self._gather_rows(greedy)
             greedy = greedy[greedy_of.start:greedy_of.stop].reshape(
                 -1, greedy_of.L)
         if last is None:
             last = h[:, -1]                                    # (*, d)
             if rows is not None:
-                last = jax.lax.all_gather(last, self.axis, axis=0,
-                                          tiled=True)
+                last = self._gather_rows(last)
         else:
             # The positions wanted lie anywhere in the flat batch: gather
             # it whole (T rows, once a step), then take.
             if rows is not None:
-                h = jax.lax.all_gather(h, self.axis, axis=0, tiled=True)
+                h = self._gather_rows(h)
             last = jnp.take(h, last, axis=0)
         # bf16 operands, fp32 accumulation — no materialized fp32 weight copy
         logits = jnp.dot(last, lm_head, preferred_element_type=jnp.float32)
@@ -448,9 +459,10 @@ class Qwen3:
                 moe_heavy=moe_heavy, return_moe_stats=return_moe_stats)
             return h, (kc, vc) + ((stats,) if return_moe_stats else ())
 
-        h, ys = jax.lax.scan(
-            body, h, (scan_layers, k_cache, v_cache,
-                      jnp.arange(c.n_layers, dtype=jnp.int32)))
+        with _ledger.repeated(c.n_layers):
+            h, ys = jax.lax.scan(
+                body, h, (scan_layers, k_cache, v_cache,
+                          jnp.arange(c.n_layers, dtype=jnp.int32)))
         moe_stats = (jax.tree.map(
             lambda x: jax.lax.psum(jnp.sum(x), self.axis), ys[2]),
         ) if return_moe_stats else ()
@@ -527,9 +539,10 @@ class Qwen3:
                 layer=li)
             return (h, state), None
 
-        (h, state), _ = jax.lax.scan(
-            body, (h, state),
-            (scan_layers, jnp.arange(c.n_layers, dtype=jnp.int32)))
+        with _ledger.repeated(c.n_layers):
+            (h, state), _ = jax.lax.scan(
+                body, (h, state),
+                (scan_layers, jnp.arange(c.n_layers, dtype=jnp.int32)))
         logits, greedy = self._head(
             params, h, rows, last=last,
             greedy_of=blocks[-1] if spec_verify else None)

@@ -18,10 +18,16 @@ Two recording paths, because kernels run in two regimes:
   one attribute check.
 - ``record_traced(...)`` marks a DEVICE-level entry point (``*_device``
   functions composed inside ``shard_map``/``jit``): it fires at TRACE
-  time, so its count is compilations, not executions — still exactly what
-  "is this kernel in the compiled program, and how many bytes does each
-  execution move" needs. Records are flagged ``traced`` so the two kinds
-  never mix.
+  time, so it counts what ONE execution of the traced program does, once
+  for each time the program is traced — exactly what "is this kernel in
+  the compiled program, and how many bytes does each execution move"
+  needs. A call site in the body of a loop is traced once and runs every
+  trip: the loop's owner says so with ``repeated(trips)`` and the record
+  counts ``trips`` calls and their bytes. Records are flagged ``traced``
+  so the two kinds never mix. ``gathering()`` hands the traced records of
+  a block to its caller whether or not the ledger is enabled: how a
+  compiled serving step keeps the count of its own collectives
+  (``BatchEngine.stats_snapshot()["collectives"]``).
 
 The ledger is process-global (like the tracer): collectives are called
 from layers, engines, and benches that share no object graph.
@@ -76,6 +82,29 @@ class LedgerEntry:
                 / (self.est_s_total / max(self.calls + self.traced_calls, 1)),
                 4)
         return d
+
+
+@dataclasses.dataclass(frozen=True)
+class TracedRecord:
+    """One device-level call site, as ``gathering()`` hands it out."""
+
+    collective: str
+    method: str
+    axis: str
+    world: int
+    calls: int      # executions of the site in one execution of the program
+    nbytes: float   # wire bytes a device sends in those
+
+
+class _TraceScope(threading.local):
+    """What the traced records of this thread are multiplied by and who,
+    beside the ledger, is handed them."""
+
+    sinks: tuple = ()
+    repeat: int = 1
+
+
+_SCOPE = _TraceScope()
 
 
 class CommLedger:
@@ -137,7 +166,9 @@ class CommLedger:
 
     def record(self, collective: str, *, axis: str, world: int,
                nbytes: float, method: str = "", est_s: float | None = None,
-               wall_s: float | None = None, traced: bool = False) -> None:
+               wall_s: float | None = None, traced: bool = False,
+               count: int = 1) -> None:
+        """``count`` calls of ``nbytes`` (and ``est_s``) each."""
         if not self.enabled:
             return
         key = (collective, method, axis, world)
@@ -148,12 +179,12 @@ class CommLedger:
                     collective=collective, method=method, axis=axis,
                     world=world)
             if traced:
-                e.traced_calls += 1
+                e.traced_calls += count
             else:
-                e.calls += 1
-            e.bytes_total += float(nbytes)
+                e.calls += count
+            e.bytes_total += float(nbytes) * count
             if est_s is not None:
-                e.est_s_total += float(est_s)
+                e.est_s_total += float(est_s) * count
             if wall_s is not None:
                 e.wall_s_total += float(wall_s)
                 e.wall_samples += 1
@@ -162,9 +193,14 @@ class CommLedger:
                       nbytes: float, method: str = "",
                       est_s: float | None = None) -> None:
         """Trace-time record for device-level entry points (see module
-        docstring: counts compilations, not executions)."""
+        docstring): one call, or the enclosing ``repeated`` trips of it,
+        to the ledger and to every open ``gathering()``."""
+        count = _SCOPE.repeat
+        for sink in _SCOPE.sinks:
+            sink.append(TracedRecord(collective, method, axis, world, count,
+                                     float(nbytes) * count))
         self.record(collective, axis=axis, world=world, nbytes=nbytes,
-                    method=method, est_s=est_s, traced=True)
+                    method=method, est_s=est_s, traced=True, count=count)
 
     def timed(self, fn, collective: str, *, axis: str, world: int,
               nbytes: float, method: str = "",
@@ -230,6 +266,38 @@ def active() -> bool:
     the ledger records OR a resilience hook needs to observe the call."""
     return (_LEDGER.enabled or _PRE_CALL_HOOK is not None
             or _DEADLINE_HOOK is not None)
+
+
+def recording() -> bool:
+    """Has a traced record a taker (the ledger or an open ``gathering``)?
+    Device-level entry points whose byte count costs something to work
+    out ask before they do."""
+    return _LEDGER.enabled or bool(_SCOPE.sinks)
+
+
+@contextlib.contextmanager
+def gathering():
+    """Yields a list that receives a ``TracedRecord`` for every traced
+    record made inside the block on this thread, ledger enabled or not."""
+    records: list[TracedRecord] = []
+    prior = _SCOPE.sinks
+    _SCOPE.sinks = prior + (records,)
+    try:
+        yield records
+    finally:
+        _SCOPE.sinks = prior
+
+
+@contextlib.contextmanager
+def repeated(trips: int):
+    """The block traces the body of a loop of ``trips`` trips: a traced
+    record made inside it stands for that many calls."""
+    prior = _SCOPE.repeat
+    _SCOPE.repeat = prior * int(trips)
+    try:
+        yield
+    finally:
+        _SCOPE.repeat = prior
 
 
 def enable() -> None:

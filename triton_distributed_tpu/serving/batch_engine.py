@@ -67,6 +67,7 @@ attribute check per site and emits bit-identical tokens.
 from __future__ import annotations
 
 import contextlib
+import copy
 import dataclasses
 import functools
 import json
@@ -457,6 +458,11 @@ class BatchEngine:
                              if prefix_cache and self.pool.prefix_cacheable
                              else None)
         self.trace_counts = {"decode": 0, "prefill": 0}
+        # What the collectives of one execution of each compiled step
+        # move, written when the step is traced (``_build_steps``).
+        self.collectives = {kind: {"collective_calls": 0, "ici_bytes": 0,
+                                   "by_collective": {}}
+                            for kind in ("decode", "prefill")}
         # Fault sites ("engine.decode"/"engine.prefill") whose jitted step
         # has returned at least once; until then a failure of the call is a
         # StepBuildError (see ``_call_step``).
@@ -513,6 +519,31 @@ class BatchEngine:
                               spec_verify=self.spec is not None, **kw)
         temperature, top_p = eng.temperature, eng.top_p
         trace_counts = self.trace_counts
+        collectives = self.collectives
+
+        def counted(kind, sm, *operands):
+            """``sm(*operands)``, with the comm ledger's trace-time
+            records of it (``perf_model``'s wire bytes, from the
+            device-level entry points of the kernels) summed by collective
+            and kept as step ``kind``'s: a side effect of the trace, like
+            ``trace_counts``. Calls an execution of the step, and bytes a
+            chip sends in them; a mesh of one records none."""
+            with _comm.gathering() as records:
+                out = sm(*operands)
+            by_collective: dict = {}
+            for r in records:
+                if r.world > 1:
+                    c = by_collective.setdefault(
+                        r.collective, {"collective_calls": 0, "ici_bytes": 0})
+                    c["collective_calls"] += r.calls
+                    c["ici_bytes"] += int(r.nbytes)
+            collectives[kind] = {
+                "collective_calls": sum(c["collective_calls"]
+                                        for c in by_collective.values()),
+                "ici_bytes": sum(c["ici_bytes"]
+                                 for c in by_collective.values()),
+                "by_collective": by_collective}
+            return out
 
         # Both steps take the pool's state (``KVPool.state``, whatever its
         # format) as their ONE donated operand and return the fixed record
@@ -559,8 +590,9 @@ class BatchEngine:
             # one-compile-across-churn guarantee the tests assert on.
             trace_counts["decode"] += 1
             ids = jnp.clip(feed(tok, fed), 0, V - 1)[:, None]
-            logits, aux, state = sm_dec(params, ids, state, offsets,
-                                        block_tables, slot_mask)
+            logits, aux, state = counted("decode", sm_dec, params, ids,
+                                         state, offsets, block_tables,
+                                         slot_mask)
             return *sample(logits, aux, corrupt, key), state
 
         @functools.partial(jax.jit, donate_argnums=(2,))
@@ -574,8 +606,9 @@ class BatchEngine:
             tok, chunk, dealt = ids
             ids = (jnp.clip(feed(tok, fed), 0, V - 1),
                    jnp.clip(chunk, 0, V - 1), dealt)
-            logits, aux, state = sm_pre(params, ids, state, offsets,
-                                        block_tables, slot_mask, seq_lens)
+            logits, aux, state = counted("prefill", sm_pre, params, ids,
+                                         state, offsets, block_tables,
+                                         slot_mask, seq_lens)
             return *sample(logits, aux, corrupt, key), state
 
         self._decode_step = decode_step
@@ -616,6 +649,7 @@ class BatchEngine:
         self._decode_step = other._decode_step
         self._mixed_step = other._mixed_step
         self.trace_counts = other.trace_counts
+        self.collectives = other.collectives
         self._steps_built = other._steps_built
 
     def _next_key(self):
@@ -874,6 +908,9 @@ class BatchEngine:
             # recorded when the shape is traced).
             "trace_counts": dict(self.trace_counts),
             "paged_arithmetic": nn.fused_paged_arithmetic(),
+            # What one execution of each step moves over the mesh, by
+            # collective (``_build_steps``): zero on a mesh of one.
+            "collectives": copy.deepcopy(self.collectives),
             # Steps dispatched while the step before was still unread,
             # and the times a step was read with nothing dispatched
             # behind it, by what made it so.
@@ -1798,6 +1835,8 @@ class BatchEngine:
                         phase.set(
                             kind=st.kind, overlapped=st.attrs["overlapped"],
                             prefill_rows=st.attrs.get("prefill_rows", 0),
+                            collective_calls=st.attrs["collective_calls"],
+                            ici_bytes=st.attrs["ici_bytes"],
                             **{name: n for name, n in st.counts.items()
                                if not name.endswith("_steps")})
                 if before is not None:
@@ -1924,11 +1963,15 @@ class BatchEngine:
         for r in rows:
             r.slot.offset += r.written
             r.slot.in_flight += r.emits
+        moved = self.collectives[
+            "decode" if span == "decode_step" else "prefill"]
         return _Step(span=span, nxt=nxt, finite=finite, greedy=greedy,
                      rows=rows, counts=counts, eff=eff,
                      verify=dict(verify), attrs={
                          **attrs, "active": len(live),
                          "decode_rows": counts["decode_rows"],
+                         "collective_calls": moved["collective_calls"],
+                         "ici_bytes": moved["ici_bytes"],
                          "overlapped": self._inflight is not None})
 
     def _run_decode(self, live) -> _Step:
