@@ -71,18 +71,27 @@ import time
 import numpy as np
 
 
-def _wall_clock_arm_attn() -> str:
-    """``paged_attn`` for the arms whose verdicts are made of wall-clock
-    seconds sized for steps of milliseconds — the SLO windows and
+def _host_arm_attn() -> str:
+    """``paged_attn`` for the arms whose contract is the HOST's: the fused
+    kernel where it is compiled, its bit-identical gather oracle where it
+    would be interpreted. Two reasons, one choice. (1) Verdicts made of
+    wall-clock seconds sized for steps of milliseconds — the SLO windows and
     detectors of ``--adaptive`` and ``--incidents``, ``--slo``'s verdicts,
-    and ``--whatif``'s cost model, calibrated from the seconds its steps
-    took: the fused kernel where it is compiled, its bit-identical gather
-    oracle where it would be interpreted. Interpreted, a kernel call costs
-    ~0.3 s (and a mixed step makes two): ``main_adaptive``'s 2 s fast
-    window never holds ``min_count`` TTFT samples and WARN cannot fire, a
-    first token waits for seconds of compilation on a busy machine, and a
-    prefilling step is priced by the interpreter, not by its work. So on a
-    CPU these arms do not cover the fused kernel; every other arm does."""
+    ``--whatif``'s cost model, calibrated from the seconds its steps took.
+    Interpreted, a kernel call costs ~0.3 s (and a mixed step makes two):
+    ``main_adaptive``'s 2 s fast window never holds ``min_count`` TTFT
+    samples and WARN cannot fire, a first token waits for seconds of
+    compilation on a busy machine, and a prefilling step is priced by the
+    interpreter, not by its work. (2) Contracts no step's attention
+    arithmetic decides — ``--chaos`` (quarantine, retries, accounting),
+    ``--replicas`` (kill, drain, requeue, ownership), ``--restore`` (journal,
+    checkpoint, zero lost), ``--spec`` (acceptance, rollback, lossless
+    against the plain engine), ``--efficiency`` (the ledger's fractions):
+    interpreted they cost minutes of tier-1 for what
+    ``tests/test_paged_attention.py`` holds once, that the two paths serve
+    the same tokens. So on a CPU two arms cover the fused kernel: the plain
+    run (Poisson churn through it) and ``--kvq`` (its dequantization of
+    adopted blocks)."""
     from triton_distributed_tpu.runtime.platform import on_tpu
 
     return "fused" if on_tpu() else "gather"
@@ -119,7 +128,7 @@ def main_fleet(duration_s: float = 30.0, *, rate_hz: float = 4.0,
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     fleet = Fleet.build(engine, n_replicas=n_replicas, n_slots=n_slots,
                         n_blocks=n_blocks, block_size=4, prefill_chunk=8,
-                        fail_threshold=2)
+                        fail_threshold=2, paged_attn=_host_arm_attn())
     plan = None
     plan_ctx = contextlib.nullcontext()
     if chaos:
@@ -246,7 +255,8 @@ def main_restore(duration_s: float = 6.0, *, rate_hz: float = 6.0,
     config = ModelConfig.from_name("tiny")
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     kw = dict(n_replicas=n_replicas, n_slots=n_slots, n_blocks=n_blocks,
-              block_size=4, prefill_chunk=8, fail_threshold=2)
+              block_size=4, prefill_chunk=8, fail_threshold=2,
+              paged_attn=_host_arm_attn())
     fleet = Fleet.build(engine, **kw)
     workdir = tempfile.mkdtemp(prefix="tdt_smoke_restore_")
     try:
@@ -364,7 +374,7 @@ def main_adaptive(*, seed: int = 0, warmup: int = 24, burst: int = 48,
     config = ModelConfig.from_name("tiny", max_length=128)
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     be = BatchEngine(engine, n_slots=4, n_blocks=96, block_size=4,
-                     prefill_chunk=8, paged_attn=_wall_clock_arm_attn())
+                     prefill_chunk=8, paged_attn=_host_arm_attn())
     rng = np.random.default_rng(seed)
     start = time.monotonic()
 
@@ -530,7 +540,8 @@ def main_spec(*, seed: int = 0, n_requests: int = 16, gen: int = 32,
 
     def run(speculative):
         be = BatchEngine(engine, n_slots=4, n_blocks=96, block_size=4,
-                         prefill_chunk=8, speculative=speculative)
+                         prefill_chunk=8, speculative=speculative,
+                         paged_attn=_host_arm_attn())
         if speculative and stats_jsonl:
             be.stream_stats(stats_jsonl, interval_s=0.5)
         for i, p in enumerate(prompts):
@@ -622,7 +633,7 @@ def main_incidents(*, seed: int = 0, warmup: int = 32,
     config = ModelConfig.from_name("tiny", max_length=128)
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     be = BatchEngine(engine, n_slots=4, n_blocks=96, block_size=4,
-                     prefill_chunk=8, paged_attn=_wall_clock_arm_attn())
+                     prefill_chunk=8, paged_attn=_host_arm_attn())
     if be.incidents is None:
         raise RuntimeError("incident engine not attached — it must be "
                            "always-on by default")
@@ -752,7 +763,7 @@ def main_whatif(*, seed: int = 0, n_requests: int = 10,
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     fleet = Fleet.build(engine, n_replicas=2, n_slots=4, n_blocks=24,
                         block_size=4, prefill_chunk=8, seed=seed,
-                        paged_attn=_wall_clock_arm_attn())
+                        paged_attn=_host_arm_attn())
     if fleet.serve_trace is None:
         raise RuntimeError("ServeTrace not attached — recording must be "
                            "always-on by default")
@@ -851,10 +862,11 @@ def main_kvq(*, seed: int = 0, kv_dtype: str = "int8", gen: int = 64,
         finished sequences donate them to the radix prefix cache.
       * WARM pass: the SAME requests again — admission must CoW-adopt
         the quantized cached blocks (nonzero ``prefix_hits``), and every
-        output must be BYTE-IDENTICAL to its cold twin over ``gen`` >= 64
-        decode steps. Per-row scales travel with their blocks, so warm
-        == cold holds exactly in the quantized domain; any scale/block
-        mispairing shows up as token divergence here.
+        output must be BYTE-IDENTICAL to its cold twin over ``gen``
+        decode steps (64 by default; the tier-1 test runs 8). Per-row
+        scales travel with their blocks, so warm == cold holds exactly in
+        the quantized domain; any scale/block mispairing shows up as token
+        divergence here.
 
     Also asserted: preemption churn actually happened (the contract is
     bit-exactness UNDER churn, not in steady state), zero retraces on
@@ -879,15 +891,18 @@ def main_kvq(*, seed: int = 0, kv_dtype: str = "int8", gen: int = 64,
     prompts = [prefix + rng.integers(0, config.vocab_size,
                                      size=4).tolist()
                for _ in range(n_req)]
-    # Peak residency per request is ceil((28 + gen + 1) / 8) = 12 blocks;
-    # 16 blocks cannot hold two of those, so the long decode phase
-    # preempts and re-admits — the churn the bit-exactness claim is about —
-    # while the other two requests wait in the queue for a slot.
+    # Peak residency per request is ceil((28 + gen + 1) / 8) blocks (12 at
+    # the default gen of 64); a pool of a third more (16) cannot hold two of
+    # those, so the decode phase preempts and re-admits — the churn the
+    # bit-exactness claim is about — while the other two requests wait in
+    # the queue for a slot. The pool follows ``gen``, so a shorter run (the
+    # tier-1 test's) still preempts.
     # Two slots and blocks of 8: where the fused kernel is interpreted
     # (any CPU run) a step costs ~0.09 s a slot, and two waves of gen
-    # steps, twice, are some 330 steps with the recompute.
-    be = BatchEngine(engine, n_slots=2, n_blocks=16, block_size=8,
-                     prefill_chunk=8, kv_dtype=kv_dtype)
+    # steps, twice, are some 330 steps with the recompute at gen 64.
+    peak = -(-(len(prompts[0]) + gen + 1) // 8)
+    be = BatchEngine(engine, n_slots=2, n_blocks=peak + peak // 3,
+                     block_size=8, prefill_chunk=8, kv_dtype=kv_dtype)
 
     def one_pass(tag):
         rids = [be.submit(p, max_new_tokens=gen, req_id=f"{tag}-{i}")
@@ -994,7 +1009,8 @@ def main(duration_s: float = 30.0, *, rate_hz: float = 4.0, n_slots: int = 4,
     # not luck.
     be = BatchEngine(engine, n_slots=n_slots, n_blocks=n_blocks,
                      block_size=4, prefill_chunk=8,
-                     paged_attn=_wall_clock_arm_attn() if slo else "fused",
+                     paged_attn=(_host_arm_attn()
+                                 if slo or chaos or efficiency else "fused"),
                      retry=RetryPolicy(retries=6, base_delay_s=0.001)
                      if chaos else None)
     slo_engine = None

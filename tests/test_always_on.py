@@ -22,6 +22,7 @@ import time
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.obs.blackbox import Blackbox
 from triton_distributed_tpu.obs.metrics import (
@@ -552,11 +553,11 @@ def test_engine_defaults_on_bit_identical_and_snapshot(setup):
     _, config, engine = setup
     prompts = _prompts(config)
 
-    # The gather path (its steps take milliseconds here, the fused kernel's
+    # The plain path (its steps take milliseconds here, the fused kernel's
     # under the interpreter 0.3 s and more on a busy machine): the snapshot
     # below must find every request inside the trailing 10 s window.
     be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
-                     paged_attn="gather")
+                     **PLAIN_PATH)
     assert be.metrics.windowed and be.blackbox is not None \
         and be.sampler is not None
     for i, p in enumerate(prompts):
@@ -575,8 +576,8 @@ def test_engine_defaults_on_bit_identical_and_snapshot(setup):
     assert {"admit", "finish", "schedule_admit"} <= kinds
 
     be_off = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
-                         paged_attn="gather", windowed_metrics=False,
-                         blackbox=False, tail_sampling=False)
+                         windowed_metrics=False, blackbox=False,
+                         tail_sampling=False, **PLAIN_PATH)
     assert be_off.blackbox is None and be_off.sampler is None
     for i, p in enumerate(prompts):
         be_off.submit(p, 5, req_id=f"r{i}")
@@ -599,7 +600,8 @@ def test_engine_stream_stats_jsonl(setup, tmp_path):
 
     _, config, engine = setup
     path = tmp_path / "stats.jsonl"
-    be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8)
+    be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
+                     **PLAIN_PATH)
     be.stream_stats(str(path), interval_s=0.0)    # emit every step
     for i, p in enumerate(_prompts(config, 4)):
         be.submit(p, 4, req_id=f"r{i}")
@@ -623,14 +625,13 @@ def test_engine_slo_fault_ladder_breach_bundle(setup):
 
     _, config, engine = setup
     prompts = _prompts(config)
-    # The gather attention path: the ladder's windows (0.4 s / 1.6 s) and
+    # The plain path: the ladder's windows (0.4 s / 1.6 s) and
     # the +100 ms fault are sized for steps of milliseconds, and the fused
     # kernel under the interpreter steps in ~0.3 s (one step's jitter then
     # fills the fast window).
     be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
-                     paged_attn="gather",
                      tail_sampling=TailSampler(head_frac=0.0, slow_s=0.05,
-                                               seed=0))
+                                               seed=0), **PLAIN_PATH)
     ri = 0
 
     def feed(n):

@@ -26,6 +26,7 @@ import zlib
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models import Engine, ModelConfig
 from triton_distributed_tpu.obs import perfdb
@@ -237,8 +238,11 @@ def test_ckpt_save_fault_keeps_previous_checkpoint(tmp_path):
 
 
 def _build_kwargs(**over):
+    # What is saved and restored is the pool, the schedule and the journal:
+    # every fleet of this file takes the plain path; no case stays on
+    # "fused".
     kw = dict(n_replicas=2, n_slots=2, n_blocks=16, block_size=4,
-              prefill_chunk=8, fail_threshold=2)
+              prefill_chunk=8, fail_threshold=2, **PLAIN_PATH)
     kw.update(over)
     return kw
 
@@ -333,12 +337,7 @@ def _kill_sweep(setup, tmp_path, stride):
     specs = _specs(config, 28, seed=3, lo=4, hi=9, glo=8, ghi=13)
     # The preemption-golden shape: slots can outgrow the pool, so decode
     # growth forces evictions — churn the sweep must survive.
-    # The gather attention path (the fused kernel's bit-identical oracle,
-    # tests/test_paged_attention.py): what is restored is the pool and the
-    # schedule, and the interpreted fused kernel makes each of the sweep's
-    # 64+-step runs ~25 s where this one takes a second.
-    kw = _build_kwargs(n_slots=3, n_blocks=8, speculative=True,
-                       paged_attn="gather")
+    kw = _build_kwargs(n_slots=3, n_blocks=8, speculative=True)
 
     golden = Fleet.build(engine, **kw)
     _submit_all(golden, specs)
@@ -507,7 +506,7 @@ def test_submit_frame_carries_arrival_stamp(setup, tmp_path):
     reconstruct the arrival process without a live fleet."""
     _, config, engine = setup
     fleet = Fleet.build(engine, n_replicas=1, n_slots=2, n_blocks=16,
-                        block_size=4, prefill_chunk=8)
+                        block_size=4, prefill_chunk=8, **PLAIN_PATH)
     path = str(tmp_path / "journal.jsonl")
     fleet.attach_journal(path)
     fleet.submit([1, 2, 3], 3, tenant="acme")

@@ -331,19 +331,16 @@ def test_latent_paged_attention_compiles(one_chip, L):
 @pytest.mark.parametrize("d,f", [(2048, 1536), (768, 2048), (2688, 1920),
                                  (1920, 2688)],
                          ids=["gate_up", "down", "relu2-up", "relu2-down"])
-def test_grouped_product_over_expert_tiles_compiles(one_chip, monkeypatch,
-                                                    tiles, rows, d, f):
+def test_grouped_product_over_expert_tiles_compiles(one_chip, tiles, rows, d,
+                                                    f):
     """The routed experts' product at the cells' sizes: tiles of rows sorted
     by expert against 16 held experts of 39 stacked layers (JoyAI's gated
     pair; Nemotron-3-Nano's ungated pair at its stored width of 1,920, whose
     f-tile is 384: 512 divides neither 1,920 nor 2,688, and without a tile
-    the wrapper would fall back to an einsum over gathered weights). The
-    wrapper asks ``on_tpu()`` before it hands Mosaic a kernel; the test
-    answers for the described chip."""
+    the wrapper would fall back to an einsum over gathered weights).
+    ``interpret=False`` hands Mosaic the kernel from a CPU process
+    (``platform.plain_off_tpu``)."""
     from triton_distributed_tpu.kernels import moe_utils
-    from triton_distributed_tpu.runtime import platform
-
-    monkeypatch.setattr(platform, "on_tpu", lambda: True)
 
     def fn(x, w, live, group_of, layer):
         return moe_utils.grouped_gemm_skip(
@@ -449,8 +446,7 @@ def test_hybrid_step_compiles_with_its_state_in_place(topo, kind):
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
-def test_nemotron_step_compiles_with_its_state_in_place(topo, monkeypatch,
-                                                        kind):
+def test_nemotron_step_compiles_with_its_state_in_place(topo, kind):
     """The whole served step of nemotron-3-nano-30b-a3b-ep8 (all 52 layers,
     every width, 16 of 128 experts held), as ``BatchEngine`` builds it
     around ``forward_paged``: it compiles, every arena of the pool's state
@@ -461,14 +457,11 @@ def test_nemotron_step_compiles_with_its_state_in_place(topo, monkeypatch,
     from triton_distributed_tpu.models.config import NemotronHConfig
     from triton_distributed_tpu.models.engine import Engine
     from triton_distributed_tpu.models.nemotron_h import NemotronH
-    from triton_distributed_tpu.runtime import platform
     from triton_distributed_tpu.serving.kv_pool import (
         paged_state_shapes,
         paged_state_specs,
     )
 
-    # the grouped product asks ``on_tpu()`` before it hands Mosaic a kernel
-    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     cfg = NemotronHConfig(experts_held=16)
     mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
     here = NamedSharding(mesh, P())
@@ -521,8 +514,7 @@ WINDOWED = {
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 @pytest.mark.parametrize("model", sorted(WINDOWED))
-def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
-                                                      model, kind):
+def test_exaone_step_compiles_with_its_state_in_place(topo, model, kind):
     """The whole served step of the two configurations the EXAONE walk
     serves, as ``BatchEngine`` builds it around ``forward_paged``:
     k-exaone-236b-a23b-ep8 (layers 0-4, every width, 16 of 128 experts held,
@@ -540,7 +532,6 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
 
     from triton_distributed_tpu.models.engine import Engine
     from triton_distributed_tpu.models.exaone_moe import ExaoneMoe
-    from triton_distributed_tpu.runtime import platform
     from triton_distributed_tpu.serving.kv_pool import (
         paged_state_shapes,
         paged_state_specs,
@@ -549,8 +540,6 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, monkeypatch,
     name, family, state_gb, weights_gb = WINDOWED[model]
     family = importlib.import_module(f"perfbench.families.{family}")
     geo = LONG[model]
-    # the grouped product asks ``on_tpu()`` before it hands Mosaic a kernel
-    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, f"perfbench/configs/{name}.json")) as f:
         file = json.load(f)

@@ -14,11 +14,14 @@ Integration layer: a real ``BatchEngine`` under chaos with the
 controller attached still traces each compiled step exactly once (knob
 moves are data, never shape), and a fleet kill + cooldown-gated
 ``revive()`` replays bit-identically (fault log, state log, action log,
-and generated tokens) across two runs with the same seed.
+and generated tokens) across two runs with the same seed. Knobs, kills and
+revives are host logic: the plants take the plain path
+(``conftest.PLAIN_PATH``); no case stays on "fused".
 """
 
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.resilience import (
     FaultPlan,
@@ -193,7 +196,8 @@ def test_engine_control_sweep_zero_retraces_under_chaos(tiny_engine):
     config = tiny_engine.config
     be = BatchEngine(tiny_engine, n_slots=4, n_blocks=24, block_size=4,
                      prefill_chunk=8,
-                     retry=RetryPolicy(retries=6, base_delay_s=0.001))
+                     retry=RetryPolicy(retries=6, base_delay_s=0.001),
+                     **PLAIN_PATH)
     ctl = be.attach_controller(interval_steps=1, relax_after=2)
     rng = np.random.default_rng(0)
     plan = FaultPlan([
@@ -228,7 +232,7 @@ def _fleet_adaptive_run(tiny_engine, seed: int):
     config = tiny_engine.config
     fleet = Fleet.build(tiny_engine, n_replicas=2, n_slots=2, n_blocks=16,
                         block_size=4, prefill_chunk=8, fail_threshold=2,
-                        revive_cooldown_steps=4)
+                        revive_cooldown_steps=4, **PLAIN_PATH)
     ctl = fleet.attach_controller(interval_steps=1, relax_after=2)
     plan = default_fleet_chaos_plan(seed, kill_replica=0, kill_after=3,
                                     kill_fires=2)
@@ -287,7 +291,7 @@ def test_revive_cooldown_and_state_gate(tiny_engine):
 
     fleet = Fleet.build(tiny_engine, n_replicas=2, n_slots=2, n_blocks=16,
                         block_size=4, prefill_chunk=8,
-                        revive_cooldown_steps=5)
+                        revive_cooldown_steps=5, **PLAIN_PATH)
     with pytest.raises(ValueError, match="not DEAD"):
         fleet.revive(0)
     rep = fleet.replicas[0]

@@ -2,7 +2,8 @@
 virtual clock, tenant-tag propagation across a seeded fleet kill+requeue
 with conserved cost totals, bounded-memory behavior, the window sum/mean
 accessors against a numpy reference, the roofline metric classes, and the
-fleet_efficiency report's determinism + exit codes."""
+fleet_efficiency report's determinism + exit codes. The ledger is host
+logic: the fleet here takes the plain path (``conftest.PLAIN_PATH``)."""
 
 import importlib.util
 import json
@@ -11,6 +12,7 @@ import pathlib
 
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.obs.efficiency import (
     BUCKETS,
@@ -186,7 +188,8 @@ def test_fleet_tenant_conservation_across_requeue():
     config = ModelConfig.from_name("tiny")
     engine = Engine(config, mesh=mesh, mode="xla", block_n=8)
     fleet = Fleet.build(engine, n_replicas=2, n_slots=4, n_blocks=32,
-                        block_size=4, prefill_chunk=8, fail_threshold=2)
+                        block_size=4, prefill_chunk=8, fail_threshold=2,
+                        **PLAIN_PATH)
     rng = np.random.default_rng(0)
     n_req = 16
     with faults.plan(default_fleet_chaos_plan(0, kill_replica=0,
@@ -373,7 +376,9 @@ def test_fleet_efficiency_renders_engine_shape():
 def test_serve_smoke_efficiency_arm():
     """The --efficiency arm: a short loaded run must end with the ledger's
     contract intact — main() itself raises on zero MFU, frac-sum breakage,
-    or bubble_frac >= 1."""
+    or bubble_frac >= 1. The ledger is the host's: the arm takes the plain
+    path where the fused kernel would be interpreted
+    (``serve_smoke._host_arm_attn``)."""
     spec = importlib.util.spec_from_file_location("serve_smoke", _SMOKE)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)

@@ -24,6 +24,7 @@ import time
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models import Engine, ModelConfig
 from triton_distributed_tpu.resilience import (
@@ -63,6 +64,9 @@ def _golden(engine, prompt, gen_len):
 
 
 def _build(engine, **kw):
+    # Routing, requeue, health and schedules are host logic: every fleet of
+    # this file takes the plain path, and no case stays on "fused".
+    kw = {**PLAIN_PATH, **kw}
     kw.setdefault("n_replicas", 3)
     kw.setdefault("n_slots", 2)
     kw.setdefault("n_blocks", 16)

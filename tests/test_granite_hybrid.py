@@ -473,6 +473,43 @@ def test_the_state_update_kernel_equals_plain_jnp(groups, tile):
     np.testing.assert_array_equal(got[2], arena[2])
 
 
+def test_off_the_tpu_the_state_update_is_the_plain_form_unless_asked(served):
+    """``platform.plain_off_tpu``: AUTO off the TPU takes the plain form.
+    Under ``interpret=None`` here (no TPU) the entry returns the reference,
+    to the bit, and the tiny model's decode step holds no ``pallas_call`` of
+    that name; under ``interpret=True`` it holds the
+    kernel (``Engine(..., interpret=True)`` is how a test asks for it inside
+    a step). ``interpret=False`` hands Mosaic the kernel from this process:
+    ``tests/test_chip_compile.py`` holds that."""
+    from triton_distributed_tpu.kernels.ssm_update import NAME
+    from triton_distributed_tpu.runtime.platform import plain_off_tpu
+
+    assert plain_off_tpu(None)
+    assert not plain_off_tpu(True) and not plain_off_tpu(False)
+    rng = np.random.default_rng(5)
+    arena = jnp.asarray(rng.standard_normal((2, 3, 4, 8, 128)), jnp.float32)
+    a = jnp.asarray(rng.uniform(0.5, 1, (3, 4)), jnp.float32)
+    u = jnp.asarray(rng.standard_normal((3, 4, 8)), jnp.float32)
+    b, c = (jnp.asarray(rng.standard_normal((3, 2, 128)), jnp.float32)
+            for _ in range(2))
+    for got, want in zip(
+            ssm_state_update(arena, jnp.int32(1), a, u, b, c),
+            ssm_state_update_reference(arena, jnp.int32(1), a, u, b, c)):
+        np.testing.assert_array_equal(got, want)
+
+    asked = Engine(served.config, mesh=served.mesh, mode="dist",
+                   params=served.params, interpret=True)
+    n_slots = 2
+    held = {}
+    for name, engine in (("auto", served), ("asked", asked)):
+        pool, _, dec = paged_steps(engine, n_slots)
+        tables = jnp.zeros((n_slots, pool.max_blocks_per_seq), jnp.int32)
+        held[name] = f"name={NAME}" in str(jax.make_jaxpr(dec)(
+            engine.params, jnp.zeros((n_slots, 1), jnp.int32), pool.state,
+            jnp.zeros(n_slots, jnp.int32), tables, jnp.ones(n_slots, bool)))
+    assert held == {"auto": False, "asked": True}
+
+
 DEAD = [-1, 0, 0]
 DEALS = {
     # the whole chunk a row: a takes three rows of the first step

@@ -1,8 +1,8 @@
 """The host's turn has spans (``obs.trace``): ``Fleet.step()`` and
 ``BatchEngine.step()`` record their phases whenever the tracer is enabled
 or a profiler capture is live, on ``time.monotonic()``, and cost one call a
-site when neither holds. Tiny sizes and the gather path: what is tested is
-host code.
+site when neither holds. Tiny sizes and the plain path
+(``conftest.PLAIN_PATH``): what is tested is host code.
 """
 
 import gc
@@ -11,6 +11,7 @@ import time
 
 import jax
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models.config import ModelConfig
 from triton_distributed_tpu.models.engine import Engine
@@ -46,7 +47,7 @@ def engine():
 @pytest.fixture
 def fleet(engine):
     return Fleet.build(engine, n_replicas=1, n_slots=4, n_blocks=32,
-                       block_size=4, prefill_chunk=8, paged_attn="gather")
+                       block_size=4, prefill_chunk=8, **PLAIN_PATH)
 
 
 @pytest.fixture(autouse=True)
@@ -163,7 +164,7 @@ def test_a_mixed_step_says_what_it_dispatched_and_admitted(fleet):
 def test_a_serial_engine_names_its_reason_and_reads_what_it_dispatched(
         engine):
     fleet = Fleet.build(engine, n_replicas=1, n_slots=4, n_blocks=32,
-                        block_size=4, prefill_chunk=8, paged_attn="gather",
+                        block_size=4, prefill_chunk=8, **PLAIN_PATH,
                         nan_guard=True)
     with trace.tracing() as tracer:
         serve_some(fleet, 1)

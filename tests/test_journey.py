@@ -16,6 +16,9 @@ The load-bearing guarantees (ISSUE 13):
      finish with hop ids monotonically numbered across replicas, and
      the forensic ``tools/explain_request.py`` report over the dumped
      journal is deterministic.
+
+Journeys are recorded by the host: the engines here take the plain path
+(``conftest.PLAIN_PATH``); no case stays on "fused".
 """
 
 import json
@@ -23,6 +26,7 @@ import json
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models import Engine, ModelConfig
 from triton_distributed_tpu.obs import trace
@@ -193,7 +197,8 @@ def test_route_breakdown_components_sum_to_score():
 def test_engine_journey_bit_identical_zero_retrace(setup):
     _, config, engine = setup
     rng = np.random.default_rng(0)
-    kw = dict(n_slots=4, n_blocks=32, block_size=4, prefill_chunk=8)
+    kw = dict(n_slots=4, n_blocks=32, block_size=4, prefill_chunk=8,
+              **PLAIN_PATH)
     be_on = BatchEngine(engine, **kw)         # journey on by default
     be_off = BatchEngine(engine, **kw, journey=False)
     assert be_on.journey is not None and be_off.journey is None
@@ -229,7 +234,7 @@ def test_engine_preemption_lands_in_preempted_bucket(setup):
     _, config, engine = setup
     rng = np.random.default_rng(1)
     be = BatchEngine(engine, n_slots=3, n_blocks=6, block_size=4,
-                     prefill_chunk=8, tail_sampling=False)
+                     prefill_chunk=8, tail_sampling=False, **PLAIN_PATH)
     prompts = [rng.integers(0, config.vocab_size, size=7).tolist()
                for _ in range(4)]
     rids = [be.submit(p, max_new_tokens=8) for p in prompts]
@@ -264,7 +269,7 @@ def test_fleet_chaos_requeue_hop_chain_and_explain(setup, tmp_path):
     _, config, engine = setup
     fleet = Fleet.build(engine, n_replicas=2, fail_threshold=2,
                         n_slots=4, n_blocks=24, block_size=4,
-                        prefill_chunk=8)
+                        prefill_chunk=8, **PLAIN_PATH)
     assert all(rep.engine.journey is fleet.journey
                for rep in fleet.replicas)     # ONE shared recorder
     fleet.journey.clock = TickClock(1e-3)     # deterministic report

@@ -6,8 +6,9 @@ Tiny config per the conftest interpreter ceiling.
 One dist-mode forward of `tiny` is about 46,000 interpreter callbacks on
 eight virtual devices (66 s on an idle 8-core host) and about 20 s on four,
 whatever the batch; an `ar` or `xla` forward on eight is 5 s or less. So
-the `dist` comparisons run at TP=4 and everything else at TP=8, and every
-engine and every served result is built once and shared."""
+the `dist` comparisons run at TP=4 (the scanned loop's at TP=2: six `dist`
+forwards for its two sides, a quarter of the cost each), everything else at
+TP=8, and every engine and every served result is built once and shared."""
 
 import functools
 
@@ -38,29 +39,48 @@ class _Shared:
             jax.random.PRNGKey(0), mesh)
         self.ids = jax.random.randint(jax.random.PRNGKey(1), (B, L0), 0,
                                       self.config.vocab_size, jnp.int32)
+        self._prefilled = {}
 
-    # No default for prefill_mode: functools.cache keys on the arguments as
-    # they are passed, and ("dist",) is not ("dist", None).
+    # No defaults: functools.cache keys on the arguments as they are passed,
+    # and ("dist",) is not ("dist", None).
     @functools.cache
     def engine(self, mode, prefill_mode):
         return Engine(self.config, mesh=self.mesh, mode=mode,
                       prefill_mode=prefill_mode, params=self.params,
                       block_n=8)
 
-    @functools.cache
     def prefill_logits(self, mode):
-        e = self.engine(mode, None)
-        return e.prefill(self.ids, e.new_cache(B))[0]
+        """The logits of the prefill that ``served(mode, None, GEN)`` ran: the
+        forward ``Engine.serve`` starts with, not one more of it."""
+        self.served(mode, None, GEN)
+        return self._prefilled[mode]
 
     @functools.cache
-    def served(self, mode, prefill_mode):
-        return np.asarray(
-            self.engine(mode, prefill_mode).serve(self.ids, GEN))
+    def served(self, mode, prefill_mode, gen):
+        e = self.engine(mode, prefill_mode)
+        prefill = e.prefill
+
+        def recording(ids, kv):
+            logits, kv = prefill(ids, kv)
+            if prefill_mode is None:
+                self._prefilled[mode] = logits
+            return logits, kv
+        e.prefill = recording
+        try:
+            return np.asarray(e.serve(self.ids, gen))
+        finally:
+            e.prefill = prefill
 
 
 @pytest.fixture(scope="module")
 def tp4():
     return _Shared(make_mesh({"tp": 4}, devices=jax.devices()[:4],
+                             set_default=False))
+
+
+@pytest.fixture(scope="module")
+def tp2():
+    return _Shared(make_mesh({"tp": 2}, devices=jax.devices()[:2],
                              set_default=False))
 
 
@@ -80,29 +100,45 @@ def test_prefill_logits_ar_matches_xla(tp8):
                     atol=2e-3, rtol=2e-3)
 
 
-@pytest.mark.parametrize("mode,prefill_mode", [
-    ("dist", None),          # dist everywhere
-    ("ar", None),            # AR everywhere
-    ("dist", "xla"),         # reference engine style: golden prefill,
-])                           # distributed decode (engine.py:121)
-def test_generation_matches_xla_golden(request, mode, prefill_mode):
+@pytest.mark.parametrize("mode,prefill_mode,gen", [
+    ("dist", None, GEN),     # dist everywhere
+    ("ar", None, GEN),       # AR everywhere
+    ("dist", "xla", 2),      # reference engine style: golden prefill,
+], ids=["dist-None", "ar-None", "dist-xla"])   # distributed decode
+def test_generation_matches_xla_golden(request, mode, prefill_mode, gen):
+    """``[dist-None]`` is the LONG FORM of per-step generation through the
+    distributed kernels (a prefill and two decodes at TP=4: what
+    ``tutorials/10-e2e-inference-engine.py`` shows once more at TP=2 only to
+    print its lines), and its prefill is the one
+    ``test_prefill_logits_dist_matches_xla`` reads. What only ``[dist-xla]``
+    shows is that a cache the golden prefill wrote is read by a distributed
+    decode step: one such step (``gen`` 2), the second decode reading the
+    first being ``[dist-None]``'s."""
     shared = request.getfixturevalue("tp4" if mode == "dist" else "tp8")
-    golden = shared.served("xla", None)
+    golden = shared.served("xla", None, GEN)
     assert golden.shape == (B, GEN)
-    np.testing.assert_array_equal(shared.served(mode, prefill_mode), golden)
+    np.testing.assert_array_equal(shared.served(mode, prefill_mode, gen),
+                                  golden[:, :gen])
 
 
-def test_serve_scanned_matches_serve(tp4, tp8):
+def test_serve_scanned_matches_serve(tp2, tp8):
     """The one-executable scanned decode loop (prefill + lax.scan) must
     generate token-for-token what the per-step loop generates, on both the
     xla golden and the distributed kernel path. GEN=3 runs the scan body
     twice: the second iteration reads the KV carry and the collectives'
-    semaphore state that the first one left."""
-    for mode, shared in (("xla", tp8), ("dist", tp4)):
+    semaphore state that the first one left. What only this test shows is
+    that second iteration inside ONE executable, which any mesh of two or
+    more shows: TP=2, against the per-step loop on the same mesh (under six
+    busy workers the TP=4 executable alone took 135-170 s of the 300 a test
+    may take). The kernels at TP=4 over consecutive steps, each reading the
+    semaphores the step before left, are
+    ``test_generation_matches_xla_golden[dist-None]``'s; this is the long
+    form of the tutorial's scanned line, which runs the body once."""
+    for mode, shared in (("xla", tp8), ("dist", tp2)):
         np.testing.assert_array_equal(
             np.asarray(
                 shared.engine(mode, None).serve_scanned(shared.ids, GEN)),
-            shared.served(mode, None), err_msg=mode)
+            shared.served(mode, None, GEN), err_msg=mode)
 
 
 def test_kv_cache_offset_advances(tp8):

@@ -34,6 +34,7 @@ import types
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models import Engine, ModelConfig
 from triton_distributed_tpu.obs.replay import (
@@ -65,10 +66,9 @@ def _build(engine, **kw):
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", 8)
     # Recording and replay are host-side schedule and virtual time; the
-    # gather attention path (the fused kernel's bit-identical oracle) keeps
-    # the dozens of fleets this file drives off the interpreter.
-    kw.setdefault("paged_attn", "gather")
-    return Fleet.build(engine, **kw)
+    # plain path keeps the dozens of fleets this file drives off the
+    # interpreter. No case stays on "fused".
+    return Fleet.build(engine, **{**PLAIN_PATH, **kw})
 
 
 def _drive(fleet, config, *, n_requests=8, seed=0, gap=2, gen=5,

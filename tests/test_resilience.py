@@ -13,6 +13,10 @@ The load-bearing guarantees (docs/resilience.md):
      out of the victim pool and gets to finish;
   5. allocator honesty — releasing an unknown/already-released seq_id
      raises instead of silently no-opping.
+
+Faults, retries, quarantine, the watchdog and admission are host logic: every
+engine of this file takes the plain path (``conftest.PLAIN_PATH``); no case
+stays on "fused".
 """
 
 import time
@@ -20,6 +24,7 @@ import time
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models import Engine, ModelConfig
 from triton_distributed_tpu.obs import comm_ledger
@@ -205,7 +210,7 @@ def test_starvation_cap_lets_low_priority_finish(setup):
     cap bounds its preemptions and it completes."""
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=2, n_blocks=6, block_size=4,
-                     prefill_chunk=8, max_seq_len=24)
+                     prefill_chunk=8, max_seq_len=24, **PLAIN_PATH)
     cap = be.scheduler.preemption_cap
     assert cap is not None
     lo = be.submit([5, 6, 7], max_new_tokens=8, priority=0, req_id="lo")
@@ -228,7 +233,7 @@ def test_quarantined_request_leaves_survivors_bit_identical(setup):
     sequence reference — the fault handling touched masks, not math."""
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=4, n_blocks=16, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, **PLAIN_PATH)
     prompts = [[3, 1, 4, 1, 5], [9, 2, 6, 5], [3, 5, 8, 9, 7, 9]]
     for i, p in enumerate(prompts):
         be.submit(p, max_new_tokens=6, req_id=f"r{i}")
@@ -260,7 +265,7 @@ def test_transient_step_faults_are_invisible_after_retry(setup):
     buffers, so the re-run starts from intact state."""
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=2, n_blocks=8, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, **PLAIN_PATH)
     prompt = [7, 3, 2, 6]
     be.submit(prompt, max_new_tokens=5, req_id="r")
     plan = FaultPlan([FaultSpec(site="engine.decode", kind="error", p=1.0,
@@ -281,7 +286,8 @@ def test_transient_step_faults_are_invisible_after_retry(setup):
 def test_chaos_plan_run_completes_and_accounts(setup):
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=4, n_blocks=12, block_size=4,
-                     prefill_chunk=8, retry=RetryPolicy(retries=6))
+                     prefill_chunk=8, retry=RetryPolicy(retries=6),
+                     **PLAIN_PATH)
     n = 8
     rng = np.random.default_rng(0)
     for i in range(n):
@@ -308,7 +314,7 @@ def test_faulted_cache_lookup_degrades_to_cold_prefill(setup):
     fires before the cache touches any state)."""
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=2, n_blocks=16, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, **PLAIN_PATH)
     prompt = [5, 3, 5, 3, 5, 3, 5, 3, 2]
     golden = _golden(engine, prompt, 4).tolist()
     be.submit(prompt, max_new_tokens=4, req_id="warm")
@@ -347,7 +353,7 @@ def test_disabled_plan_is_bit_identical(setup):
     same tokens as the single-sequence reference, statuses 'ok'."""
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=2, n_blocks=8, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, **PLAIN_PATH)
     prompt = [2, 7, 1, 8, 2, 8]
     be.submit(prompt, max_new_tokens=4, req_id="r")
     out = be.run()
@@ -361,7 +367,7 @@ def test_admission_backpressure(setup):
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=2, n_blocks=8, block_size=4,
                      prefill_chunk=8, max_seq_len=24,
-                     admission_pressure=0.9)
+                     admission_pressure=0.9, **PLAIN_PATH)
     be.submit([1, 2, 3, 4], max_new_tokens=4, req_id="a")
     be.step()                           # 'a' resident: pool 75% free < 90%
     be.submit([5, 6, 7, 8], max_new_tokens=4, req_id="b")
@@ -397,7 +403,7 @@ def test_watchdog_snapshot_contains_in_flight_table(setup):
     table — the thing an operator needs when a step wedges."""
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=2, n_blocks=8, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, **PLAIN_PATH)
     wd = be.attach_watchdog(Watchdog(), step_deadline_s=300.0)
     be.submit([1, 2, 3], max_new_tokens=6, req_id="w0")
     be.submit([4, 5, 6, 7], max_new_tokens=6, req_id="w1")

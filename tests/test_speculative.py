@@ -18,11 +18,20 @@ The load-bearing guarantees (docs/serving.md, "Speculative decoding"):
   5. acceptance accounting — with a scripted drafter the accept/reject
      stream is exact: counters, histograms, and controller k moves are
      fully predictable.
+
+Accounting, rollback, drafters and requeue are host logic: the engines here
+take the plain path (``conftest.PLAIN_PATH``). Two cases stay on "fused",
+their subject being the kernel under a verify row:
+``test_spec_ngram_bit_identical_with_preemption`` (ragged verify rows through
+the fused mixed step, with rollback's stale rows behind them) and the
+quantized rows of ``test_spec_rollback_then_prefix_cache_warm_equals_cold``
+(rolled-back rows dequantized in the kernel's staging).
 """
 
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models import Engine, ModelConfig
 from triton_distributed_tpu.resilience import FaultPlan, FaultSpec, faults
@@ -232,7 +241,7 @@ def test_spec_k0_bit_identical(setup):
     plan = Speculative(drafter=NGramDrafter(),
                        controller=SpecController(k_init=0, adaptive=False))
     be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
-                     speculative=plan)
+                     speculative=plan, **PLAIN_PATH)
     specs = [(5, 6), (3, 5), (7, 4), (4, 6)]
     prompts = [rng.integers(0, config.vocab_size, size=n).tolist()
                for n, _ in specs]
@@ -300,7 +309,7 @@ def test_scripted_full_accept_exact_accounting(setup):
     plan = Speculative(drafter=drafter,
                        controller=SpecController(k_init=2, adaptive=False))
     be = BatchEngine(engine, n_slots=2, block_size=4, prefill_chunk=8,
-                     speculative=plan)
+                     speculative=plan, **PLAIN_PATH)
     rids = [be.submit(p, g, req_id=i) for i, (p, g)
             in enumerate(zip(prompts, gens))]
     out = be.run(max_steps=200)
@@ -332,7 +341,7 @@ def test_scripted_full_reject_exact_accounting(setup):
     plan = Speculative(drafter=drafter,
                        controller=SpecController(k_init=1, adaptive=False))
     be = BatchEngine(engine, n_slots=1, block_size=4, prefill_chunk=8,
-                     speculative=plan)
+                     speculative=plan, **PLAIN_PATH)
     rid = be.submit(prompts[0], gens[0], req_id=0)
     out = be.run(max_steps=100)
     assert out[rid] == gold[0]
@@ -360,7 +369,7 @@ def test_spec_adaptive_shrinks_to_zero_on_rejection(setup):
     plan = Speculative(drafter=drafter,
                        controller=SpecController(k_init=2, min_samples=3))
     be = BatchEngine(engine, n_slots=1, block_size=4, prefill_chunk=8,
-                     speculative=plan)
+                     speculative=plan, **PLAIN_PATH)
     rid = be.submit(prompts[0], gens[0], req_id=0)
     out = be.run(max_steps=100)
     assert out[rid] == gold[0]
@@ -392,7 +401,8 @@ def test_spec_rollback_then_prefix_cache_warm_equals_cold(setup, kv_dtype):
     plan = Speculative(drafter=drafter,
                        controller=SpecController(k_init=2, adaptive=False))
     be = BatchEngine(engine, n_slots=2, block_size=4, prefill_chunk=8,
-                     speculative=plan, kv_dtype=kv_dtype)
+                     speculative=plan, kv_dtype=kv_dtype,
+                     **(PLAIN_PATH if kv_dtype is None else {}))
     be.submit(prompts[0], gens[0], req_id="cold")
     cold = be.run(max_steps=100)
     assert be.metrics.as_dict()["spec_rollback_tokens"] > 0
@@ -420,7 +430,7 @@ def test_spec_chaos_quarantine_leaves_survivors_bit_identical(setup):
     plan = Speculative(drafter=drafter,
                        controller=SpecController(k_init=2, adaptive=False))
     be = BatchEngine(engine, n_slots=3, block_size=4, prefill_chunk=8,
-                     speculative=plan)
+                     speculative=plan, **PLAIN_PATH)
     for i, (p, g) in enumerate(zip(prompts, gens)):
         be.submit(p, g, req_id=i)
     # with full-accept k=2 drafting every decode step is a verify row
@@ -459,7 +469,7 @@ def test_controller_spec_k_cap_knob(setup):
     SpecController."""
     _, config, engine = setup
     be = BatchEngine(engine, n_slots=2, block_size=4, prefill_chunk=8,
-                     speculative=True)
+                     speculative=True, **PLAIN_PATH)
     ctl = Controller(engine=be)
     assert "spec_k_cap" in ctl.knobs
     k_max = be.spec.controller.k_max
@@ -482,7 +492,8 @@ def test_controller_spec_k_cap_knob(setup):
         ctl.tick(obs(0))
     assert be.spec.controller.k_cap == k_max
     # non-speculative engines keep the stock knob set
-    be2 = BatchEngine(engine, n_slots=2, block_size=4, prefill_chunk=8)
+    be2 = BatchEngine(engine, n_slots=2, block_size=4, prefill_chunk=8,
+                      **PLAIN_PATH)
     assert "spec_k_cap" not in Controller(engine=be2).knobs
 
 
@@ -496,7 +507,7 @@ def test_fleet_kill_requeue_spec_bit_identical(setup):
     _, config, engine = setup
     fleet = Fleet.build(engine, n_replicas=3, n_slots=2, n_blocks=16,
                         block_size=4, prefill_chunk=8, fail_threshold=2,
-                        speculative=True)
+                        speculative=True, **PLAIN_PATH)
     rng = np.random.default_rng(9)
     specs = []
     for i in range(8):
@@ -562,7 +573,7 @@ def test_a_verify_row_stays_one_row_and_a_prompt_beside_it_takes_more(setup):
     plan = Speculative(drafter=drafter,
                        controller=SpecController(k_init=3, adaptive=False))
     be = BatchEngine(engine, n_slots=4, block_size=4, prefill_chunk=8,
-                     speculative=plan, paged_attn="gather")
+                     speculative=plan, **PLAIN_PATH)
     assert be.prefill_rows == 4
     calls, step = [], be._mixed_step
 

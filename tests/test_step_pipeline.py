@@ -13,6 +13,7 @@ import copy
 import jax
 import numpy as np
 import pytest
+from conftest import PLAIN_PATH
 
 from triton_distributed_tpu.models.config import (
     DeepseekV3Config,
@@ -79,7 +80,7 @@ def batch_engine(engine, **kw):
     row (``one_row_block``); engines of one ``Engine`` and geometry share
     their compiled steps, so a case costs a run, not a compile."""
     kw = {**dict(n_slots=N_SLOTS, n_blocks=N_BLOCKS, block_size=BLOCK,
-                 prefill_chunk=CHUNK, paged_attn="gather", seed=11), **kw}
+                 prefill_chunk=CHUNK, seed=11, **PLAIN_PATH), **kw}
     key = (id(engine), kw["n_blocks"], kw.get("speculative", False))
     if key not in _DONORS:          # never served from, never wrapped
         _DONORS[key] = BatchEngine(engine, **kw)
@@ -268,7 +269,7 @@ def test_a_caller_between_two_steps_finds_every_dispatched_token(
     script = churn(engine.config.vocab_size, reuse=False)
     donor = batch_engine(engine)
     kw = dict(n_replicas=2, n_slots=N_SLOTS, n_blocks=N_BLOCKS,
-              block_size=BLOCK, prefill_chunk=CHUNK, paged_attn="gather")
+              block_size=BLOCK, prefill_chunk=CHUNK, **PLAIN_PATH)
 
     def fleet():
         f = Fleet.build(engine, **kw)

@@ -292,10 +292,16 @@ def grouped_gemm_skip(grouped, weights, counts, *, layer_idx=None,
     the kernel's name in a device trace.
 
     Falls back to the einsum when the shapes don't tile (ragged f) — the
-    kernel and the einsum are interchangeable by contract."""
+    kernel and the einsum are interchangeable by contract — and, under
+    ``interpret=None``, wherever there is no TPU (``platform.plain_off_tpu``:
+    AUTO off the TPU takes the plain form; ``True`` is the interpreted
+    kernel, ``False`` Mosaic's)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    from triton_distributed_tpu.runtime.platform import resolve_interpret
+    from triton_distributed_tpu.runtime.platform import (
+        plain_off_tpu,
+        resolve_interpret,
+    )
 
     E, cap, d = grouped.shape
     stacked = weights.ndim == 4
@@ -325,24 +331,21 @@ def grouped_gemm_skip(grouped, weights, counts, *, layer_idx=None,
     # packed-tile relayout path (measured 2x SLOWER end-to-end at a cap=8
     # decode shape than the einsum despite the skip) — capacity sizing
     # keeps the EP grids at >= 16 rows (moe_mlp._ep_layer).
-    from triton_distributed_tpu.runtime.platform import on_tpu
-
     if (f % bn or cap % 8 or (cap < 16 and grouped.dtype.itemsize < 4)
-            or (interpret is not True and not on_tpu())):
+            or plain_off_tpu(interpret)):
         # The einsum fallback needs the layer slice; XLA fuses it into the
         # einsum's reads (no copy) — and for non-stacked callers this is
         # the free [0] of the [None] normalization above.
-        # interpret=False off-TPU lands here too: "compiled" has no meaning
-        # without a TPU backend, and handing Mosaic a CPU target fails at
-        # lowering — the einsum is the same math either way.
-        # AUTO-interpret (None off-TPU) also lands here: the faithful
-        # interpreter wedges executing this kernel's scalar-driven weight
-        # index maps inside a shard_map that carries an unrelated
-        # replicated mesh axis (observed: tiny-moe serve on a dp x tp
-        # virtual mesh never completes, while tp-only meshes and the
-        # direct unit test run fine). The einsum is the same math; kernel
-        # correctness stays covered by the EXPLICIT interpret=True unit
-        # test (test_grouped_gemm_skip_matches_einsum).
+        # AUTO off the TPU takes the plain form (``platform.plain_off_tpu``,
+        # the rule ``ssm_state_update`` reads too). Here it is more than
+        # time: the faithful interpreter wedges executing this kernel's
+        # scalar-driven weight index maps inside a shard_map that carries
+        # an unrelated replicated mesh axis (observed: tiny-moe serve on a
+        # dp x tp virtual mesh never completes, while tp-only meshes and
+        # the direct unit test run fine). Kernel correctness stays covered
+        # by the EXPLICIT interpret=True unit test
+        # (test_grouped_gemm_skip_matches_einsum); interpret=False hands
+        # Mosaic the kernel (tests/test_chip_compile.py).
         w = weights[layer_idx]
         return grouped_gemm(grouped, w if group_of is None else w[group_of])
     # Largest-index non-empty expert at-or-before e (leading empties clamp

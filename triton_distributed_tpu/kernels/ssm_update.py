@@ -42,7 +42,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from triton_distributed_tpu.runtime.platform import resolve_interpret
+from triton_distributed_tpu.runtime.platform import (
+    plain_off_tpu,
+    resolve_interpret,
+)
 
 NAME = "ssm_state_update"
 # Heads a grid step: 32 x 64 x 128 x 4 B = 1 MiB a block. Read on a v5e at the
@@ -70,7 +73,14 @@ def ssm_state_update(arena, layer, a, u, b, c, *, head_tile: int | None = None,
     ``b`` and ``c`` (n_slots, G, N), heads ``[g * H / G, (g + 1) * H / G)``
     reading group ``g``; all float32. Returns ``(arena, y)``: the arena with
     ``[layer]`` advanced by one token a slot (the same buffer under jit:
-    the operand is aliased to the result) and ``y`` (n_slots, H, P)."""
+    the operand is aliased to the result) and ``y`` (n_slots, H, P).
+
+    ``interpret=None`` where there is no TPU returns
+    ``ssm_state_update_reference`` (``platform.plain_off_tpu``: AUTO off the
+    TPU takes the plain form); ``True`` is the interpreted kernel, ``False``
+    Mosaic's."""
+    if plain_off_tpu(interpret):
+        return ssm_state_update_reference(arena, layer, a, u, b, c)
     n_slots, H, P, N = arena.shape[1:]
     G = b.shape[1]
     ht, hpg = min(head_tile or HEAD_TILE, H), H // G
@@ -116,7 +126,8 @@ def ssm_state_update(arena, layer, a, u, b, c, *, head_tile: int | None = None,
 
 
 def ssm_state_update_reference(arena, layer, a, u, b, c):
-    """The same in plain ``jax.numpy`` (tests; no aliasing promised)."""
+    """The same in plain ``jax.numpy`` (tests, and every run off the TPU
+    that does not ask for the kernel; no aliasing promised)."""
     H, G = arena.shape[2], b.shape[1]
     bh = jnp.repeat(b, H // G, axis=1)                  # (n_slots, H, N)
     ch = jnp.repeat(c, H // G, axis=1)

@@ -6,6 +6,8 @@ every Pallas kernel in this framework takes ``interpret=None`` and resolves it
 here — on real TPU hardware kernels compile via Mosaic; anywhere else they run
 under the Pallas TPU interpreter, which supports inter-chip remote DMA and
 semaphores on a virtual CPU mesh (``--xla_force_host_platform_device_count``).
+Two served kernels with nothing of the kind to simulate take their plain
+``jax.numpy`` equal there instead (``plain_off_tpu``, below).
 
 This is what lets ``tests/`` validate 8-way distributed kernels on a CPU-only
 CI box, and it also provides a *race detector*
@@ -63,3 +65,20 @@ def resolve_interpret(interpret: InterpretFlag = None, *, detect_races: bool = F
     if interpret is True:
         return pltpu.InterpretParams(detect_races=detect_races)
     return interpret  # explicit False: compiled path, even with detect_races
+
+
+def plain_off_tpu(interpret: InterpretFlag) -> bool:
+    """AUTO off the TPU takes the plain form: the ONE rule of the served
+    kernels that have no remote DMA or semaphore to simulate and a plain
+    ``jax.numpy`` equal standing beside them (``ssm_update
+    .ssm_state_update``, ``moe_utils.grouped_gemm_skip``). Under
+    ``interpret=None`` where there is no TPU such a kernel's entry returns
+    that equal, so a CPU run of a served step costs XLA's time and not the
+    interpreter's callbacks (720 a step of a tiny hybrid). ``True`` is the
+    interpreted kernel (its own unit test; ``Engine(..., interpret=True)``
+    for a test that wants it inside a step), ``False`` hands Mosaic the
+    kernel wherever the process runs (``tests/test_chip_compile.py`` lowers
+    for a described chip from a CPU process), and on a TPU every value
+    reaches the ``pallas_call``. ``paged_attention`` is not under this rule:
+    its plain form is chosen by ``BatchEngine(paged_attn="gather")``."""
+    return interpret is None and not on_tpu()

@@ -37,9 +37,11 @@ from triton_distributed_tpu.runtime.mesh import make_mesh  # noqa: E402
 
 # Interpreter-sized: one dist-mode forward is tens of thousands of
 # interpreter callbacks whatever the batch (about 20 s at TP=4, 65 s at
-# TP=8), so the tutorial runs TP=4 and generates two tokens per mode
-# (tests/test_qwen_e2e.py scans for three, where the loop's body runs twice).
-WORLD, B, L0, GEN = 4, 8, 4, 2
+# TP=8), so the tutorial runs TP=2 and generates two tokens per mode: four
+# dist forwards, the fewest that still print every line below. The long
+# form is tests/test_qwen_e2e.py: the same three modes at TP=4 (TP=8 for
+# ``ar``) and a scanned loop of three tokens, whose body runs twice.
+WORLD, B, L0, GEN = 2, 8, 4, 2
 
 
 def main():
