@@ -29,7 +29,12 @@ walk long enough to pass the window and to wrap the ring; and the
 SMALLTHINKER block (the same class with four things read otherwise from its
 configuration: SmallThinker-21BA3B's widths, one full layer and one over a
 window of 4,096, 28 query heads on 4 key heads, all 64 ReGLU experts routed
-from the layer's input) the same way. In all four the
+from the layer's input) the same way; and the LFM2-MoE block (the same
+class with a third kind of operator: LFM2-24B-A2B's widths, its two dense
+conv layers and one period ``full, conv, conv, conv``, 8 of 64 experts held:
+the gated short convolution's one-token update, the ``short_conv_update``
+kernel compiled by Mosaic, against its chunk shape, a slot's rows chained
+through the window). In all five the
 chunk shape takes every walked prompt several rows of the prefill block a
 step, every prompt is held to the tolerance, and where the model routes both
 programs' routers are on record: a prompt is left out only where its token
@@ -122,6 +127,17 @@ SMALLTHINKER = dict(HYBRID, config="ExaoneMoeConfig.smallthinker",
                         sliding_windows=(0, 4096),
                         mlp_layer_types=("sparse", "sparse")),
                     prompt_range=(4700, 5000), walk_len=4640)
+
+# And over the LFM2-MoE block, the same class with a third kind of operator:
+# the published widths and vocabulary, the two dense conv layers and one
+# period (attention with rope and QK norm over two key heads to a row, then
+# three conv layers), one chip's share of the experts (1.5 GB of weights).
+# The pool's per-slot state is the ``conv`` arena alone.
+LFM2_MOE = dict(HYBRID, config="Lfm2MoeConfig",
+                overrides=dict(
+                    layer_types=("conv", "conv", "full_attention", "conv",
+                                 "conv", "conv"),
+                    experts_held=8))
 
 # Largest |difference| of two logit rows over the largest |reference logit|.
 # bf16 keeps 8 mantissa bits (2^-8 per rounded op); over 28-36 layers of
@@ -718,10 +734,12 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
     c = be.metrics.counters
     tokens = sum(len(p) for p in prompts) \
         + geo["n_requests"] * (geo["new_tokens"] - 1)
+    # a recurrence's state (or none), or a convolution's window alone
+    kept = "conv" if set(be.pool.slot_state) == {"conv"} else "ssm"
     emit(phase="hybrid_serve", requests=len(rids), steps=wave["steps"],
          wall_s=round(wave["wall_s"], 3), first_call=wave["first_call"],
-         ssm_rows_advanced=c.get("ssm_rows_advanced", 0.0),
-         ssm_states_reset=c.get("ssm_states_reset", 0.0),
+         **{f"{kept}_{k}": c.get(f"{kept}_{k}", 0.0)
+            for k in ("rows_advanced", "states_reset")},
          kv_rows_appended=c.get("kv_rows_appended", 0.0),
          prefill_rows_extra=c.get("prefill_rows_extra", 0.0),
          moe_pairs_held=c.get("moe_pairs_held"),
@@ -730,8 +748,8 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
     want = {"kv_rows_appended":
             tokens * (cfg.n_cache_layers + window_layers)}
     if state_layers:
-        want.update(ssm_rows_advanced=tokens * state_layers,
-                    ssm_states_reset=geo["n_requests"])
+        want.update({f"{kept}_rows_advanced": tokens * state_layers,
+                     f"{kept}_states_reset": geo["n_requests"]})
     check(all(c.get(k) == n for k, n in want.items()),
           f"the step's counts do not add up to {tokens} tokens of "
           f"{geo['n_requests']} requests: {want}")
@@ -768,11 +786,11 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
 def run_served_blocks(devices, geo: dict, caches: _CacheEvents) -> None:
     """The one-chip smoke: the dense model, then (its buffers dropped) the
     hybrid block, then the Nemotron-H block, then the EXAONE-MoE block, then
-    the SmallThinker block."""
+    the SmallThinker block, then the LFM2-MoE block."""
     import gc
 
     run_one_chip(devices, geo, caches)
-    for block in (HYBRID, NEMOTRON_H, EXAONE_MOE, SMALLTHINKER):
+    for block in (HYBRID, NEMOTRON_H, EXAONE_MOE, SMALLTHINKER, LFM2_MOE):
         gc.collect()
         run_hybrid(devices, block, caches)
 

@@ -599,3 +599,107 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, model, kind):
     # weights, the pool and the step's own buffers fit the chip's 16 GB
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.75e9
+
+
+def test_short_conv_update_compiles_in_place(one_chip):
+    """LFM2-24B-A2B's cell: 30 layers of windows (two inputs of 2,048 a
+    slot, bfloat16), 32 slots in two grid steps of 16 rows."""
+    from triton_distributed_tpu.kernels.short_conv_update import (
+        short_conv_update,
+    )
+
+    d, layers = 2048, 30
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(
+        lambda ar, ly, bcx, w, live, fresh: short_conv_update(
+            ar, ly, bcx, w, live, fresh, interpret=False),
+        donate_argnums=0).lower(
+        _sds((layers, HYB_SLOTS, 2 * d), bf16, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((HYB_SLOTS, 3 * d), bf16, one_chip),
+        _sds((3, d), bf16, one_chip),
+        _sds((HYB_SLOTS,), bool, one_chip),
+        _sds((HYB_SLOTS,), bool, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "short_conv_update" in text
+    mem = compiled.memory_analysis()
+    # the arena is the result: no second one, and nothing beside it
+    assert mem.alias_size_in_bytes == layers * HYB_SLOTS * 2 * d * 2
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_lfm2_step_compiles_with_its_windows_in_place(topo, kind):
+    """The whole served step of lfm2-24b-a2b-ep8 (all 40 layers, every
+    width, 8 of 64 experts held, the whole tied vocabulary), as
+    ``BatchEngine`` builds it around ``forward_paged``: it compiles with the
+    update's kernel and the grouped product in it, every arena of the pool's
+    state (ten layers of packed rows, thirty of windows and NO recurrence's)
+    is aliased in to out, and the step's temporaries hold no copy of an
+    arena or of a weight stack (the smallest stack of matrices is the
+    attention layers' 0.2 GB)."""
+    import json
+
+    from perfbench.families import lfm2_moe as family
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.models.exaone_moe import ExaoneMoe
+    from triton_distributed_tpu.serving.kv_pool import (
+        paged_state_shapes,
+        paged_state_specs,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "perfbench/configs/lfm2-24b-a2b-ep8.json")) as f:
+        file = json.load(f)
+    cfg = family.program_config(file, family.sizes(file))
+    fleet = file["serve"]["fleet"]
+    assert (fleet["n_slots"], fleet["n_blocks"], fleet["block_size"],
+            fleet["prefill_chunk"]) == (HYB_SLOTS, HYB_BLOCKS, BLOCK, CHUNK)
+    mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
+    here = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, here), tree)
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    params = placed(jax.eval_shape(
+        lambda k: ExaoneMoe(cfg).init(k, mesh), jax.random.PRNGKey(0)))
+    state = placed(paged_state_shapes(
+        cfg, n_blocks=HYB_BLOCKS, block_size=BLOCK, n_slots=HYB_SLOTS))
+    assert state.ssm is None and state.conv.shape == (30, HYB_SLOTS, 4096)
+    state_bytes = nbytes(state)
+    assert 1.09e9 < state_bytes < 1.11e9
+    assert nbytes(params) == pytest.approx(7.52e9, rel=2e-3)
+    engine = Engine(cfg, mesh=mesh, params=params, mode="dist",
+                    interpret=False)
+    step = jax.jit(
+        engine._make_sm("dist", paged=kind, paged_attn="fused",
+                        state_specs=paged_state_specs(cfg)),
+        donate_argnums=(2,))
+    slots = (_sds((HYB_SLOTS,), jnp.int32, here),
+             _sds((HYB_SLOTS, MAX_BLOCKS), jnp.int32, here),
+             _sds((HYB_SLOTS,), bool, here))
+    if kind == "decode":
+        args = (_sds((HYB_SLOTS, 1), jnp.int32, here), state, *slots)
+    else:
+        ids = (_sds((HYB_SLOTS,), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, CHUNK), jnp.int32, here),
+               _sds((HYB_PREFILL_ROWS, 3), jnp.int32, here))
+        args = (ids, state, *slots, _sds((HYB_SLOTS,), jnp.int32, here))
+    compiled = step.lower(params, *args).compile()
+    text = compiled.as_text()
+    # seven layer bodies: five window updates, two block walks, two grouped
+    # products in each of five expert layers
+    assert text.count("tpu_custom_call") >= 17
+    assert "short_conv_update" in text and "moe_grouped_gemm" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes
+    # weights, the pool and the step's own buffers fit the chip's 16 GB
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.75e9

@@ -195,6 +195,39 @@ def test_smallthinker_phase_passes_at_tiny(capsys,
     assert [r.get("phase") for r in records].count("numeric") == 1
 
 
+def test_lfm2_moe_phase_passes_at_tiny(capsys, restore_compile_cache_config):
+    """The same phase over the LFM2-MoE block (the EXAONE walk with a conv
+    operator) at tiny float32 sizes: six conv layers whose whole state is a
+    window a slot, the one-token update against the chunk shape, a slot's
+    rows chained through the window and, dealt two a step against one a
+    step, the same to the bit."""
+    import dataclasses
+
+    from triton_distributed_tpu.models.config import Lfm2MoeConfig
+
+    geo = dict(chip_smoke.LFM2_MOE, interpret=None, paged_attn="gather",
+               n_slots=6, block_size=4, prefill_chunk=8, n_requests=3,
+               prompt_range=(10, 20), new_tokens=3, walk_len=10,
+               overrides=dataclasses.asdict(Lfm2MoeConfig.tiny()))
+    assert chip_smoke.LFM2_MOE["overrides"]["layer_types"].count("conv") == 5
+    rc = chip_smoke.smoke(chip_smoke.run_hybrid, jax.devices()[:1], geo)
+    records = [json.loads(line)
+               for line in capsys.readouterr().out.strip().splitlines()]
+    assert rc == 0 and records[-1]["ok"] is True
+    assert records[0]["state_layers"] == 6 and records[0]["cache_layers"] == 2
+    assert records[0]["slot_state_bytes"] == 6 * 6 * 2 * 64 * 4
+    assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
+    assert records[1]["conv_states_reset"] == 3
+    assert "ssm_states_reset" not in records[1]
+    assert records[1]["moe_pairs_held"] > 0 == records[1]["moe_dropped_pairs"]
+    assert records[1]["prefill_rows_extra"] > 0     # a slot's rows chained
+    assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4
+    assert records[2]["prefill_routed_apart"] == [] \
+        == records[2]["decode_routed_apart"]
+    assert "2 a step vs one a step" in records[3]["compared"]
+    assert records[3]["prefill_rel"] == 0 == records[3]["decode_rel"]
+
+
 def _routing(margin, experts=(4, 9, 2), base=0.5):
     """One layer's record for three prompts: prompt 0's router ``margin``
     apart at its top-2 boundary and choosing ``experts[:2]``, the others'

@@ -87,6 +87,9 @@ OF_A_FAMILY = {
     "smallthinker": re.compile(
         r"smallthinker|primary_experts|primary_router|rope_layout|"
         r"moe_ffn_hidden|reglu", re.IGNORECASE),
+    "lfm2_moe": re.compile(
+        r"lfm2|Lfm2MoeConfig|conv_L_cache|num_dense_layers|use_expert_bias",
+        re.IGNORECASE),
 }
 
 
@@ -214,7 +217,9 @@ def test_the_span_readers_entries_name_their_cells_and_find_their_readers():
     assert names[at + 4:] == ["window_attn_device_share",
                               "window_attn_roofline", "mixed_steps_share",
                               "collective_device_share",
-                              "ici_bytes_per_step"]
+                              "ici_bytes_per_step",
+                              "short_conv_device_share",
+                              "short_conv_roofline"]
 
 
 def test_the_window_readers_arithmetic_and_silence_on_an_older_program():
@@ -551,3 +556,188 @@ def test_the_collective_readers_know_the_kernels_by_name():
     assert ici_bytes_per_step.read(
         spans([span("engine.dispatch", kind="decode")])) is None
     assert ici_bytes_per_step.read(types.SimpleNamespace(trace=None)) is None
+
+
+LFM2 = "lfm2-24b-a2b-ep8.reasoning"
+
+
+def test_the_lfm2_cell_is_files_and_its_configuration_states_its_cut():
+    """``lfm2-24b-a2b-ep8.reasoning`` by name: the catalog's row of
+    LFM2-24B-A2B under its own keys at its published values, ``num_experts``
+    (8 held of 64) and ``max_position_embeddings`` alone reduced, the cut
+    written out; the benchmark's own ``reasoning`` mix unchanged on one
+    chip; the readers it reports found by name, its two new ones the LAST
+    two of ``per_layer``."""
+    from perfbench import core, families
+    from perfbench.traffic_kinds.closed_loop import Plan
+
+    bench = core.load_json(core.ROOT, "BENCHMARK.json")
+    entry, = [c for c in bench["configs"] if c["name"] == "lfm2-24b-a2b-ep8"]
+    assert entry["reduced"] == ["num_experts", "max_position_embeddings"]
+    spec = core.load_cell(LFM2)
+    assert spec["cell"]["chips"] == 1 and spec["cell"]["traffic"] == "reasoning"
+    assert len(spec["cell"]["why"]) <= 200
+    cfg = spec["config"]
+    assert cfg["source"] == entry["source"] == \
+        "https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json"
+    period = ["full_attention", "conv", "conv", "conv"]
+    published = {
+        "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+        "intermediate_size": 11776,
+        "layer_types": ["conv", "conv"] + period * 9
+        + ["full_attention", "conv"],
+        "model_type": "lfm2_moe", "moe_intermediate_size": 1536,
+        "norm_eps": 1e-05, "norm_topk_prob": True, "num_attention_heads": 32,
+        "num_dense_layers": 2, "num_experts_per_tok": 4,
+        "num_hidden_layers": 40, "num_key_value_heads": 8,
+        "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+        "routed_scaling_factor": 1, "use_expert_bias": True,
+        "vocab_size": 65536}
+    assert {k: cfg[k] for k in published} == published
+    assert (cfg["num_experts"], cfg["num_experts_published"],
+            cfg["num_experts_held"], cfg["num_experts_lo"]) == (8, 64, 8, 0)
+    assert cfg["max_position_embeddings"] == 4096
+    assert cfg["reduced"] == entry["reduced"] and cfg["family"] == "lfm2_moe"
+    assert {"num_experts", "max_position_embeddings", "head_dim",
+            "tie_word_embeddings", "torch_dtype", "conv_operator",
+            "attention_operator", "layer", "routing", "conv_state_dtype",
+            "weights"} <= set(cfg["assumed"])
+    assert "of EIGHT" in cfg["deployment"] and cfg["chips"] == 1
+    assert cfg["serve"] == {
+        "mesh": {"tp": 1}, "engine": {"mode": "dist", "interpret": False},
+        "fleet": {"n_replicas": 1, "n_slots": 32, "block_size": 16,
+                  "prefill_chunk": 64, "n_blocks": 3328,
+                  "paged_attn": "fused"}}
+    # the mix is the file the six ``reasoning`` cells share, unchanged
+    assert spec["traffic"] == core.load_cell("qwen3-1.7b.reasoning")["traffic"]
+    family = families.load_family(cfg)
+    assert family.__name__ == "perfbench.families.lfm2_moe"
+    sizes = family.sizes(cfg)
+    standing = Plan(spec["traffic"], seed=5, seconds=40,
+                    vocab=sizes.vocab_size, max_total=sizes.max_length,
+                    n_slots=32).standing()
+    assert sum(len(p.prompt) for p in standing) == 35_889
+    assert set(spec["limits"]) == {"gap_max", "gap_mean"}
+    assert set(spec["limits"]) < set(core.load_json(
+        core.ROOT, "perfbench", "cells", LFM2 + ".json")["why"])
+    assert spec["sample"]["requests"] == 3
+    assert {m["name"] for m in spec["end_to_end"]} == {
+        "itl_p95_ms", "out_tokens_per_s", "setup_s"}
+    reported = [m["name"] for m in spec["per_layer"]]
+    assert set(reported) == {
+        "decode_occupancy", "kv_used_share_peak", "preemptions",
+        "decode_step_ms", "mixed_step_ms.reasoning",
+        "paged_attn_device_share.reasoning", "decode_step_roofline",
+        "moe_ffn_device_share", "moe_ffn_roofline",
+        "host_turn_ms.reasoning", "host_dispatch_ms", "host_observe_ms",
+        "short_conv_device_share", "short_conv_roofline"}
+    for name in reported:
+        mod = core.reader_module("layer_metrics", name)
+        assert callable(__import__(mod, fromlist=["read"]).read)
+    last = bench["per_layer"][-2:]
+    assert [m["name"] for m in last] == ["short_conv_device_share",
+                                         "short_conv_roofline"]
+    assert all(m["workloads"] == [LFM2] and m["layer"] == "kernels"
+               and m["moves"] == "out_tokens_per_s"
+               and m["source"] == "device_trace" for m in last)
+
+
+def test_the_lfm2_family_passes_the_harness_checks_at_a_tiny_size():
+    """The family at a tiny float32 size through the harness's own
+    comparison (``check.compare``): what the PROGRAM serves (``BatchEngine``,
+    prefill then decode through the pool and the windows) is the reference's
+    best at every position, and the float8 control, the reference in the
+    precision below put in the program's place, is not correct."""
+    import jax
+    import numpy as np
+
+    from conftest import PLAIN_PATH
+    from perfbench import check
+    from perfbench.families import lfm2_moe as family
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.runtime.mesh import make_mesh
+    from triton_distributed_tpu.serving.batch_engine import BatchEngine
+
+    sizes = family.Sizes(
+        vocab_size=256, d_model=64, n_layers=6,
+        conv=(True, True, False, True, True, True), dense_layers=2, taps=3,
+        heads=4, kv_heads=2, head_dim=16, dense_width=96, expert_width=32,
+        router_width=8, held=8, lo=0, topk=2, scaling=1.0, norm_topk=True,
+        theta=1e4, eps=1e-5, max_length=128, dtype="float32")
+    file = {"source": "t", "conv_bias": False, "use_expert_bias": True,
+            "tie_word_embeddings": True}
+    mesh = make_mesh({"tp": 1}, devices=jax.devices()[:1], set_default=False)
+    mcfg, params = family.program(file, sizes, 17, mesh, {})
+    be = BatchEngine(Engine(mcfg, mesh=mesh, params=params, mode="dist"),
+                     n_slots=2, n_blocks=64, block_size=4, prefill_chunk=8,
+                     **PLAIN_PATH)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (9, 30, 17)]
+    rids = [be.submit(p, 12) for p in prompts]
+    be.run()
+    sample = [(p, be.finished[r].output) for p, r in zip(prompts, rids)]
+    verdict = check.compare(family, sizes, 17, sample, jax.devices()[0],
+                            limits={"gap_max": 1e-3, "gap_mean": 1e-5},
+                            control=True)
+    assert verdict["correct"] is True and verdict["tokens"] == 36
+    assert verdict["compared"]["top1_share"] == 1.0
+    assert verdict["control_correct"] is False
+    assert verdict["control"]["gap_max"] > 3e-3
+
+
+def test_the_short_conv_readers_arithmetic_and_silence_on_an_older_program():
+    """The two readers this cell adds, on a hand-made record at the
+    published sizes: the kernel's device time by its ``name=``, the family's
+    bytes over the row-layers THE PROGRAM counted on its ``decode_step``
+    spans (a mixed step's count, which holds its chunks' tokens, is left
+    out). On a program that has no such kernel or no such attribute (the
+    parent commit), on a family without the count and on an untraced run
+    each returns None and does not raise."""
+    import types
+
+    from perfbench import core, families
+    from perfbench.layer_metrics import (
+        short_conv_device_share,
+        short_conv_roofline,
+    )
+
+    spec = core.load_cell(LFM2)
+    family = families.load_family(spec["config"])
+    sizes = family.sizes(spec["config"])
+
+    def span(name, **attrs):
+        return types.SimpleNamespace(name=name, attrs=attrs or None)
+
+    counted = [span("decode_step", conv_rows_advanced=960, decode_rows=32)
+               for _ in range(100)] \
+        + [span("mixed_step", conv_rows_advanced=960 + 30 * 448),
+           span("engine.dispatch", kind="decode")]
+
+    def record(ops_s, spans=counted, family=family, trace=True):
+        return core.Records(
+            t_open=0.0, t_close=11.0, t_end=12.0, setup_s=1.0, tracked=[],
+            steps=[], kv_live=[], counters={}, queue_wait_s=[], sizes=sizes,
+            family=family, n_slots=32, n_chips=1, device_kind="TPU v5 lite",
+            trace={"host_window": (10.0, 11.0), "busy_s": 1.0,
+                   "ops_s": ops_s, "program_spans": spans} if trace else None)
+
+    ops = {"short_conv_update.3": 0.012, "short_conv_update.7": 0.003,
+           "moe_grouped_gemm.4": 0.5, "ssm_state_update.2": 0.1}
+    rec = record(ops)
+    assert short_conv_device_share.read(rec) == pytest.approx(1.5)
+    # 100 steps x 32 rows x 30 layers x 3 x 2,048 values of 2 bytes at
+    # 819 GB/s: the bytes bound it, not the 7 operations a channel
+    floor_s = 100 * 960 * 3 * 2048 * 2 / 819e9
+    assert short_conv_roofline.read(rec) == pytest.approx(
+        100 * floor_s / 0.015)
+    assert short_conv_roofline.read(rec) < 100
+    # the parent: no such kernel in the trace, no such attribute on a span
+    old = record({"moe_grouped_gemm.4": 0.5})
+    bare = record(ops, spans=[span("decode_step", decode_rows=32)])
+    for reader in (short_conv_device_share, short_conv_roofline):
+        assert reader.read(old) is None
+        assert reader.read(record(ops, trace=False)) is None
+    assert short_conv_roofline.read(bare) is None
+    assert short_conv_roofline.read(record(ops, spans=None)) is None
+    assert short_conv_roofline.read(
+        record(ops, family=types.SimpleNamespace())) is None

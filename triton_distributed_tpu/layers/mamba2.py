@@ -34,14 +34,17 @@ host.
 Several rows of a gathered block (the mixed step's prefill block, whose
 rows the host deals: ``nn.paged_token_blocks``) may belong to ONE slot,
 consecutive chunks of its prompt in consecutive rows, every row but the
-last full. Such rows are CHAINED (``chained_rows``, read from the block's
-own slots, offsets and live lengths): row k starts from the state row k-1
+last full. Such rows are CHAINED (``short_conv.chained_rows``, read from the
+block's own slots, offsets and live lengths): row k starts from the state row k-1
 ENDS on (Mamba-2's own recurrence from chunk to chunk, ``chunk_scan``'s
 pass over the rows, float32 throughout) and from the window row k-1 leaves,
 its last ``d_conv - 1`` inputs; only the FIRST row of a run reads the arena
 (or starts from zero) and only the LAST writes it. A block whose live rows
 are all different slots has no chained row and is one gather, one scan of
-independent rows and one scatter.
+independent rows and one scatter. The window's part of all this (read,
+``fresh``, chained, only a run's last row writes) is ``layers.short_conv``'s
+``slot_rows`` and ``conv_window``, which the gated short convolution calls
+too; the bias and the SiLU stay here.
 """
 
 from __future__ import annotations
@@ -53,21 +56,9 @@ import jax
 import jax.numpy as jnp
 
 from triton_distributed_tpu.kernels.ssm_update import ssm_state_update
+from triton_distributed_tpu.layers.short_conv import conv_window, slot_rows
 
 HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def chained_rows(slots, offsets, n_live, L: int):
-    """(R,) bool: row k of a gathered block goes on where row k-1 ends,
-    read from the block's own operands: the same slot, both rows live, row
-    k-1 full, and row k's cache length the one row k-1 leaves. A dead row
-    (no live position) is never chained and nothing is chained to it."""
-    def before(a):
-        return jnp.roll(a, 1, axis=0)
-
-    return ((jnp.arange(slots.shape[0]) > 0) & (slots == before(slots))
-            & (n_live > 0) & (before(n_live) == L)
-            & (offsets == before(offsets) + L))
 
 
 def chunk_scan(x, dt, a_log_step, b, c, s0, chained=None):
@@ -170,23 +161,6 @@ class Mamba2:
 
     # -- the pieces ---------------------------------------------------------
 
-    def _conv(self, params, window, xbc, n_live):
-        """Causal depthwise convolution over ``[window ; xbc]`` and the
-        window it leaves. window (R, K-1, C), oldest first; xbc (R, L, C);
-        n_live (R,) live positions of each row (its first ``n_live``).
-        Returns ``(silu(conv) (R, L, C) float32, window (R, K-1, C))``: the
-        last K-1 LIVE inputs, which for a row with none is the window it
-        came with."""
-        K, L = self.d_conv, xbc.shape[1]
-        seq = jnp.concatenate([window.astype(jnp.float32),
-                               xbc.astype(jnp.float32)], axis=1)
-        w = params["conv_w"].astype(jnp.float32)
-        out = params["conv_b"].astype(jnp.float32) + sum(
-            w[k] * seq[:, k:k + L] for k in range(K))
-        take = n_live[:, None] + jnp.arange(K - 1)[None]          # (R, K-1)
-        window = jnp.take_along_axis(seq, take[..., None], axis=1)
-        return jax.nn.silu(out), window
-
     def _block(self, params, zxbcdt, state, blk, layer, interpret):
         """One block of the token batch: ``(y (rows * L, d_inner) float32,
         state)``, y before the gate and the norm."""
@@ -195,74 +169,33 @@ class Mamba2:
         di, C, K = self.d_inner, self.conv_dim, self.d_conv
         part = zxbcdt[blk.start:blk.stop].reshape(R, L, -1)
         xbc, dt = part[..., di:di + C], part[..., di + C:]
-        live = blk.valid().reshape(R, L)
-        n_live = jnp.sum(live, axis=1)
-        fresh = (blk.offsets == 0) & (n_live > 0)  # starts from zero
-        # Where row b is slot b (the decode block) this layer's windows are
-        # one slice of the arena: read and written as a slice, the dead
-        # rows' put back as they were. As a gather and a scatter of 32 rows
-        # the same cost 0.9 ms a decode step of 36 layers on the chip.
-        whole = blk.slots is None
-        slots = jnp.arange(R) if whole else blk.slots
-        # a row with nothing live writes nothing (a dead row of a gathered
-        # block names no slot of its own): out of range, dropped
-        writes = n_live > 0
-        chained = None
-        if not whole:
-            if L < K - 1:
-                raise ValueError(
-                    f"prefill_chunk = {L} is below d_conv - 1 = {K - 1}: a "
-                    f"row of the prefill block has to hold the whole window "
-                    f"it hands to the slot's next row")
-            # Of a slot's run of rows only the last writes the arenas (a
-            # scatter with a repeated index has no defined winner): not a
-            # row that is followed (row 0 is never chained, so the roll
-            # brings the last row a False).
-            chained = chained_rows(slots, blk.offsets, n_live, L)
-            writes &= ~jnp.roll(chained, -1)
-        put = jnp.where(writes, slots, state.conv.shape[1])
-
-        held = (jax.lax.dynamic_index_in_dim(state.conv, layer, 0, False)
-                if whole else state.conv[layer, slots])
-        window = jnp.where(fresh[:, None, None], 0,
-                           held.reshape(R, K - 1, C))
-        if chained is not None:
-            # a chained row's window is the full row before's last K-1
-            # inputs, in the arena's dtype as through the arena
-            window = jnp.where(
-                chained[:, None, None],
-                jnp.roll(xbc[:, L - (K - 1):], 1, axis=0).astype(held.dtype),
-                window)
-        conv, window = self._conv(params, window, xbc, n_live)
-        window = window.reshape(R, -1).astype(held.dtype)
-        if whole:
-            conv_arena = jax.lax.dynamic_update_index_in_dim(
-                state.conv, jnp.where((n_live > 0)[:, None], window, held),
-                layer, 0)
-        else:
-            conv_arena = state.conv.at[layer, put].set(window, mode="drop")
+        rows = slot_rows(blk, K)
+        taps, conv_arena = conv_window(state.conv, layer, rows, xbc,
+                                       params["conv_w"])
+        conv = jax.nn.silu(params["conv_b"].astype(jnp.float32) + taps)
 
         x = conv[..., :di].reshape(R, L, H, P)
         b = conv[..., di:di + G * N].reshape(R, L, G, N)
         c = conv[..., di + G * N:].reshape(R, L, G, N)
         dt = jax.nn.softplus(dt.astype(jnp.float32)
                              + params["dt_bias"].astype(jnp.float32))
-        dt = jnp.where(live[..., None], dt, 0.0)                 # (R, L, H)
+        dt = jnp.where(rows.live[..., None], dt, 0.0)                 # (R, L, H)
         a_log_step = dt * -jnp.exp(params["a_log"].astype(jnp.float32))
-        if L == 1 and whole:
+        if L == 1 and rows.whole:
             # one token a slot: in place on the arena. A fresh row's old
             # state is multiplied by 0 and not by its decay; a dead row's
             # by 1 (its step is 0).
-            decay = jnp.where(fresh[:, None], 0.0, jnp.exp(a_log_step[:, 0]))
+            decay = jnp.where(rows.fresh[:, None], 0.0,
+                              jnp.exp(a_log_step[:, 0]))
             ssm, y = ssm_state_update(
                 state.ssm, layer, decay, dt[:, 0, :, None] * x[:, 0],
                 b[:, 0], c[:, 0], interpret=interpret)
             y = y[:, None]
         else:
-            s0 = jnp.where(fresh[:, None, None, None], 0.0,
-                           state.ssm[layer, slots])
-            y, s = chunk_scan(x, dt, a_log_step, b, c, s0, chained)
-            ssm = state.ssm.at[layer, put].set(s, mode="drop")
+            s0 = jnp.where(rows.fresh[:, None, None, None], 0.0,
+                           state.ssm[layer, rows.slots])
+            y, s = chunk_scan(x, dt, a_log_step, b, c, s0, rows.chained)
+            ssm = state.ssm.at[layer, rows.put(state.ssm)].set(s, mode="drop")
         y = y + params["d_skip"].astype(jnp.float32)[:, None] * x
         state = dataclasses.replace(state, ssm=ssm, conv=conv_arena)
         return y.reshape(R * L, di), state

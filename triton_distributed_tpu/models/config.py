@@ -25,6 +25,11 @@ class from the class of the object it is given:
   layers over a WINDOW of the last keys with rope and the others over every
   key without; a dense SwiGLU or sigmoid-routed experts with a shared expert
   after it), run by ``models.exaone_moe.ExaoneMoe``.
+- ``Lfm2MoeConfig``: the LFM2-MoE block (a gated short convolution that
+  keeps a window of two inputs a sequence, or rope'd grouped-query attention
+  with a per-head norm; a dense SwiGLU or sigmoid-routed experts chosen
+  under a bias after it), run by the same ``ExaoneMoe`` walk with a third
+  kind of operator.
 
 Each states what ``serving.kv_pool.KVPool`` builds the pool's state from:
 ``kv_row_shapes`` (what one token's row of each row arena looks like),
@@ -95,6 +100,15 @@ class ModelConfig:
 
 
 LANE = 128      # a cache row is padded to a multiple of the chip's lane count
+
+
+def packed_key_heads(n_kv_heads: int, head_dim: int) -> int:
+    """Key heads that share one row of the pool: heads narrower than the
+    chip's lane count are packed side by side into a lane-wide row (8 heads
+    of 64 are 4 rows of 128), so that the pool holds no padding and the
+    block walk moves whole lane tiles."""
+    return max(p for p in range(1, max(1, LANE // head_dim) + 1)
+               if n_kv_heads % p == 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,12 +262,7 @@ class GraniteHybridConfig:
 
     @property
     def kv_pack(self) -> int:
-        """Key heads that share one row of the pool: heads narrower than the
-        chip's lane count are packed side by side into a lane-wide row
-        (8 heads of 64 are 4 rows of 128), so that the pool holds no padding
-        and the block walk moves whole lane tiles."""
-        return max(p for p in range(1, max(1, LANE // self.head_dim) + 1)
-                   if self.n_kv_heads % p == 0)
+        return packed_key_heads(self.n_kv_heads, self.head_dim)
 
     @property
     def kv_row_shapes(self):
@@ -552,7 +561,15 @@ class ExaoneMoeConfig:
     def window(self) -> int:
         return max(self.sliding_windows)
 
+    # What the walk reads of a configuration and this block states one way
+    # (``Lfm2MoeConfig`` states each otherwise): no per-slot state, a key
+    # head a row of the pool, no position embedding on the full layers, an
+    # untied head, no selection bias.
     slot_state_shapes = None
+    kv_pack = 1
+    rope_full = False
+    tie_embeddings = False
+    expert_bias = False
 
     @classmethod
     def smallthinker(cls, **overrides) -> "ExaoneMoeConfig":
@@ -588,6 +605,154 @@ class ExaoneMoeConfig:
             mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
             n_heads=4, n_kv_heads=2, head_dim=16, d_ff=96, moe_d_ff=32,
             n_experts=8, n_experts_per_tok=2, rope_theta=1e4, max_length=64,
+            dtype=jnp.float32), **overrides})
+
+
+_LFM2_PERIOD = ("full_attention",) + ("conv",) * 3
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """The LFM2-MoE decoder (HF ``lfm2_moe``; HF key in brackets). Defaults
+    are LFM2-24B-A2B's public ``config.json``. A layer is an operator and an
+    FFN, each under its own RMSNorm. ``layer_types`` names each layer's
+    operator: ``"conv"``, the gated short convolution
+    (``layers.short_conv.ShortConv``: ``conv_kernel`` [conv_L_cache] taps a
+    channel, whose last ``conv_kernel - 1`` inputs are ALL a sequence keeps
+    of the layer), or ``"full_attention"``, grouped-query attention over
+    every key with a per-head RMSNorm on queries and keys and rope. The
+    first ``n_dense_layers`` [num_dense_layers] carry a SwiGLU of ``d_ff``,
+    the others ``n_experts`` sigmoid-routed SwiGLU experts of ``moe_d_ff``,
+    the ``n_experts_per_tok`` largest of score + bias [use_expert_bias]
+    weighted by the unbiased scores over their sum; no shared expert. The
+    head is the embedding table [tie_word_embeddings, the family's
+    convention].
+
+    ``experts_held`` / ``experts_lo`` as ``DeepseekV3Config`` has them: this
+    device is one chip's share of an expert-parallel deployment and holds
+    the routed experts ``[experts_lo, experts_lo + experts_held)`` of every
+    sparse layer; the router keeps its published width.
+
+    The pool it describes: ``kv_row_shapes`` rows of two packed key heads
+    (``kv_pack``) for K and for V in the ``n_cache_layers`` attention
+    layers, and ONE per-slot arena, ``conv``, as deep as the conv layers
+    (``n_state_layers``): ``slot_state_shapes`` names no ``ssm``."""
+
+    model_name: str = "LiquidAI/LFM2-24B-A2B"
+    vocab_size: int = 65_536
+    d_model: int = 2048                # hidden_size
+    layer_types: tuple = ("conv", "conv") + _LFM2_PERIOD * 9 \
+        + ("full_attention", "conv")
+    n_dense_layers: int = 2            # num_dense_layers
+    conv_kernel: int = 3               # conv_L_cache
+    conv_bias: bool = False
+    n_heads: int = 32                  # num_attention_heads
+    n_kv_heads: int = 8                # num_key_value_heads
+    d_ff: int = 11_776                 # intermediate_size (dense layers)
+    moe_d_ff: int = 1536               # moe_intermediate_size
+    n_experts: int = 64                # num_experts: the router's width
+    n_experts_per_tok: int = 4         # num_experts_per_tok
+    expert_bias: bool = True           # use_expert_bias
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    experts_held: int | None = None
+    experts_lo: int = 0
+    rope_theta: float = 1e6            # rope_parameters.rope_theta
+    rms_eps: float = 1e-5              # norm_eps
+    tie_embeddings: bool = True
+    max_length: int = 4096
+    dtype: jnp.dtype = jnp.bfloat16
+
+    # The block's own, where ``ExaoneMoeConfig`` has a field to read.
+    qk_norm = True
+    rope_full = True
+    n_shared_experts = 0
+    scoring = "sigmoid"
+    expert_activation = "swiglu"
+    router_input = "post_attn_norm"
+    n_window_layers = 0
+    window = 0
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - {"conv", "full_attention"}
+        if bad or not self.layer_types:
+            raise ValueError(f"layer_types names {sorted(bad)}; a layer is "
+                             f"'conv' or 'full_attention'")
+        if self.conv_bias:
+            raise NotImplementedError(
+                "conv_bias: the biases of the short convolution and of its "
+                "two projections are not built (LFM2's published "
+                "configurations state false)")
+        if self.conv_kernel < 2 or self.d_model % self.n_heads \
+                or self.n_heads % self.n_kv_heads \
+                or not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError("heads, taps or dense layers do not fit the "
+                             "widths and the layers given")
+        if not (0 <= self.experts_lo and self.n_held >= 1
+                and self.experts_lo + self.n_held <= self.n_experts):
+            raise ValueError(
+                f"experts held [{self.experts_lo}, "
+                f"{self.experts_lo + self.n_held}) do not lie inside the "
+                f"router's {self.n_experts}")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """``(operator, ffn)`` a layer: ``"conv"`` | ``"full"`` and
+        ``"dense"`` | ``"moe"``."""
+        return tuple(
+            ("conv" if t == "conv" else "full",
+             "dense" if i < self.n_dense_layers else "moe")
+            for i, t in enumerate(self.layer_types))
+
+    @property
+    def kv_pack(self) -> int:
+        return packed_key_heads(self.n_kv_heads, self.head_dim)
+
+    @property
+    def kv_row_shapes(self):
+        row = (self.n_kv_heads // self.kv_pack, self.kv_pack * self.head_dim)
+        return row, row
+
+    @property
+    def n_cache_layers(self) -> int:
+        return self.layer_types.count("full_attention")
+
+    @property
+    def n_state_layers(self) -> int:
+        return self.layer_types.count("conv")
+
+    @property
+    def slot_state_shapes(self):
+        """What a conv layer keeps for one sequence: the last
+        ``conv_kernel - 1`` values of ``B * X``, oldest first, side by
+        side. Nothing that grows, no recurrence."""
+        return {"conv": (((self.conv_kernel - 1) * self.d_model,),
+                         self.dtype)}
+
+    @classmethod
+    def tiny(cls, **overrides) -> "Lfm2MoeConfig":
+        """Tiny float32 sizes for tests (not a real checkpoint): two dense
+        conv layers, one period and a tail of full, conv; two key heads of
+        16 packed into one row of the pool."""
+        return cls(**{**dict(
+            model_name="tiny-lfm2-moe", vocab_size=128, d_model=64,
+            layer_types=("conv", "conv", "full_attention", "conv", "conv",
+                         "conv", "full_attention", "conv"),
+            n_heads=4, n_kv_heads=2, d_ff=96, moe_d_ff=32, n_experts=8,
+            n_experts_per_tok=2, rope_theta=1e4, max_length=64,
             dtype=jnp.float32), **overrides})
 
 

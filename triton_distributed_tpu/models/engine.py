@@ -27,6 +27,7 @@ from triton_distributed_tpu.models.config import (
     DeepseekV3Config,
     ExaoneMoeConfig,
     GraniteHybridConfig,
+    Lfm2MoeConfig,
     ModelConfig,
     NemotronHConfig,
 )
@@ -52,7 +53,7 @@ def model_for(config, *, block_n: int = 256):
         from triton_distributed_tpu.models.nemotron_h import NemotronH
 
         return NemotronH(config)
-    if isinstance(config, ExaoneMoeConfig):
+    if isinstance(config, (ExaoneMoeConfig, Lfm2MoeConfig)):
         from triton_distributed_tpu.models.exaone_moe import ExaoneMoe
 
         return ExaoneMoe(config)
@@ -62,7 +63,7 @@ def model_for(config, *, block_n: int = 256):
 class Engine:
     def __init__(self, config: ModelConfig | DeepseekV3Config
                  | GraniteHybridConfig | NemotronHConfig
-                 | ExaoneMoeConfig, *,
+                 | ExaoneMoeConfig | Lfm2MoeConfig, *,
                  mesh: Mesh | None = None,
                  mode: str = "dist", prefill_mode: str | None = None,
                  temperature: float = 0.0, top_p: float = 1.0,

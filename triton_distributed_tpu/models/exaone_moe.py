@@ -1,10 +1,11 @@
 """EXAONE-MoE decoder stack: attention in every layer, of TWO kinds that keep
 their rows differently, and after it a dense SwiGLU or sigmoid-routed experts
 with a shared expert. The fifth model class behind ``Engine``
-(``models.engine`` picks it when it is given an ``ExaoneMoeConfig``), with
-the contract ``BatchEngine`` and ``Engine._make_sm`` use: ``axis``,
-``param_specs``, ``init``, ``step_stats`` and ``forward_paged`` (the pool's
-state in and out whole).
+(``models.engine`` picks it when it is given an ``ExaoneMoeConfig`` or an
+``Lfm2MoeConfig``: "A THIRD OPERATOR" below), with the contract
+``BatchEngine`` and ``Engine._make_sm`` use: ``axis``, ``param_specs``,
+``init``, ``step_stats`` and ``forward_paged`` (the pool's state in and out
+whole).
 
 The block (HF ``exaone_moe``), pre-norm::
 
@@ -51,22 +52,40 @@ are called, after attention; they depend on nothing attention computes, so
 the compiler is free to move them (nothing is claimed from where it puts
 them). A walk with no dense layer has no ``dense`` stack.
 
+A THIRD OPERATOR (LFM2-MoE, ``models.config.Lfm2MoeConfig``): a layer's
+operator may be ``"conv"``, ``layers.short_conv.ShortConv``, the gated short
+convolution, whose whole state is a window of ``conv_kernel - 1`` inputs a
+slot in the pool's ``conv`` arena (no ``ssm`` arena beside it), read and
+written at (the conv layers before it). Such a walk's attention layers are
+its other layers only, so attention's weights are a stack over THOSE (read
+at ``window + full`` layers before; with attention in every layer that is
+the layer's own index) and the two norms a stack over all layers. Four more
+things are then read from the configuration: rope on the full layers
+(``rope_full``), key heads packed two to a row of the pool (``kv_pack``), the
+embedding table as the head (``tie_embeddings``: no ``lm_head``) and a
+selection bias a sparse layer (``expert_bias``: ``moe.bias`` is a parameter;
+without it the sigmoid form is handed zeros).
+
 What is not built, and refused by name: more than one device (the ring
-storage is not sharded, and the experts' exchange over ICI does not run
-under ``BatchEngine``), speculative verify (a rejected draft's rows would
-have overwritten ring lines that the window still needs) and a quantized
+storage and the convolution's windows are not sharded, and the experts'
+exchange over ICI does not run under ``BatchEngine``), speculative verify
+(a rejected draft's rows would have overwritten ring lines that the window
+still needs, and would have entered a convolution's window) and a quantized
 pool. Not there to call: the contiguous ``Engine.serve`` cache, and the
 multi-token-prediction layer (the main model's logits do not depend on it).
 
 Parameters (all replicated)::
 
-    embed (V, d), final_norm (d,), lm_head (d, V)
-    attn:  stacked over ALL layers
-        input_norm, post_norm, attn {w_qkv, w_o[, q_norm, k_norm]}
+    embed (V, d), final_norm (d,)[, lm_head (d, V)]
+    attn:  input_norm, post_norm stacked over ALL layers;
+           attn {w_qkv, w_o[, q_norm, k_norm]} over the attention layers
+    conv:  stacked over the conv layers    {w_in (d, 3 d), conv_w (K, d),
+           w_out (d, d)}  (absent where the walk has none)
     dense: stacked over the dense layers   {w_gate_up (d, 2 ff), w_down}
            (absent where the walk has none)
     moe:   stacked over the sparse layers
-        router (d, E) f32, w_gate_up (held, d, 2 ffe), w_down (held, ffe, d)
+        router (d, E) f32[, bias (E,) f32], w_gate_up (held, d, 2 ffe),
+        w_down (held, ffe, d)
         [, shared {w_gate_up (d, 2 ffs), w_down (ffs, d)}]
 """
 
@@ -85,34 +104,46 @@ from triton_distributed_tpu.layers.moe_mlp import (
     HeldExpertsMoE,
     swiglu,
 )
+from triton_distributed_tpu.layers.short_conv import ShortConv, fresh_rows
 from triton_distributed_tpu.layers.tp_attn import TPAttn
-from triton_distributed_tpu.models.config import ExaoneMoeConfig
+from triton_distributed_tpu.models.config import ExaoneMoeConfig, Lfm2MoeConfig
 from triton_distributed_tpu.models.nemotron_h import pattern_segments
 from triton_distributed_tpu.runtime.compat import axis_size as _axis_size
 from triton_distributed_tpu.runtime.mesh import get_default_mesh
 
 #: What a walk counts: layers, and layers of each kind.
-COUNTED = ("layer", "window", "full", "dense", "moe")
+COUNTED = ("layer", "window", "full", "conv", "dense", "moe")
+#: What a walk with conv layers counts on the device beside the others.
+CONV_STATS = ("conv_rows_advanced", "conv_states_reset")
 
 
 @dataclasses.dataclass(frozen=True)
 class ExaoneMoe:
-    config: ExaoneMoeConfig
+    config: ExaoneMoeConfig | Lfm2MoeConfig
     axis: str = "tp"
-
-    #: Device-side counts a paged step returns as ``aux["stats"]`` (int32,
-    #: this order); ``BatchEngine`` adds them to its counters of the same
-    #: names: the sparse layers' four (``layers.moe_mlp.MOE_STATS``) and the
-    #: rows appended over all layers.
-    step_stats = MOE_STATS + ("kv_rows_appended",)
 
     @functools.cached_property
     def layer_counts(self) -> dict:
         """Layers by kind (``BatchEngine.stats_snapshot()["layers"]``):
-        the FFN's two and attention's two, each layer in one of each."""
+        the FFN's two and the operator's three, each layer in one of each."""
         kinds = self.config.layer_kinds
-        return {k: n for k in ("dense", "moe", "window", "full")
+        return {k: n for k in ("dense", "moe", "window", "full", "conv")
                 if (n := sum(k in pair for pair in kinds))}
+
+    @functools.cached_property
+    def n_attn_layers(self) -> int:
+        return self.config.n_layers - self.layer_counts.get("conv", 0)
+
+    @functools.cached_property
+    def step_stats(self) -> tuple:
+        """Device-side counts a paged step returns as ``aux["stats"]``
+        (int32, this order); ``BatchEngine`` adds them to its counters of
+        the same names: the sparse layers' four (``layers.moe_mlp
+        .MOE_STATS``), where the walk has conv layers the windows advanced
+        (a live token a conv layer) and those started from zero, and the
+        rows appended over the attention layers."""
+        conv = CONV_STATS if "conv" in self.layer_counts else ()
+        return MOE_STATS + conv + ("kv_rows_appended",)
 
     @functools.cached_property
     def moe_forms(self) -> dict:
@@ -133,13 +164,19 @@ class ExaoneMoe:
                       n_kv_heads=c.n_kv_heads, head_dim=c.head_dim,
                       axis=self.axis, dtype=c.dtype, rope_theta=c.rope_theta,
                       qk_norm=c.qk_norm, rms_eps=c.rms_eps,
-                      rope=window is not None, window=window)
+                      rope=window is not None or c.rope_full,
+                      kv_pack=c.kv_pack, window=window)
 
     @functools.cached_property
     def attn(self) -> dict:
         """The two builds of attention, by kind."""
         return {"window": self._attn(self.config.window or None),
                 "full": self._attn(None)}
+
+    @functools.cached_property
+    def conv(self) -> ShortConv:
+        c = self.config
+        return ShortConv(d_model=c.d_model, taps=c.conv_kernel)
 
     @functools.cached_property
     def moe(self) -> HeldExpertsMoE:
@@ -155,7 +192,7 @@ class ExaoneMoe:
 
     def param_shapes(self):
         """The parameter tree as ``(shape, fan_in)`` leaves; ``fan_in`` None
-        marks a norm weight."""
+        marks a norm weight, 0 the selection bias."""
         c, n = self.config, self.layer_counts
         d, dh = c.d_model, c.head_dim
         ffs = c.n_shared_experts * c.moe_d_ff
@@ -165,26 +202,30 @@ class ExaoneMoe:
                 lambda leaf: ((count, *leaf[0]), leaf[1]), tree,
                 is_leaf=lambda x: isinstance(x, tuple))
 
-        attn = {"input_norm": ((d,), None), "post_norm": ((d,), None),
-                "attn": {
-                    "w_qkv": ((d, (c.n_heads + 2 * c.n_kv_heads) * dh), d),
-                    "w_o": ((c.n_heads * dh, d), c.n_heads * dh)}}
+        norms = {"input_norm": ((d,), None), "post_norm": ((d,), None)}
+        attn = {"w_qkv": ((d, (c.n_heads + 2 * c.n_kv_heads) * dh), d),
+                "w_o": ((c.n_heads * dh, d), c.n_heads * dh)}
         if c.qk_norm:
-            attn["attn"].update(q_norm=((dh,), None), k_norm=((dh,), None))
+            attn.update(q_norm=((dh,), None), k_norm=((dh,), None))
         dense = {"w_gate_up": ((d, 2 * c.d_ff), d),
                  "w_down": ((c.d_ff, d), c.d_ff)}
         moe = {"router": ((d, c.n_experts), d),
+               **({"bias": ((c.n_experts,), 0)} if c.expert_bias else {}),
                "w_gate_up": ((c.n_held, d, 2 * c.moe_d_ff), d),
                "w_down": ((c.n_held, c.moe_d_ff, d), c.moe_d_ff)}
         if ffs:
             moe["shared"] = {"w_gate_up": ((d, 2 * ffs), d),
                              "w_down": ((ffs, d), ffs)}
         tree = {"embed": ((c.vocab_size, d), d), "final_norm": ((d,), None),
-                "lm_head": ((d, c.vocab_size), d),
-                "attn": stacked(c.n_layers, attn),
+                "attn": {**stacked(c.n_layers, norms),
+                         "attn": stacked(self.n_attn_layers, attn)},
                 "moe": stacked(n.get("moe", 0), moe)}
+        if not c.tie_embeddings:
+            tree["lm_head"] = ((d, c.vocab_size), d)
         if "dense" in n:
             tree["dense"] = stacked(n["dense"], dense)
+        if "conv" in n:
+            tree["conv"] = stacked(n["conv"], self.conv.param_shapes())
         return tree
 
     def param_specs(self):
@@ -193,7 +234,7 @@ class ExaoneMoe:
 
     def init(self, key, mesh: Mesh | None = None):
         """Random replicated params (tests): matrices N(0, 1/fan_in) in the
-        model dtype (the router float32), norms 1."""
+        model dtype (the router float32), norms 1, a selection bias 0."""
         mesh = mesh or get_default_mesh()
         c = self.config
         with_paths, treedef = jax.tree_util.tree_flatten_with_path(
@@ -206,8 +247,9 @@ class ExaoneMoe:
             out = []
             for k, (path, (shape, fan_in)) in zip(
                     jax.random.split(key, len(with_paths)), with_paths):
-                if fan_in is None:
-                    out.append(jnp.ones(shape, jnp.float32))
+                if not fan_in:      # a norm's weight 1, the selection bias 0
+                    out.append(jnp.full(shape, float(fan_in is None),
+                                        jnp.float32))
                     continue
                 dt = jnp.float32 if path[-1].key == "router" else c.dtype
                 out.append(jax.random.normal(k, shape, dt)
@@ -226,7 +268,8 @@ class ExaoneMoe:
         d = c.d_model
         attn = 2 * (c.n_heads + c.n_kv_heads) * c.head_dim * d
         expert = 3 * d * c.moe_d_ff
-        fixed = (c.n_layers * attn + n.get("dense", 0) * 3 * d * c.d_ff
+        fixed = (self.n_attn_layers * attn + n.get("conv", 0) * 4 * d * d
+                 + n.get("dense", 0) * 3 * d * c.d_ff
                  + n.get("moe", 0) * (c.n_shared_experts * expert
                                       + d * c.n_experts)
                  + d * c.vocab_size)
@@ -277,7 +320,9 @@ class ExaoneMoe:
                 f"{_axis_size(self.axis)} devices. Missing for more than "
                 f"one: window layers under tensor parallelism (the pool's "
                 f"ring storage and the window build's slot table are not "
-                f"sharded) and the routed experts' exchange over ICI "
+                f"sharded), conv layers under it (nor are the "
+                f"convolution's windows) and the routed experts' exchange "
+                f"over ICI "
                 f"(layers/ep_a2a_layer.py does not run under BatchEngine). "
                 f"One device is one chip's share of the deployment "
                 f"(ExaoneMoeConfig.experts_held); no code stands in for "
@@ -287,14 +332,21 @@ class ExaoneMoe:
                 "the pool's state has no window storage: build the pool "
                 "from this model's configuration (KVPool(config, ..., "
                 "n_slots=...))")
+        if "conv" in self.layer_counts and state.conv is None:
+            raise ValueError(
+                "the pool's state has no per-slot conv arena: build the "
+                "pool from this model's configuration (KVPool(config, ..., "
+                "n_slots=...))")
         if state.k_scale is not None:
             raise NotImplementedError(
                 "the EXAONE-MoE block has no quantized build of its pool")
         if spec_verify:
             raise NotImplementedError(
                 "speculative verify is not built for a model with window "
-                "layers: a rejected draft's rows have overwritten ring "
-                "lines, and the verify row is not sized into the ring")
+                "or conv layers: a rejected draft's rows have overwritten "
+                "ring lines (the verify row is not sized into the ring) and "
+                "entered the convolution's window, which keeps no copy to "
+                "roll back to")
         flat, blocks, last = nn.paged_token_blocks(
             ids, offsets, block_tables, slot_mask, seq_lens)
         # The residual stream is carried in float32 (the sub-layers read it
@@ -308,10 +360,13 @@ class ExaoneMoe:
         # ``[layer, expert]`` of them itself. Every other leaf is read at
         # ``[layer of its kind]`` of its stack where it lies (a slice of a
         # stack handed to a scan as ``xs`` is copied out first).
-        light = {k: params[k] for k in ("attn", "dense") if k in params}
+        light = {k: params[k] for k in ("dense", "conv") if k in params}
+        light["attn"] = params["attn"]["attn"]
+        light["norms"] = {k: params["attn"][k]
+                          for k in ("input_norm", "post_norm")}
         light["moe"] = dict(params["moe"])
         heavy = {k: light["moe"].pop(k) for k in ("w_gate_up", "w_down")}
-        if c.scoring == "sigmoid":          # this block has no selection bias
+        if c.scoring == "sigmoid" and not c.expert_bias:
             heavy["bias"] = jnp.zeros((c.n_experts,), jnp.float32)
         early_router = c.router_input == "layer_input"
 
@@ -323,15 +378,20 @@ class ExaoneMoe:
         def layer(kinds, idx, h, state, stats):
             """One layer; ``idx[name]`` () int32, traced or not: the
             layer's index, and its index among the layers of its kinds."""
-            attn_kind, ffn_kind = kinds
+            op_kind, ffn_kind = kinds
             idx = {k: jnp.asarray(v, jnp.int32) for k, v in idx.items()}
-            lp = at(light["attn"], idx["layer"])
+            lp = at(light["norms"], idx["layer"])
             x_in = h
-            hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
-            a, state = self.attn[attn_kind].local_fwd(
-                lp["attn"], hn.astype(c.dtype), state, blocks=blocks,
-                paged_attn=paged_attn, layer=idx[attn_kind],
-                interpret=interpret)
+            hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps).astype(c.dtype)
+            if op_kind == "conv":
+                a, state = self.conv.fwd(
+                    at(light["conv"], idx["conv"]), hn, state, blocks=blocks,
+                    layer=idx["conv"], interpret=interpret)
+            else:
+                a, state = self.attn[op_kind].local_fwd(
+                    at(light["attn"], idx["window"] + idx["full"]), hn,
+                    state, blocks=blocks, paged_attn=paged_attn,
+                    layer=idx[op_kind], interpret=interpret)
             h = h + a
             hn = nn.rms_norm(h, lp["post_norm"], c.rms_eps)
             if ffn_kind == "dense":
@@ -377,9 +437,15 @@ class ExaoneMoe:
         h, state, moe_stats = carry
 
         h = nn.rms_norm(h, params["final_norm"], c.rms_eps).astype(c.dtype)
-        logits = jnp.dot(jnp.take(h, last, axis=0), params["lm_head"],
+        head = params["embed"].T if c.tie_embeddings else params["lm_head"]
+        logits = jnp.dot(jnp.take(h, last, axis=0), head,
                          preferred_element_type=jnp.float32)
 
-        stats = jnp.concatenate([moe_stats, (
-            jnp.sum(valid) * c.n_layers).astype(jnp.int32)[None]])
+        live = jnp.sum(valid)
+        counts = [live * self.n_attn_layers]
+        if "conv" in self.layer_counts:
+            counts = [live * self.layer_counts["conv"],
+                      fresh_rows(blocks)] + counts
+        stats = jnp.concatenate([moe_stats,
+                                 jnp.stack(counts).astype(jnp.int32)])
         return logits, {"stats": stats}, state

@@ -55,6 +55,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from triton_distributed_tpu.layers import nn
 from triton_distributed_tpu.layers.mamba2 import Mamba2, draw_own
 from triton_distributed_tpu.layers.moe_mlp import swiglu
+from triton_distributed_tpu.layers.short_conv import fresh_rows
 from triton_distributed_tpu.layers.tp_attn import TPAttn
 from triton_distributed_tpu.models.config import GraniteHybridConfig
 from triton_distributed_tpu.runtime.compat import axis_size as _axis_size
@@ -300,9 +301,6 @@ class GraniteHybrid:
                          preferred_element_type=jnp.float32) \
             / c.logits_scaling
         live = sum(jnp.sum(b.valid()) for b in blocks)
-        reset = sum(jnp.sum((b.offsets == 0)
-                            & jnp.any(b.valid().reshape(-1, b.L), axis=1))
-                    for b in blocks)
-        stats = jnp.stack([live * c.n_state_layers, reset,
+        stats = jnp.stack([live * c.n_state_layers, fresh_rows(blocks),
                            live * c.n_cache_layers]).astype(jnp.int32)
         return logits, {"stats": stats}, state

@@ -61,6 +61,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from triton_distributed_tpu.layers import nn
 from triton_distributed_tpu.layers.mamba2 import Mamba2, draw_own
 from triton_distributed_tpu.layers.moe_mlp import MOE_STATS, HeldExpertsMoE
+from triton_distributed_tpu.layers.short_conv import fresh_rows
 from triton_distributed_tpu.layers.tp_attn import TPAttn
 from triton_distributed_tpu.models.config import NemotronHConfig
 from triton_distributed_tpu.models.granite_hybrid import shortest_period
@@ -356,10 +357,7 @@ class NemotronH:
         logits = jnp.dot(jnp.take(h, last, axis=0), params["lm_head"],
                          preferred_element_type=jnp.float32)
         live = jnp.sum(valid)
-        reset = sum(jnp.sum((b.offsets == 0)
-                            & jnp.any(b.valid().reshape(-1, b.L), axis=1))
-                    for b in blocks)
         stats = jnp.concatenate([moe_stats, jnp.stack(
-            [live * c.n_state_layers, reset,
+            [live * c.n_state_layers, fresh_rows(blocks),
              live * c.n_cache_layers]).astype(jnp.int32)])
         return logits, {"stats": stats}, state

@@ -71,7 +71,8 @@ def plain_off_tpu(interpret: InterpretFlag) -> bool:
     """AUTO off the TPU takes the plain form: the ONE rule of the served
     kernels that have no remote DMA or semaphore to simulate and a plain
     ``jax.numpy`` equal standing beside them (``ssm_update
-    .ssm_state_update``, ``moe_utils.grouped_gemm_skip``). Under
+    .ssm_state_update``, ``short_conv_update.short_conv_update``,
+    ``moe_utils.grouped_gemm_skip``). Under
     ``interpret=None`` where there is no TPU such a kernel's entry returns
     that equal, so a CPU run of a served step costs XLA's time and not the
     interpreter's callbacks (720 a step of a tiny hybrid). ``True`` is the
