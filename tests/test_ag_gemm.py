@@ -25,10 +25,12 @@ from triton_distributed_tpu.runtime import assert_allclose
 WORLD = 8
 
 
+def _rand(rng, shape, dtype=jnp.float32):
+    return jnp.asarray(rng.standard_normal(shape, dtype=np.float32), dtype)
+
+
 def _ab(rng, M, K, N, dtype=jnp.float32):
-    a = jnp.asarray(rng.standard_normal((M, K), dtype=np.float32), dtype)
-    b = jnp.asarray(rng.standard_normal((K, N), dtype=np.float32), dtype)
-    return a, b
+    return _rand(rng, (M, K), dtype), _rand(rng, (K, N), dtype)
 
 
 def test_ag_gemm_vs_golden(mesh8, rng):
@@ -226,3 +228,126 @@ def test_matmul_tail_into(rng):
     golden = np.asarray(a) @ np.asarray(b)
     assert_allclose(got[:, 128:], golden[:, 128:])
     assert_allclose(got[:, :128], np.asarray(c))
+
+
+# -- the layer-stacked weight operand (a model's lax.scan body) -------------
+
+
+def _at_traced_layer(call, layer):
+    """``call(li)`` with ``li`` a TRACED () int32 equal to ``layer``: the
+    one step of a ``lax.scan`` over ``[layer]``, so that the index a
+    stacked kernel reads is dynamic, as it is in a model's layer scan."""
+    return jax.lax.scan(lambda c, li: (c, call(li)), 0,
+                        jnp.array([layer], jnp.int32))[1][0]
+
+
+_STACKED_DTYPES = pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+_STACKED_LAYERS = pytest.mark.parametrize(
+    "layer", [0, 1, 2], ids=["first", "middle", "last"])
+# How the overlap kernel gets its weight tiles: RESIDENT (each copied once
+# by the kernel, where the call's tiles fit its VMEM: every shape here) or
+# through the pipeline's BlockSpec (what a weight too large to hold takes).
+_STACKED_FETCH = pytest.mark.parametrize(
+    "layer,fetch", [(0, "resident"), (1, "resident"), (2, "resident"),
+                    (1, "pipeline")],
+    ids=["first", "middle", "last", "middle-pipeline"])
+
+
+def _fetch(monkeypatch, fetch):
+    if fetch == "pipeline":
+        from triton_distributed_tpu.kernels import common
+
+        monkeypatch.setattr(common, "RESIDENT_WEIGHT_VMEM_CAP", 0)
+
+
+@_STACKED_DTYPES
+@_STACKED_FETCH
+@pytest.mark.parametrize("overlap_cols", [None, 128],
+                         ids=["whole", "split_tail"])
+def test_ag_gemm_device_stacked_is_the_matrix_form(mesh8, rng, monkeypatch,
+                                                   overlap_cols, layer, fetch,
+                                                   dtype):
+    """``ag_gemm_device`` over the stack (3, K, n_local) at a traced layer
+    is BITWISE the 2-D form on ``b[layer]``, with and without the split
+    tail (the stack per device is 12 KB in float32: the interpreter's
+    ceiling)."""
+    _fetch(monkeypatch, fetch)
+    K, n_local = (8, 128) if overlap_cols is None else (4, 256)
+    a = _rand(rng, (8 * WORLD, K), dtype)
+    stack = _rand(rng, (3, K, n_local * WORLD), dtype)
+    cfg = AGGEMMConfig(block_n=128, overlap_cols=overlap_cols)
+
+    def stacked(al, bl):
+        return _at_traced_layer(lambda li: ag_gemm_device(
+            al, bl, axis="tp", config=cfg, layer=li), layer)
+
+    def matrix(al, bl):
+        return ag_gemm_device(al, bl[layer], axis="tp", config=cfg)
+
+    got, want = (jax.jit(shard_map(
+        f, mesh=mesh8, in_specs=(P("tp", None), P(None, None, "tp")),
+        out_specs=P(None, "tp"), check_vma=False))(a, stack)
+        for f in (stacked, matrix))
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert_allclose(
+        got, np.asarray(a, np.float32) @ np.asarray(stack[layer], np.float32),
+        atol=0.5, rtol=0.05)
+
+
+@_STACKED_DTYPES
+@_STACKED_LAYERS
+def test_matmul_tail_into_stacked_is_the_matrix_form(rng, layer, dtype):
+    """``matmul_tail_into`` reads its tail's tiles out of the stack at a
+    traced layer: BITWISE the 2-D form on ``b[layer]``, pass-through
+    columns included."""
+    from triton_distributed_tpu.kernels.allgather_gemm import matmul_tail_into
+
+    M, K, N = 64, 128, 384
+    a, stack, c = (_rand(rng, shape, dtype)
+                   for shape in ((M, K), (3, K, N), (M, 128)))
+    got = jax.jit(lambda c, a, b: _at_traced_layer(
+        lambda li: matmul_tail_into(c, a, b, 128, block_n=128, layer=li),
+        layer))(c, a, stack)
+    want = jax.jit(lambda c, a, b: matmul_tail_into(
+        c, a, b, 128, block_n=128))(c, a, stack[layer])
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(got[:, :128], np.float32),
+                                  np.asarray(c, np.float32))
+
+
+@pytest.mark.parametrize("kernel", ["ag_gemm_device", "matmul_tail_into"])
+def test_stacked_weight_that_disagrees_raises_as_the_matrix_does(rng, kernel):
+    """A stack whose (K, N) disagrees with ``a`` raises what the 2-D form
+    raises, and a stack without its layer (or a layer without a stack)
+    is refused."""
+    from jax.sharding import Mesh
+
+    from triton_distributed_tpu.kernels.allgather_gemm import matmul_tail_into
+
+    a, b = _ab(rng, 16, 128, 256)
+    c = jnp.zeros((16, 128), jnp.float32)
+    mesh1 = Mesh(np.array(jax.devices()[:1]), ("tp",))
+
+    def call(b, **kw):
+        if kernel == "matmul_tail_into":
+            return matmul_tail_into(c, a, b, 128, block_n=128, **kw)
+        return jax.jit(shard_map(
+            lambda al, bl: ag_gemm_device(al, bl, axis="tp", **kw),
+            mesh=mesh1, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False))(a, b)
+
+    stack = jnp.stack([b] * 3)
+    for bad, kw in ((b[:64], {}), (stack[:, :64], {"layer": 1})):
+        with pytest.raises(ValueError, match="K mismatch"):
+            call(bad, **kw)
+    for bad, kw in ((stack, {}), (b, {"layer": 1})):
+        with pytest.raises(ValueError, match="layer must be passed"):
+            call(bad, **kw)
+    if kernel == "matmul_tail_into":  # N: the tail is not whole tiles
+        for bad, kw in ((b[:, :192], {}), (stack[:, :, :192], {"layer": 1})):
+            with pytest.raises(ValueError, match="not multiples"):
+                call(bad, **kw)

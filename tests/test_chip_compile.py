@@ -14,6 +14,7 @@ that RUNS it may load it. Keep every such test in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -219,6 +220,77 @@ def test_gemm_rs_compiles_tp4(tp4, proj, rows):
     assert "tpu_custom_call" in text
 
 
+# Qwen3-8B's four projections a chip, as the layer scan's kernels see them:
+# the 36-layer stack whole and a traced layer index.
+LAYERS8 = 36
+STACKED8 = {"qkv": (D8, QKV8), "gate_up": (D8, 2 * FF8),
+            "o": (32 * DH, D8), "down": (FF8, D8)}
+
+
+def _staged_weights(text, shapes):
+    """The fusions of a compiled program that slice a layer's matrix of one
+    of ``shapes`` out of its stack AHEAD of the kernel that multiplies it
+    (``%dynamic-slice_bitcast_fusion.N = bf16[K,N]{...S(1)}``: a serial
+    pass over the layer's weights into on-chip memory; PERF.md section 6,
+    PR 47)."""
+    want = "|".join(f"{k},{n}" for k, n in shapes)
+    return re.findall(
+        rf"^\s*(%\S*dynamic[-_]slice\S* = bf16\[(?:{want})\]\S*)", text,
+        flags=re.M)
+
+
+@pytest.mark.parametrize("block_n,fetch", [(128, "resident"),
+                                           (256, "resident"),
+                                           (128, "pipeline")])
+@pytest.mark.parametrize("rows", **ROWS)
+@pytest.mark.parametrize("proj", sorted(STACKED8))
+def test_stacked_overlap_gemms_compile_tp4(tp4, monkeypatch, proj, rows,
+                                           block_n, fetch):
+    """``ag_gemm_device`` (QKV, gate-up: with its tail) and ``gemm_rs_device``
+    (output, down) over the 36-layer stack at a layer index a ``lax.scan``
+    traces: Mosaic takes the stack whole, and nothing slices a layer's
+    matrix out of it first. Every one of these shapes keeps its weight
+    tiles RESIDENT (the down projection's 25.2 MB a chip the largest);
+    ``pipeline`` is the BlockSpec fetch a weight too large to hold takes."""
+    from triton_distributed_tpu.kernels import common
+    from triton_distributed_tpu.kernels.allgather_gemm import (
+        AGGEMMConfig,
+        ag_gemm_device,
+    )
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        GEMMRSConfig,
+        gemm_rs_device,
+    )
+
+    if fetch == "pipeline":
+        monkeypatch.setattr(common, "RESIDENT_WEIGHT_VMEM_CAP", 0)
+    k, n = STACKED8[proj]
+    if proj in ("qkv", "gate_up"):
+        def kernel(a, b, li):
+            return ag_gemm_device(a, b, axis="tp", layer=li, interpret=False,
+                                  config=AGGEMMConfig(block_n=block_n))
+        in_specs, out_spec = (P("tp", None), P(None, None, "tp")), \
+            P(None, "tp")
+    else:
+        def kernel(a, b, li):
+            return gemm_rs_device(a, b, axis="tp", layer=li, interpret=False,
+                                  config=GEMMRSConfig(block_n=block_n))
+        in_specs, out_spec = (P(None, "tp"), P(None, "tp", None)), \
+            P("tp", None)
+
+    def every_layer(a, b):
+        first = kernel(a, b, jnp.int32(0))
+        return jax.lax.scan(lambda acc, li: (acc + kernel(a, b, li), None),
+                            first, jnp.arange(1, LAYERS8, dtype=jnp.int32))[0]
+
+    text = _tp4_compile(tp4, every_layer, in_specs, out_spec,
+                        (4 * rows, k), (LAYERS8, k, n))
+    local = (k, n // 4) if proj in ("qkv", "gate_up") else (k // 4, n)
+    # the kernel's weight operand is this chip's share of the whole stack
+    assert "bf16[%d,%d,%d]{2,1,0}}" % (LAYERS8, *local) in text
+    assert not _staged_weights(text, [local])
+
+
 def test_oneshot_allreduce_compiles_tp4(tp4):
     from triton_distributed_tpu.kernels.allreduce import oneshot_all_reduce
 
@@ -242,7 +314,8 @@ def test_the_four_chip_cells_step_compiles_with_its_kernels_named(tp4, kind):
     widths: it compiles for the four chips, the pool's arenas are aliased
     in to out, a chip's arguments are its 5.96 GB of weights (the layers'
     quarter, the table and the head whole) and 1.96 GB of pool, and the
-    compiled text knows the fused kernels by name."""
+    compiled text knows the fused kernels by name and stages no layer's
+    weights ahead of them."""
     import dataclasses
 
     from triton_distributed_tpu.models.config import ModelConfig
@@ -290,6 +363,11 @@ def test_the_four_chip_cells_step_compiles_with_its_kernels_named(tp4, kind):
     text = compiled.as_text()
     for name in ("ag_gemm", "ag_gemm_tail", "gemm_rs", "paged_attention"):
         assert f"%{name}." in text or f"%{name} =" in text, name
+    # the four projections' stacks reach those kernels whole: no layer's
+    # matrix is sliced out (and staged) ahead of them
+    assert not _staged_weights(
+        text, [(k, n // 4) if proj in ("qkv", "gate_up") else (k // 4, n)
+               for proj, (k, n) in STACKED8.items()])
     assert "all-gather" in text
     mem = compiled.memory_analysis()
     assert 1.95e9 < mem.alias_size_in_bytes < 1.97e9

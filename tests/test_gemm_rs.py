@@ -117,3 +117,88 @@ def test_gemm_rs_loopback_single_tile(rng):
     golden = (np.asarray(a, np.float32).reshape(2, 8, K).sum(0)
               @ np.asarray(b, np.float32))
     assert_allclose(got, golden, atol=1e-4, rtol=1e-4)
+
+
+# -- the layer-stacked weight operand (a model's lax.scan body) -------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "layer,fetch", [(0, "resident"), (1, "resident"), (2, "resident"),
+                    (1, "pipeline")],
+    ids=["first", "middle", "last", "middle-pipeline"])
+def test_gemm_rs_device_stacked_is_the_matrix_form(mesh8, rng, monkeypatch,
+                                                   layer, fetch, dtype):
+    """``gemm_rs_device`` over the stack (3, k_local, N) at a TRACED layer
+    (the one step of a ``lax.scan`` over ``[layer]``, as in a model's layer
+    scan) is BITWISE the 2-D form on ``b[layer]``; two column tiles, the
+    stack per device 12 KB in float32 (the interpreter's ceiling). The
+    weight's tiles RESIDENT (each copied once by the kernel, where B whole
+    fits its VMEM: every shape here) or through the pipeline's BlockSpec
+    (what a weight too large to hold takes)."""
+    import jax
+
+    from triton_distributed_tpu.kernels import common
+
+    if fetch == "pipeline":
+        monkeypatch.setattr(common, "RESIDENT_WEIGHT_VMEM_CAP", 0)
+    from jax.sharding import PartitionSpec as P
+
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        gemm_rs_device,
+    )
+
+    M, K, N = 2 * WORLD, 4 * WORLD, 256
+    a, stack = (jnp.asarray(rng.standard_normal(shape, dtype=np.float32),
+                            dtype) for shape in ((M, K), (3, K, N)))
+    cfg = GEMMRSConfig(block_n=128)
+
+    def stacked(al, bl):
+        return jax.lax.scan(
+            lambda c, li: (c, gemm_rs_device(al, bl, axis="tp", config=cfg,
+                                             layer=li)),
+            0, jnp.array([layer], jnp.int32))[1][0]
+
+    def matrix(al, bl):
+        return gemm_rs_device(al, bl[layer], axis="tp", config=cfg)
+
+    got, want = (jax.jit(shard_map(
+        f, mesh=mesh8, in_specs=(P(None, "tp"), P(None, "tp", None)),
+        out_specs=P("tp", None), check_vma=False))(a, stack)
+        for f in (stacked, matrix))
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert_allclose(
+        got, np.asarray(a, np.float32) @ np.asarray(stack[layer], np.float32),
+        atol=1.0, rtol=0.1)
+
+
+def test_gemm_rs_stacked_weight_that_disagrees_raises_as_the_matrix_does(rng):
+    """A stack whose K disagrees with ``a`` raises what the 2-D form
+    raises (both at trace time, before any kernel is built), and a stack
+    without its layer (or a layer without a stack) is refused."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        gemm_rs_device,
+    )
+
+    a, b = _ab(rng, 16, 128, 128)
+    mesh1 = Mesh(np.array(jax.devices()[:1]), ("tp",))
+
+    def call(b, **kw):
+        return jax.jit(shard_map(
+            lambda al, bl: gemm_rs_device(al, bl, axis="tp", **kw),
+            mesh=mesh1, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False))(a, b)
+
+    stack = jnp.stack([b] * 3)
+    for bad, kw in ((b[:64], {}), (stack[:, :64], {"layer": 1})):
+        with pytest.raises(ValueError, match="K mismatch"):
+            call(bad, **kw)
+    for bad, kw in ((stack, {}), (b, {"layer": 1})):
+        with pytest.raises(ValueError, match="layer must be passed"):
+            call(bad, **kw)

@@ -203,8 +203,10 @@ def _resolve_overlap_cols(config: "AGGEMMConfig", m: int, k: int, n: int,
     return cols
 
 
-def _matmul_tail_into_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *,
-                             k_tiles: int, j0: int, bn: int):
+def _matmul_tail_into_kernel(*refs, k_tiles: int, j0: int, bn: int):
+    # A stacked b's layer index rides in front as a prefetched scalar; only
+    # the index maps read it.
+    c_ref, a_ref, b_ref, o_ref, acc_ref = refs[-5:]
     j = pl.program_id(1)
     kk = pl.program_id(2)
 
@@ -230,8 +232,8 @@ def _matmul_tail_into_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *,
 
 
 def matmul_tail_into(c, a, b, col_start: int, *, block_n: int,
-                     block_m: int = 1024, block_k: int = 1024,
-                     interpret=None):
+                     block_m: int = 1024, block_k: int | None = None,
+                     interpret=None, layer=None):
     """Assemble the AG-GEMM split result in ONE kernel pass: returns the
     full ``(m, n)`` product where columns ``[0, col_start)`` come from ``c``
     (the overlap kernel's output, copied through VMEM) and columns
@@ -245,9 +247,21 @@ def matmul_tail_into(c, a, b, col_start: int, *, block_n: int,
 
     ``col_start`` must be a multiple of ``block_n``. Falls back to XLA
     compute + dynamic_update_slice when the tail blocks are infeasible
-    (ragged K — same delegation bound as ``ag_gemm_single_chip``)."""
+    (ragged K — same delegation bound as ``ag_gemm_single_chip``).
+
+    ``block_k`` None: where the rows are ONE block (``m <= block_m``) B is
+    read exactly once and the kernel is its stream, so a tile takes the
+    whole of K if that fits: one copy of ``(K, block_n)`` a column tile in
+    place of K / 1024 of a quarter the size, one in flight at a time
+    (Qwen3-8B's gate-up quarter at 64 rows on a v5e: 74.5 us against 133,
+    PERF.md section 6, PR 47). Else 1024, the sweep's winner at large M.
+
+    ``b`` may be a layer stack ``(L, K, N)`` with ``layer`` () int32 (see
+    ``ag_gemm_device``): B's index map then reads the layer from a
+    prefetched scalar and the tiles come straight out of the stack; the
+    XLA fall-back takes ``b[layer]``, a slice its dot fuses."""
     m, k = a.shape
-    _, n = b.shape
+    stacked, n = common.weight_operand(b, layer, k)
     ncols = n - col_start
     if c.shape != (m, col_start):
         raise ValueError(f"c {c.shape} != ({m}, {col_start})")
@@ -257,59 +271,86 @@ def matmul_tail_into(c, a, b, col_start: int, *, block_n: int,
             f"block_n {block_n}")
     bn = block_n
     out_dtype = c.dtype
+
+    def vmem(bm, bk):
+        return (_matmul_vmem(bm, bn, bk, a.dtype.itemsize,
+                             out_dtype.itemsize)
+                + 2 * bm * col_start * out_dtype.itemsize)
+
     try:
         bm = _fit_block(m, min(block_m, m), 8)
+        if block_k is None:
+            block_k = k if (bm == m and
+                            vmem(bm, k) <= common.MOSAIC_VMEM_BUDGET) else 1024
         bk = _fit_block(k, min(block_k, k), 128)
-        if (_matmul_vmem(bm, bn, bk, a.dtype.itemsize, out_dtype.itemsize)
-                + 2 * bm * col_start * out_dtype.itemsize
-                ) > _AUTO_VMEM_BUDGET:
+        if vmem(bm, bk) > _AUTO_VMEM_BUDGET:
             raise ValueError("tail blocks exceed the auto VMEM budget")
     except ValueError:
         # Tail columns only: the overlap kernel already produced
         # [0, col_start) in ``c`` — recomputing the full product just to
         # slice it would redo col_start/n of the FLOPs for nothing.
         tail = jnp.dot(
-            a, jax.lax.slice_in_dim(b, col_start, n, axis=1),
+            a, jax.lax.slice_in_dim(b[layer] if stacked else b,
+                                    col_start, n, axis=1),
             preferred_element_type=jnp.float32).astype(out_dtype)
         return jnp.concatenate([c, tail], axis=1)
     j0 = col_start // bn
     k_tiles = k // bk
+    # The index maps take the grid indices and then the prefetched scalars
+    # (none for a matrix, the layer for a stack).
+    if stacked:
+        b_spec = pl.BlockSpec(
+            (None, bk, bn),
+            lambda i, j, kk, li_ref: (li_ref[0], kk, jnp.maximum(j, j0)))
+        scalars = (jnp.asarray(layer, jnp.int32).reshape(1),)
+    else:
+        b_spec = pl.BlockSpec((bk, bn),
+                              lambda i, j, kk: (kk, jnp.maximum(j, j0)))
+        scalars = ()
     return pl.pallas_call(
         functools.partial(_matmul_tail_into_kernel, k_tiles=k_tiles,
                           j0=j0, bn=bn),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        grid=(m // bm, n // bn, k_tiles),
-        in_specs=[
-            # One c row-panel per i, reused across (j, kk) — fetched once.
-            pl.BlockSpec((bm, col_start), lambda i, j, kk: (i, 0)),
-            # Clamped index maps below j0: pass-through steps re-point at
-            # blocks the first compute column needs anyway (B) or at a
-            # constant block (A) instead of streaming operands the MXU
-            # never reads — pass-through columns cost one c panel, not a
-            # wasted 40MB A sweep.
-            pl.BlockSpec((bm, bk),
-                         lambda i, j, kk, j0=j0: (
-                             i, jnp.where(j >= j0, kk, 0))),
-            pl.BlockSpec((bk, bn),
-                         lambda i, j, kk, j0=j0: (kk, jnp.maximum(j, j0))),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(m // bm, n // bn, k_tiles),
+            in_specs=[
+                # One c row-panel per i, reused across (j, kk) — fetched
+                # once.
+                pl.BlockSpec((bm, col_start), lambda i, j, kk, *_: (i, 0)),
+                # Clamped index maps below j0: pass-through steps re-point
+                # at blocks the first compute column needs anyway (B) or at
+                # a constant block (A) instead of streaming operands the
+                # MXU never reads — pass-through columns cost one c panel,
+                # not a wasted 40MB A sweep.
+                pl.BlockSpec((bm, bk),
+                             lambda i, j, kk, *_: (
+                                 i, jnp.where(j >= j0, kk, 0))),
+                b_spec,
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, *_: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="ag_gemm_tail",
         interpret=resolve_interpret(interpret),
-    )(c, a, b)
+    )(*scalars, c, a, b)
 
 
 def _ag_gemm_kernel(me_ref, a_ref, b_ref, o_ref, a_full, a_vmem, send_sems,
                     recv_sems, copy_sems, *, axis: str, world: int,
-                    n_tiles: int, probe=_probes.NULL):
+                    n_tiles: int, probe=_probes.NULL, b_tiles=None):
+    # ``b_tiles`` None: ``b_ref`` is this step's (k, bn) tile, through the
+    # pipeline. Else B is RESIDENT: ``b_ref`` is the whole operand in HBM
+    # and ``b_tiles`` its ``(b_vmem, sems)``
+    # (``common.resident_weight_limit``).
     s = pl.program_id(0)
     j = pl.program_id(1)
     me = me_ref[0]
     m = a_ref.shape[0]
     k = a_ref.shape[1]
+    bn = o_ref.shape[1]
     probe.enter(s * n_tiles + j, me, world)
     src = jax.lax.rem(me + s, world)
     nxt = jax.lax.rem(me + s + 1, world)
@@ -318,6 +359,10 @@ def _ag_gemm_kernel(me_ref, a_ref, b_ref, o_ref, a_full, a_vmem, send_sems,
 
     @pl.when((s == 0) & (j == 0))
     def _startup():
+        if b_tiles is not None:
+            # Every tile's copy in flight before anything waits.
+            for jj in range(n_tiles):
+                common.weight_tile_copy(me_ref, b_ref, b_tiles, jj, bn).start()
         # All devices in the kernel before anyone receives remote pushes.
         dl.barrier_all(axis)
         probe.sem_spin(world - 1)
@@ -342,10 +387,17 @@ def _ag_gemm_kernel(me_ref, a_ref, b_ref, o_ref, a_full, a_vmem, send_sems,
         pltpu.make_async_copy(a_full.at[src], a_vmem.at[cur_slot],
                               copy_sems.at[cur_slot]).wait()
 
+    if b_tiles is not None:
+        # First touch (the own segment's walk); the gathered segments
+        # multiply what is already there.
+        @pl.when(s == 0)
+        def _tile_arrived():
+            common.weight_tile_copy(me_ref, b_ref, b_tiles, j, bn).wait()
+
     o_ref[...] = jnp.dot(
-        a_vmem[cur_slot], b_ref[...], preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
-    probe.compute(2 * m * k * o_ref.shape[1])
+        a_vmem[cur_slot], b_ref[...] if b_tiles is None else b_tiles[0][j],
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    probe.compute(2 * m * k * bn)
 
     # First-touch arrival wait for the NEXT segment (the dl.wait +
     # consume_token of the reference consumer, allgather_gemm.py:146), then
@@ -368,7 +420,7 @@ def _ag_gemm_kernel(me_ref, a_ref, b_ref, o_ref, a_full, a_vmem, send_sems,
 
 def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
                    config: AGGEMMConfig | None = None, interpret=None,
-                   probes: bool = False):
+                   probes: bool = False, layer=None):
     """Per-device AG-GEMM (composable inside shard_map):
     ``(m, K) x (K, n_local) -> (world*m, n_local)`` with the allgather of A
     overlapped into the matmul.
@@ -385,13 +437,30 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
     at bare-kernel speed (B read once, big block_m tiles). The reference's
     persistent consumer reaches the same steady state by revisiting tiles
     after the last segment signal (allgather_gemm.py:146); on TPU the tail
-    is a second Pallas call so Mosaic pipelines it with full-size blocks."""
+    is a second Pallas call so Mosaic pipelines it with full-size blocks.
+
+    ``b_local`` may be the layer STACK ``(L, K, n_local)`` with ``layer`` ()
+    int32, traced: how a model's ``lax.scan`` body calls it. The index
+    rides as a second prefetched scalar beside ``me``, so the kernel's
+    own fetch of ``[layer, :, tile]`` is the one read of the weights. A
+    matrix sliced out of the stack by the scan (the stack in ``xs``) feeds
+    a custom call XLA cannot fuse the slice into: the whole layer was
+    staged by a serial pass BEFORE the kernel started and then streamed a
+    second time (66.6 us a layer for Qwen3-8B's gate-up quarter on a v5e,
+    PERF.md section 6, PR 47). Grid, tiles, semaphores and the ledger's
+    record are the 2-D form's; the single-device branch and the ``probes``
+    build take ``b_local[layer]``.
+
+    Either form's overlap columns are RESIDENT where they fit
+    (``common.resident_weight_limit``): the grid walks ``(segment, column
+    tile)``, and a tile through the pipeline would be fetched once a
+    segment."""
     config = config or AGGEMMConfig()
     world = _axis_size(axis)
     m, k = a_local.shape
-    k2, n_local = b_local.shape
-    if k != k2:
-        raise ValueError(f"K mismatch: A has {k}, B has {k2}")
+    stacked, n_local = common.weight_operand(b_local, layer, k)
+    if stacked and (world == 1 or probes):
+        b_local, layer = b_local[layer], None
     if world == 1:
         # Degenerate path: single-chip matmul with the sweep-tuned defaults.
         # config.block_n tiles the multi-device consumer only — passing it
@@ -406,7 +475,7 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
         # Zero rows ride the gather and are dropped from every segment.
         res = ag_gemm_device(
             jnp.pad(a_local, ((0, m_pad - m), (0, 0))), b_local, axis=axis,
-            config=config, interpret=interpret, probes=probes)
+            config=config, interpret=interpret, probes=probes, layer=layer)
         out = (res[0] if probes else res).reshape(world, m_pad, n_local)
         out = out[:, :m].reshape(world * m, n_local)
         return (out, res[1]) if probes else out
@@ -430,7 +499,14 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
                                  a_local.dtype.itemsize, loopback=False)
     n_tiles = cols // bn
 
-    me = jax.lax.axis_index(axis).astype(jnp.int32)[None]
+    # Two A-segment slots, the overlap columns of B whole, two out tiles.
+    resident, vmem_limit = common.resident_weight_limit(
+        (2 * m * k + k * cols) * a_local.dtype.itemsize
+        + 2 * m * bn * out_dtype.itemsize, probes)
+    if not resident:
+        vmem_limit = _overlap_vlim(m, k, bn, a_local.dtype.itemsize,
+                                   out_dtype.itemsize)
+    me, b_spec = common.rank_and_weight_spec(axis, k, bn, layer, resident)
 
     # The gathered-A staging is an ANY-space OUTPUT, not scratch: Mosaic only
     # allocates vmem/smem/semaphore scratch memrefs, and remote DMAs need a
@@ -456,6 +532,9 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
         jax.ShapeDtypeStruct((world * m, cols), out_dtype),
         jax.ShapeDtypeStruct((world, m, k), a_local.dtype),
     ]
+    if resident:
+        kernel, scratch_shapes = common.with_resident_tiles(
+            kernel, scratch_shapes, n_tiles, k, bn, b_local.dtype)
     if probes:
         n_steps = world * n_tiles
 
@@ -474,7 +553,7 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
         grid=(world, n_tiles),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),     # a_local
-            pl.BlockSpec((k, bn), lambda s, j, me_ref: (0, j)),  # b tile
+            b_spec,                                # b tile
         ],
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
@@ -486,8 +565,7 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             collective_id=common.collective_id_for("ag_gemm"),
-            vmem_limit_bytes=_overlap_vlim(
-                m, k, bn, a_local.dtype.itemsize, out_dtype.itemsize)),
+            vmem_limit_bytes=vmem_limit),
         cost_estimate=common.cost_estimate(
             flops=2 * world * m * k * cols,
             bytes_accessed=(2 * world * m * k * a_local.dtype.itemsize
@@ -500,7 +578,8 @@ def ag_gemm_device(a_local, b_local, *, axis: str = "tp",
     out1, a_full = outs[0], outs[1]
     if cols != n_local:
         out1 = matmul_tail_into(out1, a_full.reshape(world * m, k), b_local,
-                                cols, block_n=bn_tail, interpret=interpret)
+                                cols, block_n=bn_tail, interpret=interpret,
+                                layer=layer)
     return (out1, outs[2]) if probes else out1
 
 

@@ -65,14 +65,18 @@ def collective_id_for(name: str) -> int:
         ) from None
 
 
-def compiler_params(collective_id: int | None) -> pltpu.CompilerParams:
+def compiler_params(collective_id: int | None,
+                    vmem_limit_bytes: int | None = None
+                    ) -> pltpu.CompilerParams:
     """``collective_id=None`` for kernels that never touch the barrier
     semaphore (Mosaic rejects an unused collective_id: "collective_id has to
     be unspecified ... when not using a custom barrier" — e.g. the LL
     allgather, whose whole point is needing no barrier)."""
     if collective_id is None:
         return pltpu.CompilerParams(has_side_effects=True)
-    return pltpu.CompilerParams(has_side_effects=True, collective_id=collective_id)
+    return pltpu.CompilerParams(has_side_effects=True,
+                                collective_id=collective_id,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 def cost_estimate(*, flops: int, bytes_accessed: int,
@@ -237,6 +241,95 @@ def choose_lane_block(dim: int, vmem_of_block, what: str) -> int:
     raise ValueError(
         f"no feasible {what}: resident buffers alone overflow the "
         f"{MOSAIC_VMEM_BUDGET >> 20}MB VMEM budget")
+
+
+def weight_operand(b, layer, a_k: int):
+    """``(stacked, n)`` of an overlap GEMM's weight operand: a matrix
+    ``(K, N)``, or a layer stack ``(L, K, N)`` with ``layer`` () int32 —
+    the one rule of the three kernels that take either (``ag_gemm_device``,
+    ``matmul_tail_into``, ``gemm_rs_device``). ``a_k`` is the contraction
+    width of the rows it multiplies."""
+    stacked = b.ndim == 3
+    if stacked != (layer is not None):
+        raise ValueError("layer must be passed exactly when b is "
+                         "layer-stacked (L, K, N)")
+    k, n = b.shape[-2:]
+    if k != a_k:
+        raise ValueError(f"K mismatch: A has {a_k}, B has {k}")
+    return stacked, n
+
+
+# The most VMEM an overlap GEMM asks for to keep its weight tiles RESIDENT
+# (``resident_weight_limit``): the grant the AG-GEMM overlap kernel has run
+# with since round 5. A 47MB+ grant was measured to trigger S(1)
+# result-buffer promotions that starve neighboring kernels.
+RESIDENT_WEIGHT_VMEM_CAP = 36 * 2 ** 20
+
+
+def rank_and_weight_spec(axis: str, k: int, bn: int, layer, resident: bool):
+    """``(scalars, b_spec)`` of an overlap GEMM over the grid ``(segment,
+    column tile)``: the one prefetched int32 vector, this device's rank on
+    ``axis`` and, behind it, the ``layer`` of a stacked weight (None: a
+    matrix), with the weight operand's BlockSpec. ``resident``: the operand
+    stays in HBM whole and the kernel copies its tiles itself
+    (``weight_tile_copy``); else the ``(k, bn)`` tile comes through the
+    pipeline, the stack's index map reading ``[layer, :, tile]`` straight
+    out of ``(L, K, N)``. The kernel bodies read ``scalars[0]`` for the
+    rank and see a ``(k, bn)`` tile either way."""
+    me = jax.lax.axis_index(axis).astype(jnp.int32)[None]
+    if layer is not None:
+        me = jnp.concatenate([me, jnp.asarray(layer, jnp.int32).reshape(1)])
+    if resident:
+        return me, pl.BlockSpec(memory_space=pl.ANY)
+    if layer is None:
+        return me, pl.BlockSpec((k, bn), lambda s, j, sc: (0, j))
+    return me, pl.BlockSpec((None, k, bn), lambda s, j, sc: (sc[1], 0, j))
+
+
+def resident_weight_limit(need: int, probes: bool):
+    """``(resident, vmem_limit_bytes)`` of an overlap GEMM whose working
+    set WITH every weight tile of the call held in VMEM is ``need`` bytes.
+    The grid walks ``(segment, column tile)``, so a tile that comes through
+    the pipeline is fetched once a SEGMENT, ``world`` times a call, one
+    copy in flight; resident, every tile is copied once, all copies
+    started at the kernel's first step and each awaited where the first
+    segment meets it. On a v5e at Qwen3-8B's TP=4 decode shapes the down
+    projection's 25.2 MB a chip took 145 us a call through the pipeline
+    (100.7 MB at 692 GB/s) and 65 us resident (one-chip probe of the fetch
+    alone, PERF.md section 6, PR 47). Not under ``probes`` (the
+    instrumented build keeps the pipeline's fetch) and not past
+    ``RESIDENT_WEIGHT_VMEM_CAP``."""
+    if probes or need > RESIDENT_WEIGHT_VMEM_CAP:
+        return False, None
+    if need <= MOSAIC_VMEM_BUDGET:
+        return True, None
+    # Sized to the need plus headroom for Mosaic's bookkeeping.
+    return True, need + 8 * 2 ** 20
+
+
+def with_resident_tiles(kernel, scratch_shapes, n_tiles: int, k: int,
+                        bn: int, dtype):
+    """``(kernel, scratch_shapes)`` of an overlap GEMM whose weight is
+    resident: the tiles' VMEM and their semaphores go LAST among the
+    scratch, and the body gets them as ``b_tiles``."""
+    def body(*refs):
+        kernel(*refs[:-2], b_tiles=refs[-2:])
+
+    return body, [*scratch_shapes, pltpu.VMEM((n_tiles, k, bn), dtype),
+                  dma_sems(n_tiles)]
+
+
+def weight_tile_copy(scalars_ref, b_hbm, tiles, jj, bn: int):
+    """The copy of column tile ``jj`` of a resident weight from HBM into
+    its slot: ``tiles`` is the kernel's ``(b_vmem (n_tiles, K, bn), sems
+    (n_tiles,))``, ``b_hbm`` the matrix ``(K, N)`` or, where the
+    prefetched ``scalars_ref`` carries a layer behind the rank, the stack
+    ``(L, K, N)`` read at that layer."""
+    b_vmem, sems = tiles
+    cols = pl.ds(jj * bn, bn)
+    src = (b_hbm.at[:, cols] if scalars_ref.shape[0] == 1
+           else b_hbm.at[scalars_ref[1], :, cols])
+    return pltpu.make_async_copy(src, b_vmem.at[jj], sems.at[jj])
 
 
 def _elems(shape) -> int:

@@ -76,7 +76,11 @@ class GEMMRSConfig:
 def _gemm_rs_kernel(me_ref, a_ref, b_ref, o_ref, staging, a_vmem, send_tile,
                     acc_tile, tmp_tile, out_tile, send_sems, recv_sems,
                     copy_sem, *, axis: str, world: int, n_tiles: int, bn: int,
-                    probe=_probes.NULL):
+                    probe=_probes.NULL, b_tiles=None):
+    # ``b_tiles`` None: ``b_ref`` is this step's (k_local, bn) tile, through
+    # the pipeline. Else B is RESIDENT: ``b_ref`` is the whole operand in
+    # HBM and ``b_tiles`` its ``(b_vmem, sems)``
+    # (``common.resident_weight_limit``).
     s = pl.program_id(0)
     j = pl.program_id(1)
     me = me_ref[0]
@@ -97,8 +101,20 @@ def _gemm_rs_kernel(me_ref, a_ref, b_ref, o_ref, staging, a_vmem, send_tile,
 
     @pl.when((s == 0) & (j == 0))
     def _startup():
+        if b_tiles is not None:
+            # Every tile's copy in flight before anything waits: the one
+            # read of the weights, at the DMA engines' rate.
+            for jj in range(n_tiles):
+                common.weight_tile_copy(me_ref, b_ref, b_tiles, jj, bn).start()
         dl.barrier_all(axis)  # staging live everywhere before pushes land
         probe.sem_spin(world - 1)
+
+    if b_tiles is not None:
+        # First touch (the first destination's walk); the other
+        # destinations multiply what is already there.
+        @pl.when(s == 0)
+        def _tile_arrived():
+            common.weight_tile_copy(me_ref, b_ref, b_tiles, j, bn).wait()
 
     # Load this destination's A rows once per segment.
     @pl.when(j == 0)
@@ -113,7 +129,8 @@ def _gemm_rs_kernel(me_ref, a_ref, b_ref, o_ref, staging, a_vmem, send_tile,
         common.wait_send(send_tile.at[parity], send_sems.at[parity],
                          probe=probe)
 
-    partial = jnp.dot(a_vmem[...], b_ref[...],
+    partial = jnp.dot(a_vmem[...],
+                      b_ref[...] if b_tiles is None else b_tiles[0][j],
                       preferred_element_type=jnp.float32)
     probe.compute(2 * m * k_local * bn)
 
@@ -170,18 +187,33 @@ def _gemm_rs_kernel(me_ref, a_ref, b_ref, o_ref, staging, a_vmem, send_tile,
 
 def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
                    config: GEMMRSConfig | None = None, interpret=None,
-                   probes: bool = False):
+                   probes: bool = False, layer=None):
     """Per-device GEMM-RS (composable inside shard_map):
     ``(M, k_local) x (k_local, N) -> (m, N)`` — segment ``me`` of the
     reduce-scattered full product, comm overlapped into the matmul.
 
     With ``probes=True`` (a separate compile) returns ``(out, probe_buf)``
     where ``probe_buf`` is the device-telemetry record decoded by
-    ``obs.kprobe`` (one row per grid step)."""
+    ``obs.kprobe`` (one row per grid step).
+
+    ``b_local`` may be the layer STACK ``(L, k_local, N)`` with ``layer`` ()
+    int32, traced (a model's ``lax.scan`` body; see ``ag_gemm_device``):
+    the index rides as a second prefetched scalar beside ``me``, so the
+    tiles come out of the stack where it lies and no pass stages the
+    layer's matrix before the kernel starts.
+
+    The grid walks ``(destination, column tile)``. Where B whole fits the
+    VMEM a kernel may ask for (``common.resident_weight_limit``) its tiles
+    are RESIDENT: copied from HBM once, all copies started at the first
+    step, and multiplied ``world`` times; else each tile comes through the
+    pipeline once a destination, ``world`` reads of B a call. The
+    single-device branch and the ``probes`` build take ``b_local[layer]``."""
     config = config or GEMMRSConfig()
     world = _axis_size(axis)
     M, k_local = a_local.shape
-    _, n = b_local.shape
+    stacked, n = common.weight_operand(b_local, layer, k_local)
+    if stacked and (world == 1 or probes):
+        b_local, layer = b_local[layer], None
     if world == 1:
         from triton_distributed_tpu.kernels.allgather_gemm import ag_gemm_single_chip
         # No block override: an explicit block would forfeit the automatic
@@ -200,7 +232,7 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
                         ((0, 0), (0, m_pad - m), (0, 0)))
         res = gemm_rs_device(
             a_pad.reshape(world * m_pad, k_local), b_local, axis=axis,
-            config=config, interpret=interpret, probes=probes)
+            config=config, interpret=interpret, probes=probes, layer=layer)
         return (res[0][:m], res[1]) if probes else res[:m]
     out_dtype = jnp.promote_types(a_local.dtype, b_local.dtype)
     from triton_distributed_tpu.runtime import perf_model as pm
@@ -217,7 +249,13 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
     n_tiles = config.n_tiles(n)
     bn = config.block_n
 
-    me = jax.lax.axis_index(axis).astype(jnp.int32)[None]
+    # A rows, B whole, and the send (2), accumulator, remote-partial and
+    # cast-out tiles.
+    resident, vmem_limit = common.resident_weight_limit(
+        (m * k_local + k_local * n) * a_local.dtype.itemsize
+        + m * bn * (4 * out_dtype.itemsize + 4), probes)
+    me, b_spec = common.rank_and_weight_spec(axis, k_local, bn, layer,
+                                             resident)
 
     # Incoming-partials staging is an ANY-space OUTPUT (discarded): Mosaic
     # does not allocate HBM scratch, and peer pushes need a stable HBM buffer
@@ -225,7 +263,7 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
     # last-output position).
     in_specs = [
         pl.BlockSpec(memory_space=pl.ANY),                    # a_local
-        pl.BlockSpec((k_local, bn), lambda s, j, me_ref: (0, j)),
+        b_spec,
     ]
     out_specs = [
         common.hbm_spec(),                                    # (m, N)
@@ -247,6 +285,9 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
         jax.ShapeDtypeStruct((m, n), out_dtype),
         jax.ShapeDtypeStruct((world - 1, m, n), out_dtype),
     ]
+    if resident:
+        kernel, scratch_shapes = common.with_resident_tiles(
+            kernel, scratch_shapes, n_tiles, k_local, bn, b_local.dtype)
     if probes:
         n_steps = world * n_tiles
 
@@ -274,11 +315,12 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
         out_shape=out_shape,
         grid_spec=grid_spec,
         compiler_params=common.compiler_params(
-            common.collective_id_for("gemm_rs")),
+            common.collective_id_for("gemm_rs"), vmem_limit),
         cost_estimate=common.cost_estimate(
             flops=2 * M * k_local * n,
             bytes_accessed=(M * k_local * a_local.dtype.itemsize
-                            + world * k_local * n * b_local.dtype.itemsize
+                            + (1 if resident else world) * k_local * n
+                            * b_local.dtype.itemsize
                             + M * n * out_dtype.itemsize),
             remote_bytes=(world - 1) * m * n * out_dtype.itemsize),
         name="gemm_rs",

@@ -340,7 +340,7 @@ class TPAttn:
 
     def dist_fwd(self, params, x_local, cache, offset=None, *,
                  seq_lens=None, interpret=None, blocks=None,
-                 paged_attn: str = "fused", layer=None):
+                 paged_attn: str = "fused", layer=None, layer_idx=None):
         """x_local: this device's rows of the batch — (B_local, L, d), or
         (T_local, d) of a paged step's flat batch — -> same layout out.
         AG-GEMM -> attention -> GEMM-RS (reference dist_triton_fwd :203).
@@ -348,12 +348,16 @@ class TPAttn:
         ``blocks``/``paged_attn``: paged-KV serving path (``_attend``) — the
         blocks cover the FULL batch, replicated. ``layer``: the state's
         arenas are the stacked ones, read and appended at this layer
-        (``_attend``)."""
+        (``_attend``). ``layer_idx`` () int32: ``w_qkv`` and ``w_o`` are the
+        model's layer STACKS (L, ...), handed to the kernels whole, which
+        index the layer themselves (``ag_gemm_device``); the norm weights
+        stay this layer's own."""
         world = _axis_size(self.axis)
         lead, d = x_local.shape[:-1], x_local.shape[-1]
         qkv = ag_gemm_device(
             x_local.reshape(-1, d), params["w_qkv"], axis=self.axis,
-            config=AGGEMMConfig(block_n=self.block_n), interpret=interpret)
+            config=AGGEMMConfig(block_n=self.block_n), interpret=interpret,
+            layer=layer_idx)
         if blocks is None:
             qkv = qkv.reshape(world * lead[0], *lead[1:], -1)
         out, cache = self._attend(
@@ -363,7 +367,7 @@ class TPAttn:
         out = gemm_rs_device(
             out.reshape(-1, out.shape[-1]), params["w_o"], axis=self.axis,
             config=GEMMRSConfig(block_n=min(self.block_n, self.d_model)),
-            interpret=interpret)
+            interpret=interpret, layer=layer_idx)
         return out.reshape(*lead, d), cache
 
     def local_fwd(self, params, x, state, *, blocks, paged_attn: str = "fused",

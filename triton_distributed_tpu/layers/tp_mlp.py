@@ -113,17 +113,21 @@ class TPMLP:
         return (jax.nn.silu(gate.astype(jnp.float32)) *
                 up.astype(jnp.float32)).astype(h.dtype)
 
-    def dist_fwd(self, params, x_local, *, interpret=None):
+    def dist_fwd(self, params, x_local, *, layer_idx=None, interpret=None):
         """x_local: (m, d_model) M-shard -> (m, d_model) M-shard.
-        AG-GEMM -> GLU -> GEMM-RS (reference dist_triton_fwd, tp_mlp.py:143)."""
+        AG-GEMM -> GLU -> GEMM-RS (reference dist_triton_fwd, tp_mlp.py:143).
+        ``layer_idx`` () int32: both weights are the model's layer STACKS
+        (L, ...), handed to the kernels whole, which index the layer
+        themselves (``ag_gemm_device``)."""
         h = ag_gemm_device(
             x_local, params["w_gate_up"], axis=self.axis,
-            config=AGGEMMConfig(block_n=self.block_n), interpret=interpret)
+            config=AGGEMMConfig(block_n=self.block_n), interpret=interpret,
+            layer=layer_idx)
         h = self._glu(h)
         return gemm_rs_device(
             h, params["w_down"], axis=self.axis,
             config=GEMMRSConfig(block_n=min(self.block_n, self.d_model)),
-            interpret=interpret)
+            interpret=interpret, layer=layer_idx)
 
     def ar_fwd(self, params, x_full, *, interpret=None):
         """x_full: (M, d_model) replicated -> (M, d_model) replicated.

@@ -318,37 +318,68 @@ class Qwen3:
         return jnp.take(params["embed"], my_ids, axis=0), (me, bl)
 
     def _scan_layers(self, params, mode: str):
-        """``(scan_layers, moe_heavy)``. MoE dist mode: the heavy expert
-        weights stay OUT of the scan's xs (closed over, full stacked
-        (L, E, ...)) and the body passes a layer index instead — a
-        scan-sliced (E, ...) weight operand would MATERIALIZE to feed the
-        grouped-GEMM Pallas call (1.2 GB/layer at 30b-a3b; XLA fuses the
-        slice for an einsum but not for a custom call), while the stacked
-        form block-indexes the layer inside the kernel and keeps the
-        empty-expert weight-fetch skip live e2e."""
+        """``(scan_layers, heavy)``: what rides the layer scan as ``xs``,
+        and the weight STACKS that stay out of it (``{"mlp": {...}, "attn":
+        {...}}``, closed over whole, full (L, ...); None when every leaf
+        rides) — the body passes the layer index down instead and the
+        Pallas kernel that multiplies a stack block-indexes the layer
+        itself. A scan-sliced weight is an operand XLA fuses into its own
+        dot or einsum but NOT into a custom call, which gets the layer's
+        matrix made first:
+
+        - MoE dist mode: the experts' (L, E, ...) stacks (1.2 GB a layer
+          MATERIALIZED at 30b-a3b; the stacked form also keeps the
+          empty-expert weight-fetch skip live e2e).
+        - dense dist mode on an axis of more than one device, where the
+          four projections are AG-GEMM, its tail and GEMM-RS: ``w_qkv``,
+          ``w_o``, ``w_gate_up``, ``w_down`` (each layer's 96.5 MB a chip
+          of Qwen3-8B over four was STAGED into on-chip memory by a serial
+          pass before its kernel started: 130 us a layer, a fifth of the
+          step; PERF.md section 6, PR 47).
+
+        The norm weights and everything small stay in ``xs``. With one
+        device on the axis the products are XLA's own dots, which fuse
+        the slice: nothing is taken out and the program is unchanged."""
         scan_layers = dict(params["layers"])
-        if not (self.config.n_experts and mode == "dist"):
+        if mode != "dist":
             return scan_layers, None
-        lp_mlp = dict(scan_layers["mlp"])
-        moe_heavy = {"w_gate_up": lp_mlp.pop("w_gate_up"),
-                     "w_down": lp_mlp.pop("w_down")}
-        scan_layers["mlp"] = lp_mlp
-        return scan_layers, moe_heavy
+        if self.config.n_experts:
+            take = {"mlp": ("w_gate_up", "w_down")}
+        elif _axis_size(self.axis) > 1:
+            take = {"mlp": ("w_gate_up", "w_down"), "attn": ("w_qkv", "w_o")}
+        else:
+            return scan_layers, None
+        heavy = {}
+        for block, names in take.items():
+            rest = dict(scan_layers[block])
+            heavy[block] = {name: rest.pop(name) for name in names}
+            scan_layers[block] = rest
+        return scan_layers, heavy
 
     def _layer(self, lp, h, cache, offset, li, *, mode: str, interpret,
-               moe_heavy=None, return_moe_stats: bool = False, **paged):
+               heavy=None, return_moe_stats: bool = False, **paged):
         """One decoder layer: ``(h, cache, stats)``. ``cache`` is this
         layer's ``(k, v)`` of the contiguous cache, h (rows, L, d), or,
         with ``paged`` (blocks, paged_attn, layer), the pool's state, h the
         flat token batch (T, d) — the attention layer reads the state and
-        hands it back."""
+        hands it back. ``heavy``: the weight stacks ``_scan_layers`` kept
+        out of ``lp``, which the blocks they belong to read at ``li``."""
         c = self.config
         attn, mlp = self.attn, self.mlp
+        heavy = heavy or {}
+
+        def stacked(block):
+            # (the block's parameters, the index of its stacks' layer)
+            if block not in heavy:
+                return lp[block], {}
+            return dict(lp[block], **heavy[block]), {"layer_idx": li}
+
         resid = h
         hn = nn.rms_norm(h, lp["input_norm"], c.rms_eps)
         if mode == "dist":
-            a, cache = attn.dist_fwd(lp["attn"], hn, cache, offset,
-                                     interpret=interpret, **paged)
+            attn_params, kw = stacked("attn")
+            a, cache = attn.dist_fwd(attn_params, hn, cache, offset,
+                                     interpret=interpret, **kw, **paged)
         elif mode == "xla":
             a, cache = attn.xla_fwd(lp["attn"], hn, cache, offset, **paged)
         else:
@@ -360,9 +391,7 @@ class Qwen3:
         flat = hn.reshape(-1, c.d_model)
         stats = None
         if mode == "dist":
-            mlp_params = (dict(lp["mlp"], **moe_heavy) if moe_heavy
-                          else lp["mlp"])
-            kw = ({"layer_idx": li} if moe_heavy else {})
+            mlp_params, kw = stacked("mlp")
             if return_moe_stats:
                 m, stats = mlp.dist_fwd(mlp_params, flat, return_stats=True,
                                         interpret=interpret, **kw)
@@ -450,13 +479,13 @@ class Qwen3:
                              "mode='dist' (drops only exist on the EP "
                              "dispatch path)")
         h, rows = self._embed(params, ids, mode)
-        scan_layers, moe_heavy = self._scan_layers(params, mode)
+        scan_layers, heavy = self._scan_layers(params, mode)
 
         def body(h, xs):
             lp, kc, vc, li = xs
             h, (kc, vc), stats = self._layer(
                 lp, h, (kc, vc), offset, li, mode=mode, interpret=interpret,
-                moe_heavy=moe_heavy, return_moe_stats=return_moe_stats)
+                heavy=heavy, return_moe_stats=return_moe_stats)
             return h, (kc, vc) + ((stats,) if return_moe_stats else ())
 
         with _ledger.repeated(c.n_layers):
@@ -528,14 +557,14 @@ class Qwen3:
         flat, blocks, last = nn.paged_token_blocks(
             ids, offsets, block_tables, slot_mask, seq_lens, multiple=world)
         h, rows = self._embed(params, flat, mode)
-        scan_layers, moe_heavy = self._scan_layers(params, mode)
+        scan_layers, heavy = self._scan_layers(params, mode)
 
         def body(carry, xs):
             h, state = carry
             lp, li = xs
             h, state, _ = self._layer(
                 lp, h, state, None, li, mode=mode, interpret=interpret,
-                moe_heavy=moe_heavy, blocks=blocks, paged_attn=paged_attn,
+                heavy=heavy, blocks=blocks, paged_attn=paged_attn,
                 layer=li)
             return (h, state), None
 
