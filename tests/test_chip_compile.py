@@ -291,6 +291,61 @@ def test_stacked_overlap_gemms_compile_tp4(tp4, monkeypatch, proj, rows,
     assert not _staged_weights(text, [local])
 
 
+# The four-chip cell's GEMM-RS calls (``TP4_*`` below: 32 slots, ``block_n``
+# 128), rows a device: a decode step's 32 rows (8, padded to the sublane
+# tile), the mixed step's 480 (120, padded to 128) and 512; then a prefill
+# of 2,048 rows, where the down projection's A, own block and arrived
+# partials are past the VMEM a kernel may ask for (42 MB of 36; the output
+# projection's, a third as deep, are 31).
+CELL_ROWS = dict(
+    argvalues=[(8, "one pass"), (120, "one pass"), (128, "one pass"),
+               (512, "one pass if it fits")],
+    ids=["decode-32", "mixed-480", "mixed-512", "prefill-2048"])
+
+
+@pytest.mark.parametrize("weight", ["matrix", "stacked"])
+@pytest.mark.parametrize("rows,walk", **CELL_ROWS)
+@pytest.mark.parametrize("proj", ["o", "down"])
+def test_gemm_rs_walk_compiles_at_the_cells_rows_tp4(tp4, proj, rows, walk,
+                                                     weight):
+    """``gemm_rs_device`` chooses its walk from the shapes it is given: at
+    the cell's decode and mixed rows both projections take the ONE pass
+    over the column tiles (A whole, the own block in float32, the weight's
+    tiles through a ring: the down projection's mixed call 14 of the 36
+    MB), the down projection at a prefill of 2,048 rows keeps the grid
+    ``(destination, column tile)``; the comm ledger's ``method`` says
+    which, and Mosaic takes either."""
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        GEMMRSConfig,
+        gemm_rs_device,
+    )
+    from triton_distributed_tpu.obs import comm_ledger
+
+    k = {"o": 32 * DH, "down": FF8}[proj]
+    cfg = GEMMRSConfig(block_n=128)
+    if weight == "matrix":
+        def fn(a, b):
+            return gemm_rs_device(a, b, axis="tp", config=cfg,
+                                  interpret=False)
+        b_shape, b_spec = (k, D8), P("tp", None)
+    else:
+        def fn(a, b):
+            def kernel(li):
+                return gemm_rs_device(a, b, axis="tp", layer=li, config=cfg,
+                                      interpret=False)
+            return jax.lax.scan(lambda acc, li: (acc + kernel(li), None),
+                                kernel(jnp.int32(0)),
+                                jnp.arange(1, LAYERS8, dtype=jnp.int32))[0]
+        b_shape, b_spec = (LAYERS8, k, D8), P(None, "tp", None)
+    with comm_ledger.gathering() as records:
+        text = _tp4_compile(tp4, fn, (P(None, "tp"), b_spec), P("tp", None),
+                            (4 * rows, k), b_shape)
+    fits = walk == "one pass" or proj == "o"
+    assert {r.method for r in records} == {
+        "device_one_pass" if fits else "device"}
+    assert "tpu_custom_call" in text and "gemm_rs" in text
+
+
 def test_oneshot_allreduce_compiles_tp4(tp4):
     from triton_distributed_tpu.kernels.allreduce import oneshot_all_reduce
 

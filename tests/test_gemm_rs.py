@@ -16,35 +16,173 @@ from triton_distributed_tpu.runtime.compat import shard_map
 WORLD = 8
 
 
+def _fresh_gemm_rs(a, b, mesh):
+    """``gemm_rs`` traced anew: its jitted builder is cached by ``(mesh,
+    axis, config, interpret)`` and the walk is chosen when it traces."""
+    from triton_distributed_tpu.kernels import gemm_reduce_scatter as gr
+
+    gr._build_gemm_rs.cache_clear()
+    return gemm_rs(a, b, mesh=mesh, config=GEMMRSConfig(block_n=128))
+
+
 def _ab(rng, M, K, N, dtype=jnp.float32):
     a = jnp.asarray(rng.standard_normal((M, K), dtype=np.float32), dtype)
     b = jnp.asarray(rng.standard_normal((K, N), dtype=np.float32), dtype)
     return a, b
 
 
-def test_gemm_rs_vs_golden(mesh8, rng):
-    M, K, N = 4 * WORLD, 16 * WORLD, 128
+WALKS = ["one_pass", "two_axis"]
+
+
+def _take_walk(monkeypatch, walk, M, K, N, dtype, *, world=WORLD, bn=128):
+    """``gemm_rs_device`` chooses its walk from its operands' shapes alone;
+    every shape the interpreter can hold fits the ONE pass, so the grid
+    ``(destination, column tile)`` is reached by lowering the VMEM a kernel
+    may ask for to one byte under what the one pass of this shape needs
+    (B's tiles stay resident: the two-axis walk needs less)."""
+    from triton_distributed_tpu.kernels import common
+    from triton_distributed_tpu.kernels import gemm_reduce_scatter as gr
+
+    if walk == "one_pass":
+        return
+    m, isz = M // world, jnp.dtype(dtype).itemsize
+    need = gr._one_pass_vmem(world, m, K // world, N, bn,
+                             gr._tiles_a_piece(m, bn, isz, N // bn), isz, isz)
+    monkeypatch.setattr(common, "RESIDENT_WEIGHT_VMEM_CAP", need - 1)
+
+
+def _methods(fn):
+    """The comm ledger's ``method`` of every ``gemm_rs`` record traced
+    while ``fn`` runs: the name of the walk each call took."""
+    from triton_distributed_tpu.obs import comm_ledger
+
+    with comm_ledger.gathering() as records:
+        out = fn()
+    return out, [r.method for r in records if r.collective == "gemm_rs"]
+
+
+METHOD = {"one_pass": "device_one_pass", "two_axis": "device"}
+
+
+def _golden(a, b):
+    return np.asarray(a, np.float32) @ np.asarray(b, np.float32)
+
+
+def _check_walk(mesh, rng, monkeypatch, walk, M, K, N, dtype=jnp.float32,
+                **tol):
+    """``gemm_rs`` on seeded operands under ``walk``: the ledger names it
+    and the result is the dense golden's."""
+    a, b = _ab(rng, M, K, N, dtype)
+    _take_walk(monkeypatch, walk, M, K, N, dtype)
+    out, methods = _methods(lambda: _fresh_gemm_rs(a, b, mesh))
+    assert methods == [METHOD[walk]]
+    assert out.dtype == dtype
+    assert_allclose(out, _golden(a, b), **(tol or dict(atol=1e-4, rtol=1e-4)))
+
+
+@pytest.mark.parametrize("walk", WALKS)
+def test_gemm_rs_vs_golden(mesh8, rng, monkeypatch, walk):
+    _check_walk(mesh8, rng, monkeypatch, walk, 4 * WORLD, 16 * WORLD, 128)
+
+
+@pytest.mark.parametrize("walk", WALKS)
+def test_gemm_rs_multi_tile(mesh8, rng, monkeypatch, walk):
+    _check_walk(mesh8, rng, monkeypatch, walk, 2 * WORLD, 8 * WORLD, 256)
+
+
+@pytest.mark.parametrize("walk", WALKS)
+def test_gemm_rs_bf16(mesh8, rng, monkeypatch, walk):
+    _check_walk(mesh8, rng, monkeypatch, walk, 2 * WORLD, 8 * WORLD, 128,
+                jnp.bfloat16, atol=1.0, rtol=0.1)
+
+
+@pytest.mark.parametrize("piece,steps", [(1, 3), (32 * 2 ** 10, 1)],
+                         ids=["a-push-a-tile", "one-piece"])
+def test_gemm_rs_one_pass_pieces(rng, monkeypatch, piece, steps):
+    """The one pass groups a peer's column tiles into pieces of at most
+    ``PUSH_PIECE_BYTES``: a push a tile over three steps (the third
+    reclaims the first's send slot), or all of a destination's columns in
+    one piece; on a mesh of four, three column tiles."""
+    import jax
+    from jax.sharding import Mesh
+
+    from triton_distributed_tpu.kernels import gemm_reduce_scatter as gr
+
+    monkeypatch.setattr(gr, "PUSH_PIECE_BYTES", piece)
+    M, K, N = 8, 32, 384
+    assert N // 128 // gr._tiles_a_piece(M // 4, 128, 4, N // 128) == steps
     a, b = _ab(rng, M, K, N)
-    out = gemm_rs(a, b, mesh=mesh8, config=GEMMRSConfig(block_n=128))
-    golden = np.asarray(a, np.float32) @ np.asarray(b, np.float32)
-    assert_allclose(out, golden, atol=1e-4, rtol=1e-4)
+    mesh4 = Mesh(np.array(jax.devices()[:4]), ("tp",))
+    out, methods = _methods(lambda: _fresh_gemm_rs(a, b, mesh4))
+    assert methods == ["device_one_pass"]
+    assert_allclose(out, _golden(a, b), atol=1e-4, rtol=1e-4)
 
 
-def test_gemm_rs_multi_tile(mesh8, rng):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_gemm_rs_walks_agree(mesh8, rng, monkeypatch, dtype):
+    """The two walks on the same operands: the same partial products pushed
+    in the same dtype and folded in the same fixed rank order, so they
+    agree to the golden's tolerance (a 64-row product may round as a
+    16-row one does, or not: bitwise is not promised)."""
+    M, K, N = 2 * WORLD, 8 * WORLD, 256
+    a, b = _ab(rng, M, K, N, dtype)
+    got = {}
+    for walk in WALKS:
+        with monkeypatch.context() as mp:
+            _take_walk(mp, walk, M, K, N, dtype)
+            got[walk], methods = _methods(
+                lambda: _fresh_gemm_rs(a, b, mesh8))
+        assert methods == [METHOD[walk]]
+    tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 \
+        else dict(atol=1.0, rtol=0.1)
+    assert_allclose(got["one_pass"], np.asarray(got["two_axis"], np.float32),
+                    **tol)
+    assert_allclose(got["one_pass"], _golden(a, b), **tol)
+
+
+def test_gemm_rs_ledger_counts_the_calls_of_each_walk(mesh8, rng, monkeypatch):
+    """The enabled ledger keeps a series a ``method``: a program whose
+    scan runs the one pass three times, and one whose two calls keep the
+    grid ``(destination, column tile)``, read apart in its snapshot with
+    the same bytes a call (traced only: nothing runs)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        gemm_rs_device,
+    )
+    from triton_distributed_tpu.obs import comm_ledger
+
     M, K, N = 2 * WORLD, 8 * WORLD, 256
     a, b = _ab(rng, M, K, N)
-    out = gemm_rs(a, b, mesh=mesh8, config=GEMMRSConfig(block_n=128))
-    golden = np.asarray(a, np.float32) @ np.asarray(b, np.float32)
-    assert_allclose(out, golden, atol=1e-4, rtol=1e-4)
+    cfg = GEMMRSConfig(block_n=128)
 
+    def once(al, bl):
+        return gemm_rs_device(al, bl, axis="tp", config=cfg)
 
-def test_gemm_rs_bf16(mesh8, rng):
-    M, K, N = 2 * WORLD, 8 * WORLD, 128
-    a, b = _ab(rng, M, K, N, jnp.bfloat16)
-    out = gemm_rs(a, b, mesh=mesh8, config=GEMMRSConfig(block_n=128))
-    assert out.dtype == jnp.bfloat16
-    golden = np.asarray(a, np.float32) @ np.asarray(b, np.float32)
-    assert_allclose(out, golden, atol=1.0, rtol=0.1)
+    def scanned(al, bl):
+        with comm_ledger.repeated(3):
+            return jax.lax.scan(lambda c, _: (c + once(al, bl), None),
+                                jnp.zeros((M // WORLD, N), a.dtype), None,
+                                length=3)[0]
+
+    def trace(f):
+        jax.jit(shard_map(f, mesh=mesh8, in_specs=(P(None, "tp"),
+                                                   P("tp", None)),
+                          out_specs=P("tp", None), check_vma=False)
+                ).trace(a, b)
+
+    with comm_ledger.ledger(reset_first=True):
+        trace(scanned)
+        with monkeypatch.context() as mp:
+            _take_walk(mp, "two_axis", M, K, N, a.dtype)
+            trace(lambda al, bl: once(al, bl) + once(al, bl))
+        series = {e.method: e for e in comm_ledger.get_ledger().get("gemm_rs")}
+    assert {m: e.traced_calls for m, e in series.items()} == {
+        "device_one_pass": 3, "device": 2}
+    assert series["device_one_pass"].bytes_total / 3 == \
+        series["device"].bytes_total / 2 > 0
 
 
 def test_gemm_rs_2d_vs_golden(rng):

@@ -71,8 +71,21 @@ def test_stress_ag_gemm_random_shapes_with_stragglers(mesh8, seed):
             rtol=1e-4, atol=1e-4)
 
 
+# Rows a device each walk of GEMM-RS draws. The one pass holds two send
+# slots A PEER ((world - 1) x 2 x m x 128 floats: 57 KB at 8 rows; at 16
+# the interpreter hangs, see the ceiling in conftest.py).
+GEMM_RS_ROWS = {"two_axis": [8, 16], "one_pass": [4, 8]}
+
+
 @pytest.mark.parametrize("seed", [0, 1])
-def test_stress_gemm_rs_random_shapes_with_stragglers(mesh8, seed):
+@pytest.mark.parametrize("walk", sorted(GEMM_RS_ROWS))
+def test_stress_gemm_rs_random_shapes_with_stragglers(mesh8, monkeypatch,
+                                                      walk, seed):
+    """Randomized (M, k_local) GEMM-RS under rank-proportional skew, each of
+    its two walks: the grid ``(destination, column tile)``, reached as
+    ``test_gemm_rs._take_walk`` reaches it (every shape the interpreter
+    holds fits the one pass), and the one pass over the column tiles."""
+    from test_gemm_rs import METHOD, _methods, _take_walk
     from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
         GEMMRSConfig,
         gemm_rs_device,
@@ -80,7 +93,7 @@ def test_stress_gemm_rs_random_shapes_with_stragglers(mesh8, seed):
 
     rng = np.random.default_rng(seed)
     for _ in range(3):
-        M = WORLD * int(rng.choice([8, 16]))
+        M = WORLD * int(rng.choice(GEMM_RS_ROWS[walk]))
         k_local = int(rng.choice([8, 16]))
         n = 128
         a = jnp.asarray(rng.standard_normal((M, WORLD * k_local)),
@@ -93,8 +106,12 @@ def test_stress_gemm_rs_random_shapes_with_stragglers(mesh8, seed):
             return gemm_rs_device(al, bl, axis="tp",
                                   config=GEMMRSConfig(block_n=128))
 
-        out = _run8(f, mesh8, (P(None, "tp"), P("tp", None)),
-                    P("tp", None), a, b)
+        with monkeypatch.context() as mp:
+            _take_walk(mp, walk, M, WORLD * k_local, n, a.dtype)
+            out, methods = _methods(lambda: _run8(
+                f, mesh8, (P(None, "tp"), P("tp", None)), P("tp", None),
+                a, b))
+        assert methods == [METHOD[walk]]
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(a) @ np.asarray(b),
             rtol=1e-4, atol=1e-4)

@@ -286,6 +286,19 @@ def rank_and_weight_spec(axis: str, k: int, bn: int, layer, resident: bool):
     return me, pl.BlockSpec((None, k, bn), lambda s, j, sc: (sc[1], 0, j))
 
 
+def fits_kernel_vmem(need: int):
+    """``(fits, vmem_limit_bytes)`` of a kernel whose working set is
+    ``need`` bytes: whether it is within ``RESIDENT_WEIGHT_VMEM_CAP``, the
+    most VMEM a kernel of this package asks for, and the limit to hand
+    Mosaic (None where the default budget holds it)."""
+    if need > RESIDENT_WEIGHT_VMEM_CAP:
+        return False, None
+    if need <= MOSAIC_VMEM_BUDGET:
+        return True, None
+    # Sized to the need plus headroom for Mosaic's bookkeeping.
+    return True, need + 8 * 2 ** 20
+
+
 def resident_weight_limit(need: int, probes: bool):
     """``(resident, vmem_limit_bytes)`` of an overlap GEMM whose working
     set WITH every weight tile of the call held in VMEM is ``need`` bytes.
@@ -297,21 +310,18 @@ def resident_weight_limit(need: int, probes: bool):
     projection's 25.2 MB a chip took 145 us a call through the pipeline
     (100.7 MB at 692 GB/s) and 65 us resident (one-chip probe of the fetch
     alone, PERF.md section 6, PR 47). Not under ``probes`` (the
-    instrumented build keeps the pipeline's fetch) and not past
-    ``RESIDENT_WEIGHT_VMEM_CAP``."""
-    if probes or need > RESIDENT_WEIGHT_VMEM_CAP:
-        return False, None
-    if need <= MOSAIC_VMEM_BUDGET:
-        return True, None
-    # Sized to the need plus headroom for Mosaic's bookkeeping.
-    return True, need + 8 * 2 ** 20
+    instrumented build keeps the pipeline's fetch) and not past what
+    ``fits_kernel_vmem`` allows."""
+    return (False, None) if probes else fits_kernel_vmem(need)
 
 
 def with_resident_tiles(kernel, scratch_shapes, n_tiles: int, k: int,
                         bn: int, dtype):
-    """``(kernel, scratch_shapes)`` of an overlap GEMM whose weight is
-    resident: the tiles' VMEM and their semaphores go LAST among the
-    scratch, and the body gets them as ``b_tiles``."""
+    """``(kernel, scratch_shapes)`` of an overlap GEMM that copies its
+    weight tiles itself (``n_tiles`` slots: every tile of the call where
+    the weight is resident, a ring where each tile is met once): the
+    tiles' VMEM and their semaphores go LAST among the scratch, and the
+    body gets them as ``b_tiles``."""
     def body(*refs):
         kernel(*refs[:-2], b_tiles=refs[-2:])
 
@@ -319,17 +329,19 @@ def with_resident_tiles(kernel, scratch_shapes, n_tiles: int, k: int,
                   dma_sems(n_tiles)]
 
 
-def weight_tile_copy(scalars_ref, b_hbm, tiles, jj, bn: int):
-    """The copy of column tile ``jj`` of a resident weight from HBM into
-    its slot: ``tiles`` is the kernel's ``(b_vmem (n_tiles, K, bn), sems
-    (n_tiles,))``, ``b_hbm`` the matrix ``(K, N)`` or, where the
-    prefetched ``scalars_ref`` carries a layer behind the rank, the stack
-    ``(L, K, N)`` read at that layer."""
+def weight_tile_copy(scalars_ref, b_hbm, tiles, jj, bn: int, slot=None):
+    """The copy of column tile ``jj`` of a weight from HBM into its slot
+    (``jj`` itself where every tile of the call is resident; a walk that
+    meets each tile once names a ``slot`` of its ring): ``tiles`` is the
+    kernel's ``(b_vmem (slots, K, bn), sems (slots,))``, ``b_hbm`` the
+    matrix ``(K, N)`` or, where the prefetched ``scalars_ref`` carries a
+    layer behind the rank, the stack ``(L, K, N)`` read at that layer."""
     b_vmem, sems = tiles
+    slot = jj if slot is None else slot
     cols = pl.ds(jj * bn, bn)
     src = (b_hbm.at[:, cols] if scalars_ref.shape[0] == 1
            else b_hbm.at[scalars_ref[1], :, cols])
-    return pltpu.make_async_copy(src, b_vmem.at[jj], sems.at[jj])
+    return pltpu.make_async_copy(src, b_vmem.at[slot], sems.at[slot])
 
 
 def _elems(shape) -> int:

@@ -5,14 +5,40 @@ TPU-native analog of the reference's ``kernels/nvidia/gemm_reduce_scatter.py``
 producer GEMM :130 that notifies per-tile barriers, RS consumer on a
 dedicated ``rs_stream``).
 
-TPU design: one Pallas kernel per device; the grid walks destination
-segments in swizzled order ``dst = (me + 1 + s) % world`` — remote segments
-first, own segment last. As soon as a remote segment's partial product is
-complete it is pushed over ICI into the owner's staging slot (async DMA,
-double-buffered), so all world-1 pushes are in flight while the MXU still
-computes later segments; the final grid steps compute the own segment and
-fold in arriving remote partials. Comm rides entirely under compute — the
-reference's producer-GEMM/RS-consumer stream pair collapsed into one kernel.
+TPU design: one Pallas kernel per device and ONE algorithm (partial product,
+push each remote destination's rows to their owner's staging as they
+complete, fold the arrived partials into the own rows in a fixed global rank
+order), under one of two loop nests that ``gemm_rs_device`` chooses from its
+operands' shapes alone:
+
+* **One pass over the column tiles** (``_gemm_rs_one_pass_kernel``, grid
+  ``(column tiles / piece,)``), where all ``world * m`` rows of A, the own
+  ``(m, N)`` block in float32 and the arrived partials fit the VMEM a kernel
+  may ask for (``_one_pass_vmem`` against ``common.fits_kernel_vmem``):
+  every weight tile is copied from HBM once, in order and a few tiles ahead
+  of its use through a ring of slots (B whole need not fit: a tile is met
+  once), and meets the MXU ONCE, multiplied by every destination's rows in
+  one product. A peer's rows of the result leave in pieces of up to
+  ``PUSH_PIECE_BYTES`` (at 16 rows and tiles of 128 columns: 8 tiles, 12
+  pushes a call where the other walk makes 96), from send slots
+  double-buffered per peer; the own rows stay in VMEM and are folded after
+  the last tile. The decode and mixed steps of a served model are here:
+  with few rows a product's time is the weight tile's, not the rows', so
+  four 16-row products a tile cost four times one 64-row product (PERF.md
+  section 6, PR 48).
+* **The grid ``(destination, column tile)``** (``_gemm_rs_kernel``) for
+  everything else (a prefill-sized M, the ``probes`` build): destinations in
+  swizzled order ``dst = (me + 1 + s) % world``, remote segments first, own
+  segment last; a remote tile is pushed as soon as its product is done
+  (double-buffered by tile parity), the last segment's steps compute the
+  own rows and fold in the arrived partials tile by tile. B's tiles stay
+  resident where B fits (copied once, multiplied ``world`` times), else
+  they come through the pipeline once a destination.
+
+Either way the pushes ride under later products: the reference's
+producer-GEMM/RS-consumer stream pair collapsed into one kernel. The comm
+ledger's traced record names the walk a call took (``method``:
+``"device_one_pass"`` or ``"device"``).
 
 Sharding convention (row-parallel TP matmul, reference TP_MLP down-proj):
   A: (M, K) sharded on K over ``axis``  -> per-device (M, k_local)
@@ -185,6 +211,190 @@ def _gemm_rs_kernel(me_ref, a_ref, b_ref, o_ref, staging, a_vmem, send_tile,
                                  probe=probe)
 
 
+# The most a remote destination's piece of the one-pass walk holds before it
+# is pushed: a copy costs the same up to about 32 KB (PERF.md section 6,
+# PR 42's table), so a piece is as many column tiles as fit under it. At 16
+# rows (8 tiles a piece, 4 grid steps where a push a tile makes 32) that is
+# 1.5-1.8 us of a 23-45 us call, four chips, real pushes (PERF.md section 6,
+# PR 48's review round).
+PUSH_PIECE_BYTES = 32 * 2 ** 10
+
+
+# Weight tiles the one-pass walk keeps in flight ahead of its product, in a
+# ring of one slot more. In order and a few ahead, a tile is there when the
+# walk meets it and the fetch runs under the products; all of a call's
+# copies started at once share the DMA engines, every tile lands near the
+# end and the products wait for the whole fetch (one-chip probe, PERF.md
+# section 6, PR 48: 44.4 us a call against 54.7 at the down projection's
+# decode shape; 2, 4 and 8 ahead read alike).
+TILES_IN_FLIGHT = 4
+
+
+def _ring_slots(n_tiles: int) -> int:
+    """Slots of the one-pass walk's ring of weight tiles: the tiles in
+    flight and the one being multiplied."""
+    return min(TILES_IN_FLIGHT, n_tiles) + 1
+
+
+def _tiles_a_piece(m: int, bn: int, itemsize: int, n_tiles: int) -> int:
+    """Column tiles of one pushed piece ``(m, tiles * bn)`` of the one-pass
+    walk: the most that divide ``n_tiles`` and keep the piece within
+    ``PUSH_PIECE_BYTES`` (one where a tile alone is past it)."""
+    fit = max(1, PUSH_PIECE_BYTES // (m * bn * itemsize))
+    return max(g for g in range(1, n_tiles + 1)
+               if n_tiles % g == 0 and g <= fit)
+
+
+def _one_pass_vmem(world: int, m: int, k_local: int, n: int, bn: int,
+                   group: int, in_itemsize: int, out_itemsize: int) -> int:
+    """VMEM of the one-pass walk: A whole and the ring of weight tiles, the
+    own block in float32, the send slots (two a peer), the arrived
+    partials, the cast-out block, the fold's accumulator and a step's
+    product. B whole is NOT in it: each tile is met once."""
+    gw = group * bn
+    return ((world * m * k_local + _ring_slots(n // bn) * k_local * bn)
+            * in_itemsize
+            + m * n * 4
+            + (world - 1) * 2 * m * gw * out_itemsize
+            + world * m * n * out_itemsize
+            + m * gw * 4 + world * m * bn * 4)
+
+
+def _gemm_rs_one_pass_kernel(me_ref, a_ref, b_ref, o_ref, staging, a_vmem,
+                             send, own, land, out_vmem, acc, send_sems,
+                             recv_sems, copy_sems, *, axis: str, world: int,
+                             n_tiles: int, bn: int, group: int, b_tiles):
+    """The walk over the column tiles ONCE (grid ``(n_tiles // group,)``):
+    a step multiplies ALL ``world * m`` rows by each of its ``group``
+    tiles, so every weight tile is copied once and meets the MXU once a
+    call (``b_ref`` is the whole operand in HBM, ``b_tiles`` the ring its
+    tiles pass through). A's row blocks lie in ``a_vmem`` in push order,
+    the remote destinations ``(me + 1 + s) % world`` first and the own
+    block last, so a product's row block ``s`` belongs to a destination
+    known at trace time."""
+    j = pl.program_id(0)
+    me = me_ref[0]
+    m = o_ref.shape[0]
+    n_groups = n_tiles // group
+    gw = group * bn
+    parity = jax.lax.rem(j, 2)
+    dsts = [jax.lax.rem(me + 1 + s, world) for s in range(world)]  # own last
+
+    def a_block(s):
+        return pltpu.make_async_copy(a_ref.at[pl.ds(dsts[s] * m, m)],
+                                     a_vmem.at[pl.ds(s * m, m)],
+                                     copy_sems.at[s])
+
+    ring = _ring_slots(n_tiles)  # tile jj passes through slot jj % ring
+    ahead = ring - 1
+
+    def b_tile(jj):
+        return common.weight_tile_copy(me_ref, b_ref, b_tiles, jj, bn,
+                                       slot=jax.lax.rem(jj, ring))
+
+    @pl.when(j == 0)
+    def _startup():
+        for s in range(world):
+            a_block(s).start()
+        for jj in range(ahead):
+            b_tile(jj).start()
+        dl.barrier_all(axis)  # staging live everywhere before pushes land
+        for s in range(world):
+            a_block(s).wait()
+
+    # A peer's send slot of this parity: its push of two steps back must
+    # have locally drained.
+    @pl.when(j >= 2)
+    def _reclaim():
+        for s in range(world - 1):
+            common.wait_send(send.at[s, parity], send_sems.at[s, parity])
+
+    for t in range(group):
+        tile = j * group + t
+        b_tile(tile).wait()
+
+        # Into the slot whose tile the product before this one consumed.
+        @pl.when(tile + ahead < n_tiles)
+        def _next_tile(tile=tile):
+            b_tile(tile + ahead).start()
+
+        prod = jnp.dot(a_vmem[...], b_tiles[0][jax.lax.rem(tile, ring)],
+                       preferred_element_type=jnp.float32)
+        cols = pl.ds(t * bn, bn)
+        for s in range(world - 1):
+            send[s, parity, :, cols] = prod[s * m:(s + 1) * m].astype(
+                send.dtype)
+        own[j, :, cols] = prod[(world - 1) * m:]
+
+    # The step's piece of each remote destination's rows, to its owner's
+    # staging: world - 1 pushes in flight under the next step's products.
+    for s in range(world - 1):
+        common.remote_copy(
+            send.at[s, parity],
+            staging.at[common.peer_slot(me, dsts[s]), :, pl.ds(j * gw, gw)],
+            send_sems.at[s, parity], recv_sems.at[me], axis, dsts[s])
+
+    @pl.when(j == n_groups - 1)
+    def _fold():
+        for src in range(world):
+            @pl.when(src != me)
+            def _wait(src=src):
+                common.wait_recv(staging.at[common.peer_slot(src, me)],
+                                 recv_sems.at[src])
+
+        # A piece at a time, in loops (a body is compiled once whatever the
+        # pieces: 32 at the mixed step's rows). Every source's partial of a
+        # piece comes in ONE copy, all copies in flight together and all
+        # awaited before any is read (they share a semaphore, which counts
+        # bytes, not pieces); a folded piece leaves for the output while
+        # the next is folded, one such copy in flight.
+        def arrived(g):
+            return pltpu.make_async_copy(
+                staging.at[:, :, pl.ds(g * gw, gw)], land.at[g],
+                copy_sems.at[0])
+
+        def leaves(g):
+            return pltpu.make_async_copy(
+                out_vmem.at[g], o_ref.at[:, pl.ds(g * gw, gw)],
+                copy_sems.at[1])
+
+        def fold_piece(g):
+            # The same sum as the two-axis walk's: FIXED global rank
+            # order, remote partials in the dtype they travelled in, own
+            # in float32.
+            acc[...] = jnp.zeros_like(acc)
+            for src in range(world):
+                @pl.when(src == me)
+                def _add_own():
+                    acc[...] += own[g]
+
+                @pl.when(src != me)
+                def _add_remote(src=src):
+                    acc[...] += land[g, common.peer_slot(src, me)].astype(
+                        jnp.float32)
+            out_vmem[g] = acc[...].astype(out_vmem.dtype)
+
+            @pl.when(g > 0)
+            def _left():
+                leaves(g - 1).wait()
+
+            leaves(g).start()
+
+        def every_piece(fn):
+            jax.lax.fori_loop(0, n_groups, lambda g, _: fn(g), None)
+
+        every_piece(lambda g: arrived(g).start())
+        every_piece(lambda g: arrived(g).wait())
+        every_piece(fold_piece)
+        leaves(n_groups - 1).wait()
+
+        # Drain the last push of each send slot (every earlier one was
+        # reclaimed two steps on).
+        for s in range(world - 1):
+            for p in range(min(2, n_groups)):
+                common.wait_send(send.at[s, p], send_sems.at[s, p])
+
+
 def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
                    config: GEMMRSConfig | None = None, interpret=None,
                    probes: bool = False, layer=None):
@@ -202,12 +412,20 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
     tiles come out of the stack where it lies and no pass stages the
     layer's matrix before the kernel starts.
 
-    The grid walks ``(destination, column tile)``. Where B whole fits the
-    VMEM a kernel may ask for (``common.resident_weight_limit``) its tiles
-    are RESIDENT: copied from HBM once, all copies started at the first
-    step, and multiplied ``world`` times; else each tile comes through the
-    pipeline once a destination, ``world`` reads of B a call. The
-    single-device branch and the ``probes`` build take ``b_local[layer]``."""
+    The walk is chosen from the operands' shapes (module docstring): where A
+    whole, the own block in float32 and the arrived partials fit the VMEM a
+    kernel may ask for (``_one_pass_vmem`` against
+    ``common.fits_kernel_vmem``: Qwen3-8B's TP=4 down projection takes
+    5 MB at a decode step's 64 rows and 14 MB at the mixed step's 512) the
+    column tiles are walked ONCE, every destination's rows in one product a
+    tile, the weight through a ring of a few tiles; else the grid is
+    ``(destination, column tile)``, with B's tiles resident where B whole
+    fits (copied from HBM once and multiplied ``world`` times) and through
+    the pipeline once a destination where it does not. ``block_n`` is the
+    weight's column tile in both. The comm ledger's record says which ran
+    (``method="device_one_pass"`` / ``"device"``). The single-device branch
+    and the ``probes`` build (one record a ``(destination, tile)`` step)
+    take ``b_local[layer]``."""
     config = config or GEMMRSConfig()
     world = _axis_size(axis)
     M, k_local = a_local.shape
@@ -237,25 +455,74 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
     out_dtype = jnp.promote_types(a_local.dtype, b_local.dtype)
     from triton_distributed_tpu.runtime import perf_model as pm
 
-    # Each device scatters its whole (M, n) partial product (M as it
-    # travels: after the Mosaic row pad above). A series of its own beside
-    # the host wrapper's "overlap" (see ag_gemm_device).
-    _ledger.record_traced(
-        "gemm_rs", axis=axis, world=world, method="device",
-        nbytes=pm.wire_bytes_reduce_scatter(
-            M * n * out_dtype.itemsize, world))
     config = config.resolve(m, k_local, n, a_local.dtype.itemsize,
                             out_dtype.itemsize)
     n_tiles = config.n_tiles(n)
     bn = config.block_n
+    isz, osz = a_local.dtype.itemsize, out_dtype.itemsize
 
-    # A rows, B whole, and the send (2), accumulator, remote-partial and
-    # cast-out tiles.
-    resident, vmem_limit = common.resident_weight_limit(
-        (m * k_local + k_local * n) * a_local.dtype.itemsize
-        + m * bn * (4 * out_dtype.itemsize + 4), probes)
+    # The walk, from the operands' shapes: ONE pass over the column tiles
+    # where all of A, the own block and the arrived partials fit the VMEM a
+    # kernel may ask for (the weight passes through a ring), else the grid
+    # (destination, column tile), B resident where B fits. No bound on the
+    # rows: at 256 and 512 a device, the most that fit, the one pass read
+    # 37-47% under the grid on four chips (PERF.md section 6, PR 48's review
+    # round).
+    group = _tiles_a_piece(m, bn, osz, n_tiles)
+    one_pass, vmem_limit = (False, None) if probes else \
+        common.fits_kernel_vmem(
+            _one_pass_vmem(world, m, k_local, n, bn, group, isz, osz))
+    # ``tile_slots``: the VMEM slots the kernel copies B's tiles into
+    # itself, B whole in HBM (0: the tiles come through the pipeline).
+    if one_pass:
+        tile_slots = _ring_slots(n_tiles)
+        n_groups, gw = n_tiles // group, group * bn
+        grid = (n_groups,)
+        kernel = functools.partial(
+            _gemm_rs_one_pass_kernel, axis=axis, world=world,
+            n_tiles=n_tiles, bn=bn, group=group)
+        scratch_shapes = [
+            pltpu.VMEM((M, k_local), a_local.dtype),          # A, push order
+            pltpu.VMEM((world - 1, 2, m, gw), out_dtype),     # send slots
+            pltpu.VMEM((n_groups, m, gw), jnp.float32),       # own block
+            pltpu.VMEM((n_groups, world - 1, m, gw), out_dtype),  # arrived
+            pltpu.VMEM((n_groups, m, gw), out_dtype),         # cast-out
+            pltpu.VMEM((m, gw), jnp.float32),                 # fold acc
+            common.dma_sems((world - 1, 2)),           # send (peer, parity)
+            common.dma_sems(world),                    # recv (slot per src)
+            common.dma_sems(world),                    # A's row blocks
+        ]
+    else:
+        # A rows, B whole, and the send (2), accumulator, remote-partial
+        # and cast-out tiles.
+        resident, vmem_limit = common.resident_weight_limit(
+            (m * k_local + k_local * n) * isz + m * bn * (4 * osz + 4),
+            probes)
+        tile_slots = n_tiles if resident else 0
+        grid = (world, n_tiles)
+        kernel = functools.partial(_gemm_rs_kernel, axis=axis, world=world,
+                                   n_tiles=n_tiles, bn=bn)
+        scratch_shapes = [
+            pltpu.VMEM((m, k_local), a_local.dtype),  # dst-segment A rows
+            pltpu.VMEM((2, m, bn), out_dtype),        # per-tile send buffer
+            pltpu.VMEM((m, bn), jnp.float32),         # own-tile accumulator
+            pltpu.VMEM((m, bn), out_dtype),           # remote-partial tile
+            pltpu.VMEM((m, bn), out_dtype),           # cast-out tile
+            common.dma_sems(2),                       # send (by tile parity)
+            common.dma_sems(world),                   # recv (slot per src)
+            pltpu.SemaphoreType.DMA(()),
+        ]
+
+    # Each device scatters its whole (M, n) partial product (M as it
+    # travels: after the Mosaic row pad above). A series of its own beside
+    # the host wrapper's "overlap" (see ag_gemm_device); ``method`` names
+    # the walk this call took.
+    _ledger.record_traced(
+        "gemm_rs", axis=axis, world=world,
+        method="device_one_pass" if one_pass else "device",
+        nbytes=pm.wire_bytes_reduce_scatter(M * n * osz, world))
     me, b_spec = common.rank_and_weight_spec(axis, k_local, bn, layer,
-                                             resident)
+                                             tile_slots > 0)
 
     # Incoming-partials staging is an ANY-space OUTPUT (discarded): Mosaic
     # does not allocate HBM scratch, and peer pushes need a stable HBM buffer
@@ -269,25 +536,13 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
         common.hbm_spec(),                                    # (m, N)
         common.hbm_spec(),                                    # staging
     ]
-    scratch_shapes = [
-        pltpu.VMEM((m, k_local), a_local.dtype),  # dst-segment A rows
-        pltpu.VMEM((2, m, bn), out_dtype),        # per-tile send buffer
-        pltpu.VMEM((m, bn), jnp.float32),         # own-tile accumulator
-        pltpu.VMEM((m, bn), out_dtype),           # remote-partial tile
-        pltpu.VMEM((m, bn), out_dtype),           # cast-out tile
-        common.dma_sems(2),                       # send (by tile parity)
-        common.dma_sems(world),                   # recv (slot per src)
-        pltpu.SemaphoreType.DMA(()),
-    ]
-    kernel = functools.partial(_gemm_rs_kernel, axis=axis, world=world,
-                               n_tiles=n_tiles, bn=bn)
     out_shape = [
         jax.ShapeDtypeStruct((m, n), out_dtype),
         jax.ShapeDtypeStruct((world - 1, m, n), out_dtype),
     ]
-    if resident:
+    if tile_slots:
         kernel, scratch_shapes = common.with_resident_tiles(
-            kernel, scratch_shapes, n_tiles, k_local, bn, b_local.dtype)
+            kernel, scratch_shapes, tile_slots, k_local, bn, b_local.dtype)
     if probes:
         n_steps = world * n_tiles
 
@@ -305,7 +560,7 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
         out_shape = [*out_shape, _probes.out_shape(n_steps)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(world, n_tiles),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
@@ -319,7 +574,7 @@ def gemm_rs_device(a_local, b_local, *, axis: str = "tp",
         cost_estimate=common.cost_estimate(
             flops=2 * M * k_local * n,
             bytes_accessed=(M * k_local * a_local.dtype.itemsize
-                            + (1 if resident else world) * k_local * n
+                            + (1 if tile_slots else world) * k_local * n
                             * b_local.dtype.itemsize
                             + M * n * out_dtype.itemsize),
             remote_bytes=(world - 1) * m * n * out_dtype.itemsize),
@@ -585,4 +840,42 @@ def _comm_spec_gemm_rs(world: int) -> "_comm.TraceSpec":
         ],
         grid=(world, n_tiles),
         kwargs=dict(axis="tp", world=world, n_tiles=n_tiles, bn=bn),
+    )
+
+
+@_comm.register("gemm_rs.one_pass")
+def _comm_spec_gemm_rs_one_pass(world: int) -> "_comm.TraceSpec":
+    # Six column tiles in pieces of two: three grid steps (the third
+    # reclaims the first's send slots) over a ring of five weight tiles.
+    m, k, bn, n_tiles, group = 8, 128, 128, 6, 2
+    n, gw = bn * n_tiles, bn * group
+
+    def body(*refs, **kw):  # as common.with_resident_tiles hands them
+        _gemm_rs_one_pass_kernel(*refs[:-2], b_tiles=refs[-2:], **kw)
+
+    return _comm.TraceSpec(
+        body=body,
+        args=[
+            _comm.Buf("me", (1,), _np.int32, space="smem",
+                      init=lambda r, w: _np.array([r], _np.int32)),
+            _comm.Buf("a", (world * m, k)),
+            _comm.Buf("b", (k, n)),
+            _comm.Buf("o", (m, n), covered=True),
+            _comm.Buf("staging", (world - 1, m, n)),
+            _comm.Buf("a_vmem", (world * m, k), space="vmem"),
+            _comm.Buf("send", (world - 1, 2, m, gw), space="vmem"),
+            _comm.Buf("own", (n_tiles // group, m, gw), space="vmem"),
+            _comm.Buf("land", (n_tiles // group, world - 1, m, gw),
+                      space="vmem"),
+            _comm.Buf("out_vmem", (n_tiles // group, m, gw), space="vmem"),
+            _comm.Buf("acc", (m, gw), space="vmem"),
+            _comm.Sem("send_sems", (world - 1, 2)),
+            _comm.Sem("recv_sems", (world,)),
+            _comm.Sem("copy_sems", (world,)),
+            _comm.Buf("b_vmem", (_ring_slots(n_tiles), k, bn), space="vmem"),
+            _comm.Sem("b_sems", (_ring_slots(n_tiles),)),
+        ],
+        grid=(n_tiles // group,),
+        kwargs=dict(axis="tp", world=world, n_tiles=n_tiles, bn=bn,
+                    group=group),
     )

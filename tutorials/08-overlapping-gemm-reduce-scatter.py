@@ -8,13 +8,19 @@ of the TP pair):
   scattered by M. Matmul-then-reduce-scatter serializes; the reference
   overlaps by having the producer GEMM ``notify`` per-tile barriers while
   an RS consumer on a second stream scatters tiles as they complete.
-* The TPU redesign (one Pallas kernel): the grid walks destination
-  segments in swizzled order ``dst = (me + 1 + s) % world`` — REMOTE
-  segments first. The moment a remote tile's partial product leaves the
-  MXU it is pushed over ICI to its owner (async DMA from a
-  parity-double-buffered VMEM tile); the own segment comes last, folding
-  arrivals in a FIXED global rank order (bitwise rank-independent sums).
-* All world-1 pushes are in flight while the MXU computes later segments —
+* The TPU redesign (one Pallas kernel, one algorithm, two loop nests
+  chosen from the operands' shapes). With few rows (the shapes here, and a
+  served model's decode and mixed steps: A whole and the own block fit
+  VMEM) the kernel walks the column tiles ONCE: a weight tile is copied
+  once, meets the MXU once for every destination's rows, and each peer's
+  rows of the result are pushed over ICI to their owner in pieces of a few
+  tiles (async DMA from VMEM slots double-buffered per peer) while later
+  tiles are multiplied. At prefill-sized M the grid walks ``(destination,
+  column tile)`` in swizzled order ``dst = (me + 1 + s) % world``, REMOTE
+  segments first, a tile pushed the moment its product leaves the MXU.
+  Either way the own rows come last, folding arrivals in a FIXED global
+  rank order (bitwise rank-independent sums).
+* All world-1 pushes are in flight while the MXU computes later tiles —
   same hiding argument as AG-GEMM, mirrored.
 * Across slices: ``gemm_rs_2d_device`` runs a ring reduce-scatter over the
   DCN axis at slice-block granularity (add-and-forward ppermute), with the
