@@ -34,7 +34,14 @@ class with a third kind of operator: LFM2-24B-A2B's widths, its two dense
 conv layers and one period ``full, conv, conv, conv``, 8 of 64 experts held:
 the gated short convolution's one-token update, the ``short_conv_update``
 kernel compiled by Mosaic, against its chunk shape, a slot's rows chained
-through the window). In all five the
+through the window); and the EVABYTE block (``models/evabyte.py``:
+EvaByte 6.5B's widths, two of its 32 layers, the whole byte vocabulary
+under all eight prediction heads: EVA attention, whose every layer keeps a
+ring of its aligned window's rows a slot AND one pooled row a chunk of 16
+in the block arenas): the two EVA builds of the block walk and the
+producer of the summaries at their chunk shape against their decode shape,
+over a walk that passes the window boundary at 2,048, so that the decode
+step reads 128 summaries that the other program pooled. In all six the
 chunk shape takes every walked prompt several rows of the prefill block a
 step, every prompt is held to the tolerance, and where the model routes both
 programs' routers are on record: a prompt is left out only where its token
@@ -138,6 +145,15 @@ LFM2_MOE = dict(HYBRID, config="Lfm2MoeConfig",
                     layer_types=("conv", "conv", "full_attention", "conv",
                                  "conv", "conv"),
                     experts_held=8))
+
+# And over the EvaByte block (a class of its own): the published widths, two
+# layers, the byte vocabulary under eight heads (0.83 GB of weights). The
+# walk passes the first window boundary (2,048): past it a query reads its
+# own window from position 2,048 on and the first window through 128
+# summaries, pooled chunk by chunk by the decode-shaped step in one program
+# and four to a row by the chunk shape in the other.
+EVABYTE = dict(HYBRID, config="EvaByteConfig", overrides=dict(n_layers=2),
+               prompt_range=(2300, 2600), walk_len=2200)
 
 # Largest |difference| of two logit rows over the largest |reference logit|.
 # bf16 keeps 8 mantissa bits (2^-8 per rounded op); over 28-36 layers of
@@ -741,12 +757,22 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
          **{f"{kept}_{k}": c.get(f"{kept}_{k}", 0.0)
             for k in ("rows_advanced", "states_reset")},
          kv_rows_appended=c.get("kv_rows_appended", 0.0),
+         **{k: c[k] for k in ("eva_summaries_written", "eva_windows_opened")
+            if k in c},
          prefill_rows_extra=c.get("prefill_rows_extra", 0.0),
          moe_pairs_held=c.get("moe_pairs_held"),
          moe_dropped_pairs=c.get("moe_dropped_pairs"),
          trace_counts=be.trace_counts)
-    want = {"kv_rows_appended":
-            tokens * (cfg.n_cache_layers + window_layers)}
+    # a row a token in every layer that keeps rows, by the context or by
+    # the window (where a row of the arenas stands for several tokens, an
+    # EVA layer's summaries, the ring's rows are the ones a token appends)
+    summarised = getattr(cfg, "kv_row_tokens", 1) != 1
+    want = {"kv_rows_appended": tokens * (
+        window_layers + (0 if summarised else cfg.n_cache_layers))}
+    if summarised:
+        want["eva_summaries_written"] = cfg.n_cache_layers * sum(
+            (len(p) + geo["new_tokens"] - 1) // cfg.kv_row_tokens
+            for p in prompts)
     if state_layers:
         want.update({f"{kept}_rows_advanced": tokens * state_layers,
                      f"{kept}_states_reset": geo["n_requests"]})
@@ -786,11 +812,13 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
 def run_served_blocks(devices, geo: dict, caches: _CacheEvents) -> None:
     """The one-chip smoke: the dense model, then (its buffers dropped) the
     hybrid block, then the Nemotron-H block, then the EXAONE-MoE block, then
-    the SmallThinker block, then the LFM2-MoE block."""
+    the SmallThinker block, then the LFM2-MoE block, then the EvaByte
+    block."""
     import gc
 
     run_one_chip(devices, geo, caches)
-    for block in (HYBRID, NEMOTRON_H, EXAONE_MOE, SMALLTHINKER, LFM2_MOE):
+    for block in (HYBRID, NEMOTRON_H, EXAONE_MOE, SMALLTHINKER, LFM2_MOE,
+                  EVABYTE):
         gc.collect()
         run_hybrid(devices, block, caches)
 

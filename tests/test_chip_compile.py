@@ -836,3 +836,90 @@ def test_lfm2_step_compiles_with_its_windows_in_place(topo, kind):
     # weights, the pool and the step's own buffers fit the chip's 16 GB
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.75e9
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_evabyte_step_compiles_with_ring_and_summaries_in_place(topo, kind):
+    """The whole served step of evabyte-6.5b-l8 (layers 0-7 at the published
+    widths: 32 key heads of 128, a SwiGLU of 11,008, the vocabulary of 320
+    under eight heads; 24 slots, a ring of 156 blocks a slot a layer behind
+    the window of 2,048 and a take of 448, 1,280 blocks of summary rows a
+    layer behind a 128-wide block table), as ``BatchEngine`` builds it
+    around ``forward_paged``. It compiles with the two EVA builds of the
+    block walk named, every arena of the pool's state (the rings AND the
+    rows, in EVERY layer) is aliased in to out, the step's temporaries hold
+    no copy of a ring, of an arena or of a layer's slice of a weight stack
+    (a layer of one ring is 0.49 GB, the smallest matrix 33 MB; the decode /
+    mixed step hold 2 / 19 MB), and the arguments are the
+    13.8 GB the configuration's file states."""
+    import json
+
+    from perfbench.families import evabyte as family
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.models.evabyte import EvaByte
+    from triton_distributed_tpu.serving.kv_pool import (
+        blocks_needed,
+        paged_state_shapes,
+        paged_state_specs,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "perfbench/configs/evabyte-6.5b-l8.json")) as f:
+        file = json.load(f)
+    cfg = family.program_config(file, family.sizes(file))
+    fleet = file["serve"]["fleet"]
+    slots, rows = fleet["n_slots"], HYB_PREFILL_ROWS
+    assert (slots, fleet["block_size"], fleet["prefill_chunk"]) == \
+        (24, BLOCK, CHUNK)
+    table = blocks_needed(cfg.max_length, BLOCK, cfg.kv_row_tokens)
+    assert table == 128
+    mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
+    here = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, here), tree)
+
+    params = placed(jax.eval_shape(
+        lambda k: EvaByte(cfg).init(k, mesh), jax.random.PRNGKey(0)))
+    state = placed(paged_state_shapes(
+        cfg, n_blocks=fleet["n_blocks"], block_size=BLOCK, n_slots=slots,
+        max_take=rows * CHUNK))
+    assert state.wk.shape == (8, slots, 156, BLOCK, 32, DH)
+    assert state.k.shape == (8, fleet["n_blocks"], BLOCK, 32, DH)
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(tree))
+
+    state_bytes = nbytes(state)
+    assert nbytes(params) == pytest.approx(3.262e9, rel=1e-3)
+    assert 13.7e9 < state_bytes + nbytes(params) < 15.2e9
+    engine = Engine(cfg, mesh=mesh, params=params, mode="dist",
+                    interpret=False)
+    step = jax.jit(
+        engine._make_sm("dist", paged=kind, paged_attn="fused",
+                        state_specs=paged_state_specs(cfg)),
+        donate_argnums=(2,))
+    ops = (_sds((slots,), jnp.int32, here),
+           _sds((slots, table), jnp.int32, here),
+           _sds((slots,), bool, here))
+    if kind == "decode":
+        args = (_sds((slots, 1), jnp.int32, here), state, *ops)
+    else:
+        ids = (_sds((slots,), jnp.int32, here),
+               _sds((rows, CHUNK), jnp.int32, here),
+               _sds((rows, 3), jnp.int32, here))
+        args = (ids, state, *ops, _sds((slots,), jnp.int32, here))
+    compiled = step.lower(params, *args).compile()
+    text = compiled.as_text()
+    # ONE layer body: two walks (each twice in the mixed step: the decode
+    # block and the prefill block)
+    assert text.count("tpu_custom_call") == (2 if kind == "decode" else 4)
+    assert "eva_attn_window" in text and "eva_attn_summary" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < 30e6, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.75e9

@@ -557,3 +557,34 @@ def test_kv_pool_arrays_are_allocated_in_their_sharded_layout(mesh8,
 def test_cache_dir_helper_is_fixed_and_in_checkout():
     assert _platform.cache_dir("jax") == os.path.join(_REPO, ".cache", "jax")
     assert _platform.cache_dir() == os.path.join(_REPO, ".cache")
+
+
+def test_evabyte_phase_passes_at_tiny(capsys, restore_compile_cache_config):
+    """The same phase over the EvaByte block (a class of its own: EVA
+    attention in every layer) at tiny float32 sizes: a window of 32 in
+    chunks of 4, the walk past two window boundaries, so that the chunk
+    shape and the decode shape each pool the summaries the other reads; the
+    counts of rows appended (the ring's, a token a layer) and of summaries
+    written add up."""
+    import dataclasses
+
+    from triton_distributed_tpu.models.config import EvaByteConfig
+
+    geo = dict(chip_smoke.EVABYTE, interpret=None, paged_attn="gather",
+               n_slots=6, block_size=4, prefill_chunk=8, n_requests=3,
+               prompt_range=(80, 100), new_tokens=3, walk_len=75,
+               overrides=dataclasses.asdict(EvaByteConfig.tiny()))
+    assert chip_smoke.EVABYTE["overrides"] == {"n_layers": 2}
+    assert chip_smoke.EVABYTE["walk_len"] > EvaByteConfig().window
+    rc = chip_smoke.smoke(chip_smoke.run_hybrid, jax.devices()[:1], geo)
+    records = [json.loads(line)
+               for line in capsys.readouterr().out.strip().splitlines()]
+    assert rc == 0 and records[-1]["ok"] is True
+    assert records[0]["state_layers"] == 0
+    assert records[0]["cache_layers"] == records[0]["window_layers"] == 3
+    assert records[0]["window"]["window"] == 32
+    assert records[1]["trace_counts"] == {"decode": 1, "prefill": 1}
+    assert records[1]["eva_summaries_written"] > 0
+    assert records[1]["eva_windows_opened"] >= 3 * 2   # 80+ tokens: 32, 64
+    assert records[1]["prefill_rows_extra"] > 0
+    assert records[2]["prefill_rel"] < 1e-4 and records[2]["decode_rel"] < 1e-4

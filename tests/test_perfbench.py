@@ -90,6 +90,9 @@ OF_A_FAMILY = {
     "lfm2_moe": re.compile(
         r"lfm2|Lfm2MoeConfig|conv_L_cache|num_dense_layers|use_expert_bias",
         re.IGNORECASE),
+    "evabyte": re.compile(
+        r"evabyte|EvaByteConfig|num_pred_heads|norm_add_unit_offset|"
+        r"attention_class|adaptive_mu", re.IGNORECASE),
 }
 
 
@@ -219,7 +222,8 @@ def test_the_span_readers_entries_name_their_cells_and_find_their_readers():
                               "collective_device_share",
                               "ici_bytes_per_step",
                               "short_conv_device_share",
-                              "short_conv_roofline"]
+                              "short_conv_roofline",
+                              "eva_attn_device_share", "eva_attn_roofline"]
 
 
 def test_the_window_readers_arithmetic_and_silence_on_an_older_program():
@@ -566,8 +570,8 @@ def test_the_lfm2_cell_is_files_and_its_configuration_states_its_cut():
     LFM2-24B-A2B under its own keys at its published values, ``num_experts``
     (8 held of 64) and ``max_position_embeddings`` alone reduced, the cut
     written out; the benchmark's own ``reasoning`` mix unchanged on one
-    chip; the readers it reports found by name, its two new ones the LAST
-    two of ``per_layer``."""
+    chip; the readers it reports found by name, its two new ones side by
+    side where it appended them."""
     from perfbench import core, families
     from perfbench.traffic_kinds.closed_loop import Plan
 
@@ -634,7 +638,11 @@ def test_the_lfm2_cell_is_files_and_its_configuration_states_its_cut():
     for name in reported:
         mod = core.reader_module("layer_metrics", name)
         assert callable(__import__(mod, fromlist=["read"]).read)
-    last = bench["per_layer"][-2:]
+    # (its two, side by side, where PR 46 appended them: the entries a
+    # later PR appended lie behind)
+    at = [m["name"] for m in bench["per_layer"]].index(
+        "short_conv_device_share")
+    last = bench["per_layer"][at:at + 2]
     assert [m["name"] for m in last] == ["short_conv_device_share",
                                          "short_conv_roofline"]
     assert all(m["workloads"] == [LFM2] and m["layer"] == "kernels"
@@ -740,4 +748,213 @@ def test_the_short_conv_readers_arithmetic_and_silence_on_an_older_program():
     assert short_conv_roofline.read(bare) is None
     assert short_conv_roofline.read(record(ops, spans=None)) is None
     assert short_conv_roofline.read(
+        record(ops, family=types.SimpleNamespace())) is None
+
+
+EVA = "evabyte-6.5b-l8.reasoning-bytes"
+
+
+def test_the_evabyte_cell_is_files_and_its_configuration_states_its_cut():
+    """``evabyte-6.5b-l8.reasoning-bytes`` by name: the catalog's row of
+    EvaByte under its own keys at its published values, ``num_hidden_layers``
+    (8 of 32) alone reduced, the cut and the three assumed points written
+    out; a closed loop of 24 clients whose every context lies past the first
+    window and ends inside 28,672 positions; the readers it reports found by
+    name, its two new ones the LAST two of ``per_layer``."""
+    from perfbench import core, families
+    from perfbench.traffic_kinds.closed_loop import Plan
+
+    bench = core.load_json(core.ROOT, "BENCHMARK.json")
+    entry, = [c for c in bench["configs"] if c["name"] == "evabyte-6.5b-l8"]
+    assert entry["reduced"] == ["num_hidden_layers"]
+    assert len(entry["why"]) <= 200
+    spec = core.load_cell(EVA)
+    assert spec["cell"]["chips"] == 1
+    assert spec["cell"]["traffic"] == "reasoning-bytes"
+    assert len(spec["cell"]["why"]) <= 200
+    cfg = spec["config"]
+    assert cfg["source"] == entry["source"] == \
+        "https://huggingface.co/EvaByte/EvaByte/blob/main/config.json"
+    published = {
+        "attention_bias": False, "attention_class": "eva", "chunk_size": 16,
+        "fp32_ln": False, "fp32_logits": True, "fp32_skip_add": True,
+        "hidden_act": "silu", "hidden_size": 4096,
+        "init_cutoff_factor": None, "init_fn": "v2", "init_std": 0.01275,
+        "intermediate_size": 11008, "lazy_init": True,
+        "max_position_embeddings": 32768, "max_seq_length": 32768,
+        "mixedp_attn": True, "model_type": "evabyte",
+        "norm_add_unit_offset": True, "num_attention_heads": 32,
+        "num_chunks": None, "num_key_value_heads": 32, "num_pred_heads": 8,
+        "rms_norm_eps": 1e-05, "rope_scaling": None, "rope_theta": 100000,
+        "tie_word_embeddings": False, "vocab_size": 320,
+        "window_size": 2048}
+    assert {k: cfg[k] for k in published} == published
+    assert (cfg["num_hidden_layers"],
+            cfg["num_hidden_layers_published"]) == (8, 32)
+    assert cfg["reduced"] == entry["reduced"] and cfg["family"] == "evabyte"
+    assert {"num_hidden_layers", "rope_before_pooling", "pooling_scores",
+            "pooling_vectors", "attention", "norms_and_residual",
+            "prediction_heads", "weights", "torch_dtype"} \
+        <= set(cfg["assumed"])
+    assert "STAGES OF A PIPELINE" in cfg["deployment"] and cfg["chips"] == 1
+    assert cfg["serve"]["mesh"] == {"tp": 1}
+    fleet = cfg["serve"]["fleet"]
+    assert {k: fleet[k] for k in ("n_replicas", "n_slots", "block_size",
+                                  "prefill_chunk", "paged_attn")} == {
+        "n_replicas": 1, "n_slots": 24, "block_size": 16,
+        "prefill_chunk": 64, "paged_attn": "fused"}
+    assert 1280 <= fleet["n_blocks"] <= 1792
+    family = families.load_family(cfg)
+    assert family.__name__ == "perfbench.families.evabyte"
+    sizes = family.sizes(cfg)
+    # 8 x 202,391,552 + 11,796,480 (+ the final norm's 4,096): 3.26 GB
+    assert family.layer_params(sizes) + 2 * 4096 + 2 * 32 * 128 == 202_391_552
+    assert family.params_held(sizes) == 8 * 202_391_552 + 11_796_480
+    assert sizes.row_bytes == 16_384 and sizes.per_window == 128
+    # the mix: every context past one window, every request inside 28,672
+    assert spec["traffic"]["kind"] == "closed_loop"
+    plan = Plan(spec["traffic"], seed=5, seconds=40, vocab=sizes.vocab_size,
+                max_total=sizes.max_length, n_slots=24)
+    standing = plan.standing()
+    assert len(standing) == 24
+    assert all(len(p.prompt) >= 2048 for p in standing)
+    assert all(len(p.prompt) + p.max_new_tokens <= 28_672 for p in standing)
+    assert all(2048 <= p and p + o <= 28_672 and o >= 8192
+               for p, o in plan._later)
+    live = sum(len(p.prompt) for p in standing)
+    assert 200_000 < live < 300_000
+    # ... which the pool's blocks hold at 256 positions a block, with room
+    # (the loop's steady state: what ends makes room for what grows)
+    from triton_distributed_tpu.serving.kv_pool import blocks_needed
+    assert sum(blocks_needed(len(p.prompt) + 1, 16, 16)
+               for p in standing) <= 0.85 * fleet["n_blocks"]
+    assert set(spec["limits"]) == {"gap_max", "gap_mean"}
+    assert set(spec["limits"]) < set(core.load_json(
+        core.ROOT, "perfbench", "cells", EVA + ".json")["why"])
+    assert spec["sample"] == {**core.SAMPLE, "requests": 2,
+                              "token_budget": 45_000}
+    assert {m["name"] for m in spec["end_to_end"]} == {
+        "itl_p95_ms", "out_tokens_per_s", "setup_s"}
+    reported = [m["name"] for m in spec["per_layer"]]
+    assert set(reported) == {
+        "decode_occupancy", "kv_used_share_peak", "preemptions",
+        "decode_step_ms", "mixed_step_ms.reasoning", "decode_step_roofline",
+        "host_turn_ms.reasoning", "host_dispatch_ms", "host_observe_ms",
+        "eva_attn_device_share", "eva_attn_roofline"}
+    for name in reported:
+        mod = core.reader_module("layer_metrics", name)
+        assert callable(__import__(mod, fromlist=["read"]).read)
+    last = bench["per_layer"][-2:]
+    assert [m["name"] for m in last] == ["eva_attn_device_share",
+                                         "eva_attn_roofline"]
+    assert all(m["workloads"] == [EVA] and m["layer"] == "kernels"
+               and m["moves"] == "out_tokens_per_s"
+               and m["source"] == "device_trace" for m in last)
+
+
+def test_the_evabyte_family_passes_the_harness_checks_at_a_tiny_size():
+    """The family at a tiny float32 size through the harness's own
+    comparison (``check.compare``): what the PROGRAM serves (``BatchEngine``,
+    prefill then decode through the ring and the chunk summaries, past three
+    window boundaries) is the reference's best at every position, and the
+    float8 control, the reference in the precision below put in the
+    program's place, is not correct."""
+    import jax
+    import numpy as np
+
+    from conftest import PLAIN_PATH
+    from perfbench import check
+    from perfbench.families import evabyte as family
+    from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.runtime.mesh import make_mesh
+    from triton_distributed_tpu.serving.batch_engine import BatchEngine
+
+    sizes = family.Sizes(
+        vocab_size=40, d_model=64, n_layers=3, heads=4, head_dim=16, d_ff=96,
+        window=32, chunk=4, pred_heads=8, theta=1e4, eps=1e-5,
+        max_length=160, dtype="float32")
+    mesh = make_mesh({"tp": 1}, devices=jax.devices()[:1], set_default=False)
+    mcfg, params = family.program({"source": "t"}, sizes, 17, mesh, {})
+    be = BatchEngine(Engine(mcfg, mesh=mesh, params=params, mode="dist"),
+                     n_slots=2, n_blocks=24, block_size=4, prefill_chunk=8,
+                     **PLAIN_PATH)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 40, n).tolist() for n in (9, 70, 33)]
+    rids = [be.submit(p, 40) for p in prompts]
+    be.run()
+    sample = [(p, be.finished[r].output) for p, r in zip(prompts, rids)]
+    verdict = check.compare(family, sizes, 17, sample, jax.devices()[0],
+                            limits={"gap_max": 1e-3, "gap_mean": 1e-5},
+                            control=True)
+    assert verdict["correct"] is True and verdict["tokens"] == 120
+    assert verdict["compared"]["top1_share"] == 1.0
+    assert verdict["control_correct"] is False
+    assert verdict["control"]["gap_max"] > 3e-3
+
+
+def test_the_eva_readers_arithmetic_and_silence_on_an_older_program():
+    """The two readers this cell adds, on a hand-made record at the
+    published sizes: the calls' device time by their ``name=``, the family's
+    bytes over the rows THE PROGRAM counted on its ``decode_step`` spans
+    (exact and summary rows alike; a mixed step's spans are left out). On a
+    program that has no such call or no such attribute (the parent commit),
+    on a family without the count and on an untraced run each returns None
+    and does not raise."""
+    import types
+
+    from perfbench import core, families
+    from perfbench.layer_metrics import (
+        eva_attn_device_share,
+        eva_attn_roofline,
+    )
+
+    spec = core.load_cell(EVA)
+    family = families.load_family(spec["config"])
+    sizes = family.sizes(spec["config"])
+
+    def span(name, **attrs):
+        return types.SimpleNamespace(name=name, attrs=attrs or None)
+
+    # 24 rows at context 10,240 + 1,023: 1,024 exact and 640 summary rows
+    # a row a layer
+    exact, seen = 24 * 8 * 1024, 24 * 8 * 640
+    assert family.rows_needed(sizes, 11_263) == (1024, 640)
+    counted = [span("decode_step", eva_exact_rows=exact,
+                    eva_summary_rows=seen, decode_rows=24)
+               for _ in range(100)] \
+        + [span("mixed_step", eva_exact_rows=exact, eva_summary_rows=seen),
+           span("engine.dispatch", kind="decode")]
+
+    def record(ops_s, spans=counted, family=family, trace=True):
+        return core.Records(
+            t_open=0.0, t_close=11.0, t_end=12.0, setup_s=1.0, tracked=[],
+            steps=[], kv_live=[], counters={}, queue_wait_s=[], sizes=sizes,
+            family=family, n_slots=24, n_chips=1, device_kind="TPU v5 lite",
+            trace={"host_window": (10.0, 11.0), "busy_s": 2.0,
+                   "ops_s": ops_s, "program_spans": spans} if trace else None)
+
+    ops = {"eva_attn_window.3": 0.6, "eva_attn_summary.7": 0.4,
+           "window_paged_attention.2": 0.2, "fusion.9": 0.3}
+    rec = record(ops)
+    assert eva_attn_device_share.read(rec) == pytest.approx(50.0)
+    floor_s = 100 * (exact + seen) * 16_384 / 819e9
+    assert floor_s > 100 * (exact + seen) * 4 * 4096 / 197e12
+    assert eva_attn_roofline.read(rec) == pytest.approx(100 * floor_s / 1.0)
+    assert eva_attn_roofline.read(rec) < 100
+    # a decode step's least bytes: the weights and C / 16 rows a layer
+    assert family.decode_step_min_bytes(sizes, [24 * 11_263]) == \
+        pytest.approx(2 * (8 * (4 * 4096 ** 2 + 3 * 4096 * 11008)
+                           + 4096 * 320)
+                      + 8 * 16_384 * 24 * 11_263 / 16)
+    assert family.decode_step_min_bytes(sizes, [24 * 11_263]) <= \
+        family.weight_bytes_read(sizes) + 24 * 8 * 16_384 * (1024 + 640)
+    # the parent: no such call in the trace, no such attribute on a span
+    old = record({"window_paged_attention.2": 0.5})
+    bare = record(ops, spans=[span("decode_step", decode_rows=24)])
+    for reader in (eva_attn_device_share, eva_attn_roofline):
+        assert reader.read(old) is None
+        assert reader.read(record(ops, trace=False)) is None
+    assert eva_attn_roofline.read(bare) is None
+    assert eva_attn_roofline.read(record(ops, spans=None)) is None
+    assert eva_attn_roofline.read(
         record(ops, family=types.SimpleNamespace())) is None

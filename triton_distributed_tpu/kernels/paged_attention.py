@@ -272,7 +272,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                        g: int, q_tile: int, n_q_tiles: int,
                        probe_steps: int = 0, v_dim: int | None = None,
                        folded: bool = False, compiled: bool = False,
-                       window: int | None = None):
+                       window: int | None = None, aligned: bool = False,
+                       summary: tuple | None = None, stats: bool = False):
     """One (slot, query-tile) grid step of fused paged attention: the kv
     tiles of the slot are walked by a loop INSIDE the step, two staging
     slots deep.
@@ -358,6 +359,21 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     The pipeline's order, the semaphores and both arithmetics are the K+V
     build's own.
 
+    THE TWO HALVES OF AN EVA LAYER (``layers/eva_attn.py``) are two more
+    static choices of WHERE A QUERY'S KEYS BEGIN AND END, each over arenas
+    this kernel already walks. ``aligned`` (with ``window``): the window
+    does not slide: a query at ``p`` sees ``(p // window) * window <= j <=
+    p``, its own window from its first position (one key at a boundary).
+    ``summary=(window, rows)`` (the K+V build over the block arenas, whose
+    row ``c`` stands for the ``window // rows`` positions of chunk ``c``): a
+    query at ``p`` sees the rows ``c < rows * (p // window)``, every chunk
+    of every EARLIER window and none of its own; ``kv_lens`` and ``q_lens``
+    stay the token positions and the row limit is computed here, a query
+    row at a time, so a query tile that straddles a boundary is exact.
+    ``stats``: the call also returns each query row's running maximum and
+    denominator, what one combine of the two halves needs
+    (``nn.eva_attn_with_cache``).
+
     Builds, by ``n_arenas``: 2 — K and V ``(..., Hkv, dh)``. 4 — a
     QUANTIZED pool (int8/fp8 wire dtype): the per-row f32 scale arenas
     ``(..., Hkv)`` ride the same pipeline and dequant happens HERE, right
@@ -375,6 +391,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     arenas = refs[5:5 + n_arenas]
     o_ref = refs[5 + n_arenas]
     rest = refs[6 + n_arenas:]
+    if stats:
+        (m_out, l_out), rest = rest[:2], rest[2:]
     probe = _probes.NULL
     if probe_steps:
         probe = _probes.Probe(rest[0], rest[-1], n_steps=probe_steps)
@@ -400,6 +418,10 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         kv_len, q_len = kvlen_ref[b], qlen_ref[b]
         jmax_p1 = jnp.minimum((qt + 1) * q_tile, q_len)
         limit = jnp.minimum(kv_len, kv_len - q_len + jmax_p1)
+        if summary is not None:
+            # rows, not positions: the chunks of the windows before the
+            # tile's last live query's own
+            limit = summary[1] * (jnp.maximum(limit - 1, 0) // summary[0])
         n_live = jnp.where(
             qt * q_tile < q_len,
             jnp.clip(pl.cdiv(limit, span), 0, n_tiles), 0)
@@ -414,8 +436,9 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         here is traced."""
         if window is None:
             return 0, 0, n_live
-        lo = jnp.maximum(
-            kvlen_ref[b] - qlen_ref[b] + qt * q_tile - (window - 1), 0)
+        head = kvlen_ref[b] - qlen_ref[b] + qt * q_tile
+        lo = ((jnp.maximum(head, 0) // window) * window if aligned
+              else jnp.maximum(head - (window - 1), 0))
         first = lo // span
         return lo, first, jnp.maximum(n_live - first, 0)
 
@@ -639,8 +662,14 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         # and emit exact zeros at the end — the varlen contract.
         j = (qt * q_tile
              + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0) // g)
-        valid = (j < q_len) & (pos <= kv_len - q_len + j)
-        if window is not None:
+        if summary is not None:
+            valid = (j < q_len) & (pos < summary[1] * (
+                (kv_len - q_len + j) // summary[0]))
+        else:
+            valid = (j < q_len) & (pos <= kv_len - q_len + j)
+        if aligned:
+            valid &= pos >= ((kv_len - q_len + j) // window) * window
+        elif window is not None:
             valid &= pos > kv_len - q_len + j - window
         for h in range(n_kv):
             if latent:
@@ -714,6 +743,9 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
 
     denom = jnp.maximum(l_ref[...], 1e-30)           # (n_kv, q_tile*g, 1)
     o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    if stats:
+        m_out[0] = m_ref[...]
+        l_out[0] = l_ref[...]
 
 
 def tile_arithmetic(n_kv_heads: int, q_tile: int, *, latent: bool = False,
@@ -762,7 +794,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                     probes: bool = False, k_scale=None, v_scale=None,
                     layer=None, v_dim: int | None = None,
                     resolved: dict | None = None,
-                    window: int | None = None):
+                    window: int | None = None, aligned: bool = False,
+                    summary: tuple | None = None, stats: bool = False):
     """GQA attention of an L-token query block per slot directly over a
     block-paged KV pool — decode (L=1), chunked prefill, and ragged mixed
     steps all through ONE kernel.
@@ -776,7 +809,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     walk starts at the tile that holds the oldest visible key; what lies
     behind the window costs no copy. The call is named
     ``window_paged_attention`` in a device trace. No quantized or latent
-    build, no probes.
+    build, no probes. ``aligned``: the window does not slide, a query sees
+    ``(p // window) * window <= j <= p`` (an EVA layer's exact set; the call
+    is then named ``eva_attn_window``).
+
+    SUMMARY form (``summary=(window, rows)``, the K+V build over stacked
+    block arenas whose row ``c`` stands for chunk ``c`` of ``window // rows``
+    positions): a query at ``p`` sees the rows ``c < rows * (p // window)``;
+    ``kv_lens`` / ``q_lens`` stay TOKEN positions. Named
+    ``eva_attn_summary``. ``stats=True`` (either EVA half): returns ``(out,
+    running maximum, denominator)``, the last two (B, L, Hq) float32
+    (``-1e30`` and 0 for a row that saw no key), for the caller's one
+    combine. No quantized, latent or probed build of either.
 
     LATENT form (``v_pool=None`` with ``v_dim``): ``k_pool`` is the one
     latent arena ``(n_blocks, block_size, W)`` — stacked ``(n_layers,
@@ -858,6 +902,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     if latent != (v_dim is not None):
         raise ValueError("v_dim goes with a latent pool (v_pool=None) and "
                          "only with it")
+    if (aligned and window is None) or (summary is not None and (
+            window is not None or k_pool.ndim != 5)):
+        raise ValueError("aligned goes with a window over ring storage, "
+                         "summary with stacked block arenas and no window")
+    if (summary is not None or stats) and (latent or quant or probes):
+        raise NotImplementedError(
+            "the summary build and the returned maximum and denominator are "
+            "the K+V build's in the model dtype: no latent, quantized or "
+            "probed build")
     if window is not None:
         if latent or quant or probes:
             raise NotImplementedError(
@@ -997,7 +1050,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         tile_blocks=tile_blocks, bs=bs, n_blocks=n_blocks, scale=scale,
         n_kv=Hkv, g=g, q_tile=q_tile, n_q_tiles=n_q_tiles, v_dim=v_dim,
         probe_steps=n_steps if probes else 0, folded=folded,
-        compiled=not interpret, window=window)
+        compiled=not interpret, window=window, aligned=aligned,
+        summary=summary, stats=stats)
     dv = v_dim if latent else dh          # width of a value row
     out_specs = pl.BlockSpec((1, heads, rows, dv), q_index)
     out_shape = jax.ShapeDtypeStruct((*qh.shape[:3], dv), jnp.float32)
@@ -1016,6 +1070,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         out_specs = [out_specs, _probes.out_spec()]
         scratch_shapes = [*scratch_shapes, _probes.ord_scratch()]
         out_shape = [out_shape, _probes.out_shape(n_steps)]
+    if stats:
+        # each query row's running maximum and denominator, as the scratch
+        # holds them
+        out_specs = [out_specs] + [pl.BlockSpec((1, heads, rows, 1),
+                                                q_index)] * 2
+        out_shape = [out_shape] + [jax.ShapeDtypeStruct(
+            (*qh.shape[:3], 1), jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # The block table stays the FIRST operand (the benchmark's trace
         # reader knows this kernel by it); the layer index goes last.
@@ -1047,14 +1108,24 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         interpret=interpret,
         # What the device trace calls the kernel's events.
         name=("latent_paged_attention" if latent else
+              "eva_attn_summary" if summary is not None else
               "paged_attention" if window is None else
+              "eva_attn_window" if aligned else
               "window_paged_attention"),
     )(block_tables, kv_lens, q_lens, layer, qh, *arenas)
-    o = outs[0] if probes else outs
-    if not folded:
-        o = o.reshape(B, Hkv, L_pad, g, dv).transpose(0, 2, 1, 3, 4)
-        o = o.reshape(B, L_pad, Hq, dv)[:, :L]
-    o = o.astype(q.dtype)
+    o = outs[0] if probes or stats else outs
+
+    def token_major(a):
+        # (B, Hkv, L_pad * g, w) -> (B, L, Hq, w): what the q layout undid
+        if folded:
+            return a
+        w = a.shape[-1]
+        a = a.reshape(B, Hkv, L_pad, g, w).transpose(0, 2, 1, 3, 4)
+        return a.reshape(B, L_pad, Hq, w)[:, :L]
+
+    o = token_major(o).astype(q.dtype)
+    if stats:
+        return o, token_major(outs[1])[..., 0], token_major(outs[2])[..., 0]
     if probes:
         return o, outs[1]
     return o

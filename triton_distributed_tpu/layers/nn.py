@@ -418,8 +418,9 @@ def _fused_paged_attention(arrays: dict, **static):
                                        resolved=resolved))(*flat)
         _FUSED_TRACES[key] = closed, resolved["arithmetic"]
     closed, _ = _FUSED_TRACES[key]
-    out, = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat)
-    return out
+    out = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat)
+    # (one result, or an EVA half's three: ``stats=True``)
+    return out[0] if len(out) == 1 else tuple(out)
 
 
 def fused_paged_arithmetic() -> dict:
@@ -431,9 +432,12 @@ def fused_paged_arithmetic() -> dict:
     for (names, avals, static), (_, arithmetic) in _FUSED_TRACES.items():
         by_name = dict(zip(names, avals))
         q_shape = "x".join(str(d) for d in by_name["q"][0])
-        window = dict(static).get("window")
+        static = dict(static)
+        window = static.get("window")
         out[f"q{q_shape}:{by_name['k_pool'][1].name}"
-            + (f":window{window}" if window else "")] = arithmetic
+            + (f":window{window}" if window else "")
+            + (":aligned" if static.get("aligned") else "")
+            + (":summary" if static.get("summary") else "")] = arithmetic
     return out
 
 
@@ -650,6 +654,157 @@ def window_cache_update(ring, new, slots, offsets, write_mask, layer):
     slot = jnp.where(wm, slot, n_slots)             # out of range -> dropped
     return ring.at[layer, slot, (pos // bs) % n_ring, pos % bs].set(
         new.astype(ring.dtype), mode="drop")
+
+
+def eva_attn_with_cache(q, k_ring, v_ring, k_sum, v_sum, slots, block_tables,
+                        offset, *, window: int, chunk: int, layer,
+                        scale: float, slot_mask=None, seq_lens=None,
+                        interpret=None, paged_attn: str = "fused"):
+    """EVA attention of new queries (``layers/eva_attn.py``): a query at
+    position ``p`` reads its OWN ALIGNED WINDOW key by key, ``(p // window)
+    * window <= j <= p`` out of the slot's ring, and every EARLIER window
+    through its chunk summaries, the rows ``c < (window // chunk) * (p //
+    window)`` of the slot's blocks in the row arenas, all under one softmax.
+
+    q: (B, L, Hq, dh); k/v_ring as ``window_attn_with_cache`` takes them;
+    k/v_sum: the stacked block arenas ``(layers, n_blocks, block_size, Hkv,
+    dh)`` whose row ``c`` of a sequence is chunk ``c``'s summary, found
+    through ``block_tables`` (B, max_blocks); both read at ``layer``. The
+    step's new rows are in the ring and the chunks it closed in the arenas
+    already. ``slots``, offsets, ``seq_lens``, ``slot_mask`` as in
+    ``window_attn_with_cache``. -> (B, L, Hq, dh).
+
+    ``"fused"``: the block walk's two EVA builds (``kernels
+    .paged_attention``: ``eva_attn_window`` over the ring, ``eva_attn_summary``
+    over the summaries' blocks), each returning its running maximum and
+    denominator, and ONE combine here: ``o = (w1 o1 + w2 o2) / (w1 + w2)``,
+    ``w = l * exp(m - max(m1, m2))``. A half that saw no key has ``l`` 0.
+    ``"gather"``: the plain-jnp oracle, one softmax over the ring's lines
+    and the gathered summary rows."""
+    if paged_attn not in ("fused", "gather"):
+        raise ValueError(
+            f"paged_attn must be 'fused' or 'gather', got {paged_attn!r}")
+    B, L, Hq, dh = q.shape
+    per_window = window // chunk
+    off = jnp.broadcast_to(jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
+    q_lens = (jnp.full((B,), L, jnp.int32) if seq_lens is None
+              else jnp.asarray(seq_lens, jnp.int32))
+    slots = jnp.asarray(slots, jnp.int32)
+    if paged_attn == "fused":
+        rows = dict(q=q, kv_lens=off + q_lens, q_lens=q_lens, layer=layer)
+        if slot_mask is not None:
+            rows["slot_mask"] = slot_mask
+
+        def half(k_pool, v_pool, tables, **build):
+            return _fused_paged_attention(
+                dict(rows, k_pool=k_pool, v_pool=v_pool, block_tables=tables),
+                scale=scale, interpret=interpret, stats=True, **build)
+
+        o1, m1, l1 = half(k_ring, v_ring, slots[:, None], window=int(window),
+                          aligned=True)
+        o2, m2, l2 = half(k_sum, v_sum, block_tables,
+                          summary=(int(window), int(per_window)))
+        m = jnp.maximum(m1, m2)
+        w1, w2 = l1 * jnp.exp(m1 - m), l2 * jnp.exp(m2 - m)
+        out = ((w1[..., None] * o1.astype(jnp.float32)
+                + w2[..., None] * o2.astype(jnp.float32))
+               / jnp.maximum(w1 + w2, 1e-30)[..., None])
+        return out.astype(q.dtype)
+    if slot_mask is not None:
+        slots = jnp.where(slot_mask, slots, 0)
+        block_tables = jnp.where(slot_mask[:, None], block_tables, 0)
+    from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
+
+    def ring_view(ring):
+        rows = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
+        rows = jnp.take(rows, slots, axis=0, mode="clip")
+        return rows.reshape(B, -1, *rows.shape[3:])      # (B, lines, Hkv, dh)
+
+    def summary_view(pool):
+        pool = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+        return paged_gather_kv(pool, block_tables)       # (B, rows, Hkv, dh)
+
+    k1, v1 = ring_view(k_ring), ring_view(v_ring)
+    k2, v2 = summary_view(k_sum), summary_view(v_sum)
+    lines, Hkv = k1.shape[1:3]
+    last = (off + q_lens - 1)[:, None]                             # (B, 1)
+    # the newest position <= last that ring line r can hold (below 0: none)
+    key_pos = (last - (last - jnp.arange(lines)[None]) % lines)[:, None, :]
+    q_pos = (off[:, None] + jnp.arange(L))[..., None]              # (B, L, 1)
+    live = (jnp.arange(L)[None] < q_lens[:, None])[..., None]      # (B, L, 1)
+    exact = ((key_pos >= (q_pos // window) * window) & (key_pos <= q_pos)
+             & live)
+    seen = (jnp.arange(k2.shape[1])[None, None]
+            < per_window * (q_pos // window)) & live
+    mask = jnp.concatenate([exact, seen], axis=-1)                 # (B, L, S)
+    k = jnp.concatenate([k1, k2], axis=1)
+    v = jnp.concatenate([v1, v2], axis=1)
+    # (a line or a row never written holds whatever the arena was born
+    # with: its weight is zero, and ``0 * NaN`` is NaN)
+    v = jnp.where(jnp.any(mask, axis=1)[..., None, None], v,
+                  jnp.zeros_like(v))
+    scores = jnp.einsum("blhgd,bshd->blhgs",
+                        q.reshape(B, L, Hkv, Hq // Hkv, dh), k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask[:, :, None, None], scores, _NEG_INF)
+    p = jnp.where(live[:, :, None, None], jax.nn.softmax(scores, axis=-1),
+                  0.0)
+    out = jnp.einsum("blhgs,bshd->blhgd", p, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, L, Hq, dh).astype(q.dtype)
+
+
+def eva_summary_update(k_sum, v_sum, k_ring, v_ring, mu, phi, slots,
+                       block_tables, offsets, lengths, layer, *, chunk: int,
+                       scale: float, max_len: int):
+    """THE PRODUCER of an EVA layer's summaries: for every chunk of
+    ``chunk`` positions that the rows' new tokens CLOSED (position ``chunk *
+    c + chunk - 1`` among them), read the chunk's lines of the slot's ring
+    (a ring block IS a chunk: ``block_size == chunk``), pool them in float32,
+
+        k~_c = sum_j softmax_j(scale k_j . mu) k_j
+        v~_c = sum_j softmax_j(scale k_j . phi) v_j        (a head each)
+
+    and write one K row and one V row at summary row ``c`` of the sequence,
+    block ``block_tables[b, c // block_size]`` line ``c % block_size`` of the
+    row arenas, at ``layer``. Row b's new tokens are the ``lengths[b]``
+    (``max_len`` at most, static) from ``offsets[b]`` on, already in the
+    ring; a chunk they leave ragged waits there. mu, phi: (Hkv, dh).
+    Returns ``(k_sum, v_sum)``."""
+    bs = k_ring.shape[3]
+    if bs != chunk:
+        raise ValueError(
+            f"a ring block is {bs} lines and a chunk {chunk} positions: the "
+            f"producer reads a chunk as ONE ring block")
+    n_ring, n_blocks = k_ring.shape[2], k_sum.shape[1]
+    offsets = jnp.asarray(offsets, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    # at most cdiv(max_len, chunk) chunks close under max_len new tokens
+    c = (offsets // chunk)[:, None] + jnp.arange(
+        -(-max_len // chunk), dtype=jnp.int32)[None]               # (B, C)
+    closed = chunk * (c + 1) <= (offsets + lengths)[:, None]
+    at = jnp.asarray(slots, jnp.int32)[:, None]
+
+    def lines(ring):                       # (B, C, chunk, Hkv, dh) float32
+        # (one gather out of the arena where it lies: a layer of it sliced
+        # out first is a copy of every slot's ring)
+        return ring[layer, at, c % n_ring].astype(jnp.float32)
+
+    k, v = lines(k_ring), lines(v_ring)
+
+    def pooled(rows, by):
+        w = jax.nn.softmax(
+            jnp.einsum("bcjhd,hd->bcjh", k, by.astype(jnp.float32)) * scale,
+            axis=2)
+        return jnp.einsum("bcjh,bcjhd->bchd", w, rows)
+
+    row = jnp.minimum(c // bs, block_tables.shape[1] - 1)
+    blk = jnp.where(closed, jnp.take_along_axis(block_tables, row, axis=1),
+                    n_blocks)                       # out of range -> dropped
+    idx = (layer, blk, c % bs)
+    return (k_sum.at[idx].set(pooled(k, mu).astype(k_sum.dtype), mode="drop"),
+            v_sum.at[idx].set(pooled(v, phi).astype(v_sum.dtype),
+                              mode="drop"))
 
 
 def latent_attn_with_cache(q, pool, block_tables, offset, *, v_dim: int,

@@ -43,6 +43,26 @@ from triton_distributed_tpu.layers import nn
 from triton_distributed_tpu.runtime.mesh import get_default_mesh
 
 
+def ring_slots(blocks, ring, window: int) -> list:
+    """The slot of each row of each block of a step over ring storage, after
+    the check that the ring holds the step: all of a step's appends come
+    before any read, and the reader masks by position, so a line overwritten
+    under the first row would be read as a valid key. A block that names its
+    rows' slots may give ONE slot every row; one that names none gives slot
+    b row b."""
+    take = max(blk.L * (1 if blk.slots is None else blk.offsets.shape[0])
+               for blk in blocks)
+    lines = ring.shape[2] * ring.shape[3]
+    if window - 1 + take > lines:
+        raise ValueError(
+            f"a slot's ring holds {lines} lines, and a step that gives "
+            f"one slot {take} tokens behind a window of {window} "
+            f"needs {window - 1 + take}: build the pool with "
+            f"max_take >= {take} (KVPool(config, ..., max_take=))")
+    return [jnp.arange(blk.offsets.shape[0], dtype=jnp.int32)
+            if blk.slots is None else blk.slots for blk in blocks]
+
+
 @dataclasses.dataclass(frozen=True)
 class TPAttn:
     """GQA attention with TP-sharded weights.
@@ -293,22 +313,7 @@ class TPAttn:
         if self.kv_pack > 1 or world != 1:
             raise NotImplementedError(
                 "a window layer is built for one device and unpacked rows")
-        # All of a step's appends come before any read, and the reader masks
-        # by position: a line overwritten under the first row would be read
-        # as a valid key. A block that names its rows' slots may give ONE
-        # slot every row; one that names none gives slot b row b.
-        take = max(blk.L * (1 if blk.slots is None else blk.offsets.shape[0])
-                   for blk in blocks)
-        lines = state.wk.shape[2] * state.wk.shape[3]
-        if self.window - 1 + take > lines:
-            raise ValueError(
-                f"a slot's ring holds {lines} lines, and a step that gives "
-                f"one slot {take} tokens behind a window of {self.window} "
-                f"needs {self.window - 1 + take}: build the pool with "
-                f"max_take >= {take} (KVPool(config, ..., max_take=))")
-        # the slot of each row: row b of a block that names none is slot b
-        slots = [jnp.arange(blk.offsets.shape[0], dtype=jnp.int32)
-                 if blk.slots is None else blk.slots for blk in blocks]
+        slots = ring_slots(blocks, state.wk, self.window)
         queries = []
         for blk, at in zip(blocks, slots):
             part = qkv[blk.start:blk.stop].reshape(-1, blk.L, qkv.shape[-1])

@@ -30,6 +30,10 @@ class from the class of the object it is given:
   with a per-head norm; a dense SwiGLU or sigmoid-routed experts chosen
   under a bias after it), run by the same ``ExaoneMoe`` walk with a third
   kind of operator.
+- ``EvaByteConfig``: the EvaByte block (EVA attention in every layer: the
+  query's own ALIGNED window read key by key, every earlier window through
+  one learned summary a chunk; a dense SwiGLU; a byte vocabulary under
+  several prediction heads), run by ``models.evabyte.EvaByte``.
 
 Each states what ``serving.kv_pool.KVPool`` builds the pool's state from:
 ``kv_row_shapes`` (what one token's row of each row arena looks like),
@@ -37,7 +41,9 @@ Each states what ``serving.kv_pool.KVPool`` builds the pool's state from:
 ``slot_state_shapes`` (the arenas that hold a fixed-size state for each
 SLOT, none for a model whose every layer keeps rows) and, where some layers
 keep rows for a window only, ``n_window_layers`` and ``window`` (a ring of
-rows for each slot, ``serving.kv_pool``).
+rows for each slot, ``serving.kv_pool``). A model whose rows in the block
+arenas stand for SEVERAL tokens each says how many (``kv_row_tokens``; every
+other model states nothing and a row is a token).
 """
 
 from __future__ import annotations
@@ -754,6 +760,89 @@ class Lfm2MoeConfig:
             n_heads=4, n_kv_heads=2, d_ff=96, moe_d_ff=32, n_experts=8,
             n_experts_per_tok=2, rope_theta=1e4, max_length=64,
             dtype=jnp.float32), **overrides})
+
+
+@dataclasses.dataclass(frozen=True)
+class EvaByteConfig:
+    """The EvaByte decoder (HF ``evabyte``; HF key in brackets). Defaults
+    are EvaByte/EvaByte's public ``config.json`` (6.5B). Every layer is the
+    same: EVA attention [attention_class eva] with as many key heads as
+    query heads, no bias, rope over the whole head; a SwiGLU of ``d_ff``;
+    RMSNorm whose weight is ``1 + g`` [norm_add_unit_offset]; the residual
+    adds in float32 [fp32_skip_add]. A query at position ``p`` reads the
+    keys of its own ALIGNED window, ``(p // window) * window <= j <= p``
+    [window_size], one by one, and every EARLIER window through one summary
+    a chunk of ``chunk_size`` positions [chunk_size]: a key and a value
+    pooled over the chunk under two learned vectors a head
+    (``layers/eva_attn.py``). The head is ``n_pred_heads`` [num_pred_heads]
+    heads of ``vocab_size`` side by side, head 0 the next byte; the served
+    step samples from head 0.
+
+    The pool it describes is the first whose every layer keeps TWO kinds of
+    cache: a ring of the window's rows a slot (``n_window_layers ==
+    n_layers``) AND rows in the block arenas (``n_cache_layers ==
+    n_layers``), ONE A CHUNK: ``kv_row_tokens`` is ``chunk_size``, and a
+    sequence of ``n`` tokens owns ``ceil(ceil(n / chunk_size) /
+    block_size)`` blocks (``serving.kv_pool.blocks_needed``)."""
+
+    model_name: str = "EvaByte/EvaByte"
+    vocab_size: int = 320
+    d_model: int = 4096                # hidden_size
+    n_layers: int = 32                 # num_hidden_layers
+    n_heads: int = 32                  # num_attention_heads
+    n_kv_heads: int = 32               # num_key_value_heads
+    head_dim: int = 128
+    d_ff: int = 11_008                 # intermediate_size
+    window: int = 2048                 # window_size
+    chunk_size: int = 16
+    n_pred_heads: int = 8              # num_pred_heads
+    rope_theta: float = 1e5
+    rms_eps: float = 1e-5              # rms_norm_eps
+    max_length: int = 32_768           # max_position_embeddings
+    dtype: jnp.dtype = jnp.bfloat16
+
+    slot_state_shapes = None
+
+    def __post_init__(self):
+        if self.n_kv_heads != self.n_heads:
+            raise ValueError(
+                "EVA attention pools a summary a head: as many key heads as "
+                "query heads")
+        if self.chunk_size < 1 or self.window % self.chunk_size \
+                or self.window < self.chunk_size or self.n_pred_heads < 1:
+            raise ValueError(
+                f"a window of {self.window} positions is not whole chunks "
+                f"of {self.chunk_size}")
+
+    @property
+    def kv_row_shapes(self):
+        row = (self.n_kv_heads, self.head_dim)
+        return row, row
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Every layer keeps summary rows for the whole context ..."""
+        return self.n_layers
+
+    @property
+    def n_window_layers(self) -> int:
+        """... and its window's rows in a ring."""
+        return self.n_layers
+
+    @property
+    def kv_row_tokens(self) -> int:
+        """Tokens one row of the block arenas stands for."""
+        return self.chunk_size
+
+    @classmethod
+    def tiny(cls, **overrides) -> "EvaByteConfig":
+        """Tiny float32 sizes for tests (not a real checkpoint): a window
+        of 32 positions in chunks of 4."""
+        return cls(**{**dict(
+            model_name="tiny-evabyte", vocab_size=40, d_model=64,
+            n_layers=3, n_heads=4, n_kv_heads=4, head_dim=16, d_ff=96,
+            window=32, chunk_size=4, n_pred_heads=8, rope_theta=1e4,
+            max_length=160, dtype=jnp.float32), **overrides})
 
 
 # Public Qwen3 architecture hyper-parameters (HF config.json values).

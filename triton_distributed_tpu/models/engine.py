@@ -25,6 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_distributed_tpu.models.config import (
     DeepseekV3Config,
+    EvaByteConfig,
     ExaoneMoeConfig,
     GraniteHybridConfig,
     Lfm2MoeConfig,
@@ -57,13 +58,17 @@ def model_for(config, *, block_n: int = 256):
         from triton_distributed_tpu.models.exaone_moe import ExaoneMoe
 
         return ExaoneMoe(config)
+    if isinstance(config, EvaByteConfig):
+        from triton_distributed_tpu.models.evabyte import EvaByte
+
+        return EvaByte(config)
     return Qwen3(config, block_n=block_n)
 
 
 class Engine:
     def __init__(self, config: ModelConfig | DeepseekV3Config
                  | GraniteHybridConfig | NemotronHConfig
-                 | ExaoneMoeConfig | Lfm2MoeConfig, *,
+                 | ExaoneMoeConfig | Lfm2MoeConfig | EvaByteConfig, *,
                  mesh: Mesh | None = None,
                  mode: str = "dist", prefill_mode: str | None = None,
                  temperature: float = 0.0, top_p: float = 1.0,

@@ -96,7 +96,11 @@ from triton_distributed_tpu.obs.slo import (
 from triton_distributed_tpu.obs.trace import TailSampler
 from triton_distributed_tpu.resilience import faults as _faults
 from triton_distributed_tpu.resilience import guards as _guards
-from triton_distributed_tpu.serving.kv_pool import KVPool
+from triton_distributed_tpu.serving.kv_pool import (
+    KVPool,
+    blocks_needed,
+    row_tokens,
+)
 from triton_distributed_tpu.serving.metrics import Metrics
 from triton_distributed_tpu.serving.prefix_cache import RadixPrefixCache
 from triton_distributed_tpu.serving.scheduler import Request, Scheduler
@@ -365,7 +369,8 @@ class BatchEngine:
         self.prefill_budget = prefill_chunk
         max_seq_len = max_seq_len or engine.max_length
         if n_blocks is None:
-            n_blocks = n_slots * -(-max_seq_len // block_size)
+            n_blocks = n_slots * blocks_needed(
+                max_seq_len, block_size, row_tokens(engine.config))
         self.pool = KVPool(engine.config, n_blocks=n_blocks,
                            block_size=block_size, max_seq_len=max_seq_len,
                            mesh=engine.mesh, axis=engine.model.axis,
@@ -929,6 +934,14 @@ class BatchEngine:
                     "prefill_rows_extra", "prefill_rows_deferred",
                     "mixed_step_tokens")}},
         }
+        # A pool whose rows stand for several tokens each says how many,
+        # and the rows its live sequences hold (whole chunks written).
+        if self.pool.row_tokens != 1:
+            snap["pool"].update(
+                row_tokens=self.pool.row_tokens,
+                summary_rows_held=sum(
+                    s.offset // self.pool.row_tokens
+                    for s in self._slots if s is not None))
         # A model whose layers are of several kinds says how many of each
         # (what the ``step_stats`` counts of a step are sums over).
         kinds = getattr(self.engine.model, "layer_counts", None)

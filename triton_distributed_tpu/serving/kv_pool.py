@@ -46,9 +46,24 @@ appends of a step come before any read and one slot may take several rows
 of the mixed step's prefill block, so the ring holds the window AND a step's
 largest take (``window_ring_blocks``): the later rows' appends then land on
 lines that the first row's window has left behind. ``n_layers`` of the block
-arenas is then the count of FULL layers only. What such a pool cannot give:
-a prefix cache (a cached block would have to carry the window layers' last
-``w`` rows at its boundary: ``prefix_cacheable``).
+arenas is the model's ``n_cache_layers`` whatever its window layers are: the
+FULL layers only where a layer is of one kind or the other (EXAONE-MoE,
+SmallThinker), EVERY layer where a layer keeps both, its window in the ring
+and rows for the whole context in the block arenas (an EVA layer,
+``layers/eva_attn.py``: ``n_window_layers == n_cache_layers == n_layers``).
+What a pool with a ring cannot give: a prefix cache (a cached block would
+have to carry the window layers' last ``w`` rows at its boundary:
+``prefix_cacheable``).
+
+ROWS THAT STAND FOR SEVERAL TOKENS. A model may state how many tokens one
+row of the block arenas stands for (``config.kv_row_tokens``; every model
+that states nothing: 1). An EVA layer's row is the summary of a CHUNK of 16
+positions, so a sequence of ``n`` tokens has ``ceil(n / 16)`` rows and owns
+``ceil(ceil(n / 16) / block_size)`` blocks: ``blocks_needed`` takes the
+tokens a row stands for, and the table width, ``ensure``, ``truncate``,
+admission and the live share sampled for ``kv_used_share_peak`` follow from
+it. Such a model's ring block is one chunk (``block_size ==
+kv_row_tokens``): the producer of the summaries reads a chunk as one block.
 
 Plus a HOST-side free-list allocator mapping sequences onto blocks. A
 sequence of ``n`` tokens owns ``ceil(n / block_size)`` blocks, listed in
@@ -157,12 +172,21 @@ def _zeros(shape, dtype, sharding):
     return _zeros_fn(tuple(shape), jnp.dtype(dtype), sharding)()
 
 
-def blocks_needed(n_tokens: int, block_size: int) -> int:
-    """THE block-rounding rule: ``ceil(n_tokens / block_size)``. One
+def blocks_needed(n_tokens: int, block_size: int,
+                  row_tokens: int = 1) -> int:
+    """THE block-rounding rule: ``ceil(rows / block_size)`` for the
+    ``ceil(n_tokens / row_tokens)`` rows the tokens have (one a token for
+    every model that states nothing: ``ceil(n_tokens / block_size)``). One
     definition shared by allocation (``KVPool.blocks_for``) and admission
     accounting (``Scheduler.admit``) so the two can never disagree on how
     many blocks a sequence costs."""
-    return math.ceil(n_tokens / block_size)
+    return math.ceil(math.ceil(n_tokens / row_tokens) / block_size)
+
+
+def row_tokens(config) -> int:
+    """Tokens one row of the block arenas stands for, as the model's
+    configuration states it; 1 for a model that states nothing."""
+    return int(getattr(config, "kv_row_tokens", 1) or 1)
 
 
 @jax.tree_util.register_dataclass
@@ -259,18 +283,31 @@ def paged_state_shapes(config, *, n_blocks: int, block_size: int,
     tokens one slot appends in a step: a model with window layers has no
     default for it (a ring built for a smaller take than a step's is
     overwritten under the step's first row, and the mask by position reads
-    the newer rows as valid keys)."""
+    the newer rows as valid keys). A layer may pair a ring with rows in
+    the block arenas only as a model whose rows stand for several tokens
+    (``kv_row_tokens``: an EVA layer's chunk summaries) does: the ring's
+    block is then one such row's tokens, and another ``block_size`` is
+    refused."""
     dtype, quant = resolve_kv_dtype(config, kv_dtype)
     k_row, v_row = config.kv_row_shapes
     n_window, window = window_kind(config)
     ring = None
+    if row_tokens(config) != 1 and (not n_window
+                                    or block_size != row_tokens(config)):
+        raise ValueError(
+            f"a row of the block arenas stands for {row_tokens(config)} "
+            f"tokens: the rows are summaries of chunks that wait in a ring, "
+            f"so the model needs window layers and a ring block of one "
+            f"chunk (block_size == {row_tokens(config)}, got {block_size})")
     if n_window:
         if n_slots is None or max_take is None or quant or v_row is None:
             raise ValueError(
                 "window layers keep a ring of K and V rows for each slot in "
                 "the model dtype: the pool needs n_slots and max_take (the "
                 "most tokens one slot appends in a step), and has no "
-                "quantized or latent build of them")
+                "quantized or latent build of them (a model pairs a ring "
+                "with rows in the SAME layer by stating kv_row_tokens: an "
+                "EVA layer; window layers beside full ones state nothing)")
         ring = jax.ShapeDtypeStruct(
             (n_window, n_slots,
              window_ring_blocks(window, block_size, max_take), block_size,
@@ -314,7 +351,10 @@ class KVPool:
         self.block_size = block_size
         self.n_blocks = n_blocks
         self.max_seq_len = max_seq_len or config.max_length
-        self.max_blocks_per_seq = math.ceil(self.max_seq_len / block_size)
+        #: Tokens one row of the block arenas stands for (module text).
+        self.row_tokens = row_tokens(config)
+        self.max_blocks_per_seq = blocks_needed(self.max_seq_len, block_size,
+                                                self.row_tokens)
         self.kv_dtype, self.kv_quant = resolve_kv_dtype(config, kv_dtype)
         if self.kv_quant and on_tpu():
             raise NotImplementedError(
@@ -423,7 +463,7 @@ class KVPool:
         return self._cache.evict(min(need, self.n_reclaimable))
 
     def blocks_for(self, n_tokens: int) -> int:
-        return blocks_needed(n_tokens, self.block_size)
+        return blocks_needed(n_tokens, self.block_size, self.row_tokens)
 
     def geometry(self) -> dict:
         """JSON-safe pool geometry for checkpoint manifests
@@ -436,6 +476,8 @@ class KVPool:
                "max_seq_len": self.max_seq_len,
                "max_blocks_per_seq": self.max_blocks_per_seq,
                "kv_dtype": self.kv_dtype.name}
+        if self.row_tokens != 1:
+            geo["row_tokens"] = self.row_tokens
         if self.slot_state:
             geo["slot_state"] = {
                 name: list(getattr(self.state, name).shape)
@@ -484,6 +526,8 @@ class KVPool:
             scheme += ":slot[" + "+".join(sorted(self.slot_state)) + "]"
         if self.window_layers:
             scheme += f":window{self.window}x{self.window_layers}"
+        if self.row_tokens != 1:
+            scheme += f":row{self.row_tokens}"
         return f"{self.kv_dtype.name}:{scheme}"
 
     def owned(self, seq_id) -> int:
@@ -782,6 +826,12 @@ class KVPool:
                    for b in owned + self._free + list(self._cached))
         empty = [sid for sid, t in self._tables.items() if not t]
         assert not empty, f"empty (stale) tables for seq_ids {empty!r}"
+        # No table is wider than the step's operand: ``max_seq_len`` tokens
+        # at ``row_tokens`` tokens a row.
+        assert self.max_blocks_per_seq == blocks_needed(
+            self.max_seq_len, self.block_size, self.row_tokens) and all(
+            len(t) <= self.max_blocks_per_seq
+            for t in self._tables.values()), "a table past the step's width"
         # Quantized-mode soundness: every cache-resident block carries a
         # recorded wire fingerprint (and ONLY residents do), and the scale
         # arenas exist iff the pool is quantized, shaped like the K/V
