@@ -12,7 +12,8 @@ is that gap in units of the row's own spread:
 
 Two numbers are held to a limit each: the widest gap (``gap_max``) and the
 mean gap (``gap_mean``) over all served positions of the sample. Valid for
-greedy tokens only, which is what every mix sends.
+greedy tokens only, which is what every mix sends. The same two numbers of
+each request alone stand beside the pooled ones (``per_request``).
 
 The control is the reference itself in the precision below the
 configuration's (float8 for bfloat16), put in the program's place: at each
@@ -45,6 +46,10 @@ def compare(family, sizes, seed: int, sample, device, *, limits=None,
     """``sample`` is a list of (prompt, served tokens); ``family`` is the
     configuration's (``perfbench/families/``). Returns the numbers compared
     beside their limits, and ``correct``."""
+    import zlib
+
+    import numpy as np
+
     from perfbench import reference, weights
 
     limits = {**DEFAULT_LIMITS.get(sizes.dtype, {}), **(limits or {})}
@@ -59,19 +64,25 @@ def compare(family, sizes, seed: int, sample, device, *, limits=None,
     w = weights.Weights(family, sizes, seed, device)
     seqs = [(list(prompt) + list(served), len(prompt))
             for prompt, served in sample]
-    sound = [g for r in reference.forward_positions(w, seqs)
-             for g in gaps(r)]
-    ctrl = []
+    sound = [gaps(r) for r in reference.forward_positions(w, seqs)]
+    # One row a request, in the sample's order: what that request reads on
+    # its own. A sample can be one wide request and little else, so a limit
+    # stands above the largest of these (limits.py reads them all).
+    out["per_request"] = [
+        {"prompt_crc32": zlib.crc32(np.asarray(prompt, np.int32).tobytes()),
+         "prompt_tokens": len(prompt), "tokens": len(served), **summarise(g)}
+        for (prompt, served), g in zip(sample, sound)]
+    out["compared"] = summarise([g for one in sound for g in one])
+    out["correct"] = all(out["compared"][k] <= limits[k] for k in limits)
     if control:
         low = reference.forward_positions(w, seqs,
                                           precision=CONTROL_PRECISION)
         ref_low = reference.forward_positions(
             w, seqs, gather=[r["best_token"] for r in low])
-        ctrl = [g for r in ref_low for g in gaps(r)]
-    out["compared"] = summarise(sound)
-    out["correct"] = all(out["compared"][k] <= limits[k] for k in limits)
-    if control:
-        out["control"] = summarise(ctrl)
+        ctrl = [gaps(r) for r in ref_low]
+        for row, g in zip(out["per_request"], ctrl):
+            row["control"] = summarise(g)
+        out["control"] = summarise([g for one in ctrl for g in one])
         out["control_correct"] = all(out["control"][k] <= limits[k]
                                      for k in limits)
     return out

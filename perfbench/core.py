@@ -289,30 +289,44 @@ class CompileCounter:
                 self._on)
 
 
-def pick_sample(finished, seed: int, requests: int, token_budget: int):
-    """The finished requests the reference reads: the longest, then others
-    drawn from the seed, inside the token budget."""
+def pick_sample(finished, seed: int, requests: int, token_budget: int,
+                arrived: int = 0) -> "list[int]":
+    """The finished requests the reference reads, as indices into
+    ``finished``: the longest, then others drawn from the seed, inside the
+    token budget. Where the cell's ``"sample"`` states ``"arrived": n``, the
+    ``n`` places after the longest go to requests that ARRIVED in the window
+    (``standing`` false), in the order the seed drew, where any finished:
+    such a request was prefilled by the window's own mixed steps and serves
+    its whole answer, so every run reads that path and not only the
+    standing population set-up prefilled."""
     from perfbench import lengths
 
     if not finished:
         return []
 
-    def size(t):
-        return len(t.planned.prompt) + len(t.req.output)
+    def size(i):
+        return len(finished[i].planned.prompt) + len(finished[i].req.output)
 
-    order = sorted(range(len(finished)), key=lambda i: -size(finished[i]))
+    order = sorted(range(len(finished)), key=lambda i: -size(i))
     rest = order[1:]
     lengths.rng_for(seed, 7).shuffle(rest)
     picked, budget = [], token_budget
-    for i in [order[0]] + rest:
-        if len(picked) == requests:
-            break
-        if picked and size(finished[i]) > budget:
-            continue
-        picked.append(i)
-        budget -= size(finished[i])
-    return [(list(finished[i].planned.prompt), list(finished[i].req.output))
-            for i in picked]
+
+    def take(candidates, places):
+        nonlocal budget
+        for i in candidates:
+            if len(picked) >= places:
+                break
+            if i in picked or (picked and size(i) > budget):
+                continue
+            picked.append(i)
+            budget -= size(i)
+
+    take(order[:1], min(1, requests))
+    take([i for i in rest if not finished[i].standing],
+         min(requests, 1 + arrived))
+    take(rest, requests)
+    return picked
 
 
 def set_up(spec: dict, seed: int, *, t_start: float, allow_cpu: bool = False,
@@ -368,12 +382,15 @@ def set_up(spec: dict, seed: int, *, t_start: float, allow_cpu: bool = False,
 def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
              t_start: float | None = None, root: str = ROOT,
              allow_cpu: bool = False, engine_overrides: dict | None = None,
-             control: bool = False, tamper=None) -> dict:
+             control: bool = False, all_finished: bool = False,
+             tamper=None) -> dict:
     """Run one cell once and return the result object (the last line).
 
     ``allow_cpu``, ``engine_overrides`` and ``tamper`` are the tests' entry:
     the command itself never sets them. ``control=True`` also reads the
-    lower-precision control (tools/limits, never a benchmark run)."""
+    lower-precision control, and ``all_finished=True`` hands the reference
+    EVERY finished request in place of the cell's sample (both
+    ``limits.py``'s, never a benchmark run's)."""
     t_start = time.monotonic() if t_start is None else t_start
     spec = load_cell(workload, root)
     traffic = spec["traffic"]
@@ -440,11 +457,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
         tokens_in_window=sum(s[4] for s in rec.steps
                              if s[1] < rec.t_close))
 
-    sample = pick_sample(rec.finished, seed, **spec["sample"])
+    finished = rec.finished
+    picked = (list(range(len(finished))) if all_finished
+              else pick_sample(finished, seed, **spec["sample"]))
+    sample = [(list(finished[i].planned.prompt), list(finished[i].req.output))
+              for i in picked]
     served.close()
     t0 = time.monotonic()
     verdict = check.compare(family, sizes, seed, sample, devices[0],
                             limits=spec["limits"], control=control)
+    for row, i in zip(verdict.get("per_request", ()), picked):
+        row["standing"] = finished[i].standing
     verdict["seconds"] = time.monotonic() - t0
     say("correct", **verdict)
     correct = bool(verdict["correct"] and attempted > 0)

@@ -100,6 +100,18 @@ def test_open_loop_deals_the_same_sizes_and_gaps_in_another_order_by_seed():
         return sorted(round(y - x, 9) for x, y in zip(ts, ts[1:]))
 
     assert gaps(a) == gaps(c)
+    # a prompt, its answer and the gap AFTER it are one unit for every seed
+    # (the longest gap's unit comes last, half of that gap before the first
+    # arrival): what an arrival costs the next one is not the seed's to deal
+
+    def units(ps):
+        after = ([y.due_s - x.due_s for x, y in zip(ps, ps[1:])]
+                 + [2 * ps[0].due_s])
+        return sorted((len(p.prompt), p.max_new_tokens, round(g, 9))
+                      for p, g in zip(ps, after))
+
+    assert units(a) == units(c) and len(set(units(a))) == 60
+    assert 2 * a[0].due_s == pytest.approx(max(u[2] for u in units(a)))
     # every eight arrivals in a row span the lengths: none holds only the
     # short or only the long half
     median = sorted(len(p.prompt) for p in a)[30]
@@ -126,6 +138,32 @@ def test_open_loop_deals_the_same_sizes_and_gaps_in_another_order_by_seed():
     assert [p.prompt for p in sa] != [p.prompt for p in sc]
     del params["standing"]
     assert whole(params, 5)[0] == []          # no pace stated: an idle fleet
+
+
+def test_open_loop_pairing_takes_the_draw_of_collisions_from_the_seed():
+    """In a plain model of the served path (one prompt at a time, a step
+    for every 448 tokens, the next arrival waits for what is left) the sum
+    of the waits that arrivals cost each other is the same for every seed
+    up to the chains of three, where it followed the seed's draw before."""
+    params = {**load_mix("chat"), "rate_rps": 0.8}
+    step_s, rows = 0.023, 448
+
+    def waits_cost(seed):
+        plan = load_kind("open_poisson")(params, seed=seed, seconds=40.0,
+                                         vocab=1000, max_total=4096,
+                                         n_slots=32)
+        busy_until, cost = 0.0, 0.0
+        for p in plan.requests:
+            start = max(p.due_s, busy_until)
+            cost += start - p.due_s
+            busy_until = start + step_s * -(-len(p.prompt) // rows)
+        return cost
+
+    costs = sorted(waits_cost(seed) for seed in range(40))
+    # one prompt of 1,407 tokens with 20 ms behind it, in every seed; four
+    # seeds in forty chain a third arrival onto it
+    assert costs[0] == pytest.approx(0.072, abs=0.002)
+    assert costs[33] - costs[0] < 1e-6 and costs[-1] < 0.1
 
 
 def test_closed_loop_runs_the_same_schedule_for_every_seed():

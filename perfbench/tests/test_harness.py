@@ -5,6 +5,7 @@ metric readers and a model family, none of which any file of the harness
 names."""
 
 import json
+import os
 import sys
 import textwrap
 
@@ -467,3 +468,189 @@ def test_per_layer_readers_are_found_by_name_and_may_find_nothing(root):
     # "<name>.<suffix>" is read by the reader <name>
     assert got == {"decode_step_ms.closed": pytest.approx(100.0),
                    "steps_counted": 3.0, "paged_attn_device_share": None}
+
+
+# A hand-made list of finished requests: (prompt tokens, served tokens,
+# standing). Index 2 is the longest; six of the twelve arrived in the window.
+FINISHED = [(300, 2000, True), (200, 1500, True), (400, 3100, True),
+            (150, 1100, False), (500, 1300, False), (250, 2400, True),
+            (180, 1024, False), (350, 2900, True), (220, 1700, False),
+            (128, 1250, False), (410, 3000, True), (333, 1111, False)]
+
+
+def finished_list(only_standing=False):
+    import types
+
+    return [core.Tracked(types.SimpleNamespace(prompt=[0] * p),
+                         types.SimpleNamespace(output=[0] * o, status="ok"),
+                         0.0, 0.0, standing=standing)
+            for p, o, standing in FINISHED if standing or not only_standing]
+
+
+# What ``pick_sample`` returned for these seeds BEFORE it knew of arrivals
+# (read from the parent's tree): three requests inside 9,000 tokens, and
+# inside 6,000.
+PICKED_BEFORE = {3: ([2, 0, 8], [2, 0]), 51: ([2, 7, 3], [2, 0]),
+                 77: ([2, 6, 4], [2, 6, 3])}
+
+
+@pytest.mark.parametrize("seed", sorted(PICKED_BEFORE))
+def test_a_cell_that_states_no_arrived_reads_the_requests_it_read_before(seed):
+    fin = finished_list()
+    roomy, tight = PICKED_BEFORE[seed]
+    assert core.pick_sample(fin, seed, 3, 9000) == roomy
+    assert core.pick_sample(fin, seed, 3, 6000) == tight
+    assert core.pick_sample(fin, seed, **core.SAMPLE) == roomy
+    assert "arrived" not in core.SAMPLE
+
+
+def test_a_sample_with_a_place_for_an_arrival_holds_one_after_the_longest():
+    fin = finished_list()
+    for seed in range(40):
+        picked = core.pick_sample(fin, seed, 3, 9000, arrived=1)
+        assert picked[0] == 2 and len(picked) == 3        # the longest first
+        assert fin[picked[1]].standing is False           # then an arrival
+        # the arrival is the one the seed's shuffle puts first, and the last
+        # place goes on in that shuffle's order, as every place did before
+        before = core.pick_sample(fin, seed, 3, 9000)
+        first = next(i for i in core.pick_sample(fin, seed, len(fin), 10 ** 6)
+                     [1:] if not fin[i].standing)
+        assert picked[1] == first
+        if not fin[before[1]].standing:
+            assert picked == before
+    # inside a budget that no second request fits after the arrival
+    assert core.pick_sample(fin, 51, 3, 6000, arrived=1) == [2, 3, 6]
+    assert core.pick_sample(fin, 3, 3, 6000, arrived=1) == [2, 8]
+    # no arrival finished: the order it had before
+    old = finished_list(only_standing=True)
+    for seed in (3, 51, 77):
+        assert core.pick_sample(old, seed, 3, 9000, arrived=1) \
+            == core.pick_sample(old, seed, 3, 9000)
+    assert core.pick_sample([], 3, 3, 9000, arrived=1) == []
+
+
+def test_every_sample_the_rule_can_draw_is_walked_and_no_other():
+    """``limits.every_sample`` is what a limit is held against: whatever
+    ``pick_sample`` returns for a seed is among them, and over many seeds
+    every one of a small set is met."""
+    from perfbench import limits
+
+    fin = finished_list()
+    sizes = [p + o for p, o, _ in FINISHED]
+    standing = [s for _, _, s in FINISHED]
+    for requests, budget, arrived in ((3, 9000, 0), (3, 9000, 1),
+                                      (3, 6000, 1), (2, 9000, 1),
+                                      (4, 12000, 2), (1, 9000, 1)):
+        allowed = {tuple(x) for x in limits.every_sample(
+            sizes, standing, requests, budget, arrived)}
+        met = {tuple(core.pick_sample(fin, seed, requests, budget, arrived))
+               for seed in range(400)}
+        assert met <= allowed
+        if len(allowed) <= 10:
+            assert met == allowed
+    assert len(set(map(tuple, limits.every_sample(
+        sizes, standing, 3, 9000, 1)))) == 6 * 10
+    rows = [{"prompt_tokens": p, "tokens": o, "standing": s,
+             "gap_max": 0.3 if i == 8 else 0.01,
+             "gap_mean": 0.002 if i == 8 else 0.0001}
+            for i, (p, o, s) in enumerate(FINISHED)]
+    got = limits.worst_draws(rows, {"gap_max": 0.1, "gap_mean": 0.001},
+                             {"requests": 3, "token_budget": 9000})
+    # of the 100 ordered pairs that fit the budget after the longest, request
+    # 8 comes first in 10 and second in 10; the other two dilute its mean
+    assert (got["samples"], got["over_a_limit"]) == (100, 20)
+    assert got["gap_max"] == 0.3 and 0.0004 < got["gap_mean"] < 0.001
+
+
+def test_limits_all_finished_prints_a_row_a_request_and_fails_the_control(
+        root, capsys, restore_compile_cache_config):
+    """``limits.py --all-finished`` at a tiny size: the reference reads
+    EVERY finished request, one row each with the population it belongs to
+    and its own numbers, the pooled numbers stay what a run compares, and
+    the float8 control still comes out not correct."""
+    from perfbench import limits
+
+    rc = limits.main(["--workload", "step.open", "--seeds", str(2 ** 31 + 7),
+                      "--seconds", "2", "--control-seeds", "1",
+                      "--all-finished"], root=root, allow_cpu=True)
+    assert rc == 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    window = next(x for x in lines if x["phase"] == "window")
+    verdict = next(x for x in lines if x["phase"] == "correct")
+    rows = [x for x in lines if x["phase"] == "request"]
+    seed = next(x for x in lines if x["phase"] == "seed")
+    assert len(rows) == window["finished"] == verdict["requests"] > 2
+    assert all(isinstance(r["standing"], bool) for r in rows)
+    assert sum(r["standing"] for r in rows) == window["standing"]
+    assert sum(r["tokens"] for r in rows) == verdict["tokens"] == seed["tokens"]
+    for k in ("gap_max", "gap_mean"):
+        assert max(r[k] for r in rows) <= verdict["limits"][k]
+        assert verdict["control"][k] > 3 * verdict["limits"][k]
+        # the control's own reading of each request stands beside the sound
+        # one (a request of 16 tokens may well pass alone: the pooled fails)
+        assert max(r["control"][k] for r in rows) >= verdict["control"][k]
+    assert verdict["compared"]["gap_max"] == max(r["gap_max"] for r in rows)
+    assert limits.pooled(rows)["gap_mean"] == pytest.approx(
+        verdict["compared"]["gap_mean"], rel=1e-9)
+    assert seed["correct"] is True and verdict["control_correct"] is False
+    pops = seed["populations"]
+    assert pops["standing"]["requests"] == window["standing"]
+    assert pops["standing"]["requests"] + pops["arrived"]["requests"] \
+        == len(rows)
+    summary = lines[-1]
+    assert summary["phase"] == "limits"
+    assert summary["gap_max"]["control_min"] > 3 * verdict["limits"]["gap_max"]
+    alone = summary["single_request"]
+    assert alone["standing"]["requests"] == window["standing"]
+    assert max(p["gap_max"] for p in alone.values() if "gap_max" in p) \
+        == verdict["compared"]["gap_max"]
+    # the rows, saved, are what ``--draws`` walks without a chip
+    path = os.path.join(root, "rows.jsonl")
+    with open(path, "w") as f:
+        f.writelines(json.dumps(r) + "\n" for r in rows)
+    assert limits.main(["--workload", "step.open", "--draws", path],
+                       root=root) == 0
+    drawn = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert [d["sample"].get("arrived") for d in drawn] == [0, None]
+    assert all(d["over_a_limit"] == 0 and d["samples"] >= 1 for d in drawn)
+
+
+def test_the_four_chip_cells_kept_readings_pass_its_limits_and_failed_the_old():
+    """The rows its limits were set from (``readings/``, every finished
+    request of each seed read on four chips): under the limits and the rule
+    that stood before, some sample the rule could draw was not correct; under
+    the cell's own, NO sample it can draw for any shuffle reads over either
+    limit, each limit stands at twice the largest single request or more,
+    and the float8 control fails each limit on every sample the two seeds
+    it was read on can draw (one request alone, of 16 served tokens, reads
+    next to nothing for it)."""
+    from perfbench import limits
+
+    cell = "qwen3-8b-tp4.reasoning"
+    spec = core.load_cell(cell)
+    assert spec["sample"]["arrived"] == 1
+    by_seed = limits.read_rows(os.path.join(
+        core.ROOT, "perfbench", "readings", cell + ".jsonl"))
+    rows = [r for seed_rows in by_seed.values() for r in seed_rows]
+    assert len(by_seed) >= 3
+    assert sum(not r["standing"] for r in rows) >= 60
+    old = {"gap_max": 0.1, "gap_mean": 0.001}
+    before = [limits.worst_draws(r, old, {**spec["sample"], "arrived": 0})
+              for r in by_seed.values()]
+    assert any(d["over_a_limit"] for d in before)
+    for seed_rows in by_seed.values():
+        now = limits.worst_draws(seed_rows, spec["limits"], spec["sample"])
+        assert now["samples"] > 0 and now["over_a_limit"] == 0
+    for k, limit in spec["limits"].items():
+        assert 2 * max(r[k] for r in rows) <= limit, k
+    judged = [limits.worst_draws(r, spec["limits"], spec["sample"])
+              for r in by_seed.values() if "control" in r[0]]
+    assert len(judged) >= 2
+    for d in judged:
+        assert d["control_passes"] == 0
+        assert all(d["control_least"][k] > limit
+                   for k, limit in spec["limits"].items())
+    # the issue's first guess would not have held either
+    assert limits.worst_draws(by_seed[5100200001],
+                              {"gap_max": 0.45, "gap_mean": 0.004},
+                              spec["sample"])["over_a_limit"] > 0

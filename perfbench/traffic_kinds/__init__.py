@@ -13,7 +13,8 @@ n_slots)`` with:
 - ``close()``: the window has closed, nothing further becomes due.
 
 Every seed gets the same set of sizes and gaps, dealt out in another order
-(``lengths.dealt``), and its own token ids. The standing population is the
+(``lengths.dealt``), and its own token ids; where requests arrive on a
+schedule, a prompt and the gap after it stay together (``open_poisson``). The standing population is the
 same set for every seed: how much context the window opens on is not the
 seed's to decide.
 """

@@ -3,8 +3,15 @@ earlier requests have finished. Arrivals at ``rate_rps`` with exponential
 gaps; prompt and output lengths log-normal, clipped.
 
 Every seed's window holds the same ``rate_rps x seconds`` lengths and gaps
-(evenly spaced quantiles), dealt out by the seed so that every eight
-consecutive arrivals span the distributions.
+(evenly spaced quantiles), and the same UNITS of them: a prompt, its answer
+and the gap AFTER it are put together once (``PAIRING_DEAL``), and the seed
+deals the order of the units, so that every eight consecutive arrivals span
+the prompts. What an arrival costs the next one is the part of its prefill
+that is still to do when the next comes, which its prompt and the gap after
+it decide: with the three dealt apart, each seed drew its own collisions
+(the 20 ms gap behind the 3,000-token prompt, or behind the 64-token one),
+and the mean wait for a first token followed the draw and not the program
+(PERF.md section 6, PR 51).
 
 With ``standing`` in the parameters the window opens on the steady state.
 The same arrival process is run backwards from the opening for as long as
@@ -13,9 +20,10 @@ caught part-way, as it would stand after ``a`` seconds of service at the
 stated pace (``prefill_tokens_per_s``, then a token every ``token_s``):
 what it would have generated is already in its prompt, the rest is what it
 asks for, and one that would have finished is left out. That population is
-the same for every seed (its sizes are dealt once, by ``STANDING_DEAL``; the
-seed gives its token ids): dealt by the seed, how much context the window
-opened on was the seed's doing, and the waits of the window followed it."""
+the same for every seed (its sizes are dealt once, each on its own, by
+``STANDING_DEAL``; the seed gives its token ids): dealt by the seed, how
+much context the window opened on was the seed's doing, and the waits of
+the window followed it."""
 
 from __future__ import annotations
 
@@ -24,6 +32,8 @@ from perfbench.traffic_kinds import Planned
 
 
 STANDING_DEAL = 0      # the one deal of every seed's standing population
+PAIRING_DEAL = 0       # the one pairing of a prompt, its answer and the gap
+                       # after it, for every seed's window
 
 
 class Plan:
@@ -34,25 +44,40 @@ class Plan:
         self._ids = lengths.rng_for(seed, 4)
         self.requests = [
             Planned(t, lengths.token_ids(self._ids, p, vocab), o)
-            for t, p, o in self._arrivals(seconds, seed, stream=0)]
+            for t, p, o in self._arrivals(seconds, seed, stream=0,
+                                          pairing=PAIRING_DEAL)]
         self._next = 0
 
-    def _arrivals(self, span_s, seed, stream):
+    def _arrivals(self, span_s, seed, stream, pairing=None):
         """(time, prompt, output) of ``rate x span_s`` arrivals inside
-        (0, span_s), in order of time, dealt by ``seed``."""
+        (0, span_s), in order of time. With a ``pairing`` the sorted
+        prompts, the answers and the gaps AFTER them are made units by that
+        deal and ``seed`` deals the order of the units; the unit with the
+        longest gap comes last, and half of that gap lies before the first
+        arrival (no shorter gap is lost at the window's end, and with it
+        what its prompt costs the next arrival). Without one ``seed`` deals
+        the three apart."""
         params = self._params
         n = max(1, round(self.rate * span_s))
 
-        def deal(values, k):
-            return lengths.dealt(values, lengths.rng_for(seed,
+        def deal(values, by, k):
+            return lengths.dealt(values, lengths.rng_for(by,
                                                          10 * stream + k))
 
-        prompts = deal(lengths.lognormal_quantiles(n, **params["prompt"]), 1)
-        outputs = deal(lengths.lognormal_quantiles(n, **params["output"]), 2)
-        gaps = deal(lengths.exponential_quantiles(n, 1.0 / self.rate), 3)
+        prompts = lengths.lognormal_quantiles(n, **params["prompt"])
+        outputs = lengths.lognormal_quantiles(n, **params["output"])
+        gaps = lengths.exponential_quantiles(n, 1.0 / self.rate)
+        if pairing is None:
+            units = list(zip(deal(prompts, seed, 1), deal(outputs, seed, 2)))
+            before = deal(gaps, seed, 3)
+        else:
+            units = deal(list(zip(sorted(prompts), deal(outputs, pairing, 2),
+                                  deal(gaps, pairing, 3))), seed, 1)
+            units.sort(key=lambda u: u[2] == max(gaps))     # stable
+            before = [max(gaps) / 2] + [u[2] for u in units[:-1]]
         scale = span_s * n / (n + 0.5) / sum(gaps)
         out, t = [], 0.0
-        for p, o, g in zip(prompts, outputs, gaps):
+        for (p, o, *_), g in zip(units, before):
             t += g * scale
             out.append((t, *lengths.fit_lengths(p, o, self._max_total)))
         return out
