@@ -433,8 +433,9 @@ def _bench_paged_attn(prefill_chunk: int = 8) -> dict:
     chunk = max(2, min(int(prefill_chunk), (2 * S) // 3))
     n_blocks = B * max_blocks + 2
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)), jnp.float32)
+    # the pool's one arena: a block's K plane and V plane side by side
+    pool = jnp.asarray(rng.normal(size=(n_blocks, 2, bs, Hkv, dh)),
+                       jnp.float32)
     tables = jnp.asarray(
         rng.permutation(n_blocks)[:B * max_blocks].reshape(B, max_blocks),
         jnp.int32)
@@ -462,7 +463,7 @@ def _bench_paged_attn(prefill_chunk: int = 8) -> dict:
         jax.block_until_ready(fn())
         return (time.perf_counter() - t0) * 1e3
 
-    shape_kw = dict(n_q_heads=Hq, itemsize=kp.dtype.itemsize)
+    shape_kw = dict(n_q_heads=Hq, itemsize=pool.dtype.itemsize)
     extras = {
         "paged_attn_prefill_chunk": chunk,
         "paged_attn_roofline_class": roofline.metric_class(
@@ -475,7 +476,7 @@ def _bench_paged_attn(prefill_chunk: int = 8) -> dict:
         for m in ("fused", "gather"):
             def call(m=m):
                 return nn.paged_attn_with_cache(
-                    q, kp, vp, tables, offset, scale=dh ** -0.5,
+                    q, pool, tables, offset, scale=dh ** -0.5,
                     seq_lens=seq_lens, slot_mask=slot_mask, paged_attn=m)
             # one call under the ledger (bytes_total accumulates per call),
             # then the timing reps outside it
@@ -495,7 +496,7 @@ def _bench_paged_attn(prefill_chunk: int = 8) -> dict:
                                f"{max_err} exceeds f32 tolerance")
         fused_m = "fused_decode" if L == 1 else "fused_prefill"
         _, q_tile = tuned_paged_tile(bs, Hkv, dh, max_blocks,
-                                     str(kp.dtype), L=L, g=g)
+                                     str(pool.dtype), L=L, g=g)
         fused_b = pm.paged_attn_bytes(B, max_blocks, bs, Hkv, dh,
                                       method=fused_m, L=L, q_tile=q_tile,
                                       **shape_kw)
@@ -594,13 +595,10 @@ def _bench_paged_kvq(prefill_chunk: int = 8, kv_dtype: str = "int8") -> dict:
     chunk = max(2, min(int(prefill_chunk), (2 * S) // 3))
     n_blocks = B * max_blocks + 2
     rng = np.random.default_rng(0)
-    k_src = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)),
-                        jnp.float32)
-    v_src = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)),
-                        jnp.float32)
-    kp, vp = k_src.astype(jnp.bfloat16), v_src.astype(jnp.bfloat16)
-    kq, ks = nn.quantize_kv_rows(k_src, wire)
-    vq, vs = nn.quantize_kv_rows(v_src, wire)
+    src = jnp.asarray(rng.normal(size=(n_blocks, 2, bs, Hkv, dh)),
+                      jnp.float32)
+    pool = src.astype(jnp.bfloat16)
+    pool_q, scales = nn.quantize_kv_rows(src, wire)
     tables = jnp.asarray(
         rng.permutation(n_blocks)[:B * max_blocks].reshape(B, max_blocks),
         jnp.int32)
@@ -642,12 +640,12 @@ def _bench_paged_kvq(prefill_chunk: int = 8, kv_dtype: str = "int8") -> dict:
         def call(mode):
             if mode == "base":
                 return nn.paged_attn_with_cache(
-                    q16, kp, vp, tables, offset, scale=dh ** -0.5,
+                    q16, pool, tables, offset, scale=dh ** -0.5,
                     seq_lens=seq_lens, slot_mask=slot_mask)
             return nn.paged_attn_with_cache(
-                q32, kq, vq, tables, offset, scale=dh ** -0.5,
+                q32, pool_q, tables, offset, scale=dh ** -0.5,
                 seq_lens=seq_lens, slot_mask=slot_mask,
-                kv_scales=(ks, vs),
+                kv_scales=scales,
                 paged_attn="fused" if mode == "kvq" else "gather")
 
         outs, snaps = {}, {}
@@ -876,25 +874,25 @@ def _bench_probe_overhead() -> dict:
     n_blocks = B * max_blocks
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(B, Hq, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(n_blocks, 2, bs, Hkv, dh)),
+                       jnp.float32)
     tables = jnp.asarray(rng.permutation(n_blocks).reshape(B, max_blocks),
                          jnp.int32)
     kv_lens = jnp.asarray(
         rng.integers(1, max_blocks * bs + 1, size=B), jnp.int32)
 
     @jax.jit
-    def f_off(q, kp, vp, tables, kv_lens):
-        return paged_decode_attention(q, kp, vp, tables, kv_lens,
+    def f_off(q, pool, tables, kv_lens):
+        return paged_decode_attention(q, pool, tables, kv_lens,
                                       tile_blocks=tile)
 
     @jax.jit
-    def f_on(q, kp, vp, tables, kv_lens):
-        return paged_decode_attention(q, kp, vp, tables, kv_lens,
+    def f_on(q, pool, tables, kv_lens):
+        return paged_decode_attention(q, pool, tables, kv_lens,
                                       tile_blocks=tile, probes=True)
 
-    out_off = f_off(q, kp, vp, tables, kv_lens)
-    out_on, pbuf = f_on(q, kp, vp, tables, kv_lens)
+    out_off = f_off(q, pool, tables, kv_lens)
+    out_on, pbuf = f_on(q, pool, tables, kv_lens)
     jax.block_until_ready((out_off, out_on))
     if not np.array_equal(np.asarray(out_off), np.asarray(out_on)):
         raise RuntimeError("probed build output differs from plain build")
@@ -908,7 +906,7 @@ def _bench_probe_overhead() -> dict:
     def once(f):
         t0 = _time.perf_counter()
         for _ in range(iters):
-            r = f(q, kp, vp, tables, kv_lens)
+            r = f(q, pool, tables, kv_lens)
         jax.block_until_ready(r)
         return (_time.perf_counter() - t0) * 1e3 / iters
 

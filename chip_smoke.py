@@ -733,7 +733,7 @@ def run_hybrid(devices, geo: dict, caches: _CacheEvents) -> None:
     emit(phase="hybrid_build", model=cfg.model_name, n_layers=cfg.n_layers,
          state_layers=state_layers, cache_layers=cfg.n_cache_layers,
          window_layers=window_layers, d_model=cfg.d_model,
-         vocab=cfg.vocab_size, kv_rows=list(be.pool.state.k.shape),
+         vocab=cfg.vocab_size, kv_rows=list(be.pool.state.kv.shape),
          slot_state_bytes=be.pool.slot_state_bytes,
          window=be.pool.geometry().get("window"))
     check(be.prefix_cache is None, "a model with per-slot state or window "

@@ -149,3 +149,16 @@ def mesh4x2():
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+
+def pair_planes(k, v, row_ndim: int = 2):
+    """K rows and V rows ``(..., lines, *row)`` -> the pool's ONE arena of
+    paired planes ``(..., 2, lines, *row)`` (``serving.kv_pool``: plane 0
+    the keys, plane 1 the values), for a test that makes its own pool.
+    ``row_ndim``: the axes of a row after its line, 2 for ``(Hkv, dh)``, 1
+    for a scale arena's ``(Hkv,)``, 3 for a ring's ``(line, Hkv, dh)``
+    behind its blocks."""
+    import jax.numpy as jnp
+
+    return jnp.stack([k, v], axis=-(row_ndim + 2))

@@ -330,6 +330,47 @@ def test_restore_refuses_mismatched_geometry(setup, tmp_path):
         Fleet.restore(ck, engine, **_build_kwargs(block_size=8, n_blocks=8))
 
 
+def test_a_manifest_or_cached_block_of_the_two_arena_layout_is_refused(
+        setup, tmp_path):
+    """The pool keeps a block's K plane and V plane in ONE arena and says so:
+    ``geometry()`` names the layout and ``kv_fingerprint()`` ends in it. A
+    checkpoint whose pool geometry names none (written when K and V were
+    two arenas) is refused at restore, and a cached block recorded under
+    the fingerprint of that time is refused at adoption, naming both."""
+    from triton_distributed_tpu.serving.kv_pool import KV_LAYOUT, KVPool
+    from triton_distributed_tpu.serving.prefix_cache import RadixPrefixCache
+
+    _mesh, config, engine = setup
+    f1 = Fleet.build(engine, **_build_kwargs())
+    f1.submit([1, 2, 3], 4, req_id="r0")
+    ck = str(tmp_path / "ck")
+    f1.checkpoint(ck)
+    state, manifest = load_checkpoint(ck)
+    assert state["pool_geometry"]["layout"] == KV_LAYOUT == "paired"
+    Fleet.restore(ck, engine, **_build_kwargs())        # its own: adopted
+    del state["pool_geometry"]["layout"]                # as PR 51 wrote it
+    save_checkpoint(ck, state, journal_seq=manifest["journal_seq"],
+                    journal_path=manifest["journal_path"])
+    with pytest.raises(ValueError, match="geometry"):
+        Fleet.restore(ck, engine, **_build_kwargs())
+
+    pool = KVPool(config, n_blocks=8, block_size=4, max_seq_len=32)
+    cache = RadixPrefixCache(pool)
+    assert pool.kv_fingerprint() == f"{pool.kv_dtype.name}:none:paired"
+    toks = list(range(8))
+    assert pool.ensure("a", 8)
+    cache.insert("a", toks)
+    pool.release("a")
+    m = cache.match(toks, max_len=7)
+    split = f"{pool.kv_dtype.name}:none"                # two arenas' name
+    pool._cached_fp[m.blocks[0]] = split
+    with pytest.raises(ValueError) as refused:
+        pool.ensure("b", 8, adopt=m.blocks, cow_src=m.cow_src)
+    assert f"{split!r}" in str(refused.value)
+    assert pool.kv_fingerprint() in str(refused.value)
+    pool.check_invariants()                             # nothing adopted
+
+
 def _kill_sweep(setup, tmp_path, stride):
     """The tentpole property: for every cut point in a churny,
     speculative fleet trace, checkpoint+journal restore == golden."""

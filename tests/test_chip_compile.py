@@ -89,13 +89,15 @@ def test_paged_attention_compiles(one_chip, model, L):
 
     hq, hkv = HEADS[model]
 
-    def fn(q, kp, vp, tables, kv_lens, q_lens):
-        return paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
+    def fn(q, pool, tables, kv_lens, q_lens):
+        return paged_attention(q, pool, tables, kv_lens, q_lens=q_lens,
                                interpret=False)
 
-    pool = _sds((N_BLOCKS, BLOCK, hkv, DH), jnp.bfloat16, one_chip)
+    # a block's K plane and V plane side by side: ONE copy a block (at
+    # qwen3-8b-tp4's two key heads a chip, 16 KB where a plane is 8)
+    pool = _sds((N_BLOCKS, 2, BLOCK, hkv, DH), jnp.bfloat16, one_chip)
     compiled = jax.jit(fn).lower(
-        _sds((SLOTS, L, hq, DH), jnp.bfloat16, one_chip), pool, pool,
+        _sds((SLOTS, L, hq, DH), jnp.bfloat16, one_chip), pool,
         _sds((SLOTS, MAX_BLOCKS), jnp.int32, one_chip),
         _sds((SLOTS,), jnp.int32, one_chip),
         _sds((SLOTS,), jnp.int32, one_chip)).compile()
@@ -143,20 +145,20 @@ def test_paged_attention_compiles_at_long_contexts(one_chip, model, build, L):
     rows = EXA_SLOTS if L == 1 else 7
     window = geo["window"] if build == "window" else None
 
-    def fn(q, kp, vp, tables, kv_lens, q_lens, layer):
-        return paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
+    def fn(q, pool, tables, kv_lens, q_lens, layer):
+        return paged_attention(q, pool, tables, kv_lens, q_lens=q_lens,
                                interpret=False, layer=layer, window=window)
 
     if window:
-        pool = _sds((geo["window_layers"], EXA_SLOTS, geo["ring"], BLOCK,
+        pool = _sds((geo["window_layers"], EXA_SLOTS, 2, geo["ring"], BLOCK,
                      hkv, DH), jnp.bfloat16, one_chip)
         tables = _sds((rows, 1), jnp.int32, one_chip)
     else:
-        pool = _sds((1, geo["blocks"], BLOCK, hkv, DH), jnp.bfloat16,
+        pool = _sds((1, geo["blocks"], 2, BLOCK, hkv, DH), jnp.bfloat16,
                     one_chip)
         tables = _sds((rows, geo["table"]), jnp.int32, one_chip)
     text = jax.jit(fn).lower(
-        _sds((rows, L, hq, DH), jnp.bfloat16, one_chip), pool, pool, tables,
+        _sds((rows, L, hq, DH), jnp.bfloat16, one_chip), pool, tables,
         _sds((rows,), jnp.int32, one_chip),
         _sds((rows,), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip)).compile().as_text()
@@ -424,6 +426,11 @@ def test_the_four_chip_cells_step_compiles_with_its_kernels_named(tp4, kind):
         text, [(k, n // 4) if proj in ("qkv", "gate_up") else (k // 4, n)
                for proj, (k, n) in STACKED8.items()])
     assert "all-gather" in text
+    # a chip's pool is ONE arena, its two key heads' K plane and V plane of
+    # a block side by side (16 KB a copy): the block walk and the append
+    # take that operand and no arena of single planes is left
+    assert f"bf16[36,{TP4_BLOCKS},2,{BLOCK},2,{DH}]" in text
+    assert f"bf16[36,{TP4_BLOCKS},{BLOCK},2,{DH}]" not in text
     mem = compiled.memory_analysis()
     assert 1.95e9 < mem.alias_size_in_bytes < 1.97e9
     assert 7.9e9 < mem.argument_size_in_bytes < 7.95e9
@@ -444,7 +451,7 @@ def test_latent_paged_attention_compiles(one_chip, L):
     )
 
     def fn(q, arena, tables, kv_lens, q_lens, layer):
-        return paged_attention(q, arena, None, tables, kv_lens, q_lens=q_lens,
+        return paged_attention(q, arena, tables, kv_lens, q_lens=q_lens,
                                interpret=False, layer=layer, v_dim=LAT_V,
                                scale=192 ** -0.5)
 
@@ -693,8 +700,8 @@ def test_exaone_step_compiles_with_its_state_in_place(topo, model, kind):
     state = placed(paged_state_shapes(
         cfg, n_blocks=geo["blocks"], block_size=BLOCK, n_slots=EXA_SLOTS,
         max_take=HYB_PREFILL_ROWS * CHUNK))
-    assert state.wk.shape == (geo["window_layers"], EXA_SLOTS, geo["ring"],
-                              BLOCK, geo["heads"][1], DH)
+    assert state.wkv.shape == (geo["window_layers"], EXA_SLOTS, 2,
+                               geo["ring"], BLOCK, geo["heads"][1], DH)
 
     def nbytes(tree):
         return sum(int(np.prod(a.shape)) * a.dtype.itemsize
@@ -886,8 +893,8 @@ def test_evabyte_step_compiles_with_ring_and_summaries_in_place(topo, kind):
     state = placed(paged_state_shapes(
         cfg, n_blocks=fleet["n_blocks"], block_size=BLOCK, n_slots=slots,
         max_take=rows * CHUNK))
-    assert state.wk.shape == (8, slots, 156, BLOCK, 32, DH)
-    assert state.k.shape == (8, fleet["n_blocks"], BLOCK, 32, DH)
+    assert state.wkv.shape == (8, slots, 2, 156, BLOCK, 32, DH)
+    assert state.kv.shape == (8, fleet["n_blocks"], 2, BLOCK, 32, DH)
 
     def nbytes(tree):
         return sum(int(np.prod(a.shape)) * a.dtype.itemsize

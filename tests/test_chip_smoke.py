@@ -541,16 +541,18 @@ def test_kv_pool_arrays_are_allocated_in_their_sharded_layout(mesh8,
     pool = _kv.KVPool(cfg, n_blocks=6, block_size=4, mesh=mesh8,
                       kv_dtype="int8")
     st = pool.state
-    want = NamedSharding(mesh8, KVCache.spec("tp")[0])
-    for arr in (st.k, st.v):
-        assert arr.sharding.is_equivalent_to(want, arr.ndim)
-        assert {s.data.shape for s in arr.addressable_shards} == {
-            (cfg.n_layers, 6, 4, 1, cfg.head_dim)}
-        assert not np.asarray(arr).any()
-    want_s = NamedSharding(mesh8, KVCache.scale_spec("tp"))
-    for arr in (st.k_scale, st.v_scale):
-        assert arr.dtype == jnp.float32
-        assert arr.sharding.is_equivalent_to(want_s, arr.ndim)
+    # the head dim of the contiguous cache's spec, one position further
+    # right: a block's two planes sit between the block and its lines
+    want = NamedSharding(mesh8, _kv.PartitionSpec(
+        None, None, None, *KVCache.spec("tp")[0][2:]))
+    assert st.kv.sharding.is_equivalent_to(want, st.kv.ndim)
+    assert {s.data.shape for s in st.kv.addressable_shards} == {
+        (cfg.n_layers, 6, 2, 4, 1, cfg.head_dim)}
+    assert not np.asarray(st.kv).any()
+    want_s = NamedSharding(mesh8, _kv.PartitionSpec(
+        None, None, None, *KVCache.scale_spec("tp")[2:]))
+    assert st.kv_scale.dtype == jnp.float32
+    assert st.kv_scale.sharding.is_equivalent_to(want_s, st.kv_scale.ndim)
     _kv._zeros_fn.cache_clear()
 
 

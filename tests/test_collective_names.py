@@ -209,6 +209,49 @@ def _trace_both_steps(tp: int):
     return be, entries
 
 
+def test_a_pool_over_four_devices_shards_its_heads_and_the_steps_take_it():
+    """The paired arena under ``tp`` = 4: (layers, blocks, 2 planes, lines,
+    Hkv, dh) sharded on the head dim alone (one position right of where two
+    arenas kept it), a device's shard both planes of its own heads; the
+    paged steps take the pool under those specs and hand it back under them
+    (the traced steps' state goes in and comes out at the whole arena's
+    shape and sharding), and the block walk inside the shard_map reads a
+    device's arena: ``Hkv / 4`` heads, ONE operand."""
+    mesh = make_mesh({"tp": 4}, devices=jax.devices()[:4], set_default=False)
+    eng = Engine(ModelConfig.from_name("tiny"), mesh=mesh, mode="dist",
+                 block_n=8)
+    be = BatchEngine(eng, n_slots=SLOTS, block_size=BLOCK,
+                     prefill_chunk=CHUNK)
+    cfg, kv = eng.config, be.pool.state.kv
+    assert tuple(be.pool.specs.kv) == (None, None, None, None, "tp", None)
+    assert tuple(kv.sharding.spec) == tuple(be.pool.specs.kv)
+    local = (cfg.n_layers, be.pool.n_blocks, 2, BLOCK, cfg.n_kv_heads // 4,
+             cfg.head_dim)
+    assert {s.data.shape for s in kv.addressable_shards} == {local}
+    offsets, tables, mask = be._operands([])
+    traced = be._decode_step.trace(
+        eng.params, jnp.zeros((SLOTS,), jnp.int32), be.pool.state, offsets,
+        tables, mask, jnp.zeros((SLOTS,), jnp.float32), None,
+        (be._prev, jnp.zeros((SLOTS,), bool)))
+    out_state = jax.tree.leaves(traced.out_info)[-1]
+    assert out_state.shape == kv.shape and out_state.dtype == kv.dtype
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+    walks = [e for e in pallas_calls(traced.jaxpr.jaxpr)
+             if "paged_attention" in str(e.params.get("name")
+                                         or e.params.get("name_and_src_info"))]
+    assert walks
+    for e in walks:
+        arenas = [v.aval.shape for v in e.invars if len(v.aval.shape) == 6]
+        assert arenas == [local]
+
+
 def test_a_mesh_of_one_counts_no_collective():
     be, entries = _trace_both_steps(1)
     assert be.trace_counts == {"decode": 1, "prefill": 1}

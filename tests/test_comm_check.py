@@ -250,6 +250,39 @@ def test_quiet_drains_given_handles():
     assert vs == [], [str(v) for v in vs]
 
 
+@pytest.mark.parametrize("early", [False, True], ids=["after-wait", "early"])
+def test_copies_into_both_planes_of_a_slot_interleave_without_a_race(early):
+    """A copy into rows of BOTH planes of a staging slot (the paged walk's
+    one copy a block) is two runs of bytes with the other blocks' rows
+    between them: the race check holds a strided copy to its runs, not to
+    their bounding box, so a second block's copy in flight does not race a
+    read of the first block's arrived rows, and a read of rows whose copy is
+    still in flight is still caught."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def body(o_ref, sig, x_ref, stage, sems, *, axis, world):
+        del sig
+        copies = [pltpu.make_async_copy(
+            x_ref.at[i], stage.at[:, pl.ds(4 * i, 4)], sems.at[i])
+            for i in (0, 1)]
+        for c in copies:
+            c.start()
+        if early:
+            o_ref[0, 0] = stage[0, 4, 0]        # block 1's rows: in flight
+        copies[0].wait()
+        # block 0's rows of plane 1 lie INSIDE the box of block 1's copy
+        # (plane 0 rows 4-7 .. plane 1 rows 4-7) and are none of its bytes
+        o_ref[0, 1] = stage[1, 0, 0]
+        copies[1].wait()
+
+    vs = _trace(body, extra_sems=(Buf("x", (2, 2, 4, 128)),
+                                  Buf("stage", (2, 8, 128), space="vmem"),
+                                  Sem("sems", (2,))))
+    assert {v.check for v in vs} == ({"buffer-race"} if early else set()), \
+        [str(v) for v in vs]
+
+
 # ---------------------------------------------------------------------------
 # Tracer/registry plumbing.
 # ---------------------------------------------------------------------------

@@ -68,7 +68,7 @@ def test_prefill_then_decode_through_the_latent_pool_agrees_on_logits(served):
     m = SIZES
     tokens = np.random.default_rng(3).integers(0, m.vocab_size, 22).tolist()
     pool = KVPool(served.config, n_blocks=16, block_size=4, mesh=served.mesh)
-    assert pool.state.v is None and pool.state.k.shape == (3, 16, 4, 128)
+    assert pool.state.latent and pool.state.kv.shape == (3, 16, 4, 128)
     assert pool.ensure("a", 22)
     tables = jnp.asarray(pool.padded_tables(["a", None]))
     mask = jnp.asarray([True, False])
@@ -141,16 +141,16 @@ def test_absorbed_attention_equals_the_expanded_form(served):
     p = jax.tree.map(lambda a: a[1], served.params["layers"]["attn"])
     B, L = 2, 7
     x = jax.random.normal(jax.random.PRNGKey(1), (B, L, m.d_model))
-    state = PagedKVState(k=jnp.zeros((8, 4, attn.cache_row)), v=None)
+    state = PagedKVState(kv=jnp.zeros((8, 4, attn.cache_row)))
     tables = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
     block = nn.TokenBlock(0, L, jnp.zeros((B,), jnp.int32), tables, None,
                           None)
     out, state = attn.fwd(p, x.reshape(B * L, -1), state, blocks=(block,),
                           paged_attn="gather")
     out = out.reshape(B, L, -1)
-    assert state.v is None
+    assert state.kv.ndim == 3      # one arena of rows, no planes
     # the cache row: the normalised latent, then the rotated key, then zeros
-    rows = np.asarray(state.k).reshape(2, 16, -1)[:, :L]
+    rows = np.asarray(state.kv).reshape(2, 16, -1)[:, :L]
     assert np.all(rows[..., m.cache_width:] == 0) and np.any(rows != 0)
 
     cq = nn.rms_norm(x @ p["w_qa"], p["q_a_norm"], m.eps)
@@ -338,13 +338,13 @@ def test_more_than_one_device_is_refused_by_name():
 def test_latent_pool_copies_a_block_and_states_its_wire_format():
     cfg = DeepseekV3Config.tiny()
     pool = KVPool(cfg, n_blocks=6, block_size=4)
-    assert pool.latent and pool.state.v is None
+    assert pool.latent and pool.state.latent
     assert pool.kv_fingerprint() == "float32:none:latent128"
     pool.state = dataclasses.replace(
-        pool.state, k=pool.state.k.at[:, 2].set(7.0))
+        pool.state, kv=pool.state.kv.at[:, 2].set(7.0))
     pool._copy_block_device(2, 5)
-    assert np.all(np.asarray(pool.state.k[:, 5]) == 7.0)
-    assert np.all(np.asarray(pool.state.k[:, 4]) == 0.0)
+    assert np.all(np.asarray(pool.state.kv[:, 5]) == 7.0)
+    assert np.all(np.asarray(pool.state.kv[:, 4]) == 0.0)
     pool.check_invariants()
     with pytest.raises(NotImplementedError, match="quantized"):
         KVPool(cfg, n_blocks=6, block_size=4, kv_dtype="int8")

@@ -40,6 +40,8 @@ from triton_distributed_tpu.serving.kv_pool import (
 )
 from triton_distributed_tpu.serving.scheduler import Request, Scheduler
 
+from conftest import pair_planes
+
 WINDOW, CHUNK, N_LAYERS, HEADS, VOCAB = 32, 4, 3, 8, 40
 SIZES = family.Sizes(
     vocab_size=VOCAB, d_model=64, n_layers=N_LAYERS, heads=4, head_dim=16,
@@ -315,7 +317,7 @@ def test_preemption_under_the_batch_engine_serves_the_same_tokens(served):
     assert snap["pool"]["row_tokens"] == CHUNK
     assert snap["pool"]["summary_rows_held"] == 0      # nothing is live
     assert tight.pool.geometry()["row_tokens"] == CHUNK
-    assert tight.pool.kv_fingerprint().endswith(":window32x3:row4")
+    assert tight.pool.kv_fingerprint().endswith(":paired:window32x3:row4")
     assert not tight.pool.prefix_cacheable and tight.prefix_cache is None
     # every token served was appended in every layer; the recompute's again
     assert m["kv_rows_appended"] > N_LAYERS * sum(
@@ -327,7 +329,7 @@ def test_preemption_under_the_batch_engine_serves_the_same_tokens(served):
 def test_a_ring_too_small_for_the_steps_take_is_refused(served):
     pool = KVPool(served.config, n_blocks=16, block_size=CHUNK,
                   max_seq_len=160, mesh=served.mesh, n_slots=2, max_take=8)
-    assert pool.state.wk.shape[2] == blocks_needed(WINDOW - 1 + 8, CHUNK)
+    assert pool.state.wkv.shape[3] == blocks_needed(WINDOW - 1 + 8, CHUNK)
     pre = served._make_sm("dist", paged="prefill", paged_attn="gather",
                           state_specs=pool.specs)
     with pytest.raises(ValueError, match="max_take >= 24"):
@@ -352,8 +354,10 @@ def _eva_case(rng, offsets, lens, L, paged_attn):
     v_sum = rng.normal(size=k_sum.shape)
     tables = rng.permutation(n_blocks)[:B * table].reshape(B, table)
     q = rng.normal(size=(B, L, H, dh))
-    args = [jnp.asarray(a, jnp.float32) for a in (q, k_ring, v_ring, k_sum,
-                                                  v_sum)]
+    # the pool's arenas: a ring's planes outside its lines, a block's two
+    # planes side by side
+    args = [jnp.asarray(a, jnp.float32) for a in (
+        q, pair_planes(k_ring, v_ring, 3), pair_planes(k_sum, v_sum))]
     out = nn.eva_attn_with_cache(
         *args, jnp.arange(B), jnp.asarray(tables, jnp.int32),
         jnp.asarray(offsets, jnp.int32), window=WINDOW, chunk=CHUNK,
@@ -433,8 +437,9 @@ def test_the_producer_pools_the_chunks_a_step_closed_and_no_other():
     # rows: closes none (6, 7: one token), closes chunk 7 (position 31),
     # 8 tokens from 58: closes chunks 14 and 15 and leaves 64-65 ragged, dead
     offsets, lengths = [6, 31, 58, 20], [1, 1, 8, 0]
-    new_k, new_v = nn.eva_summary_update(
-        k_sum, v_sum, k_ring, v_ring, mu, phi, jnp.arange(B),
+    ring = pair_planes(k_ring, v_ring, 3)
+    new = nn.eva_summary_update(
+        pair_planes(k_sum, v_sum), ring, mu, phi, jnp.arange(B),
         jnp.asarray(tables), jnp.asarray(offsets), jnp.asarray(lengths),
         jnp.int32(1), chunk=CHUNK, scale=dh ** -0.5, max_len=8)
     want_k, want_v = np.array(k_sum), np.array(v_sum)
@@ -447,13 +452,14 @@ def test_the_producer_pools_the_chunks_a_step_closed_and_no_other():
                 w = np.exp(s - s.max())
                 dst[1, tables[b, c // CHUNK], c % CHUNK, h] = \
                     (w / w.sum()) @ rows[:, h]
+    new_k, new_v = new[:, :, 0], new[:, :, 1]   # ONE scatter, both planes
     np.testing.assert_allclose(new_k, want_k, atol=1e-5)
     np.testing.assert_allclose(new_v, want_v, atol=1e-5)
     changed = np.any(np.asarray(new_k) != np.asarray(k_sum), axis=(-1, -2))
     assert changed.sum() == 3 and not changed[0].any()
     with pytest.raises(ValueError, match="ONE ring block"):
         nn.eva_summary_update(
-            k_sum, v_sum, k_ring, v_ring, mu, phi, jnp.arange(B),
+            pair_planes(k_sum, v_sum), ring, mu, phi, jnp.arange(B),
             jnp.asarray(tables), jnp.asarray(offsets), jnp.asarray(lengths),
             jnp.int32(1), chunk=8, scale=1.0, max_len=8)
 
@@ -481,7 +487,7 @@ def test_blocks_for_rows_that_stand_for_several_tokens_and_for_all_others():
     plain = KVPool(ModelConfig.from_name("tiny"), n_blocks=8, block_size=4)
     assert plain.row_tokens == 1 and "row_tokens" not in plain.geometry()
     assert plain.max_blocks_per_seq == 8 and plain.blocks_for(9) == 3
-    assert plain.kv_fingerprint() == "float32:none"
+    assert plain.kv_fingerprint() == "float32:none:paired"
 
 
 def test_the_pools_tables_follow_the_tokens_a_row_stands_for(mesh):
@@ -493,8 +499,9 @@ def test_the_pools_tables_follow_the_tokens_a_row_stands_for(mesh):
     pool = KVPool(cfg, n_blocks=12, block_size=CHUNK, max_seq_len=160,
                   mesh=mesh, n_slots=2, max_take=8)
     assert (pool.row_tokens, pool.max_blocks_per_seq) == (4, 10)
-    assert pool.state.k.shape == (3, 12, 4, 4, 16)
-    assert pool.state.wk.shape == (3, 2, blocks_needed(31 + 8, 4), 4, 4, 16)
+    assert pool.state.kv.shape == (3, 12, 2, 4, 4, 16)
+    assert pool.state.wkv.shape == (3, 2, 2, blocks_needed(31 + 8, 4), 4, 4,
+                                    16)
     assert [pool.blocks_for(n) for n in (1, 16, 17, 64, 65, 160)] == \
         [1, 1, 2, 4, 5, 10]
     assert pool.ensure("a", 17) and pool.owned("a") == 2

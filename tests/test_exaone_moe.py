@@ -30,6 +30,8 @@ from triton_distributed_tpu.runtime.mesh import make_mesh
 from triton_distributed_tpu.serving.batch_engine import BatchEngine
 from triton_distributed_tpu.serving.kv_pool import KVPool, window_ring_blocks
 
+from conftest import pair_planes
+
 WINDOW = 6
 SIZES = family.Sizes(
     vocab_size=256, d_model=64, n_layers=5, windows=(6, 6, 0, 6, 0),
@@ -149,8 +151,8 @@ def logits_of_a_staggered_batch(engine):
     Returns the logits of a at positions 76..80 and of b at 11, 12."""
     a, b = TOKENS_A, TOKENS_B
     pool, pre, dec = paged_steps(engine, 3)
-    assert pool.state.wk.shape == (
-        N_WINDOW, 3, -(-(engine.config.window - 1 + 24) // 4), 4, 2, 16)
+    assert pool.state.wkv.shape == (
+        N_WINDOW, 3, 2, -(-(engine.config.window - 1 + 24) // 4), 4, 2, 16)
     assert pool.ensure("a", 82) and pool.ensure("b", 14)
     tables = jnp.asarray(pool.padded_tables(["a", None, "b"]))
     state, got_a, got_b = pool.state, [], []
@@ -314,8 +316,7 @@ def test_batch_engine_serves_what_the_reference_puts_first(served,
     snap = be.stats_snapshot()
     assert snap["layers"] == {"dense": 1, "moe": N_MOE, "window": N_WINDOW,
                               "full": N_FULL}
-    assert snap["pool"]["window_bytes"] == be.pool.state.wk.nbytes \
-        + be.pool.state.wv.nbytes > 0
+    assert snap["pool"]["window_bytes"] == be.pool.state.wkv.nbytes > 0
     if paged_attn == "fused":
         named = [k for k in snap["paged_arithmetic"]
                  if k.endswith(f":window{WINDOW}")]
@@ -377,18 +378,19 @@ def test_the_window_storage_is_sized_by_the_window_and_not_by_the_context(
     kw = dict(block_size=4, n_slots=3, max_take=32)
     pool = KVPool(served.config, n_blocks=6, max_seq_len=64, **kw)
     st = pool.state
-    assert st.k.shape == st.v.shape == (N_FULL, 6, 4, 2, 16)
+    assert st.kv.shape == (N_FULL, 6, 2, 4, 2, 16)
     assert window_ring_blocks(WINDOW, 4, 32) == 10     # ceil((6 - 1 + 32) / 4)
-    assert st.wk.shape == st.wv.shape == (N_WINDOW, 3, 10, 4, 2, 16)
+    # one ring a (window layer, slot), the planes OUTSIDE its lines
+    assert st.wkv.shape == (N_WINDOW, 3, 2, 10, 4, 2, 16)
     assert pool.window_bytes == 2 * N_WINDOW * 3 * 10 * 4 * 2 * 16 * 4
     assert pool.geometry()["window"] == {
         "layers": N_WINDOW, "window": WINDOW, "max_take": 32,
         "ring_blocks": 10, "bytes": pool.window_bytes}
-    assert pool.kv_fingerprint() == "float32:none:window6x3"
+    assert pool.kv_fingerprint() == "float32:none:paired:window6x3"
     pool.check_invariants()
     big = KVPool(served.config, n_blocks=600, max_seq_len=4096, **kw)
     assert big.window_bytes == pool.window_bytes
-    assert big.state.k.nbytes == 100 * st.k.nbytes
+    assert big.state.kv.nbytes == 100 * st.kv.nbytes
     # a decode-only engine needs the window alone; the published geometry
     assert window_ring_blocks(128, 16, 1) == 8
     assert window_ring_blocks(128, 16, 7 * 64) == 36
@@ -396,7 +398,7 @@ def test_the_window_storage_is_sized_by_the_window_and_not_by_the_context(
     from triton_distributed_tpu.models.config import ModelConfig
 
     rows = KVPool(ModelConfig.from_name("tiny"), n_blocks=6, block_size=4)
-    assert rows.state.wk is None and rows.window_bytes == 0
+    assert rows.state.wkv is None and rows.window_bytes == 0
     assert rows.prefix_cacheable and "window" not in rows.geometry()
 
 
@@ -409,7 +411,7 @@ def test_a_ring_too_small_for_the_steps_take_is_refused(served):
         KVPool(served.config, n_blocks=8, block_size=4, n_slots=2)
     pool = KVPool(served.config, n_blocks=8, block_size=4, n_slots=2,
                   max_take=8)                 # 4 ring blocks: 16 lines
-    assert pool.state.wk.shape[2] == 4
+    assert pool.state.wkv.shape[3] == 4
     step = jax.jit(served._make_sm(
         "dist", paged="prefill", paged_attn="gather",
         state_specs=pool.specs))
@@ -463,7 +465,8 @@ def test_what_is_not_built_is_refused_by_name(served):
 # -- the window build of the block walk -------------------------------------------
 
 def ring_of(seq, lens, n_slots, slots, ring_blocks, bs):
-    """Ring storage ``(1, n_slots, ring_blocks, bs, Hkv, dh)`` holding what
+    """One plane ``(1, n_slots, ring_blocks, bs, Hkv, dh)`` of ring storage
+    (``pair_planes(k plane, v plane, 3)`` is the ring) holding what
     appending the first ``lens[b]`` positions of ``seq`` (B, S, Hkv, dh),
     one by one, leaves there: the last ``ring_blocks * bs`` of them, each in
     its line; NaN where nothing was written (a reader must never let it
@@ -539,13 +542,14 @@ def test_the_window_build_equals_plain_numpy(walk, L, Hq):
     vr = ring_of(v, kv_lens, B + 2, slots, ring_blocks, bs)
     want = plain_window_attention(q, k, v, kv_lens, q_lens, window,
                                   dh ** -0.5)
+    ring = pair_planes(kr, vr, 3)       # the planes outside a slot's lines
     got = paged_attention(
-        jnp.asarray(q), kr, vr, jnp.asarray(slots)[:, None],
+        jnp.asarray(q), ring, jnp.asarray(slots)[:, None],
         jnp.asarray(kv_lens), q_lens=jnp.asarray(q_lens), layer=0,
         window=window, interpret=True, tile_blocks=tile[0] if tile else None)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
     oracle = nn.window_attn_with_cache(
-        jnp.asarray(q), kr, vr, jnp.asarray(slots),
+        jnp.asarray(q), ring, jnp.asarray(slots),
         jnp.asarray(kv_lens - q_lens), window=window, layer=0,
         scale=dh ** -0.5, seq_lens=jnp.asarray(q_lens), paged_attn="gather")
     np.testing.assert_allclose(np.asarray(oracle), want, atol=2e-5)
@@ -555,12 +559,12 @@ def test_the_window_build_refuses_what_it_has_not():
     """(Its name in a device trace, ``window_paged_attention``, is the
     compile rehearsal's to check: ``tests/test_chip_compile.py``.)"""
     q = jnp.zeros((2, 1, 4, 16))
-    ring = jnp.zeros((1, 2, 4, 4, 2, 16))
+    ring = jnp.zeros((1, 2, 2, 4, 4, 2, 16))
     with pytest.raises(ValueError, match="ring storage"):
-        paged_attention(q, ring[0], ring[0], jnp.zeros((2, 1), jnp.int32),
+        paged_attention(q, ring[0], jnp.zeros((2, 1), jnp.int32),
                         jnp.ones((2,), jnp.int32), layer=0, window=4)
     with pytest.raises(NotImplementedError, match="no latent, quantized"):
-        paged_attention(q, ring, ring, jnp.zeros((2, 1), jnp.int32),
+        paged_attention(q, ring, jnp.zeros((2, 1), jnp.int32),
                         jnp.ones((2,), jnp.int32), layer=0, window=4,
                         probes=True)
 
@@ -604,7 +608,7 @@ def test_a_block_behind_the_window_is_neither_copied_nor_waited_for(shape):
     blocks): a grid step moves the blocks that hold a visible key and no
     other (the decode shape: keys 24..47, three blocks of six; a chunk's
     query tile of 4: four), a WHOLE tile of them whose blocks lie side by
-    side in the ring as ONE copy an arena (the decode shape: blocks 4-5;
+    side in the ring as ONE copy, both planes (the decode shape: blocks 4-5;
     block 3 alone, its tile ragged at the start), every other live block as
     its own; every started copy is waited for, at its size, and the sweeps
     of every registered kernel stay clean with it. Round a ring of 7 blocks
@@ -629,14 +633,15 @@ def test_a_block_behind_the_window_is_neither_copied_nor_waited_for(shape):
     if "kv_len" not in shape:           # the numbers the docstring gives
         assert (side_by_side, ragged) == ((2, 2) if L == 1 else (6, 4))
     log = events.trace_kernel(spec, 1).logs[0]
-    block_bytes = bs * kw["n_kv"] * 128 * 4               # a K or V block
+    pair_bytes = 2 * bs * kw["n_kv"] * 128 * 4    # a block's K and V plane
     for kind in ("inc", "wait"):
         sizes = sorted(e.amount for e in log if e.kind == kind)
-        # K and V: one copy a tile that is whole and side by side, one a
-        # live block of any other; a block behind the window moves no byte
+        # K and V TOGETHER: one copy a tile that is whole and side by side,
+        # one a live block of any other (half the copies two arenas took);
+        # a block behind the window moves no byte
         assert sizes == sorted(
-            [block_bytes] * (2 * (wrapping * tile + ragged))
-            + [tile * block_bytes] * (2 * side_by_side)), kind
+            [pair_bytes] * (wrapping * tile + ragged)
+            + [tile * pair_bytes] * side_by_side), kind
     assert checks.check_kernel("paged.window", 1) == []
     assert resources.check_kernel("paged.window", 1, shape) == []
 
@@ -781,13 +786,12 @@ def test_counts_of_the_published_configuration():
         mcfg, n_blocks=fleet["n_blocks"], block_size=fleet["block_size"],
         n_slots=fleet["n_slots"], max_take=7 * fleet["prefill_chunk"])
     nbytes = {f: int(np.prod(a.shape)) * a.dtype.itemsize
-              for f in ("k", "v", "wk", "wv") if (a := getattr(state, f))}
+              for f in ("kv", "wkv") if (a := getattr(state, f))}
     assert (fleet["n_slots"], fleet["n_blocks"]) == (32, 28_672)
-    assert nbytes["k"] + nbytes["v"] == pytest.approx(1.879e9, rel=1e-3)
-    assert nbytes["wk"] + nbytes["wv"] == 4 * 32 * 36 * 16 * 4096
+    assert nbytes["kv"] == pytest.approx(1.879e9, rel=1e-3)
+    assert nbytes["wkv"] == 4 * 32 * 36 * 16 * 4096
     # five layers of full rows would be 9.4 GB beside 7.43 GB of weights
-    assert sum(nbytes.values()) < 2.5e9 < 9.3e9 < 5 * (nbytes["k"]
-                                                       + nbytes["v"])
+    assert sum(nbytes.values()) < 2.5e9 < 9.3e9 < 5 * nbytes["kv"]
     # every published key of the catalog's row stands at its published value
     assert cfg["reduced"] == ["num_hidden_layers", "num_experts",
                               "vocab_size", "max_position_embeddings"]

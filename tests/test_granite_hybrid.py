@@ -366,7 +366,7 @@ def test_rows_of_one_slot_are_chained_through_the_chunk_scan(case, groups):
     K, C = m.ssm_conv, m.conv_width
     rng = np.random.default_rng(len(case))
     dirty = PagedKVState(
-        k=jnp.zeros((1, 1, 1, 1, 1)), v=jnp.zeros((1, 1, 1, 1, 1)),
+        kv=jnp.zeros((1, 1, 2, 1, 1, 1)),
         ssm=jnp.asarray(rng.standard_normal(
             (2, B, m.ssm_heads, m.ssm_head_width, m.ssm_state)), jnp.float32),
         conv=jnp.asarray(rng.standard_normal((2, B, (K - 1) * C)),
@@ -646,20 +646,21 @@ def test_the_pool_holds_two_kinds_of_state(served):
         KVPool(cfg, n_blocks=6, block_size=4, n_slots=2, kv_dtype="int8")
     pool = KVPool(cfg, n_blocks=6, block_size=4, n_slots=3)
     st = pool.state
-    # rows: as deep as the model has attention layers, two heads to a row
-    assert st.k.shape == st.v.shape == (2, 6, 4, 1, 32)
+    # rows: as deep as the model has attention layers, two heads to a row,
+    # a block's K plane and V plane side by side
+    assert st.kv.shape == (2, 6, 2, 4, 1, 32)
     assert st.ssm.shape == (4, 3, 4, 8, 16) and st.ssm.dtype == jnp.float32
     assert st.conv.shape == (4, 3, 3 * (32 + 2 * 2 * 16))
     assert pool.slot_state_bytes == st.ssm.nbytes + st.conv.nbytes
-    assert pool.kv_fingerprint() == "float32:none:slot[conv+ssm]"
+    assert pool.kv_fingerprint() == "float32:none:paired:slot[conv+ssm]"
     assert pool.geometry()["slot_state"] == {
         "conv": [4, 3, 288], "ssm": [4, 3, 4, 8, 16]}
     assert jax.tree.structure(pool.specs) == jax.tree.structure(st)
     # a block's copy moves rows and leaves the per-slot arenas alone
     pool.state = dataclasses.replace(
-        st, k=st.k.at[:, 2].set(7.0), ssm=st.ssm.at[:, 2].set(5.0))
+        st, kv=st.kv.at[:, 2].set(7.0), ssm=st.ssm.at[:, 2].set(5.0))
     pool._copy_block_device(2, 5)
-    assert np.all(np.asarray(pool.state.k[:, 5]) == 7.0)
+    assert np.all(np.asarray(pool.state.kv[:, 5]) == 7.0)
     assert np.all(np.asarray(pool.state.ssm[:, 1]) == 0.0)
     assert np.all(np.asarray(pool.state.ssm[:, 2]) == 5.0)
     pool.check_invariants()

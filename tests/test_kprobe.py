@@ -33,6 +33,8 @@ from triton_distributed_tpu.obs import kprobe, roofline, trace
 from triton_distributed_tpu.runtime import perf_model as pm
 from triton_distributed_tpu.runtime.compat import shard_map
 
+from conftest import pair_planes
+
 WORLDS = (2, 4, 8)
 PROBE_VARIANTS = tuple(f"{base}+probe" for base in probes.PROBE_BASES)
 
@@ -328,9 +330,10 @@ def test_paged_attention_bit_identity(rng):
     tables = jnp.arange(n_blocks, dtype=jnp.int32).reshape(B, max_blocks)
     kv_lens = jnp.asarray([max_blocks * bs, bs + 3], jnp.int32)
 
-    off = paged_decode_attention(q, kp, vp, tables, kv_lens, tile_blocks=2,
+    pool = pair_planes(kp, vp)
+    off = paged_decode_attention(q, pool, tables, kv_lens, tile_blocks=2,
                                  interpret=True)
-    on, pbuf = paged_decode_attention(q, kp, vp, tables, kv_lens,
+    on, pbuf = paged_decode_attention(q, pool, tables, kv_lens,
                                       tile_blocks=2, interpret=True,
                                       probes=True)
     assert np.array_equal(np.asarray(off), np.asarray(on))
@@ -363,9 +366,10 @@ def test_paged_prefill_probe_bit_identity(rng):
     kv_lens = jnp.asarray([max_blocks * bs, bs + 3], jnp.int32)
     q_lens = jnp.asarray([L, 3], jnp.int32)        # ragged mixed step
 
-    off = paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
+    pool = pair_planes(kp, vp)
+    off = paged_attention(q, pool, tables, kv_lens, q_lens=q_lens,
                           tile_blocks=2, q_tile=4, interpret=True)
-    on, pbuf = paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
+    on, pbuf = paged_attention(q, pool, tables, kv_lens, q_lens=q_lens,
                                tile_blocks=2, q_tile=4, interpret=True,
                                probes=True)
     assert np.array_equal(np.asarray(off), np.asarray(on))

@@ -95,11 +95,14 @@ def test_pool_quant_lifecycle(setup, kv_dtype, wire):
     _, config, _ = setup
     pool, cache = _qpool(config, kv_dtype)
     st = pool.state
-    assert st.k.dtype == st.v.dtype == jnp.dtype(wire)
-    assert st.k_scale is not None and st.v_scale is not None
-    assert st.k_scale.shape == st.k.shape[:-1]          # arenas minus dh
-    assert st.k_scale.dtype == jnp.float32
-    assert pool.kv_fingerprint() == f"{jnp.dtype(wire).name}:rowmax:v1"
+    # one arena of wire rows, K plane and V plane of a block side by side,
+    # and one of scales in the same layout
+    assert st.kv.dtype == jnp.dtype(wire) and st.kv.shape[2] == 2
+    assert st.kv_scale is not None
+    assert st.kv_scale.shape == st.kv.shape[:-1]        # the arena minus dh
+    assert st.kv_scale.dtype == jnp.float32
+    assert pool.kv_fingerprint() == (
+        f"{jnp.dtype(wire).name}:rowmax:v1:paired")
     assert pool.geometry()["kv_dtype"] == jnp.dtype(wire).name
     toks = list(range(10))
     assert pool.ensure("a", 10)
@@ -116,8 +119,8 @@ def test_pool_quant_lifecycle(setup, kv_dtype, wire):
 def test_unquantized_pool_has_no_scale_arenas(setup):
     _, config, _ = setup
     pool = KVPool(config, n_blocks=4, block_size=4, max_seq_len=32)
-    assert pool.state.k_scale is None and pool.state.v_scale is None
-    assert pool.kv_fingerprint().endswith(":none")
+    assert pool.state.kv_scale is None
+    assert pool.kv_fingerprint().endswith(":none:paired")
     pool.check_invariants()
 
 
@@ -157,9 +160,9 @@ def test_cow_copies_scale_rows_with_wire_rows(setup):
     src = pool.table("a")[1]
     st = pool.state
     pool.state = type(st)(
-        k=st.k.at[:, src].set(7), v=st.v.at[:, src].set(-3),
-        k_scale=st.k_scale.at[:, src].set(0.125),
-        v_scale=st.v_scale.at[:, src].set(2.5))
+        kv=st.kv.at[:, src, 0].set(7).at[:, src, 1].set(-3),
+        kv_scale=st.kv_scale.at[:, src, 0].set(0.125)
+        .at[:, src, 1].set(2.5))
     cache.insert("a", toks)
     pool.release("a")
     m = cache.match(toks, max_len=5)
@@ -168,9 +171,11 @@ def test_cow_copies_scale_rows_with_wire_rows(setup):
     dst = pool.table("b")[1]
     assert dst != src
     st = pool.state
-    for arena in (st.k, st.v, st.k_scale, st.v_scale):
+    for arena in (st.kv, st.kv_scale):           # both planes of each
         np.testing.assert_array_equal(np.asarray(arena[:, dst]),
                                       np.asarray(arena[:, src]))
+    assert np.all(np.asarray(st.kv[:, dst, 0]) == 7)
+    assert np.all(np.asarray(st.kv[:, dst, 1]) == -3)
     pool.release("b")
     pool.check_invariants()
 
@@ -294,4 +299,5 @@ def test_checkpoint_geometry_carries_kv_dtype(setup, tmp_path):
     with pytest.raises(ValueError, match="geometry"):
         Fleet.restore(ck, engine, **kw)        # bf16/f32 pool: refused
     f2 = Fleet.restore(ck, engine, kv_dtype="int8", **kw)
-    assert f2.replicas[0].engine.pool.kv_fingerprint() == "int8:rowmax:v1"
+    assert f2.replicas[0].engine.pool.kv_fingerprint() == \
+        "int8:rowmax:v1:paired"

@@ -167,7 +167,7 @@ def operator():
 def dirty_state(seed):
     rng = np.random.default_rng(seed)
     return PagedKVState(
-        k=jnp.zeros((1, 1, 1, 1, 1)), v=jnp.zeros((1, 1, 1, 1, 1)),
+        kv=jnp.zeros((1, 1, 2, 1, 1, 1)),
         conv=jnp.asarray(rng.standard_normal((2, B, 2 * D)), jnp.float32))
 
 
@@ -457,7 +457,7 @@ def test_batch_engine_serves_what_the_reference_puts_first(served,
     first request left there: ``conv_states_reset`` counts the three starts.
     The pool has a ``conv`` arena and NO ``ssm``, and no prefix cache."""
     be = batch_engine(served, paged_attn=paged_attn)
-    assert be.pool.state.ssm is None and be.pool.state.wk is None
+    assert be.pool.state.ssm is None and be.pool.state.wkv is None
     assert be.pool.state.conv.shape == (N_CONV, 2, 2 * SIZES.d_model)
     ps = prompts(5, 5, 27, 11)
     reqs = [be.submit(ps[0], 3), be.submit(ps[1], 9)]
@@ -802,13 +802,14 @@ def test_counts_of_the_published_configuration():
     state = paged_state_shapes(
         mcfg, n_blocks=fleet["n_blocks"], block_size=fleet["block_size"],
         n_slots=fleet["n_slots"])
-    assert state.ssm is None and state.wk is None
+    assert state.ssm is None and state.wkv is None
     assert state.conv.shape == (30, 32, 4096)
     assert state.conv.dtype == jnp.bfloat16
-    assert state.k.shape == state.v.shape == (10, 3328, 16, 4, 128)
+    # a block's pair is 32 KiB: one copy where two arenas took two of 16
+    assert state.kv.shape == (10, 3328, 2, 16, 4, 128)
     nbytes = {f: int(np.prod(a.shape)) * a.dtype.itemsize
-              for f in ("k", "v", "conv") if (a := getattr(state, f))}
+              for f in ("kv", "conv") if (a := getattr(state, f))}
     assert nbytes["conv"] == pytest.approx(7.9e6, rel=0.01)
-    assert nbytes["k"] + nbytes["v"] == pytest.approx(1.09e9, rel=2e-3)
+    assert nbytes["kv"] == pytest.approx(1.09e9, rel=2e-3)
     assert 2 * family.params_held(m) + sum(nbytes.values()) == \
         pytest.approx(8.6e9, rel=0.01)

@@ -272,7 +272,7 @@ def test_a_slots_rows_in_one_mixed_step_give_what_a_row_a_step_gives(served,
         assert_logits_agree([got[slot]], seqs[slot][:end], end)
     np.testing.assert_allclose(state.ssm, state1.ssm, atol=1e-5)
     np.testing.assert_allclose(state.conv, state1.conv, atol=1e-6)
-    np.testing.assert_allclose(state.k, state1.k, atol=1e-5)
+    np.testing.assert_allclose(state.kv, state1.kv, atol=1e-5)
 
 
 FAULTS = {
@@ -392,10 +392,10 @@ def test_the_pool_holds_rows_six_deep_beside_state_twenty_three_deep(served):
     attention layers, per-slot arenas as deep as the Mamba-2 layers."""
     pool = KVPool(served.config, n_blocks=6, block_size=4, n_slots=3)
     st = pool.state
-    assert st.k.shape == st.v.shape == (N_A, 6, 4, 2, 16)
+    assert st.kv.shape == (N_A, 6, 2, 4, 2, 16)
     assert st.ssm.shape == (N_M, 3, 4, 8, 16) and st.ssm.dtype == jnp.float32
     assert st.conv.shape == (N_M, 3, 3 * (32 + 2 * 2 * 16))
-    assert pool.kv_fingerprint() == "float32:none:slot[conv+ssm]"
+    assert pool.kv_fingerprint() == "float32:none:paired:slot[conv+ssm]"
     pub = NemotronHConfig(experts_held=16)
     assert (pub.n_cache_layers, pub.n_state_layers) == (6, 23)
     assert pub.kv_row_shapes == ((2, 128), (2, 128))

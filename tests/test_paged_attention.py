@@ -50,6 +50,8 @@ from triton_distributed_tpu.runtime import perf_model as pm
 from triton_distributed_tpu.runtime.mesh import make_mesh
 from triton_distributed_tpu.serving import BatchEngine, KVPool
 
+from conftest import pair_planes
+
 
 def _ref_attn(q, kp, vp, tables, kv_lens, slot_mask=None):
     """Gather + masked dense softmax — the reference composition."""
@@ -126,7 +128,7 @@ def test_fused_matches_gather_reference(rng, bs, max_blocks, g):
         kv_lens = jnp.asarray([1, 100, 129, 2 * 128 - 1], jnp.int32)
     ref = _ref_attn(q, kp, vp, tables, kv_lens)
     for tile in (None, 1, max_blocks):
-        out = paged_decode_attention(q, kp, vp, tables, kv_lens,
+        out = paged_decode_attention(q, pair_planes(kp, vp), tables, kv_lens,
                                      tile_blocks=tile, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5,
@@ -138,7 +140,7 @@ def test_fused_dead_slots_and_scalar_kvlen(rng):
     q, kp, vp, tables, kv_lens = _pool_case(rng, B, bs, Hkv, g, dh,
                                             max_blocks)
     slot_mask = jnp.asarray([True, False, True, False])
-    out = paged_decode_attention(q, kp, vp, tables, kv_lens,
+    out = paged_decode_attention(q, pair_planes(kp, vp), tables, kv_lens,
                                  slot_mask=slot_mask, interpret=True)
     ref = _ref_attn(q, kp, vp, tables, kv_lens, slot_mask=slot_mask)
     live = np.asarray(slot_mask)
@@ -147,7 +149,8 @@ def test_fused_dead_slots_and_scalar_kvlen(rng):
     assert np.isfinite(np.asarray(out)).all(), \
         "dead slots must emit finite garbage, not NaN"
     # scalar kv_len broadcasts over the batch
-    out_s = paged_decode_attention(q, kp, vp, tables, 7, interpret=True)
+    out_s = paged_decode_attention(q, pair_planes(kp, vp), tables, 7,
+                                   interpret=True)
     ref_s = _ref_attn(q, kp, vp, tables, jnp.full((B,), 7, jnp.int32))
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(ref_s),
                                atol=1e-5)
@@ -158,10 +161,10 @@ def test_fused_dead_slots_and_scalar_kvlen(rng):
 def test_stacked_arena_layer_equals_per_layer_call(rng, shape, kv_dtype):
     """The model's layer scan carries the STACKED arena and hands the
     kernel a layer index: ``paged_attention(..., layer=li)`` over
-    (n_layers, n_blocks, bs, Hkv, dh) must equal the per-layer call on
+    (n_layers, n_blocks, 2, bs, Hkv, dh) must equal the per-layer call on
     ``pool[li]`` bit for bit, for a traced index as for a static one —
     decode shape (L=1) and chunk shape (L>1, ragged q_lens), bf16 and
-    quantized int8 pools (scale arenas stacked the same way)."""
+    quantized int8 pools (the scale arena stacked the same way)."""
     n_layers, B, bs, Hkv, g, dh, max_blocks = 3, 3, 8, 2, 2, 16, 4
     n_blocks = B * max_blocks + 2
     L = 1 if shape == "decode" else 6
@@ -177,8 +180,8 @@ def test_stacked_arena_layer_equals_per_layer_call(rng, shape, kv_dtype):
     def scales(li=None):
         if not quant:
             return {}
-        return dict(k_scale=ks if li is None else ks[li],
-                    v_scale=vs if li is None else vs[li])
+        return dict(scales=pair_planes(ks, vs, 1) if li is None
+                    else pair_planes(ks[li], vs[li], 1))
 
     q = jnp.asarray(rng.normal(size=(B, L, Hkv * g, dh)), q_dtype)
     tables = jnp.asarray(
@@ -191,15 +194,15 @@ def test_stacked_arena_layer_equals_per_layer_call(rng, shape, kv_dtype):
 
     @jax.jit
     def traced(li):
-        return paged_attention(q, kp, vp, tables, kv_lens, layer=li,
-                               **scales(), **kw)
+        return paged_attention(q, pair_planes(kp, vp), tables, kv_lens,
+                               layer=li, **scales(), **kw)
 
     outs = []
     for li in range(n_layers):
-        per_layer = paged_attention(q, kp[li], vp[li], tables, kv_lens,
-                                    **scales(li), **kw)
-        stacked = paged_attention(q, kp, vp, tables, kv_lens, layer=li,
-                                  **scales(), **kw)
+        per_layer = paged_attention(q, pair_planes(kp[li], vp[li]), tables,
+                                    kv_lens, **scales(li), **kw)
+        stacked = paged_attention(q, pair_planes(kp, vp), tables, kv_lens,
+                                  layer=li, **scales(), **kw)
         np.testing.assert_array_equal(np.asarray(stacked, np.float32),
                                       np.asarray(per_layer, np.float32))
         np.testing.assert_array_equal(
@@ -209,10 +212,11 @@ def test_stacked_arena_layer_equals_per_layer_call(rng, shape, kv_dtype):
     # distinct data per layer: the index is live, not ignored
     assert not np.array_equal(outs[0], outs[1])
     with pytest.raises(ValueError, match="layer"):
-        paged_attention(q, kp, vp, tables, kv_lens, **scales(), **kw)
+        paged_attention(q, pair_planes(kp, vp), tables, kv_lens, **scales(),
+                        **kw)
     with pytest.raises(ValueError, match="layer"):
-        paged_attention(q, kp[0], vp[0], tables, kv_lens, layer=0,
-                        **scales(0), **kw)
+        paged_attention(q, pair_planes(kp[0], vp[0]), tables, kv_lens,
+                        layer=0, **scales(0), **kw)
 
 
 # -- the fetch pipeline: two staging slots, a walk as long as the slot -------
@@ -268,9 +272,10 @@ def _walk_case(rng, shape, tile_blocks, poison=False):
     q = jnp.asarray(rng.normal(size=(B, L, Hkv * g, dh)), dtype)
     kp, vp = jnp.asarray(rows, dtype), jnp.asarray(vrows, dtype)
     if latent:
-        kp, vp = kp[:, :, :, 0], None
+        pool = kp[:, :, :, 0]
         ref_k, ref_v = rows[li], rows[li][..., :v_dim]
     else:
+        pool = pair_planes(kp, vp)
         ref_k, ref_v = rows[li], vrows[li]
 
     def oracle(values):
@@ -292,7 +297,7 @@ def _walk_case(rng, shape, tile_blocks, poison=False):
     kw = dict(q_lens=jnp.asarray(q_lens, jnp.int32),
               slot_mask=jnp.asarray(slot_mask), tile_blocks=tile_blocks,
               q_tile=min(L, 4), interpret=True, v_dim=v_dim)
-    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(kv_lens, jnp.int32))
+    args = (q, pool, jnp.asarray(tables), jnp.asarray(kv_lens, jnp.int32))
     return args, kw, li, ref, slot_mask, tol
 
 
@@ -323,18 +328,18 @@ def test_pipelined_walk_matches_gather_reference(rng, shape, tile_blocks):
     layer index, equals the gather oracle — for the K+V build in the decode
     and the chunk shape, for the latent build, and for the folded
     arithmetic over a bf16 pool within the rounding of ``p``."""
-    (q, kp, vp, tables, kv_lens), kw, li, ref, live, tol = _walk_case(
+    (q, pool, tables, kv_lens), kw, li, ref, live, tol = _walk_case(
         rng, shape, tile_blocks)
 
     @jax.jit
     def traced(layer):
-        return paged_attention(q, kp, vp, tables, kv_lens, layer=layer, **kw)
+        return paged_attention(q, pool, tables, kv_lens, layer=layer, **kw)
 
     out = np.asarray(traced(jnp.int32(li)), np.float32)
     _assert_within(out[live], ref[live], tol[live])
     assert np.isfinite(out).all(), \
         "dead slots must emit finite garbage, not NaN"
-    static = paged_attention(q, kp, vp, tables, kv_lens, layer=li, **kw)
+    static = paged_attention(q, pool, tables, kv_lens, layer=li, **kw)
     np.testing.assert_array_equal(np.asarray(static, np.float32), out)
 
 
@@ -344,9 +349,9 @@ def test_prefetch_stages_nothing_the_mask_does_not_scrub(rng, shape):
     a slot's frontier: whatever either staging slot held — this tile's dead
     rows, the last tile's leftovers, a neighbour's blocks — the output of
     the live slots is finite and the oracle's."""
-    (q, kp, vp, tables, kv_lens), kw, li, ref, live, tol = _walk_case(
+    (q, pool, tables, kv_lens), kw, li, ref, live, tol = _walk_case(
         rng, shape, 2, poison=True)
-    out = np.asarray(paged_attention(q, kp, vp, tables, kv_lens, layer=li,
+    out = np.asarray(paged_attention(q, pool, tables, kv_lens, layer=li,
                                      **kw), np.float32)
     assert np.isfinite(out[live]).all()
     _assert_within(out[live], ref[live], tol[live])
@@ -408,8 +413,9 @@ def test_fetch_pipeline_structure(kernel):
 def test_fused_rejects_non_int32_tables(rng):
     q, kp, vp, tables, kv_lens = _pool_case(rng, 2, 8, 2, 1, 16, 2)
     with pytest.raises(TypeError, match="int32"):
-        paged_decode_attention(q, kp, vp, tables.astype(jnp.float32),
-                               kv_lens, interpret=True)
+        paged_decode_attention(q, pair_planes(kp, vp),
+                               tables.astype(jnp.float32), kv_lens,
+                               interpret=True)
     with pytest.raises(TypeError, match="int32"):
         paged_gather_kv(kp, tables.astype(jnp.float32))
 
@@ -477,7 +483,7 @@ def test_paged_attn_with_cache_fused_equals_gather(rng):
     with comm_ledger.ledger(reset_first=True):
         for method in ("fused", "gather"):
             outs[method] = nn.paged_attn_with_cache(
-                q, kp, vp, tables, offset, scale=dh ** -0.5,
+                q, pair_planes(kp, vp), tables, offset, scale=dh ** -0.5,
                 slot_mask=slot_mask, paged_attn=method)
         snap = comm_ledger.snapshot()
     np.testing.assert_allclose(np.asarray(outs["fused"])[:3],
@@ -492,6 +498,127 @@ def test_paged_attn_with_cache_fused_equals_gather(rng):
                                      itemsize=kp.dtype.itemsize,
                                      method=method)
         assert entry["bytes_total"] == expect, method
+    # the size of the walk's fetch rides the fused series: ONE copy carries
+    # a block's K plane and V plane, and a whole tile starts one a block
+    fused = series["fused_decode"]
+    assert fused["copy_bytes"] == 2 * bs * Hkv * dh * kp.dtype.itemsize
+    assert fused["copies_per_tile"] == min(
+        max_blocks, tuned_paged_tile(bs, Hkv, dh, max_blocks,
+                                     str(kp.dtype), L=1, g=g)[0])
+    assert "copy_bytes" not in series["gather"]
+
+
+@pytest.mark.parametrize("Hkv", [2, 8])
+@pytest.mark.parametrize("build", ["decode", "chunk", "window"])
+def test_one_copy_a_block_over_the_paired_pool_equals_the_gather_oracle(
+        rng, build, Hkv):
+    """The pool's ONE arena (a block's K plane and V plane side by side; a
+    ring's planes outside its lines), walked with one copy a block: at the
+    decode shape, the chunk shape (ragged ``seq_lens``) and the window
+    build, at two key heads (the four-chip cell's chip: 16 KB a copy) and
+    at eight, the fused kernel reads what the gather oracle reads, and a
+    head that projects either onto a vocabulary picks the same tokens."""
+    B, bs, g, dh, max_blocks, n_layers, li = 3, 8, 2, 16, 5, 2, 1
+    L = 1 if build == "decode" else 6
+    seq_lens = jnp.asarray([L, max(1, L - 2), max(1, L // 2)], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, L, Hkv * g, dh)), jnp.float32)
+    kw = dict(scale=dh ** -0.5, layer=jnp.int32(li),
+              slot_mask=jnp.asarray([True, True, True]),
+              seq_lens=None if L == 1 else seq_lens)   # ragged chunk rows
+    outs = {}
+    if build == "window":
+        window, ring_blocks = 12, 4                 # 32 lines a slot
+        ring = jnp.asarray(rng.normal(
+            size=(n_layers, B + 1, 2, ring_blocks, bs, Hkv, dh)), jnp.float32)
+        offset = jnp.asarray([3, 21, 70], jnp.int32)    # one round the ring
+        for m in ("fused", "gather"):
+            outs[m] = nn.window_attn_with_cache(
+                q, ring, jnp.asarray([2, 0, 3], jnp.int32), offset,
+                window=window, paged_attn=m, **kw)
+    else:
+        n_blocks = B * max_blocks + 3
+        pool = jnp.asarray(rng.normal(
+            size=(n_layers, n_blocks, 2, bs, Hkv, dh)), jnp.float32)
+        tables = jnp.asarray(rng.permutation(n_blocks)[:B * max_blocks]
+                             .reshape(B, max_blocks), jnp.int32)
+        offset = jnp.asarray([0, 17, max_blocks * bs - L], jnp.int32)
+        for m in ("fused", "gather"):
+            outs[m] = nn.paged_attn_with_cache(q, pool, tables, offset,
+                                               paged_attn=m, **kw)
+    fused, oracle = (np.asarray(outs[m], np.float32)
+                     for m in ("fused", "gather"))
+    np.testing.assert_allclose(fused, oracle, atol=1e-5, rtol=1e-5)
+    head = rng.normal(size=(Hkv * g * dh, 64)).astype(np.float32)
+    live = np.arange(L)[None] < np.asarray(seq_lens)[:, None]
+    tokens = {m: (o.reshape(B, L, -1) @ head).argmax(-1)[live]
+              for m, o in (("fused", fused), ("gather", oracle))}
+    np.testing.assert_array_equal(tokens["fused"], tokens["gather"])
+
+
+@pytest.mark.parametrize("arena", ["blocks", "stacked", "ring"])
+def test_one_append_writes_both_planes_of_its_line_and_a_masked_row_neither(
+        rng, arena):
+    """``nn.paged_cache_update`` / ``nn.window_cache_update`` over the one
+    arena: a token's K row lands in plane 0 and its V row in plane 1 of the
+    (block, line) its position names (one layer of the pool, the stacked
+    arena at a layer, a slot's ring round its end), a masked token and a
+    dead row write NEITHER plane, and every other byte is the input's."""
+    B, L, bs, H, dh, n_blocks, max_blocks = 3, 5, 4, 2, 8, 14, 4
+    new = rng.normal(size=(B, L, 2, H, dh)).astype(np.float32)
+    offsets = np.asarray([0, 10, 11], np.int32)   # row 1 wraps a ring of 12
+    mask = np.arange(L)[None] < np.asarray([5, 3, 0])[:, None]  # row 2 dead
+    tables = rng.permutation(n_blocks)[:B * max_blocks].reshape(
+        B, max_blocks).astype(np.int32)
+    slots, ring_blocks = np.asarray([2, 0, 1], np.int32), 3     # 12 lines
+    if arena == "ring":
+        before = rng.normal(size=(2, 3, 2, ring_blocks, bs, H, dh))
+        got = nn.window_cache_update(
+            jnp.asarray(before, jnp.float32), jnp.asarray(new),
+            jnp.asarray(slots), jnp.asarray(offsets), jnp.asarray(mask),
+            jnp.int32(1))
+    else:
+        before = rng.normal(size=(2, n_blocks, 2, bs, H, dh))
+        pool = jnp.asarray(before, jnp.float32)
+        got = (nn.paged_cache_update(pool, jnp.asarray(new),
+                                     jnp.asarray(tables),
+                                     jnp.asarray(offsets), jnp.asarray(mask),
+                                     layer=jnp.int32(1))
+               if arena == "stacked" else
+               jnp.asarray(before, jnp.float32).at[1].set(
+                   nn.paged_cache_update(pool[1], jnp.asarray(new),
+                                         jnp.asarray(tables),
+                                         jnp.asarray(offsets),
+                                         jnp.asarray(mask))))
+    want = before.astype(np.float32)
+    for b in range(B):
+        for l in range(L):
+            if not mask[b, l]:
+                continue
+            p = int(offsets[b]) + l
+            for plane in (0, 1):
+                if arena == "ring":
+                    want[1, slots[b], plane, (p // bs) % ring_blocks,
+                         p % bs] = new[b, l, plane]
+                else:
+                    want[1, tables[b, p // bs], plane, p % bs] = \
+                        new[b, l, plane]
+    np.testing.assert_array_equal(np.asarray(got), want)
+    changed = np.any(want != before.astype(np.float32), axis=(-1, -2))
+    assert changed.sum() == 2 * mask.sum()      # a K line and a V line each
+
+
+def test_the_kernel_refuses_a_pool_of_single_planes(rng):
+    """K and V as two arenas is no pool any more: an arena without the two
+    planes of a block is refused by name, as is a K+V append without both
+    rows."""
+    q, kp, vp, tables, kv_lens = _pool_case(rng, 2, 8, 2, 1, 16, 2)
+    with pytest.raises(ValueError, match="side by side"):
+        paged_attention(q[:, None], kp[None, :, None], tables, kv_lens,
+                        layer=0, interpret=True)
+    with pytest.raises(ValueError, match="K row and V row together"):
+        nn.paged_cache_update(
+            pair_planes(kp, vp), jnp.zeros((2, 1, 1, 2, 16)), tables,
+            kv_lens - 1)
 
 
 @pytest.mark.parametrize("shape,want", [
@@ -520,12 +647,13 @@ def test_trace_record_names_the_arithmetic(rng, shape, want):
     elif shape == "decode-int8":
         (kq, ks), (vq, vs) = (nn.quantize_kv_rows(x, jnp.int8)
                               for x in (kp, vp))
-        nn.paged_attn_with_cache(q, kq, vq, tables, offset, scale=1.0,
-                                 kv_scales=(ks, vs), interpret=True)
+        nn.paged_attn_with_cache(q, pair_planes(kq, vq), tables, offset,
+                                 scale=1.0, kv_scales=pair_planes(ks, vs, 1),
+                                 interpret=True)
         key = f"q{B}x{L}x{Hkv * g}x{dh}:int8"
     else:
-        nn.paged_attn_with_cache(q, kp, vp, tables, offset, scale=1.0,
-                                 interpret=True)
+        nn.paged_attn_with_cache(q, pair_planes(kp, vp), tables, offset,
+                                 scale=1.0, interpret=True)
         key = f"q{B}x{L}x{Hkv * g}x{dh}:float32"
     assert nn.fused_paged_arithmetic()[key] == want
 
@@ -541,7 +669,7 @@ def test_paged_attn_with_cache_prefill_routes_fused(rng):
     offset = jnp.asarray([3, 0], jnp.int32)          # mixed warm/cold starts
     seq_lens = jnp.asarray([L, 2], jnp.int32)        # ragged chunk lengths
     with comm_ledger.ledger(reset_first=True):
-        out = nn.paged_attn_with_cache(q, kp, vp, tables, offset,
+        out = nn.paged_attn_with_cache(q, pair_planes(kp, vp), tables, offset,
                                        scale=dh ** -0.5, seq_lens=seq_lens,
                                        paged_attn="fused", interpret=True)
         snap = comm_ledger.snapshot()
@@ -550,7 +678,7 @@ def test_paged_attn_with_cache_prefill_routes_fused(rng):
                if isinstance(d, dict) and d.get("collective") == "paged_attn"}
     assert methods == {"fused_prefill"}
     # the explicit escape hatch is the oracle
-    oracle = nn.paged_attn_with_cache(q, kp, vp, tables, offset,
+    oracle = nn.paged_attn_with_cache(q, pair_planes(kp, vp), tables, offset,
                                       scale=dh ** -0.5, seq_lens=seq_lens,
                                       paged_attn="gather")
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
@@ -572,7 +700,7 @@ def test_paged_attn_flag_validation(rng):
     _, kp, vp, tables, kv_lens = _pool_case(rng, 2, 8, 2, 1, 16, 2)
     q = jnp.zeros((2, 1, 2, 16), jnp.float32)
     with pytest.raises(ValueError, match="paged_attn"):
-        nn.paged_attn_with_cache(q, kp, vp, tables, kv_lens - 1,
+        nn.paged_attn_with_cache(q, pair_planes(kp, vp), tables, kv_lens - 1,
                                  scale=0.25, paged_attn="turbo")
     # BatchEngine rejects the flag before building anything
     with pytest.raises(ValueError, match="paged_attn"):

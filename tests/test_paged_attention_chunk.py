@@ -8,6 +8,7 @@ that file's).
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import pair_planes
 from test_paged_attention import _pool_case, _ref_attn_chunk
 
 from triton_distributed_tpu.kernels.paged_attention import paged_attention
@@ -33,7 +34,7 @@ def test_fused_prefill_matches_gather_reference(rng, bs, max_blocks, g, L):
     ref = _ref_attn_chunk(q, kp, vp, tables, kv_lens,
                           jnp.full((B,), L, jnp.int32))
     for q_tile in (None, 1, 4, L):
-        out = paged_attention(q, kp, vp, tables, kv_lens, q_tile=q_tile,
+        out = paged_attention(q, pair_planes(kp, vp), tables, kv_lens, q_tile=q_tile,
                               interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5,
@@ -51,7 +52,7 @@ def test_fused_ragged_mixed_step_and_dead_slots(rng):
     offs = jnp.asarray([16, 0, 9, 2], jnp.int32)        # warm + cold starts
     kv_lens = offs + q_lens
     slot_mask = jnp.asarray([True, True, True, False])
-    out = paged_attention(q, kp, vp, tables, kv_lens, q_lens=q_lens,
+    out = paged_attention(q, pair_planes(kp, vp), tables, kv_lens, q_lens=q_lens,
                           slot_mask=slot_mask, interpret=True)
     masked_tables = jnp.where(slot_mask[:, None], tables, 0)
     ref = _ref_attn_chunk(q, kp, vp, masked_tables, kv_lens, q_lens)
@@ -80,7 +81,7 @@ def test_fused_prefill_causal_boundary_straddle(rng):
     ref = _ref_attn_chunk(q, kp, vp, tables, kv_lens,
                           jnp.full((B,), L, jnp.int32))
     for tile_blocks in (1, 2):
-        out = paged_attention(q, kp, vp, tables, kv_lens, q_tile=4,
+        out = paged_attention(q, pair_planes(kp, vp), tables, kv_lens, q_tile=4,
                               tile_blocks=tile_blocks, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5,

@@ -98,16 +98,16 @@ def test_adoption_refcounts_through_ensure(setup):
 
 
 def test_cow_copies_device_rows(setup):
-    """The CoW block must hold the source block's exact K/V bytes."""
+    """The CoW block must hold the source block's exact K/V bytes: BOTH
+    planes of the block's one run."""
     _, config, _ = setup
     pool, cache = _pool_and_cache(config)
     toks = list(range(6))
     assert pool.ensure("a", 6)
     src_blk = pool.table("a")[1]                # the partial tail block
     # stamp recognizable values into the source block on device
-    k = pool.state.k.at[:, src_blk].set(3.25)
-    v = pool.state.v.at[:, src_blk].set(-1.5)
-    pool.state = type(pool.state)(k=k, v=v)
+    kv = pool.state.kv.at[:, src_blk, 0].set(3.25).at[:, src_blk, 1].set(-1.5)
+    pool.state = type(pool.state)(kv=kv)
     cache.insert("a", toks)
     pool.release("a")
     m = cache.match(toks, max_len=5)
@@ -115,10 +115,10 @@ def test_cow_copies_device_rows(setup):
     assert pool.ensure("b", 6, adopt=m.blocks, cow_src=m.cow_src)
     dst_blk = pool.table("b")[1]
     assert dst_blk != src_blk
-    np.testing.assert_array_equal(np.asarray(pool.state.k[:, dst_blk]),
-                                  np.asarray(pool.state.k[:, src_blk]))
-    np.testing.assert_array_equal(np.asarray(pool.state.v[:, dst_blk]),
-                                  np.asarray(pool.state.v[:, src_blk]))
+    np.testing.assert_array_equal(np.asarray(pool.state.kv[:, dst_blk]),
+                                  np.asarray(pool.state.kv[:, src_blk]))
+    assert np.all(np.asarray(pool.state.kv[:, dst_blk, 0]) == 3.25)
+    assert np.all(np.asarray(pool.state.kv[:, dst_blk, 1]) == -1.5)
     pool.release("b")
     pool.check_invariants()
 

@@ -220,12 +220,13 @@ def test_paged_decode_config_sensitivity():
     assert {f.check for f in blown} == {"vmem-budget"}
 
 
-@pytest.mark.parametrize("kernel,arenas", [("paged.decode", 2),
-                                           ("paged.decode.kvq", 4),
+@pytest.mark.parametrize("kernel,arenas", [("paged.decode", 1),
+                                           ("paged.decode.kvq", 2),
                                            ("paged.latent", 1)])
 def test_paged_footprint_bills_two_staging_slots(kernel, arenas):
     """The walk's fetch pipeline stages every arena twice (tile n + 1 lands
-    while tile n is computed) and owns one semaphore a (slot, arena): the
+    while tile n is computed; the K+V arena's slot holds both planes of the
+    tile) and owns one semaphore a (slot, arena): the
     spec declares what the kernel allocates, so doubling the tile doubles
     twice the staging."""
     geometry = dict(bs=16, max_blocks=64)

@@ -242,7 +242,7 @@ def test_serve_smoke_kvq(tmp_path):
     db = tmp_path / "perf.jsonl"
     m = _load().main_kvq(seed=0, gen=8, perfdb_path=str(db))
     assert m["kv_dtype"] == "int8"
-    assert m["kv_fingerprint"] == "int8:rowmax:v1"
+    assert m["kv_fingerprint"] == "int8:rowmax:v1:paired"
     assert m["warm_bit_identical"] is True
     assert m["gen"] == 8
     assert m["requests_completed"] == m["requests_submitted"] > 0

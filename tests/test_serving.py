@@ -343,16 +343,17 @@ def _paged_step(engine, kind, paged_attn, fmt="bf16"):
     """One hand-made paged step of ``forward_paged`` (through the step's
     own shard_map): 4 slots with slot 2 DEAD but holding stale table rows,
     shuffled tables, every arena of the pool's state filled with random
-    data. ``fmt``: "bf16" (K and V arenas in the model dtype), "int8" (plus
-    the two scale arenas) or "latent" (``engine`` a latent model: one
-    arena). Returns ``(sm, args, written)``: ``args[2]`` is the state and
+    data. ``fmt``: "bf16" (the K+V arena in the model dtype, a block's two
+    planes side by side), "int8" (plus the scale arena) or "latent"
+    (``engine`` a latent model: one arena of rows, no planes). Returns ``(sm, args, written)``: ``args[2]`` is the state and
     ``written`` the set of (block, line) the step's tables address for live
     tokens — the same in every layer."""
     c = engine.config
     rng = np.random.default_rng(7)
     B, L = 4, (1 if kind == "decode" else 4)
     max_blocks = min(c.max_length // _BS, _NB // B)
-    shape = (c.n_layers, _NB, _BS, *c.kv_row_shapes[0])
+    planes = () if fmt == "latent" else (2,)
+    shape = (c.n_layers, _NB, *planes, _BS, *c.kv_row_shapes[0])
     tables = rng.permutation(_NB)[:B * max_blocks].reshape(B, max_blocks)
     offsets = np.asarray([5, 0, 9, 3], np.int32)
     mask = np.asarray([True, True, False, True])
@@ -366,14 +367,11 @@ def _paged_step(engine, kind, paged_attn, fmt="bf16"):
 
     if fmt == "int8":
         state = PagedKVState(
-            *[jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
-              for _ in "kv"],
-            *[jnp.asarray(rng.uniform(0.01, 0.02, size=shape[:-1]),
-                          jnp.float32) for _ in "kv"])
-    elif fmt == "latent":
-        state = PagedKVState(k=rows(), v=None)
+            jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8),
+            jnp.asarray(rng.uniform(0.01, 0.02, size=shape[:-1]),
+                        jnp.float32))
     else:
-        state = PagedKVState(k=rows(), v=rows())
+        state = PagedKVState(kv=rows())
     ids = jnp.asarray(rng.integers(0, c.vocab_size, size=(B, L)), jnp.int32)
     args = [engine.params, ids, state, jnp.asarray(offsets),
             jnp.asarray(tables, jnp.int32), jnp.asarray(mask)]
@@ -385,6 +383,19 @@ def _paged_step(engine, kind, paged_attn, fmt="bf16"):
     return sm, args, written
 
 
+def _touched(arena, written):
+    """The rows of a row arena that appends at the (block, line) pairs
+    ``written`` may touch, in every layer: BOTH planes of the pair in a K+V
+    arena ``(layers, blocks, 2, lines, ...)`` (or its scale arena), the row
+    in a latent one ``(layers, blocks, lines, row)``."""
+    paired = arena.shape[2] == 2 and arena.ndim >= 5
+    touched = np.zeros(arena.shape[:4 if paired else 3], bool)
+    for blk, line in written:
+        touched[(slice(None), blk, slice(None), line) if paired
+                else (slice(None), blk, line)] = True
+    return touched
+
+
 @pytest.mark.parametrize("fmt", ["bf16", "latent"])
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_paged_step_appends_in_place_fused_equals_gather(
@@ -392,10 +403,12 @@ def test_paged_step_appends_in_place_fused_equals_gather(
     """One decode step and one mixed step on a pool full of data: the
     append touches exactly the (layer, block, line) rows the tables
     address for live tokens — a dead slot's stale table rows and positions
-    past ``seq_lens`` write NOTHING, every other byte of every layer is
-    the input's — on the fused path (the kernel DMAs ``[layer, block]`` out
-    of the carried arena) and on the gather oracle (``pool[layer]``)
-    alike, for K and V arenas and for a latent pool's one arena. The two
+    past ``seq_lens`` write NOTHING (neither plane), every other byte of
+    every layer is the input's — on the fused path (the kernel DMAs
+    ``[layer, block]``, both planes in one copy, out of the carried arena)
+    and on the gather oracle (``pool[layer]``) alike, for the K+V arena
+    (ONE append writes the K plane and the V plane of the (block, line))
+    and for a latent pool's arena. The two
     agree bit for bit on the first layer's appended rows and, past it, to
     the float32 rounding of their two softmax orders."""
     engine = latent_engine if fmt == "latent" else setup[2]
@@ -410,9 +423,7 @@ def test_paged_step_appends_in_place_fused_equals_gather(
         for before, after in zip(jax.tree.leaves(args[2]),
                                  pools[paged_attn]):
             before = np.asarray(before)
-            touched = np.zeros(before.shape[:3], bool)
-            for blk, line in written:
-                touched[:, blk, line] = True
+            touched = _touched(before, written)
             np.testing.assert_array_equal(after[~touched], before[~touched])
             # an appended row is new data, in every layer
             n = int(touched.sum())
@@ -432,7 +443,7 @@ def test_paged_step_appends_in_place_fused_equals_gather(
 def test_paged_pool_rides_the_layer_scan_as_carry(setup, latent_engine, kind,
                                                   paged_attn, fmt):
     """Structural guard (PERF.md, PR 26): in ``forward_paged`` the arenas
-    of the pool's state — K and V, a quantized pool's scale arenas, a
+    of the pool's state — the K+V arena, a quantized pool's scale arena, a
     latent pool's one arena — appear in the layer scan ONLY among the
     carry. As ``xs``/``ys`` each layer of the pool is sliced out to feed
     the Pallas call and stacked back, five passes over both arenas a step
@@ -440,7 +451,7 @@ def test_paged_pool_rides_the_layer_scan_as_carry(setup, latent_engine, kind,
     engine = latent_engine if fmt == "latent" else setup[2]
     config = engine.config
     sm, args, _ = _paged_step(engine, kind, paged_attn, fmt)
-    arena = tuple(args[2].k.shape)
+    arena = tuple(args[2].kv.shape)
     pool_shapes = {arena, arena[1:], arena[:-1], arena[1:-1]}
 
     def scans(jaxpr):
@@ -463,7 +474,7 @@ def test_paged_pool_rides_the_layer_scan_as_carry(setup, latent_engine, kind,
     n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
     carry = eqn.invars[n_const:n_const + n_carry]
     n_arenas = len(jax.tree.leaves(args[2]))
-    assert n_arenas == {"bf16": 2, "int8": 4, "latent": 1}[fmt]
+    assert n_arenas == {"bf16": 1, "int8": 2, "latent": 1}[fmt]
     assert n_pool(carry) == n_arenas
     assert n_pool(eqn.invars) == n_arenas            # none in consts or xs
     assert n_pool(eqn.outvars[:n_carry]) == n_arenas
@@ -492,7 +503,7 @@ def test_the_steps_take_the_state_whole_and_return_one_record(
     structure = jax.tree.structure(be.pool.state)
     assert structure == jax.tree.structure(be.pool.specs)
     assert len(jax.tree.leaves(be.pool.state)) == {
-        "model-dtype": 2, "int8": 4, "latent": 1}[fmt]
+        "model-dtype": 1, "int8": 2, "latent": 1}[fmt]
     steps = {name: getattr(be, name)
              for name in ("_decode_step", "_mixed_step")}
     seen = {}
@@ -772,9 +783,7 @@ def test_two_blocks_equal_the_dense_block(setup, latent_engine, paged_attn,
     assert jax.tree.structure(state) == jax.tree.structure(args[2])
     for before, d, t in zip(*map(jax.tree.leaves, (args[2], dense, state))):
         before, d, t = map(np.asarray, (before, d, t))
-        touched = np.zeros(before.shape[:3], bool)
-        for blk, line in written:
-            touched[:, blk, line] = True
+        touched = _touched(before, written)
         np.testing.assert_array_equal(t[~touched], before[~touched])
         np.testing.assert_array_equal(t[0], d[0])
         np.testing.assert_allclose(t.astype(np.float32),

@@ -17,11 +17,13 @@ from triton_distributed_tpu.serving import BatchEngine, KVPool
 def test_pool_sharded_over_kv_heads(mesh8):
     config = ModelConfig.from_name("tiny")
     pool = KVPool(config, n_blocks=16, block_size=4, mesh=mesh8)
-    spec = pool.state.k.sharding.spec
-    assert tuple(spec) == (None, None, None, "tp", None)
-    # 8 kv heads over 8 devices: each shard holds one head
-    shard = pool.state.k.addressable_shards[0].data
-    assert shard.shape[3] == config.n_kv_heads // 8
+    # (layers, blocks, 2 planes, lines, Hkv, dh): the head dim one position
+    # right of where two arenas kept it
+    spec = pool.state.kv.sharding.spec
+    assert tuple(spec) == (None, None, None, None, "tp", None)
+    # 8 kv heads over 8 devices: each shard holds one head, both planes
+    shard = pool.state.kv.addressable_shards[0].data
+    assert shard.shape[2] == 2 and shard.shape[4] == config.n_kv_heads // 8
 
 
 @pytest.fixture(scope="module")

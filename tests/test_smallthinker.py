@@ -125,8 +125,8 @@ def logits_through_the_pool(engine):
     kw = dict(paged_attn="gather", state_specs=pool.specs)
     pre = jax.jit(engine._make_sm("dist", paged="prefill", **kw))
     dec = jax.jit(engine._make_sm("dist", paged="decode", **kw))
-    assert pool.state.wk.shape == (N_WINDOW, 2, 8, 4, 2, 16)
-    assert pool.state.k.shape[0] == N_FULL
+    assert pool.state.wkv.shape == (N_WINDOW, 2, 2, 8, 4, 2, 16)
+    assert pool.state.kv.shape[0] == N_FULL
     assert pool.ensure("a", 51)
     tables = jnp.asarray(pool.padded_tables([None, "a"]))
     state, got, off = pool.state, [], 0
@@ -247,8 +247,7 @@ def test_batch_engine_serves_what_the_reference_puts_first(served,
                               "full": N_FULL}
     assert snap["moe"] == served.model.moe_forms
     assert snap["moe"]["router_input"] == "layer_input"
-    assert snap["pool"]["window_bytes"] == be.pool.state.wk.nbytes \
-        + be.pool.state.wv.nbytes > 0
+    assert snap["pool"]["window_bytes"] == be.pool.state.wkv.nbytes > 0
     if paged_attn == "fused":
         named = {k: v for k, v in snap["paged_arithmetic"].items()
                  if k.endswith(f":window{WINDOW}") and "x6x16:" in k}
@@ -475,11 +474,11 @@ def test_counts_of_the_published_configuration():
         mcfg, n_blocks=fleet["n_blocks"], block_size=fleet["block_size"],
         n_slots=fleet["n_slots"], max_take=7 * fleet["prefill_chunk"])
     nbytes = {f: int(np.prod(a.shape)) * a.dtype.itemsize
-              for f in ("k", "v", "wk", "wv") if (a := getattr(state, f))}
-    assert state.wk.shape == (6, 32, 284, 16, 4, 128)
+              for f in ("kv", "wkv") if (a := getattr(state, f))}
+    assert state.wkv.shape == (6, 32, 2, 284, 16, 4, 128)
     assert (fleet["n_slots"], fleet["n_blocks"]) == (32, 20_480)
-    assert nbytes["wk"] + nbytes["wv"] == 6 * 32 * 284 * 16 * 2048
-    assert nbytes["wk"] + nbytes["wv"] == pytest.approx(1.79e9, rel=2e-3)
-    assert nbytes["k"] + nbytes["v"] == pytest.approx(1.34e9, rel=2e-3)
+    assert nbytes["wkv"] == 6 * 32 * 284 * 16 * 2048
+    assert nbytes["wkv"] == pytest.approx(1.79e9, rel=2e-3)
+    assert nbytes["kv"] == pytest.approx(1.34e9, rel=2e-3)
     # eight layers of full rows at this context would be 5.4 GB
     assert 2 * family.params_held(m) + sum(nbytes.values()) < 11.1e9

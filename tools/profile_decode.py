@@ -25,9 +25,13 @@ contexts drawn 300 .. ``--max-len`` less a chunk (there is no pool to fill),
 so ``--window 4096 --max-len 16384 --layers 6 --hkv 4 --g 7`` is the
 SmallThinker cell's geometry (a ring of 284 blocks a slot, contexts past the
 window and round the ring); its lines also say how many copies a layer's
-walks start: two a WHOLE tile whose blocks lie side by side in the ring,
-two a live block of any other tile (ragged at an end, or wrapping the
-ring). No cell runs it; it is ROADMAP S5's yardstick."""
+walks start: one a WHOLE tile whose blocks lie side by side in the ring,
+one a live block of any other tile (ragged at an end, or wrapping the
+ring). Every line says what one copy carries (``copy_bytes``: a block's K
+plane and V plane are one run of the pool's one arena, ``serving.kv_pool``)
+and how many a whole tile starts. ``--hkv 2 --g 4 --layers 36`` is the
+four-chip cell's walk, a chip's two key heads (16 KB a copy). No cell runs
+it; it is ROADMAP S5's yardstick."""
 import functools, time
 import os, sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -47,15 +51,15 @@ def _probes_mode():
     n_blocks = B * max_blocks
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(B, L, Hq, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, dh)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(n_blocks, 2, bs, Hkv, dh)),
+                       jnp.float32)
     tables = jnp.asarray(rng.permutation(n_blocks).reshape(B, max_blocks),
                          jnp.int32)
     kv_lens = jnp.asarray(
         rng.integers(L, max_blocks * bs + 1, size=B), jnp.int32)
 
     t0 = time.perf_counter()
-    out, pbuf = paged_attention(q, kp, vp, tables, kv_lens,
+    out, pbuf = paged_attention(q, pool, tables, kv_lens,
                                 tile_blocks=tile, probes=True)
     jax.block_until_ready(out)
     wall_us = (time.perf_counter() - t0) * 1e6
@@ -80,9 +84,9 @@ def _probes_mode():
 
 def _window_copies(kv_len, window, bs, tile, ring):
     """What a decoding row's window walk fetches, by the kernel's rule: (whole
-    tiles whose blocks lie side by side in the ring: ONE copy an arena; live
-    blocks of every other tile, ragged at an end or wrapping the ring: a copy
-    each)."""
+    tiles whose blocks lie side by side in the ring: ONE copy, both planes;
+    live blocks of every other tile, ragged at an end or wrapping the ring: a
+    copy each)."""
     lo, span = max(kv_len - window, 0), tile * bs
     whole = blocks = 0
     for t in range(lo // span, -(-kv_len // span)):
@@ -160,21 +164,19 @@ def _kernel_mode():
         row, Hq, dh, kw = (W,), a.g, W, dict(v_dim=V)
     else:
         row, Hq, dh, kw = (a.hkv, a.dh), a.hkv * a.g, a.dh, {}
-    pool = (a.layers, nb, bs)
+    # the pool's one arena (serving.kv_pool): a block's K plane and V plane
+    # side by side; a latent block's rows are both and have no planes
+    pool = (a.layers, nb, bs) if a.latent else (a.layers, nb, 2, bs)
     if a.window:
         # a ring a slot that holds the window and a step's 7 rows of a chunk
-        # (serving.kv_pool.window_ring_blocks); the table names slots
+        # (serving.kv_pool.window_ring_blocks), the planes outside its
+        # lines; the table names slots
         ring = -(-(a.window - 1 + 7 * a.chunk) // bs)
-        pool, nb = (a.layers, B, ring, bs), B * ring
+        pool, nb = (a.layers, B, 2, ring, bs), B * ring
         tables = np.arange(B, dtype=np.int32)[:, None]
         kw = dict(window=a.window)
-    arenas = [jax.random.normal(jax.random.fold_in(key, i),
-                                (*pool, *row), jnp.bfloat16)
-              for i in range(1 if a.latent else 2)]
-    if a.latent:
-        arenas.append(None)
-    block_bytes = sum(x.nbytes for x in arenas if x is not None) \
-        // (a.layers * nb)
+    arena = jax.random.normal(key, (*pool, *row), jnp.bfloat16)
+    block_bytes = arena.nbytes // (a.layers * nb)
     dist_print(
         f"geometry: {a.layers} layers, pool {nb} x {bs} rows, row {row}, "
         f"{B} slots, contexts {lens.min()}-{lens.max()} "
@@ -196,10 +198,10 @@ def _kernel_mode():
             resolved = {}       # what the call chose: tile and arithmetic
 
             @jax.jit
-            def step(q, arenas):
+            def step(q, arena):
                 def layer(q, li):
                     out = paged_attention(
-                        q, arenas[0], arenas[1], jnp.asarray(tables),
+                        q, arena, jnp.asarray(tables),
                         jnp.asarray(kv_lens), q_lens=jnp.asarray(q_lens),
                         layer=li, tile_blocks=tile, resolved=resolved,
                         **kw)
@@ -208,12 +210,12 @@ def _kernel_mode():
                 return jax.lax.scan(layer, q,
                                     jnp.arange(a.layers, dtype=jnp.int32))[0]
 
-            step(q, arenas).block_until_ready()
-            step(q, arenas).block_until_ready()
+            step(q, arena).block_until_ready()
+            step(q, arena).block_until_ready()
             t0 = time.perf_counter()
             out = q
             for _ in range(a.iters):
-                out = step(out, arenas)
+                out = step(out, arena)
             out.block_until_ready()
             ms = (time.perf_counter() - t0) * 1e3 / a.iters
             # Under jit the kernel takes the heuristic default (an eager
@@ -235,17 +237,19 @@ def _kernel_mode():
                     w, o = _window_copies(int(kv_lens[b]), a.window, bs,
                                           t_used, ring)
                     whole, other = whole + w, other + o
-                copies = (f", {2 * (whole + other)} copies a layer for the "
-                          f"decoding rows ({whole} whole tiles x 2, {other} "
-                          f"blocks x 2; a copy a block: "
-                          f"{2 * (whole * t_used + other)})")
+                copies = (f", {whole + other} copies a layer for the "
+                          f"decoding rows ({whole} whole tiles, {other} "
+                          f"blocks; a copy a block: "
+                          f"{whole * t_used + other})")
             dist_print(
                 f"{shape:6s} tile_blocks={t_used:3d} "
                 f"{resolved['arithmetic']:8s}: {ms:8.3f} ms a step "
                 f"({ms / a.layers * 1e3:7.1f} us a layer), "
                 f"{n_tiles} live tiles a layer, "
                 f"{ms * 1e3 / a.layers / n_tiles:6.2f} us a tile, "
-                f"{live_bytes / ms / 1e6:6.1f} GB/s of live bytes"
+                f"{live_bytes / ms / 1e6:6.1f} GB/s of live bytes, "
+                f"{resolved['copy_bytes']} B a copy, "
+                f"{resolved['copies_per_tile']} copies a whole tile"
                 + copies)
 
 

@@ -101,8 +101,18 @@ def check_trace(trace: events.TraceResult, sim: comm_graph.SimResult, *,
     return vs
 
 
-def _overlap(a_lo, a_hi, b_lo, b_hi) -> bool:
-    return a_lo < b_hi and b_lo < a_hi
+def _overlap(a_lo, a_hi, b_lo, b_hi, a_runs=(), b_runs=()) -> bool:
+    """Do two byte ranges of one buffer share a byte? ``*_runs``: a strided
+    view's contiguous runs (``events.FakeRef.runs``), of which [lo, hi) is
+    only the bounding box: two copies into different rows of a staging
+    slot's two planes interleave without touching."""
+    if not (a_lo < b_hi and b_lo < a_hi):
+        return False
+    if not a_runs and not b_runs:
+        return True
+    return any(x_lo < y_hi and y_lo < x_hi
+               for x_lo, x_hi in a_runs or ((a_lo, a_hi),)
+               for y_lo, y_hi in b_runs or ((b_lo, b_hi),))
 
 
 def _avail_seq(sim: comm_graph.SimResult, eid: int | None,
@@ -133,7 +143,8 @@ def _race_check(trace: events.TraceResult, sim: comm_graph.SimResult,
                 continue
             if ev.buf != rec.dst_buf:
                 continue
-            if not _overlap(ev.lo, ev.hi, rec.dst_lo, rec.dst_hi):
+            if not _overlap(ev.lo, ev.hi, rec.dst_lo, rec.dst_hi, ev.runs,
+                            rec.dst_runs):
                 continue
             if ev.seq <= start:
                 continue
@@ -158,7 +169,8 @@ def _race_check(trace: events.TraceResult, sim: comm_graph.SimResult,
                 continue
             if ev.buf != rec.src_buf:
                 continue
-            if not _overlap(ev.lo, ev.hi, rec.src_lo, rec.src_hi):
+            if not _overlap(ev.lo, ev.hi, rec.src_lo, rec.src_hi, ev.runs,
+                            rec.src_runs):
                 continue
             if rec.start_seq < ev.seq < savail:
                 vs.append(Violation(

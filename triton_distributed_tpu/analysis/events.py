@@ -74,6 +74,9 @@ class Event:
     buf: str | None = None      # read/write: root buffer name
     lo: int = 0                 # read/write: byte range [lo, hi) in buffer
     hi: int = 0
+    # a DMA's read/write of a STRIDED view: its contiguous byte runs, where
+    # [lo, hi) is only their bounding box (() = the range is exact)
+    runs: tuple = ()
     dma: int | None = None      # id of the DMA this event belongs to
     side: str | None = None     # inc: 'send' | 'recv' for DMA increments
     label: str = ""
@@ -101,6 +104,8 @@ class DmaRecord:
     start_seq: int              # seq (src rank log) where .start() ran
     send_eid: int | None        # eid of the send-side inc (remote only)
     recv_eid: int | None        # eid of the recv-side inc
+    src_runs: tuple = ()        # the runs of a strided source / destination
+    dst_runs: tuple = ()        # (``FakeRef.runs``; () = the range is exact)
 
     def describe(self) -> str:
         if self.kind == "local":
@@ -315,6 +320,36 @@ class FakeRef:
                   for s, st in zip(v.shape, v.strides)) + v.itemsize
         return (int(off), int(off + ext))
 
+    #: Most runs ``runs`` lists; a view in more pieces keeps its bounding box.
+    MAX_RUNS = 64
+
+    def runs(self) -> tuple:
+        """The contiguous byte runs ``((lo, hi), ...)`` of a STRIDED view
+        inside its root buffer, in order, or ``()`` where the view is one
+        run (``bbox`` is then exact) or has more than ``MAX_RUNS`` (its
+        bounding box stands for it: conservative). A copy into the two
+        planes of a staging slot is two runs; between them lie the rows of
+        the tile's other blocks, which another copy may write meanwhile."""
+        v = self._view
+        if v.size == 0:
+            return ()
+        block, dim = v.itemsize, v.ndim
+        while dim and (v.shape[dim - 1] == 1 or v.strides[dim - 1] == block):
+            block *= v.shape[dim - 1]
+            dim -= 1
+        lead = v.shape[:dim]
+        if not lead or int(np.prod(lead)) > self.MAX_RUNS:
+            return ()
+        base = self.bbox()[0]       # strides are positive: the first byte
+        out: list[tuple[int, int]] = []
+        for idx in np.ndindex(*lead):
+            lo = base + int(np.dot(idx, v.strides[:dim]))
+            if out and out[-1][1] == lo:
+                out[-1] = (out[-1][0], lo + block)
+            else:
+                out.append((lo, lo + block))
+        return tuple(out) if len(out) > 1 else ()
+
     # -- slicing (no event: pure view, like pl.Ref.at) ---------------------
     @property
     def at(self):
@@ -512,14 +547,16 @@ class FakeDMA:
         t = self._tracer
         did = t.new_dma_id()
         src_lo, src_hi = self.src.bbox()
+        src_runs = self.src.runs()
         start_seq = len(t.logs[t.rank]) if t.recording else 0
         t.emit(kind="read", buf=self.src.name, lo=src_lo, hi=src_hi,
-               dma=did)
+               runs=src_runs, dma=did)
         if self.kind == "local":
             dst_lo, dst_hi = self.dst.bbox()
+            dst_runs = self.dst.runs()
             self._copy_into(self.dst)
             t.emit(kind="write", buf=self.dst.name, lo=dst_lo, hi=dst_hi,
-                   dma=did)
+                   runs=dst_runs, dma=did)
             ev = t.emit(kind="inc", sem=self.recv_sem.sid, target=t.rank,
                         amount=self.dst.nbytes, dma=did, side="recv")
             if did is not None:
@@ -529,7 +566,8 @@ class FakeDMA:
                     dst_buf=self.dst.name, dst_lo=dst_lo, dst_hi=dst_hi,
                     send_sem=None, recv_sem=self.recv_sem.sid,
                     start_seq=start_seq, send_eid=None,
-                    recv_eid=ev.eid if ev else None))
+                    recv_eid=ev.eid if ev else None,
+                    src_runs=src_runs, dst_runs=dst_runs))
         else:
             peer_dst = self.dst._rebind(self.dst_rank)
             dst_lo, dst_hi = peer_dst.bbox()
@@ -549,7 +587,8 @@ class FakeDMA:
                     send_sem=self.send_sem.sid, recv_sem=self.recv_sem.sid,
                     start_seq=start_seq,
                     send_eid=send_ev.eid if send_ev else None,
-                    recv_eid=recv_ev.eid if recv_ev else None))
+                    recv_eid=recv_ev.eid if recv_ev else None,
+                    src_runs=src_runs, dst_runs=peer_dst.runs()))
         return self
 
     def _copy_into(self, dst: FakeRef) -> None:

@@ -32,7 +32,10 @@ scalar-prefetched lengths: the running (acc, max, denom) triple lives for
 the step, and tiles past a slot's causal frontier are never visited — a
 short sequence in a long-table batch costs only its own tiles. The loop is
 a two-slot FETCH PIPELINE (one path for the K+V, the quantized and the
-latent build): every live block of a tile is one DMA an arena, all of a
+latent build): every live block of a tile is ONE DMA (the pool keeps a
+block's K rows and V rows side by side, ``serving.kv_pool``: both planes are
+one run of bytes and one copy; a quantized pool's scale planes are a second
+arena and a second copy), all of a
 tile's copies are started before any is waited for (a 32 KiB copy waited
 for alone costs its latency, 0.46 us: 70 GB/s of 819), and tile ``n + 1``'s
 copies — after a step's last tile, the next grid step's first — fly into
@@ -42,14 +45,15 @@ outputs discarded by the caller; padding query rows (j >= q_lens[b]) emit
 exact zeros, matching ``attn_with_cache``'s varlen contract.
 
 Three things are static a call site, and each is one choice of the ONE
-kernel body: the BUILD (K+V arenas, their quantized form with scale arenas,
-or one latent arena), the ARITHMETIC of a staged tile (``tile_arithmetic``:
-folded at the decode shape, per head elsewhere) and, since the model with
+kernel body: the BUILD (one K+V arena of paired planes, its quantized form
+with a scale arena, or one latent arena), the ARITHMETIC of a staged tile
+(``tile_arithmetic``: folded at the decode shape, per head elsewhere) and,
+since the model with
 window layers, where the WALK STARTS: at block 0 with the causal frontier
 its only limit, or (``window=w``) at the tile that holds the query tile's
 oldest visible key, over a window layer's ring storage (``(layers, slots,
-ring blocks, ...)``, the table one slot id a row and a logical block's
-place in the ring arithmetic), with a lower bound in the score-side select
+2 planes, ring blocks, ...)``, the table one slot id a row and a logical
+block's place in the ring arithmetic), with a lower bound in the score-side select
 and the V scrub. A block wholly behind the window costs no copy and no
 wait, so a window layer's step reads ``window`` rows a sequence whatever
 its context; the fetch pipeline and both arithmetics are shared. That build
@@ -97,9 +101,9 @@ _QTILE_CANDIDATES = (64, 32, 16, 8, 4, 2, 1)
 def _feasible_tiles(block_size: int, n_kv_heads: int, head_dim: int,
                     max_blocks: int, itemsize: int,
                     kv_scales: bool = False) -> list[int]:
-    """Candidate kv tiles whose VMEM staging — K and V, TWO slots each,
-    what the kernel allocates for its fetch pipeline — fits the collective
-    staging budget, capped at the table width; heuristic default first
+    """Candidate kv tiles whose VMEM staging — the K and the V plane, TWO
+    slots each, what the kernel allocates for its fetch pipeline — fits the
+    collective staging budget, capped at the table width; heuristic default first
     (largest feasible tile staging <= 512 cache rows — enough copies in
     flight to hide a DMA's latency without hogging VMEM, the flash-decode
     chunk preference applied to blocks). ``kv_scales`` bills the quantized
@@ -211,22 +215,15 @@ def tuned_paged_tile(block_size: int, n_kv_heads: int, head_dim: int,
         dtype = jnp.dtype(dtype_str)
         n_blocks = B * max_blocks
         key = jax.random.PRNGKey(0)
-        ks = vs = None
+        pool = jax.random.normal(
+            key, (n_blocks, 2, block_size, n_kv_heads, head_dim))
+        scales = None
         if quant:
             from triton_distributed_tpu.layers.nn import quantize_kv_rows
 
-            kp, ks = quantize_kv_rows(jax.random.normal(
-                key, (n_blocks, block_size, n_kv_heads, head_dim)), dtype)
-            vp, vs = quantize_kv_rows(jax.random.normal(
-                jax.random.fold_in(key, 1),
-                (n_blocks, block_size, n_kv_heads, head_dim)), dtype)
+            pool, scales = quantize_kv_rows(pool, dtype)
         else:
-            kp = jax.random.normal(
-                key,
-                (n_blocks, block_size, n_kv_heads, head_dim)).astype(dtype)
-            vp = jax.random.normal(
-                jax.random.fold_in(key, 1),
-                (n_blocks, block_size, n_kv_heads, head_dim)).astype(dtype)
+            pool = pool.astype(dtype)
         q = jax.random.normal(
             jax.random.fold_in(key, 2),
             (B, L, n_kv_heads * g, head_dim)).astype(
@@ -243,9 +240,9 @@ def tuned_paged_tile(block_size: int, n_kv_heads: int, head_dim: int,
             def loop(q, n_iter):
                 def body(_, acc):
                     out = paged_attention(
-                        acc.astype(q.dtype), kp, vp, tables, kv_lens,
+                        acc.astype(q.dtype), pool, tables, kv_lens,
                         q_lens=q_lens, tile_blocks=tile, q_tile=q_tile,
-                        k_scale=ks, v_scale=vs)
+                        scales=scales)
                     return out.astype(jnp.float32)
                 return jax.lax.fori_loop(0, n_iter, body,
                                          q.astype(jnp.float32))
@@ -284,16 +281,25 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     is issued, which is the whole trick: the block ids ARE the gather,
     resolved in-kernel); the q block; ``n_arenas`` pool arenas in ANY/HBM;
     the out block (then the probe buffer of a probed build); one staging
-    buffer an arena, ``(2, tile_blocks * bs, ...)``; the running (acc, max,
+    buffer an arena, ``(2 slots, 2 planes, tile_blocks * bs, ...)`` (a
+    latent arena has no planes); the running (acc, max,
     denominator); the DMA semaphores ``(2, n_arenas)``; the walk's state
     across grid steps (then the probe's ordinal cell). The arenas stay
-    STACKED ``(n_layers, n_blocks, bs, ...)`` — the layer is one more DMA
+    STACKED ``(n_layers, n_blocks, 2, bs, ...)`` — the layer is one more DMA
     index, so the model's layer scan never slices (and so never
     materializes) a layer of the pool.
 
     THE FETCH PIPELINE — one path for every build. Block ``i`` of a kv tile
-    is one copy an arena, ``arena.at[layer, blk] -> staging.at[slot, rows
-    of i]`` on semaphore ``[slot, arena]``. Turn ``j`` of the walk STARTS
+    is ONE copy an arena, ``arena.at[layer, blk] -> staging.at[slot, :, rows
+    of i]`` on semaphore ``[slot, arena]``: the block's K plane and V plane
+    lie side by side in HBM (``2 * bs * Hkv * dh`` items, one run) and land
+    in the two planes of the staging slot, so ``staging[slot, 0]`` is the
+    tile's keys and ``staging[slot, 1]`` its values. A copy costs 35-37 ns
+    whatever it carries and a tile is bound by the NUMBER of its copies
+    until a copy is about 25-32 KiB (PERF.md section 6, PR 42): a pair is
+    half the starts and half the waits of a copy a plane, at twice the
+    bytes (``copy_bytes`` / ``copies_per_tile`` of ``paged_attn_cost`` and
+    of the comm ledger's ``paged_attn`` series). Turn ``j`` of the walk STARTS
     every live copy of tile ``j`` — none is waited for until all are in
     flight — and then waits for tile ``j - 1``'s copies in the other slot
     and computes from it: tile ``j``'s bytes fly while tile ``j - 1`` is
@@ -335,27 +341,29 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     cast to float32 (bf16 operands of a few query rows hit Mosaic's
     relayout path), V selected tile by tile.
 
-    THE WINDOW BUILD (``window`` given; K+V arenas only) is the third static
+    THE WINDOW BUILD (``window`` given; the K+V build only) is the third static
     choice of the walk, beside the build and the arithmetic: a query at
     position ``p`` sees the keys ``p - window < j <= p``. The walk STARTS at
     the tile that holds the query tile's oldest visible key, so a block
     wholly behind the window is neither copied nor waited for; the
     score-side select and the V scrub take that lower bound beside the
-    causal one. The arenas are a window layer's RING storage ``(layers,
-    slots, ring blocks, bs, Hkv, dh)`` (``serving.kv_pool``), handed to the
-    kernel as a slot's lines ``(layers, slots, ring blocks * bs, Hkv, dh)``
-    (the same bytes): the table is ``(B, 1)``, the SLOT each row belongs
+    causal one. The arena is a window layer's RING storage ``(layers,
+    slots, 2, ring blocks, bs, Hkv, dh)`` (``serving.kv_pool``: the planes
+    OUTSIDE a slot's lines), handed to the
+    kernel as a slot's lines ``(layers, slots, 2, ring blocks * bs, Hkv,
+    dh)`` (the same bytes): the table is ``(B, 1)``, the SLOT each row belongs
     to, and logical block ``j`` of that slot is ring block ``j % ring
     blocks`` of it: arithmetic, no table of blocks. So the blocks of a tile
     lie SIDE BY SIDE in HBM, which no paged pool's do, and the fetch takes
     its copy size from that: a WHOLE tile (every block live: none behind
-    ``lo``, none past ``limit``) that does not wrap the ring is ONE copy an
-    arena, started once and waited for once at the tile's size; a tile
+    ``lo``, none past ``limit``) that does not wrap the ring is ONE copy
+    (a run of lines a plane: two chunks of one DMA), started once and waited
+    for once at the tile's size; a tile
     ragged at either end, or one that wraps, goes a copy a live block like
     any other build's. A copy costs about 37 ns whatever it carries
-    (PERF.md section 6, PR 42), so a tile of 32 blocks of 16 KiB is bound by
-    its 64 copies, and by its bytes only as 2. No whole copy reads a block
-    the walk by blocks would not.
+    (PERF.md section 6, PR 42), so a tile of 32 blocks of 16 KiB a plane is
+    bound by its 32 copies, and by its bytes only as 1. No whole copy reads
+    a block the walk by blocks would not.
     The pipeline's order, the semaphores and both arithmetics are the K+V
     build's own.
 
@@ -374,12 +382,13 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     denominator, what one combine of the two halves needs
     (``nn.eva_attn_with_cache``).
 
-    Builds, by ``n_arenas``: 2 — K and V ``(..., Hkv, dh)``. 4 — a
-    QUANTIZED pool (int8/fp8 wire dtype): the per-row f32 scale arenas
-    ``(..., Hkv)`` ride the same pipeline and dequant happens HERE, right
+    Builds: the K+V build is ONE arena of paired planes ``(..., 2, bs, Hkv,
+    dh)``. ``n_arenas == 2`` — a QUANTIZED pool (int8/fp8 wire dtype): the
+    per-row f32 scale arena ``(..., 2, bs, Hkv)`` rides the same pipeline
+    (a second copy a block) and dequant happens HERE, right
     after staging — the wire cast to f32 times the staged scale column —
     so HBM only ever moves wire bytes while the arithmetic is the per-head
-    float32 one. 1 — a LATENT pool (``v_dim`` given): ONE arena ``(..., W)``
+    float32 one. ``v_dim`` given — a LATENT pool: ONE arena ``(..., bs, W)``
     of rows shared by every query head (absorbed latent attention is
     multi-query attention with one key head); each block is copied ONCE and
     used twice: the whole staged row is the key, its first ``v_dim``
@@ -399,14 +408,15 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         rest = rest[1:-1]
     stages = rest[:n_arenas]
     acc_ref, m_ref, l_ref, sems, walk_ref = rest[n_arenas:]
-    quant = n_arenas == 4
+    quant = n_arenas == 2
+    kv = stages[0]      # (slot, plane, row, head, dh); latent: (slot, row, W)
 
     b = pl.program_id(0)
     qt = pl.program_id(1)
     layer = layer_ref[0]
     span = tile_blocks * bs
     if window is not None:
-        ring = arenas[0].shape[2] // bs     # blocks of a slot's ring
+        ring = arenas[0].shape[3] // bs     # blocks of a slot's ring
 
     def frontier(b, qt):
         """(kv_len, q_len, fetch ceiling, live kv tiles) of a grid step.
@@ -471,23 +481,26 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
     def block_copies(b, tile, slot, i, n=1):
         """Blocks ``i .. i + n`` of slot ``b``'s kv tile ``tile``: one
         (source, staging rows, semaphore) copy an arena into staging slot
-        ``slot``. A paged pool scatters its blocks, so there ``n`` is 1; a
-        ring's lie side by side and ``n`` of them are one copy. ``b`` None
+        ``slot``, BOTH planes in it. A paged pool scatters its blocks, so
+        there ``n`` is 1 and the source is the block as it lies, planes
+        side by side; a ring's blocks lie side by side a plane and ``n`` of
+        them are one copy of two chunks. ``b`` None
         builds a copy to WAIT for: that needs its size and semaphore, not
         its source, so it reads no table entry."""
         rows = pl.ds(i * bs, n * bs)
+        dst = (slot, rows) if latent else (slot, slice(None), rows)
         if window is None:
             # Same defensive clamp as the gather path's mode="clip".
             src = (layer, 0 if b is None else jnp.clip(
                 tbl_ref[b, tile * tile_blocks + i], 0, n_blocks - 1))
         else:
-            # Ring storage: the row's slot, then the lines of the logical
-            # block's place in the slot's ring.
-            src = (layer, 0, rows) if b is None else (
-                layer, jnp.clip(tbl_ref[b, 0], 0, n_blocks - 1),
+            # Ring storage: the row's slot, then (a plane) the lines of the
+            # logical block's place in the slot's ring.
+            src = (layer, 0, slice(None), rows) if b is None else (
+                layer, jnp.clip(tbl_ref[b, 0], 0, n_blocks - 1), slice(None),
                 pl.ds(jax.lax.rem(tile * tile_blocks + i, ring) * bs,
                       n * bs))
-        return [(arena.at[src], stage.at[slot, rows], sems.at[slot, a])
+        return [(arena.at[src], stage.at[dst], sems.at[slot, a])
                 for a, (arena, stage) in enumerate(zip(arenas, stages))]
 
     def for_live_blocks(tile, limit, lo, fn):
@@ -509,7 +522,7 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                     fn(i)
         else:
             # A whole tile whose blocks do not wrap the ring is ONE run of
-            # lines in HBM: one copy an arena. One that wraps goes block by
+            # lines a plane in HBM: one copy. One that wraps goes block by
             # block with the ragged ones.
             whole &= tile * span + bs > lo
             whole &= (jax.lax.rem(tile * tile_blocks, ring) + tile_blocks
@@ -585,32 +598,31 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         # (twice the kernel's code, 2.2 x the time at n_kv 4). The
         # interpreter has no view of a ref, nor has the analyzer's tracer:
         # they reshape the value, the same rows.
-        if compiled and stages[0].dtype.itemsize == 2 and n_kv % 2 == 0:
-            words = [st.bitcast(jnp.uint32).reshape(2, width // 2, dh)
-                     for st in stages]
+        if compiled and kv.dtype.itemsize == 2 and n_kv % 2 == 0:
+            words = kv.bitcast(jnp.uint32).reshape(2, 2, width // 2, dh)
 
-            def rows_of(a):
-                return pltpu.bitcast(words[a][slot], stages[a].dtype)
+            def rows_of(plane):
+                return pltpu.bitcast(words[slot, plane], kv.dtype)
 
             def scrub_v():
                 r = jax.lax.broadcasted_iota(jnp.int32, (width // 2, dh), 0)
-                w = words[1][slot]
+                w = words[slot, 1]
                 keep = r < (limit - base) * (n_kv // 2)
                 if window is not None:
                     keep &= r >= (lo - base) * (n_kv // 2)
-                words[1][slot] = jnp.where(keep, w, jnp.zeros_like(w))
+                words[slot, 1] = jnp.where(keep, w, jnp.zeros_like(w))
         else:
-            def rows_of(a):
-                return stages[a][slot].reshape(width, dh)
+            def rows_of(plane):
+                return kv[slot, plane].reshape(width, dh)
 
             def scrub_v():
                 row_pos = base + jax.lax.broadcasted_iota(
                     jnp.int32, (span, 1, 1), 0)
-                v = stages[1][slot]
+                v = kv[slot, 1]
                 keep = row_pos < limit
                 if window is not None:
                     keep &= row_pos >= lo
-                stages[1][slot] = jnp.where(keep, v, jnp.zeros_like(v))
+                kv[slot, 1] = jnp.where(keep, v, jnp.zeros_like(v))
 
         # Rows past the causal frontier hold whatever the pool or a skipped
         # fetch left there, and ``0 * NaN`` is NaN in the PV dot. They exist
@@ -674,8 +686,8 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
         for h in range(n_kv):
             if latent:
                 q = q_ref[0, 0]                              # (q_tile*g, W)
-                k = stages[0][slot]                          # (T*bs, W)
-                v = stages[0][slot, :, :v_dim]
+                k = kv[slot]                                 # (T*bs, W)
+                v = kv[slot, :, :v_dim]
             else:
                 # A head's rows are a sublane-strided slice of the staging;
                 # the f32 casts are deliberate — see _flash_decode_kernel:
@@ -683,16 +695,16 @@ def _paged_attn_kernel(*refs, n_arenas: int, n_tiles: int, tile_blocks: int,
                 # measured slower. (The decode shape takes compute_folded
                 # and casts nothing.)
                 q = q_ref[0, h].astype(jnp.float32)          # (q_tile*g, dh)
-                k = stages[0][slot, :, h, :].astype(jnp.float32)  # (T*bs, dh)
-                v = stages[1][slot, :, h, :].astype(jnp.float32)
+                k = kv[slot, 0, :, h, :].astype(jnp.float32)  # (T*bs, dh)
+                v = kv[slot, 1, :, h, :].astype(jnp.float32)
             if quant:
                 # In-staging dequant: one f32 scale per staged (row, kv
                 # head), broadcast over head_dim. Stale (unfetched) rows'
                 # garbage products are scrubbed exactly like the
                 # unquantized build: K by the score-side causal mask, V by
                 # the row_live select below.
-                k = k * stages[2][slot, :, h:h + 1]
-                v = v * stages[3][slot, :, h:h + 1]
+                k = k * stages[1][slot, 0, :, h:h + 1]
+                v = v * stages[1][slot, 1, :, h:h + 1]
             # where, not multiply: 0 * NaN is still NaN.
             v = jnp.where(row_live, v, jnp.zeros_like(v))
             accumulate(h, q, k, v, valid, guard=True)
@@ -763,6 +775,24 @@ def tile_arithmetic(n_kv_heads: int, q_tile: int, *, latent: bool = False,
     return "folded"
 
 
+def copy_size(block_size: int, n_kv_heads: int, head_dim: int,
+              itemsize: int, tile_blocks: int, *, latent: bool = False,
+              kv_scales: bool = False) -> dict:
+    """The size of the walk's fetch, what the comm ledger's ``paged_attn``
+    series carries: ``copy_bytes``, the bytes ONE DMA of the row arena
+    moves (a block's K plane and V plane together, ``2 * block_size * Hkv *
+    dh`` items; a latent block's rows once), and ``copies_per_tile``, the
+    copies a whole kv tile of ``tile_blocks`` blocks starts and waits for
+    (one a block; a quantized pool's scale planes are one more each). A
+    copy costs 35-37 ns whatever it carries: under about 25-32 KiB a copy
+    a tile is bound by this count, above by its bytes (PERF.md section 6,
+    PR 42). A window layer's whole tile is one copy whatever this says."""
+    planes = 1 if latent else 2
+    return {"copy_bytes": planes * block_size * n_kv_heads * head_dim
+            * itemsize,
+            "copies_per_tile": tile_blocks * (2 if kv_scales else 1)}
+
+
 def paged_attn_cost(B: int, max_blocks: int, block_size: int,
                     n_kv_heads: int, head_dim: int, *, n_q_heads: int,
                     itemsize: int = 2, L: int = 1,
@@ -775,7 +805,8 @@ def paged_attn_cost(B: int, max_blocks: int, block_size: int,
     estimate, the comm-ledger series, and the bench byte-ratio gate are one
     arithmetic. ``kv_itemsize``/``kv_scales``: quantized-pool wire bytes
     (+ per-row scale reads) — the FLOPs are unchanged because dequant
-    rides the same f32 pipeline."""
+    rides the same f32 pipeline. The bytes come in copies of
+    ``copy_size(...)["copy_bytes"]``, ``copies_per_tile`` a kv tile."""
     from triton_distributed_tpu.runtime import perf_model as _pm
 
     return common.cost_estimate(
@@ -787,11 +818,11 @@ def paged_attn_cost(B: int, max_blocks: int, block_size: int,
             L=L, q_tile=q_tile))
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
+def paged_attention(q, pool, block_tables, kv_lens, *,
                     q_lens=None, slot_mask=None, scale: float | None = None,
                     tile_blocks: int | None = None,
                     q_tile: int | None = None, interpret=None,
-                    probes: bool = False, k_scale=None, v_scale=None,
+                    probes: bool = False, scales=None,
                     layer=None, v_dim: int | None = None,
                     resolved: dict | None = None,
                     window: int | None = None, aligned: bool = False,
@@ -801,10 +832,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     steps all through ONE kernel.
 
     WINDOW form (``window`` given): a query at position ``p`` sees the keys
-    ``p - window < j <= p``. ``k_pool`` / ``v_pool`` are a window layer's
-    ring storage ``(n_layers, n_slots, ring_blocks, block_size, Hkv, dh)``
-    (``serving.kv_pool``: token ``p`` of the sequence in slot ``s`` lies in
-    ring block ``(p // block_size) % ring_blocks`` of ``s``) with ``layer``,
+    ``p - window < j <= p``. ``pool`` is a window layer's
+    ring storage ``(n_layers, n_slots, 2, ring_blocks, block_size, Hkv,
+    dh)`` (``serving.kv_pool``: token ``p`` of the sequence in slot ``s``
+    lies in ring block ``(p // block_size) % ring_blocks`` of ``s``, its key
+    in plane 0 and its value in plane 1) with ``layer``,
     and ``block_tables`` is ``(B, 1)`` int32: the SLOT each row reads. The
     walk starts at the tile that holds the oldest visible key; what lies
     behind the window costs no copy. The call is named
@@ -813,8 +845,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     ``(p // window) * window <= j <= p`` (an EVA layer's exact set; the call
     is then named ``eva_attn_window``).
 
-    SUMMARY form (``summary=(window, rows)``, the K+V build over stacked
-    block arenas whose row ``c`` stands for chunk ``c`` of ``window // rows``
+    SUMMARY form (``summary=(window, rows)``, the K+V build over a stacked
+    block arena whose row ``c`` stands for chunk ``c`` of ``window // rows``
     positions): a query at ``p`` sees the rows ``c < rows * (p // window)``;
     ``kv_lens`` / ``q_lens`` stay TOKEN positions. Named
     ``eva_attn_summary``. ``stats=True`` (either EVA half): returns ``(out,
@@ -822,7 +854,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     (``-1e30`` and 0 for a row that saw no key), for the caller's one
     combine. No quantized, latent or probed build of either.
 
-    LATENT form (``v_pool=None`` with ``v_dim``): ``k_pool`` is the one
+    LATENT form (``v_dim`` given): ``pool`` is the one
     latent arena ``(n_blocks, block_size, W)`` — stacked ``(n_layers,
     n_blocks, block_size, W)`` with ``layer`` — whose rows every query head
     shares: the keys are the whole rows, the values their first ``v_dim``
@@ -834,15 +866,16 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     q:            (B, L, Hq, dh) new (rope'd) query rows per slot; the new
                   tokens' K/V are already in the pool
                   (``nn.paged_cache_update`` runs first).
-    k/v_pool:     (n_blocks, block_size, Hkv, dh) — ONE layer of this
+    pool:         (n_blocks, 2, block_size, Hkv, dh) — ONE layer of this
                   device's kv-head shard of ``serving.kv_pool.PagedKVState``
-                  — or the whole stacked arena (n_layers, n_blocks,
-                  block_size, Hkv, dh) with ``layer`` naming the layer to
-                  read. One kernel either way: the 4-D form is viewed as a
+                  ``.kv``, a block's K plane then its V plane — or the
+                  whole stacked arena (n_layers, n_blocks, 2, block_size,
+                  Hkv, dh) with ``layer`` naming the layer to
+                  read. One kernel either way: the 5-D form is viewed as a
                   one-layer arena (a free reshape) and read at layer 0.
     layer:        () int32 (traced or static) — which layer of a stacked
-                  arena this call attends over; required with 5-D pools,
-                  refused with 4-D ones. The model's layer scan carries
+                  arena this call attends over; required with 6-D pools,
+                  refused with 5-D ones. The model's layer scan carries
                   the arenas whole and passes its layer index here, so no
                   per-layer slice of the pool is ever materialized.
     block_tables: (B, max_blocks) int32 — slot b's sequence occupies blocks
@@ -866,11 +899,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     tile_blocks / q_tile: pool blocks staged per turn of the in-kernel
                   walk, and query tokens per grid step (None = autotuned /
                   heuristic, ``tuned_paged_tile``).
-    k/v_scale:    (n_blocks, block_size, Hkv) f32 — stacked like the pools
-                  when they are — or None: per-row dequant
+    scales:       (n_blocks, 2, block_size, Hkv) f32 — stacked like the
+                  pool when it is — or None: per-row dequant
                   scales of a QUANTIZED pool (int8/fp8 wire dtype, written
                   by ``nn.paged_cache_update``'s quantizing append). Given,
-                  each staged block's scale rows DMA with it and the kernel
+                  each staged block's scale planes DMA with it and the kernel
                   dequantizes in VMEM before the per-head float32
                   arithmetic — storage precision is the ONLY thing that
                   changes against a float32 pool.
@@ -882,7 +915,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                   record ordinals are deterministic.
     resolved:     a dict to fill, or None: what this call chose statically
                   from its operands — ``tile_blocks``, ``q_tile`` and the
-                  ``arithmetic`` of a staged tile (``tile_arithmetic``) —
+                  ``arithmetic`` of a staged tile (``tile_arithmetic``),
+                  and the size of the fetch (``copy_size``) —
                   for a caller that keeps a record of it.
 
     Returns (B, L, Hq, dh) in q.dtype: streaming softmax over the same
@@ -895,17 +929,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     are held in tests/test_paged_attention.py.
     """
     B, L, Hq, dh = q.shape
-    quant = k_scale is not None
-    if quant != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be given together")
-    latent = v_pool is None
-    if latent != (v_dim is not None):
-        raise ValueError("v_dim goes with a latent pool (v_pool=None) and "
-                         "only with it")
+    quant = scales is not None
+    latent = v_dim is not None
     if (aligned and window is None) or (summary is not None and (
-            window is not None or k_pool.ndim != 5)):
+            window is not None or pool.ndim != 6)):
         raise ValueError("aligned goes with a window over ring storage, "
-                         "summary with stacked block arenas and no window")
+                         "summary with a stacked block arena and no window")
     if (summary is not None or stats) and (latent or quant or probes):
         raise NotImplementedError(
             "the summary build and the returned maximum and denominator are "
@@ -914,51 +943,55 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     if window is not None:
         if latent or quant or probes:
             raise NotImplementedError(
-                "the window build walks K and V arenas in the model dtype: "
+                "the window build walks a K+V ring in the model dtype: "
                 "no latent, quantized or probed build")
-        if k_pool.ndim != 6 or layer is None or window < 1:
+        if pool.ndim != 7 or layer is None or window < 1:
             raise ValueError(
-                "the window build reads ring storage (n_layers, n_slots, "
+                "the window build reads ring storage (n_layers, n_slots, 2, "
                 "ring_blocks, block_size, Hkv, dh) at a layer, over a "
                 "window of at least one key")
         block_tables = block_tables.reshape(B, 1)
-        _, n_blocks, ring, bs, Hkv, _ = k_pool.shape   # n_blocks: slots
-        # The kernel reads a slot's ring as its LINES, (layers, slots, ring
-        # blocks * bs, Hkv, dh) (the same bytes): blocks side by side are
-        # one run of lines, which is what one copy can take.
-        k_pool, v_pool = (a.reshape(*a.shape[:2], ring * bs, *a.shape[4:])
-                          for a in (k_pool, v_pool))
+        _, n_blocks, _, ring, bs, Hkv, _ = pool.shape   # n_blocks: slots
+        # The kernel reads a slot's ring as its LINES, (layers, slots, 2,
+        # ring blocks * bs, Hkv, dh) (the same bytes): blocks side by side
+        # are one run of lines a plane, which is what one copy can take.
+        pool = pool.reshape(*pool.shape[:3], ring * bs, *pool.shape[5:])
     elif latent:
         if quant:
             raise NotImplementedError("the latent pool has no quantized "
                                       "build")
         # One key head, shared by every query head; the arena keeps its
         # rank (a unit head axis would change its tiled layout).
-        if k_pool.ndim == 3:
+        if pool.ndim == 3:
             if layer is not None:
                 raise ValueError("layer indexes a stacked arena; this "
                                  "latent pool is one layer")
-            layer, k_pool = 0, k_pool[None]
+            layer, pool = 0, pool[None]
         elif layer is None:
             raise ValueError("a stacked latent arena needs the layer to "
                              "read")
-        _, n_blocks, bs, _ = k_pool.shape
+        _, n_blocks, bs, _ = pool.shape
         Hkv = 1
-    elif k_pool.ndim == 4:
+    elif pool.ndim == 5:
         if layer is not None:
             raise ValueError("layer indexes a stacked (n_layers, n_blocks, "
                              "...) arena; this pool is one layer")
         layer = 0
-        k_pool, v_pool = k_pool[None], v_pool[None]
+        pool = pool[None]
         if quant:
-            k_scale, v_scale = k_scale[None], v_scale[None]
+            scales = scales[None]
     elif layer is None:
         raise ValueError("a stacked (n_layers, n_blocks, ...) arena needs "
                          "the layer to read")
     if not latent and window is None:
-        _, n_blocks, bs, Hkv, _ = k_pool.shape
-    if k_pool.shape[-1] != dh:
-        raise ValueError(f"pool rows are {k_pool.shape[-1]} wide, queries "
+        if pool.ndim != 6 or pool.shape[2] != 2:
+            raise ValueError(
+                f"a K+V pool keeps a block's K plane and V plane side by "
+                f"side, (n_blocks, 2, block_size, Hkv, dh) a layer: got "
+                f"{pool.shape}")
+        _, n_blocks, _, bs, Hkv, _ = pool.shape
+    if pool.shape[-1] != dh:
+        raise ValueError(f"pool rows are {pool.shape[-1]} wide, queries "
                          f"{dh}")
     if Hq % Hkv:
         raise ValueError(f"q heads {Hq} not divisible by kv heads {Hkv}")
@@ -975,12 +1008,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     g = Hq // Hkv
     scale = dh ** -0.5 if scale is None else scale
     if quant:
-        if k_scale.shape != k_pool.shape[:4]:
+        if scales.shape != pool.shape[:-1]:
             raise ValueError(
-                f"k_scale shape {k_scale.shape} != pool rows "
-                f"{k_pool.shape[:4]}")
-        if k_scale.dtype != jnp.float32:
-            raise TypeError(f"scales must be f32, got {k_scale.dtype}")
+                f"scales shape {scales.shape} != pool rows "
+                f"{pool.shape[:-1]}")
+        if scales.dtype != jnp.float32:
+            raise TypeError(f"scales must be f32, got {scales.dtype}")
     if slot_mask is not None:
         block_tables = jnp.where(slot_mask[:, None], block_tables, 0)
     kv_lens = jnp.broadcast_to(
@@ -994,7 +1027,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     interpret = resolve_interpret(interpret)
     if tile_blocks is None or q_tile is None:
         t_cfg, qt_cfg = tuned_paged_tile(bs, Hkv, dh, max_blocks,
-                                         str(k_pool.dtype), L=L, g=g)
+                                         str(pool.dtype), L=L, g=g)
         tile_blocks = t_cfg if tile_blocks is None else tile_blocks
         q_tile = qt_cfg if q_tile is None else q_tile
     tile_blocks = max(1, min(int(tile_blocks), max_blocks))
@@ -1017,7 +1050,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     folded = arithmetic == "folded"
     if resolved is not None:
         resolved.update(tile_blocks=tile_blocks, q_tile=q_tile,
-                        arithmetic=arithmetic, window=window)
+                        arithmetic=arithmetic, window=window,
+                        **copy_size(bs, Hkv, dh, pool.dtype.itemsize,
+                                    tile_blocks, latent=latent,
+                                    kv_scales=quant))
     if folded:
         # One token a grid step, every query head in one operand: q as it
         # arrives, (B, L, Hq, dh) with head h * g + j in kv head h's group,
@@ -1041,9 +1077,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         def q_index(b, qt, tbl, kl, ql, ly):
             return (b, 0, qt, 0)
 
-    arenas = (k_pool,) if latent else (k_pool, v_pool)
-    if quant:
-        arenas += (k_scale, v_scale)
+    arenas = (pool, scales) if quant else (pool,)
+    planes = () if latent else (2,)       # of a block, and of a staging slot
     n_steps = B * n_q_tiles * n_tiles     # probe rows: (slot, q-tile, kv-tile)
     kernel = functools.partial(
         _paged_attn_kernel, n_arenas=len(arenas), n_tiles=n_tiles,
@@ -1056,9 +1091,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     out_specs = pl.BlockSpec((1, heads, rows, dv), q_index)
     out_shape = jax.ShapeDtypeStruct((*qh.shape[:3], dv), jnp.float32)
     scratch_shapes = [
-        # Staging, two slots an arena: tile j + 1 lands in one while tile j
-        # is computed from the other.
-        *(pltpu.VMEM((2, tile_blocks * bs, *a.shape[3:]), a.dtype)
+        # Staging, two slots an arena (the K and the V plane in each): tile
+        # j + 1 lands in one while tile j is computed from the other.
+        *(pltpu.VMEM((2, *planes, tile_blocks * bs,
+                      *a.shape[3 + len(planes):]), a.dtype)
           for a in arenas),
         pltpu.VMEM((heads, rows, dv), jnp.float32),  # acc
         pltpu.VMEM((heads, rows, 1), jnp.float32),   # running max
@@ -1102,8 +1138,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
         cost_estimate=paged_attn_cost(
             B, max_blocks, bs, Hkv, dh, n_q_heads=Hq,
             itemsize=(q.dtype.itemsize if quant
-                      else k_pool.dtype.itemsize),
-            kv_itemsize=k_pool.dtype.itemsize, kv_scales=quant,
+                      else pool.dtype.itemsize),
+            kv_itemsize=pool.dtype.itemsize, kv_scales=quant,
             L=L, q_tile=q_tile),
         interpret=interpret,
         # What the device trace calls the kernel's events.
@@ -1131,7 +1167,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     return o
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
+def paged_decode_attention(q, pool, block_tables, kv_lens, *,
                            slot_mask=None, scale: float | None = None,
                            tile_blocks: int | None = None, interpret=None,
                            probes: bool = False):
@@ -1144,8 +1180,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
     with L = 1 (one query tile, causal mask degenerate to
     ``pos < kv_len``)."""
     B, Hq, dh = q.shape
-    out = paged_attention(q[:, None], k_pool, v_pool, block_tables,
-                          kv_lens, slot_mask=slot_mask, scale=scale,
+    out = paged_attention(q[:, None], pool, block_tables, kv_lens,
+                          slot_mask=slot_mask, scale=scale,
                           tile_blocks=tile_blocks, q_tile=1,
                           interpret=interpret, probes=probes)
     if probes:
@@ -1213,12 +1249,14 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
     # analyzer sees.
     if latent:
         n_kv = 1
-        arenas = [("kp", (dh,), dt)]
+        arenas = [("kvp", (dh,), dt)]
     else:
-        arenas = [("kp", (n_kv, dh), dt), ("vp", (n_kv, dh), dt)]
+        arenas = [("kvp", (n_kv, dh), dt)]
     if kvq:
-        arenas += [("ksp", (n_kv,), _np.float32),
-                   ("vsp", (n_kv,), _np.float32)]
+        arenas += [("ksp", (n_kv,), _np.float32)]
+    # a block's (or, in a ring, a slot's) planes: K then V; a latent row
+    # is both and has none
+    planes = () if latent else (2,)
     # The q/o blocks and the accumulators, as the wrapper lays them out for
     # the arithmetic this shape takes.
     folded = tile_arithmetic(n_kv, q_tile, latent=latent,
@@ -1235,9 +1273,9 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
 
     # The window build: ring storage (layers, slots, ring blocks, ...) read
     # by slot, the table one slot id a row; ``max_blocks`` is the ring.
-    pool = (2, n_blocks, bs)
+    pool = (2, n_blocks, *planes, bs)
     if window is not None:
-        pool, tbl_w, n_blocks = (2, B, max_blocks * bs), 1, B
+        pool, tbl_w, n_blocks = (2, B, 2, max_blocks * bs), 1, B
         n_tiles = 1 << 20
 
         def tables(r, w):                                   # noqa: F811
@@ -1269,7 +1307,8 @@ def _paged_spec(world: int, *, tile_blocks: int = 2, bs: int = 16,
             _comm.Buf("o", (*qo, v_dim or dh), _np.float32, space="vmem",
                       covered=True),
             # Staging: two slots an arena, as the kernel allocates them.
-            *(_comm.Buf(f"{name}_stage", (2, tile_blocks * bs, *row), adt,
+            *(_comm.Buf(f"{name}_stage",
+                        (2, *planes, tile_blocks * bs, *row), adt,
                         space="vmem")
               for name, row, adt in arenas),
             _comm.Buf("acc", (heads, rows, v_dim or dh), _np.float32,
@@ -1294,9 +1333,9 @@ _comm.register("paged.decode")(_paged_spec)
 def _paged_spec_kvq(world: int, *, dtype: str = "int8",
                     **kw) -> "_comm.TraceSpec":
     """The QUANTIZED pool decode shape: int8 (or fp8) wire-dtype K/V
-    arenas plus per-row f32 scale pools and their VMEM staging pair —
-    proving the dequant-in-staging choreography (two extra DMAs on
-    semaphores 2/3 per staged block) and the shrunken wire footprint the
+    arena plus the per-row f32 scale arena and its VMEM staging —
+    proving the dequant-in-staging choreography (one extra DMA on
+    semaphore 1 per staged block) and the shrunken wire footprint the
     autotuner's bigger quantized tiles rely on."""
     return _paged_spec(world, dtype=dtype, kvq=True, **kw)
 
@@ -1340,7 +1379,7 @@ def _paged_spec_window(world: int, *, window: int = 24, bs: int = 8,
     window's first tile; ``max_blocks`` is the ring's blocks), decode shape:
     contexts of the whole ring, so the first tile lies behind the window
     and is neither copied nor waited for, the second is ragged at its start
-    (a copy a live block) and the third whole: ONE copy an arena.
+    (a copy a live block) and the third whole: ONE copy, both planes.
     ``kv_len`` (one, or one a slot) takes the contexts round the ring."""
     return _paged_spec(world, window=window, bs=bs, tile_blocks=tile_blocks,
                        max_blocks=max_blocks, **kw)

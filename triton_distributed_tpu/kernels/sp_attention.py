@@ -801,12 +801,14 @@ def flash_decode_local(q, k_cache, v_cache, *, kv_len=None,
 # ---------------------------------------------------------------------------
 
 
-def paged_gather_kv(pool, block_tables, *, slot_mask=None):
+def paged_gather_kv(pool, block_tables, *, slot_mask=None, plane=None):
     """Gather one layer's block-paged KV pool into the contiguous per-slot
     layout the attention paths consume (vLLM-style PagedAttention read).
 
     pool: (n_blocks, block_size, Hkv, dh) — this device's kv-head shard of
-    one layer of ``serving.kv_pool.PagedKVState``. block_tables:
+    one PLANE (the keys or the values) of one layer of
+    ``serving.kv_pool.PagedKVState.kv``, or a latent pool's layer (n_blocks,
+    block_size, row). block_tables:
     (B, max_blocks) int32 — slot b's sequence occupies blocks
     ``block_tables[b, :ceil(len/block_size)]`` in order; tail entries are
     allocator padding. Returns (B, max_blocks * block_size, Hkv, dh) — slot
@@ -819,6 +821,11 @@ def paged_gather_kv(pool, block_tables, *, slot_mask=None):
     to other sequences — masked-out garbage either way (attention masks
     positions >= the slot offset), but the mask keeps a dead slot from
     touching live sequences' blocks at all.
+
+    ``plane`` (0 the keys, 1 the values): ``pool`` is one layer of the K+V
+    arena as it lies, (n_blocks, 2, block_size, Hkv, dh), and the view is of
+    that plane alone: one gather of the plane's blocks, no slice of the
+    layer first.
 
     This is now the REFERENCE path only: every step shape — decode,
     chunked prefill, ragged mixed — routes through the fused in-kernel
@@ -839,8 +846,10 @@ def paged_gather_kv(pool, block_tables, *, slot_mask=None):
         block_tables = jnp.where(slot_mask[:, None], block_tables, 0)
     # mode="clip" makes the OOB policy explicit (jnp.take's default today,
     # but the correctness of padded/stale table entries rests on it).
-    g = jnp.take(pool, block_tables.reshape(-1), axis=0, mode="clip")
-    return g.reshape(B, nb * pool.shape[1], *pool.shape[2:])
+    ids = block_tables.reshape(-1)
+    g = (jnp.take(pool, ids, axis=0, mode="clip") if plane is None
+         else pool.at[ids, plane].get(mode="clip"))
+    return g.reshape(B, nb * g.shape[1], *g.shape[2:])
 
 
 def decode_partial_feat(dh: int) -> int:

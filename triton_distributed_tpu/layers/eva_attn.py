@@ -25,11 +25,11 @@ further rotation.
 The layer keeps TWO kinds of cache in the pool's state
 (``serving.kv_pool``), both at ``layer`` of arenas as deep as the model:
 
-- the window's rows in the RING a slot (``state.wk`` / ``state.wv``), as a
+- the window's rows in the RING a slot (``state.wkv``), as a
   sliding-window layer does (``layers.tp_attn``): ``window - 1`` rows and a
   step's largest take;
-- one K row and one V row A CHUNK in the block arenas (``state.k`` /
-  ``state.v``), through the slot's block table: row ``c`` of a sequence is
+- one K row and one V row A CHUNK in the block arena (the two planes of
+  ``state.kv``), through the slot's block table: row ``c`` of a sequence is
   chunk ``c``'s summary, so a block of ``block_size`` rows stands for
   ``block_size * chunk`` positions (``config.kv_row_tokens``).
 
@@ -94,36 +94,32 @@ class EvaAttn:
         """x the flat token batch (T, d) -> ``((T, d), state)``: local
         products and no collective. ``blocks`` as ``TPAttn._attend`` takes
         them; ``layer`` () int32 indexes the ring and the row arenas."""
-        if state.wk is None or state.v is None:
+        if state.wkv is None or state.latent:
             raise ValueError(
                 "the pool's state lacks the ring or the row arenas an EVA "
                 "layer keeps: build the pool from this model's "
                 "configuration (KVPool(config, ..., n_slots=...))")
         scale = self.head_dim ** -0.5
         qkv = jnp.dot(x, params["w_qkv"])
-        slots = ring_slots(blocks, state.wk, self.window)
+        slots = ring_slots(blocks, state.wkv, self.window)
         queries = []
         for blk, at in zip(blocks, slots):
             part = qkv[blk.start:blk.stop].reshape(-1, blk.L, qkv.shape[-1])
             q, k, v = self._core._qkv_rope(params, part, blk.offsets, 1)
             queries.append(q)
             wm = blk.valid().reshape(-1, blk.L)
-            state = dataclasses.replace(
-                state,
-                wk=nn.window_cache_update(state.wk, k, at, blk.offsets, wm,
-                                          layer),
-                wv=nn.window_cache_update(state.wv, v, at, blk.offsets, wm,
-                                          layer))
+            state = dataclasses.replace(state, wkv=nn.window_cache_update(
+                state.wkv, jnp.stack([k, v], axis=2), at, blk.offsets, wm,
+                layer))
         # every append of the step lies in the ring: the chunks it closed
         for blk, at in zip(blocks, slots):
-            k_sum, v_sum = nn.eva_summary_update(
-                state.k, state.v, state.wk, state.wv, params["mu"],
+            state = dataclasses.replace(state, kv=nn.eva_summary_update(
+                state.kv, state.wkv, params["mu"],
                 params["phi"], at, blk.tables, blk.offsets,
                 jnp.sum(blk.valid().reshape(-1, blk.L), axis=1), layer,
-                chunk=self.chunk, scale=scale, max_len=blk.L)
-            state = dataclasses.replace(state, k=k_sum, v=v_sum)
+                chunk=self.chunk, scale=scale, max_len=blk.L))
         outs = [nn.eva_attn_with_cache(
-            q, state.wk, state.wv, state.k, state.v, at, blk.tables,
+            q, state.wkv, state.kv, at, blk.tables,
             blk.offsets, window=self.window, chunk=self.chunk, layer=layer,
             scale=scale, slot_mask=blk.mask, seq_lens=blk.seq_lens,
             interpret=interpret, paged_attn=paged_attn).reshape(

@@ -99,7 +99,7 @@ class MLAttn:
                            params["w_kvb_k"],
                            preferred_element_type=f32).astype(x.dtype)
 
-        pool, queries = state.k, []
+        pool, queries = state.kv, []
         for blk in blocks:
             span, rows = slice(blk.start, blk.stop), blk.offsets.shape[0]
             positions = blk.offsets[:, None] + jnp.arange(blk.L)
@@ -130,4 +130,4 @@ class MLAttn:
                        params["w_kvb_v"],
                        preferred_element_type=f32).astype(x.dtype)
         return (dot(o.reshape(T, H * self.v_dim), params["w_o"]),
-                dataclasses.replace(state, k=pool))
+                dataclasses.replace(state, kv=pool))

@@ -14,6 +14,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _NEG_INF = float("-inf")
 
@@ -400,9 +401,10 @@ def _fused_paged_attention(arrays: dict, **static):
     the mixed step's decode block are one shape. Replaying binds the same
     equations (one ``pallas_call``), so the compiled programs are what the
     direct call gives. ``arrays`` holds the operands that are not None.
-    Beside each shape's trace the record keeps the ARITHMETIC its staged
-    tiles take (``folded`` / ``per_head``), a choice that is static a call
-    site: ``fused_paged_arithmetic`` reads it back."""
+    Beside each shape's trace the record keeps what the call chose
+    statically (``paged_attention``'s ``resolved``): the ARITHMETIC its
+    staged tiles take (``folded`` / ``per_head``), which
+    ``fused_paged_arithmetic`` reads back, and the size of its fetch."""
     from triton_distributed_tpu.kernels.paged_attention import (
         paged_attention,
     )
@@ -416,7 +418,7 @@ def _fused_paged_attention(arrays: dict, **static):
         closed = jax.make_jaxpr(
             lambda *a: paged_attention(**dict(zip(names, a)), **static,
                                        resolved=resolved))(*flat)
-        _FUSED_TRACES[key] = closed, resolved["arithmetic"]
+        _FUSED_TRACES[key] = closed, resolved
     closed, _ = _FUSED_TRACES[key]
     out = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat)
     # (one result, or an EVA half's three: ``stats=True``)
@@ -429,19 +431,30 @@ def fused_paged_arithmetic() -> dict:
     process's record, written when a shape is first traced. A call of the
     WINDOW build is named by it: ``"q<shape>:<pool dtype>:window<n>"``."""
     out = {}
-    for (names, avals, static), (_, arithmetic) in _FUSED_TRACES.items():
+    for (names, avals, static), (_, resolved) in _FUSED_TRACES.items():
         by_name = dict(zip(names, avals))
         q_shape = "x".join(str(d) for d in by_name["q"][0])
         static = dict(static)
         window = static.get("window")
-        out[f"q{q_shape}:{by_name['k_pool'][1].name}"
+        out[f"q{q_shape}:{by_name['pool'][1].name}"
             + (f":window{window}" if window else "")
             + (":aligned" if static.get("aligned") else "")
-            + (":summary" if static.get("summary") else "")] = arithmetic
+            + (":summary" if static.get("summary") else "")
+            ] = resolved["arithmetic"]
     return out
 
 
-def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
+def gathered_planes(pool, block_tables, slot_mask=None):
+    """The gather oracle's read of one layer of a K+V arena ``(n_blocks, 2,
+    block_size, ...)`` through ``paged_gather_kv``, -> the (K view, V view)
+    pair, each ``(B, max_blocks * block_size, ...)``."""
+    from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
+
+    return tuple(paged_gather_kv(pool, block_tables, slot_mask=slot_mask,
+                                 plane=plane) for plane in (0, 1))
+
+
+def paged_attn_with_cache(q, pool, block_tables, offset, *,
                           scale: float, slot_mask=None,
                           use_flash_decode: bool = True, seq_lens=None,
                           interpret=None, paged_attn: str = "fused",
@@ -464,19 +477,20 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
     q:            (B, L, Hq, dh) new queries (rope'd); the new tokens' K/V
                   are already in the pool (``paged_cache_update`` runs
                   first).
-    k/v_pool:     (n_blocks, block_size, Hkv, dh) one layer of the pool,
-                  or — with ``layer`` () int32 — the stacked
-                  (n_layers, n_blocks, block_size, Hkv, dh) arena the
-                  model's layer scan carries whole: the fused kernel DMAs
-                  ``[layer, block]`` straight out of it, the gather oracle
+    pool:         (n_blocks, 2, block_size, Hkv, dh) one layer of the pool
+                  (a block's K plane, then its V plane:
+                  ``serving.kv_pool``), or — with ``layer`` () int32 — the
+                  stacked (n_layers, n_blocks, 2, block_size, Hkv, dh) arena
+                  the model's layer scan carries whole: the fused kernel DMAs
+                  ``[layer, block]``, both planes in one copy, straight out
+                  of it, the gather oracle
                   reads ``pool[layer]`` (a slice XLA fuses into the gather).
     block_tables: (B, max_blocks) int32; offset: () or (B,) cache length
     BEFORE this step; slot_mask: (B,) bool dead-slot mask (dead rows'
     outputs are garbage the serving engine discards). -> (B, L, Hq, dh).
 
-    ``kv_scales`` — ``(k_scale, v_scale)``, each (n_blocks, block_size,
-    Hkv) f32, stacked like the pools — marks the pool QUANTIZED (int8/fp8
-    wire dtype, per-row
+    ``kv_scales`` — (n_blocks, 2, block_size, Hkv) f32, stacked like the
+    pool — marks the pool QUANTIZED (int8/fp8 wire dtype, per-row
     scales from ``quantize_kv_rows``): the fused kernel dequantizes in
     VMEM staging right after the pool->VMEM DMA, the gather oracle
     dequantizes its materialized view with ``dequantize_kv_rows``, and
@@ -484,7 +498,9 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
 
     When the comm ledger is enabled, records a ``paged_attn`` series with
     the analytic ``perf_model.paged_attn_bytes`` for whichever method ran
-    (``fused_decode`` / ``fused_prefill`` / ``gather``) — the roofline
+    (``fused_decode`` / ``fused_prefill`` / ``gather``) and, for the fused
+    ones, the size of the walk's fetch (``copy_bytes``, what one DMA
+    carries: a block's two planes; ``copies_per_tile``) — the roofline
     classifies it HBM-bound (one pool touch), and the bench ``paged_attn``
     arm gates the fused/gather byte ratio on decode, pure-prefill, and
     mixed rows.
@@ -494,44 +510,47 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
             f"paged_attn must be 'fused' or 'gather', got {paged_attn!r}")
     B, L, Hq, dh = q.shape
     fused = paged_attn == "fused"
-    bs, Hkv = k_pool.shape[-3:-1]
+    bs, Hkv = pool.shape[-3:-1]
     quant = kv_scales is not None
-    if quant and kv_scales[0].shape != k_pool.shape[:-1]:
+    if quant and kv_scales.shape != pool.shape[:-1]:
         raise ValueError(
-            f"kv_scales shape {kv_scales[0].shape} does not match pool "
-            f"rows {k_pool.shape[:-1]}")
+            f"kv_scales shape {kv_scales.shape} does not match pool "
+            f"rows {pool.shape[:-1]}")
 
     from triton_distributed_tpu.obs import comm_ledger as _ledger
 
     if _ledger.enabled():
         from triton_distributed_tpu.runtime import perf_model as pm
 
-        q_tile = None
+        q_tile = detail = None
         if not fused:
             method = "gather"
-        elif L == 1:
-            method = "fused_decode"
         else:
             from triton_distributed_tpu.kernels.paged_attention import (
+                copy_size,
                 tuned_paged_tile,
             )
 
-            method = "fused_prefill"
-            # The exact q_tile the kernel will run (memoized/deterministic
+            method = "fused_decode" if L == 1 else "fused_prefill"
+            # The exact tiles the kernel will run (memoized/deterministic
             # off-TPU), so the ledger equals the analytic model.
-            _, q_tile = tuned_paged_tile(
+            tile, q_tile = tuned_paged_tile(
                 bs, Hkv, dh, block_tables.shape[1],
-                str(k_pool.dtype), L=L, g=Hq // Hkv)
+                str(pool.dtype), L=L, g=Hq // Hkv)
+            detail = copy_size(bs, Hkv, dh, pool.dtype.itemsize,
+                               min(tile, block_tables.shape[1]),
+                               kv_scales=quant)
         nbytes = pm.paged_attn_bytes(
             B, block_tables.shape[1], bs, Hkv, dh,
             n_q_heads=Hq,
             itemsize=(q.dtype.itemsize if quant
-                      else k_pool.dtype.itemsize),
-            kv_itemsize=k_pool.dtype.itemsize, kv_scales=quant,
+                      else pool.dtype.itemsize),
+            kv_itemsize=pool.dtype.itemsize, kv_scales=quant,
             method=method, L=L, q_tile=q_tile)
         _ledger.record_traced(
             "paged_attn", axis="local", world=1, nbytes=nbytes,
-            method=method, est_s=nbytes / pm.detect_hardware().hbm_bw)
+            method=method, est_s=nbytes / pm.detect_hardware().hbm_bw,
+            detail=detail)
 
     if fused:
         off = jnp.broadcast_to(
@@ -541,29 +560,25 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
         else:
             q_lens = jnp.broadcast_to(
                 jnp.asarray(seq_lens, jnp.int32).reshape(-1), (B,))
-        arrays = dict(q=q, k_pool=k_pool, v_pool=v_pool,
-                      block_tables=block_tables, kv_lens=off + q_lens,
-                      q_lens=q_lens, slot_mask=slot_mask, layer=layer)
-        if quant:
-            arrays.update(k_scale=kv_scales[0], v_scale=kv_scales[1])
+        arrays = dict(q=q, pool=pool, block_tables=block_tables,
+                      kv_lens=off + q_lens, q_lens=q_lens,
+                      slot_mask=slot_mask, layer=layer, scales=kv_scales)
         return _fused_paged_attention(
             {k: v for k, v in arrays.items() if v is not None},
             scale=scale, interpret=interpret)
 
-    from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
-
-    def view(pool):
+    def view(arena):
         if layer is not None:
-            pool = jax.lax.dynamic_index_in_dim(pool, layer, 0,
-                                                keepdims=False)
-        return paged_gather_kv(pool, block_tables, slot_mask=slot_mask)
+            arena = jax.lax.dynamic_index_in_dim(arena, layer, 0,
+                                                 keepdims=False)
+        return gathered_planes(arena, block_tables, slot_mask)
 
-    k_view, v_view = view(k_pool), view(v_pool)
+    k_view, v_view = view(pool)
     if quant:
         # Oracle-side dequant: gather the per-row scales through the SAME
         # table walk, reconstruct f32 views (identical expression to the
         # kernel's in-VMEM dequant), and run the dense reference on those.
-        ks_view, vs_view = view(kv_scales[0]), view(kv_scales[1])
+        ks_view, vs_view = view(kv_scales)
         k_view = dequantize_kv_rows(k_view, ks_view)
         v_view = dequantize_kv_rows(v_view, vs_view)
     return attn_with_cache(q, k_view, v_view, offset, scale=scale,
@@ -571,15 +586,31 @@ def paged_attn_with_cache(q, k_pool, v_pool, block_tables, offset, *,
                            seq_lens=seq_lens, interpret=interpret)
 
 
-def window_attn_with_cache(q, k_ring, v_ring, slots, offset, *, window: int,
+# The two planes of a K+V arena as an index beside a (rows, tokens) pair of
+# block and line indices: (1, 1, 2), plane 0 the keys, plane 1 the values.
+_PLANES = np.arange(2, dtype=np.int32)[None, None]
+
+
+def ring_planes(ring, layer, slots):
+    """The oracle's read of ring storage ``(window layers, n_slots, 2,
+    ring_blocks, block_size, Hkv, dh)`` at ``layer``: the rings of ``slots``
+    (B,) as lines, -> the (K, V) pair, each ``(B, lines, Hkv, dh)``."""
+    rows = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
+    rows = jnp.take(rows, slots, axis=0, mode="clip")
+    rows = rows.reshape(*rows.shape[:2], -1, *rows.shape[4:])
+    return rows[:, 0], rows[:, 1]
+
+
+def window_attn_with_cache(q, ring, slots, offset, *, window: int,
                            layer, scale: float, slot_mask=None,
                            seq_lens=None, interpret=None,
                            paged_attn: str = "fused"):
     """GQA attention of new queries over a WINDOW layer's ring storage: a
     query at position ``p`` sees the keys ``p - window < j <= p``.
 
-    q: (B, L, Hq, dh); k/v_ring: ``(window layers, n_slots, ring_blocks,
-    block_size, Hkv, dh)`` (``serving.kv_pool``), read at ``layer``; the new
+    q: (B, L, Hq, dh); ring: ``(window layers, n_slots, 2, ring_blocks,
+    block_size, Hkv, dh)`` (``serving.kv_pool``: plane 0 the keys, plane 1
+    the values), read at ``layer``; the new
     tokens' rows are already in it (``window_cache_update``).
     ``slots`` (B,) int32: the slot each row belongs to. Line ``r`` of a
     slot's ring (``ring_blocks * block_size`` lines) holds the NEWEST
@@ -599,7 +630,7 @@ def window_attn_with_cache(q, k_ring, v_ring, slots, offset, *, window: int,
               else jnp.asarray(seq_lens, jnp.int32))
     slots = jnp.asarray(slots, jnp.int32)
     if paged_attn == "fused":
-        arrays = dict(q=q, k_pool=k_ring, v_pool=v_ring,
+        arrays = dict(q=q, pool=ring,
                       block_tables=slots[:, None], kv_lens=off + q_lens,
                       q_lens=q_lens, slot_mask=slot_mask, layer=layer)
         return _fused_paged_attention(
@@ -608,12 +639,7 @@ def window_attn_with_cache(q, k_ring, v_ring, slots, offset, *, window: int,
     if slot_mask is not None:
         slots = jnp.where(slot_mask, slots, 0)
 
-    def view(ring):
-        rows = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
-        rows = jnp.take(rows, slots, axis=0, mode="clip")
-        return rows.reshape(B, -1, *rows.shape[3:])      # (B, lines, Hkv, dh)
-
-    k, v = view(k_ring), view(v_ring)
+    k, v = ring_planes(ring, layer, slots)
     lines, Hkv = k.shape[1:3]
     last = (off + q_lens - 1)[:, None]                             # (B, 1)
     # the newest position <= last that line r can hold; below 0: none yet
@@ -638,25 +664,27 @@ def window_attn_with_cache(q, k_ring, v_ring, slots, offset, *, window: int,
 
 
 def window_cache_update(ring, new, slots, offsets, write_mask, layer):
-    """Write ``new`` (B, L, H, dh) into a window layer's RING storage
-    ``(n_layers, n_slots, ring_blocks, block_size, H, dh)``
-    (``serving.kv_pool``) at ``layer``: token (b, l) lands in ring block
+    """Write ``new`` (B, L, 2, H, dh), a token's K row and V row, into a
+    window layer's RING storage ``(n_layers, n_slots, 2, ring_blocks,
+    block_size, H, dh)`` (``serving.kv_pool``) at ``layer``, ONE scatter for
+    both planes: token (b, l) lands in ring block
     ``((offsets[b] + l) // block_size) % ring_blocks`` of slot ``slots[b]``,
     line ``(offsets[b] + l) % block_size``, over whatever an older lap left
     there. ``write_mask`` (B,) or (B, L) drops masked writes, as
     ``paged_cache_update`` does. Functional: returns the new storage."""
-    n_slots, n_ring, bs = ring.shape[1:4]
+    n_slots, _, n_ring, bs = ring.shape[1:5]
     B, L = new.shape[:2]
     pos = (jnp.asarray(offsets, jnp.int32)[:, None]
            + jnp.arange(L, dtype=jnp.int32)[None])                 # (B, L)
     slot = jnp.broadcast_to(jnp.asarray(slots, jnp.int32)[:, None], (B, L))
     wm = write_mask if write_mask.ndim == 2 else write_mask[:, None]
     slot = jnp.where(wm, slot, n_slots)             # out of range -> dropped
-    return ring.at[layer, slot, (pos // bs) % n_ring, pos % bs].set(
+    return ring.at[layer, slot[..., None], _PLANES,
+                   (pos[..., None] // bs) % n_ring, pos[..., None] % bs].set(
         new.astype(ring.dtype), mode="drop")
 
 
-def eva_attn_with_cache(q, k_ring, v_ring, k_sum, v_sum, slots, block_tables,
+def eva_attn_with_cache(q, ring, summaries, slots, block_tables,
                         offset, *, window: int, chunk: int, layer,
                         scale: float, slot_mask=None, seq_lens=None,
                         interpret=None, paged_attn: str = "fused"):
@@ -666,9 +694,10 @@ def eva_attn_with_cache(q, k_ring, v_ring, k_sum, v_sum, slots, block_tables,
     through its chunk summaries, the rows ``c < (window // chunk) * (p //
     window)`` of the slot's blocks in the row arenas, all under one softmax.
 
-    q: (B, L, Hq, dh); k/v_ring as ``window_attn_with_cache`` takes them;
-    k/v_sum: the stacked block arenas ``(layers, n_blocks, block_size, Hkv,
-    dh)`` whose row ``c`` of a sequence is chunk ``c``'s summary, found
+    q: (B, L, Hq, dh); ``ring`` as ``window_attn_with_cache`` takes it;
+    ``summaries``: the stacked block arena ``(layers, n_blocks, 2,
+    block_size, Hkv, dh)`` whose row ``c`` of a sequence is chunk ``c``'s
+    summary (the pooled key in plane 0, the pooled value in plane 1), found
     through ``block_tables`` (B, max_blocks); both read at ``layer``. The
     step's new rows are in the ring and the chunks it closed in the arenas
     already. ``slots``, offsets, ``seq_lens``, ``slot_mask`` as in
@@ -695,14 +724,14 @@ def eva_attn_with_cache(q, k_ring, v_ring, k_sum, v_sum, slots, block_tables,
         if slot_mask is not None:
             rows["slot_mask"] = slot_mask
 
-        def half(k_pool, v_pool, tables, **build):
+        def half(pool, tables, **build):
             return _fused_paged_attention(
-                dict(rows, k_pool=k_pool, v_pool=v_pool, block_tables=tables),
+                dict(rows, pool=pool, block_tables=tables),
                 scale=scale, interpret=interpret, stats=True, **build)
 
-        o1, m1, l1 = half(k_ring, v_ring, slots[:, None], window=int(window),
+        o1, m1, l1 = half(ring, slots[:, None], window=int(window),
                           aligned=True)
-        o2, m2, l2 = half(k_sum, v_sum, block_tables,
+        o2, m2, l2 = half(summaries, block_tables,
                           summary=(int(window), int(per_window)))
         m = jnp.maximum(m1, m2)
         w1, w2 = l1 * jnp.exp(m1 - m), l2 * jnp.exp(m2 - m)
@@ -713,19 +742,10 @@ def eva_attn_with_cache(q, k_ring, v_ring, k_sum, v_sum, slots, block_tables,
     if slot_mask is not None:
         slots = jnp.where(slot_mask, slots, 0)
         block_tables = jnp.where(slot_mask[:, None], block_tables, 0)
-    from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
-
-    def ring_view(ring):
-        rows = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
-        rows = jnp.take(rows, slots, axis=0, mode="clip")
-        return rows.reshape(B, -1, *rows.shape[3:])      # (B, lines, Hkv, dh)
-
-    def summary_view(pool):
-        pool = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-        return paged_gather_kv(pool, block_tables)       # (B, rows, Hkv, dh)
-
-    k1, v1 = ring_view(k_ring), ring_view(v_ring)
-    k2, v2 = summary_view(k_sum), summary_view(v_sum)
+    k1, v1 = ring_planes(ring, layer, slots)             # (B, lines, Hkv, dh)
+    k2, v2 = gathered_planes(                            # (B, rows, Hkv, dh)
+        jax.lax.dynamic_index_in_dim(summaries, layer, 0, keepdims=False),
+        block_tables)
     lines, Hkv = k1.shape[1:3]
     last = (off + q_lens - 1)[:, None]                             # (B, 1)
     # the newest position <= last that ring line r can hold (below 0: none)
@@ -754,7 +774,7 @@ def eva_attn_with_cache(q, k_ring, v_ring, k_sum, v_sum, slots, block_tables,
     return out.reshape(B, L, Hq, dh).astype(q.dtype)
 
 
-def eva_summary_update(k_sum, v_sum, k_ring, v_ring, mu, phi, slots,
+def eva_summary_update(summaries, ring, mu, phi, slots,
                        block_tables, offsets, lengths, layer, *, chunk: int,
                        scale: float, max_len: int):
     """THE PRODUCER of an EVA layer's summaries: for every chunk of
@@ -765,18 +785,19 @@ def eva_summary_update(k_sum, v_sum, k_ring, v_ring, mu, phi, slots,
         k~_c = sum_j softmax_j(scale k_j . mu) k_j
         v~_c = sum_j softmax_j(scale k_j . phi) v_j        (a head each)
 
-    and write one K row and one V row at summary row ``c`` of the sequence,
-    block ``block_tables[b, c // block_size]`` line ``c % block_size`` of the
-    row arenas, at ``layer``. Row b's new tokens are the ``lengths[b]``
+    and write one K row and one V row (ONE scatter, both planes) at summary
+    row ``c`` of the sequence, block ``block_tables[b, c // block_size]``
+    line ``c % block_size`` of the row arena ``summaries``, at ``layer``.
+    Row b's new tokens are the ``lengths[b]``
     (``max_len`` at most, static) from ``offsets[b]`` on, already in the
     ring; a chunk they leave ragged waits there. mu, phi: (Hkv, dh).
-    Returns ``(k_sum, v_sum)``."""
-    bs = k_ring.shape[3]
+    Returns the arena."""
+    bs = ring.shape[4]
     if bs != chunk:
         raise ValueError(
             f"a ring block is {bs} lines and a chunk {chunk} positions: the "
             f"producer reads a chunk as ONE ring block")
-    n_ring, n_blocks = k_ring.shape[2], k_sum.shape[1]
+    n_ring, n_blocks = ring.shape[3], summaries.shape[1]
     offsets = jnp.asarray(offsets, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     # at most cdiv(max_len, chunk) chunks close under max_len new tokens
@@ -785,12 +806,10 @@ def eva_summary_update(k_sum, v_sum, k_ring, v_ring, mu, phi, slots,
     closed = chunk * (c + 1) <= (offsets + lengths)[:, None]
     at = jnp.asarray(slots, jnp.int32)[:, None]
 
-    def lines(ring):                       # (B, C, chunk, Hkv, dh) float32
-        # (one gather out of the arena where it lies: a layer of it sliced
-        # out first is a copy of every slot's ring)
-        return ring[layer, at, c % n_ring].astype(jnp.float32)
-
-    k, v = lines(k_ring), lines(v_ring)
+    # (one gather out of the arena where it lies: a layer of it sliced out
+    # first is a copy of every slot's ring)
+    lines = ring[layer, at, :, c % n_ring].astype(jnp.float32)
+    k, v = lines[:, :, 0], lines[:, :, 1]  # (B, C, chunk, Hkv, dh) each
 
     def pooled(rows, by):
         w = jax.nn.softmax(
@@ -801,10 +820,9 @@ def eva_summary_update(k_sum, v_sum, k_ring, v_ring, mu, phi, slots,
     row = jnp.minimum(c // bs, block_tables.shape[1] - 1)
     blk = jnp.where(closed, jnp.take_along_axis(block_tables, row, axis=1),
                     n_blocks)                       # out of range -> dropped
-    idx = (layer, blk, c % bs)
-    return (k_sum.at[idx].set(pooled(k, mu).astype(k_sum.dtype), mode="drop"),
-            v_sum.at[idx].set(pooled(v, phi).astype(v_sum.dtype),
-                              mode="drop"))
+    rows = jnp.stack([pooled(k, mu), pooled(v, phi)], axis=2)
+    return summaries.at[layer, blk[..., None], _PLANES, c[..., None] % bs].set(
+        rows.astype(summaries.dtype), mode="drop")
 
 
 def latent_attn_with_cache(q, pool, block_tables, offset, *, v_dim: int,
@@ -831,11 +849,11 @@ def latent_attn_with_cache(q, pool, block_tables, offset, *, v_dim: int,
     q_lens = (jnp.full((B,), L, jnp.int32) if seq_lens is None
               else jnp.asarray(seq_lens, jnp.int32))
     if paged_attn == "fused":
-        arrays = dict(q=q, k_pool=pool, block_tables=block_tables,
+        arrays = dict(q=q, pool=pool, block_tables=block_tables,
                       kv_lens=off + q_lens, q_lens=q_lens,
                       slot_mask=slot_mask, layer=layer)
         return _fused_paged_attention(
-            {k: v for k, v in arrays.items() if v is not None}, v_pool=None,
+            {k: v for k, v in arrays.items() if v is not None},
             scale=scale, interpret=interpret, v_dim=v_dim)
     from triton_distributed_tpu.kernels.sp_attention import paged_gather_kv
 
@@ -886,16 +904,19 @@ def cache_update(cache, new, offset):
 
 def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
                        scale_pool=None, layer=None):
-    """Write ``new`` (B, L, H, dh) into a block-paged KV pool layer
-    (n_blocks, block_size, H, dh) — or ``new`` (B, L, W) into a latent
-    pool layer (n_blocks, block_size, W) — at per-slot positions — the
-    PagedAttention write: token (b, l) lands in block
+    """Write a step's new rows into a block-paged pool layer at per-slot
+    positions — the PagedAttention write: token (b, l) lands in block
     ``block_tables[b, (offsets[b] + l) // block_size]`` at line
-    ``(offsets[b] + l) % block_size``. Functional: returns the new pool.
+    ``(offsets[b] + l) % block_size``. ``new`` (B, L, 2, H, dh), a token's K
+    row and V row, goes into a K+V pool layer (n_blocks, 2, block_size, H,
+    dh) with ONE scatter at ``[block, plane, line]``: both planes of the
+    (block, line), which lie in the one arena (``serving.kv_pool``); ``new``
+    (B, L, W) into a latent pool layer (n_blocks, block_size, W).
+    Functional: returns the new pool.
 
     ``layer`` () int32 — the pool is the STACKED arena (n_layers,
-    n_blocks, block_size, H, dh) and the rows land at ``[layer, block,
-    line]``: one scatter of B*L rows into the arena where it lies (the
+    n_blocks, ...) and the rows land at ``[layer, block, plane, line]``: one
+    scatter of B*L rows into the arena where it lies (the
     layer scan carries the arena, and XLA updates a carried operand in
     place), never a slice-update-restack of a whole layer.
 
@@ -903,9 +924,9 @@ def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
     chunked prefill: only row b's first seq_lens[b] tokens are real) —
     DROPS masked writes entirely (routed out of range under scatter mode
     'drop'), so inactive slots and padding rows can never corrupt blocks
-    owned by live sequences.
+    owned by live sequences: a masked row writes neither plane.
 
-    ``scale_pool`` — (n_blocks, block_size, H) f32 — marks the pool
+    ``scale_pool`` — (n_blocks, 2, block_size, H) f32 — marks the pool
     QUANTIZED: ``new`` is quantized per row (``quantize_kv_rows``) to the
     pool's wire dtype INSIDE this compiled append, and the row scales are
     scattered through the identical (block, line) indexing (same drop
@@ -918,7 +939,14 @@ def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
             f"and only with it (pool rank {pool.ndim}, rows' {new.ndim}, "
             f"layer {layer!r})")
     B, L = new.shape[:2]
-    n_blocks, bs = pool.shape[-new.ndim:][:2]
+    paired = new.ndim == 5
+    if paired and (new.shape[2] != 2 or pool.shape[-4] != 2):
+        raise ValueError(
+            f"a K+V pool takes a token's K row and V row together, new (B, "
+            f"L, 2, H, dh) into (n_blocks, 2, block_size, H, dh): got rows "
+            f"{new.shape} for a pool {pool.shape}")
+    n_blocks = pool.shape[-new.ndim]
+    bs = pool.shape[-3 if paired else -2]
     pos = (jnp.asarray(offsets, jnp.int32)[:, None]
            + jnp.arange(L, dtype=jnp.int32)[None])                 # (B, L)
     slot = jnp.minimum(pos // bs, block_tables.shape[1] - 1)
@@ -928,7 +956,14 @@ def paged_cache_update(pool, new, block_tables, offsets, write_mask=None,
     if write_mask is not None:
         wm = (write_mask if write_mask.ndim == 2 else write_mask[:, None])
         blk = jnp.where(wm, blk, n_blocks)          # out of range -> dropped
-    idx = (blk, pos % bs) if layer is None else (layer, blk, pos % bs)
+    idx = (blk, pos % bs)
+    if paired:
+        # (block, PLANE, line), the plane an index like the others: one
+        # scatter of both rows, in place (a slice between the index arrays
+        # costs XLA's CPU backend a copy of the arena)
+        idx = (blk[..., None], _PLANES, pos[..., None] % bs)
+    if layer is not None:
+        idx = (layer, *idx)
     if scale_pool is None:
         return pool.at[idx].set(new.astype(pool.dtype), mode="drop")
     q, scales = quantize_kv_rows(new, pool.dtype)

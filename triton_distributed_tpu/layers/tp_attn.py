@@ -52,7 +52,7 @@ def ring_slots(blocks, ring, window: int) -> list:
     b row b."""
     take = max(blk.L * (1 if blk.slots is None else blk.offsets.shape[0])
                for blk in blocks)
-    lines = ring.shape[2] * ring.shape[3]
+    lines = ring.shape[3] * ring.shape[4]
     if window - 1 + take > lines:
         raise ValueError(
             f"a slot's ring holds {lines} lines, and a step that gives "
@@ -93,7 +93,7 @@ class TPAttn:
     kv_pack: int = 1
     # A WINDOW layer (paged path only): a query sees the last ``window``
     # keys up to itself, and the layer's rows live in the pool's ring
-    # storage (``state.wk`` / ``state.wv``), read and appended at ``layer``
+    # storage (``state.wkv``), read and appended at ``layer``
     # of the WINDOW layers. None: every key, the block arenas.
     window: int | None = None
 
@@ -207,12 +207,14 @@ class TPAttn:
           block at chunk shape; positions no block owns give zeros.
           ``cache`` is the pool's state
           (``serving.kv_pool.PagedKVState``), taken and returned whole;
-          this layer is where its arenas are read. ``k``/``v`` are one
-          layer of the block pool (n_blocks, block_size, Hkv, dh) or, with
+          this layer is where its arenas are read. ``kv`` is one
+          layer of the block pool (n_blocks, 2, block_size, Hkv, dh), a
+          block's K plane and V plane side by side, or, with
           ``layer`` () int32, the whole stacked arena (n_layers, n_blocks,
-          block_size, Hkv, dh) — what the model's layer scan carries,
+          2, block_size, Hkv, dh) — what the model's layer scan carries,
           appended to and read at ``[layer, block]`` where it lies. EVERY
-          block's new K/V are scattered into the pool first, one scatter
+          block's new K/V are scattered into the pool first (a token's K
+          row and V row in ONE scatter), one scatter
           after another on the carried arena, and only then is any block
           attended (sequences own their blocks, so a block reads nothing
           another wrote this step): an append that had to leave the pool
@@ -226,8 +228,8 @@ class TPAttn:
           hatch / test oracle — either way arriving/finishing sequences
           are pure DATA changes and the step never retraces.
 
-        Quantized paged KV (the state has ``k_scale``/``v_scale`` arenas,
-        the K/V arenas' shape minus dh, f32): the pool arenas hold
+        Quantized paged KV (the state has a ``kv_scale`` arena,
+        the K+V arena's shape minus dh, f32): the pool arena holds
         int8/fp8 rows, new K/V are quantized per (row, kv head) at append
         time (``nn.paged_cache_update(scale_pool=...)``), and the
         attention read dequantizes — inside the fused kernel's VMEM
@@ -262,33 +264,23 @@ class TPAttn:
                 v = v.reshape(*v.shape[:2], -1, pk * self.head_dim)
             queries.append(q)
             wm = blk.valid().reshape(-1, blk.L)
-            if state.k_scale is not None:
-                k_pool, ks = nn.paged_cache_update(
-                    state.k, k, blk.tables, blk.offsets, wm,
-                    scale_pool=state.k_scale, layer=layer)
-                v_pool, vs = nn.paged_cache_update(
-                    state.v, v, blk.tables, blk.offsets, wm,
-                    scale_pool=state.v_scale, layer=layer)
-            else:
-                k_pool = nn.paged_cache_update(state.k, k, blk.tables,
-                                               blk.offsets, wm, layer=layer)
-                v_pool = nn.paged_cache_update(state.v, v, blk.tables,
-                                               blk.offsets, wm, layer=layer)
-                ks = vs = None
-            state = dataclasses.replace(state, k=k_pool, v=v_pool,
-                                        k_scale=ks, v_scale=vs)
-        scales = (None if state.k_scale is None
-                  else (state.k_scale, state.v_scale))
+            # a token's K row and V row: one scatter into both planes
+            pool = nn.paged_cache_update(
+                state.kv, jnp.stack([k, v], axis=2), blk.tables,
+                blk.offsets, wm, scale_pool=state.kv_scale, layer=layer)
+            pool, scales = (pool, None) if state.kv_scale is None else pool
+            state = dataclasses.replace(state, kv=pool, kv_scale=scales)
+
         def own_heads(o):
             # each query head's own columns of the packed value rows
             return o if self.kv_pack == 1 else nn.unpack_output_heads(
                 o, self.kv_pack, self.n_heads // self.n_kv_heads)
 
         outs = [own_heads(nn.paged_attn_with_cache(
-            q, state.k, state.v, blk.tables, blk.offsets, scale=scale,
+            q, state.kv, blk.tables, blk.offsets, scale=scale,
             slot_mask=blk.mask, use_flash_decode=use_flash_decode,
             seq_lens=blk.seq_lens, interpret=interpret,
-            paged_attn=paged_attn, kv_scales=scales,
+            paged_attn=paged_attn, kv_scales=state.kv_scale,
             layer=layer)).reshape(blk.stop - blk.start, -1)
             for q, blk in zip(queries, blocks)]
         tail = qkv.shape[0] - blocks[-1].stop
@@ -305,7 +297,7 @@ class TPAttn:
         rows of the prefill block may be one slot's consecutive chunks, and
         the ring holds the window and a step's largest take, so the later
         rows' appends overwrite nothing the first row reads."""
-        if state.wk is None:
+        if state.wkv is None:
             raise ValueError(
                 "the pool's state has no window storage: build the pool "
                 "from this model's configuration (KVPool(config, ..., "
@@ -313,21 +305,18 @@ class TPAttn:
         if self.kv_pack > 1 or world != 1:
             raise NotImplementedError(
                 "a window layer is built for one device and unpacked rows")
-        slots = ring_slots(blocks, state.wk, self.window)
+        slots = ring_slots(blocks, state.wkv, self.window)
         queries = []
         for blk, at in zip(blocks, slots):
             part = qkv[blk.start:blk.stop].reshape(-1, blk.L, qkv.shape[-1])
             q, k, v = self._qkv_rope(params, part, blk.offsets, world)
             queries.append(q)
             wm = blk.valid().reshape(-1, blk.L)
-            state = dataclasses.replace(
-                state,
-                wk=nn.window_cache_update(state.wk, k, at, blk.offsets, wm,
-                                          layer),
-                wv=nn.window_cache_update(state.wv, v, at, blk.offsets, wm,
-                                          layer))
+            state = dataclasses.replace(state, wkv=nn.window_cache_update(
+                state.wkv, jnp.stack([k, v], axis=2), at, blk.offsets, wm,
+                layer))
         outs = [nn.window_attn_with_cache(
-            q, state.wk, state.wv, at, blk.offsets, window=self.window,
+            q, state.wkv, at, blk.offsets, window=self.window,
             layer=layer, scale=scale, slot_mask=blk.mask,
             seq_lens=blk.seq_lens, interpret=interpret,
             paged_attn=paged_attn).reshape(blk.stop - blk.start, -1)

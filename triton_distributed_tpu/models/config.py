@@ -83,8 +83,8 @@ class ModelConfig:
 
     @property
     def kv_row_shapes(self):
-        """One token's row in the paged pool's K arena and in its V arena:
-        per-head keys and values."""
+        """One token's row in the K plane and in the V plane of the paged
+        pool's arena: per-head keys and values."""
         row = (self.n_kv_heads, self.head_dim)
         return row, row
 
@@ -185,8 +185,8 @@ class DeepseekV3Config:
 
     @property
     def kv_row_shapes(self):
-        """ONE latent arena (no V arena): keys are the whole row, values
-        its first ``kv_lora_rank`` columns."""
+        """ONE latent arena (no V row, so no planes): keys are the whole
+        row, values its first ``kv_lora_rank`` columns."""
         return (self.cache_row,), None
 
     @property

@@ -215,10 +215,10 @@ class DeepseekV3:
                 f"under BatchEngine). One device is one chip's share of "
                 f"the deployment (DeepseekV3Config.experts_held); no code "
                 f"stands in for the other chips.")
-        if state.v is not None or state.k_scale is not None:
+        if not state.latent or state.kv_scale is not None:
             raise NotImplementedError(
-                "the latent attention reads a latent pool: one arena in the "
-                "model dtype, no V arena and no quantized build")
+                "the latent attention reads a latent pool: one arena of rows "
+                "in the model dtype, no planes and no quantized build")
         if spec_verify:
             raise NotImplementedError(
                 "speculative verify is not built for the latent/"

@@ -196,12 +196,12 @@ class EvaByte:
                 f"sharded over the key heads). One device is one pipeline "
                 f"stage's share of the deployment; no code stands in for "
                 f"the other stages.")
-        if state.wk is None:
+        if state.wkv is None:
             raise ValueError(
                 "the pool's state has no window storage: build the pool "
                 "from this model's configuration (KVPool(config, ..., "
                 "n_slots=...))")
-        if state.k_scale is not None:
+        if state.kv_scale is not None:
             raise NotImplementedError(
                 "the EvaByte block has no quantized build of its pool")
         if spec_verify:

@@ -327,7 +327,7 @@ class ExaoneMoe:
                 f"One device is one chip's share of the deployment "
                 f"(ExaoneMoeConfig.experts_held); no code stands in for "
                 f"the other chips.")
-        if c.n_window_layers and state.wk is None:
+        if c.n_window_layers and state.wkv is None:
             raise ValueError(
                 "the pool's state has no window storage: build the pool "
                 "from this model's configuration (KVPool(config, ..., "
@@ -337,7 +337,7 @@ class ExaoneMoe:
                 "the pool's state has no per-slot conv arena: build the "
                 "pool from this model's configuration (KVPool(config, ..., "
                 "n_slots=...))")
-        if state.k_scale is not None:
+        if state.kv_scale is not None:
             raise NotImplementedError(
                 "the EXAONE-MoE block has no quantized build of its pool")
         if spec_verify:

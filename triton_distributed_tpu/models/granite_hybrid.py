@@ -243,7 +243,7 @@ class GraniteHybrid:
                 "the pool's state has no per-slot arenas: build the pool "
                 "from this model's configuration (KVPool(config, ...,"
                 " n_slots=...))")
-        if state.k_scale is not None:
+        if state.kv_scale is not None:
             raise NotImplementedError(
                 "the hybrid block has no quantized build of its pool")
         if spec_verify:

@@ -66,6 +66,10 @@ class LedgerEntry:
     est_s_total: float = 0.0  # perf_model estimated seconds, summed
     wall_s_total: float = 0.0 # achieved seconds (host-level calls only)
     wall_samples: int = 0
+    # static facts of the series' call sites, as its last record stated
+    # them (``paged_attn``: ``copy_bytes``, ``copies_per_tile``); they join
+    # the aggregate's keys in ``as_dict``
+    detail: dict = dataclasses.field(default_factory=dict)
 
     @property
     def key(self) -> str:
@@ -74,6 +78,7 @@ class LedgerEntry:
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
+        d.update(d.pop("detail"))
         if self.wall_samples and self.est_s_total:
             # achieved / estimated: ~1 means the perf model is honest;
             # >>1 on one rank but not others names the straggler.
@@ -167,8 +172,9 @@ class CommLedger:
     def record(self, collective: str, *, axis: str, world: int,
                nbytes: float, method: str = "", est_s: float | None = None,
                wall_s: float | None = None, traced: bool = False,
-               count: int = 1) -> None:
-        """``count`` calls of ``nbytes`` (and ``est_s``) each."""
+               count: int = 1, detail: dict | None = None) -> None:
+        """``count`` calls of ``nbytes`` (and ``est_s``) each. ``detail``:
+        static facts of the call site, kept beside the series' sums."""
         if not self.enabled:
             return
         key = (collective, method, axis, world)
@@ -188,10 +194,13 @@ class CommLedger:
             if wall_s is not None:
                 e.wall_s_total += float(wall_s)
                 e.wall_samples += 1
+            if detail:
+                e.detail.update(detail)
 
     def record_traced(self, collective: str, *, axis: str, world: int,
                       nbytes: float, method: str = "",
-                      est_s: float | None = None) -> None:
+                      est_s: float | None = None,
+                      detail: dict | None = None) -> None:
         """Trace-time record for device-level entry points (see module
         docstring): one call, or the enclosing ``repeated`` trips of it,
         to the ledger and to every open ``gathering()``."""
@@ -200,7 +209,8 @@ class CommLedger:
             sink.append(TracedRecord(collective, method, axis, world, count,
                                      float(nbytes) * count))
         self.record(collective, axis=axis, world=world, nbytes=nbytes,
-                    method=method, est_s=est_s, traced=True, count=count)
+                    method=method, est_s=est_s, traced=True, count=count,
+                    detail=detail)
 
     def timed(self, fn, collective: str, *, axis: str, world: int,
               nbytes: float, method: str = "",

@@ -1,14 +1,20 @@
 """Block-paged KV pool (PagedAttention-style memory management).
 
 The serving-side replacement for the per-request contiguous ``KVCache``:
-one fixed device allocation of ``n_blocks`` KV blocks per layer
+one fixed device allocation of ``n_blocks`` KV blocks per layer, ONE arena
+whose block keeps its K rows and its V rows side by side (plane 0 the keys,
+plane 1 the values)
 
-    k, v: (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
+    kv: (n_layers, n_blocks, 2, block_size, n_kv_heads, head_dim)
 
-or, for a model whose cache is one latent row a token
-(``config.kv_row_shapes`` names no V row), ONE arena
+so the two planes of a (layer, block) are one run of bytes in HBM and the
+block walk fetches them with ONE copy (``kernels/paged_attention.py``: a
+copy costs about 37 ns whatever it carries, and K's and V's rows of a block
+are always wanted together); or, for a model whose cache is one latent row a
+token (``config.kv_row_shapes`` names no V row: the row is both), an arena
+with no planes
 
-    k: (n_layers, n_blocks, block_size, row)
+    kv: (n_layers, n_blocks, block_size, row)
 
 ``n_layers`` here is the model's ``n_cache_layers``: the layers that keep
 rows. A model some of whose layers keep a state of FIXED size a sequence
@@ -28,17 +34,19 @@ keys (``config.n_window_layers`` of them, ``config.window``) keeps those
 layers' rows in a third kind of arena, by the window and not by the context:
 a RING a slot,
 
-    wk, wv: (window layers, n_slots, ring_blocks, block_size, n_kv_heads,
-             head_dim)
+    wkv: (window layers, n_slots, 2, ring_blocks, block_size, n_kv_heads,
+          head_dim)
 
-Token ``p`` of the sequence in slot ``s`` lies in ring block ``(p //
-block_size) % ring_blocks`` of ``s``, line ``p % block_size``, over whatever
+(the two planes OUTSIDE the ring's lines: a run of consecutive blocks is one
+run of lines a plane, and one copy of two chunks takes both). Token ``p`` of
+the sequence in slot ``s`` lies in ring block ``(p // block_size) %
+ring_blocks`` of ``s``, line ``p % block_size``, over whatever
 an older lap (or the slot's last request) left there. So the window layers
 need no allocator, no table beyond arithmetic, and nothing to free; their
 bytes do not move with ``max_seq_len`` or ``n_blocks``; consecutive blocks of
 a slot lie side by side in HBM, so the window build of the block walk
-fetches a whole tile of them that does not wrap the ring in ONE copy an
-arena where a paged pool's scattered blocks are a copy each
+fetches a whole tile of them that does not wrap the ring in ONE copy
+where a paged pool's scattered blocks are a copy each
 (``kernels/paged_attention.py``); and what a line held
 before is never seen, because a reader masks by POSITION (line ``r`` holds
 the newest position congruent to ``r`` that the sequence has written). All
@@ -73,16 +81,16 @@ request, so a fixed HBM budget serves many more concurrent sequences.
 
 Device arrays are a functional pytree (``PagedKVState``) updated in place
 under jit via buffer donation, exactly like ``KVCache``; the pool is
-sharded over the TP axis on the kv-head dim with the SAME PartitionSpec
-(``KVCache.spec``) — both layouts keep kv-heads at index 3.
+sharded over the TP axis on the kv-head dim (``KVCache.spec``, one position
+further right: the planes sit between the block and its lines).
 
 ``PagedKVState`` is the ONE description of the pool's format: which arenas
 exist and (``paged_state_specs``) how each is sharded. The compiled steps,
 ``Engine._make_sm`` and the model classes pass it whole — in as the one
 donated operand, through the layer scan as carry, out as one result — and
-only the attention layers (``layers/tp_attn.py``, ``layers/mla_attn.py``),
-which need the arrays, read its fields. A format that needs another arena
-adds a field here and reads it in its own layer.
+only the attention layers (``layers/tp_attn.py``, ``layers/mla_attn.py``,
+``layers/eva_attn.py``), which need the arrays, read its fields. A format
+that needs another arena adds a field here and reads it in its own layer.
 
 The allocator is deliberately plain Python: allocation decisions are
 host-side control flow between compiled steps (the reference engine makes
@@ -130,6 +138,12 @@ KV_WIRE_DTYPES = {
     "fp8": jnp.float8_e4m3fn,
     "float8_e4m3fn": jnp.float8_e4m3fn,
 }
+
+#: How a K+V pool lays a block out: its K plane and its V plane side by
+#: side in ONE arena (``PagedKVState.kv``). Named in ``KVPool.geometry`` and
+#: ``kv_fingerprint``, so a checkpoint manifest or a cached block written
+#: under the two-arena layout before it is refused, not adopted.
+KV_LAYOUT = "paired"
 
 #: Version tag of the per-row symmetric absmax scheme (layers/nn.py
 #: ``quantize_kv_rows``). Bump on ANY change to the quantization math —
@@ -189,13 +203,17 @@ def row_tokens(config) -> int:
     return int(getattr(config, "kv_row_tokens", 1) or 1)
 
 
+#: Planes of a block of a K+V arena: 0 the keys, 1 the values.
+KV_PLANES = 2
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVState:
     """Device half of the pool: the block arrays (functional pytree).
 
-    Quantized pools (``kv_dtype="int8"|"fp8"``) carry two extra arrays:
-    per-row f32 dequantization scales, shaped like the K/V arenas minus
+    Quantized pools (``kv_dtype="int8"|"fp8"``) carry one extra array:
+    per-row f32 dequantization scales, shaped like the K+V arena minus
     head_dim. A field that is ``None`` is an arena the format does not
     have (an empty pytree subtree): the tree's structure IS the format.
     A ROW arena (``ROW_ARENAS``) keeps (layer, block) as its two leading
@@ -203,33 +221,37 @@ class PagedKVState:
     ``slot_state_shapes`` names them) keeps (state layer, slot).
     """
 
-    k: jax.Array   # (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
-    v: jax.Array | None    # None: a latent pool, whose one arena ``k``
-                           # (n_layers, n_blocks, block_size, row) is both
-    k_scale: jax.Array | None = None   # (n_layers, n_blocks, bs, n_kv_heads)
-    v_scale: jax.Array | None = None
+    # (n_layers, n_blocks, 2, block_size, n_kv_heads, head_dim): a block's
+    # K plane and V plane side by side; a latent pool's one row is both
+    # and its arena has no planes, (n_layers, n_blocks, block_size, row)
+    kv: jax.Array
+    # (n_layers, n_blocks, 2, block_size, n_kv_heads) float32
+    kv_scale: jax.Array | None = None
     # (state layers, n_slots, heads, head width, state width) float32: the
     # state of a recurrence, one a slot and layer
     ssm: jax.Array | None = None
     # (state layers, n_slots, (d_conv - 1) * conv width): the last inputs
     # of a causal convolution, oldest first
     conv: jax.Array | None = None
-    # (window layers, n_slots, ring_blocks, block_size, n_kv_heads,
-    # head_dim): the ring storage of the layers that see a window of keys
-    wk: jax.Array | None = None
-    wv: jax.Array | None = None
+    # (window layers, n_slots, 2, ring_blocks, block_size, n_kv_heads,
+    # head_dim): the ring storage of the layers that see a window of keys,
+    # the planes outside a slot's lines
+    wkv: jax.Array | None = None
+
+    @property
+    def latent(self) -> bool:
+        return self.kv.ndim == 4
 
     @property
     def n_blocks(self) -> int:
-        return self.k.shape[1]
+        return self.kv.shape[1]
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[2]
+        return self.kv.shape[2 if self.latent else 3]
 
 
-ROW_ARENAS = ("k", "v", "k_scale", "v_scale")
-WINDOW_ARENAS = ("wk", "wv")
+ROW_ARENAS = ("kv", "kv_scale")
 
 
 def window_kind(config) -> tuple[int, int]:
@@ -249,22 +271,25 @@ def window_ring_blocks(window: int, block_size: int, max_take: int) -> int:
 def paged_state_specs(config, axis: str = "tp", *,
                       quant: bool = False) -> PagedKVState:
     """The pool's ``PartitionSpec``s as a ``PagedKVState`` of the structure
-    the pool's state has: K and V arenas sharded over ``axis`` on the
-    kv-head dim (``KVCache.spec``), a quantized pool's scale arenas the
-    same minus head_dim (``KVCache.scale_spec``); a latent pool
+    the pool's state has: the K+V arena sharded over ``axis`` on the
+    kv-head dim (``KVCache.spec`` with the planes' axis before the lines),
+    a quantized pool's scale arena the same minus head_dim; a latent pool
     (``config.kv_row_shapes`` names no V row) is one replicated arena,
     its row shared by every head. The per-slot arenas a model states
     (``config.slot_state_shapes``) are replicated: no model shards them
     yet. ``KVPool`` allocates under these and the paged step's shard_map
     takes them as the state's in/out specs."""
     latent = config.kv_row_shapes[1] is None
-    kv = PartitionSpec() if latent else KVCache.spec(axis)[0]
-    scale = KVCache.scale_spec(axis) if quant else None
+
+    def paired(spec):           # (layer, block, PLANE, line, ...)
+        return PartitionSpec(*spec[:2], None, *spec[2:])
+
+    kv = PartitionSpec() if latent else paired(KVCache.spec(axis)[0])
+    scale = paired(KVCache.scale_spec(axis)) if quant else None
     # (the ring storage of window layers is replicated too: no model runs
     # them on more than one device yet)
     ring = PartitionSpec() if window_kind(config)[0] else None
-    return PagedKVState(k=kv, v=None if latent else kv,
-                        k_scale=scale, v_scale=scale, wk=ring, wv=ring,
+    return PagedKVState(kv=kv, kv_scale=scale, wkv=ring,
                         **dict.fromkeys(config.slot_state_shapes or (),
                                         PartitionSpec()))
 
@@ -275,10 +300,11 @@ def paged_state_shapes(config, *, n_blocks: int, block_size: int,
     """The pool's state as ``jax.ShapeDtypeStruct``s, a ``PagedKVState`` of
     the structure ``paged_state_specs`` gives: what ``KVPool`` allocates,
     and what a compile rehearsal hands the step in place of arrays. Row
-    arenas ``(n_cache_layers, n_blocks, block_size, *row)`` in the wire
-    dtype (scale arenas the same minus the row's width, float32), per-slot
+    arena ``(n_cache_layers, n_blocks, 2, block_size, *row)`` in the wire
+    dtype (no planes where the model names no V row; the scale arena the
+    same minus the row's width, float32), per-slot
     arenas ``(n_state_layers, n_slots, *shape)`` as the model states them,
-    window storage ``(n_window_layers, n_slots, ring_blocks, block_size,
+    window storage ``(n_window_layers, n_slots, 2, ring_blocks, block_size,
     *row)`` with ``ring_blocks`` from the window and ``max_take``, the most
     tokens one slot appends in a step: a model with window layers has no
     default for it (a ring built for a smaller take than a step's is
@@ -309,11 +335,13 @@ def paged_state_shapes(config, *, n_blocks: int, block_size: int,
                 "with rows in the SAME layer by stating kv_row_tokens: an "
                 "EVA layer; window layers beside full ones state nothing)")
         ring = jax.ShapeDtypeStruct(
-            (n_window, n_slots,
+            (n_window, n_slots, KV_PLANES,
              window_ring_blocks(window, block_size, max_take), block_size,
              *k_row), dtype)
+    planes = () if v_row is None else (KV_PLANES,)
     rows = jax.ShapeDtypeStruct(
-        (config.n_cache_layers, n_blocks, block_size, *k_row), dtype)
+        (config.n_cache_layers, n_blocks, *planes, block_size, *k_row),
+        dtype)
     scale = (jax.ShapeDtypeStruct(rows.shape[:-1], jnp.float32)
              if quant else None)
     slot_state = config.slot_state_shapes or {}
@@ -322,8 +350,7 @@ def paged_state_shapes(config, *, n_blocks: int, block_size: int,
             f"{sorted(slot_state)}: the model keeps a state for each slot; "
             f"the pool needs n_slots to build its arenas")
     return PagedKVState(
-        k=rows, v=None if v_row is None else rows, k_scale=scale,
-        v_scale=scale, wk=ring, wv=ring,
+        kv=rows, kv_scale=scale, wkv=ring,
         **{name: jax.ShapeDtypeStruct(
             (config.n_state_layers, n_slots, *shape), jnp.dtype(dt))
            for name, (shape, dt) in slot_state.items()})
@@ -367,7 +394,7 @@ class KVPool:
                 f"run under the Pallas interpreter; serve with the model "
                 f"dtype on a TPU (ROADMAP S5).")
         # What one token's row looks like is the model's to say: per-head
-        # K and V rows, or one latent row and no V arena.
+        # K and V rows, or one latent row that is both.
         k_row, v_row = config.kv_row_shapes
         self.latent = v_row is None
         self._row_width = k_row[-1]
@@ -476,6 +503,10 @@ class KVPool:
                "max_seq_len": self.max_seq_len,
                "max_blocks_per_seq": self.max_blocks_per_seq,
                "kv_dtype": self.kv_dtype.name}
+        if not self.latent:
+            # K's and V's rows of a block are one run of bytes: a manifest
+            # written over two arenas names no layout and is refused
+            geo["layout"] = KV_LAYOUT
         if self.row_tokens != 1:
             geo["row_tokens"] = self.row_tokens
         if self.slot_state:
@@ -486,7 +517,7 @@ class KVPool:
             geo["window"] = {
                 "layers": self.window_layers, "window": self.window,
                 "max_take": self.max_take,
-                "ring_blocks": self.state.wk.shape[2],
+                "ring_blocks": self.state.wkv.shape[3],
                 "bytes": self.window_bytes}
         return geo
 
@@ -501,8 +532,7 @@ class KVPool:
         """Bytes of the window layers' ring storage (0 without any): fixed
         by the window, the step's take and the slots, whatever
         ``max_seq_len`` and ``n_blocks`` are."""
-        return sum(getattr(self.state, name).nbytes
-                   for name in WINDOW_ARENAS) if self.window_layers else 0
+        return self.state.wkv.nbytes if self.window_layers else 0
 
     @property
     def prefix_cacheable(self) -> bool:
@@ -514,14 +544,16 @@ class KVPool:
         return not self.slot_state and not self.window_layers
 
     def kv_fingerprint(self) -> str:
-        """Wire-format identity of this pool's KV bytes: ``dtype:scheme``
-        (e.g. ``"int8:rowmax:v1"``, ``"bfloat16:none"``). Adoption of a
-        cached block is only legal between identical fingerprints — the
-        block's stored bytes are meaningless under any other
-        (dtype, quantization scheme) pair."""
+        """Wire-format identity of this pool's KV bytes:
+        ``dtype:scheme:layout`` (e.g. ``"int8:rowmax:v1:paired"``,
+        ``"bfloat16:none:paired"``). Adoption of a cached block is only
+        legal between identical fingerprints — the block's stored bytes are
+        meaningless under any other (dtype, quantization scheme, layout)
+        triple: a block id recorded over two arenas names no bytes of this
+        one."""
         scheme = KV_QUANT_SCHEME if self.kv_quant else "none"
-        if self.latent:
-            scheme += f":latent{self._row_width}"
+        scheme += (f":latent{self._row_width}" if self.latent
+                   else f":{KV_LAYOUT}")
         if self.slot_state:
             scheme += ":slot[" + "+".join(sorted(self.slot_state)) + "]"
         if self.window_layers:
@@ -732,9 +764,9 @@ class KVPool:
         self._free.append(block)
 
     def _copy_block_device(self, src: int, dst: int) -> None:
-        """Copy-on-write kernel: duplicate block ``src``'s K/V rows (every
-        layer) into ``dst`` on device — and, in a quantized pool, the
-        block's scale rows with them (a wire-dtype row without its scale
+        """Copy-on-write kernel: duplicate block ``src``'s rows (both
+        planes, every layer) into ``dst`` on device — and, in a quantized
+        pool, the block's scale rows with them (a wire-dtype row without its scale
         is garbage; scales MOVE with their blocks). Compiled ONCE per pool
         — src/dst are traced scalars, so CoW churn never retraces — with
         all pool arrays donated (the copy is in-place for HBM accounting,
@@ -840,43 +872,40 @@ class KVPool:
             "cached-block fingerprints out of sync with residency")
         st = self.state
         if self.kv_quant:
-            assert st.k_scale is not None and st.v_scale is not None, (
-                "quantized pool missing scale arenas")
-            assert (st.k_scale.shape == st.v_scale.shape
-                    == st.k.shape[:-1]), (
-                f"scale arena shape {st.k_scale.shape} != KV arena rows "
-                f"{st.k.shape[:-1]}")
-            assert st.k_scale.dtype == jnp.float32
+            assert st.kv_scale is not None, (
+                "quantized pool missing its scale arena")
+            assert st.kv_scale.shape == st.kv.shape[:-1], (
+                f"scale arena shape {st.kv_scale.shape} != KV arena rows "
+                f"{st.kv.shape[:-1]}")
+            assert st.kv_scale.dtype == jnp.float32
         else:
-            assert st.k_scale is None and st.v_scale is None, (
-                "unquantized pool carrying scale arenas")
-        assert st.k.dtype == self.kv_dtype, (
-            f"pool arena dtype {st.k.dtype} != declared {self.kv_dtype}")
-        # One latent arena and no V arena, or K and V arenas alike.
+            assert st.kv_scale is None, (
+                "unquantized pool carrying a scale arena")
+        assert st.kv.dtype == self.kv_dtype, (
+            f"pool arena dtype {st.kv.dtype} != declared {self.kv_dtype}")
+        # One latent arena of rows, or one arena of K and V planes.
         if self.latent:
-            assert st.v is None and st.k.ndim == 4, (
-                "a latent pool holds one 4-D arena and no V arena")
+            assert st.kv.ndim == 4, "a latent pool holds one 4-D arena"
         else:
-            assert st.v is not None and st.v.shape == st.k.shape, (
-                "K and V arenas differ")
-        # The per-slot arenas are the ones the model states, one entry a
-        # (state layer, slot) each.
-        # The window storage exists iff the model has window layers: K and V
-        # rings alike, one a (window layer, slot), rows as the K arena's.
-        for name in WINDOW_ARENAS:
-            a = getattr(st, name)
-            if not self.window_layers:
-                assert a is None, f"pool carrying an unasked arena {name}"
-                continue
+            assert st.kv.ndim == 6 and st.kv.shape[2] == KV_PLANES, (
+                f"a K+V arena keeps two planes a block: {st.kv.shape}")
+        # The window storage exists iff the model has window layers: one
+        # ring a (window layer, slot), two planes of rows as the arena's.
+        if not self.window_layers:
+            assert st.wkv is None, "pool carrying an unasked arena wkv"
+        else:
             ring = window_ring_blocks(self.window, self.block_size,
                                       self.max_take)
-            assert a is not None and a.dtype == self.kv_dtype, (
-                f"pool missing its window storage {name}")
-            assert (a.shape[0], a.shape[2]) == (self.window_layers, ring) \
-                and a.shape[3:] == st.k.shape[2:], (
-                    f"window storage {name}: {a.shape}")
+            assert st.wkv is not None and st.wkv.dtype == self.kv_dtype, (
+                "pool missing its window storage wkv")
+            assert st.wkv.shape[0] == self.window_layers \
+                and st.wkv.shape[2:4] == (KV_PLANES, ring) \
+                and st.wkv.shape[4:] == st.kv.shape[3:], (
+                    f"window storage wkv: {st.wkv.shape}")
+        # The per-slot arenas are the ones the model states, one entry a
+        # (state layer, slot) each.
         for f in dataclasses.fields(st):
-            if f.name in ROW_ARENAS or f.name in WINDOW_ARENAS:
+            if f.name in ROW_ARENAS or f.name == "wkv":
                 continue
             a = getattr(st, f.name)
             if f.name not in self.slot_state:
